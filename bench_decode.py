@@ -74,12 +74,13 @@ _HBM_BW = {
 }
 
 
-def _hbm_bw(device_kind: str):
+def _hbm_bw(device_kind: str) -> float:
     kind = device_kind.lower()
     for key in sorted(_HBM_BW, key=len, reverse=True):
         if key in kind:
-            return _HBM_BW[key], False
-    return 819e9, True  # assume v5e-class if unrecognized
+            return _HBM_BW[key]
+    raise ValueError(f"no HBM bandwidth for device_kind {device_kind!r}: "
+                     "add it to _HBM_BW with its source")
 
 
 def _throughput(run, *args) -> float:
@@ -103,6 +104,17 @@ def main() -> int:
                                                            tp_generate,
                                                            tp_shard_params)
 
+    from distributed_llm_code_samples_tpu.runtime.init import (
+        describe_devices, enable_compile_cache)
+    enable_compile_cache()
+    tp_only = os.environ.get("DECODE_TP_ONLY")  # scaling-probe mode
+    # the roofline's denominator, looked up before anything is timed:
+    # a device the table does not know is an error, not a default. The
+    # scaling probe's children run on the CPU by design (ratios only)
+    # and report no roofline.
+    device = describe_devices()
+    bw = None if tp_only else _hbm_bw(device["kind"])
+
     params = init_lm(jax.random.PRNGKey(0), V, D, L, T0 + NEW)
     prompt = jax.random.randint(jax.random.PRNGKey(1), (B, T0), 0, V)
     paths = {}
@@ -113,8 +125,6 @@ def main() -> int:
             fn()
         except Exception as exc:  # noqa: BLE001
             paths[key] = f"error: {type(exc).__name__}: {str(exc)[:160]}"
-
-    tp_only = os.environ.get("DECODE_TP_ONLY")  # scaling-probe mode
 
     def lm_path():
         run = jax.jit(lambda p, pr: generate(p, pr, NEW, H))
@@ -297,7 +307,7 @@ def main() -> int:
             "scheduler amortization: expect > 1 where steps are "
             "dispatch- or HBM-bound (real chips), < 1 on CPU where "
             "the verify program's (k+1)x compute is not hidden — "
-            "chip numbers land with run_hw_artifacts.sh")
+            "not measured on the chip")
 
     if not tp_only and os.environ.get("DECODE_ENGINE", "1") != "0":
         guarded("engine_spec_tokens_per_sec", spec_rows)
@@ -541,21 +551,15 @@ def main() -> int:
     # through EngineConfig(kernel=...) per KV dtype. Off-chip this runs
     # the Pallas INTERPRETER (a correctness lane, orders of magnitude
     # slower than compiled XLA — the ratio is honest but meaningless
-    # for perf); the real-chip ratio lands with run_hw_artifacts.sh
-    # (ROADMAP item 6). BENCH_FUSED_NEW bounds the interpret-lane cost.
+    # for perf); the real-chip ratio has not been measured.
+    # BENCH_FUSED_NEW bounds the interpret-lane cost.
     def fused_rows():
         import numpy as np
 
         from distributed_llm_code_samples_tpu.decode import (
             DecodeEngine, EngineConfig)
-        from distributed_llm_code_samples_tpu.ops.pallas_paged_attention \
-            import interpret_supported
 
         on_tpu = jax.default_backend() == "tpu"
-        if not on_tpu and not interpret_supported():
-            paths["fused_vs_gather"] = ("skipped: no scalar-prefetch "
-                                        "pallas surface")
-            return
         new = int(os.environ.get("BENCH_FUSED_NEW",
                                  NEW if on_tpu else min(NEW, 24)))
         n_seq = B if on_tpu else min(B, 2)
@@ -591,8 +595,8 @@ def main() -> int:
         if not on_tpu:
             paths["fused_vs_gather_note"] = (
                 "CPU interpret lane: fused runs the Pallas interpreter "
-                "(correctness only; expect << 1). Real-chip ratio is a "
-                "run_hw_artifacts.sh artifact (ROADMAP item 6).")
+                "(correctness only; expect << 1). Real-chip ratio: not "
+                "measured.")
 
     if not tp_only and os.environ.get("DECODE_FUSED", "1") != "0":
         guarded("fused_vs_gather", fused_rows)
@@ -655,8 +659,8 @@ def main() -> int:
             "engine, stepped round-robin in one process: aggregate "
             "tokens per fleet ROUND is the CPU proxy for per-chip "
             "wall clock (outputs asserted byte-identical across N; "
-            ">= 1.8x at N=2 asserted). Real-chip wall-clock scaling "
-            "lands with run_hw_artifacts.sh (ROADMAP item 6).")
+            ">= 1.8x at N=2 asserted). Real-chip wall-clock scaling: "
+            "not measured.")
 
         # Prefill-interference row: p90 engine-step wall time for an
         # engine serving steady decodes while a LONG prompt prefills.
@@ -1668,6 +1672,10 @@ def main() -> int:
                 k2: round(v / base, 3) for k2, v in scaling.items()
                 if isinstance(v, (int, float))}
 
+    if tp_only:
+        print(json.dumps(paths))
+        return 0
+
     lm_tps = paths.get("lm_tokens_per_sec")
 
     # KV-cache bandwidth roofline for the lm path: each decode step
@@ -1677,7 +1685,6 @@ def main() -> int:
     param_bytes = 4 * num_params
     t_avg = T0 + NEW / 2
     kv_bytes_avg = 2 * L * t_avg * D * 4          # per sequence, f32 k+v
-    bw, bw_assumed = _hbm_bw(jax.devices()[0].device_kind)
     step_s_min = (param_bytes + B * kv_bytes_avg) / bw
     roofline = B / step_s_min
     # the engine's KV-dtype lever against the same roofline: shrinking
@@ -1716,8 +1723,6 @@ def main() -> int:
         "hbm_bw_gbps": round(bw / 1e9, 1),
         **paths,
     }
-    if bw_assumed:
-        payload["hbm_bw_assumed"] = True
     print(json.dumps(payload))
     artifact = os.environ.get("DECODE_ARTIFACT")
     if artifact:

@@ -113,6 +113,10 @@ def _onchip_oom(payload):
 
 
 def main() -> int:
+    from distributed_llm_code_samples_tpu.runtime.init import (
+        describe_devices, enable_compile_cache)
+    enable_compile_cache()
+    describe_devices()
     payload = {
         "metric": "memdemo_fsdp_fits_where_ddp_ooms",
         "unit": "bool",

@@ -57,6 +57,10 @@ from distributed_llm_code_samples_tpu.utils.trace_analysis import (
 
 
 def main() -> int:
+    from distributed_llm_code_samples_tpu.runtime.init import (
+        describe_devices, enable_compile_cache)
+    enable_compile_cache()
+    describe_devices()
     from distributed_llm_code_samples_tpu.data import make_seed_schedule
     from distributed_llm_code_samples_tpu.models import init_ffn_stack
     from distributed_llm_code_samples_tpu.parallel import (DATA_AXIS,

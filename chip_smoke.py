@@ -1,0 +1,439 @@
+#!/usr/bin/env python
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py            # one TPU chip: serve, fused kernel, train
+    python chip_smoke.py --chips 4  # four chips: the cross-chip paths only
+
+One process. It imports JAX once and drives the program through the
+entry point a user calls — ``train_ffns.py`` ->
+``distributed_llm_code_samples_tpu.cli.main(argv)`` — in-process, so no
+child ever needs the chip this process holds. It never picks a
+platform, never fakes devices, never asks for interpret mode; it fails
+(non-zero exit, no result line) unless JAX's first device is a TPU.
+
+Every phase raises on failure. Each prints one JSON object (phase,
+argv, wall and compile seconds, tokens or steps, peak device bytes);
+the LAST line of stdout is the result:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+The sizes below are the real ones (GPT-2 small at its published
+widths, the paper's FFN stack at d=8192). ``tests/test_chip_smoke.py``
+rehearses the same control flow on the CPU by shrinking them in the
+test — that is the only way they change.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import io
+import json
+import math
+import os
+import shutil
+import sys
+import time
+
+import jax
+
+from distributed_llm_code_samples_tpu import cli
+from distributed_llm_code_samples_tpu.parallel import launcher
+from distributed_llm_code_samples_tpu.runtime import native, telemetry
+from distributed_llm_code_samples_tpu.runtime.init import (
+    describe_devices, enable_compile_cache)
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+# GPT-2 small at its published widths; weights from the seed, f32.
+SERVE = dict(model=["-d", "768", "-l", "12", "--heads", "12",
+                    "--max_seq_len", "1024"],
+             vocab=50257,
+             # the vocab-parallel head wants V divisible by the model
+             # axis: GPT-2's padded vocabulary, on both sides of the
+             # --tp comparison
+             vocab_tp=50304,
+             prompt_lens="48,137,290,512", max_new=32,
+             # the engine's default block: the fused walk must compile
+             # at it (tests/test_chip_compile.py), not at a special one
+             block_size=16)
+# The paper's model at the paper's width (train_ffns.py's docstring
+# shape; 2 GiB of f32 parameters), then BASELINE.json config 5's shape,
+# the one with chip history. Both at the reference's own learning rate
+# (1e-5, the default): gradients are SUMS over 8192 tokens, and at
+# --lr 0.1 the d=8192 run was seen on the v5e to reach weights of 1e12
+# in 8 steps and a gradient norm that overflows f32.
+# ``moves``: whether 8 steps move layer 0's printed corner. They cannot
+# at config 5's depth: 24 un-normalised layers at init scale 0.02 shrink
+# the signal ~0.4x each, so every gradient is below f32 resolution of
+# the weights (norm ~1.5e-4 over 113M parameters) — that shape is a
+# step-rate workload, and is held to a finite, non-zero gradient only.
+TRAIN = [dict(argv=["-d", "8192", "-l", "1", "-n", "1024", "-bs", "8",
+                    "-s", "8"], moves=True),
+         dict(argv=["-d", "768", "-l", "24", "-n", "1024", "-bs", "8",
+                    "-s", "8"], moves=False)]
+# the LM trainer across four chips (vocab-parallel Megatron TP)
+TRAIN_LM = ["-d", "768", "-l", "12", "--heads", "12", "--vocab", "50304",
+            "-n", "1024", "-bs", "8", "-s", "4", "--lr", "0.1"]
+
+MOSAIC = "tpu_custom_call"
+
+
+def require_tpu() -> dict:
+    """The device as JAX reports it — or an error where it is no TPU."""
+    device = describe_devices()
+    if device["platform"] != "tpu":
+        raise SystemExit(
+            f"chip_smoke: JAX's first device is {device['platform']!r} "
+            f"({device['kind']}), not a TPU — nothing was run")
+    return device
+
+
+class _Compiles:
+    """Compile seconds and persistent-cache traffic, from JAX's own
+    monitoring events."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.hits = self.misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._dur)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _dur(self, event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += secs
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def snapshot(self):
+        return self.seconds, self.hits, self.misses
+
+
+COMPILES = _Compiles()
+
+
+def peak_bytes(device) -> int | None:
+    """The device allocator's high-water mark (None where the backend
+    keeps no statistics — the CPU)."""
+    return (device.memory_stats() or {}).get("peak_bytes_in_use")
+
+
+def run_cli(phase: str, argv: list[str], **extra) -> tuple[str, dict]:
+    """One ``cli.main(argv)`` call: its return code must be 0. Returns
+    what it printed and the phase record (the caller adds its findings
+    and emits it). The CLI's own chatter goes to stderr so stdout stays
+    the records."""
+    s0, h0, m0 = COMPILES.snapshot()
+    out = io.StringIO()
+    t0 = time.perf_counter()
+    with contextlib.redirect_stdout(out):
+        rc = cli.main(list(argv))
+    wall = time.perf_counter() - t0
+    text = out.getvalue()
+    sys.stderr.write(text)
+    if rc != 0:
+        raise RuntimeError(f"{phase}: cli.main returned {rc} for {argv}")
+    s1, h1, m1 = COMPILES.snapshot()
+    rec = {"phase": phase, "argv": list(argv), "wall_s": round(wall, 3),
+           "compile_s": round(s1 - s0, 3), "cache_hits": h1 - h0,
+           "cache_misses": m1 - m0,
+           "peak_bytes_in_use": peak_bytes(jax.devices()[0]), **extra}
+    return text, rec
+
+
+def emit(rec: dict) -> None:
+    print(json.dumps(rec), flush=True)
+
+
+def generate(phase: str, argv: list[str], vocab: int) -> tuple[dict, dict]:
+    """A ``generate`` run and its checks: every request completed, every
+    token id in range."""
+    text, rec = run_cli(phase, ["generate", *argv])
+    payload = json.loads(text.strip().splitlines()[-1])
+    if payload["failed"]:
+        raise RuntimeError(f"{phase}: failed requests {payload['failed']}")
+    n_req = len(argv[argv.index("--prompt_lens") + 1].split(","))
+    if len(payload["sequences"]) != n_req:
+        raise RuntimeError(f"{phase}: {len(payload['sequences'])} of "
+                           f"{n_req} requests completed")
+    for seq in payload["sequences"]:
+        if not all(0 <= t < vocab for t in seq["tokens"]):
+            raise RuntimeError(f"{phase}: token id outside [0, {vocab})")
+    rec["tokens"] = payload["tokens_generated"]
+    rec["compiled_programs"] = payload["compiled_programs"]
+    return payload, rec
+
+
+def tokens_of(payload: dict) -> list:
+    return [s["tokens"] for s in payload["sequences"]]
+
+
+def serve_argv(vocab: int, *more: str) -> list[str]:
+    return [*SERVE["model"], "--vocab", str(vocab), "-r", "7",
+            "--prompt_lens", SERVE["prompt_lens"],
+            "--max_new", str(SERVE["max_new"]), *more]
+
+
+def phase_serve(out_dir: str) -> None:
+    """Phase 1: the paged-KV engine on the gather path, bf16 KV."""
+    mdir = os.path.join(out_dir, "serve_metrics")
+    argv = serve_argv(SERVE["vocab"], "--kv_dtype", "bf16",
+                      "--metrics_dir", mdir)
+    first, rec = generate("serve_gather", argv, SERVE["vocab"])
+    emit(rec)
+    again, rec2 = generate("serve_gather_again", argv, SERVE["vocab"])
+    emit(rec2)
+    if tokens_of(first) != tokens_of(again):
+        raise RuntimeError("serve: the same argv gave different tokens "
+                           "the second time")
+    if rec2["cache_misses"]:
+        raise RuntimeError(f"serve: the second run compiled "
+                           f"{rec2['cache_misses']} new program(s)")
+    _, rec3 = run_cli("serve_report", ["report", mdir])
+    emit(rec3)
+
+
+class _UntilMosaic(list):
+    """A ``launcher.CAPTURE_COMPILED`` sink that keeps compiled programs
+    until one carries the Mosaic call, then disarms the hook (the engine
+    re-compiles every dispatch while it is armed)."""
+
+    def append(self, hlo: str) -> None:
+        if MOSAIC in hlo:
+            super().append(hlo)
+            launcher.CAPTURE_COMPILED = None
+
+
+def first_difference(a: list, b: list):
+    for uid, (x, y) in enumerate(zip(a, b)):
+        for pos, (t, u) in enumerate(zip(x, y)):
+            if t != u:
+                return {"uid": uid, "position": pos, "tokens": [t, u]}
+    return None
+
+
+def phase_fused(out_dir: str) -> None:
+    """Phase 2: the fused Pallas block-table walk against the gather
+    path — same prompts, same block size, f32 KV, greedy. The compiled
+    decode program must carry the Mosaic call, so an interpreted kernel
+    cannot pass for a compiled one.
+
+    Same tokens is the bar at float32 matmul precision. At the default
+    — how ``generate`` runs — XLA's f32 dot on the MXU and Mosaic's
+    round their operands differently, and a random-weight model's
+    near-tied logits turn that into different greedy picks: that pair
+    runs too, and where it first differs is RECORDED, not asserted (the
+    kernel module's docstring states the contract per backend)."""
+    del out_dir
+    shape = {"model": SERVE["model"], "block_size": SERVE["block_size"],
+             "kv_dtype": "f32"}
+    common = serve_argv(SERVE["vocab"], "--kv_dtype", "f32",
+                        "--block_size", str(SERVE["block_size"]))
+
+    def pair(tag: str) -> tuple[dict, bool]:
+        want, rec = generate(f"serve_f32_gather{tag}",
+                             [*common, "--kernel", "gather"], SERVE["vocab"])
+        emit(rec)
+        launcher.CAPTURE_COMPILED = captured = _UntilMosaic()
+        try:
+            got, rec = generate(f"serve_f32_fused{tag}",
+                                [*common, "--kernel", "fused"],
+                                SERVE["vocab"])
+        finally:
+            launcher.CAPTURE_COMPILED = None
+        rec["fused_shape"] = shape
+        rec["mosaic_call_in_compiled_decode"] = bool(captured)
+        rec["first_difference_from_gather"] = first_difference(
+            tokens_of(got), tokens_of(want))
+        return rec, tokens_of(got) == tokens_of(want)
+
+    rec, _ = pair("_default_precision")
+    rec["matmul_precision"] = "default"
+    emit(rec)
+    with jax.default_matmul_precision("highest"):
+        rec, same = pair("")
+    rec["matmul_precision"] = "highest"
+    emit(rec)
+    if not rec["mosaic_call_in_compiled_decode"]:
+        raise RuntimeError(f"fused: no compiled engine program contains "
+                           f"{MOSAIC} — the kernel did not reach Mosaic")
+    if not same:
+        raise RuntimeError(f"fused: tokens differ from the gather path's: "
+                           f"{rec['first_difference_from_gather']}")
+
+
+def step_records(mdir: str) -> list[dict]:
+    records, errors = telemetry.read_metrics(
+        os.path.join(mdir, "metrics.jsonl"))
+    if errors:
+        raise RuntimeError(f"telemetry stream {mdir}: {errors[:3]}")
+    return [r for r in records if r["kind"] == "step"]
+
+
+def corners(text: str, tag: str) -> str:
+    """The 5x5 parameter corners the CLI prints after ``tag`` (the
+    reference's before/after printout) — the values line, not the
+    shapes line."""
+    block = text.split(tag)[2]
+    end = block.find("\n\n")
+    return block if end < 0 else block[:end]
+
+
+def phase_train(out_dir: str) -> None:
+    """Phase 3: the paper's FFN stack through method 1, at the paper's
+    width and at the one shape with chip history."""
+    for i, shape in enumerate(TRAIN):
+        mdir = os.path.join(out_dir, f"train_metrics_{i}")
+        argv = ["-m", "1", *shape["argv"], "-r", "7", "--strict",
+                "--metrics_dir", mdir]
+        text, rec = run_cli(f"train_single_{i}", argv)
+        steps = step_records(mdir)
+        before = corners(text, "initial layers_params[0]")
+        after = corners(text, "final train_single layers_params[0]")
+        rec["steps"] = steps[-1]["step"] if steps else 0
+        rec["mfu"] = [r["mfu"] for r in steps]
+        rec["grad_norm"] = [r["grad_norm"] for r in steps]
+        rec["corner_moved"] = before != after
+        emit(rec)
+        want = int(shape["argv"][shape["argv"].index("-s") + 1])
+        if rec["steps"] != want:
+            raise RuntimeError(f"train {argv}: {rec['steps']} of {want} "
+                               "steps recorded")
+        if any(m is None or not m > 0 for m in rec["mfu"]):
+            raise RuntimeError(
+                f"train {argv}: null MFU — runtime/telemetry.py's peak "
+                f"table does not know {jax.devices()[0].device_kind!r}")
+        # finite: the probe's gradient norm over ALL parameters at their
+        # final values (one NaN or inf weight poisons it), and the
+        # corner the CLI prints. Changed: that corner, where the shape
+        # lets 8 steps move it; everywhere, a non-zero gradient.
+        if any(g is None or not math.isfinite(g) or not g > 0
+               for g in rec["grad_norm"]):
+            raise RuntimeError(f"train {argv}: gradient norm "
+                               f"{rec['grad_norm']}")
+        if "nan" in after or "inf" in after:
+            raise RuntimeError(f"train {argv}: non-finite parameters")
+        if shape["moves"] and not rec["corner_moved"]:
+            raise RuntimeError(f"train {argv}: parameters did not change")
+
+
+def phase_cross_chip(out_dir: str) -> None:
+    """``--chips 4``: the cross-chip paths and what each is compared
+    with — nothing else.
+
+    (a) is ``-m 0 --strict`` as a user runs it: single, DDP, FSDP and
+    TP with the CLI's own differential check (``cli.py::
+    strategy_disagreement``; the CLI runs it at float32 matmul
+    precision of its own accord), whose verdict ``--strict`` turns into
+    the return code that ``run_cli`` holds to 0. (b) compares greedy
+    tokens, at float32 matmul precision too: sharding a contraction
+    over chips rounds it differently at the MXU's default (bf16
+    passes)."""
+    n = jax.device_count()
+    if n != 4:
+        raise RuntimeError(f"--chips 4 needs 4 devices, JAX sees {n}")
+    v = SERVE["vocab_tp"]
+    common = serve_argv(v, "--kv_dtype", "f32")
+    launcher.CAPTURE_COMPILED = captured = []
+    try:
+        text, rec = run_cli("train_all_strategies",
+                            ["-m", "0", *TRAIN[0]["argv"], "-r", "7",
+                             "--strict"])
+    finally:
+        launcher.CAPTURE_COMPILED = None
+    # what the CLI's check said: one "compared" line per pair, plus the
+    # ReLU flips it admitted. Both pairs must have been compared — a
+    # return code of 0 from a check that never ran proves nothing.
+    rec["cli_check"] = [ln for ln in text.splitlines() if ln.startswith(
+        ("compared ", "relu flips", "SoftAssertionError"))]
+    compared = [ln for ln in rec["cli_check"] if ln.startswith("compared ")]
+    # every device ran a program: the launched DDP/FSDP/TP programs are
+    # compiled for 4 partitions (a mesh that silently has one entry is
+    # the failure to catch), and every device's allocator saw bytes
+    spmd = [h for h in captured if f"num_partitions={n}" in h[:2000]]
+    peaks = [peak_bytes(d) for d in jax.devices()]
+    rec["programs_over_4_partitions"] = len(spmd)
+    rec["peak_bytes_per_device"] = peaks
+    emit(rec)
+    if len(compared) != 2 or not all(": agree (" in ln for ln in compared):
+        raise RuntimeError(f"cross-chip: the CLI's differential check "
+                           f"did not report two agreeing pairs: "
+                           f"{rec['cli_check']}")
+    if len(spmd) < 3:
+        raise RuntimeError(f"cross-chip: {len(spmd)} of the 3 sharded "
+                           f"strategies compiled for {n} partitions")
+    if not all(peaks):  # None (no allocator statistics) fails like 0
+        raise RuntimeError(f"cross-chip: an idle device, peaks {peaks}")
+    with jax.default_matmul_precision("highest"):
+        # (b) Megatron decode over 4 chips against one device
+        want, rec = generate("serve_tp1", [*common, "--tp", "1"], v)
+        emit(rec)
+        got, rec = generate("serve_tp4", [*common, "--tp", "4"], v)
+    diff = first_difference(tokens_of(got), tokens_of(want))
+    rec["first_difference_from_tp1"] = diff
+    rec["note"] = (f"vocab {v} on both sides: the vocab-parallel head "
+                   "needs V divisible by 4; float32 matmul precision")
+    emit(rec)
+    if got["tp"] != 4:
+        raise RuntimeError(f"serve --tp 4 ran at tp={got['tp']}")
+    if diff or tokens_of(got) != tokens_of(want):
+        raise RuntimeError(f"serve --tp 4: tokens differ from --tp 1: "
+                           f"{diff}")
+    # (c) the LM trainer, vocab-parallel TP: loss finite and falling
+    mdir = os.path.join(out_dir, "train_lm_metrics")
+    _, rec = run_cli("train_lm_tp4",
+                     ["-m", "11", "--tp", "4", *TRAIN_LM, "-r", "7",
+                      "--metrics_dir", mdir, "--log_every", "1"])
+    losses = [r["loss"] for r in step_records(mdir)]
+    rec["steps"], rec["loss"] = len(losses), losses
+    emit(rec)
+    if len(losses) < 2 or any(x is None or not math.isfinite(x)
+                              for x in losses):
+        raise RuntimeError(f"train_lm_tp4: losses {losses}")
+    # each loss is probed on the NEXT step's batch, so neighbours
+    # jitter; falling means it ends below where it began
+    if not losses[-1] < losses[0]:
+        raise RuntimeError(f"train_lm_tp4: loss not falling: {losses}")
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    args = ap.parse_args(argv)
+    t0 = time.perf_counter()
+    device = require_tpu()
+    cache_dir = enable_compile_cache()
+    out_dir = os.path.join(HERE, "chiprun_out",
+                           f"chip_smoke_{args.chips}chip")
+    shutil.rmtree(out_dir, ignore_errors=True)  # metrics streams append
+    os.makedirs(out_dir)
+    phases = ([phase_cross_chip] if args.chips == 4
+              else [phase_serve, phase_fused, phase_train])
+    for phase in phases:
+        try:
+            phase(out_dir)
+        except BaseException as e:
+            print(f"chip_smoke: {phase.__name__} failed: "
+                  f"{type(e).__name__}: {e}", file=sys.stderr)
+            raise
+    # built from what git holds: no phase may have needed the native
+    # libraries (git-ignored .so files, built by make on first use)
+    if native._LIB is not None or native._FFI_LIB is not None:
+        raise RuntimeError("a phase loaded the native C++ libraries")
+    compile_s, hits, misses = COMPILES.snapshot()
+    emit({"phase": "total", "compile_cache_dir": cache_dir,
+          "wall_s": round(time.perf_counter() - t0, 3),
+          "compile_s": round(compile_s, 3), "cache_hits": hits,
+          "cache_misses": misses})
+    if args.chips == 4 and device["count"] != 4:
+        raise RuntimeError(f"device count {device['count']} != 4")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

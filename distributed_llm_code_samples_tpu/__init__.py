@@ -23,136 +23,6 @@ model families), ``parallel`` (mesh, collectives, strategies, launcher),
 
 __version__ = "0.1.0"
 
-import jax as _jax  # noqa: E402
-
-# --- jax version compat (a backend-environment robustness layer in the
-# same spirit as the env-matrix probe: the framework must not die on the
-# jax it is handed). The code targets the graduated >= 0.5 API surface;
-# on older jax each shim maps to the pre-graduation equivalent. Every
-# shim is hasattr-gated: all of this is a no-op on modern jax.
-
-if not hasattr(_jax, "shard_map"):
-    # shard_map lived under jax.experimental with the pre-graduation
-    # keyword spelling (check_rep, renamed check_vma on graduation).
-    # check_rep is pinned False: the old replication-checking discipline
-    # predates the vma type system this code is written against (pcast/
-    # pvary annotations below), and mixing the two only manufactures
-    # spurious type errors — without it the shard_map is plain SPMD,
-    # which is the semantics every strategy here hand-verifies anyway.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _shard_map_compat(f, *, mesh, in_specs, out_specs,
-                          check_vma=True, **kw):
-        del check_vma
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False, **kw)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    # the classic spelling: a psum of the literal 1 over the axis is
-    # evaluated statically to the axis size
-    def _axis_size(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size
-
-if not hasattr(_jax, "typeof"):
-    # jax.typeof + the vma (varying-manual-axes) type system arrived
-    # with graduated shard_map. Pre-vma jax tracks no varying-axes type,
-    # so: typeof exposes an aval whose .vma is empty, and the pcast /
-    # pvary annotations that adjust vma types are identity functions —
-    # with replication checking off (above) they carried no runtime
-    # semantics to begin with.
-    class _AvalView:
-        __slots__ = ("_aval",)
-
-        def __init__(self, aval):
-            self._aval = aval
-
-        def __getattr__(self, name):
-            if name == "vma":
-                return getattr(self._aval, "vma", frozenset())
-            return getattr(self._aval, name)
-
-    def _typeof(x):
-        return _AvalView(_jax.core.get_aval(x))
-
-    # the capability marker consumers key on: with vma typing erased,
-    # NO cotangent is ever auto-reduced (transposes of the implicit
-    # pvary don't exist), so grad_reduce's vma-off force contract is
-    # the correct regime everywhere (parallel/collectives.py)
-    _typeof.erased_vma = True
-    _jax.typeof = _typeof
-
-if not hasattr(_jax.lax, "pcast"):
-    def _pcast(x, axes, *, to=None):
-        del axes, to
-        return x
-
-    def _pvary(x, axes):
-        del axes
-        return x
-
-    _jax.lax.pcast = _pcast
-    if not hasattr(_jax.lax, "pvary"):
-        _jax.lax.pvary = _pvary
-
-try:
-    _jax.ShapeDtypeStruct((), "float32", vma=frozenset())
-except TypeError:
-    # pre-vma ShapeDtypeStruct has no vma kwarg; the annotation carries
-    # no information in the erased-vma regime, so swallow it
-    _OrigSDS = _jax.ShapeDtypeStruct
-
-    class _SDSCompat(_OrigSDS):
-        def __init__(self, shape, dtype, *, vma=None, **kw):
-            del vma
-            super().__init__(shape, dtype, **kw)
-
-    _jax.ShapeDtypeStruct = _SDSCompat
-
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-
-    if not hasattr(_pltpu, "CompilerParams"):
-        # renamed from TPUCompilerParams when the pallas TPU surface
-        # dropped its prefix; later fields (has_side_effects, ...) do
-        # not exist pre-rename — drop them rather than die, the CPU
-        # interpret paths this environment runs don't consume them
-        import dataclasses as _dc
-
-        _tpu_fields = {f.name
-                       for f in _dc.fields(_pltpu.TPUCompilerParams)}
-
-        def _compiler_params(**kw):
-            return _pltpu.TPUCompilerParams(
-                **{k: v for k, v in kw.items() if k in _tpu_fields})
-
-        _pltpu.CompilerParams = _compiler_params
-    if not hasattr(_pltpu, "InterpretParams"):
-        # the dedicated TPU interpret mode (simulated RDMA/semaphores)
-        # does not exist pre-graduation; fall back to generic
-        # interpret=True, the best this jax can do off-TPU
-        def _interpret_params(**kw):
-            del kw
-            return True
-
-        _pltpu.InterpretParams = _interpret_params
-except ImportError:  # pallas not on this build; ops modules self-guard
-    pass
-
-if not hasattr(_jax, "ffi"):
-    # jax.ffi graduated from jax.extend.ffi with the same callable-
-    # builder API; alias the module so both `jax.ffi.x` attribute access
-    # and `import jax.ffi` resolve
-    import sys as _sys
-
-    from jax.extend import ffi as _ffi
-
-    _jax.ffi = _ffi
-    _sys.modules.setdefault("jax.ffi", _ffi)
-
 # Training hyperparameters of the reference workload (train_ffns.py:29-30).
 LR = 1e-5
 DLOSS_DX_COEF = 0.1

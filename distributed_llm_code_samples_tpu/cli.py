@@ -17,6 +17,59 @@ import os
 import sys
 import time
 
+# What the differential check (``-m 0`` / ``-m 9``) admits beyond its
+# elementwise tolerance — see ``strategy_disagreement``.
+FLIP_ROWS = 0.01    # at most this share of a weight's rows ...
+FLIP_UPDATE = 0.05  # ... each within this share of its largest update
+
+
+def strategy_disagreement(a, b, init, rtol, atol):
+    """How the final weights ``a`` and ``b`` of two strategies, both
+    trained from ``init``, disagree: ``(failed, text)``, text None where
+    every element is inside ``rtol``/``atol``.
+
+    Elementwise closeness is the bar, with one admitted exception.
+    ReLU is not smooth: two programs that compute the same step in a
+    different order (another sharding, another reduction) put a
+    pre-activation that sits at zero on opposite sides of it, and that
+    one (token, unit) then adds or withholds its whole term
+    ``lr * da * x`` in row ``unit`` of the weight that feeds the ReLU —
+    a jump the size of one token's share of the update, however small
+    the rounding difference that caused it. The number of pre-activations
+    grows with width x tokens, so at the paper's d=8192 (2.7e8 a step)
+    some always flip. Such a disagreement is *row-sparse* and *small
+    against the update*: it is admitted (and printed as a note) where it
+    is confined to ``FLIP_ROWS`` of the weight's rows and stays inside
+    ``FLIP_UPDATE`` of the largest update the weight received. A lost or
+    doubled reduction, a wrong shard or a shard-boundary slip moves a
+    quarter of the rows, or a row by the update itself.
+
+    Measured on four v5e chips (``-m 0`` at d=8192, 8 steps, float32
+    matmul precision): DDP vs FSDP differed in 7 of w1's 32768 rows, by
+    at most 0.65% of the largest update; in each of those rows ONE
+    token's vector carried over 99.97% of the difference and that
+    token's pre-activation sat among the smallest 1% of the run's
+    65536; every other row agreed to 7.5e-9, and w2 everywhere. The two
+    bounds sit between that and the smallest fault planted on the same
+    arrays (one row left at its initial value: that row's whole
+    update), which fails."""
+    import numpy as np
+
+    bad = ~np.isclose(a, b, rtol=rtol, atol=atol)
+    if not bad.any():
+        return False, None
+    diff = np.abs(a - b)
+    text = f"max|diff|={diff.max()}"
+    if a.ndim < 2:
+        return True, text
+    rows = bad.any(axis=-1)
+    update = np.abs(b - init).max()
+    text += (f", {bad.sum()} elements in {rows.sum()} of {rows.size} rows"
+             f", largest update {update}")
+    flips = (rows.sum() <= FLIP_ROWS * rows.size
+             and diff.max() <= FLIP_UPDATE * update)
+    return not flips, text
+
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
@@ -281,6 +334,34 @@ def main(argv=None) -> int:
     import jax
     if args.fake_devices:
         jax.config.update("jax_platforms", "cpu")
+
+    from .runtime.init import describe_devices, enable_compile_cache
+    enable_compile_cache()
+    describe_devices()
+
+    if (args.method in (0, 9) and not (args.mixed or args.pallas)
+            and jax.config.jax_default_matmul_precision is None):
+        # The differential check compares float32 programs, as the
+        # reference's does (torch multiplies f32 in f32). The MXU's
+        # default rounds f32 operands to bf16: seen on four v5e chips
+        # at d=8192, one device and TP then differ in EVERY row of w2,
+        # by 1e-3 of the update — two strategies are then two bf16
+        # roundings of the same math, and no f32 tolerance holds. At
+        # float32 precision they agree to 1e-8 outside a handful of
+        # ReLU flips (``strategy_disagreement``). An ambient setting
+        # (JAX_DEFAULT_MATMUL_PRECISION, a caller's context) is kept.
+        print("differential check: strategies run at float32 matmul "
+              "precision; their timings below are not default-precision "
+              "step times")
+        with jax.default_matmul_precision("highest"):
+            return _train(args, argv)
+    return _train(args, argv)
+
+
+def _train(args, argv) -> int:
+    """Everything after start-up: the selected strategies, their
+    telemetry, and for ``-m 0`` / ``-m 9`` the differential check."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -950,8 +1031,8 @@ def main(argv=None) -> int:
         # order composes with bf16 rounding).
         rtol, atol = ((1e-4, 1e-5) if args.pallas else
                       (2e-2, 1e-4) if args.mixed else (1e-5, 1e-7))
-        checks = [("ddp", "fsdp", results[2], results[3], rtol, atol),
-                  ("1dev", "tp", results[1], results[4], rtol, atol)]
+        checks = [("ddp", "fsdp", results[2], results[3], rtol, atol, 2),
+                  ("1dev", "tp", results[1], results[4], rtol, atol, 1)]
         if args.method == 9:
             # every extension strategy against its oracle (the reference's
             # --method 0 idea extended to the full surface)
@@ -965,31 +1046,31 @@ def main(argv=None) -> int:
                                make_mesh({DATA_AXIS: dp}), lr=lr,
                                unroll=unroll)
             checks.append(("hybrid", f"ddp({dp})", results[5], ddp_dp,
-                           rtol, atol))
+                           rtol, atol, 5))
             # PP replicates the data; microbatch grads sum to the
             # full-batch grad => equals the single-device run
             checks.append(("pp", "1dev", results[6], results[1],
-                           rtol, atol))
+                           rtol, atol, 1))
             # EP == the dense grouped-dispatch oracle, no mesh involved
             moe_dense = train_moe_dense(params_for(7), seeds, tokens,
                                         args.model_size, lr=lr,
                                         n_groups=n_dev)
             checks.append(("moe_ep", "moe_dense", results[7], moe_dense,
-                           1e-4, 1e-5))
+                           1e-4, 1e-5, 7))
             # transformer TP replicates the data => equals transformer
             # single-device
             t_single = train_transformer_single(
                 params_for(8), seeds, tokens, args.model_size, lr=lr,
                 seq_len=args.seq_len, n_heads=args.heads)
             checks.append(("ttp", "t1dev", results[8], t_single,
-                           1e-4, 1e-5))
+                           1e-4, 1e-5, 8))
             # GShard MoE transformer == its dense grouped oracle
             from .parallel import train_moe_transformer_dense
             mt_dense = train_moe_transformer_dense(
                 params_for(10), seeds, tokens, args.model_size, lr=lr,
                 seq_len=args.seq_len, n_heads=args.heads, n_groups=n_dev)
             checks.append(("moe_tf_ep", "moe_tf_dense", results[10],
-                           mt_dense, 1e-4, 1e-5))
+                           mt_dense, 1e-4, 1e-5, 10))
             # vocab-parallel LM TP replicates the data => equals the LM
             # single-device oracle on the real objective
             from .parallel import train_lm_single
@@ -997,31 +1078,42 @@ def main(argv=None) -> int:
                 params_for(11), seeds, tokens, args.model_size, lr=lr,
                 seq_len=args.seq_len, n_heads=args.heads)
             checks.append(("lm_tp", "lm_1dev", results[11], lm_single,
-                           1e-4, 1e-5))
+                           1e-4, 1e-5, 11))
             # sequence-parallel LM replicates the data too (each shard
             # regenerates the batch and takes its token block) => equals
             # the same single-device oracle
             checks.append(("lm_seq", "lm_1dev", results[13], lm_single,
-                           1e-4, 1e-5))
+                           1e-4, 1e-5, 11))
             # GShard MoE-LM == its dense grouped oracle (real loss + aux)
             from .parallel import train_moe_lm_dense
             moe_lm_dense = train_moe_lm_dense(
                 params_for(12), seeds, tokens, args.model_size, lr=lr,
                 seq_len=args.seq_len, n_heads=args.heads, n_groups=n_dev)
             checks.append(("moe_lm_ep", "moe_lm_dense", results[12],
-                           moe_lm_dense, 1e-4, 1e-5))
-        for la, lb, a, b, rt, at in checks:
+                           moe_lm_dense, 1e-4, 1e-5, 12))
+        for la, lb, a, b, rt, at, fam in checks:
             # leaves-with-paths rather than _fields: the LM family's params
             # nest (blocks is a NamedTuple inside LMParams)
             flat_a = jax.tree_util.tree_flatten_with_path(a)[0]
             flat_b = jax.tree_util.tree_leaves(b)
-            for (path, leaf_a), leaf_b in zip(flat_a, flat_b):
+            flat_0 = jax.tree_util.tree_leaves(params_for(fam))
+            agree = True
+            for (path, leaf_a), leaf_b, leaf_0 in zip(flat_a, flat_b,
+                                                      flat_0):
                 field = jax.tree_util.keystr(path)
-                pa, pb = np.asarray(leaf_a), np.asarray(leaf_b)
-                if not np.allclose(pa, pb, rtol=rt, atol=at):
-                    print(f"SoftAssertionError: {la}{field} vs "
-                          f"{lb}{field} max|diff|={np.abs(pa - pb).max()}")
-                    failed = True
+                bad, text = strategy_disagreement(
+                    np.asarray(leaf_a), np.asarray(leaf_b),
+                    np.asarray(leaf_0), rt, at)
+                if text:
+                    print(f"{'SoftAssertionError' if bad else 'relu flips'}"
+                          f": {la}{field} vs {lb}{field} {text}")
+                agree &= not bad
+            # one line per comparison, so a reader (and chip_smoke.py)
+            # sees that it ran and what it was held to
+            print(f"compared {la} vs {lb}: "
+                  f"{'agree' if agree else 'DISAGREE'} "
+                  f"(rtol={rt}, atol={at}, {len(flat_0)} arrays)")
+            failed |= not agree
     if metrics is not None:
         metrics.close()  # drain the writer: records are on disk on exit
     return 1 if (failed and args.strict) else 0

@@ -89,7 +89,11 @@ attacks on per-token cost):
   (``ops/pallas_paged_attention.py``) instead of the gather →
   ``decode_attn`` two-pass — pool bytes cross the bus once, at the
   storage dtype, int8 dequant folded in. The gather path stays the
-  differential oracle (bit-identical at f32 under jit).
+  differential oracle: on the CPU interpreter within a stated ULP
+  bound of it and token-identical through the engine; on the chip
+  token-identical at float32 matmul precision only — at the default
+  precision the two diverge on near-tied logits (the kernel module's
+  docstring has the per-backend contract).
 
 Shared-prefix layer (round 13, DESIGN.md section 19 — the capacity
 multiplier: most requests share a long system prompt, so N admissions
@@ -516,19 +520,18 @@ class DecodeEngine:
                     "kernel='fused' is single-device (the head-sharded "
                     "TP pool runs the gather path); pass mesh=None or "
                     "kernel='gather'")
-            from ..ops.pallas_paged_attention import interpret_supported
-            if jax.default_backend() != "tpu" and not \
-                    interpret_supported():
-                raise ValueError(
-                    "kernel='fused' needs the scalar-prefetch pallas "
-                    "surface for its off-chip interpret mode; this jax "
-                    "lacks it — use kernel='gather'")
         self.params = params
         self.n_heads = n_heads
         self.cfg = cfg
         self.mesh = mesh
         self.dh = params.d_model // n_heads
         self.kv_heads = params.blocks.wk.shape[1] // self.dh
+        if cfg.kernel == "fused":
+            # a shape Mosaic would refuse is refused here, on every
+            # backend — never at the first decode step on the chip
+            from ..ops.pallas_paged_attention import check_fused_shape
+            check_fused_shape(n_heads // self.kv_heads, cfg.block_size,
+                              self.dh, cfg.max_blocks_per_seq)
         if mesh is not None:
             from ..parallel.lm import tp_shard_params
             from ..parallel.mesh import MODEL_AXIS, require_axes
@@ -861,8 +864,10 @@ class DecodeEngine:
         contiguous view (``gather_layer``, the "gather" scope) and run
         ``decode_attn`` — the differential oracle. ``fused``: the
         Pallas block-table walk (``ops/pallas_paged_attention.py``),
-        dequant folded in, no gathered layout in HBM — bit-identical
-        to the oracle at f32 under jit. ``n_attend [b]`` is the
+        dequant folded in, no gathered layout in HBM — held to the
+        oracle per backend as the kernel module states (no token
+        identity on the chip at the default matmul precision).
+        ``n_attend [b]`` is the
         per-slot attendable-position count (always >= 1)."""
         if self.cfg.kernel == "fused":
             with jax.named_scope("attn"):

@@ -600,6 +600,9 @@ def generate_main(argv=None) -> int:
         jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
+    from ..runtime.init import describe_devices, enable_compile_cache
+    enable_compile_cache()
+
     from ..models import init_lm
     from .engine import AdmissionError, DecodeEngine, EngineConfig, \
         ServePolicy
@@ -937,6 +940,19 @@ def generate_main(argv=None) -> int:
         if args.watch:
             from ..runtime.watch import parse_watch_spec
             watch_policy = parse_watch_spec(args.watch)
+        # the flags are sound: say where this runs before any device
+        # work starts
+        platform = describe_devices()["platform"]
+        if platform == "tpu" and args.transport in ("process", "tcp"):
+            # one chip, one process: this parent holds the chip the
+            # moment it touched jax, so engine workers started from it
+            # could only fail or hang waiting for the device
+            raise ValueError(
+                f"--transport {args.transport} starts each engine in "
+                "its own worker process, and on a TPU the parent "
+                "already holds the chip, so the workers can never get "
+                "it; use --transport inproc here (process/tcp are "
+                "verified on the CPU backend only)")
         # under the process transport the router never touches weights
         # — each worker rebuilds them from the recipe (same seed, same
         # bits) — so building them here would just double peak host

@@ -75,24 +75,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def interpret_collectives_supported() -> bool:
-    """Can these kernels run OFF-chip (interpret mode) on this jax?
-
-    The dedicated TPU interpreter (``pltpu.InterpretParams``) models
-    semaphores and remote DMA; it arrived with the graduated (>= 0.5)
-    pallas surface. The pre-graduation interpreter has no discharge
-    rules for them ("Remote signal not implemented" at trace time), so
-    off-TPU callers must degrade gracefully — skip the Mosaic transport
-    and keep the XLA one — instead of dying mid-run. Same graduation
-    marker the parallel compat layer keys on (``collectives.vma_erased``
-    — the compat shims install ``jax.typeof``/``InterpretParams``
-    stand-ins on old jax, so hasattr alone would lie; the ``erased_vma``
-    flag those shims carry is the truth). On-chip Mosaic compilation is
-    unaffected either way."""
-    return (hasattr(jax, "typeof")
-            and not getattr(jax.typeof, "erased_vma", False))
-
-
 def _interpret_arg(interpret: bool | None):
     # the TPU interpreter models semaphores + remote DMA; the generic
     # pallas interpreter does not. None = auto: interpreter off-TPU,

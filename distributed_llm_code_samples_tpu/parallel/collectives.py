@@ -24,38 +24,13 @@ scheduler moves independent compute between them — the role the reference's
 
 from __future__ import annotations
 
-import functools
-
 import jax
 from jax import lax
 
 
-def vma_erased() -> bool:
-    """True when this process runs the pre-vma jax compat layer (package
-    ``__init__``): no varying-manual-axes typing exists, so every launch
-    must take its vma-off path — ``check_vma=False`` semantics, explicit
-    ``force=True`` reductions — exactly the contract the interpret-mode
-    Pallas launches already exercise on modern jax."""
-    return getattr(jax.typeof, "erased_vma", False)
-
-
-if vma_erased():
-    # Pre-vma jax transposes psum to ANOTHER psum: a cotangent crossing
-    # an all_reduce differentiated through (vp_embed's row completion)
-    # comes back scaled by the axis size. Modern jax — in both the vma-on
-    # and vma-off regimes — transposes psum to an identity pbroadcast,
-    # and the strategies are written against that contract. Restore it
-    # with a hand-written VJP (sum forward, pass-through backward).
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-    def all_reduce(x, axis_name: str):
-        return lax.psum(x, axis_name)
-
-    all_reduce.defvjp(lambda x, a: (lax.psum(x, a), None),
-                      lambda a, _, dy: (dy,))
-else:
-    def all_reduce(x, axis_name: str):
-        """Sum across the mesh axis — NCCL ``all_reduce(SUM)`` / ``dist.all_reduce``."""
-        return lax.psum(x, axis_name)
+def all_reduce(x, axis_name: str):
+    """Sum across the mesh axis — NCCL ``all_reduce(SUM)`` / ``dist.all_reduce``."""
+    return lax.psum(x, axis_name)
 
 
 def all_gather(x, axis_name: str, *, dim: int = 0):
@@ -73,30 +48,52 @@ def reduce_scatter(x, axis_name: str, *, dim: int = 0):
     return lax.psum_scatter(x, axis_name, scatter_dimension=dim, tiled=True)
 
 
+def vary(tree, axis_name):
+    """Type every leaf of ``tree`` as varying over one axis (or a tuple
+    of axes) — ``lax.pcast``, no data movement — where it is not already.
+
+    This is how a replicated operand enters a hand-written ``custom_vjp``
+    rule next to shard-varying ones. JAX holds a rule to its primal's
+    type: the cotangent it returns for an argument must vary over exactly
+    the axes the argument does. A rule fed a replicated weight and a
+    shard's activations computes a per-shard partial — varying — for a
+    primal that is not, and is refused. Cast first, and the partial is
+    the right type; ``grad_reduce`` then sums it, once, where the
+    strategy says so."""
+    axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
+
+    def one(x):
+        need = tuple(a for a in axes if a not in jax.typeof(x).vma)
+        return lax.pcast(x, need, to="varying") if need else x
+
+    return jax.tree_util.tree_map(one, tree)
+
+
 def grad_reduce(g, axis_name, force: bool = False):
     """Sum a *gradient* across one axis (or a tuple of axes, one fused
     ``psum``) iff it is still a partial sum there.
 
-    Under JAX's varying-manual-axes (vma) typing, a cotangent's provenance
-    decides its state: transposes of plain ops auto-reduce cotangents onto
-    axis-invariant (replicated) primals — the transpose of the implicit
-    ``pvary`` is a ``psum`` — so they arrive already summed (axis absent
-    from ``typeof(g).vma``); cotangents built inside hand-written
-    ``custom_vjp`` rules (this framework's entire ops layer) arrive still
-    partial (axis present). An unconditional ``psum`` would double-reduce
-    the former — grads scale by the axis size. The check is static at
-    trace time.
+    Under JAX's varying-manual-axes (vma) typing, a cotangent's type says
+    which state it is in. Differentiated against a replicated primal, a
+    plain op's transpose ends in the ``psum`` that undoes the implicit
+    ``pcast``: the cotangent arrives already summed (axis absent from
+    ``typeof(g).vma``). Differentiated against a primal the strategy
+    first typed varying (``vary`` above — the only way a replicated
+    weight may enter a hand-written ``custom_vjp`` rule beside a shard's
+    activations), it arrives as the rule built it: a per-shard partial
+    (axis present). An unconditional ``psum`` would double-reduce the
+    former — grads scale by the axis size. The check is static at trace
+    time.
     """
     axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
     if force:
         # the vma-off contract (launcher ran check_vma=False — the
-        # interpret-mode Pallas launches on modern jax, or EVERY launch
-        # under the pre-vma compat layer, see vma_erased): typing is
-        # erased, transposes do NOT auto-psum, every cotangent arrives
-        # partial — the unconditional psum is then the correct single
-        # reduction. Non-forced calls no-op in that regime (empty vma),
-        # which is also part of the contract: the gates stand down and
-        # each strategy's explicit force sweep reduces each leaf once.
+        # interpret-mode Pallas launches): typing is erased, transposes
+        # do NOT auto-psum, every cotangent arrives partial — the
+        # unconditional psum is then the correct single reduction.
+        # Non-forced calls no-op in that regime (empty vma), which is
+        # also part of the contract: the gates stand down and each
+        # strategy's explicit force sweep reduces each leaf once.
         return lax.psum(g, axes)
     pending = tuple(a for a in axes if a in jax.typeof(g).vma)
     return lax.psum(g, pending) if pending else g

@@ -55,7 +55,7 @@ from ..ops.moe import (dispatch_tensor, dispatch_tensor_topk,
                        route_top1, route_topk, router_aux_loss,
                        scatter_combine, scatter_dispatch)
 from ..optim import sgd
-from .collectives import all_to_all, grad_reduce, vma_erased
+from .collectives import all_to_all, grad_reduce, vary
 from .launcher import launch, launch_strided
 from .mesh import DATA_AXIS, EXPERT_AXIS, require_axes
 
@@ -171,7 +171,7 @@ def make_step(batch_size: int, model_size: int, lr: float = LR,
     """
 
     axes = (axis,) if data_axis is None else (axis, data_axis)
-    reducer = (grad_reduce if comm == "psum" and not vma_erased()
+    reducer = (grad_reduce if comm == "psum"
                else (lambda g, ax: lax.psum(g, ax)))
 
     def fwd_aux(params: MoEStackParams, x):
@@ -190,7 +190,12 @@ def make_step(batch_size: int, model_size: int, lr: float = LR,
             x, dloss_dx = batch_from_seed(seed, batch_size, model_size,
                                           params.w1.dtype)
             with jax.named_scope("fwd"):
-                _, vjp = jax.vjp(lambda p: fwd_aux(p, x), params)
+                # the replicated router (and, on a 2-D mesh, the
+                # data-replicated experts) enter the hand-written rules
+                # typed varying like this shard's tokens; "comm" below
+                # sums the per-shard partials that come back
+                _, vjp = jax.vjp(lambda p: fwd_aux(p, x),
+                                 vary(params, axes))
             # the aux output is shard-varying under shard_map; its cotangent
             # (the constant aux coefficient) must be cast to match — over
             # every axis the aux varies on (a 2-D mesh adds "data")

@@ -40,7 +40,7 @@ from ..ops.norm import layernorm
 from ..ops.xent import xent_loss
 from ..optim import check_state_args, sgd
 from .collectives import (all_gather, all_reduce, axis_index,
-                          grad_reduce, vma_erased)
+                          grad_reduce, vary)
 from .launcher import launch, launch_strided
 from .mesh import DATA_AXIS, MODEL_AXIS, SEQ_AXIS, require_axes
 from .transformer import (TP_SPECS, _f_gate, _shard, _validate_shapes,
@@ -104,8 +104,11 @@ def _make_step(batch_size: int, model_size: int, seq_len: int,
             # autodiff strategy: jax.grad traces forward and transpose in
             # one call, so the "fwd" region also tags the backward ops
             # (the naming-map caveat, utils/trace_analysis.py)
-            grads = jax.grad(lm_loss)(params, tokens, targets, n_heads,
-                                      attn, head, mixed)
+            # replicated weights enter the hand-written rules typed
+            # varying over the axes this shard's batch varies on; their
+            # cotangents come back per-shard partials for "comm"
+            grads = jax.grad(lm_loss)(vary(params, reduce_axes), tokens,
+                                      targets, n_heads, attn, head, mixed)
         if reduce_axes:
             with jax.named_scope("comm"):
                 # force_reduce: the launcher runs check_vma=False
@@ -220,12 +223,7 @@ def _vma_check(attn_impl, head_impl=None) -> bool:
     already-reduced embedding part (scaled by the axis size). The
     vma-off force-reduce contract (``grad_reduce(force=True)``) keeps
     every cotangent partial and reduces exactly once; the oracle head
-    never hits this because both of its wte uses are plain ops.
-
-    Under the pre-vma jax compat layer there is no vma typing at all,
-    so EVERY launch takes the vma-off path (``collectives.vma_erased``)."""
-    if vma_erased():
-        return False
+    never hits this because both of its wte uses are plain ops."""
     if head_impl == "fused":
         return False
     return not (attn_impl == "flash"
@@ -530,7 +528,9 @@ def _make_tp_step(batch_size: int, model_size: int, seq_len: int,
             return vp_xent(logits_local, targets.reshape(-1))
 
         with jax.named_scope("fwd"):
-            grads = jax.grad(loss_fn)(params)
+            # hybrid: every leaf is replicated over the data axes and
+            # meets that replica's batch — typed varying going in
+            grads = jax.grad(loss_fn)(vary(params, data_axes))
         with jax.named_scope("comm"):
             # wpe and the LN gains saw complete, replicated dx — but the
             # cotangents produced inside the hand-written rules come back
@@ -920,10 +920,10 @@ def train_lm_seq(params: LMParams, seeds, batch_size: int, model_size: int,
             return xent_loss(logits.reshape(-1, vocab),
                              targets.reshape(-1)) / n
 
+        axes = (SEQ_AXIS, DATA_AXIS) if dp > 1 else (SEQ_AXIS,)
         with jax.named_scope("lm"):
             with jax.named_scope("fwd"):
-                grads = jax.grad(loss_fn)(params)
-            axes = (SEQ_AXIS, DATA_AXIS) if dp > 1 else (SEQ_AXIS,)
+                grads = jax.grad(loss_fn)(vary(params, axes))
             with jax.named_scope("comm"):
                 # vma-off (interpret-mode flash/fused head): force the
                 # psum — grad_reduce would silently no-op on the partial

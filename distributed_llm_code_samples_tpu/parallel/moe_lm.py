@@ -26,7 +26,7 @@ from ..data import lm_batch_from_seed, shard_seeds_strided
 from ..models.ffn_stack import clone_params
 from ..models.moe_lm import MoELMParams, moe_lm_loss_aux
 from ..optim import sgd
-from .collectives import grad_reduce
+from .collectives import grad_reduce, vary
 from .expert import _local_capacity, moe_layer_ep
 from .launcher import launch_strided
 from .mesh import EXPERT_AXIS, require_axes
@@ -104,7 +104,9 @@ def train_moe_lm_ep(params: MoELMParams, seeds, batch_size: int,
         # the a2a dispatch inside moe_layer_ep adds nested comm scopes)
         with jax.named_scope("moe_lm"):
             with jax.named_scope("fwd"):
-                grads = jax.grad(loss_fn)(params)
+                # replicated leaves enter the hand-written rules typed
+                # varying; "comm" sums their per-shard partials
+                grads = jax.grad(loss_fn)(vary(params, EXPERT_AXIS))
             with jax.named_scope("comm"):
                 grads = _reduce_replicated(grads, force=not check)
             with jax.named_scope("optim"):

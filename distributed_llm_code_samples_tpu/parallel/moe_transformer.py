@@ -33,7 +33,7 @@ from ..models.moe_transformer import (MoETransformerParams,
                                       moe_transformer_fwd_aux)
 from ..optim import sgd
 from .expert import _local_capacity, moe_layer_ep
-from .collectives import grad_reduce, vma_erased
+from .collectives import grad_reduce, vary
 from .launcher import launch_strided
 from .mesh import EXPERT_AXIS, require_axes
 
@@ -102,15 +102,16 @@ def train_moe_transformer_ep(params: MoETransformerParams, seeds,
                 _, vjp = jax.vjp(
                     lambda p: moe_transformer_fwd_aux(
                         p, x, n_heads, causal, moe_fn=moe_fn, attn=attn),
-                    params)
+                    # replicated leaves enter the hand-written rules
+                    # typed varying; "comm" sums their partials
+                    vary(params, EXPERT_AXIS))
             coef = lax.pcast(jnp.asarray(aux_coef, jnp.float32),
                              EXPERT_AXIS, to="varying")
             with jax.named_scope("bwd"):
                 grads = vjp((dloss_dx, coef))[0]
             with jax.named_scope("comm"):
                 grads = grads._replace(**{
-                    f: grad_reduce(getattr(grads, f), EXPERT_AXIS,
-                                   force=vma_erased())
+                    f: grad_reduce(getattr(grads, f), EXPERT_AXIS)
                     for f in _REPLICATED})
             with jax.named_scope("optim"):
                 return sgd(params, grads, lr)

@@ -43,7 +43,7 @@ from ..ops.ffn import ffn_block
 from ..ops.norm import layernorm
 from ..optim import sgd
 from .collectives import (all_gather, all_reduce, axis_index, grad_reduce,
-                          reduce_scatter, vma_erased)
+                          reduce_scatter, vary)
 from .launcher import launch, launch_strided
 from .mesh import DATA_AXIS, MODEL_AXIS, SEQ_AXIS, require_axes
 
@@ -72,23 +72,23 @@ def _shard(params: TransformerParams, mesh, specs) -> TransformerParams:
 
 
 def _f_gate(axis: str):
-    """Megatron's ``f`` operator: identity forward, all-reduce backward —
-    but *vma-aware*. Under JAX's varying-manual-axes typing, cotangents
-    flowing back through plain ops are auto-reduced when they cross an
-    implicit ``pvary`` (its transpose is ``psum``), while cotangents
-    produced inside hand-written ``custom_vjp`` rules (``ffn_block``,
-    ``attention``) come back still partial (axis in ``typeof(dy).vma``).
-    The gate psums exactly when the cotangent is still partial — a static,
-    trace-time check — so neither path is double-reduced. (The symptom of
-    an unconditional psum: LN grads scale by the axis size on whichever
-    sublayer's backward was auto-reduced.)"""
+    """Megatron's ``f`` operator: identity forward, all-reduce backward.
+    In JAX's varying-manual-axes typing that is a replicated activation
+    re-typed as varying over the model axis on the way in (``pcast``, no
+    data movement — what follows multiplies it by this shard's weight
+    columns), and the per-shard partial input-grads summed on the way
+    back. The rule honours the custom_vjp type contract both ways: the
+    output varies, the cotangent it hands back for ``x`` does not.
+    (``grad_reduce``, not a bare ``psum``: in a vma-off launch — the
+    interpret-mode Pallas kernels — the gate stands down like every
+    non-forced reduction, ``collectives.grad_reduce``.)"""
 
     @jax.custom_vjp
     def f(x):
-        return x
+        return vary(x, axis)
 
-    f.defvjp(lambda x: (x, None),
-             lambda _, dy: (grad_reduce(dy, axis, force=vma_erased()),))
+    f.defvjp(lambda x: (vary(x, axis), None),
+             lambda _, dy: (grad_reduce(dy, axis),))
     return f
 
 
@@ -213,15 +213,18 @@ def train_transformer_ddp(params: TransformerParams, seeds, batch_size: int,
             x, dloss_dx = _reshape_batch(seed, batch_size, seq_len,
                                          model_size, params.w1.dtype)
             with jax.named_scope("fwd"):
+                # replicated weights meet this shard's batch inside the
+                # hand-written rules: typed varying going in, their
+                # cotangents come back per-shard partials for "comm"
                 _, vjp = jax.vjp(
                     lambda p: transformer_fwd(p, x, n_heads, causal,
-                                              attn), params)
+                                              attn),
+                    vary(params, DATA_AXIS))
             with jax.named_scope("bwd"):
                 grads = vjp(dloss_dx)[0]
             with jax.named_scope("comm"):
                 grads = jax.tree_util.tree_map(
-                    lambda g: grad_reduce(g, DATA_AXIS,
-                                          force=vma_erased()), grads)
+                    lambda g: grad_reduce(g, DATA_AXIS), grads)
             with jax.named_scope("optim"):
                 return sgd(params, grads, lr)
 
@@ -408,7 +411,12 @@ def make_tp_step(batch_size: int, model_size: int, seq_len: int,
 
         with jax.named_scope("tf"):
             with jax.named_scope("fwd"):
-                _, vjp = jax.vjp(fwd, params)
+                # sequence-parallel LN gains see only this shard's
+                # tokens: typed varying like the token shard they scale
+                _, vjp = jax.vjp(fwd, params._replace(
+                    ln1=vary(params.ln1, MODEL_AXIS),
+                    ln2=vary(params.ln2, MODEL_AXIS))
+                    if sequence_parallel else params)
             with jax.named_scope("bwd"):
                 grads = vjp(dloss_dx)[0]
             if sequence_parallel:
@@ -417,10 +425,8 @@ def make_tp_step(batch_size: int, model_size: int, seq_len: int,
                     # model axis. Everything else saw full (gathered)
                     # tokens and is complete per shard.
                     grads = grads._replace(
-                        ln1=grad_reduce(grads.ln1, MODEL_AXIS,
-                                        force=vma_erased()),
-                        ln2=grad_reduce(grads.ln2, MODEL_AXIS,
-                                        force=vma_erased()))
+                        ln1=grad_reduce(grads.ln1, MODEL_AXIS),
+                        ln2=grad_reduce(grads.ln2, MODEL_AXIS))
             # projection/FFN grads are shard-local (each shard owns its
             # heads/features); in the plain form LN grads replicate —
             # data and dx are identical on all shards after the f-gate
@@ -479,21 +485,21 @@ def train_transformer_seq(params: TransformerParams, seeds,
         x, dloss_dx = (lax.dynamic_slice_in_dim(t, r * t_local, t_local, 1)
                        for t in (x, dloss_dx))
 
+        # weight grads are partial sums over this shard's tokens — and,
+        # on a 2-D mesh, over the data replicas (DDP semantics)
+        axes = (SEQ_AXIS, DATA_AXIS) if dp > 1 else (SEQ_AXIS,)
         with jax.named_scope("seq"):
             with jax.named_scope("fwd"):
                 _, vjp = jax.vjp(
                     lambda p: transformer_fwd(p, x, n_heads, causal,
-                                              attn), params)
+                                              attn), vary(params, axes))
             with jax.named_scope("bwd"):
                 grads = vjp(dloss_dx)[0]
             with jax.named_scope("comm"):
-                # weight grads are partial sums over this shard's tokens
-                # — and, on a 2-D mesh, over the data replicas (DDP
-                # semantics). One fused psum over both axes per leaf,
-                # not one per axis.
-                axes = (SEQ_AXIS, DATA_AXIS) if dp > 1 else (SEQ_AXIS,)
+                # one fused psum over both axes per leaf, not one per
+                # axis
                 grads = jax.tree_util.tree_map(
-                    lambda g: grad_reduce(g, axes, force=vma_erased()),
+                    lambda g: grad_reduce(g, axes),
                     grads)
             with jax.named_scope("optim"):
                 return sgd(params, grads, lr)
@@ -537,7 +543,7 @@ def train_transformer_hybrid(params: TransformerParams, seeds,
 
         with jax.named_scope("tf"):
             with jax.named_scope("fwd"):
-                _, vjp = jax.vjp(fwd, params)
+                _, vjp = jax.vjp(fwd, vary(params, DATA_AXIS))
             with jax.named_scope("bwd"):
                 grads = vjp(dloss_dx)[0]
             with jax.named_scope("comm"):
@@ -545,8 +551,7 @@ def train_transformer_hybrid(params: TransformerParams, seeds,
                 # the data axis still needs the DDP reduction (orthogonal
                 # psums, the 2-D mesh composition)
                 grads = jax.tree_util.tree_map(
-                    lambda g: grad_reduce(g, DATA_AXIS,
-                                          force=vma_erased()), grads)
+                    lambda g: grad_reduce(g, DATA_AXIS), grads)
             with jax.named_scope("optim"):
                 return sgd(params, grads, lr)
 

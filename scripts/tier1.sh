@@ -5,10 +5,9 @@
 #
 # Scope notes:
 # - `-m 'not slow'` keeps it CPU-fast; the chaos/probe/recovery tests
-#   (tests/test_chaos.py, tests/test_backend_probe.py, plus the
-#   corruption/exhaustion additions in tests/test_checkpoint.py and
-#   tests/test_failure.py) are deliberately NOT slow-marked, so fault
-#   injection and the env-matrix probe are exercised on every tier-1 run.
+#   (tests/test_chaos.py, plus the corruption/exhaustion additions in
+#   tests/test_checkpoint.py and tests/test_failure.py) are deliberately
+#   NOT slow-marked, so fault injection is exercised on every tier-1 run.
 # - DOTS_PASSED counts progress dots so a collection-error run can't
 #   masquerade as a pass.
 # - The telemetry smoke drives a tiny CPU run with --metrics_dir,
@@ -1209,7 +1208,7 @@ echo "DEPLOY_SMOKE=OK"
 phase_done deploy_smoke
 
 echo "=== bench-trend smoke ==="
-# The committed BENCH_*/SCALING_* round artifacts must keep their row
+# The committed SCALING_*/DECODE_* round artifacts must keep their row
 # contracts (scripts/bench_trend.py exits 2 on drift or a missing
 # headline key) — the bench-trajectory story stays parseable.
 if ! timeout -k 10 60 python scripts/bench_trend.py > /dev/null; then

@@ -320,47 +320,14 @@ def test_flash_ring_matches_plain_ring_8_shards():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_flash_ring_aot_v5e8_codegen():
-    """The fused ring AOT-compiles for a real v5e-8 ring: the lowered
-    module carries BOTH the ICI hop (collective-permute) and the Mosaic
-    flash kernels (tpu custom call) — cross-chip ring + in-chip fusion
-    in one program."""
-    import functools
-    from conftest import require_aot_topology
-    from jax.experimental import topologies
-    from jax.sharding import Mesh, PartitionSpec as P
-    require_aot_topology()  # bounded probe: a hung discovery skips fast
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x4")
-    except Exception as e:
-        pytest.skip(f"no TPU AOT topology support: {e}")
-    mesh = Mesh(np.array(topo.devices).reshape(8), (SEQ_AXIS,))
-    spec = P(SEQ_AXIS, None)
-    f = jax.jit(jax.shard_map(
-        functools.partial(ring_attention, axis_name=SEQ_AXIS, causal=True,
-                          attn_impl="flash"),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec))
-    x = jax.ShapeDtypeStruct((8 * 128, 128), jnp.float32)
-    hlo = f.lower(x, x, x).compile().as_text()
-    assert "collective-permute" in hlo
-    assert "custom-call" in hlo
-
-
 def test_ulysses_pallas_a2a_transport(qkv_heads):
     """Ulysses with comm="pallas_a2a": both re-shards (and their VJP
     transposes) through the hand-scheduled peer fan-out kernel == the
     XLA all_to_all path, forward and gradients."""
     import functools
     from jax.sharding import PartitionSpec as P
-    from distributed_llm_code_samples_tpu.ops.pallas_ring import (
-        interpret_collectives_supported)
     from distributed_llm_code_samples_tpu.parallel.sequence import (
         ulysses_attention)
-    if not interpret_collectives_supported() \
-            and jax.default_backend() != "tpu":
-        pytest.skip("pallas interpreter lacks remote DMA on this jax; "
-                    "the peer-DMA a2a transport is chip-only here")
     q, k, v = qkv_heads
     mesh = make_mesh({SEQ_AXIS: 4})
     spec = P(None, SEQ_AXIS, None)

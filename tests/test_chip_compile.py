@@ -1,0 +1,590 @@
+"""Compiles for the chip, without the chip.
+
+The TPU's compiler is installed wherever JAX's TPU support is, and it
+compiles for a chip that is described, not attached. Every test here
+hands a jitted program of this repo the described devices and shapes
+(nothing runs; there is no device to hold an array) and asserts what
+matters: Mosaic accepted the kernel (``tpu_custom_call`` in the
+module), the collectives the schedule promises are there, a whole step
+fits a v5e's 16 GB.
+
+This is the ONLY file that describes a TPU topology, and it does so in
+module-scoped fixtures: only one process at a time may load the TPU's
+library, so the call may run only once a test of this file has started
+— never while a module is imported, in a ``skipif``, in a
+``parametrize`` argument or in ``conftest.py``, and never in a child
+process. Under pytest-xdist's ``--dist loadfile`` the whole file goes
+to one worker, which is then the one that loads the library.
+
+Two groups: the kernels and steps ``chip_smoke.py`` runs on one v5e
+chip at its real widths (GPT-2 small; the paper's FFN stack at
+d=8192), and the cross-chip schedules on a described v5e-8.
+"""
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P, SingleDeviceSharding
+
+from distributed_llm_code_samples_tpu.models import init_ffn_stack
+from distributed_llm_code_samples_tpu.parallel import (DATA_AXIS, MODEL_AXIS,
+                                                       SEQ_AXIS, ddp, fsdp)
+
+HBM_V5E = 16 * 2 ** 30
+MOSAIC = "tpu_custom_call"
+
+
+def _describe(name):
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name=name)
+    except Exception as e:
+        pytest.skip(f"no {name} topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    return _describe("v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def v5e8_mesh():
+    """``axes -> Mesh`` over a described 8-chip v5e: real multi-chip TPU
+    codegen with no chip attached."""
+    topo8 = _describe("v5e:2x4")
+
+    def make(axes: dict) -> Mesh:
+        return Mesh(np.array(topo8.devices).reshape(tuple(axes.values())),
+                    tuple(axes))
+
+    return make
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to JAX's persistent
+    cache but cannot be read back without the chip: the next run would
+    warn and compile again. Keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _shapes_of(tree, sharding=None):
+    """Shapes of ``tree`` (arrays or shapes in), placed on ``sharding``
+    where one is given."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=sharding), tree)
+
+
+def _total_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+# ---------------------------------------------------------------------
+# one v5e chip, at the widths chip_smoke.py runs
+
+GPT2 = dict(vocab=50257, d=768, layers=12, heads=12, dh=64, max_seq=1024)
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "bf16", "int8"])
+def test_fused_paged_walk_compiles_at_engine_block(one_chip, kv_dtype):
+    """The fused block-table walk at the engine's DEFAULT block size
+    (16) and GPT-2-small heads (H12, dh64, 64 blocks per sequence =
+    max_seq_len 1024), every pool dtype — the shape Mosaic used to
+    refuse ("cannot statically prove that index in dimension 1 is a
+    multiple of 128") while every interpret-mode test passed."""
+    from distributed_llm_code_samples_tpu.ops.pallas_paged_attention import (
+        paged_decode_attn)
+    b, h, dh, blk, mb = 4, GPT2["heads"], GPT2["dh"], 16, 64
+    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}[
+        kv_dtype]
+    nb = 1 + b * mb
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scale = s((nb, h), jnp.float32) if kv_dtype == "int8" else None
+    pool = s((nb, h, blk, dh), dt)
+    compiled = jax.jit(
+        functools.partial(paged_decode_attn, interpret=False)).lower(
+            s((b, h, dh), jnp.float32), pool, pool, scale, scale,
+            s((b, mb), jnp.int32), s((b,), jnp.int32)).compile()
+    assert MOSAIC in compiled.as_text()
+
+
+def test_fused_paged_walk_refused_past_vmem_up_front():
+    """What Mosaic would refuse (the whole row no longer fits scoped
+    VMEM) the engine refuses at construction, limit named, on every
+    backend — never a Mosaic error in the first decode step."""
+    from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
+                                                         EngineConfig)
+    from distributed_llm_code_samples_tpu.models import init_lm
+    params = init_lm(jax.random.PRNGKey(0), 64, 32, 1, max_seq_len=64,
+                     n_heads=2)
+    cfg = EngineConfig(block_size=16, max_blocks_per_seq=2048,
+                       n_blocks=2049, max_slots=1, kernel="fused")
+    with pytest.raises(ValueError, match="MiB of scratch, over the 12 MiB"):
+        DecodeEngine(params, 2, cfg)
+
+
+def test_flash_attention_compiles_at_gpt2_shape(one_chip):
+    """Flash forward and both backward kernels at T=1024, dh=64."""
+    from distributed_llm_code_samples_tpu.ops.pallas_attention import (
+        flash_attention)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True, False))
+
+    x = jax.ShapeDtypeStruct((GPT2["max_seq"], GPT2["dh"]), jnp.float32,
+                             sharding=one_chip)
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert hlo.count(MOSAIC) >= 3      # fwd + bwd-dq + bwd-dkv
+
+
+def test_fused_head_compiles_at_gpt2_shape(one_chip):
+    """The fused LM head + xent, forward and both backward kernels, at
+    N=8192 tokens, d=768, V=50304 — and the step fits the chip."""
+    from distributed_llm_code_samples_tpu.ops.pallas_xent import head_xent
+    n, d, v = 8192, GPT2["d"], 50304
+
+    def loss_and_grads(h, w, t):
+        return jax.value_and_grad(
+            lambda h, w: head_xent(h, w, t, False), argnums=(0, 1))(h, w)
+
+    compiled = jax.jit(loss_and_grads).lower(
+        jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((v, d), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)).compile()
+    assert MOSAIC in compiled.as_text()
+    assert _total_bytes(compiled) < HBM_V5E
+
+
+@pytest.fixture(scope="module")
+def gpt2_engine_args():
+    """``kernel -> (engine, decode args, prefill args)`` for the engine
+    chip_smoke's serving phases build (GPT-2 small, 4 slots, block 16,
+    34 blocks per sequence). The engine fingerprints its weights, so
+    they are real (on the CPU); what is lowered is their shapes."""
+    from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
+                                                         EngineConfig)
+    from distributed_llm_code_samples_tpu.models import init_lm
+    params = init_lm(jax.random.PRNGKey(7), GPT2["vocab"], GPT2["d"],
+                     GPT2["layers"], max_seq_len=GPT2["max_seq"],
+                     n_heads=GPT2["heads"])
+
+    def build(kernel, kv_dtype):
+        slots, mbps, chunk = 4, 34, 16
+        eng = DecodeEngine(params, GPT2["heads"], EngineConfig(
+            block_size=16, n_blocks=1 + slots * mbps, max_slots=slots,
+            max_blocks_per_seq=mbps, prefill_chunk=chunk,
+            kv_dtype=kv_dtype, kernel=kernel))
+        i32 = jnp.int32
+        decode = (params, eng.pool, np.zeros((slots, mbps), i32),
+                  np.zeros((slots,), i32), np.zeros((slots,), i32),
+                  np.zeros((slots,), i32), i32(0))
+        prefill = (params, eng.pool, np.zeros((mbps,), i32), i32(0),
+                   np.zeros((chunk,), i32), i32(0), i32(0))
+        return eng, decode, prefill, slots, chunk
+
+    return build
+
+
+@pytest.mark.parametrize("kernel,kv_dtype", [("gather", "bf16"),
+                                             ("fused", "f32")])
+def test_engine_decode_step_compiles(one_chip, gpt2_engine_args,
+                                     monkeypatch, kernel, kv_dtype):
+    """The engine's own decode program at chip_smoke's phase-1 (gather,
+    bf16 KV) and phase-2 (fused, f32 KV) shapes: compiles for one v5e
+    chip and fits it; the fused one carries the Mosaic call."""
+    from distributed_llm_code_samples_tpu.ops import pallas_paged_attention
+    # the engine asks jax.default_backend(), which here is the CPU:
+    # steer its kernel to Mosaic, as it goes on the chip
+    monkeypatch.setattr(pallas_paged_attention, "_interpret_arg",
+                        lambda interpret: False)
+    eng, decode, _, slots, _ = gpt2_engine_args(kernel, kv_dtype)
+    compiled = eng._program("decode", slots).lower(
+        *_shapes_of(decode, one_chip)).compile()
+    assert (MOSAIC in compiled.as_text()) == (kernel == "fused")
+    assert _total_bytes(compiled) < HBM_V5E
+
+
+def test_engine_prefill_chunk_compiles(one_chip, gpt2_engine_args):
+    """One prefill chunk program (16 tokens) of the phase-1 engine."""
+    eng, _, prefill, _, chunk = gpt2_engine_args("gather", "bf16")
+    compiled = eng._program("prefill", chunk).lower(
+        *_shapes_of(prefill, one_chip)).compile()
+    assert _total_bytes(compiled) < HBM_V5E
+
+
+def test_train_single_step_compiles_at_paper_width(one_chip):
+    """Method 1 at the paper's width (d=8192, one layer, 8x1024 tokens,
+    2 GiB of f32 parameters): the whole 8-step program fits one v5e."""
+    from distributed_llm_code_samples_tpu.parallel import single
+    d, tokens = 8192, 8 * 1024
+    params = jax.eval_shape(
+        lambda: init_ffn_stack(jax.random.PRNGKey(7), d, 1))
+    seeds = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    compiled = single._run.lower(
+        _shapes_of(params, one_chip), seeds, tokens, d, 0.1, True, False, False,
+        False, None, False, 1).compile()
+    assert _total_bytes(compiled) < HBM_V5E
+
+
+# ---------------------------------------------------------------------
+# a described v5e-8: the cross-chip schedules
+
+def test_flash_ring_aot_v5e8_codegen(v5e8_mesh):
+    """The fused ring AOT-compiles for a real v5e-8 ring: the lowered
+    module carries BOTH the ICI hop (collective-permute) and the Mosaic
+    flash kernels (tpu custom call) — cross-chip ring + in-chip fusion
+    in one program."""
+    from distributed_llm_code_samples_tpu.parallel.sequence import (
+        ring_attention)
+    mesh = v5e8_mesh({SEQ_AXIS: 8})
+    spec = P(SEQ_AXIS, None)
+    f = jax.jit(jax.shard_map(
+        functools.partial(ring_attention, axis_name=SEQ_AXIS, causal=True,
+                          attn_impl="flash"),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec))
+    x = jax.ShapeDtypeStruct((8 * 128, 128), jnp.float32)
+    hlo = f.lower(x, x, x).compile().as_text()
+    assert "collective-permute" in hlo
+    assert "custom-call" in hlo
+
+
+def test_flash_attention_aot_v5e_at_bench_shapes(v5e8_mesh):
+    """De-risks the bench_attention chip run: the flash forward AND
+    backward kernels compile under REAL Mosaic/VMEM constraints at the
+    largest shape the bench times (T=8192, dh=64) — no interpret mode
+    anywhere. A tiling or VMEM regression in the kernels fails here,
+    chip or no chip. (Mosaic kernels aren't auto-partitionable, so the
+    compile wraps in a replicated shard_map — the same program a 1-chip
+    run executes.)"""
+    from distributed_llm_code_samples_tpu.ops.pallas_attention import (
+        flash_attention)
+    mesh = v5e8_mesh({"d": 8})
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True, False))
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    f = jax.jit(jax.shard_map(grad, mesh=mesh, in_specs=(P(), P(), P()),
+                              out_specs=(P(), P(), P()),
+                              check_vma=False))
+    x = jax.ShapeDtypeStruct((8192, 64), jnp.float32)
+    hlo = f.lower(x, x, x).compile().as_text()
+    assert hlo.count("custom-call") >= 3  # fwd + bwd-dq + bwd-dkv kernels
+
+
+def test_head_xent_aot_v5e_codegen(v5e8_mesh):
+    """Fwd + both bwd kernels Mosaic-compile for a real v5e at the bench
+    family shape (N=8192 tokens, V=50304, d=768) — real tiling and VMEM
+    constraints, no interpret mode. Replicated shard_map over the AOT
+    topology mesh targets the TPU backend; value_and_grad drives all
+    three kernels."""
+    from distributed_llm_code_samples_tpu.ops.pallas_xent import head_xent
+    mesh = v5e8_mesh({"data": 8})
+    N, d, V = 8192, 768, 50304
+    h = jax.ShapeDtypeStruct((N, d), jnp.float32)
+    w = jax.ShapeDtypeStruct((V, d), jnp.float32)
+    t = jax.ShapeDtypeStruct((N,), jnp.int32)
+
+    def loss_and_grads(h, w, t):
+        return jax.value_and_grad(
+            lambda h, w: head_xent(h, w, t), argnums=(0, 1))(h, w)
+
+    f = jax.jit(jax.shard_map(loss_and_grads, mesh=mesh,
+                              in_specs=(P(), P(), P()),
+                              out_specs=(P(), (P(), P())),
+                              check_vma=False))
+    hlo = f.lower(h, w, t).compile().as_text()
+    assert "custom-call" in hlo  # Mosaic kernels present
+
+
+def _ring_program(v5e8_mesh, kernel):
+    mesh = v5e8_mesh({DATA_AXIS: 8})
+    f = jax.jit(jax.shard_map(
+        functools.partial(kernel, axis_name=DATA_AXIS, interpret=False),
+        mesh=mesh, in_specs=P(DATA_AXIS, None),
+        out_specs=P(DATA_AXIS, None), check_vma=False))
+    return f.lower(jax.ShapeDtypeStruct((8 * 8, 128), jnp.float32))
+
+
+def test_ring_all_reduce_aot_v5e8_mosaic_codegen(v5e8_mesh):
+    """The ring compiles under REAL Mosaic constraints for a v5e-8 ring
+    and the lowered module carries the hand-written custom call (our
+    DMA kernel) instead of an XLA all-reduce — the codegen half of the
+    explicit-control story (the interpret differentials are the
+    semantics half)."""
+    from distributed_llm_code_samples_tpu.ops.pallas_ring import (
+        ring_all_reduce)
+    lowered = _ring_program(v5e8_mesh, ring_all_reduce)
+    stablehlo = lowered.as_text()
+    assert MOSAIC in stablehlo             # the Mosaic kernel is there
+    # ...and REPLACES the XLA op (match the op spelling, not the
+    # module name @jit_ring_all_reduce)
+    assert "stablehlo.all_reduce" not in stablehlo
+    hlo = lowered.compile().as_text()      # Mosaic actually compiles it
+    assert "custom-call" in hlo
+    assert "all-reduce" not in hlo
+
+
+def test_ppermute_dma_aot_v5e8_mosaic_codegen(v5e8_mesh):
+    """Same for the single-hop primitive vs collective-permute."""
+    from distributed_llm_code_samples_tpu.ops.pallas_ring import (
+        ppermute_dma)
+    lowered = _ring_program(v5e8_mesh, ppermute_dma)
+    assert MOSAIC in lowered.as_text()
+    hlo = lowered.compile().as_text()
+    assert "custom-call" in hlo
+    assert "collective-permute" not in hlo
+
+
+def test_all_to_all_dma_aot_v5e8_codegen(v5e8_mesh):
+    """The fan-out kernel Mosaic-compiles for v5e-8 with the custom call
+    replacing the XLA all-to-all."""
+    from distributed_llm_code_samples_tpu.ops.pallas_ring import (
+        all_to_all_dma)
+    lowered = _ring_program(v5e8_mesh, all_to_all_dma)
+    assert MOSAIC in lowered.as_text()
+    hlo = lowered.compile().as_text()
+    assert "custom-call" in hlo
+    assert "all-to-all" not in hlo
+
+
+def test_fsdp_ring_aot_v5e8_codegen(v5e8_mesh):
+    """The FSDP step with comm="pallas_ring" AOT-compiles for v5e-8 with
+    the Mosaic kernels carrying ALL the collectives: no XLA all-gather
+    or reduce-scatter ops remain in the lowered module."""
+    mesh = v5e8_mesh({DATA_AXIS: 8})
+    params = init_ffn_stack(jax.random.PRNGKey(0), 64, 2)
+    f = jax.jit(jax.shard_map(
+        fsdp.make_step(32, 64, 0.1, comm="pallas_ring",
+                       ring_interpret=False), mesh=mesh,
+        in_specs=(fsdp.PARAM_SPECS, P()), out_specs=fsdp.PARAM_SPECS,
+        check_vma=False))
+    hlo = f.lower(_shapes_of(params),
+                  jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
+    assert "custom-call" in hlo
+    assert "all-gather" not in hlo
+    assert "reduce-scatter" not in hlo
+
+
+def test_ring_ppermute_aot_v5e8(v5e8_mesh):
+    """Ring attention's rotation lowers to collective-permute on the v5e
+    ICI ring (both the forward and the hand-written backward ring)."""
+    from distributed_llm_code_samples_tpu.parallel.sequence import (
+        ring_attention)
+    mesh = v5e8_mesh({SEQ_AXIS: 8})
+    spec = P(SEQ_AXIS, None)
+    f = jax.shard_map(functools.partial(ring_attention, axis_name=SEQ_AXIS),
+                      mesh=mesh, in_specs=(spec, spec, spec),
+                      out_specs=spec)
+
+    def loss(q, k, v):
+        return jnp.sum(f(q, k, v))
+
+    x = jax.ShapeDtypeStruct((8 * 16, 32), jnp.float32)
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert hlo.count("collective-permute") > 0
+
+
+def test_zero1_aot_v5e8(v5e8_mesh):
+    """ZeRO-1's reduce_scatter + all_gather schedule survives real v5e-8
+    TPU codegen (AOT, no chips), with async start/done splits available
+    for the scheduler to overlap. Shapes are realistic (2k tokens, d=256,
+    8 layers): at toy sizes the backend legitimately rewrites scatters as
+    all-reduce + slice."""
+    from distributed_llm_code_samples_tpu.optim import adam
+    from distributed_llm_code_samples_tpu.parallel import zero1
+    mesh = v5e8_mesh({DATA_AXIS: 8})
+    big = init_ffn_stack(jax.random.PRNGKey(0), 256, 8)
+    step, shard_of, opt = zero1.make_step(2048, 256, 8, 0.1,
+                                          optimizer=adam())
+
+    def one(p, seed):
+        return step((p, opt.init(shard_of(p))), seed)[0]
+
+    f = jax.jit(jax.shard_map(one, mesh=mesh, in_specs=(P(), P()),
+                              out_specs=P(), check_vma=False))
+    hlo = f.lower(_shapes_of(big),
+                  jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
+    assert hlo.count("reduce-scatter") > 0
+    assert hlo.count("all-gather") > 0
+    assert hlo.count("-start") > 0  # async splits for overlap
+
+
+def test_tp_sp_aot_v5e8(v5e8_mesh):
+    """Sequence-parallel TP's gather/scatter decomposition survives v5e-8
+    codegen at a realistic shape, with async splits; the backend may fold
+    a few small scatters back to all-reduce+slice, so the assertion is on
+    the schedule's presence, not all_reduce's total absence."""
+    from distributed_llm_code_samples_tpu.parallel import tp
+    mesh = v5e8_mesh({MODEL_AXIS: 8})
+    big = init_ffn_stack(jax.random.PRNGKey(0), 256, 4)
+    step = tp.make_sp_step(2048, 256, 8, 0.1)
+    f = jax.jit(jax.shard_map(step, mesh=mesh,
+                              in_specs=(tp.PARAM_SPECS, P()),
+                              out_specs=tp.PARAM_SPECS, check_vma=False))
+    hlo = f.lower(_shapes_of(big),
+                  jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
+    assert hlo.count("all-gather") > 0
+    assert hlo.count("reduce-scatter") > 0
+    assert hlo.count("-start") > 0  # async splits for overlap
+
+
+def _bench_scaling(v5e8_mesh):
+    """``bench_scaling`` describes its own topologies as it runs: only
+    from inside this file's tests, after the fixture proved it can."""
+    v5e8_mesh({DATA_AXIS: 8})
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import bench_scaling
+    return bench_scaling
+
+
+def test_bench_scaling_scenario_compiles(v5e8_mesh):
+    """The scaling harness's first scenario (FSDP on v5e-8) AOT-compiles
+    and reports the expected collective classes + roofline fields — keeps
+    bench_scaling.py from rotting."""
+    from distributed_llm_code_samples_tpu.utils import count_async_pairs
+    bench_scaling = _bench_scaling(v5e8_mesh)
+    name, chips, build = bench_scaling._scenarios()[0]
+    step, mesh, specs, params, flops, comm = build()
+    hlo = bench_scaling._compile_hlo(step, mesh, specs, params)
+    counts = bench_scaling._count_hlo_collectives(hlo)
+    pairs = count_async_pairs(hlo)
+    assert (counts["all-gather"] + pairs["async_collective"]
+            + pairs["all_gather"]) > 0
+    assert counts["reduce-scatter"] > 0  # substring: async forms included
+    assert flops > 0 and comm > 0
+
+
+@pytest.mark.slow
+def test_fsdp_async_overlap_aot_v5e8(v5e8_mesh):
+    """Multi-chip TPU codegen evidence without multi-chip hardware: AOT-
+    compile the FSDP step against an 8-chip v5e topology and assert XLA
+    split the per-layer gathers into async start/done pairs — the overlap
+    the reference hand-built with handles (train_ffns.py:200-249). Fails
+    if XLA stops splitting the collectives.
+
+    slow-marked: this single AOT compile costs ~8 min of CPU on a 2-core
+    box — more than half the tier-1 budget for one assertion."""
+    from distributed_llm_code_samples_tpu.utils import count_async_pairs
+    mesh = v5e8_mesh({DATA_AXIS: 8})
+    params = init_ffn_stack(jax.random.PRNGKey(0), 64, 3)
+    f = jax.jit(jax.shard_map(fsdp.make_step(16, 64, 0.1), mesh=mesh,
+                              in_specs=(fsdp.PARAM_SPECS, P()),
+                              out_specs=fsdp.PARAM_SPECS))
+    hlo = f.lower(_shapes_of(params),
+                  jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
+    pairs = count_async_pairs(hlo)
+    assert pairs["async_collective"] + pairs["all_gather"] > 0, (
+        "no async-split collectives in v5e-8 FSDP codegen: "
+        f"{dict(pairs)}")
+    # the sync collectives must still all be there in some form
+    assert hlo.count("reduce-scatter") > 0
+
+
+@pytest.mark.slow
+def test_memory_capability_demo_at_reference_scale(v5e8_mesh):
+    """The reference's headline capability demo at its real scale
+    (train_ffns.py:8-10: ~4.3B params fp32, d=8192, L=8, 8k tokens —
+    trains under FSDP, OOMs under DDP), pinned by the actual TPU
+    compiler against a v5e-8 topology (16 GB HBM/chip): FSDP's per-chip
+    argument+temp+output bytes fit the budget; DDP's replicated params
+    make the SAME compiler raise RESOURCE_EXHAUSTED (observed: 'Used
+    29.25G of 15.75G hbm'). Sharding-actually-shards, falsifiably."""
+    from distributed_llm_code_samples_tpu.models.ffn_stack import (
+        FFNStackParams)
+    D_big, L_big, TOK = 8192, 8, 8 * 1024
+    mesh = v5e8_mesh({DATA_AXIS: 8})
+    sp = FFNStackParams(
+        w1=jax.ShapeDtypeStruct((L_big, 4 * D_big, D_big), jnp.float32),
+        w2=jax.ShapeDtypeStruct((L_big, D_big, 4 * D_big), jnp.float32))
+    seed = jax.ShapeDtypeStruct((), jnp.int32)
+
+    f = jax.jit(jax.shard_map(fsdp.make_step(TOK, D_big, 0.1), mesh=mesh,
+                              in_specs=(fsdp.PARAM_SPECS, P()),
+                              out_specs=fsdp.PARAM_SPECS))
+    m = f.lower(sp, seed).compile().memory_analysis()
+    if m is None:
+        pytest.skip("no memory analysis from this compiler")
+    fsdp_total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+                  + m.output_size_in_bytes)
+    assert fsdp_total <= HBM_V5E, f"FSDP does not fit v5e: {fsdp_total}"
+
+    g = jax.jit(jax.shard_map(ddp.make_step(TOK, D_big, 0.1), mesh=mesh,
+                              in_specs=(P(), P()), out_specs=P()))
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
+        g.lower(sp, seed).compile()
+
+
+@pytest.mark.slow
+@pytest.mark.serial
+def test_scaling_harness_headroom_and_bubble(v5e8_mesh):
+    """The scaling evidence, asserted so regressions break CI: run
+    bench_scaling's collection (real v5e AOT codegen + roofline) on a
+    representative subset and require (a) the north-star FSDP config's
+    overlapped-ICI headroom >= 1 at v5e-32, (b) DDP headroom >= 1 at 8
+    chips, (c) the pp rows carry bubble fields with the interleaved
+    schedule's bubble strictly below GPipe's at the same M. Runs
+    IN-PROCESS: the TPU library's lock is held for the life of a process
+    that compiled, so a subprocess would abort on it."""
+    import signal
+    from conftest import load_scaled_timeout
+    bench_scaling = _bench_scaling(v5e8_mesh)
+    # bound the in-process run so a hung AOT compile fails this test
+    # instead of stalling the suite (no pytest-timeout plugin in this
+    # image; SIGALRM on the main thread does the job)
+    deadline = int(load_scaled_timeout(1200))
+
+    def _alarm(signum, frame):
+        raise TimeoutError(f"scaling collect exceeded {deadline}s")
+
+    old = signal.signal(signal.SIGALRM, _alarm)
+    signal.alarm(deadline)
+    try:
+        rows, ok = bench_scaling.collect(wanted={
+            "fsdp_d768_L24", "ddp_d768_L24", "pp_d2048_L8_M2",
+            "pp_d2048_L16_M2_interleaved"})
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
+    assert ok, rows
+    by_name = {}
+    for row in rows:
+        by_name.setdefault(row["scenario"], []).append(row)
+    fsdp32 = [r_ for r_ in by_name["fsdp_d768_L24"] if r_["chips"] == 32]
+    assert fsdp32 and fsdp32[0]["headroom_x_overlapped"] >= 1, fsdp32
+    ddp8 = [r_ for r_ in by_name["ddp_d768_L24"] if r_["chips"] == 8]
+    assert ddp8 and ddp8[0]["headroom_x_overlapped"] >= 1, ddp8
+    gpipe = by_name["pp_d2048_L8_M2"][0]
+    inter = by_name["pp_d2048_L16_M2_interleaved"][0]
+    assert 0 < inter["bubble_fraction"] < gpipe["bubble_fraction"]
+    assert (inter["max_scaling_from_bubble"]
+            > gpipe["max_scaling_from_bubble"])
+    # the codegen really contains the ring (collective-permute) path
+    assert any("collective-permute" in k for k in gpipe["collectives"])

@@ -300,3 +300,98 @@ def test_cli_head_flag():
                  "64", "--heads", "4", "--head", "fused", "--attn",
                  "flash", "--lr", "0.1")
     assert r.returncode == 0, r.stdout + r.stderr
+
+
+# --- the differential check's verdict (cli.strategy_disagreement) ---------
+#
+# Planted faults, each on the same synthetic "trained" weight: what a
+# strategy bug does must fail, what a ReLU sign flip does must not.
+
+def _trained(rows=400, cols=64, seed=0):
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    init = (0.02 * rng.standard_normal((1, rows, cols))).astype(np.float32)
+    a = init + (1e-3 * rng.standard_normal(init.shape)).astype(np.float32)
+    return rng, init, a
+
+
+def _flip(rng, a, rows, size):
+    """One token's term ``size * x`` added to each of ``rows``."""
+    b = a.copy()
+    for r in rows:
+        b[0, r] += (size * rng.standard_normal(a.shape[-1])).astype(a.dtype)
+    return b
+
+
+def _row_not_updated(a, init, row):
+    b = a.copy()
+    b[0, row] = init[0, row]
+    return b
+
+
+_PLANTED = {
+    # name: (fault, failed?)
+    "identical": (lambda rng, init, a: a.copy(), False),
+    "inside_tolerance": (lambda rng, init, a: a * (1 + 5e-6), False),
+    "relu_flips_in_three_rows":
+        (lambda rng, init, a: _flip(rng, a, (3, 77, 310), 1e-5), False),
+    "flips_in_too_many_rows":
+        (lambda rng, init, a: _flip(rng, a, range(0, 400, 20), 1e-5), True),
+    "a_row_off_by_its_update":
+        (lambda rng, init, a: _flip(rng, a, (5,), 1e-3), True),
+    "a_row_never_updated":
+        (lambda rng, init, a: _row_not_updated(a, init, 100), True),
+    "a_quarter_of_the_reduction_lost":
+        (lambda rng, init, a: init + 0.75 * (a - init), True),
+    "dense_error_ten_times_the_tolerance":
+        (lambda rng, init, a: a + 3e-6, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PLANTED))
+def test_strategy_disagreement_planted(name):
+    from distributed_llm_code_samples_tpu.cli import strategy_disagreement
+    fault, want_failed = _PLANTED[name]
+    rng, init, a = _trained()
+    failed, text = strategy_disagreement(a, fault(rng, init, a), init,
+                                         1e-5, 1e-7)
+    assert failed is want_failed, (name, text)
+    # silent only where every element is inside the tolerance
+    assert (text is None) == (name in ("identical", "inside_tolerance"))
+
+
+def test_strategy_disagreement_vectors_get_no_exception():
+    """Rows are a matrix's; a 1-D array (a bias, a gain) is held to the
+    elementwise tolerance alone."""
+    import numpy as np
+    from distributed_llm_code_samples_tpu.cli import strategy_disagreement
+    init = np.zeros(512, np.float32)
+    a = init + 1e-3
+    b = a.copy()
+    b[7] += 1e-5
+    assert strategy_disagreement(a, b, init, 1e-5, 1e-7)[0] is True
+
+
+def test_cli_strict_fails_on_a_planted_strategy_fault(monkeypatch, capsys):
+    """Through the CLI: FSDP handed back with a quarter of its update
+    lost -> ``-m 0 --strict`` returns 1 and names the pair."""
+    import jax
+    from distributed_llm_code_samples_tpu import cli, parallel
+    name, fsdp = parallel.STRATEGIES[3]
+    keep = {}
+
+    def faulty(params, *args, **kwargs):
+        keep["init"] = jax.tree_util.tree_map(lambda w: w + 0, params)
+        out = fsdp(params, *args, **kwargs)
+        return jax.tree_util.tree_map(lambda w, w0: w0 + 0.75 * (w - w0),
+                                      out, keep["init"])
+
+    monkeypatch.setitem(parallel.STRATEGIES, 3, (name, faulty))
+    argv = ["-s", "8", "-bs", "4", "-n", "16", "-l", "2", "-d", "32",
+            "-m", "0", "-r", "7", "--lr", "0.1"]    # 8 devices: conftest
+    assert cli.main(argv) == 0              # soft by default ...
+    out = capsys.readouterr().out
+    assert "SoftAssertionError: ddp.w1 vs fsdp.w1" in out
+    assert "compared ddp vs fsdp: DISAGREE" in out
+    assert "compared 1dev vs tp: agree" in out
+    assert cli.main([*argv, "--strict"]) == 1   # ... hard under --strict

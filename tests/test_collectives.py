@@ -113,14 +113,12 @@ def test_barrier_preserves_value(mesh8):
 
 def test_grad_reduce_both_regimes(mesh8):
     """grad_reduce must sum exactly once whether the cotangent was already
-    auto-reduced (plain-op transpose) or arrives partial (custom_vjp rule).
+    auto-reduced (plain-op transpose against the replicated ``w``) or
+    arrives partial (a hand-written custom_vjp rule, which JAX holds to
+    its primal's type — so ``w`` enters it through ``coll.vary`` and the
+    rule's per-shard cotangent is legitimately varying).
     Both losses below are mathematically identical: sum over shards of
-    w . x_shard, so dw = sum(x) in both cases.
-
-    Under the pre-vma compat layer (``coll.vma_erased()``) there is no
-    auto-reduction at all — EVERY cotangent arrives partial, non-forced
-    grad_reduce no-ops by contract, and the explicit force is the one
-    correct reduction for both paths."""
+    w . x_shard, so dw = sum(x) in both cases."""
     x = np.random.default_rng(7).normal(size=(N, 4)).astype(np.float32)
     w = np.random.default_rng(8).normal(size=(4,)).astype(np.float32)
 
@@ -131,10 +129,10 @@ def test_grad_reduce_both_regimes(mesh8):
     dot_manual.defvjp(lambda w, xs: (jnp.vdot(w, xs), (w, xs)),
                       lambda res, dy: (dy * res[1], dy * res[0]))
 
-    def make_loss(dot):
+    def make_loss(dot, enter=lambda w: w):
         def body(w, xs):  # w replicated, xs one shard row
-            g = jax.grad(lambda w: dot(w, xs[0]))(w)
-            return coll.grad_reduce(g, DATA_AXIS, force=coll.vma_erased())
+            g = jax.grad(lambda w: dot(w, xs[0]))(enter(w))
+            return coll.grad_reduce(g, DATA_AXIS)
 
         return jax.jit(jax.shard_map(body, mesh=mesh8,
                                      in_specs=(P(), P(DATA_AXIS)),
@@ -143,7 +141,8 @@ def test_grad_reduce_both_regimes(mesh8):
     expected = x.sum(axis=0)
     plain = make_loss(lambda w, xs: jnp.vdot(w, xs))(jnp.asarray(w),
                                                      jnp.asarray(x))
-    manual = make_loss(dot_manual)(jnp.asarray(w), jnp.asarray(x))
+    manual = make_loss(dot_manual, lambda w: coll.vary(w, DATA_AXIS))(
+        jnp.asarray(w), jnp.asarray(x))
     np.testing.assert_allclose(np.asarray(plain), expected, rtol=1e-6)
     np.testing.assert_allclose(np.asarray(manual), expected, rtol=1e-6)
 

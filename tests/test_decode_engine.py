@@ -153,9 +153,12 @@ def test_paged_bit_identical_to_contiguous_f32(lm_params, prompts):
     bit-for-bit. The contiguous baseline is the same engine with ONE
     block spanning the whole per-sequence capacity (the block table
     degenerates to an identity map, i.e. a contiguous cache lane); the
-    paged run chops the same capacity into 8-token blocks. Caches are
-    compared position-by-position mid-run, before any release."""
-    paged = DecodeEngine(lm_params, H, EngineConfig(**BASE))
+    paged run chops the SAME capacity (64 positions: the two engines
+    then run the same attention row shape, so XLA owes them the same
+    bits) into 8-token blocks. Caches are compared position-by-position
+    mid-run, before any release."""
+    paged = DecodeEngine(lm_params, H, EngineConfig(
+        **{**BASE, "max_blocks_per_seq": 8}))
     contig = DecodeEngine(lm_params, H, EngineConfig(
         block_size=64, n_blocks=4, max_slots=3, max_blocks_per_seq=1,
         prefill_chunk=8))

@@ -200,97 +200,6 @@ def test_fsdp_async_overlap_on_tpu(params):
     assert a["all_gather"] > 0 or a["async_collective"] > 0
 
 
-def _v5e8_mesh(axes):
-    """An 8-chip v5e mesh from a *topology description* — real TPU codegen
-    with no TPU attached (AOT compile-only)."""
-    from conftest import require_aot_topology
-    require_aot_topology()  # bounded probe: a hung discovery skips fast
-    from jax.experimental import topologies
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x4")
-    except Exception as e:  # no libtpu AOT support in this install
-        pytest.skip(f"no TPU AOT topology support: {e}")
-    devs = np.array(topo.devices)
-    from jax.sharding import Mesh
-    return Mesh(devs.reshape(tuple(axes.values())), tuple(axes))
-
-
-def _shapes_of(tree):
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), tree)
-
-
-@pytest.mark.slow
-def test_fsdp_async_overlap_aot_v5e8(params):
-    """Multi-chip TPU codegen evidence without multi-chip hardware: AOT-
-    compile the FSDP step against an 8-chip v5e topology and assert XLA
-    split the per-layer gathers into async start/done pairs — the overlap
-    the reference hand-built with handles (train_ffns.py:200-249). Fails
-    if XLA stops splitting the collectives (VERDICT r1 item 4).
-
-    slow-marked: this single AOT compile costs ~8 min of CPU on the
-    2-core tier-1 box — more than half the wall-clock budget for one
-    assertion — so it runs in the slow lane, not the tier-1 gate."""
-    from distributed_llm_code_samples_tpu.utils import count_async_pairs
-    mesh = _v5e8_mesh({DATA_AXIS: 8})
-    f = jax.jit(jax.shard_map(fsdp.make_step(B, D, 0.1), mesh=mesh,
-                              in_specs=(fsdp.PARAM_SPECS, P()),
-                              out_specs=fsdp.PARAM_SPECS))
-    hlo = f.lower(_shapes_of(params),
-                  jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
-    pairs = count_async_pairs(hlo)
-    assert pairs["async_collective"] + pairs["all_gather"] > 0, (
-        "no async-split collectives in v5e-8 FSDP codegen: "
-        f"{dict(pairs)}")
-    # the sync collectives must still all be there in some form
-    assert hlo.count("reduce-scatter") > 0
-
-
-def test_bench_scaling_scenario_compiles():
-    """The scaling harness's first scenario (FSDP on v5e-8) AOT-compiles
-    and reports the expected collective classes + roofline fields — keeps
-    bench_scaling.py from rotting. Only missing AOT support skips; any
-    other failure is a real regression and must fail."""
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    _v5e8_mesh({DATA_AXIS: 8})  # probe: skips if no TPU AOT support
-    import bench_scaling
-    name, chips, build = bench_scaling._scenarios()[0]
-    step, mesh, specs, params, flops, comm = build()
-    hlo = bench_scaling._compile_hlo(step, mesh, specs, params)
-    counts = bench_scaling._count_hlo_collectives(hlo)
-    from distributed_llm_code_samples_tpu.utils import count_async_pairs
-    pairs = count_async_pairs(hlo)
-    assert (counts["all-gather"] + pairs["async_collective"]
-            + pairs["all_gather"]) > 0
-    assert counts["reduce-scatter"] > 0  # substring: async forms included
-    assert flops > 0 and comm > 0
-
-
-def test_ring_ppermute_aot_v5e8():
-    """Ring attention's rotation lowers to collective-permute on the v5e
-    ICI ring (both the forward and the hand-written backward ring)."""
-    import functools
-    from distributed_llm_code_samples_tpu.parallel import SEQ_AXIS
-    from distributed_llm_code_samples_tpu.parallel.sequence import (
-        ring_attention)
-    mesh = _v5e8_mesh({SEQ_AXIS: 8})
-    spec = P(SEQ_AXIS, None)
-    f = jax.shard_map(functools.partial(ring_attention, axis_name=SEQ_AXIS),
-                      mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec)
-
-    def loss(q, k, v):
-        return jnp.sum(f(q, k, v))
-
-    x = jax.ShapeDtypeStruct((8 * 16, 32), jnp.float32)
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        x, x, x).compile().as_text()
-    assert hlo.count("collective-permute") > 0
-
-
 def test_fsdp_output_bytes_are_sharded(params, mesh4):
     """sharding-actually-shards: each device holds 1/4 of the params."""
     seeds = make_seed_schedule(4, random_seed=1)
@@ -317,40 +226,6 @@ def test_fsdp_argument_memory_is_fraction_of_ddp(params, mesh4):
     assert m_fsdp["argument_bytes"] < m_ddp["argument_bytes"] / 2
 
 
-@pytest.mark.slow
-def test_memory_capability_demo_at_reference_scale():
-    """The reference's headline capability demo at its real scale
-    (train_ffns.py:8-10: ~4.3B params fp32, d=8192, L=8, 8k tokens —
-    trains under FSDP, OOMs under DDP), pinned by the actual TPU
-    compiler against a v5e-8 topology (16 GB HBM/chip): FSDP's per-chip
-    argument+temp+output bytes fit the budget; DDP's replicated params
-    make the SAME compiler raise RESOURCE_EXHAUSTED (observed: 'Used
-    29.25G of 15.75G hbm'). Sharding-actually-shards, falsifiably."""
-    from distributed_llm_code_samples_tpu.models.ffn_stack import (
-        FFNStackParams)
-    D_big, L_big, TOK = 8192, 8, 8 * 1024
-    mesh = _v5e8_mesh({DATA_AXIS: 8})
-    sp = FFNStackParams(
-        w1=jax.ShapeDtypeStruct((L_big, 4 * D_big, D_big), jnp.float32),
-        w2=jax.ShapeDtypeStruct((L_big, D_big, 4 * D_big), jnp.float32))
-    seed = jax.ShapeDtypeStruct((), jnp.int32)
-
-    f = jax.jit(jax.shard_map(fsdp.make_step(TOK, D_big, 0.1), mesh=mesh,
-                              in_specs=(fsdp.PARAM_SPECS, P()),
-                              out_specs=fsdp.PARAM_SPECS))
-    m = f.lower(sp, seed).compile().memory_analysis()
-    if m is None:
-        pytest.skip("no memory analysis from this compiler")
-    fsdp_total = (m.argument_size_in_bytes + m.temp_size_in_bytes
-                  + m.output_size_in_bytes)
-    assert fsdp_total <= 16 * 2**30, f"FSDP does not fit v5e: {fsdp_total}"
-
-    g = jax.jit(jax.shard_map(ddp.make_step(TOK, D_big, 0.1), mesh=mesh,
-                              in_specs=(P(), P()), out_specs=P()))
-    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
-        g.lower(sp, seed).compile()
-
-
 def test_timed_returns_result_and_duration(params):
     from distributed_llm_code_samples_tpu.parallel import train_single
     seeds = make_seed_schedule(2, random_seed=3)
@@ -374,101 +249,3 @@ def test_profile_rank_0_writes_trace(tmp_path, params):
         found.extend(files)
     assert found, "profiler produced no trace files"
 
-
-def test_zero1_aot_v5e8():
-    """ZeRO-1's reduce_scatter + all_gather schedule survives real v5e-8
-    TPU codegen (AOT, no chips), with async start/done splits available
-    for the scheduler to overlap. Shapes are realistic (2k tokens, d=256,
-    8 layers): at toy sizes the backend legitimately rewrites scatters as
-    all-reduce + slice."""
-    from distributed_llm_code_samples_tpu.optim import adam
-    from distributed_llm_code_samples_tpu.parallel import zero1
-    mesh = _v5e8_mesh({DATA_AXIS: 8})
-    big = init_ffn_stack(jax.random.PRNGKey(0), 256, 8)
-    step, shard_of, opt = zero1.make_step(2048, 256, 8, 0.1,
-                                          optimizer=adam())
-
-    def one(p, seed):
-        return step((p, opt.init(shard_of(p))), seed)[0]
-
-    f = jax.jit(jax.shard_map(one, mesh=mesh, in_specs=(P(), P()),
-                              out_specs=P(), check_vma=False))
-    hlo = f.lower(_shapes_of(big),
-                  jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
-    assert hlo.count("reduce-scatter") > 0
-    assert hlo.count("all-gather") > 0
-    assert hlo.count("-start") > 0  # async splits for overlap
-
-
-def test_tp_sp_aot_v5e8():
-    """Sequence-parallel TP's gather/scatter decomposition survives v5e-8
-    codegen at a realistic shape, with async splits; the backend may fold
-    a few small scatters back to all-reduce+slice, so the assertion is on
-    the schedule's presence, not all_reduce's total absence."""
-    from distributed_llm_code_samples_tpu.parallel import tp
-    mesh = _v5e8_mesh({MODEL_AXIS: 8})
-    big = init_ffn_stack(jax.random.PRNGKey(0), 256, 4)
-    step = tp.make_sp_step(2048, 256, 8, 0.1)
-    f = jax.jit(jax.shard_map(step, mesh=mesh,
-                              in_specs=(tp.PARAM_SPECS, P()),
-                              out_specs=tp.PARAM_SPECS, check_vma=False))
-    hlo = f.lower(_shapes_of(big),
-                  jax.ShapeDtypeStruct((), jnp.int32)).compile().as_text()
-    assert hlo.count("all-gather") > 0
-    assert hlo.count("reduce-scatter") > 0
-    assert hlo.count("-start") > 0  # async splits for overlap
-
-
-@pytest.mark.slow
-@pytest.mark.serial
-def test_scaling_harness_headroom_and_bubble():
-    """The round's scaling evidence, asserted so regressions break CI:
-    run bench_scaling's collection (real v5e AOT codegen + roofline) on
-    a representative subset and require (a) the north-star FSDP config's
-    overlapped-ICI headroom >= 1 at v5e-32, (b) DDP headroom >= 1 at 8
-    chips, (c) the pp rows carry bubble fields with the interleaved
-    schedule's bubble strictly below GPipe's at the same M. Runs
-    IN-PROCESS: libtpu's AOT lockfile is held for the life of a process
-    that compiled, so after this suite's own AOT tests a subprocess
-    would ABORT on the lockfile."""
-    import signal
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench_scaling
-
-    # in-process run loses the old subprocess timeout: bound it so a
-    # hung AOT compile fails this test instead of stalling the suite
-    # (no pytest-timeout plugin in this image; SIGALRM on the main
-    # thread does the job). Load-scaled: under -n 8 the AOT compiles
-    # contend with seven sibling workers (VERDICT r5 weak #6).
-    from conftest import load_scaled_timeout
-    deadline = int(load_scaled_timeout(1200))
-
-    def _alarm(signum, frame):
-        raise TimeoutError(f"scaling collect exceeded {deadline}s")
-
-    old = signal.signal(signal.SIGALRM, _alarm)
-    signal.alarm(deadline)
-    try:
-        rows, ok = bench_scaling.collect(wanted={
-            "fsdp_d768_L24", "ddp_d768_L24", "pp_d2048_L8_M2",
-            "pp_d2048_L16_M2_interleaved"})
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
-    assert ok, rows
-    by_name = {}
-    for row in rows:
-        by_name.setdefault(row["scenario"], []).append(row)
-    fsdp32 = [r_ for r_ in by_name["fsdp_d768_L24"] if r_["chips"] == 32]
-    assert fsdp32 and fsdp32[0]["headroom_x_overlapped"] >= 1, fsdp32
-    ddp8 = [r_ for r_ in by_name["ddp_d768_L24"] if r_["chips"] == 8]
-    assert ddp8 and ddp8[0]["headroom_x_overlapped"] >= 1, ddp8
-    gpipe = by_name["pp_d2048_L8_M2"][0]
-    inter = by_name["pp_d2048_L16_M2_interleaved"][0]
-    assert 0 < inter["bubble_fraction"] < gpipe["bubble_fraction"]
-    assert (inter["max_scaling_from_bubble"]
-            > gpipe["max_scaling_from_bubble"])
-    # the codegen really contains the ring (collective-permute) path
-    assert any("collective-permute" in k for k in gpipe["collectives"])

@@ -150,41 +150,6 @@ def test_resolve_head_rejects_unknown():
         resolve_head("nope")
 
 
-def test_head_xent_aot_v5e_codegen():
-    """Fwd + both bwd kernels Mosaic-compile for a real v5e at the bench
-    family shape (N=8192 tokens, V=50304, d=768) — real tiling and VMEM
-    constraints, no interpret mode. Replicated shard_map over the AOT
-    topology mesh targets the TPU backend (the test_pallas_ring
-    pattern); value_and_grad drives all three kernels."""
-    import functools
-    from conftest import require_aot_topology
-    from jax.experimental import topologies
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    require_aot_topology()  # bounded probe: a hung discovery skips fast
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x4")
-    except Exception as e:
-        pytest.skip(f"no TPU AOT topology support: {e}")
-    mesh = Mesh(np.array(topo.devices).reshape(8), ("data",))
-    N, d, V = 8192, 768, 50304
-    h = jax.ShapeDtypeStruct((N, d), jnp.float32)
-    w = jax.ShapeDtypeStruct((V, d), jnp.float32)
-    t = jax.ShapeDtypeStruct((N,), jnp.int32)
-
-    def loss_and_grads(h, w, t):
-        return jax.value_and_grad(
-            lambda h, w: head_xent(h, w, t), argnums=(0, 1))(h, w)
-
-    f = jax.jit(jax.shard_map(loss_and_grads, mesh=mesh,
-                              in_specs=(P(), P(), P()),
-                              out_specs=(P(), (P(), P())),
-                              check_vma=False))
-    hlo = f.lower(h, w, t).compile().as_text()
-    assert "custom-call" in hlo  # Mosaic kernels present
-
-
 def test_train_lm_tp_fused_head_leaves_interpret_to_backend(monkeypatch):
     """Regression (ADVICE r4): ``train_lm_tp`` tied ``interpret`` to the
     vma decision (``not _vma_check(...)``), so ``head_impl='fused'`` —
@@ -247,18 +212,7 @@ def test_vp_fused_head_matches_single_device():
     from distributed_llm_code_samples_tpu.models import init_lm
     from distributed_llm_code_samples_tpu.parallel import (
         MODEL_AXIS, make_mesh, train_lm_single)
-    from distributed_llm_code_samples_tpu.parallel.collectives import (
-        vma_erased)
     from distributed_llm_code_samples_tpu.parallel.lm import train_lm_tp
-    if vma_erased():
-        # pre-vma jax: the 3-step differential lands within ~1e-3 of the
-        # oracle (no factor-of-n reduction error — the vma-off force
-        # contract holds) but drifts past the 2e-3/2e-5 pin; the tied
-        # wte's mixed-provenance cotangent path can't be made exact
-        # without the vma type system. Chip correctness is pinned by the
-        # TPU runs; the compat gap is a known erased-regime limitation.
-        pytest.xfail("pre-vma jax: fused vp head differential drifts "
-                     "past the exact-pin tolerance (known compat gap)")
 
     params = init_lm(jax.random.PRNGKey(2), 384, 32, 2, 64, n_heads=4)
     seeds = make_seed_schedule(3, random_seed=11)
@@ -330,17 +284,9 @@ def test_vma_check_contract():
     auto-psummed embedding-gather part with the kernel's partial dw, and
     a downstream psum would double-count the former (scaled by the axis
     size). Flash alone keeps full checking on TPU."""
-    from distributed_llm_code_samples_tpu.parallel.collectives import (
-        vma_erased)
     from distributed_llm_code_samples_tpu.parallel.lm import _vma_check
     assert _vma_check(None, "fused") is False
     assert _vma_check("flash", "fused") is False
-    if vma_erased():
-        # pre-vma compat layer: no vma typing exists, EVERY launch runs
-        # the vma-off force-reduce contract
-        assert _vma_check("flash", None) is False
-        assert _vma_check(None, None) is False
-    else:
-        # flash-only: off here exactly when interpreting (CPU suite)
-        assert _vma_check("flash", None) == (jax.default_backend() == "tpu")
-        assert _vma_check(None, None) is True
+    # flash-only: off here exactly when interpreting (CPU suite)
+    assert _vma_check("flash", None) == (jax.default_backend() == "tpu")
+    assert _vma_check(None, None) is True

@@ -357,3 +357,29 @@ def test_tcp_cli_spec_rejections():
         assert rc == 2, (bad, err.getvalue())
         msg = err.getvalue().strip()
         assert msg and len(msg.splitlines()) == 1, (bad, msg)
+
+
+def test_worker_transports_refuse_a_tpu_parent(monkeypatch):
+    """One chip, one process: on a TPU backend the router process holds
+    the chip, so --transport process|tcp (a worker process per engine)
+    must fail at start, rc 2, with one message — not hang waiting for
+    a device the children can never get. inproc is not refused."""
+    from distributed_llm_code_samples_tpu.decode.generate_cli import (
+        generate_main)
+    from distributed_llm_code_samples_tpu.runtime import init
+    monkeypatch.setattr(init, "describe_devices", lambda: {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    shape = ["--prompt_lens", "4", "--max_new", "2", "-d", "32",
+             "-l", "2", "--heads", "4", "--vocab", "64",
+             "--max_seq_len", "64", "--block_size", "8", "--fleet", "2"]
+    for transport in ("process", "tcp"):
+        err = io.StringIO()
+        with contextlib.redirect_stderr(err), \
+                contextlib.redirect_stdout(io.StringIO()):
+            rc = generate_main(shape + ["--transport", transport])
+        assert rc == 2
+        msg = err.getvalue().strip()
+        assert len(msg.splitlines()) == 1 and "holds the chip" in msg, msg
+    with contextlib.redirect_stderr(io.StringIO()), \
+            contextlib.redirect_stdout(io.StringIO()):
+        assert generate_main(shape + ["--transport", "inproc"]) == 0
