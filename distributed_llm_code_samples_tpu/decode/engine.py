@@ -170,10 +170,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..models.attention import chunk_attn, rope
 from ..models.lm import LMParams, decode_attn
 from ..ops.norm import layernorm
+from ..parallel import launcher
 from ..runtime.guardrails import rows_finite
 from ..runtime.policy import QosPolicy
-from ..runtime.telemetry import FLIGHT_FILENAME
-from ..runtime.tracing import SpanTracer
+from ..runtime.telemetry import FLIGHT_FILENAME, STEP_SPAN
+from ..runtime.tracing import PhaseTimer, SpanTracer
 from ..runtime.workload import tenant_key
 from ..runtime.weights import (BOOT_VERSION, architecture_diff,
                                model_fingerprint, same_architecture)
@@ -707,6 +708,11 @@ class DecodeEngine:
         self._step_prefill_uid: int | None = None
         self._step_decode_uids: list[int] = []
         self._dump_reason: str | None = None
+        # host phases of the current step (runtime/tracing.py): always
+        # stamped, summed into the digest's ``phase_ms``; with a writer
+        # attached one ``engine_step`` span record a step carries them
+        # whole
+        self.phases = PhaseTimer("engine")
 
     # -- pool ----------------------------------------------------------
 
@@ -2454,7 +2460,6 @@ class DecodeEngine:
         shard_map'd training programs (which the cache can't serialize)
         the single-device engine programs DO round-trip through it, so
         a warm tier-1 cache would void the contract test."""
-        from ..parallel import launcher
         if launcher.CAPTURE_COMPILED is None:
             return
         old = jax.config.jax_compilation_cache_dir
@@ -2467,6 +2472,35 @@ class DecodeEngine:
 
     def _prefill_step(self, slot: int) -> None:
         seq = self.slots[slot]
+        phase = self.phases.phase
+        with phase("prefill.cow"):
+            c = self._prefill_chunk(seq)
+            bs = self.cfg.block_size
+            self._cow_private(slot, seq.prefilled // bs,
+                              (seq.prefilled + c - 1) // bs)
+        with phase("prefill.upload"):
+            self.prefill_dispatches += 1
+            fn = self._program("prefill", c)
+            chunk = np.asarray(
+                seq.prompt[seq.prefilled:seq.prefilled + c], np.int32)
+            args = (self._params_for(seq.weights_version), self.pool,
+                    jnp.asarray(self.tables[slot]),
+                    jnp.int32(seq.prefilled), jnp.asarray(chunk),
+                    jnp.int32(seq.uid), jnp.int32(self._poison_uid))
+        self._maybe_capture(fn, *args)
+        with phase("prefill.dispatch"):
+            pool, nxt, ok = fn(*args)
+        with phase("prefill.readback"):
+            self.pool = pool
+            fine = bool(ok)
+            # the pick is read only where the chunk completes the prompt
+            pick = (int(nxt) if fine and seq.prefilled + c
+                    == len(seq.prompt) else None)
+        with phase("prefill.book"):
+            self._prefill_book(slot, seq, c, fine, pick)
+
+    def _prefill_chunk(self, seq: _Seq) -> int:
+        """The next chunk's size for ``seq``."""
         remaining = len(seq.prompt) - seq.prefilled
         # largest power-of-two bucket that fits the remaining prompt:
         # chunk starts stay multiples of the chunk size, so no chunk
@@ -2482,22 +2516,14 @@ class DecodeEngine:
             # anchored to the block edge instead of offset zero
             gap = bs - seq.prefilled % bs
             c = max(b for b in self.chunk_buckets if b <= min(c, gap))
-        self._cow_private(slot, seq.prefilled // bs,
-                          (seq.prefilled + c - 1) // bs)
-        self.prefill_dispatches += 1
-        fn = self._program("prefill", c)
-        chunk = np.asarray(seq.prompt[seq.prefilled:seq.prefilled + c],
-                           np.int32)
-        args = (self._params_for(seq.weights_version), self.pool,
-                jnp.asarray(self.tables[slot]),
-                jnp.int32(seq.prefilled), jnp.asarray(chunk),
-                jnp.int32(seq.uid), jnp.int32(self._poison_uid))
-        self._maybe_capture(fn, *args)
-        pool, nxt, ok = fn(*args)
-        self.pool = pool
+        return c
+
+    def _prefill_book(self, slot: int, seq: _Seq, c: int, fine: bool,
+                      pick: int | None) -> None:
+        """Fold a dispatched chunk's result into the slot."""
         self._step_prefill_uid = seq.uid
-        self._step_finite = [bool(ok)]
-        if not bool(ok):
+        self._step_finite = [fine]
+        if not fine:
             self._quarantine(slot, "nonfinite_logits")
             return
         seq.prefilled += c
@@ -2521,7 +2547,7 @@ class DecodeEngine:
             self.tracer.transition(
                 seq.uid, "replay" if seq.replaying else "decode",
                 self.global_step, t=now, tokens=c)
-            self._emit(slot, int(nxt))
+            self._emit(slot, pick)
         else:
             # one span per prefill chunk, telescoping across the engine
             # steps spent on other slots in between
@@ -2561,36 +2587,40 @@ class DecodeEngine:
                               []).append(slot)
         return [groups[v] for v in sorted(groups)]
 
-    def _decode_step(self, ready: list[int]) -> None:
-        for group in self._version_groups(ready):
-            self._decode_dispatch(group)
-
     def _decode_dispatch(self, ready: list[int]) -> None:
+        phase = self.phases.phase
         bs = self.cfg.block_size
-        for slot in ready:                  # the CoW write barrier
-            self._cow_private(slot, int(self.lengths[slot]) // bs,
-                              int(self.lengths[slot]) // bs)
-        params = self._params_for(self.slots[ready[0]].weights_version)
-        b, tables, lengths, tokens, uids = self._marshal(ready)
-        fn = self._program("decode", b)
-        args = (params, self.pool, jnp.asarray(tables),
-                jnp.asarray(lengths), jnp.asarray(tokens),
-                jnp.asarray(uids), jnp.int32(self._poison_uid))
+        with phase("decode.cow"):
+            for slot in ready:              # the CoW write barrier
+                self._cow_private(slot, int(self.lengths[slot]) // bs,
+                                  int(self.lengths[slot]) // bs)
+        with phase("decode.marshal"):
+            params = self._params_for(
+                self.slots[ready[0]].weights_version)
+            b, tables, lengths, tokens, uids = self._marshal(ready)
+            fn = self._program("decode", b)
+        with phase("decode.upload"):
+            args = (params, self.pool, jnp.asarray(tables),
+                    jnp.asarray(lengths), jnp.asarray(tokens),
+                    jnp.asarray(uids), jnp.int32(self._poison_uid))
         self._maybe_capture(fn, *args)
-        pool, picks, ok = fn(*args)
-        self.pool = pool
-        picks = np.asarray(picks)
-        ok = np.asarray(ok)
-        self._step_decode_uids += [self.slots[s].uid for s in ready]
-        flags = [bool(ok[j]) for j in range(len(ready))]
-        self._step_finite = (flags if self._step_finite is None
-                             else self._step_finite + flags)
-        for j, slot in enumerate(ready):
-            if not bool(ok[j]):      # pad rows are never in `ready`
-                self._quarantine(slot, "nonfinite_logits")
-                continue
-            self.lengths[slot] += 1
-            self._emit(slot, int(picks[j]))
+        with phase("decode.dispatch"):
+            pool, picks, ok = fn(*args)
+        with phase("decode.readback"):
+            self.pool = pool
+            picks = np.asarray(picks)
+            ok = np.asarray(ok)
+        with phase("decode.emit"):
+            self._step_decode_uids += [self.slots[s].uid for s in ready]
+            flags = [bool(ok[j]) for j in range(len(ready))]
+            self._step_finite = (flags if self._step_finite is None
+                                 else self._step_finite + flags)
+            for j, slot in enumerate(ready):
+                if not flags[j]:     # pad rows are never in `ready`
+                    self._quarantine(slot, "nonfinite_logits")
+                    continue
+                self.lengths[slot] += 1
+                self._emit(slot, int(picks[j]))
 
     # -- speculative decoding (DESIGN.md section 18) -------------------
 
@@ -2603,7 +2633,7 @@ class DecodeEngine:
         live sequences) the n-gram prompt-copy drafter proposes from
         the full known history. Both sources are pure functions of
         ``prompt + out`` — the re-draft-identically contract. The
-        replay count lets ``_verify_step`` keep teacher-forced tokens
+        replay count lets ``_verify_dispatch`` keep teacher-forced tokens
         out of ``drafted_tokens``/``accepted_tokens``: they are
         accepted by construction, not by drafter skill, and a
         crash-resume already restored them into the counters once."""
@@ -2616,10 +2646,6 @@ class DecodeEngine:
             return rec + guess[:budget - len(rec)], len(rec)
         return rec[:budget], budget
 
-    def _verify_step(self, ready: list[int]) -> None:
-        for group in self._version_groups(ready):
-            self._verify_dispatch(group)
-
     def _verify_dispatch(self, ready: list[int]) -> None:
         """The speculative decode dispatch: draft per slot (capped so
         accepted emissions can never outrun ``max_new`` or the block
@@ -2631,60 +2657,68 @@ class DecodeEngine:
         nothing is emitted, the drafted tail is rolled back whole
         (its masked rows only ever landed in the uid's own blocks,
         which quarantine frees and scrubs)."""
+        phase = self.phases.phase
         k = self.cfg.speculate
         bs = self.cfg.block_size
-        for slot in ready:
-            # the verify window writes positions lengths..lengths+k
-            # (rejected rows land on scratch, but the barrier guards
-            # the whole window — a masked write must never even AIM at
-            # a shared block)
-            self._cow_private(slot, int(self.lengths[slot]) // bs,
-                              (int(self.lengths[slot]) + k) // bs)
-        b, tables, lengths, tokens, uids = self._marshal(ready)
-        drafts = np.zeros((b, k), np.int32)
-        dlens = np.zeros((b,), np.int32)
-        replayed = np.zeros((b,), np.int32)
-        for j, slot in enumerate(ready):
-            seq = self.slots[slot]
-            # emissions this step <= max_new - emitted (the final
-            # token of a sequence is returned, never cached, so the
-            # row budget works out to exactly the capacity check
-            # submit() performed)
-            d, n_rec = self._draft_for(
-                seq, min(k, seq.max_new - seq.emitted - 1))
-            dlens[j] = len(d)
-            drafts[j, :len(d)] = d
-            replayed[j] = n_rec
-            self.drafted_tokens += len(d) - n_rec
-        fn = self._program("verify", b)
-        params = self._params_for(self.slots[ready[0]].weights_version)
-        args = (params, self.pool, jnp.asarray(tables),
-                jnp.asarray(lengths), jnp.asarray(tokens),
-                jnp.asarray(uids), jnp.asarray(drafts),
-                jnp.asarray(dlens), jnp.int32(self._poison_uid))
+        with phase("decode.cow"):
+            for slot in ready:
+                # the verify window writes positions lengths..lengths+k
+                # (rejected rows land on scratch, but the barrier guards
+                # the whole window — a masked write must never even AIM
+                # at a shared block)
+                self._cow_private(slot, int(self.lengths[slot]) // bs,
+                                  (int(self.lengths[slot]) + k) // bs)
+        with phase("decode.marshal"):
+            b, tables, lengths, tokens, uids = self._marshal(ready)
+            drafts = np.zeros((b, k), np.int32)
+            dlens = np.zeros((b,), np.int32)
+            replayed = np.zeros((b,), np.int32)
+            for j, slot in enumerate(ready):
+                seq = self.slots[slot]
+                # emissions this step <= max_new - emitted (the final
+                # token of a sequence is returned, never cached, so the
+                # row budget works out to exactly the capacity check
+                # submit() performed)
+                d, n_rec = self._draft_for(
+                    seq, min(k, seq.max_new - seq.emitted - 1))
+                dlens[j] = len(d)
+                drafts[j, :len(d)] = d
+                replayed[j] = n_rec
+                self.drafted_tokens += len(d) - n_rec
+            fn = self._program("verify", b)
+            params = self._params_for(
+                self.slots[ready[0]].weights_version)
+        with phase("decode.upload"):
+            args = (params, self.pool, jnp.asarray(tables),
+                    jnp.asarray(lengths), jnp.asarray(tokens),
+                    jnp.asarray(uids), jnp.asarray(drafts),
+                    jnp.asarray(dlens), jnp.int32(self._poison_uid))
         self._maybe_capture(fn, *args)
-        pool, picks, acc, ok = fn(*args)
-        self.pool = pool
-        picks = np.asarray(picks)
-        acc = np.asarray(acc)
-        ok = np.asarray(ok)
-        self._step_decode_uids += [self.slots[s].uid for s in ready]
-        flags = []
-        for j, slot in enumerate(ready):
-            m = int(acc[j])
-            fine = bool(ok[j, :m + 1].all())
-            flags.append(fine)
-            if not fine:
-                self._quarantine(slot, "nonfinite_logits")
-                continue
-            self.accepted_tokens += max(0, m - int(replayed[j]))
-            self.lengths[slot] += m + 1
-            for t in range(m + 1):
-                if self.slots[slot] is None:
-                    break           # released at its final emission
-                self._emit(slot, int(picks[j, t]))
-        self._step_finite = (flags if self._step_finite is None
-                             else self._step_finite + flags)
+        with phase("decode.dispatch"):
+            pool, picks, acc, ok = fn(*args)
+        with phase("decode.readback"):
+            self.pool = pool
+            picks = np.asarray(picks)
+            acc = np.asarray(acc)
+            ok = np.asarray(ok)
+        with phase("decode.emit"):
+            self._step_decode_uids += [self.slots[s].uid for s in ready]
+            flags = []
+            for j, slot in enumerate(ready):
+                m = int(acc[j])
+                fine = bool(ok[j, :m + 1].all())
+                flags.append(fine)
+                if not fine:
+                    self._quarantine(slot, "nonfinite_logits")
+                    continue
+                self.accepted_tokens += max(0, m - int(replayed[j]))
+                self.lengths[slot] += m + 1
+                for t in range(m + 1):
+                    if self.slots[slot] is None:
+                        break       # released at its final emission
+                    self._emit(slot, int(picks[j, t]))
+            self._step_finite = (flags if self._step_finite is None
+                                 else self._step_finite + flags)
 
     def step(self, prefill_only: bool = False) -> bool:
         """One scheduler iteration: expire deadlines, admit (with
@@ -2701,6 +2735,19 @@ class DecodeEngine:
         prefill-tier engine never compiles or dispatches a decode
         program at all (the disaggregation dispatch proof, both
         directions)."""
+        phases = self.phases
+        phases.begin(self.global_step + 1)
+        with phases.phase("step"):
+            did = self._step(prefill_only)
+        _, start_ns, end_ns = phases.stamps.pop()   # the parent closed last
+        if did and self.metrics is not None:
+            self.metrics.span(self._step_record(start_ns, end_ns))
+        return did
+
+    def _step(self, prefill_only: bool) -> bool:
+        """``step``'s body, every part of it inside one phase of
+        ``self.phases`` (``runtime/tracing.py`` has the vocabulary)."""
+        phase = self.phases.phase
         # _step_events is NOT reset here: shed/rejected events from
         # between-step submissions (and a prior dispatch-free step)
         # belong to the next digest taken — resetting would drop them
@@ -2708,62 +2755,87 @@ class DecodeEngine:
         self._step_finite = None
         self._step_prefill_uid = None
         self._step_decode_uids = []
-        # spill-tier housekeeping: a fresh promotion budget each step
-        # (the restore analogue of one-prefill-chunk-per-step), and the
-        # proactive low-watermark demotion — keep a cushion of free
-        # blocks so admission bursts don't pay the demotion walk inline
-        self._restores_left = self.cfg.spill_restore_per_step
-        self._step_restores = 0
-        if (self.spill is not None and self.cfg.spill_low_water > 0
-                and len(self.free_blocks) < self.cfg.spill_low_water):
-            self._demote(self.cfg.spill_low_water
-                         - len(self.free_blocks))
-        self._expire_deadlines()
-        self._admit()
+        with phase("expire"):
+            # spill-tier housekeeping: a fresh promotion budget each
+            # step (the restore analogue of one-prefill-chunk-per-step),
+            # and the proactive low-watermark demotion — keep a cushion
+            # of free blocks so admission bursts don't pay the demotion
+            # walk inline
+            self._restores_left = self.cfg.spill_restore_per_step
+            self._step_restores = 0
+            if (self.spill is not None and self.cfg.spill_low_water > 0
+                    and len(self.free_blocks) < self.cfg.spill_low_water):
+                self._demote(self.cfg.spill_low_water
+                             - len(self.free_blocks))
+            self._expire_deadlines()
+        with phase("admit"):
+            self._admit()
+            pre = next((i for i, s in enumerate(self.slots)
+                        if s is not None and not s.prompt_done), None)
         did = False
-        pre = next((i for i, s in enumerate(self.slots)
-                    if s is not None and not s.prompt_done), None)
         if pre is not None:
             self._prefill_step(pre)
             did = True
-        ready = ([] if prefill_only else
-                 [i for i, s in enumerate(self.slots)
-                  if s is not None and s.prompt_done])
-        if ready:
-            # speculation on -> every decode dispatch is a verify
-            # dispatch (one program kind per bucket; a zero-draft step
-            # degenerates to plain decode inside the same program, so
-            # the steady-state compile surface stays bounded)
-            if self.cfg.speculate:
-                self._verify_step(ready)
-            else:
-                self._decode_step(ready)
+        with phase("decode.marshal"):
+            ready = ([] if prefill_only else
+                     [i for i, s in enumerate(self.slots)
+                      if s is not None and s.prompt_done])
+            groups = self._version_groups(ready)
+        # speculation on -> every decode dispatch is a verify dispatch
+        # (one program kind per bucket; a zero-draft step degenerates to
+        # plain decode inside the same program, so the steady-state
+        # compile surface stays bounded)
+        dispatch = (self._verify_dispatch if self.cfg.speculate
+                    else self._decode_dispatch)
+        for group in groups:
+            dispatch(group)
             did = True
-        if self._step_restores:
-            # budget-deferred admission: restores ran compiled implant
-            # work this step even if no prefill/decode dispatched —
-            # that IS progress (run()'s stall guard must see it; the
-            # deferred head admits once the budget catches up)
-            did = True
-        if did:
-            self.steps += 1
-            self._poison_uid = POISON_NONE      # one-step fault window
-            active = sum(s is not None for s in self.slots)
-            self._occ_sum += active / self.cfg.max_slots
-            free = len(self.free_blocks)
-            self._free_lo = min(self._free_lo, free)
-            self._free_hi = max(self._free_hi, free)
-        if did or self._step_events:
-            # a dispatch-free step that only expired/shed requests is
-            # still a scheduler decision the post-mortem needs
-            self.flight.append(self._flight_digest())
-            self._step_events = []
-        if self._dump_reason is not None:
-            # a quarantine happened this step: dump now that the step's
-            # own digest is in the ring ("the steps UP TO the fault")
-            self.dump_flight_recorder(self._dump_reason)
-            self._dump_reason = None
+        with phase("digest"):
+            if self._step_restores:
+                # budget-deferred admission: restores ran compiled
+                # implant work this step even if no prefill/decode
+                # dispatched — that IS progress (run()'s stall guard
+                # must see it; the deferred head admits once the budget
+                # catches up)
+                did = True
+            if did:
+                self.steps += 1
+                self._poison_uid = POISON_NONE  # one-step fault window
+                active = sum(s is not None for s in self.slots)
+                self._occ_sum += active / self.cfg.max_slots
+                free = len(self.free_blocks)
+                self._free_lo = min(self._free_lo, free)
+                self._free_hi = max(self._free_hi, free)
+            if did or self._step_events:
+                # a dispatch-free step that only expired/shed requests
+                # is still a scheduler decision the post-mortem needs
+                self.flight.append(self._flight_digest())
+                self._step_events = []
+            if self._dump_reason is not None:
+                # a quarantine happened this step: dump now that the
+                # step's own digest is in the ring ("the steps UP TO
+                # the fault")
+                self.dump_flight_recorder(self._dump_reason)
+                self._dump_reason = None
         return did
+
+    def _step_record(self, start_ns: int, end_ns: int) -> dict:
+        """The executed step as ONE ``engine_step`` span record
+        (telemetry v18): the parent span and its phases in the order
+        they closed (each a child by being in this list).
+        ``tokens_generated`` is what a reader joins a step on."""
+        return {
+            "uid": None,
+            "span": STEP_SPAN,
+            "start_step": self.global_step,
+            "step": self.global_step,
+            "start_ns": start_ns,
+            "end_ns": end_ns,
+            "t": end_ns / 1e9,
+            "duration_s": round((end_ns - start_ns) / 1e9, 6),
+            "phases": self.phases.stamps,
+            "tokens_generated": self.tokens_generated,
+        }
 
     @property
     def active(self) -> int:
@@ -2939,6 +3011,10 @@ class DecodeEngine:
             "occupancy": round(self.active / self.cfg.max_slots, 4),
             "free_blocks": len(self.free_blocks),
             "waiting": len(self.waiting),
+            # where the step's host time went up to this digest
+            # (runtime/tracing.py PhaseTimer): what an UNTRACED run's
+            # ring says about a slow step
+            "phase_ms": self.phases.phase_ms(),
         }
 
     def dump_flight_recorder(self, reason: str) -> str | None:

@@ -29,6 +29,10 @@ from typing import Callable
 import jax
 from jax import lax
 
+from ..runtime.tracing import PhaseTimer
+
+_PHASES = PhaseTimer("launch")  # never begun: annotations, no stamps
+
 # Test/introspection hook: when a list is installed here, every launch
 # also lowers+compiles its program AOT and appends the optimized HLO
 # text (the named-scope presence contract is asserted against the REAL
@@ -98,69 +102,61 @@ def launch(step: Callable, params, seeds_arr, mesh, param_specs, seed_spec,
                                  takes_scale=guard_scale)
         gstate = init_state(guard) if guard_state is None else guard_state
 
-    if state is not None:
-        if guard is None:
-            def run_state(params, state, seeds):
-                local = select_local(seeds)
-                out = lax.scan(lambda c, s: (step(c, s), None),
-                               (params, state), local)[0]
-                return out if return_state else out[0]
+    # each branch names the per-shard program, its specs and operands;
+    # the tail below builds and runs it
+    if state is not None and guard is None:
+        def run(params, state, seeds):
+            local = select_local(seeds)
+            out = lax.scan(lambda c, s: (step(c, s), None),
+                           (params, state), local)[0]
+            return out if return_state else out[0]
 
-            out_specs = ((param_specs, state_specs) if return_state
-                         else param_specs)
-            run_sharded = jax.shard_map(
-                run_state, mesh=mesh,
-                in_specs=(param_specs, state_specs, seed_spec),
-                out_specs=out_specs, check_vma=check_vma)
-            jitted = jax.jit(run_sharded, donate_argnums=(0, 1))
-            _maybe_capture(jitted, params, state, seeds_arr)
-            return jitted(params, state, seeds_arr)
-
-        def run_state_g(params, state, gstate, seeds):
+        in_specs = (param_specs, state_specs, seed_spec)
+        out_specs = ((param_specs, state_specs) if return_state
+                     else param_specs)
+        args, donate = (params, state, seeds_arr), (0, 1)
+    elif state is not None:
+        def run(params, state, gstate, seeds):
             local = select_local(seeds)
             carry, g = lax.scan(lambda c, s: (step(c, s), None),
                                 ((params, state), gstate), local)[0]
             return (carry if return_state else carry[0]), g
 
+        in_specs = (param_specs, state_specs, P(), seed_spec)
         out_specs = (((param_specs, state_specs) if return_state
                       else param_specs), P())
-        run_sharded = jax.shard_map(
-            run_state_g, mesh=mesh,
-            in_specs=(param_specs, state_specs, P(), seed_spec),
-            out_specs=out_specs, check_vma=check_vma)
-        jitted = jax.jit(run_sharded, donate_argnums=(0, 1))
-        _maybe_capture(jitted, params, state, gstate, seeds_arr)
-        return jitted(params, state, gstate, seeds_arr)
-
-    if guard is None:
+        args, donate = (params, state, gstate, seeds_arr), (0, 1)
+    elif guard is None:
         def run(params, seeds):
             local = select_local(seeds)
             carry = params if make_carry is None else make_carry(params)
             out = lax.scan(lambda c, s: (step(c, s), None), carry, local)[0]
             return out if make_carry is None else out[0]
 
-        run_sharded = jax.shard_map(run, mesh=mesh,
-                                    in_specs=(param_specs, seed_spec),
-                                    out_specs=param_specs,
-                                    check_vma=check_vma)
-        jitted = jax.jit(run_sharded, donate_argnums=0)
-        _maybe_capture(jitted, params, seeds_arr)
-        return jitted(params, seeds_arr)
+        in_specs, out_specs = (param_specs, seed_spec), param_specs
+        args, donate = (params, seeds_arr), 0
+    else:
+        def run(params, gstate, seeds):
+            local = select_local(seeds)
+            carry = params if make_carry is None else make_carry(params)
+            out, g = lax.scan(lambda c, s: (step(c, s), None),
+                              (carry, gstate), local)[0]
+            return (out if make_carry is None else out[0]), g
 
-    def run_g(params, gstate, seeds):
-        local = select_local(seeds)
-        carry = params if make_carry is None else make_carry(params)
-        out, g = lax.scan(lambda c, s: (step(c, s), None),
-                          (carry, gstate), local)[0]
-        return (out if make_carry is None else out[0]), g
+        in_specs = (param_specs, P(), seed_spec)
+        out_specs = (param_specs, P())
+        args, donate = (params, gstate, seeds_arr), 0
 
-    run_sharded = jax.shard_map(run_g, mesh=mesh,
-                                in_specs=(param_specs, P(), seed_spec),
-                                out_specs=(param_specs, P()),
-                                check_vma=check_vma)
-    jitted = jax.jit(run_sharded, donate_argnums=0)
-    _maybe_capture(jitted, params, gstate, seeds_arr)
-    return jitted(params, gstate, seeds_arr)
+    # host events launch:build / launch:run in a profiler trace: a new
+    # jax.jit every call, then trace + lower + cache look-up + enqueue
+    with _PHASES.phase("build"):
+        jitted = jax.jit(
+            jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=check_vma),
+            donate_argnums=donate)
+    _maybe_capture(jitted, *args)
+    with _PHASES.phase("run"):
+        return jitted(*args)
 
 
 def launch_strided(step: Callable, params, seeds, mesh, axis: str,

@@ -21,6 +21,9 @@ from ..optim import sgd
 from ..ops.ffn import ffn_fwd, ffn_bwd
 from ..ops.stack import (accumulated_grads, stack_fwd, stack_bwd,
                          stack_grads)
+from ..runtime.tracing import PhaseTimer
+
+_PHASES = PhaseTimer("train")   # never begun: annotations, no stamps
 
 
 def make_step(batch_size: int, model_size: int, lr: float = LR,
@@ -189,13 +192,16 @@ def train_single(params: FFNStackParams, seeds, batch_size: int,
             "guard.loss_scale > 0 but train_single has no loss-scale "
             "hook: dynamic scaling is a mixed-precision DDP/FSDP "
             "surface — pass loss_scale=0 here")
+    # host events train:clone / train:run in a profiler trace: the
+    # donated copy, then trace + cache look-up + enqueue of the program
+    with _PHASES.phase("clone"):
+        owned = clone_params(params)
+    static = (batch_size, model_size, lr, unroll, use_pallas, interpret,
+              manual_loop, remat, mixed, accum)
     if guard is None:
-        return _run(clone_params(params), jnp.asarray(seeds), batch_size,
-                    model_size, lr, unroll, use_pallas, interpret,
-                    manual_loop, remat, mixed, accum)
-    out, g = _run_guarded(clone_params(params), host_state(guard_state,
-                                                           guard),
-                          jnp.asarray(seeds), batch_size, model_size, lr,
-                          unroll, use_pallas, interpret, manual_loop,
-                          remat, mixed, accum, guard)
+        with _PHASES.phase("run"):
+            return _run(owned, jnp.asarray(seeds), *static)
+    with _PHASES.phase("run"):
+        out, g = _run_guarded(owned, host_state(guard_state, guard),
+                              jnp.asarray(seeds), *static, guard)
     return (out, g) if return_guard else out

@@ -49,8 +49,10 @@ The merged timeline is byte-deterministic: entries sort by
 the same dirs render identical output even under equal timestamps.
 
 Output: step-time percentiles, throughput, MFU, HBM high-water, the
-serving summary + reliability block per engine, a per-request
-**waterfall** (schema-v5 ``span`` records: queued / prefill / replay /
+serving summary + reliability block per engine, a **step phases**
+table (schema-v18 ``engine_step`` span records: per host phase of
+``engine.step()`` the count, mean, p99 and share of step time), a
+per-request **waterfall** (schema-v5 ``span`` records: queued / prefill / replay /
 decode / quarantine / preempt_gap, whose summed durations RECONCILE
 with each completed request's recorded ``latency_s``), and ONE merged
 timeline carrying every stream's progress, faults, and recoveries in
@@ -73,7 +75,7 @@ import numpy as np
 from .runtime.telemetry import (FLIGHT_FILENAME, METRICS_FILENAME,
                                 RECORD_KINDS,
                                 ROUTER_POSTMORTEM_PREFIX,
-                                STATUS_FILENAME, read_metrics)
+                                STATUS_FILENAME, STEP_SPAN, read_metrics)
 
 # a completed request's span durations telescope to its latency by
 # construction (runtime/tracing.py); the tolerance only absorbs the
@@ -310,8 +312,13 @@ class _Stream:
             self.requests.append(r)
         # span records: the same replay-dedup, keyed on the span's full
         # step window (two prefill-chunk spans can share a start_step —
-        # admission and the first chunk land in one engine step)
+        # admission and the first chunk land in one engine step).
+        # ``engine_step`` spans (v18) belong to a step, not a request:
+        # they are kept apart, so every per-request reader of
+        # ``self.spans`` (waterfall, --trace, ITL slices) never sees a
+        # null uid
         self.spans = []
+        self.step_spans = []
         seen_span = set()
         for s in by.get("span", []):
             key = (s.get("uid"), s.get("span"), s.get("start_step"),
@@ -319,7 +326,8 @@ class _Stream:
             if key in seen_span:
                 continue
             seen_span.add(key)
-            self.spans.append(s)
+            (self.step_spans if s.get("span") == STEP_SPAN
+             else self.spans).append(s)
 
         # run header: later metas refine earlier ones
         self.header = {}
@@ -526,6 +534,37 @@ class _Stream:
             "rollbacks": len(self.rollbacks),
             "loss_spikes": sum(1 for e in self.events
                                if e.get("event") == "loss_spike"),
+        }
+
+    def step_phases(self) -> dict | None:
+        """Where the engine's steps spent their host time: per phase
+        of the ``engine_step`` records (runtime/tracing.py PhaseTimer)
+        how many steps had it, its mean and p99 per step that had it
+        (a phase repeated in a step is summed first), and its share of
+        all step time. ``(between phases)`` is what no phase covers."""
+        if not self.step_spans:
+            return None
+        per_phase: dict[str, list[float]] = {}
+        total_ms = 0.0
+        for rec in self.step_spans:
+            step_ms = (rec["end_ns"] - rec["start_ns"]) / 1e6
+            total_ms += step_ms
+            mine: dict[str, float] = {}
+            for name, t0, t1 in rec["phases"]:
+                mine[name] = mine.get(name, 0.0) + (t1 - t0) / 1e6
+            mine["(between phases)"] = step_ms - sum(mine.values())
+            for name, ms in mine.items():
+                per_phase.setdefault(name, []).append(ms)
+        return {
+            "steps": len(self.step_spans),
+            "step_mean_ms": round(total_ms / len(self.step_spans), 4),
+            "phases": {
+                name: {"steps": len(ms),
+                       "mean_ms": round(float(np.mean(ms)), 4),
+                       "p99_ms": round(float(np.percentile(ms, 99)), 4),
+                       "share": round(sum(ms) / total_ms, 4)
+                       if total_ms else None}
+                for name, ms in per_phase.items()},
         }
 
     def waterfalls(self) -> dict:
@@ -1759,6 +1798,18 @@ def _render_engine_sections(out: list, doc: dict) -> None:
             out.append("  completions by weights version: " + ", ".join(
                 f"{k} x{v}" for k, v in sorted(
                     rl["completed_by_version"].items())))
+    if doc.get("step_phases"):
+        sp = doc["step_phases"]
+        out.append("")
+        out.append(f"step phases: {sp['steps']} engine step(s), mean "
+                   f"{sp['step_mean_ms']} ms")
+        out.append(f"  {'phase':18s} {'steps':>6s} {'mean ms':>10s} "
+                   f"{'p99 ms':>10s} {'share':>7s}")
+        for name, ph in sorted(sp["phases"].items(),
+                               key=lambda kv: -(kv[1]["share"] or 0.0)):
+            out.append(f"  {name:18s} {ph['steps']:6d} "
+                       f"{ph['mean_ms']:10.4f} {ph['p99_ms']:10.4f} "
+                       f"{100 * (ph['share'] or 0.0):6.2f}%")
     rec = doc.get("recovery", {})
     if (rec.get("attempts_failed") or rec.get("nonfinite_skips")
             or rec.get("attempt_log")
@@ -2444,6 +2495,9 @@ def report_main(argv=None) -> int:
         rel = s.reliability()
         if rel:
             sub["serving_reliability"] = rel
+        phases = s.step_phases()
+        if phases:
+            sub["step_phases"] = phases
         per_engine[s.label] = sub
         wf = s.waterfalls()
         if wf:

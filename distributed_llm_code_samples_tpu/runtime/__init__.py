@@ -10,8 +10,9 @@ detection and checkpoint-based elastic recovery; ``chaos`` injects
 deterministic faults so that story is continuously tested; ``telemetry``
 is the unified metrics stream (schema-versioned per-step JSONL records +
 the ``StepReport`` static fold) every run/bench/report shares;
-``tracing`` is the per-request span layer on top of it (the serving
-waterfall's telescoping clock).
+``tracing`` is the span layer on top of it: per-request lifecycle spans
+(the serving waterfall's telescoping clock) and per-step host phases
+(``PhaseTimer``, the package's profiler annotations).
 """
 
 from . import chaos, native, telemetry, tracing, weights
@@ -20,7 +21,7 @@ from .failure import (HealthCheckError, device_healthcheck, supervise)
 from .init import (DEFAULT_COORDINATOR, describe_devices,
                    enable_compile_cache, initialize, runtime_info)
 from .telemetry import StepReport, TelemetryWriter
-from .tracing import SpanTracer
+from .tracing import PhaseTimer, SpanTracer
 from .weights import VersionLedger, model_fingerprint
 
 __all__ = ["chaos", "native", "telemetry", "tracing",
@@ -28,5 +29,5 @@ __all__ = ["chaos", "native", "telemetry", "tracing",
            "initialize", "runtime_info",
            "DEFAULT_COORDINATOR", "FaultPlan", "HealthCheckError",
            "device_healthcheck", "supervise", "StepReport",
-           "TelemetryWriter", "SpanTracer", "VersionLedger",
+           "TelemetryWriter", "PhaseTimer", "SpanTracer", "VersionLedger",
            "model_fingerprint"]

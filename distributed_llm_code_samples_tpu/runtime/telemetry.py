@@ -215,7 +215,18 @@ import numpy as np
 # ``host_tier_utilization`` the instantaneous spill-tier occupancy
 # fraction (0.0 when the tier is off). All keys are pinned even with
 # the tier disabled (zeros) — the uniform-envelope stance.
-SCHEMA_VERSION = 17
+# v18 (PR 25): step phases. The span vocabulary gains ``engine_step``
+# — ONE record per executed engine step (``decode/engine.py``), the
+# only span that belongs to no request: ``uid`` is null (refused under
+# every other span name), ``step`` == ``start_step`` the engine's
+# global step, and the record pins ``phases`` — the step's host phases
+# as ``[name, start_ns, end_ns]`` in the order they closed
+# (``runtime/tracing.py`` PhaseTimer has the vocabulary and each
+# phase's class) — with ``start_ns`` / ``end_ns`` of the parent span
+# on the same ``time.time_ns()`` clock — and ``tokens_generated``
+# after the step, what a reader joins a step on. Per-request readers
+# skip the record.
+SCHEMA_VERSION = 18
 
 METRICS_FILENAME = "metrics.jsonl"
 
@@ -354,6 +365,8 @@ REQUEST_COMPLETED_REQUIRED = ("latency_s", "ttft_s")
 # v13: ``tenant`` — the owning request's tenant tag (null
 # single-tenant), so per-tenant ITL percentiles come straight off the
 # decode-segment spans.
+# v18: ``engine_step`` spans belong to a step, not a request: null
+# ``uid``, and STEP_SPAN_REQUIRED on top (validate_record).
 # Same version-bump discipline as STEP_KEYS.
 SPAN_REQUIRED = ("step", "uid", "span", "start_step", "duration_s",
                  "trace_id", "tenant")
@@ -361,7 +374,12 @@ SPAN_REQUIRED = ("step", "uid", "span", "start_step", "duration_s",
 # The span vocabulary (runtime/tracing.py callers use these; report
 # renders any name, so a new phase is additive)
 SPAN_NAMES = ("queued", "prefill", "replay", "decode", "quarantine",
-              "preempt_gap")
+              "preempt_gap", "engine_step")
+
+# the one span that belongs to a STEP, not a request (v18): null uid,
+# and the extra keys it must carry
+STEP_SPAN = "engine_step"
+STEP_SPAN_REQUIRED = ("phases", "start_ns", "end_ns")
 
 # The router-record contract (``decode/fleet.py``): one record per
 # fleet-router decision. ``step`` is the ROUTER's step clock (fleet
@@ -884,11 +902,13 @@ class TelemetryWriter:
         self._put(rec)
 
     def span(self, record: dict) -> None:
-        """Enqueue one per-request lifecycle span record (a CLOSED
-        phase: queued / prefill / replay / decode / quarantine /
-        preempt_gap; ``runtime/tracing.py``; ``SPAN_REQUIRED``
-        contract). Callers pass ``t`` explicitly (the span's close
-        time) so span sums reconcile with request latencies."""
+        """Enqueue one span record: a CLOSED per-request lifecycle
+        phase (queued / prefill / replay / decode / quarantine /
+        preempt_gap) or one executed engine step with its host phases
+        (``engine_step``, null uid; ``runtime/tracing.py``;
+        ``SPAN_REQUIRED`` contract). Callers pass ``t`` explicitly
+        (the span's close time) so span sums reconcile with request
+        latencies."""
         rec = dict(record)
         rec.setdefault("t", time.time())
         rec.setdefault("trace_id", None)
@@ -1005,6 +1025,18 @@ def validate_record(rec: Any) -> tuple[bool, str]:
         if missing:
             return False, (f"request record (event completed) missing "
                            f"required key(s) {missing}")
+    if kind == "span":
+        # v18 conditional pins: a step's span names its phases and no
+        # request; every other span is some request's
+        if rec["span"] == STEP_SPAN:
+            missing = [k for k in STEP_SPAN_REQUIRED if k not in rec]
+            if missing:
+                return False, (f"span record (span {STEP_SPAN}) missing "
+                               f"required key(s) {missing}")
+        elif rec["uid"] is None:
+            return False, (f"span record (span {rec['span']}) has a "
+                           f"null 'uid': only {STEP_SPAN} belongs to "
+                           "no request")
     if kind == "router" and rec.get("event") in ("handoff", "migrated"):
         # v10 conditional pin: only a move ships blocks/bytes and has a
         # transport to attribute — routed/shed records place or drop a

@@ -1,4 +1,6 @@
-"""Request span tracing: where one serving request's latency goes.
+"""Span tracing: where one serving request's latency goes
+(``SpanTracer``), and where one engine step's or one trainer call's
+host time goes (``PhaseTimer``, at the end of this file).
 
 PR 5's ``request`` records say WHAT happened to a request (admitted /
 quarantined / completed); nothing says where its wall-clock went —
@@ -62,6 +64,8 @@ from __future__ import annotations
 
 import time
 from typing import Callable
+
+import jax
 
 
 class SpanTracer:
@@ -169,3 +173,105 @@ class SpanTracer:
             "duration_s": round(end_t - cur["start_t"], 6),
             **extra,
         })
+
+
+# -- step phases -------------------------------------------------------
+#
+# ``SpanTracer`` follows a REQUEST across steps; nothing above says what
+# the host did INSIDE one step. ``PhaseTimer`` is that record, and the
+# package's only use of ``jax.profiler.TraceAnnotation``: every phase is
+# a host event ``<site>:<name>`` in a profiler trace (``--profile_dir``,
+# the benchmark's traced window), on the same clock as the device's
+# ``XLA Ops`` / ``XLA Modules`` lines, so an idle gap of the device can
+# be put down to the phase the host was in. The profiler stores event
+# times relative to the trace's start (``profile_start_time`` in the
+# trace's ``Task Environment`` plane, wall-clock ns); a stamp below is
+# wall-clock ns, so ``stamp - profile_start_time`` is the event's time.
+#
+# The engine's vocabulary (``decode/engine.py::step``; ``engine:step``
+# is the parent of the rest, ``prefill.*`` / ``decode.*`` repeat per
+# dispatch, the speculative verify path takes the ``decode.*`` names):
+#
+#   host    expire  admit  prefill.cow  prefill.book  decode.marshal
+#           decode.cow  decode.emit  digest
+#   launch  prefill.upload  prefill.dispatch  decode.upload
+#           decode.dispatch
+#   wait    prefill.readback  decode.readback
+#
+# ``host`` neither feeds nor waits for the device, ``launch`` hands it
+# operands and a program, ``wait`` blocks on its results. The trainer's
+# sites are annotations only: ``train:clone`` / ``train:run``
+# (``parallel/single.py``), ``launch:build`` / ``launch:run``
+# (``parallel/launcher.py``).
+
+
+class _Phase:
+    """One open phase (``PhaseTimer.phase``)."""
+
+    __slots__ = ("_timer", "_name", "_annot", "_t0")
+
+    def __init__(self, timer: "PhaseTimer", name: str):
+        self._timer, self._name = timer, name
+
+    def __enter__(self):
+        timer = self._timer
+        label = timer.label(self._name)
+        # outside a running trace a TraceAnnotation is a flag check
+        self._annot = (jax.profiler.TraceAnnotation(label)
+                       if timer.step is None else
+                       jax.profiler.TraceAnnotation(label, step=timer.step))
+        self._annot.__enter__()
+        self._t0 = time.time_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.time_ns()
+        self._annot.__exit__(*exc)
+        stamps = self._timer.stamps
+        if stamps is not None:
+            stamps.append([self._name, self._t0, t1])
+        return False
+
+
+class PhaseTimer:
+    """Host phases of one site (``engine``, ``train``, ``launch``).
+
+    ``phase(name)`` is the one context manager: a
+    ``TraceAnnotation("<site>:<name>")`` round the block, and — once
+    ``begin(step)`` has opened a step — ``[name, start_ns, end_ns]``
+    (``time.time_ns()``, the ``SpanTracer`` clock family) appended to
+    that step's ``stamps`` as the phase CLOSES, so a parent follows its
+    children and a name may repeat. A site that never calls ``begin``
+    (the trainer's) gets the annotations and keeps nothing. No switch:
+    what differs between tracing off and on is whether somebody reads
+    the stamps or runs the profiler."""
+
+    def __init__(self, site: str):
+        self.site = site
+        self.step: int | None = None
+        self.stamps: list[list] | None = None
+        self._labels: dict[str, str] = {}
+
+    def begin(self, step: int) -> None:
+        """Open ``step``: its annotations carry ``step=<n>`` (the
+        profiler's ``#step=n#`` stat) and its stamps start empty."""
+        self.step = int(step)
+        self.stamps = []
+
+    def label(self, name: str) -> str:
+        """``<site>:<name>``, formatted once per name."""
+        label = self._labels.get(name)
+        if label is None:
+            label = self._labels[name] = f"{self.site}:{name}"
+        return label
+
+    def phase(self, name: str) -> _Phase:
+        return _Phase(self, name)
+
+    def phase_ms(self) -> dict[str, float]:
+        """Milliseconds per phase name over the open step's stamps
+        (a repeated name is summed)."""
+        out: dict[str, float] = {}
+        for name, t0, t1 in self.stamps or ():
+            out[name] = out.get(name, 0.0) + (t1 - t0) / 1e6
+        return {k: round(v, 4) for k, v in out.items()}

@@ -80,6 +80,11 @@ def test_span_stream_reconciles_with_latency(lm_params, prompts,
     assert problems == []
     spans = [r for r in records if r["kind"] == "span"]
     assert spans and all(validate_record(s)[0] for s in spans)
+    # v18: engine_step spans belong to a step, not a request
+    steps = [s for s in spans if s["span"] == "engine_step"]
+    assert len(steps) == eng.steps
+    spans = [s for s in spans if s["span"] != "engine_step"]
+    records = [r for r in records if r not in steps]
     lat = _latencies(records)
     sums = _span_sums(records)
     assert set(lat) <= set(sums)

@@ -195,7 +195,6 @@ def test_schema_v17_record_with_restores(lm_params, sessions, tmp_path):
     from distributed_llm_code_samples_tpu.runtime.telemetry import (
         DECODE_REQUIRED, METRICS_FILENAME, SCHEMA_VERSION,
         TelemetryWriter, read_metrics, validate_record)
-    assert SCHEMA_VERSION == 17
     mdir = str(tmp_path / "metrics")
     with TelemetryWriter(mdir, meta={"subcommand": "generate"}) as w:
         eng = DecodeEngine(lm_params, H, EngineConfig(
@@ -211,7 +210,7 @@ def test_schema_v17_record_with_restores(lm_params, sessions, tmp_path):
     decs = [r for r in records if r["kind"] == "decode"]
     assert decs
     for r in decs:
-        assert r["schema"] == 17
+        assert r["schema"] == SCHEMA_VERSION
         ok, reason = validate_record(r)
         assert ok, reason
         for key in ("spilled_blocks", "spill_bytes", "restores",

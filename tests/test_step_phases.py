@@ -1,0 +1,440 @@
+"""Step phases (ISSUE 25): ``runtime/tracing.py::PhaseTimer`` at its two
+sites — inside ``DecodeEngine.step()`` (stamps, the flight digest's
+``phase_ms``, one ``engine_step`` span record a step when a writer is
+attached) and round the trainer's calls (profiler annotations only).
+
+What is proved: the phases TILE the step (every scheduler method runs
+inside the phase named for it, children are ordered, nested, disjoint,
+and what lies between them is small against the step), the record's
+counts are the engine's own counters, tokens do not depend on a writer,
+schema v18 takes the record and refuses a null uid anywhere else,
+``report`` reads it without disturbing the per-request waterfall, and
+the annotations reach a real ``jax.profiler`` trace by name.
+"""
+
+import glob
+import json
+import os
+import statistics
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
+                                                     EngineConfig)
+from distributed_llm_code_samples_tpu.models import init_lm
+from distributed_llm_code_samples_tpu.runtime.telemetry import (
+    METRICS_FILENAME, SCHEMA_VERSION, SPAN_NAMES, STEP_SPAN,
+    TelemetryWriter, read_metrics, validate_record)
+from distributed_llm_code_samples_tpu.runtime.tracing import PhaseTimer
+
+V, D, L, H = 64, 32, 2, 4
+BASE = dict(block_size=8, n_blocks=33, max_slots=3, max_blocks_per_seq=6,
+            prefill_chunk=8)
+
+HOST = {"expire", "admit", "prefill.cow", "prefill.book",
+        "decode.marshal", "decode.cow", "decode.emit", "digest"}
+LAUNCH = {"prefill.upload", "prefill.dispatch", "decode.upload",
+          "decode.dispatch"}
+WAIT = {"prefill.readback", "decode.readback"}
+# one dispatch's phases, in the order the engine runs them
+PREFILL = ["prefill.cow", "prefill.upload", "prefill.dispatch",
+           "prefill.readback", "prefill.book"]
+DECODE = ["decode.cow", "decode.marshal", "decode.upload",
+          "decode.dispatch", "decode.readback", "decode.emit"]
+
+
+class Collector:
+    """A writer that keeps span records in memory (what the benchmark
+    attaches in its traced runs)."""
+
+    def __init__(self):
+        self.spans = []
+        self.path = None
+
+    def span(self, rec):
+        self.spans.append(rec)
+
+    def __getattr__(self, _name):
+        return lambda *a, **k: None
+
+    def steps(self):
+        return [r for r in self.spans if r["span"] == STEP_SPAN]
+
+
+@pytest.fixture(scope="module")
+def lm_params():
+    return init_lm(jax.random.PRNGKey(0), V, D, L, max_seq_len=64)
+
+
+@pytest.fixture(scope="module")
+def prompts():
+    rng = np.random.default_rng(1)
+    return [rng.integers(0, V, size=n).tolist() for n in (5, 9, 13)]
+
+
+def _slow_dispatch(eng, seconds=0.02):
+    """Make every compiled program take ``seconds`` longer to return,
+    as a device would: a CPU step at this size is a millisecond of
+    Python, against which no share means anything."""
+    real = eng._program
+
+    def program(kind, bucket):
+        fn = real(kind, bucket)
+
+        def slowed(*args):
+            time.sleep(seconds)
+            return fn(*args)
+        slowed.lower = fn.lower
+        return slowed
+    eng._program = program
+
+
+def _dispatches(rec):
+    """How many prefill and decode (or verify) programs ``rec``'s step
+    dispatched, by its phases."""
+    names = [p[0] for p in rec["phases"]]
+    return names.count("prefill.dispatch"), names.count("decode.dispatch")
+
+
+def _expected(rec):
+    """The phase names a step with ``rec``'s dispatches has to show, in
+    order."""
+    n_prefill, n_decode = _dispatches(rec)
+    return (["expire", "admit"] + PREFILL * n_prefill
+            + ["decode.marshal"] + DECODE * n_decode + ["digest"])
+
+
+def _run_scenario(name, lm_params, prompts):
+    """The engine_step records of one scenario, each a list of steps of
+    the kind the scenario is named for."""
+    cfg = dict(BASE)
+    if name == "verify":
+        cfg["speculate"] = 2
+    sink = Collector()
+    eng = DecodeEngine(lm_params, H, EngineConfig(**cfg), metrics=sink)
+    if name == "prefill_only":
+        for p in prompts:
+            eng.submit(p, 4)
+        eng.run()                       # compile outside what is read
+        sink.spans.clear()
+        _slow_dispatch(eng)
+        for p in prompts:
+            eng.submit(p, 4)
+        while eng.step(prefill_only=True):
+            pass
+        recs = sink.steps()
+        assert recs and all(_dispatches(r) == (1, 0) for r in recs)
+        return recs
+    if name == "two_versions":
+        other = init_lm(jax.random.PRNGKey(5), V, D, L, max_seq_len=64)
+        eng.submit(prompts[0], 24)
+        eng.run()
+        sink.spans.clear()
+        _slow_dispatch(eng)
+        eng.submit(prompts[0], 24)
+        for _ in range(4):
+            eng.step()
+        eng.load_weights(1, other)
+        eng.set_serving_version(1)
+        eng.submit(prompts[1], 12)
+        eng.run()
+        recs = [r for r in sink.steps() if _dispatches(r) == (0, 2)]
+        assert recs, "no step dispatched one decode per resident version"
+        return recs
+    eng.generate(prompts, 8)            # compile outside what is read
+    sink.spans.clear()
+    _slow_dispatch(eng)
+    eng.generate(prompts, 8)
+    want = (1, 1) if name == "prefill_decode" else (0, 1)
+    recs = [r for r in sink.steps() if _dispatches(r) == want]
+    assert len(recs) >= 3, (name, [_dispatches(r) for r in sink.steps()])
+    if name == "verify":
+        assert {k for k, _ in eng._programs} == {"prefill", "verify"}
+    return recs
+
+
+@pytest.mark.parametrize("scenario", [
+    "prefill_only", "decode_only", "prefill_decode", "two_versions",
+    "verify"])
+def test_phases_tile_the_step(scenario, lm_params, prompts):
+    """Children in order, inside the parent, none overlapping, named as
+    the step's dispatches say; and the time no child covers is within
+    2% (or 50 us) of the step in the scenario's median step."""
+    recs = _run_scenario(scenario, lm_params, prompts)
+    uncovered = []
+    for rec in recs:
+        assert rec["uid"] is None and rec["step"] == rec["start_step"]
+        phases = rec["phases"]
+        assert [p[0] for p in phases] == _expected(rec)
+        assert {p[0] for p in phases} <= HOST | LAUNCH | WAIT
+        t = rec["start_ns"]
+        for name, start, end in phases:
+            assert t <= start <= end, (name, rec["step"])
+            t = end
+        assert t <= rec["end_ns"]
+        step_ns = rec["end_ns"] - rec["start_ns"]
+        assert rec["duration_s"] == pytest.approx(step_ns / 1e9, abs=1e-6)
+        uncovered.append((step_ns - sum(e - s for _, s, e in phases),
+                          step_ns))
+    gap, step_ns = sorted(uncovered, key=lambda g: g[0] / g[1])[
+        len(uncovered) // 2]
+    assert gap <= max(0.02 * step_ns, 50_000), (gap, step_ns)
+
+
+def test_scheduler_methods_run_inside_their_phase(lm_params, prompts):
+    """Tiling by structure, not by the clock: every call the step makes
+    into the scheduler's methods happens while the phase named for that
+    work is open."""
+    home = {"_expire_deadlines": {"expire"}, "_admit": {"admit"},
+            "_cow_private": {"prefill.cow", "decode.cow"},
+            "_marshal": {"decode.marshal"},
+            "_version_groups": {"decode.marshal"},
+            "_cache_full_blocks": {"prefill.book"},
+            "_emit": {"prefill.book", "decode.emit"},
+            "_flight_digest": {"digest"}}
+    sink = Collector()
+    eng = DecodeEngine(lm_params, H, EngineConfig(**BASE), metrics=sink)
+    calls = []
+
+    def spy(name):
+        real = getattr(eng, name)
+
+        def wrapped(*a, **k):
+            calls.append((name, time.time_ns()))
+            return real(*a, **k)
+        setattr(eng, name, wrapped)
+    for name in home:
+        spy(name)
+    eng.generate(prompts, 6)
+    phases = sorted((s, e, n) for r in sink.steps()
+                    for n, s, e in r["phases"])
+    assert {n for n, _ in calls} == set(home)
+    for name, t in calls:
+        inside = [n for s, e, n in phases if s <= t <= e]
+        assert inside and set(inside) <= home[name], (name, inside)
+
+
+def test_tokens_do_not_depend_on_a_writer(lm_params, prompts):
+    outs = []
+    for sink in (None, Collector()):
+        eng = DecodeEngine(lm_params, H, EngineConfig(**BASE),
+                           metrics=sink)
+        outs.append(eng.generate(prompts, 8))
+    assert outs[0] == outs[1]
+
+
+def test_no_writer_no_record_digest_carries_phase_ms(lm_params, prompts,
+                                                     monkeypatch):
+    eng = DecodeEngine(lm_params, H, EngineConfig(**BASE))
+    monkeypatch.setattr(eng, "_step_record", lambda *a: pytest.fail(
+        "a record was built with no writer attached"))
+    eng.generate(prompts, 4)
+    assert len(eng.flight) == eng.steps
+    for digest in eng.flight:
+        ms = digest["phase_ms"]
+        assert {"expire", "admit", "decode.marshal"} <= set(ms)
+        assert "digest" not in ms       # summed while it is still open
+        assert all(v >= 0 for v in ms.values())
+    assert any("prefill.dispatch" in d["phase_ms"] for d in eng.flight)
+    assert "decode.dispatch" in eng.flight[-1]["phase_ms"]
+    json.dumps(list(eng.flight))            # the dump stays serialisable
+
+
+def test_record_counts_are_the_engines_counters(lm_params, prompts):
+    """One record an executed step, holding what is read and no more:
+    the step number and ``tokens_generated`` after the step (what a
+    reader joins on), and one dispatch phase per program dispatched."""
+    sink = Collector()
+    eng = DecodeEngine(lm_params, H, EngineConfig(**BASE), metrics=sink)
+    for p in prompts:
+        eng.submit(p, 6)
+    n = 0
+    while eng.active or eng.waiting:
+        pre, dispatches = eng.prefill_dispatches, eng.dispatch_count
+        assert eng.step()
+        n += 1
+        rec = sink.steps()[-1]
+        assert len(sink.steps()) == n
+        assert set(rec) == {"uid", "span", "start_step", "step",
+                            "start_ns", "end_ns", "t", "duration_s",
+                            "phases", "tokens_generated"}
+        assert rec["step"] == eng.global_step == eng.flight[-1]["step"]
+        assert rec["tokens_generated"] == eng.tokens_generated
+        n_pre = eng.prefill_dispatches - pre
+        assert _dispatches(rec) == (
+            n_pre, eng.dispatch_count - dispatches - n_pre)
+    assert not eng.step() and len(sink.steps()) == n    # idle: no record
+
+
+def _step_record(**over):
+    rec = {"schema": SCHEMA_VERSION, "kind": "span", "t": 2.0,
+           "uid": None, "trace_id": None, "tenant": None,
+           "span": STEP_SPAN, "start_step": 3, "step": 3,
+           "duration_s": 1.0, "start_ns": 1_000_000_000,
+           "end_ns": 2_000_000_000,
+           "phases": [["admit", 1_000_000_100, 1_000_000_900]]}
+    rec.update(over)
+    return rec
+
+
+def test_validate_record_takes_v18_engine_step():
+    assert SCHEMA_VERSION >= 18 and STEP_SPAN in SPAN_NAMES
+    ok, reason = validate_record(_step_record())
+    assert ok, reason
+    for key in ("phases", "start_ns", "end_ns"):
+        rec = _step_record()
+        del rec[key]
+        ok, reason = validate_record(rec)
+        assert not ok and key in reason and STEP_SPAN in reason
+
+
+@pytest.mark.parametrize("span", [s for s in SPAN_NAMES if s != STEP_SPAN])
+def test_validate_record_refuses_null_uid_elsewhere(span):
+    ok, reason = validate_record(_step_record(span=span))
+    assert not ok and "uid" in reason and span in reason
+    ok, reason = validate_record(_step_record(span=span, uid=7))
+    assert ok, reason
+
+
+def test_writer_round_trips_engine_step_records(lm_params, prompts,
+                                                tmp_path):
+    mdir = str(tmp_path / "m")
+    with TelemetryWriter(mdir) as w:
+        eng = DecodeEngine(lm_params, H, EngineConfig(**BASE), metrics=w)
+        eng.generate(prompts, 4)
+    records, problems = read_metrics(os.path.join(mdir, METRICS_FILENAME))
+    assert problems == []
+    steps = [r for r in records if r.get("span") == STEP_SPAN]
+    assert len(steps) == eng.steps
+    assert [r["step"] for r in steps] == list(range(1, eng.steps + 1))
+    assert all(r["uid"] is None and r["trace_id"] is None for r in steps)
+
+
+def test_report_reads_step_phases_and_keeps_the_waterfall(
+        lm_params, prompts, tmp_path, capsys):
+    """``report`` over a stream with engine_step records: the
+    per-request waterfall is what the same stream gives without them,
+    and the step-phases table is there."""
+    from distributed_llm_code_samples_tpu.report import report_main
+    with_dir, without_dir = str(tmp_path / "a"), str(tmp_path / "b")
+    with TelemetryWriter(with_dir, meta={"engine_id": "solo"}) as w:
+        eng = DecodeEngine(lm_params, H, EngineConfig(**BASE), metrics=w)
+        eng.generate(prompts, 6, log_every=2)
+    os.makedirs(without_dir)
+    with open(os.path.join(with_dir, METRICS_FILENAME)) as f, \
+            open(os.path.join(without_dir, METRICS_FILENAME), "w") as g:
+        lines = f.readlines()
+        kept = [ln for ln in lines
+                if json.loads(ln).get("span") != STEP_SPAN]
+        assert len(lines) - len(kept) == eng.steps
+        g.writelines(kept)
+    docs = []
+    for mdir in (with_dir, without_dir):
+        capsys.readouterr()
+        assert report_main([mdir, "--json"]) == 0
+        docs.append(json.loads(capsys.readouterr().out))
+    assert docs[0]["problems"] == []
+    assert docs[0]["waterfalls"] == docs[1]["waterfalls"]
+    assert all(w["reconciled"] for w in docs[0]["waterfalls"].values())
+    assert docs[0]["serving_reliability"] == docs[1]["serving_reliability"]
+    assert "step_phases" not in docs[1]
+    table = docs[0]["step_phases"]
+    assert table["steps"] == eng.steps
+    assert set(table["phases"]) <= HOST | LAUNCH | WAIT | {
+        "(between phases)"}
+    assert table["phases"]["admit"]["steps"] == eng.steps
+    assert sum(p["share"] for p in table["phases"].values()) == \
+        pytest.approx(1.0, abs=1e-3)
+    # --trace UID stitches one request's spans and never meets a null uid
+    assert report_main([with_dir, "--trace", "0"]) == 0
+    capsys.readouterr()
+    assert report_main([with_dir]) == 0
+    text = capsys.readouterr().out
+    assert "step phases:" in text and "decode.readback" in text
+    assert "per-request waterfalls" in text
+
+
+def test_phase_timer_keeps_nothing_until_begun():
+    timer = PhaseTimer("train")
+    with timer.phase("run"):
+        pass
+    assert timer.stamps is None and timer.phase_ms() == {}
+    timer.begin(4)
+    with timer.phase("outer"):
+        with timer.phase("a"):
+            pass
+        with timer.phase("a"):
+            pass
+    assert [s[0] for s in timer.stamps] == ["a", "a", "outer"]
+    (_, a0, a1), (_, b0, b1), (_, o0, o1) = timer.stamps
+    assert o0 <= a0 <= a1 <= b0 <= b1 <= o1
+    assert timer.phase_ms()["a"] == pytest.approx(
+        (a1 - a0 + b1 - b0) / 1e6, abs=1e-3)
+    timer.begin(5)
+    assert timer.stamps == [] and timer.step == 5
+
+
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+    [path] = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                    "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("engine:", "train:", "launch:")):
+                    out.append((ev.name, dict(ev.stats), ev.start_ns,
+                                ev.duration_ns))
+    return out
+
+
+def test_profiler_trace_holds_the_program_spans(lm_params, prompts,
+                                                tmp_path, mesh8):
+    """A CPU ``jax.profiler`` trace of three engine steps, one
+    ``train_single`` call and one sharded launch holds the program's
+    annotations by name, the engine's with their step number."""
+    from distributed_llm_code_samples_tpu.data import make_seed_schedule
+    from distributed_llm_code_samples_tpu.models import init_ffn_stack
+    from distributed_llm_code_samples_tpu.parallel import (train_ddp,
+                                                           train_single)
+    sink = Collector()
+    eng = DecodeEngine(lm_params, H, EngineConfig(**BASE), metrics=sink)
+    eng.generate(prompts, 4)
+    ffn = init_ffn_stack(jax.random.PRNGKey(2), 16, 2)
+    seeds = make_seed_schedule(8, random_seed=3)
+    train_single(ffn, seeds[:2], 4, 16)
+    sink.spans.clear()
+    for p in prompts:
+        eng.submit(p, 4)
+    trace_dir = str(tmp_path / "trace")
+    jax.profiler.start_trace(trace_dir)
+    try:
+        for _ in range(3):
+            assert eng.step()
+        jax.block_until_ready(train_single(ffn, seeds[:2], 4, 16))
+        jax.block_until_ready(train_ddp(ffn, seeds, 4, 16, mesh8))
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(trace_dir)
+    names = [e[0] for e in events]
+    steps = [e for e in events if e[0] == "engine:step"]
+    recs = sink.steps()
+    assert [e[1].get("step") for e in steps] == [r["step"] for r in recs]
+    assert len(steps) == 3
+    for want in ("engine:admit", "engine:prefill.dispatch",
+                 "engine:decode.dispatch", "engine:decode.readback",
+                 "engine:digest", "train:clone", "train:run",
+                 "launch:build", "launch:run"):
+        assert want in names, want
+    # the stamps and the profiler's events are one clock, shifted by the
+    # trace's start: the shift is the same for every step
+    shifts = [r["start_ns"] - e[2] for r, e in zip(recs, steps)]
+    assert max(shifts) - min(shifts) < 200_000, shifts
+    assert statistics.median(
+        abs((r["end_ns"] - r["start_ns"]) - e[3])
+        for r, e in zip(recs, steps)) < 200_000

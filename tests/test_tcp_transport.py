@@ -196,7 +196,7 @@ def test_tcp_kill_one_of_three_partition_heal(lm_params, prompts,
     for r in recon:
         ok, reason = validate_record(r)
         assert ok, reason
-        assert r["schema"] == SCHEMA_VERSION == 17
+        assert r["schema"] == SCHEMA_VERSION
         assert r["attempts"] >= 1 and r["uid"] == -1
         assert r["replayed_ops"] >= 0
     for r in [r for r in records if r["kind"] == "router"

@@ -107,7 +107,11 @@ from distributed_llm_code_samples_tpu.runtime.telemetry import (
 # partial_hits cumulative sub-block CoW shares;
 # host_tier_utilization the instantaneous spill-tier occupancy,
 # 0.0 when the tier is off — zeros pinned even when disabled).
-_PINNED_VERSION = 17
+# v18 (PR 25): step phases — the span vocabulary gains ``engine_step``
+# (one record per executed engine step: null uid, refused under any
+# other span name; pins ``phases`` + ``start_ns`` / ``end_ns``,
+# STEP_SPAN_REQUIRED).
+_PINNED_VERSION = 18
 _PINNED_STEP_KEYS = frozenset({
     "schema", "kind", "t", "step", "strategy", "loss", "grad_norm",
     "tokens_per_sec", "step_time_s", "mfu", "hbm_high_water_bytes",

@@ -108,6 +108,8 @@ def test_trace_id_consistent_across_record_kinds(lm_params, tmp_path):
         if r["kind"] in ("request", "span"):
             ok, reason = validate_record(r)
             assert ok, reason
+            if r.get("span") == "engine_step":
+                continue        # v18: a step's span, no request's
             assert r["trace_id"], r
             by_uid.setdefault(r["uid"], set()).add(r["trace_id"])
     assert set(by_uid) == {0, 1, 2}
