@@ -725,19 +725,19 @@ class DecodeEngine:
             return pool
         from ..parallel.mesh import MODEL_AXIS
         # head-sharded pool: each model shard caches its own KV heads
-        arr = P(None, None, MODEL_AXIS, None, None)
-        sc = None if pool.k_scale is None else P(None, None, MODEL_AXIS)
-        return PagedKV(*(None if x is None
-                         else jax.device_put(x, NamedSharding(self.mesh,
-                                                              spec))
-                         for x, spec in zip(pool, (arr, arr, sc, sc))))
+        return jax.tree.map(
+            lambda x, spec: jax.device_put(x, NamedSharding(self.mesh,
+                                                            spec)),
+            pool, self._pool_specs())
 
     def _pool_specs(self) -> PagedKV:
+        """Heads are contiguous in a stored row's minor axis
+        (``H_kv*dh``), so sharding that axis shards the heads."""
         from ..parallel.mesh import MODEL_AXIS
-        arr = P(None, None, MODEL_AXIS, None, None)
-        sc = None if self.pool.k_scale is None else P(None, None,
-                                                      MODEL_AXIS)
-        return PagedKV(arr, arr, sc, sc)
+        arr = P(None, None, None, MODEL_AXIS)
+        sc = (P(None, None, MODEL_AXIS) if self.cfg.kv_dtype == "int8"
+              else None)
+        return PagedKV(arr, arr, sc, sc, self.dh)
 
     # -- compiled programs (one per (kind, bucket); bounded) -----------
 
@@ -858,10 +858,18 @@ class DecodeEngine:
     def _jit(self, run, n_aux: int = 5, n_out: int = 3):
         """jit (or shard_map+jit under TP) with the pool donated: the
         engine replaces ``self.pool`` with the returned pool after every
-        dispatch, so XLA may update the blocks in place instead of
-        copying the whole pool per step — without donation each decode
-        step would pay a full-pool allocate+copy, swamping the
-        kv_bytes roofline term this engine exists to shrink."""
+        dispatch, and XLA updates the blocks in place — without
+        donation each decode step would pay a full-pool allocate+copy,
+        swamping the kv_bytes roofline term this engine exists to
+        shrink. Donation alone does not make that so: the buffer also
+        has to cross the program boundary in the layout the program's
+        scatters and gathers work in. The pool's stored form
+        (``decode/paged.py``: a token's row holds its heads, ``(block,
+        H_kv*dh)`` minor) is the one the chip keeps row-major, so the
+        program takes and returns it as it lies; the head-major form
+        before it was converted whole on the way in and again on the
+        way out, four pool-sized copies a program
+        (``tests/test_chip_compile.py`` pins the compiled module)."""
         return jax.jit(self._wrap(run, n_aux, n_out), donate_argnums=(1,))
 
     def _cached_attn(self, pool: PagedKV, l: int, q, tables, n_attend):

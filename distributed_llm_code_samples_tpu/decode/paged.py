@@ -6,11 +6,29 @@ sequences pays for its longest member and freeing a finished sequence
 means rebuilding the batch (a recompile). This module is the
 PagedAttention-style answer in the repo's first-principles idiom: the
 cache is a static-shape **pool of fixed-size blocks**
-(``k/v [L, n_blocks, H_kv, block, dh]``) and each sequence names its
+(``k/v [L, n_blocks, block, H_kv*dh]``) and each sequence names its
 blocks through a per-slot int32 **block table** — the KV read is a
 gather (``models.attention.gather_paged_kv``), the write is a scatter,
 and freeing a sequence is a host-side table edit. Shapes never depend
 on sequence length, so one compiled decode step serves every occupancy.
+
+The stored form is the one the chip keeps as it is. A token's row holds
+all its KV heads side by side (``H_kv*dh`` lanes, head ``h`` at
+``[h*dh, (h+1)*dh)``), so the two minor axes are ``(block, H_kv*dh)``:
+the TPU tiles those row-major, and unpadded wherever ``H_kv*dh`` is a
+multiple of 128 lanes (a narrower row, as a toy model's or a TP shard's
+320, pads up to the next 128 — correct, and counted in ``PERF.md``). A
+jitted program therefore takes and returns the donated pool without
+converting it. The old head-major ``[L, n_blocks, H_kv, block, dh]``
+left ``(block, dh) = (16, 64)`` minor: the chip stored that with the
+BLOCK index innermost, padded, and every program re-laid the whole
+pool out on the way in and on the way out. The layer rides inside the
+indices of every step-path read and write (``k[layer, phys, off]``,
+``k[full_like(table, layer), table]``): no ``[n_blocks, ...]`` slab of
+one layer is ever sliced out. Host-side block documents (handoff,
+spill, snapshots) keep the head-major ``[L, n, H_kv, block, dh]``;
+``extract_blocks`` / ``implant_block`` convert at that edge, off the
+step path.
 
 Physical block 0 is reserved as the **scratch block**: unassigned table
 slots and padded bucket rows point at it, so padded writes land
@@ -38,7 +56,8 @@ Python int (the engine unrolls layers at trace time, like
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -49,14 +68,25 @@ KV_DTYPES = ("f32", "bf16", "int8")
 SCRATCH_BLOCK = 0
 
 
-class PagedKV(NamedTuple):
-    """The block pool. ``k/v [L, n_blocks, H_kv, block, dh]`` in the
-    storage dtype; ``k_scale/v_scale [L, n_blocks, H_kv]`` f32 per-block
-    dequantization scales (``None`` unless ``kv_dtype="int8"``)."""
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["k", "v", "k_scale", "v_scale"],
+                   meta_fields=["head_dim"])
+@dataclasses.dataclass(frozen=True)
+class PagedKV:
+    """The block pool. ``k/v [L, n_blocks, block, H_kv*dh]`` in the
+    storage dtype (a token's row holds its heads side by side: the
+    module docstring says why); ``k_scale/v_scale [L, n_blocks, H_kv]``
+    f32 per-block dequantization scales (``None`` unless
+    ``kv_dtype="int8"``). ``head_dim`` is static (pytree metadata, not
+    a leaf): it is what splits a row back into heads."""
     k: jax.Array
     v: jax.Array
     k_scale: jax.Array | None
     v_scale: jax.Array | None
+    head_dim: int
+
+    def _replace(self, **fields) -> "PagedKV":
+        return dataclasses.replace(self, **fields)
 
     @property
     def n_blocks(self) -> int:
@@ -64,7 +94,27 @@ class PagedKV(NamedTuple):
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[3]
+        return self.k.shape[2]
+
+    @property
+    def kv_heads(self) -> int:
+        """KV heads in a row — the LOCAL count inside a TP shard."""
+        return self.k.shape[3] // self.head_dim
+
+
+def _heads_major(x, head_dim: int):
+    """``[..., block, H_kv*dh] -> [..., H_kv, block, dh]``: stored rows
+    to the head-major block the int8 quantizer, the Pallas walk and the
+    host documents speak. Works on numpy and jax arrays alike."""
+    *lead, blk, m = x.shape
+    return x.reshape(*lead, blk, m // head_dim, head_dim).swapaxes(-3, -2)
+
+
+def _rows_major(x):
+    """``[..., H_kv, block, dh] -> [..., block, H_kv*dh]``: the inverse
+    of ``_heads_major``."""
+    *lead, hkv, blk, dh = x.shape
+    return x.swapaxes(-3, -2).reshape(*lead, blk, hkv * dh)
 
 
 def storage_dtype(kv_dtype: str):
@@ -104,7 +154,7 @@ def init_pool(n_layers: int, n_blocks: int, kv_heads: int,
     if n_blocks < 2:
         raise ValueError(f"n_blocks must be >= 2 (block {SCRATCH_BLOCK} "
                          f"is the reserved scratch block), got {n_blocks}")
-    shape = (n_layers, n_blocks, kv_heads, block_size, head_dim)
+    shape = (n_layers, n_blocks, block_size, kv_heads * head_dim)
     dt = storage_dtype(kv_dtype)
 
     def scale():
@@ -115,7 +165,7 @@ def init_pool(n_layers: int, n_blocks: int, kv_heads: int,
                 if kv_dtype == "int8" else None)
 
     return PagedKV(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt),
-                   k_scale=scale(), v_scale=scale())
+                   k_scale=scale(), v_scale=scale(), head_dim=head_dim)
 
 
 def _quantize(x: jax.Array, valid: jax.Array):
@@ -142,15 +192,14 @@ def write_rows(pool: PagedKV, layer: int, phys: jax.Array,
                off: jax.Array, k_new: jax.Array, v_new: jax.Array,
                kv_dtype: str) -> PagedKV:
     """Scatter ``N`` new KV rows into the pool: row ``i`` lands at
-    ``(layer, phys[i], :, off[i], :)``. ``k_new/v_new [N, H_kv, dh]``
+    ``(layer, phys[i], off[i], :)``. ``k_new/v_new [N, H_kv, dh]``
     f32. For f32/bf16 this is one masked-free scatter; for int8 each
     touched block is read back, dequantized, re-quantized over its valid
     rows ``0..off[i]`` (blocks fill in order, so everything at or below
     the newest offset is live) and written whole. Duplicate ``phys``
     entries are only ever the scratch block (padded bucket rows) — last
     writer wins there, and nothing reads it unmasked."""
-    hkv = pool.k.shape[2]
-    heads = jnp.arange(hkv)
+    n = off.shape[0]
     # "requant" tags the KV write in traces/HLO (utils/trace_analysis
     # SCOPES: decode/requant, prefill/requant). At f32/bf16 the region
     # is the plain scatter; the name stays "requant" because the int8
@@ -158,30 +207,32 @@ def write_rows(pool: PagedKV, layer: int, phys: jax.Array,
     # separate — the cheap dtypes show the region near zero.
     if kv_dtype != "int8":
         dt = pool.k.dtype
-        idx = (layer, phys[:, None], heads[None, :], off[:, None])
+        idx = (layer, phys, off)        # the layer rides in the indices
         with jax.named_scope("requant"):
             return pool._replace(
-                k=pool.k.at[idx].set(k_new.astype(dt)),
-                v=pool.v.at[idx].set(v_new.astype(dt)))
-    # int8: read-modify-requantize the touched blocks
-    blk = pool.block_size
+                k=pool.k.at[idx].set(k_new.reshape(n, -1).astype(dt)),
+                v=pool.v.at[idx].set(v_new.reshape(n, -1).astype(dt)))
+    # int8: read-modify-requantize the touched blocks, head-major (the
+    # quantizer's scales are per (block, head))
+    blk, hkv = pool.block_size, pool.kv_heads
     rows = jnp.arange(blk)
     valid = rows[None, :] <= off[:, None]               # [N, block]
-    valid = jnp.broadcast_to(valid[:, None, :], (off.shape[0], hkv, blk))
+    valid = jnp.broadcast_to(valid[:, None, :], (n, hkv, blk))
 
     def requant(pool_side, scale_side, new):
-        old = _dequantize(pool_side[layer, phys],      # [N, Hkv, blk, dh]
-                          scale_side[layer, phys])
+        old = _dequantize(                              # [N, Hkv, blk, dh]
+            _heads_major(pool_side[layer, phys], pool.head_dim),
+            scale_side[layer, phys])
         ins = rows[None, None, :, None] == off[:, None, None, None]
         cur = jnp.where(ins, new[:, :, None, :], old)
         q, scale = _quantize(cur, valid)
-        return (pool_side.at[layer, phys].set(q),
+        return (pool_side.at[layer, phys].set(_rows_major(q)),
                 scale_side.at[layer, phys].set(scale))
 
     with jax.named_scope("requant"):
         k, ks = requant(pool.k, pool.k_scale, k_new)
         v, vs = requant(pool.v, pool.v_scale, v_new)
-    return PagedKV(k=k, v=v, k_scale=ks, v_scale=vs)
+    return pool._replace(k=k, v=v, k_scale=ks, v_scale=vs)
 
 
 def write_chunk(pool: PagedKV, layer: int, table: jax.Array, pos0,
@@ -212,21 +263,20 @@ def write_chunk(pool: PagedKV, layer: int, table: jax.Array, pos0,
         raise ValueError(f"chunk {c} > block {blk} must be a whole "
                          "multiple (power-of-two buckets guarantee it)")
     nb = c // blk
-    hkv = pool.k.shape[2]
-    dh = pool.k.shape[4]
+    hkv, dh = pool.kv_heads, pool.head_dim
     blocks = table[pos0 // blk + jnp.arange(nb)]        # [nb]
     valid = jnp.ones((nb, hkv, blk), bool)
 
     def quant_whole(pool_side, scale_side, new):
         shaped = new.reshape(nb, blk, hkv, dh).transpose(0, 2, 1, 3)
         q, scale = _quantize(shaped, valid)
-        return (pool_side.at[layer, blocks].set(q),
+        return (pool_side.at[layer, blocks].set(_rows_major(q)),
                 scale_side.at[layer, blocks].set(scale))
 
     with jax.named_scope("requant"):
         k, ks = quant_whole(pool.k, pool.k_scale, k_new)
         v, vs = quant_whole(pool.v, pool.v_scale, v_new)
-    return PagedKV(k=k, v=v, k_scale=ks, v_scale=vs)
+    return pool._replace(k=k, v=v, k_scale=ks, v_scale=vs)
 
 
 def _int8_partial_chunk(pool: PagedKV, layer: int, phys, off: jax.Array,
@@ -235,26 +285,27 @@ def _int8_partial_chunk(pool: PagedKV, layer: int, phys, off: jax.Array,
     block, dequantize, insert the ``C`` rows at ``off``, re-quantize
     over rows ``0..max(off)``."""
     blk = pool.block_size
-    hkv, dh = pool.k.shape[2], pool.k.shape[4]
+    hkv, dh = pool.kv_heads, pool.head_dim
     rows = jnp.arange(blk)
     valid_hi = off[-1]                                  # fills in order
     valid = jnp.broadcast_to((rows <= valid_hi)[None, :], (hkv, blk))
     hit = jnp.zeros((blk,), bool).at[off].set(True)
 
     def requant(pool_side, scale_side, new):
-        old = _dequantize(pool_side[layer, phys],       # [Hkv, blk, dh]
-                          scale_side[layer, phys])
+        old = _dequantize(                              # [Hkv, blk, dh]
+            _heads_major(pool_side[layer, phys], dh),
+            scale_side[layer, phys])
         # insert row c at offset off[c] (offsets are distinct)
         upd = jnp.zeros((blk, hkv, dh), new.dtype).at[off].set(new)
         cur = jnp.where(hit[None, :, None], upd.transpose(1, 0, 2), old)
         q, scale = _quantize(cur, valid)
-        return (pool_side.at[layer, phys].set(q),
+        return (pool_side.at[layer, phys].set(_rows_major(q)),
                 scale_side.at[layer, phys].set(scale))
 
     with jax.named_scope("requant"):
         k, ks = requant(pool.k, pool.k_scale, k_new)
         v, vs = requant(pool.v, pool.v_scale, v_new)
-    return PagedKV(k=k, v=v, k_scale=ks, v_scale=vs)
+    return pool._replace(k=k, v=v, k_scale=ks, v_scale=vs)
 
 
 def scrub_blocks(pool: PagedKV, blocks) -> PagedKV:
@@ -318,7 +369,7 @@ def copy_block_rows(pool: PagedKV, src, dst, n_rows) -> PagedKV:
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
     n = jnp.asarray(n_rows, jnp.int32)
-    mask = (jnp.arange(pool.block_size) < n)[None, None, :, None]
+    mask = (jnp.arange(pool.block_size) < n)[None, :, None]
     z = jnp.zeros((), pool.k.dtype)
     out = pool._replace(
         k=pool.k.at[:, dst].set(jnp.where(mask, pool.k[:, src], z)),
@@ -338,11 +389,18 @@ def extract_blocks(pool: PagedKV, blocks) -> dict:
     round-trip through f32, or the bit-exactness contract dies at the
     requantization boundary), ``k_scale``/``v_scale`` ``[L, n, H_kv]``
     f32 (None unless int8). A plain eager gather + device->host
-    readback: export rides the host, never the compiled program set."""
+    readback: export rides the host, never the compiled program set —
+    and the stored rows become the document's head-major blocks here, on
+    the host (a byte shuffle; no wire or snapshot format knows the
+    stored form)."""
     import numpy as np
     idx = np.asarray(blocks, np.int32)
-    out = {"k": np.asarray(pool.k[:, idx]),
-           "v": np.asarray(pool.v[:, idx]),
+
+    def doc(side):
+        return np.ascontiguousarray(
+            _heads_major(np.asarray(side[:, idx]), pool.head_dim))
+
+    out = {"k": doc(pool.k), "v": doc(pool.v),
            "k_scale": None, "v_scale": None}
     if pool.k_scale is not None:
         out["k_scale"] = np.asarray(pool.k_scale[:, idx])
@@ -354,13 +412,14 @@ def implant_block(pool: PagedKV, dst, k_blk, v_blk,
                   k_scale=None, v_scale=None) -> PagedKV:
     """Write one imported block's bytes (values AND int8 scales) at
     physical block ``dst`` across every layer — the import half of the
-    KV handoff. ``k_blk``/``v_blk`` are ``[L, H_kv, block, dh]`` in the
-    pool's storage dtype; ``dst`` may be a traced scalar, so ONE
-    compiled implant program (donated, like the step programs) serves
-    every destination block — importing never recompiles."""
+    KV handoff. ``k_blk``/``v_blk`` are ``[L, H_kv, block, dh]`` (the
+    document's form) in the pool's storage dtype, re-formed into stored
+    rows here; ``dst`` may be a traced scalar, so ONE compiled implant
+    program (donated, like the step programs) serves every destination
+    block — importing never recompiles."""
     dst = jnp.asarray(dst, jnp.int32)
-    out = pool._replace(k=pool.k.at[:, dst].set(k_blk),
-                        v=pool.v.at[:, dst].set(v_blk))
+    out = pool._replace(k=pool.k.at[:, dst].set(_rows_major(k_blk)),
+                        v=pool.v.at[:, dst].set(_rows_major(v_blk)))
     if pool.k_scale is not None:
         out = out._replace(k_scale=pool.k_scale.at[:, dst].set(k_scale),
                            v_scale=pool.v_scale.at[:, dst].set(v_scale))
@@ -404,12 +463,18 @@ def fused_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     interpreter within 8 ULP of the row's scale at every pool dtype
     (tests/test_pallas_paged_attention.py); on the chip no ULP bound
     is measured, and greedy tokens match the oracle's at float32 matmul
-    precision only (the kernel module states the contract)."""
+    precision only (the kernel module states the contract).
+
+    The kernel's ``BlockSpec``s walk ``[n_blocks, H_kv, block, dh]``
+    (a 64-lane block of a wider stored row is not a legal Mosaic
+    block), so this ONE layer is re-formed in front of the call: a
+    slab-sized copy a layer, on the opt-in path only."""
     from ..ops.pallas_paged_attention import paged_decode_attn
     ks = None if pool.k_scale is None else pool.k_scale[layer]
     vs = None if pool.v_scale is None else pool.v_scale[layer]
-    return paged_decode_attn(q, pool.k[layer], pool.v[layer], ks, vs,
-                             tables, lengths, interpret=interpret)
+    return paged_decode_attn(q, _heads_major(pool.k[layer], pool.head_dim),
+                             _heads_major(pool.v[layer], pool.head_dim),
+                             ks, vs, tables, lengths, interpret=interpret)
 
 
 def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
@@ -423,7 +488,8 @@ def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
     # (utils/trace_analysis SCOPES: decode/gather, prefill/gather) —
     # the paged-KV traffic term the DECODE roofline prices
     with jax.named_scope("gather"):
-        k, v = gather_paged_kv(pool.k[layer], pool.v[layer], table)
+        k, v = gather_paged_kv(pool.k, pool.v, layer, table,
+                               pool.head_dim)
         if pool.k_scale is None:
             if k.dtype != jnp.float32:
                 k = k.astype(jnp.float32)
@@ -431,7 +497,7 @@ def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
             return k, v
         blk = pool.block_size
         # per-block scales -> per-position: [MB, Hkv] -> [Hkv, MB*blk]
-        ks = jnp.repeat(pool.k_scale[layer][table].T, blk, axis=1)
-        vs = jnp.repeat(pool.v_scale[layer][table].T, blk, axis=1)
+        ks = jnp.repeat(pool.k_scale[layer, table].T, blk, axis=1)
+        vs = jnp.repeat(pool.v_scale[layer, table].T, blk, axis=1)
         return (k.astype(jnp.float32) * ks[..., None],
                 v.astype(jnp.float32) * vs[..., None])

@@ -145,12 +145,16 @@ rope_mha.supports_gqa = True  # handles fewer k heads (see attn_sublayer)
 # a sequence is a table edit, never a recompile.
 
 
-def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array,
-                    table: jax.Array):
+def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array, layer: int,
+                    table: jax.Array, head_dim: int):
     """Materialize one sequence's contiguous KV view from the block pool.
 
-    ``pool_k/pool_v [n_blocks, H_kv, block, dh]`` (one layer's pool),
-    ``table [max_blocks]`` int32 physical block ids, in sequence order.
+    ``pool_k/pool_v [L, n_blocks, block, H_kv*dh]`` (the WHOLE pool, as
+    ``decode/paged.py`` stores it: a token's row holds its heads side by
+    side), ``layer`` a Python int, ``table [max_blocks]`` int32 physical
+    block ids, in sequence order. The layer rides inside the gather's
+    indices, so no one-layer ``[n_blocks, ...]`` slab is sliced out of
+    the pool first.
     Returns ``(k, v)`` each ``[H_kv, max_blocks * block, dh]`` — exactly
     the contiguous cache layout ``_decode_attn`` reads, so downstream
     attention is bit-identical to a contiguous cache holding the same
@@ -166,11 +170,13 @@ def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array,
     materializing this layout in HBM, and must match this path
     bit-for-bit at f32 under jit (tests/test_pallas_paged_attention.py
     pins it)."""
-    k = pool_k[table]                      # [MB, H_kv, block, dh]
-    v = pool_v[table]
-    mb, hkv, blk, dh = k.shape
-    k = k.transpose(1, 0, 2, 3).reshape(hkv, mb * blk, dh)
-    v = v.transpose(1, 0, 2, 3).reshape(hkv, mb * blk, dh)
+    layers = jnp.full_like(table, layer)
+    k = pool_k[layers, table]              # [MB, block, H_kv*dh]
+    v = pool_v[layers, table]
+    mb, blk, m = k.shape
+    hkv = m // head_dim
+    k = k.reshape(mb * blk, hkv, head_dim).transpose(1, 0, 2)
+    v = v.reshape(mb * blk, hkv, head_dim).transpose(1, 0, 2)
     return k, v
 
 

@@ -180,6 +180,19 @@ def test_fused_head_compiles_at_gpt2_shape(one_chip):
     assert _total_bytes(compiled) < HBM_V5E
 
 
+def _step_args(params, pool, slots, mbps, chunk):
+    """The operands of one decode dispatch over ``slots`` rows and of
+    one prefill dispatch of ``chunk`` tokens, as the engine passes
+    them (only their shapes are lowered)."""
+    i32 = jnp.int32
+    decode = (params, pool, np.zeros((slots, mbps), i32),
+              np.zeros((slots,), i32), np.zeros((slots,), i32),
+              np.zeros((slots,), i32), i32(0))
+    prefill = (params, pool, np.zeros((mbps,), i32), i32(0),
+               np.zeros((chunk,), i32), i32(0), i32(0))
+    return decode, prefill
+
+
 @pytest.fixture(scope="module")
 def gpt2_engine_args():
     """``kernel -> (engine, decode args, prefill args)`` for the engine
@@ -199,12 +212,7 @@ def gpt2_engine_args():
             block_size=16, n_blocks=1 + slots * mbps, max_slots=slots,
             max_blocks_per_seq=mbps, prefill_chunk=chunk,
             kv_dtype=kv_dtype, kernel=kernel))
-        i32 = jnp.int32
-        decode = (params, eng.pool, np.zeros((slots, mbps), i32),
-                  np.zeros((slots,), i32), np.zeros((slots,), i32),
-                  np.zeros((slots,), i32), i32(0))
-        prefill = (params, eng.pool, np.zeros((mbps,), i32), i32(0),
-                   np.zeros((chunk,), i32), i32(0), i32(0))
+        decode, prefill = _step_args(params, eng.pool, slots, mbps, chunk)
         return eng, decode, prefill, slots, chunk
 
     return build
@@ -235,6 +243,83 @@ def test_engine_prefill_chunk_compiles(one_chip, gpt2_engine_args):
     compiled = eng._program("prefill", chunk).lower(
         *_shapes_of(prefill, one_chip)).compile()
     assert _total_bytes(compiled) < HBM_V5E
+
+
+# the serving cell of the benchmark (BENCHMARK.json,
+# gpt2-large.batch-offline): GPT-2 large's widths, 12 slots x 1024
+# positions of bf16 KV, cut to 2 layers so the compile stays short
+GPT2_LARGE = dict(vocab=50257, d=1280, layers=2, heads=20, max_seq=1024,
+                  slots=12, mbps=64, chunk=16)
+
+
+@pytest.fixture(scope="module")
+def gpt2_large_engine_args():
+    """``(engine, {kind: (bucket, args)})`` at the serving cell's
+    widths, built as ``benchmark/configs/gpt2_engine_driver.py`` builds
+    it (every tunable at the program's default)."""
+    from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
+                                                         EngineConfig)
+    from distributed_llm_code_samples_tpu.models import init_lm
+    g = GPT2_LARGE
+    params = init_lm(jax.random.PRNGKey(7), g["vocab"], g["d"], g["layers"],
+                     max_seq_len=g["max_seq"], n_heads=g["heads"])
+    slots, mbps, chunk = g["slots"], g["mbps"], g["chunk"]
+    eng = DecodeEngine(params, g["heads"], EngineConfig(
+        n_blocks=1 + slots * mbps, max_slots=slots,
+        max_blocks_per_seq=mbps, prefill_chunk=chunk, kv_dtype="bf16"))
+    decode, prefill = _step_args(params, eng.pool, slots, mbps, chunk)
+    return eng, {"decode": (slots, decode), "prefill": (chunk, prefill)}
+
+
+def _hlo_results(hlo: str, ops: tuple[str, ...], dtype: str):
+    """``(op, elements)`` of every instruction of the module (fused
+    computations included) whose op is one of ``ops`` and whose result
+    is a ``dtype`` array."""
+    import re
+    pat = re.compile(r"= %s\[([\d,]+)\]\S* (%s)\(" % (
+        dtype, "|".join(re.escape(o) for o in ops)))
+    return [(m.group(2), int(np.prod([int(x)
+                                      for x in m.group(1).split(",")])))
+            for m in pat.finditer(hlo)]
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_step_program_keeps_the_pool_as_stored(one_chip,
+                                               gpt2_large_engine_args, kind):
+    """The stored form is the form the chip keeps (``decode/paged.py``):
+    a step program of the serving cell takes the donated pool row-major
+    and unpadded, updates it in place, and never copies the pool or
+    slices one layer's slab out of it. What stands in a counter's
+    place: a stored form does not engage sometimes. (The parent of
+    PR 26 failed all but the aliasing: per program 4 whole-pool copies,
+    and 2 slab copies and 2 slab-sized slices a layer; the pool entered
+    block-index minor, padded 769 -> 896, 17% over its logical bytes.)"""
+    eng, programs = gpt2_large_engine_args
+    bucket, args = programs[kind]
+    compiled = eng._program(kind, bucket).lower(
+        *_shapes_of(args, one_chip)).compile()
+    pool = eng.pool
+    slab = pool.k.size // pool.k.shape[0]
+    # nothing of one layer's slab or more is copied or sliced out, in
+    # the pool's dtype (the batch's gathered blocks are one block short
+    # of a slab, and are what the attention reads)
+    moved = [r for r in _hlo_results(
+        compiled.as_text(), ("copy", "slice", "dynamic-slice"), "bf16")
+        if r[1] >= slab]
+    assert not moved, moved
+    # the pool enters as it is stored: row-major
+    pool_formats = compiled.input_formats[0][1]
+    for side in (pool_formats.k, pool_formats.v):
+        assert side.layout.major_to_minor == tuple(range(pool.k.ndim)), side
+    m = compiled.memory_analysis()
+    pool_bytes = pool.k.nbytes + pool.v.nbytes
+    # donation is real: the whole pool is updated in place
+    assert m.alias_size_in_bytes >= pool_bytes
+    # ...and unpadded: the arguments' bytes are their logical bytes
+    # (the weights' few odd rows pad by kilobytes; the parent's pool
+    # padded by 17% of itself)
+    logical = sum(x.nbytes for x in jax.tree_util.tree_leaves(args))
+    assert m.argument_size_in_bytes - logical < pool_bytes // 100
 
 
 def test_train_single_step_compiles_at_paper_width(one_chip):

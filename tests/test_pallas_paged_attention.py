@@ -34,7 +34,7 @@ from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
                                                      gather_layer,
                                                      init_pool)
 from distributed_llm_code_samples_tpu.decode.paged import (
-    _quantize, fused_decode_attn)
+    _quantize, _rows_major, fused_decode_attn)
 from distributed_llm_code_samples_tpu.models import init_lm
 from distributed_llm_code_samples_tpu.models.lm import decode_attn
 from distributed_llm_code_samples_tpu.ops.pallas_paged_attention import (
@@ -68,12 +68,13 @@ def _pool_with_content(kv_dtype, n_blocks=9, hkv=2, blk=8, dh=8, seed=0):
         valid = jnp.ones((n_blocks, hkv, blk), bool)
         qk, ks = _quantize(jnp.asarray(src_k), valid)
         qv, vs = _quantize(jnp.asarray(src_v), valid)
-        pool = pool._replace(k=qk[None], v=qv[None], k_scale=ks[None],
+        pool = pool._replace(k=_rows_major(qk)[None],
+                             v=_rows_major(qv)[None], k_scale=ks[None],
                              v_scale=vs[None])
     else:
         dt = pool.k.dtype
-        pool = pool._replace(k=jnp.asarray(src_k, dt)[None],
-                             v=jnp.asarray(src_v, dt)[None])
+        pool = pool._replace(k=_rows_major(jnp.asarray(src_k, dt))[None],
+                             v=_rows_major(jnp.asarray(src_v, dt))[None])
     return pool, src_k, src_v
 
 
