@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """The quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py            # one TPU chip: serve, fused kernel, train
+    python chip_smoke.py            # one chip: serve, fused, hybrid, train
     python chip_smoke.py --chips 4  # four chips: the cross-chip paths only
 
 One process. It imports JAX once and drives the program through the
@@ -75,6 +75,25 @@ TRAIN = [dict(argv=["-d", "8192", "-l", "1", "-n", "1024", "-bs", "8",
 # the LM trainer across four chips (vocab-parallel Megatron TP)
 TRAIN_LM = ["-d", "768", "-l", "12", "--heads", "12", "--vocab", "50304",
             "-n", "1024", "-bs", "8", "-s", "4", "--lr", "0.1"]
+
+# A hybrid in small (``models/hybrid_lm.py``): Mamba-1 and attention
+# layers in one stack, the shape of the benchmark's jamba2-3b-serve at
+# toy widths, as a published-style config.json. A toy by intent: the
+# published widths are the benchmark's cell; this phase proves the
+# recurrent state beside the paged KV is right ON THE CHIP, against the
+# plain reference, which the CPU's tests cannot.
+HYBRID = dict(config=dict(
+    model_type="jamba", hidden_size=64, intermediate_size=128,
+    mamba_expand=2, mamba_d_state=4, mamba_d_conv=4, mamba_dt_rank=8,
+    mamba_conv_bias=True, mamba_proj_bias=False, num_hidden_layers=6,
+    attn_layer_period=3, attn_layer_offset=1, num_attention_heads=4,
+    num_key_value_heads=1, vocab_size=96, rms_norm_eps=1e-6,
+    max_position_embeddings=256, num_experts=1, hidden_act="silu",
+    tie_word_embeddings=True, sliding_window=None, initializer_range=0.2),
+    prompt_lens="5,19,40,64,11", max_new=24, max_slots=3,
+    # a served token has to be the reference's first wherever its top
+    # two logits lie further apart than this (float32 on both sides)
+    tie=1e-3)
 
 MOSAIC = "tpu_custom_call"
 
@@ -266,6 +285,54 @@ def phase_fused(out_dir: str) -> None:
                            f"{rec['first_difference_from_gather']}")
 
 
+def phase_hybrid(out_dir: str) -> None:
+    """Phase 3: the toy hybrid through ``generate --model_config`` at
+    float32 matmul precision (more requests than slots, so rows of the
+    recurrent state are reused), against the plain reference
+    teacher-forced on what was served: every served token is the
+    reference's first wherever its top two logits are not tied."""
+    import numpy as np
+    config = HYBRID["config"]
+    path = os.path.join(out_dir, "hybrid_config.json")
+    with open(path, "w") as f:
+        json.dump(config, f)
+    argv = ["--model_config", path, "-r", "7", "--prompt_lens",
+            HYBRID["prompt_lens"], "--max_new", str(HYBRID["max_new"]),
+            "--max_slots", str(HYBRID["max_slots"])]
+    # the benchmark's plain reference and the weights its driver file
+    # makes, found by name as the benchmark finds them
+    from benchmark import harness
+    ref = harness.reference_module({"reference": "jamba_lm_reference"})
+    driver = harness.driver_module({"driver": "jamba_engine_driver"})
+    with jax.default_matmul_precision("highest"):
+        payload, rec = generate("serve_hybrid", argv, config["vocab_size"])
+        w = driver.make_weights(config, 7)
+        clear = total = 0
+        for seq in payload["sequences"]:
+            full, plen = seq["tokens"], seq["prompt_len"]
+            rows = np.asarray(ref.logits(w, np.asarray(full), config))[
+                plen - 1:len(full) - 1]
+            served = np.asarray(full[plen:])
+            top2 = np.sort(rows, -1)[:, -2:]
+            sure = top2[:, 1] - top2[:, 0] > HYBRID["tie"]
+            wrong = np.flatnonzero(sure & (rows.argmax(-1) != served))
+            if wrong.size:
+                raise RuntimeError(
+                    f"hybrid: uid {seq['uid']} output token "
+                    f"{int(wrong[0])} is {int(served[wrong[0]])}, the "
+                    f"reference's first is "
+                    f"{int(rows.argmax(-1)[wrong[0]])}")
+            clear += int(sure.sum())
+            total += len(served)
+    rec["matmul_precision"] = "highest"
+    rec["tokens_compared"] = clear
+    rec["tokens_tied"] = total - clear
+    emit(rec)
+    if clear < 0.9 * total:
+        raise RuntimeError(f"hybrid: only {clear} of {total} tokens had "
+                           "a clear first: the check compared too little")
+
+
 def step_records(mdir: str) -> list[dict]:
     records, errors = telemetry.read_metrics(
         os.path.join(mdir, "metrics.jsonl"))
@@ -412,7 +479,7 @@ def main(argv=None) -> int:
     shutil.rmtree(out_dir, ignore_errors=True)  # metrics streams append
     os.makedirs(out_dir)
     phases = ([phase_cross_chip] if args.chips == 4
-              else [phase_serve, phase_fused, phase_train])
+              else [phase_serve, phase_fused, phase_hybrid, phase_train])
     for phase in phases:
         try:
             phase(out_dir)

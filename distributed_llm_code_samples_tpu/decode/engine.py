@@ -25,6 +25,17 @@ wrappers):
   ``(engine seed, sequence uid, position)`` — continuous-batching
   output is token-identical to decoding each sequence alone.
 
+Models: ``LMParams`` (the GPT-2-shaped block, every layer attention)
+and ``HybridLMParams`` (``models/hybrid_lm.py``: Mamba-1 and attention
+layers in one stack). A model says, per layer, which kind it is and
+which index of its kind's weights and cache it owns; ``_trunk`` walks
+that. A recurrent layer's state lives beside the pool, by slot
+(``paged.RecurrentState``), donated and updated in place like it; a
+slot's state is zero at position 0 inside the prefill program. What
+cannot carry that state yet — prefix hits, speculation, a mesh, the KV
+handoff, snapshots, the spill tier — refuses in one line for such a
+model (``_refuse_recurrent``), by what the model is and under no flag.
+
 Strategies: ``mesh=None`` runs single-device (the ``lm`` family);
 passing a model-axis mesh runs the Megatron decode layout
 (``parallel.lm``): head-sharded KV pool (each shard caches its own
@@ -167,7 +178,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..models import hybrid_lm
 from ..models.attention import chunk_attn, rope
+from ..models.hybrid_lm import ATTN, HybridLMParams, mm
 from ..models.lm import LMParams, decode_attn
 from ..ops.norm import layernorm
 from ..parallel import launcher
@@ -180,11 +193,11 @@ from ..runtime.weights import (BOOT_VERSION, architecture_diff,
                                model_fingerprint, same_architecture)
 from ..runtime import wire
 from .draft import draft_tokens
-from .paged import (PagedKV, SCRATCH_BLOCK, copy_block, copy_block_rows,
-                    corrupt_block as
+from .paged import (PagedKV, RecurrentState, SCRATCH_BLOCK, copy_block,
+                    copy_block_rows, corrupt_block as
                     _pool_corrupt_block, extract_blocks,
                     fused_decode_attn, gather_layer, implant_block,
-                    init_pool, kv_bytes_per_token, pool_bytes,
+                    init_pool, init_state, kv_bytes_per_token, pool_bytes,
                     scrub_blocks, write_chunk, write_rows)
 from .prefix import PrefixCache
 from .spill import SpillTier
@@ -477,7 +490,7 @@ class DecodeEngine:
     returns ``{uid: full token list}``. See the module docstring for the
     design; DESIGN.md section 15 for the state machine."""
 
-    def __init__(self, params: LMParams, n_heads: int,
+    def __init__(self, params: LMParams | HybridLMParams, n_heads: int,
                  config: EngineConfig | None = None, mesh=None,
                  policy: ServePolicy | None = None, metrics=None,
                  qos: QosPolicy | None = None):
@@ -512,6 +525,20 @@ class DecodeEngine:
                 "cache; they require prefix_cache=True")
         check_sampling(cfg.temperature, cfg.top_k, cfg.top_p, params.vocab)
         check_speculation(cfg.speculate, cfg.temperature)
+        # the kinds of layer that carry a recurrent state beside the KV
+        # blocks (``models/hybrid_lm.py``; none for ``LMParams``). What
+        # cannot carry that state yet refuses, here and at the entry of
+        # every later call, by what the model is: no flag turns it off
+        self.recurrent = sorted({kind for kind, _ in params.layers}
+                                - {ATTN})
+        if mesh is not None:
+            self._refuse_recurrent("a model-axis mesh (--tp)")
+        if cfg.speculate:
+            self._refuse_recurrent("speculate > 0 (a rejected draft "
+                                   "would have to be undone in the state)")
+        if cfg.spill_blocks or cfg.prefix_partial:
+            self._refuse_recurrent("spill_blocks / prefix_partial (they "
+                                   "extend the prefix cache, which is off)")
         if cfg.kernel not in ("gather", "fused"):
             raise ValueError(f"kernel must be 'gather' or 'fused', got "
                              f"{cfg.kernel!r}")
@@ -525,8 +552,8 @@ class DecodeEngine:
         self.n_heads = n_heads
         self.cfg = cfg
         self.mesh = mesh
-        self.dh = params.d_model // n_heads
-        self.kv_heads = params.blocks.wk.shape[1] // self.dh
+        self.dh = getattr(params, "head_dim", params.d_model // n_heads)
+        self.kv_heads = params.attn.wk.shape[1] // self.dh
         if cfg.kernel == "fused":
             # a shape Mosaic would refuse is refused here, on every
             # backend — never at the first decode step on the chip
@@ -579,6 +606,10 @@ class DecodeEngine:
         # (None single-tenant) — host metadata only, like _traces
         self._tenants: dict[int, str | None] = {}
         self.pool = self._init_pool()
+        # the recurrent layers' state, by slot, beside the pool (None
+        # for a model that has none): donated into the step programs
+        # with it and updated in place
+        self.state = self._init_state()
         s, mb = cfg.max_slots, cfg.max_blocks_per_seq
         self.tables = np.full((s, mb), SCRATCH_BLOCK, np.int32)
         self.lengths = np.zeros((s,), np.int32)
@@ -660,8 +691,12 @@ class DecodeEngine:
         self.spill = (SpillTier(cfg.spill_blocks)
                       if cfg.prefix_cache and cfg.spill_blocks > 0
                       else None)
+        # a KV block hit is worth nothing without the recurrent state at
+        # that boundary (ROADMAP M4), so a model with recurrent layers
+        # takes no hits and inserts no blocks: no cache object at all
         self.prefix = (PrefixCache(cfg.block_size, spill=self.spill)
-                       if cfg.prefix_cache else None)
+                       if cfg.prefix_cache and not self.recurrent
+                       else None)
         # cumulative, snapshot-persisted (monotonic across crash-resume
         # like the churn trio): hit blocks mapped at admission, prompt
         # tokens those hits skipped, copy-on-write triggers (0 in
@@ -707,6 +742,10 @@ class DecodeEngine:
         self._step_finite: list[bool] | None = None
         self._step_prefill_uid: int | None = None
         self._step_decode_uids: list[int] = []
+        # recurrent-state bytes this step's decode dispatches read (one
+        # row a ready slot; written back the same size): the engine_step
+        # record's and the digest's ``state_bytes``
+        self._step_state_bytes = 0
         self._dump_reason: str | None = None
         # host phases of the current step (runtime/tracing.py): always
         # stamped, summed into the digest's ``phase_ms``; with a writer
@@ -716,9 +755,21 @@ class DecodeEngine:
 
     # -- pool ----------------------------------------------------------
 
+    def _refuse_recurrent(self, what: str) -> None:
+        """The one line every path that cannot carry a recurrent state
+        refuses with, for a model that has one."""
+        if self.recurrent:
+            raise ValueError(
+                f"{what} is not served for a model with "
+                f"{'/'.join(self.recurrent)} layers: it cannot carry "
+                "their recurrent state yet")
+
     def _init_pool(self) -> PagedKV:
         cfg = self.cfg
-        pool = init_pool(self.params.n_layers, cfg.n_blocks,
+        # the pool's layer axis is the layers that own a KV cache index
+        # (all of an LMParams' layers; a hybrid's attention layers only)
+        kv_layers = sum(kind == ATTN for kind, _ in self.params.layers)
+        pool = init_pool(kv_layers, cfg.n_blocks,
                          self.kv_heads, cfg.block_size, self.dh,
                          cfg.kv_dtype)
         if self.mesh is None:
@@ -729,6 +780,30 @@ class DecodeEngine:
             lambda x, spec: jax.device_put(x, NamedSharding(self.mesh,
                                                             spec)),
             pool, self._pool_specs())
+
+    def _init_state(self) -> RecurrentState | None:
+        if not self.recurrent:
+            return None
+        m = self.params.mamba
+        return init_state(m.w_in.shape[0], self.cfg.max_slots,
+                          d_inner=m.conv_w.shape[2],
+                          d_state=m.a_log.shape[1],
+                          d_conv=m.conv_w.shape[1])
+
+    def _cache(self):
+        """The donated operand of every step program: the pool, and for
+        a model with recurrent layers the pair (pool, recurrent
+        state) — ``_trunk`` takes it apart and puts it together."""
+        return self.pool if self.state is None else (self.pool,
+                                                      self.state)
+
+    def _keep(self, cache) -> None:
+        """Take back what a step program returned in ``_cache()``'s
+        place."""
+        if self.state is None:
+            self.pool = cache
+        else:
+            self.pool, self.state = cache
 
     def _pool_specs(self) -> PagedKV:
         """Heads are contiguous in a stored row's minor axis
@@ -773,18 +848,19 @@ class DecodeEngine:
         self._program("implant", 0)
         return self.compile_count
 
-    def _attn_qkv(self, p: LMParams, l: int, a, positions):
-        """Shared q/k/v projection + rotary for one layer: ``a [N, d]``
+    def _attn_qkv(self, p, l: int, a, positions):
+        """Shared q/k/v projection + rotary for attention layer ``l`` of
+        the model's attention stack: ``a [N, d]``
         -> ``q [N, h_loc, dh], k/v [N, kv_loc, dh]`` (local head counts
         read off the — possibly sharded — weight shapes, the
         ``cached_attn_step`` convention)."""
-        blk = p.blocks
+        blk = p.attn
         dh = self.dh
         h_loc = blk.wq.shape[1] // dh
         kv_loc = blk.wk.shape[1] // dh
-        q = (a @ blk.wq[l].T).reshape(-1, h_loc, dh)
-        k = (a @ blk.wk[l].T).reshape(-1, kv_loc, dh)
-        v = (a @ blk.wv[l].T).reshape(-1, kv_loc, dh)
+        q = mm(a, blk.wq[l]).reshape(-1, h_loc, dh)
+        k = mm(a, blk.wk[l]).reshape(-1, kv_loc, dh)
+        v = mm(a, blk.wv[l]).reshape(-1, kv_loc, dh)
         if self.cfg.use_rope:
             rot = jax.vmap(lambda x, pos: rope(x[:, None, :],
                                                pos[None])[:, 0, :])
@@ -792,44 +868,73 @@ class DecodeEngine:
             k = rot(k, positions)
         return q, k, v
 
-    def _embed(self, p: LMParams, tokens, positions):
+    def _embed(self, p, tokens, positions):
+        if isinstance(p, HybridLMParams):
+            # no position of any kind: the recurrent layers carry order
+            return p.wte[tokens].astype(jnp.float32)
         if self.mesh is not None:
             from ..parallel.lm import vp_embed
             return vp_embed(p.wte, tokens) + p.wpe[positions]
         return p.wte[tokens] + p.wpe[positions]
 
-    def _trunk(self, p: LMParams, pool: PagedKV, x, positions,
-               write_attn):
+    @staticmethod
+    def _norm(p, g, x):
+        """The model's norm with gain ``g``: RMSNorm for the hybrid
+        family, the gain-only LayerNorm for ``LMParams``."""
+        if isinstance(p, HybridLMParams):
+            return hybrid_lm.rmsnorm(g, x, p.eps)
+        return layernorm(g, x)
+
+    def _trunk(self, p, pool, x, positions, write_attn, mix=None):
         """The shared per-layer forward both compiled programs run —
-        ONE definition, so prefill and decode numerics can never drift:
-        LN, q/k/v, then the caller's ``write_attn(l, pool, q, k, v) ->
-        (pool, y [N, h_loc, dh])`` (the only step where the two programs
-        differ: batched single-token writes + per-slot gathers vs one
-        slot's chunk write + chunk attention), output projection, FFN
-        — with the Megatron psums when a mesh is set."""
+        ONE definition, so prefill and decode numerics can never drift.
+        The model says, per layer, which kind it is and which index of
+        its kind's weights and cache it owns (``p.layers``). An
+        attention layer: norm, q/k/v, then the caller's ``write_attn(i,
+        pool, q, k, v) -> (pool, y [N, h_loc, dh])`` (the only step
+        where the two programs differ: batched single-token writes +
+        per-slot gathers vs one slot's chunk write + chunk attention),
+        output projection. A recurrent layer: norm, then the caller's
+        ``mix(i, state, a) -> (state, y [N, d])`` (one token for each
+        row's own state vs a chunk scanned through one slot's). Then
+        the FFN — with the Megatron psums when a mesh is set.
+
+        ``pool`` is the program's donated cache operand
+        (``_cache()``): the ``PagedKV``, or for a model with recurrent
+        layers the pair of it and the ``RecurrentState``; it is
+        returned in the same form."""
         tp = self.mesh is not None
         if tp:
             from ..parallel.collectives import all_reduce
             from ..parallel.mesh import MODEL_AXIS
-        blk = p.blocks
+        state = None
+        if self.recurrent:
+            pool, state = pool
         n = x.shape[0]
-        for l in range(p.n_layers):
-            a = layernorm(blk.ln1[l], x)
-            q, k, v = self._attn_qkv(p, l, a, positions)
-            pool, y = write_attn(l, pool, q, k, v)
-            y = y.reshape(n, -1) @ blk.wo[l].T
+        for l, (kind, i) in enumerate(p.layers):
+            a = self._norm(p, p.norm_in[l], x)
+            if kind == ATTN:
+                q, k, v = self._attn_qkv(p, i, a, positions)
+                pool, y = write_attn(i, pool, q, k, v)
+                y = mm(y.reshape(n, -1), p.attn.wo[i])
+            else:
+                state, y = mix(i, state, a)
             x = x + (all_reduce(y, MODEL_AXIS) if tp else y)
-            h = layernorm(blk.ln2[l], x)
-            f = jnp.maximum(h @ blk.w1[l].T, 0.0) @ blk.w2[l].T
+            h = self._norm(p, p.norm_ff[l], x)
+            if isinstance(p, HybridLMParams):
+                f = hybrid_lm.gated_mlp(p, l, h)
+            else:
+                blk = p.blocks
+                f = jnp.maximum(h @ blk.w1[l].T, 0.0) @ blk.w2[l].T
             x = x + (all_reduce(f, MODEL_AXIS) if tp else f)
-        return pool, x
+        return (pool if state is None else (pool, state)), x
 
-    def _logits(self, p: LMParams, h):
+    def _logits(self, p, h):
         """Tied head; under TP each shard scores its V/n vocab rows and
         the in-graph gather re-assembles the full row so the fused pick
         (keys fold uid/position, never the shard) draws identically
         everywhere — the output is replicated."""
-        logits = h @ p.wte.T
+        logits = mm(h, p.wte)
         if self.mesh is not None:
             from ..parallel.collectives import all_gather
             from ..parallel.mesh import MODEL_AXIS
@@ -892,6 +997,74 @@ class DecodeEngine:
         with jax.named_scope("attn"):
             return decode_attn(q, ck, cv, n_attend)
 
+    def _decode_hidden(self, b: int, p, pool, tables, lengths, tokens,
+                       rows=None):
+        """The decode program up to the head: each of ``b`` rows' input
+        token embedded, written at its own position and attended over
+        its gathered blocks; in a recurrent layer each row's own state
+        (``rows [b]``: its slot's state row, the scratch row for a
+        padded one) advanced by one token. Returns ``(pool, x [b,
+        d])``."""
+        cfg = self.cfg
+        x = self._embed(p, tokens, lengths)             # [b, d]
+        slot_phys = lengths // cfg.block_size
+        off = lengths % cfg.block_size
+
+        def write_attn(l, pool, q, k, v):
+            phys = tables[jnp.arange(b), slot_phys]
+            pool = write_rows(pool, l, phys, off, k, v, cfg.kv_dtype)
+            return pool, self._cached_attn(pool, l, q, tables,
+                                           lengths + 1)
+
+        def mix(i, state, a):
+            with jax.named_scope("ssm"):
+                tail = state.conv[i, rows]
+                y, tail, s = hybrid_lm.mamba_step(
+                    p, i, a, tail.reshape(b, -1, state.ssm.shape[-1]),
+                    state.ssm[i, rows])
+                state = state._replace(
+                    conv=state.conv.at[i, rows].set(tail.reshape(b, -1)),
+                    ssm=state.ssm.at[i, rows].set(s))
+            return state, y
+
+        return self._trunk(p, pool, x, lengths, write_attn, mix)
+
+    def _prefill_hidden(self, c: int, p, pool, table, pos0, tokens,
+                        row=None):
+        """The prefill program up to the head: ``c`` prompt tokens of
+        ONE slot enter the cache through its block table and attend
+        causally over the gathered view; in a recurrent layer the chunk
+        is scanned through the slot's state (``row``: its state row),
+        which is zero at position 0 whatever the row still holds from
+        the sequence before (admission, and the replay after a
+        preemption or a retry: every prefill starts at 0). Returns
+        ``(pool, x [c, d])``."""
+        cfg = self.cfg
+        positions = pos0 + jnp.arange(c)
+        x = self._embed(p, tokens, positions)           # [c, d]
+
+        def write_attn(l, pool, q, k, v):
+            pool = write_chunk(pool, l, table, pos0, k, v,
+                               cfg.kv_dtype)
+            ck, cv = gather_layer(pool, l, table)
+            with jax.named_scope("attn"):
+                y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0)
+            return pool, y.transpose(1, 0, 2)
+
+        def mix(i, state, a):
+            with jax.named_scope("ssm"):
+                fresh = pos0 == 0
+                tail = jnp.where(fresh, 0.0, state.conv[i, row])
+                y, tail, s = hybrid_lm.mamba_chunk(
+                    p, i, a, tail.reshape(-1, state.ssm.shape[-1]),
+                    jnp.where(fresh, 0.0, state.ssm[i, row]))
+                state = state._replace(
+                    conv=state.conv.at[i, row].set(tail.reshape(-1)),
+                    ssm=state.ssm.at[i, row].set(s))
+            return state, y
+
+        return self._trunk(p, pool, x, positions, write_attn, mix)
+
     def _decode_fn(self, b: int):
         """The raw (un-jitted) decode-step body for a ``b``-slot bucket:
         write each slot's input token at its own position, attend over
@@ -916,21 +1089,11 @@ class DecodeEngine:
                          self.params.vocab, cfg.seed)
 
         @jax.named_scope("decode")
-        def run(p: LMParams, pool: PagedKV, tables, lengths, tokens,
-                uids, poison):
-            x = self._embed(p, tokens, lengths)             # [b, d]
-            slot_phys = lengths // cfg.block_size
-            off = lengths % cfg.block_size
-
-            def write_attn(l, pool, q, k, v):
-                phys = tables[jnp.arange(b), slot_phys]
-                pool = write_rows(pool, l, phys, off, k, v, cfg.kv_dtype)
-                return pool, self._cached_attn(pool, l, q, tables,
-                                               lengths + 1)
-
-            pool, x = self._trunk(p, pool, x, lengths, write_attn)
+        def run(p, pool, tables, lengths, tokens, uids, poison, *rows):
+            pool, x = self._decode_hidden(b, p, pool, tables, lengths,
+                                          tokens, *rows)
             with jax.named_scope("head"):
-                logits = self._logits(p, layernorm(p.ln_f, x))
+                logits = self._logits(p, self._norm(p, p.ln_f, x))
             bad = jnp.logical_or(uids == poison, poison == POISON_ALL)
             logits = jnp.where(bad[:, None],
                                jnp.asarray(jnp.nan, logits.dtype), logits)
@@ -969,8 +1132,8 @@ class DecodeEngine:
                          self.params.vocab, cfg.seed)
 
         @jax.named_scope("decode")
-        def run(p: LMParams, pool: PagedKV, tables, lengths, tokens,
-                uids, drafts, dlens, poison):
+        def run(p, pool, tables, lengths, tokens, uids, drafts, dlens,
+                poison):
             rows = jnp.arange(b)
             alive = jnp.ones((b,), bool)
             acc = jnp.zeros((b,), jnp.int32)
@@ -993,7 +1156,7 @@ class DecodeEngine:
 
                 pool, x = self._trunk(p, pool, x, pos, write_attn)
                 with jax.named_scope("head"):
-                    logits = self._logits(p, layernorm(p.ln_f, x))
+                    logits = self._logits(p, self._norm(p, p.ln_f, x))
                 bad = jnp.logical_or(uids == poison,
                                      poison == POISON_ALL)
                 logits = jnp.where(bad[:, None],
@@ -1030,22 +1193,11 @@ class DecodeEngine:
                          self.params.vocab, cfg.seed)
 
         @jax.named_scope("prefill")
-        def run(p: LMParams, pool: PagedKV, table, pos0, tokens, uid,
-                poison):
-            positions = pos0 + jnp.arange(c)
-            x = self._embed(p, tokens, positions)           # [c, d]
-
-            def write_attn(l, pool, q, k, v):
-                pool = write_chunk(pool, l, table, pos0, k, v,
-                                   cfg.kv_dtype)
-                ck, cv = gather_layer(pool, l, table)
-                with jax.named_scope("attn"):
-                    y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0)
-                return pool, y.transpose(1, 0, 2)
-
-            pool, x = self._trunk(p, pool, x, positions, write_attn)
+        def run(p, pool, table, pos0, tokens, uid, poison, *row):
+            pool, x = self._prefill_hidden(c, p, pool, table, pos0,
+                                           tokens, *row)
             with jax.named_scope("head"):
-                h = layernorm(p.ln_f, x[-1:])               # last row
+                h = self._norm(p, p.ln_f, x[-1:])          # last row
                 logits = self._logits(p, h)
             bad = jnp.logical_or(uid == poison, poison == POISON_ALL)
             logits = jnp.where(bad,
@@ -1210,6 +1362,7 @@ class DecodeEngine:
         KV the sync path would have shipped). No handoff event is
         emitted and no span closes until the commit — the sequence has
         not left yet."""
+        self._refuse_recurrent("export_sequence (the KV handoff)")
         if self.mesh is not None:
             raise ValueError(
                 "KV handoff is single-device (the fleet runs "
@@ -1329,6 +1482,7 @@ class DecodeEngine:
         next step — no replay, no prefill dispatch. Model fingerprint
         and the numerics-relevant config keys must match the source's
         (pool-size keys may differ; that is the point of renumbering)."""
+        self._refuse_recurrent("import_sequence (the KV handoff)")
         if self.mesh is not None:
             raise ValueError(
                 "KV handoff is single-device (the fleet runs "
@@ -2491,15 +2645,17 @@ class DecodeEngine:
             fn = self._program("prefill", c)
             chunk = np.asarray(
                 seq.prompt[seq.prefilled:seq.prefilled + c], np.int32)
-            args = (self._params_for(seq.weights_version), self.pool,
+            args = (self._params_for(seq.weights_version), self._cache(),
                     jnp.asarray(self.tables[slot]),
                     jnp.int32(seq.prefilled), jnp.asarray(chunk),
                     jnp.int32(seq.uid), jnp.int32(self._poison_uid))
+            if self.state is not None:
+                args += (jnp.int32(slot),)      # the slot's state row
         self._maybe_capture(fn, *args)
         with phase("prefill.dispatch"):
             pool, nxt, ok = fn(*args)
         with phase("prefill.readback"):
-            self.pool = pool
+            self._keep(pool)
             fine = bool(ok)
             # the pick is read only where the chunk completes the prompt
             pick = (int(nxt) if fine and seq.prefilled + c
@@ -2608,14 +2764,21 @@ class DecodeEngine:
             b, tables, lengths, tokens, uids = self._marshal(ready)
             fn = self._program("decode", b)
         with phase("decode.upload"):
-            args = (params, self.pool, jnp.asarray(tables),
+            args = (params, self._cache(), jnp.asarray(tables),
                     jnp.asarray(lengths), jnp.asarray(tokens),
                     jnp.asarray(uids), jnp.int32(self._poison_uid))
+            if self.state is not None:
+                # each batch row's state row: its slot, and the scratch
+                # row for the bucket's padded rows
+                rows = ready + [self.state.scratch_row] * (b - len(ready))
+                args += (jnp.asarray(rows, jnp.int32),)
+                self._step_state_bytes += (len(ready)
+                                           * self.state.bytes_per_slot)
         self._maybe_capture(fn, *args)
         with phase("decode.dispatch"):
             pool, picks, ok = fn(*args)
         with phase("decode.readback"):
-            self.pool = pool
+            self._keep(pool)
             picks = np.asarray(picks)
             ok = np.asarray(ok)
         with phase("decode.emit"):
@@ -2763,6 +2926,7 @@ class DecodeEngine:
         self._step_finite = None
         self._step_prefill_uid = None
         self._step_decode_uids = []
+        self._step_state_bytes = 0
         with phase("expire"):
             # spill-tier housekeeping: a fresh promotion budget each
             # step (the restore analogue of one-prefill-chunk-per-step),
@@ -2843,6 +3007,7 @@ class DecodeEngine:
             "duration_s": round((end_ns - start_ns) / 1e9, 6),
             "phases": self.phases.stamps,
             "tokens_generated": self.tokens_generated,
+            "state_bytes": self._step_state_bytes,
         }
 
     @property
@@ -2908,7 +3073,7 @@ class DecodeEngine:
         """Live-token KV bytes at the engine's storage dtype — the
         measured form of the roofline's ``B * kv_bytes`` term."""
         return int(self.live_tokens() * kv_bytes_per_token(
-            self.cfg.kv_dtype, self.params.n_layers, self.kv_heads,
+            self.cfg.kv_dtype, self.pool.k.shape[0], self.kv_heads,
             self.dh))
 
     def telemetry_record(self, tokens_per_sec=None) -> dict:
@@ -3019,6 +3184,12 @@ class DecodeEngine:
             "occupancy": round(self.active / self.cfg.max_slots, 4),
             "free_blocks": len(self.free_blocks),
             "waiting": len(self.waiting),
+            # the recurrent state beside the blocks: rows that hold a
+            # resident sequence's state at step end, and the bytes this
+            # step's decode dispatches read of it (0 / 0 for a model
+            # with no recurrent layer)
+            "state_slots": self.active if self.state is not None else 0,
+            "state_bytes": self._step_state_bytes,
             # where the step's host time went up to this digest
             # (runtime/tracing.py PhaseTimer): what an UNTRACED run's
             # ring says about a slow step
@@ -3071,11 +3242,12 @@ class DecodeEngine:
         tables = jnp.full((b, self.cfg.max_blocks_per_seq),
                           SCRATCH_BLOCK, jnp.int32)
         z = jnp.zeros((b,), jnp.int32)
+        rows = () if self.state is None else (z,)
         rep = StepReport.of(self._wrap(self._decode_fn(b)), self.params,
-                            self.pool, tables, z, z, z,
-                            jnp.int32(POISON_NONE))
+                            self._cache(), tables, z, z, z,
+                            jnp.int32(POISON_NONE), *rows)
         per_tok = kv_bytes_per_token(self.cfg.kv_dtype,
-                                     self.params.n_layers,
+                                     self.pool.k.shape[0],
                                      self.kv_heads, self.dh)
         kv_bytes, scale_bytes = pool_bytes(self.pool)
         return {

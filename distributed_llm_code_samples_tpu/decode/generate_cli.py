@@ -58,6 +58,14 @@ def build_generate_parser() -> argparse.ArgumentParser:
                         "pool by heads/kv_heads")
     p.add_argument("--vocab", type=int, default=256)
     p.add_argument("--max_seq_len", type=int, default=256)
+    p.add_argument("--model_config", default=None, metavar="FILE",
+                   help="a published-style config.json (model_type "
+                        "jamba: Mamba and attention layers in one "
+                        "stack, models/hybrid_lm.py). The model comes "
+                        "from its keys, in the type it states; "
+                        "-d/-l/--heads/--kv_heads/--vocab/--max_seq_len "
+                        "are then ignored, the weights come from -r or "
+                        "--weights_from")
     p.add_argument("-r", "--random_seed", type=int, default=0,
                    help="model init seed (the cli.py convention)")
     p.add_argument("--use_rope", action="store_true",
@@ -606,6 +614,21 @@ def generate_main(argv=None) -> int:
     from ..models import init_lm
     from .engine import AdmissionError, DecodeEngine, EngineConfig, \
         ServePolicy
+    from .model_config import engine_from_config, params_from_config
+
+    model_config = None
+    if args.model_config:
+        try:
+            with open(args.model_config) as f:
+                model_config = json.load(f)
+            # the prompts below draw their ids from the model's own
+            # vocabulary, the reservation from its own context
+            args.vocab = int(model_config["vocab_size"])
+            args.max_seq_len = int(
+                model_config["max_position_embeddings"])
+        except (OSError, ValueError, KeyError) as e:
+            print(f"error: --model_config: {e!r}", file=sys.stderr)
+            return 2
 
     n_sources = sum(x is not None for x in
                     (args.prompts, args.prompt_lens, args.trace,
@@ -958,7 +981,18 @@ def generate_main(argv=None) -> int:
         # bits) — so building them here would just double peak host
         # memory for nothing
         params = None
-        if not (args.fleet and args.transport in ("process", "tcp")):
+        if model_config is not None:
+            params = params_from_config(model_config, args.random_seed)
+            kinds = sorted({k for k, _ in params.layers} - {"attn"})
+            if kinds and (args.fleet or args.snapshot_dir):
+                # both move a sequence by its KV blocks alone (handoff,
+                # snapshot-resume): refused up front, by what the model
+                # is, and not mid-serve
+                raise ValueError(
+                    "--fleet / --snapshot_dir are not served for a "
+                    f"model with {'/'.join(kinds)} layers: they cannot "
+                    "carry their recurrent state yet")
+        elif not (args.fleet and args.transport in ("process", "tcp")):
             params = init_lm(jax.random.PRNGKey(args.random_seed),
                              args.vocab, args.model_size, args.layers,
                              max_seq_len=args.max_seq_len,
@@ -1047,6 +1081,13 @@ def generate_main(argv=None) -> int:
         metrics = TelemetryWriter(args.metrics_dir, meta=meta)
 
     mesh_kw = dict(mesh=mesh, policy=policy, qos=qos)
+
+    def make_engine(**kw):
+        if model_config is not None:
+            return engine_from_config(model_config, params,
+                                      engine_config=cfg, **mesh_kw, **kw)
+        return DecodeEngine(params, args.heads, cfg, **mesh_kw, **kw)
+
     shed = 0
     workload = None
     prior_tokens = 0
@@ -1065,7 +1106,7 @@ def generate_main(argv=None) -> int:
                       "flags ignored — the snapshot is authoritative)",
                       file=sys.stderr)
             engine = supervise_decode(
-                lambda: DecodeEngine(params, args.heads, cfg, **mesh_kw),
+                make_engine,
                 [(pr, args.max_new) for pr in prompts],
                 snapshot_dir=args.snapshot_dir, chaos=chaos_plan,
                 watchdog_ms=args.watchdog_ms, metrics=metrics,
@@ -1075,8 +1116,7 @@ def generate_main(argv=None) -> int:
             shed = engine.rejected
         elif trace_doc is not None:
             from .workload_driver import replay_trace
-            engine = DecodeEngine(params, args.heads, cfg,
-                                  metrics=metrics, **mesh_kw)
+            engine = make_engine(metrics=metrics)
             workload = replay_trace(
                 engine, *trace_doc, vocab=args.vocab,
                 pace=args.trace_pace or "virtual",
@@ -1086,8 +1126,7 @@ def generate_main(argv=None) -> int:
                 log_every=args.log_every, metrics=metrics)
             shed = workload["shed"]
         else:
-            engine = DecodeEngine(params, args.heads, cfg,
-                                  metrics=metrics, **mesh_kw)
+            engine = make_engine(metrics=metrics)
             for pr in prompts:
                 try:
                     engine.submit(pr, args.max_new)

@@ -1,0 +1,54 @@
+"""An engine from a published-style ``config.json``.
+
+ONE function builds the engine for ``train_ffns.py generate
+--model_config FILE`` and for the benchmark's driver file
+(``benchmark/configs/jamba_engine_driver.py``): the published keys say
+what the model is (``models/hybrid_lm.py::spec_from_config``), the
+weights come from a seed or from the caller (a checkpoint restored into
+the seeded tree, the benchmark's own arrays), and every engine tunable
+keeps the program's default unless the caller's ``EngineConfig`` says
+otherwise.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..models import hybrid_lm
+from .engine import DecodeEngine, EngineConfig
+
+DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
+
+
+def weights_dtype(config: dict):
+    """The type the configuration states its weights in
+    (``precision.weights`` as the benchmark's files write it, or the
+    published ``torch_dtype``); float32 where it states none."""
+    name = (config.get("precision", {}).get("weights")
+            or config.get("torch_dtype") or "float32")
+    if name not in DTYPES:
+        raise ValueError(f"weights type {name!r} not in {sorted(DTYPES)}")
+    return DTYPES[name]
+
+
+def params_from_config(config: dict, seed: int = 0):
+    """Seeded weights of the configured model, in its stated type."""
+    spec = hybrid_lm.spec_from_config(config)
+    seed = int(seed)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF),
+                             seed >> 31)
+    return hybrid_lm.init_hybrid_lm(
+        key, spec, dtype=weights_dtype(config),
+        scale=float(config.get("initializer_range", 2e-2)))
+
+
+def engine_from_config(config: dict, params=None, *, seed: int = 0,
+                       engine_config: EngineConfig | None = None,
+                       **engine_kw) -> DecodeEngine:
+    """``DecodeEngine`` for the model ``config`` describes, over
+    ``params`` (default: seeded from ``seed``)."""
+    if params is None:
+        params = params_from_config(config, seed)
+    return DecodeEngine(params, int(config["num_attention_heads"]),
+                        engine_config, **engine_kw)

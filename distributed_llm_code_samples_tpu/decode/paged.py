@@ -49,6 +49,13 @@ Quantization (``kv_dtype``):
   sequence's write history, so continuous batching stays token-identical
   to sequential decode at any dtype (tests/test_decode_engine.py).
 
+The pool's layer axis counts the layers that own a KV cache index: all
+of an ``LMParams``' layers, the attention layers only of a hybrid
+(``models/hybrid_lm.py``: 2 of 28). What such a model's other layers
+carry is the second kind of per-sequence state, ``RecurrentState``: it
+does not grow with the sequence, so it is indexed by slot and not
+through a block table, and lives beside the pool.
+
 All functions are pure jnp with static shapes; the layer index is a
 Python int (the engine unrolls layers at trace time, like
 ``models.lm.decode_step``).
@@ -100,6 +107,52 @@ class PagedKV:
     def kv_heads(self) -> int:
         """KV heads in a row — the LOCAL count inside a TP shard."""
         return self.k.shape[3] // self.head_dim
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["conv", "ssm"], meta_fields=[])
+@dataclasses.dataclass(frozen=True)
+class RecurrentState:
+    """The second kind of per-sequence state: what a recurrent
+    (state-space) layer carries, which does not grow with the sequence.
+    Indexed by SLOT, not through a block table: ``conv [L_r, slots+1,
+    (K-1)*D]`` the convolution's last ``K-1`` inputs, oldest first, and
+    ``ssm [L_r, slots+1, N, D]`` the scan state (``ops/ssm.py``), both
+    float32, ``L_r`` the model's recurrent layers. The inner width is
+    the minor axis of both, so the chip keeps them unpadded (a ``[D,
+    N]`` state would pad ``N = 16`` up to 128 lanes). Row ``slots`` is
+    the scratch row — the pool's idiom: padded bucket rows read and
+    write it, nothing else does. A slot's row is never cleared: the
+    prefill program takes zeros in its place at position 0. Donated
+    into the step programs beside the pool and updated in place."""
+    conv: jax.Array
+    ssm: jax.Array
+
+    def _replace(self, **fields) -> "RecurrentState":
+        return dataclasses.replace(self, **fields)
+
+    @property
+    def scratch_row(self) -> int:
+        return self.ssm.shape[1] - 1
+
+    @property
+    def bytes_per_slot(self) -> int:
+        """Bytes one sequence's state holds over all recurrent layers
+        (what a decode dispatch reads, and writes, for each ready
+        slot)."""
+        return int((self.conv.nbytes + self.ssm.nbytes)
+                   // self.ssm.shape[1])
+
+
+def init_state(n_layers: int, slots: int, d_inner: int, d_state: int,
+               d_conv: int) -> RecurrentState:
+    """Zero-filled recurrent state for ``slots`` sequences (+ the
+    scratch row) over ``n_layers`` recurrent layers."""
+    rows = slots + 1
+    return RecurrentState(
+        conv=jnp.zeros((n_layers, rows, (d_conv - 1) * d_inner),
+                       jnp.float32),
+        ssm=jnp.zeros((n_layers, rows, d_state, d_inner), jnp.float32))
 
 
 def _heads_major(x, head_dim: int):

@@ -63,6 +63,29 @@ class LMParams(NamedTuple):
         return (self.wte.size + self.wpe.size + self.ln_f.size +
                 self.blocks.num_params())
 
+    # What the decode engine's trunk asks of a model
+    # (``decode/engine.py::_trunk``; ``models/hybrid_lm.py`` answers the
+    # same for a stack of mixed layer kinds): per layer its kind and the
+    # index of its weights and cache, the attention stack, the gains of
+    # the norm before each mixer and each FFN.
+    @property
+    def layers(self) -> tuple:
+        """Every layer is attention and owns the cache index of its
+        own number."""
+        return tuple(("attn", l) for l in range(self.n_layers))
+
+    @property
+    def attn(self) -> TransformerParams:
+        return self.blocks
+
+    @property
+    def norm_in(self) -> jax.Array:
+        return self.blocks.ln1
+
+    @property
+    def norm_ff(self) -> jax.Array:
+        return self.blocks.ln2
+
     # The CLI's uniform per-layer report reads ``.w1``/``.w2``
     # (train_ffns.py:370-371 prints layers_params[0]); delegate to the
     # block stack's FFN pair.

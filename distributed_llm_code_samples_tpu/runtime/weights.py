@@ -70,7 +70,7 @@ def model_fingerprint(params, n_heads: int) -> dict:
         "n_layers": int(params.n_layers),
         "max_seq_len": int(params.max_seq_len),
         "n_heads": int(n_heads),
-        "kv_heads": int(params.blocks.wk.shape[1] // dh),
+        "kv_heads": int(params.attn.wk.shape[1] // dh),
         "wte0_sum": round(float(jnp.sum(params.wte[0])), 2),
     }
 

@@ -322,6 +322,109 @@ def test_step_program_keeps_the_pool_as_stored(one_chip,
     assert m.argument_size_in_bytes - logical < pool_bytes // 100
 
 
+# the hybrid serving cell of the benchmark (jamba2-3b.reasoning-offline):
+# AI21-Jamba2-3B's widths, 64 slots x 2048 positions, bf16 weights and KV,
+# float32 recurrent state, cut to 4 layers (one attention) so the compile
+# stays short
+JAMBA = dict(layers=4, period=4, offset=1, slots=64, mbps=128, chunk=16)
+
+
+@pytest.fixture(scope="module")
+def jamba_engine_args():
+    """``(engine, {kind: (bucket, args)})`` at the hybrid cell's widths,
+    built as ``benchmark/configs/jamba_engine_driver.py`` builds it: the
+    configuration's own file, through ``engine_from_config``."""
+    import json
+    from distributed_llm_code_samples_tpu.decode import EngineConfig
+    from distributed_llm_code_samples_tpu.decode.model_config import (
+        engine_from_config)
+    g = JAMBA
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "jamba2-3b-serve.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=g["layers"],
+                      attn_layer_period=g["period"],
+                      attn_layer_offset=g["offset"])
+    slots, mbps, chunk = g["slots"], g["mbps"], g["chunk"]
+    eng = engine_from_config(config, seed=7, engine_config=EngineConfig(
+        n_blocks=1 + slots * mbps, max_slots=slots,
+        max_blocks_per_seq=mbps, prefill_chunk=chunk, kv_dtype="bf16"))
+    decode, prefill = _step_args(eng.params, eng._cache(), slots, mbps,
+                                 chunk)
+    i32 = jnp.int32
+    return eng, {"decode": (slots, decode + (np.zeros((slots,), i32),)),
+                 "prefill": (chunk, prefill + (i32(0),))}
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_hybrid_step_program_keeps_the_state_as_stored(one_chip,
+                                                       jamba_engine_args,
+                                                       kind):
+    """The recurrent state beside the pool (``decode/paged.py::
+    RecurrentState``, inner width minor) is to a step program what the
+    pool is: taken as it is stored, row-major and all but unpadded,
+    updated in place (aliased whole, with the pool), and never copied or
+    sliced out whole — a decode batch gathers its rows, a prefill chunk
+    slices its one. (The compiler may stage the store through its fast
+    memory, ``copy-start`` / ``copy-done``; that is no second copy in
+    HBM and is not counted.)"""
+    eng, programs = jamba_engine_args
+    bucket, args = programs[kind]
+    compiled = eng._program(kind, bucket).lower(
+        *_shapes_of(args, one_chip)).compile()
+    pool, state = eng.pool, eng.state
+    assert pool.k.shape[0] == 1 and state.ssm.shape[0] == 3
+    moved = [r for r in _hlo_results(
+        compiled.as_text(), ("copy", "slice", "dynamic-slice"), "f32")
+        if r[1] >= min(state.conv.size, state.ssm.size)]
+    assert not moved, moved
+    cache_formats = compiled.input_formats[0][1]
+    for fmt, arr in ((cache_formats[1].conv, state.conv),
+                     (cache_formats[1].ssm, state.ssm),
+                     (cache_formats[0].k, pool.k)):
+        assert fmt.layout.major_to_minor == tuple(range(arr.ndim)), fmt
+    m = compiled.memory_analysis()
+    held = (pool.k.nbytes + pool.v.nbytes + state.conv.nbytes
+            + state.ssm.nbytes)
+    assert m.alias_size_in_bytes >= held
+    # unpadded but for the convolution tail's 65 rows (8-row tiles: 72)
+    logical = sum(x.nbytes for x in jax.tree_util.tree_leaves(args))
+    assert m.argument_size_in_bytes - logical < state.conv.nbytes // 8
+    assert _total_bytes(compiled) < HBM_V5E
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_hybrid_cell_rehearsal_on_the_cpu(monkeypatch, trace):
+    """The new cell's whole control flow on the CPU at toy size, as
+    ``benchmark/tests/test_rehearsal.py`` rehearses the older cells
+    (its ``shrink.py`` knows those only; the hybrid cell's shrink is
+    ``benchmark/tests/shrink_jamba.py``). Nothing here is a measurement."""
+    import json
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmark import flops, run
+    from benchmark.tests import shrink_jamba
+    real = flops.peaks
+    monkeypatch.setattr(flops, "peaks", lambda kind: real("TPU v5 lite"))
+    name = "jamba2-3b.reasoning-offline"
+    line = run.run_cell(name, 2**31 + 4242, 1.5, bool(trace),
+                        check_device=False, shrink=shrink_jamba.serve)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    if not trace:
+        assert set(line["metrics"]) == {"out_tokens_per_s", "setup_s"}
+    else:
+        listed = {m["name"]: m for m in bench["per_layer"]}
+        for metric, body in line["metrics"].items():
+            assert name in listed[metric].get("workloads", [name])
+            assert body["unit"] == listed[metric]["unit"]
+        # the program's counter has its reader (device metrics need a
+        # device trace: none on the CPU)
+        assert line["metrics"]["state_bytes_live"]["value"] > 0
+
+
 def test_train_single_step_compiles_at_paper_width(one_chip):
     """Method 1 at the paper's width (d=8192, one layer, 8x1024 tokens,
     2 GiB of f32 parameters): the whole 8-step program fits one v5e."""

@@ -72,7 +72,8 @@ def _assert_result_line(rec: dict, count: int) -> None:
 
 def test_default_phases_at_toy_size(tmp_path):
     """serve (twice, same tokens, nothing compiled the second time,
-    report rc 0) -> fused vs gather -> train at both shapes; every
+    report rc 0) -> fused vs gather -> the toy hybrid against the plain
+    reference -> train at both shapes; every
     stdout line is one JSON object and the last is the fixed one. The
     interpreter's kernel carries no Mosaic call, so the marker the
     fused phase looks for is stood in for here — the next test pins
@@ -84,8 +85,8 @@ def test_default_phases_at_toy_size(tmp_path):
         "serve_gather", "serve_gather_again", "serve_report",
         "serve_f32_gather_default_precision",
         "serve_f32_fused_default_precision",
-        "serve_f32_gather", "serve_f32_fused", "train_single_0",
-        "train_single_1", "total"]
+        "serve_f32_gather", "serve_f32_fused", "serve_hybrid",
+        "train_single_0", "train_single_1", "total"]
     _assert_result_line(recs[-1], 1)
     again = recs[1]
     assert again["cache_misses"] == 0 and again["tokens"] == 4 * 6
@@ -94,7 +95,9 @@ def test_default_phases_at_toy_size(tmp_path):
     assert [recs[i]["matmul_precision"] for i in (4, 6)] == ["default",
                                                              "highest"]
     assert recs[6]["first_difference_from_gather"] is None
-    assert all(m > 0 for x in recs[7:9] for m in x["mfu"])
+    hybrid = recs[7]
+    assert hybrid["tokens"] == 5 * 24 and hybrid["tokens_compared"] > 100
+    assert all(m > 0 for x in recs[8:10] for m in x["mfu"])
     assert "devices: platform=cpu" in r.stderr     # each entry says where
 
 

@@ -260,7 +260,9 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
         assert len(sink.steps()) == n
         assert set(rec) == {"uid", "span", "start_step", "step",
                             "start_ns", "end_ns", "t", "duration_s",
-                            "phases", "tokens_generated"}
+                            "phases", "tokens_generated",
+                            "state_bytes"}
+        assert rec["state_bytes"] == 0      # no recurrent layer here
         assert rec["step"] == eng.global_step == eng.flight[-1]["step"]
         assert rec["tokens_generated"] == eng.tokens_generated
         n_pre = eng.prefill_dispatches - pre
