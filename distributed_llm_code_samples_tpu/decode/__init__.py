@@ -15,7 +15,7 @@ from .paged import (KV_DTYPES, PagedKV, SCRATCH_BLOCK, copy_block,
                     corrupt_block, extract_blocks, fused_decode_attn,
                     gather_layer, implant_block, init_pool,
                     kv_bytes_per_token, pool_bytes, scrub_blocks,
-                    write_chunk, write_rows)
+                    stored_decode_attn, write_chunk, write_rows)
 from .prefix import PrefixCache, PrefixNode
 from .sampling import check_sampling, check_speculation, make_pick
 from .supervise import (SNAPSHOT_FILENAME, load_snapshot,
@@ -36,7 +36,7 @@ __all__ = [
     "gather_layer", "implant_block", "init_pool",
     "kv_bytes_per_token", "pool_bytes",
     "PrefixCache", "PrefixNode",
-    "scrub_blocks", "write_chunk", "write_rows",
+    "scrub_blocks", "stored_decode_attn", "write_chunk", "write_rows",
     "check_sampling", "check_speculation", "make_pick",
     "SNAPSHOT_FILENAME", "load_snapshot", "restore_engine_state",
     "snapshot_state", "supervise_decode", "write_snapshot",

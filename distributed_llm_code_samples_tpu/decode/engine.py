@@ -97,11 +97,12 @@ attacks on per-token cost):
   the live n-gram drafter only.
 - **Fused paged-attention kernel** (``EngineConfig(kernel="fused")``):
   the decode/verify cache read runs the Pallas block-table walk
-  (``ops/pallas_paged_attention.py``) instead of the gather →
-  ``decode_attn`` two-pass — pool bytes cross the bus once, at the
-  storage dtype, int8 dequant folded in. The gather path stays the
-  differential oracle: on the CPU interpreter within a stated ULP
-  bound of it and token-identical through the engine; on the chip
+  (``ops/pallas_paged_attention.py``) instead of the gather and the
+  two products over the gathered rows — pool bytes cross the bus once,
+  at the storage dtype, int8 dequant folded in. ``gather_layer`` →
+  ``decode_attn`` stays the differential oracle of both: the walk is on
+  the CPU interpreter within a stated ULP bound of it and
+  token-identical to the gather engine; on the chip
   token-identical at float32 matmul precision only — at the default
   precision the two diverge on near-tied logits (the kernel module's
   docstring has the per-backend contract).
@@ -181,7 +182,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..models import hybrid_lm
 from ..models.attention import chunk_attn, rope
 from ..models.hybrid_lm import ATTN, HybridLMParams, mm
-from ..models.lm import LMParams, decode_attn
+from ..models.lm import LMParams
 from ..ops.norm import layernorm
 from ..parallel import launcher
 from ..runtime.guardrails import rows_finite
@@ -198,7 +199,8 @@ from .paged import (PagedKV, RecurrentState, SCRATCH_BLOCK, copy_block,
                     _pool_corrupt_block, extract_blocks,
                     fused_decode_attn, gather_layer, implant_block,
                     init_pool, init_state, kv_bytes_per_token, pool_bytes,
-                    scrub_blocks, write_chunk, write_rows)
+                    scrub_blocks, stored_decode_attn, write_chunk,
+                    write_rows)
 from .prefix import PrefixCache
 from .spill import SpillTier
 from .sampling import check_sampling, check_speculation, make_pick
@@ -328,8 +330,8 @@ class EngineConfig:
     dispatch becomes a ``speculate+1``-token verify program emitting
     the accepted greedy prefix (requires ``temperature == 0``;
     ``decode/draft.py``). ``kernel`` selects the cache-read path for
-    decode/verify steps: ``"gather"`` (two-pass oracle:
-    ``gather_paged_kv`` then ``decode_attn``) or ``"fused"`` (the
+    decode/verify steps: ``"gather"`` (each slot's blocks gathered and
+    attended as stored: ``paged.stored_decode_attn``) or ``"fused"`` (the
     Pallas block-table walk, single-device only — prefill keeps its
     chunked gather attention either way). ``prefix_cache`` enables the
     shared-prefix radix cache (``decode/prefix.py``) — host-side only,
@@ -978,24 +980,28 @@ class DecodeEngine:
         return jax.jit(self._wrap(run, n_aux, n_out), donate_argnums=(1,))
 
     def _cached_attn(self, pool: PagedKV, l: int, q, tables, n_attend):
-        """One single-query attention over the block-table cache — the
-        ``kernel=`` knob. ``gather``: materialize each slot's
-        contiguous view (``gather_layer``, the "gather" scope) and run
-        ``decode_attn`` — the differential oracle. ``fused``: the
-        Pallas block-table walk (``ops/pallas_paged_attention.py``),
-        dequant folded in, no gathered layout in HBM — held to the
-        oracle per backend as the kernel module states (no token
+        """One single-query attention over the block-table cache, for
+        the decode and the speculation verify programs (and a TP
+        shard's local heads) — the ``kernel=`` knob. ``gather``: each
+        slot's blocks gathered as they are stored and attended as two
+        matrix products over whole rows in the pool's dtype
+        (``paged.stored_decode_attn``: no f32 copy of the view, no
+        split into heads). ``fused``: the Pallas block-table walk
+        (``ops/pallas_paged_attention.py``), dequant folded in, no
+        gathered layout in HBM. Both are held to ONE differential
+        oracle, ``decode_attn(q, *vmap(gather_layer))`` — what the
+        lockstep ``generate`` computes and the prefill chunk still
+        reads through (``gather_layer`` + ``chunk_attn``); the engine's
+        decode side does not run it. ``gather`` agrees with it to the
+        pool's operand precision (``tests/test_paged_layout.py``),
+        ``fused`` per backend as the kernel module states (no token
         identity on the chip at the default matmul precision).
-        ``n_attend [b]`` is the
-        per-slot attendable-position count (always >= 1)."""
+        ``n_attend [b]`` is the per-slot attendable-position count
+        (always >= 1)."""
         if self.cfg.kernel == "fused":
             with jax.named_scope("attn"):
                 return fused_decode_attn(pool, l, q, tables, n_attend)
-        ck, cv = jax.vmap(
-            lambda t, _l=l, _pool=pool: gather_layer(_pool, _l, t)
-        )(tables)                           # [b, Hkv_loc, T_cap, dh]
-        with jax.named_scope("attn"):
-            return decode_attn(q, ck, cv, n_attend)
+        return stored_decode_attn(pool, l, q, tables, n_attend)
 
     def _decode_hidden(self, b: int, p, pool, tables, lengths, tokens,
                        rows=None):

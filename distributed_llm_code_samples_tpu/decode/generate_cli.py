@@ -137,8 +137,9 @@ def build_generate_parser() -> argparse.ArgumentParser:
                         "0; a step emits 1 + accepted tokens; 0 = off)")
     p.add_argument("--kernel", choices=["gather", "fused"],
                    default="gather",
-                   help="decode attention path: 'gather' (two-pass "
-                        "oracle) or 'fused' (Pallas block-table walk, "
+                   help="decode attention path: 'gather' (each slot's "
+                        "blocks gathered and attended as stored, two MXU "
+                        "products) or 'fused' (Pallas block-table walk, "
                         "single-device; ops/pallas_paged_attention.py)")
     # shared-prefix KV reuse (round 13, DESIGN.md section 19)
     p.add_argument("--prefix_cache", default=True,

@@ -8,9 +8,12 @@ PagedAttention-style answer in the repo's first-principles idiom: the
 cache is a static-shape **pool of fixed-size blocks**
 (``k/v [L, n_blocks, block, H_kv*dh]``) and each sequence names its
 blocks through a per-slot int32 **block table** — the KV read is a
-gather (``models.attention.gather_paged_kv``), the write is a scatter,
-and freeing a sequence is a host-side table edit. Shapes never depend
-on sequence length, so one compiled decode step serves every occupancy.
+gather (the decode programs attend over the gathered rows as stored,
+``stored_decode_attn``; ``models.attention.gather_paged_kv`` is the
+head-split f32 view of the oracle and the prefill chunk), the write is
+a scatter, and freeing a sequence is a host-side table edit. Shapes
+never depend on sequence length, so one compiled decode step serves
+every occupancy.
 
 The stored form is the one the chip keeps as it is. A token's row holds
 all its KV heads side by side (``H_kv*dh`` lanes, head ``h`` at
@@ -39,8 +42,9 @@ read from it unmasked.
 Quantization (``kv_dtype``):
 
 - ``"f32"`` — exact; the bit-for-bit baseline.
-- ``"bf16"`` — cast on write, upcast on read (exact mantissa truncation;
-  2x fewer KV bytes).
+- ``"bf16"`` — cast on write; the decode read multiplies the rows as
+  bf16 operands under f32 accumulation, the oracle view upcasts them
+  (2x fewer KV bytes).
 - ``"int8"`` — symmetric per-(layer, block, kv-head) scales
   (``k_scale/v_scale [L, n_blocks, H_kv]`` f32, ``scale = amax/127``).
   A write re-quantizes the touched block over its *valid* rows only
@@ -530,12 +534,84 @@ def fused_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                              ks, vs, tables, lengths, interpret=interpret)
 
 
+def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
+                       tables: jax.Array, lengths: jax.Array) -> jax.Array:
+    """Single-query attention for one layer over the gathered rows AS
+    STORED — what the engine's decode-side programs run
+    (``kernel="gather"``). ``q [B, H, dh]`` f32, ``tables [B, MB]``
+    int32, ``lengths [B]`` attendable positions; returns ``[B, H, dh]``
+    f32. The same mathematics as the oracle ``decode_attn(q,
+    *vmap(gather_layer), lengths)`` — same mask, scale and f32 softmax —
+    as two matrix products over ``[B, T_cap, H_kv*dh]`` in the pool's
+    dtype, accumulated in f32:
+
+    - the query is scattered into its KV head's lane group, ``qbd[b,
+      k*dh+d, (k,g)] = q[b, (k,g), d]`` and zero elsewhere, so
+      ``scores[b,h,t] = sum_j K[b,t,j] * qbd[b,j,h]`` runs over the
+      whole stored row (one KV head: ``qbd`` is just ``q^T``);
+    - ``full[b,h,j] = sum_t p[b,h,t] * V[b,t,j]``, of which head ``h``
+      keeps its own KV head's ``dh`` lanes.
+
+    The small operands (``qbd``, the probabilities) are brought to the
+    rows' dtype — bf16 operands under f32 accumulation for a bf16 pool,
+    f32 for an f32 one; int8 codes are exact in bf16 and their
+    per-(block, KV-head) scales multiply the small side (the scores
+    after the product, the probabilities before it). The oracle splits
+    each row into heads in f32: on the chip a ``[B, T_cap, H_kv, dh]``
+    f32 copy of the view, padded where ``dh`` < 128 lanes, and a
+    matrix-vector product over it on the vector unit. Here nothing of
+    the gathered view's size is written after the gather — no cast, no
+    head split, no transpose of the cache, no dequantized copy
+    (``tests/test_chip_compile.py`` pins the compiled program). Against
+    the oracle: f32 pools to reduction order; otherwise to two bf16
+    roundings of the small operands (``tests/test_paged_layout.py``
+    states the bound). Stale bytes beyond ``lengths`` meet a
+    probability that is exactly 0, as in the oracle (and a NaN there
+    still poisons the row: ``corrupt_block``)."""
+    b, h, dh = q.shape
+    hkv, blk = pool.kv_heads, pool.block_size
+    g = h // hkv
+    with jax.named_scope("gather"):
+        layers = jnp.full_like(tables, layer)   # the layer rides in the indices
+        k = pool.k[layers, tables].reshape(b, -1, hkv * dh)
+        v = pool.v[layers, tables].reshape(b, -1, hkv * dh)
+        if pool.k_scale is not None:
+            # per-block scales -> per (query head, position): [B, H, T]
+            def per_head(scale):
+                s = scale[layers, tables].transpose(0, 2, 1)   # [B, Hkv, MB]
+                return jnp.repeat(jnp.repeat(s, g, axis=1), blk, axis=2)
+            ks, vs = per_head(pool.k_scale), per_head(pool.v_scale)
+    dt = k.dtype if jnp.issubdtype(k.dtype, jnp.floating) else jnp.bfloat16
+    with jax.named_scope("attn"):
+        qt = q.reshape(b, hkv, g, dh).transpose(0, 1, 3, 2)
+        qbd = jnp.where(jnp.eye(hkv, dtype=bool)[:, None, :, None],
+                        qt[:, :, :, None, :], 0)        # [B, k, d, K, g]
+        s = jnp.einsum("btj,bjh->bht", k.astype(dt),
+                       qbd.reshape(b, hkv * dh, h).astype(dt),
+                       preferred_element_type=jnp.float32)
+        if pool.k_scale is not None:
+            s = s * ks
+        s = s / jnp.sqrt(jnp.asarray(dh, jnp.float32))
+        mask = jnp.arange(k.shape[1]) < lengths[:, None, None]
+        p = jax.nn.softmax(jnp.where(mask, s, jnp.float32(-1e30)), axis=-1)
+        if pool.k_scale is not None:
+            p = p * vs
+        full = jnp.einsum("bht,btj->bhj", p.astype(dt), v.astype(dt),
+                          preferred_element_type=jnp.float32)
+        y = jnp.einsum("bkgkd->bkgd", full.reshape(b, hkv, g, hkv, dh))
+    return y.reshape(b, h, dh)
+
+
 def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
     """One sequence's dequantized contiguous KV view for one layer:
     ``table [max_blocks]`` -> ``(k, v)`` each ``[H_kv, T_cap, dh]`` f32
     (``T_cap = max_blocks * block``). The gather itself is
     ``models.attention.gather_paged_kv`` — the attention read against a
-    block table; this wrapper only adds the dtype story."""
+    block table; this wrapper only adds the dtype story. With
+    ``decode_attn`` this is the ORACLE the tests hold
+    ``stored_decode_attn`` and the Pallas walk to; in the engine only
+    the prefill chunk reads through it (one slot's view), the decode
+    side attends over the rows as stored."""
     from ..models.attention import gather_paged_kv
     # "gather" tags the block-table read + dequant in traces/HLO
     # (utils/trace_analysis SCOPES: decode/gather, prefill/gather) —

@@ -393,6 +393,60 @@ def test_hybrid_step_program_keeps_the_state_as_stored(one_chip,
     assert _total_bytes(compiled) < HBM_V5E
 
 
+def _entry_results(hlo: str):
+    """``(op, bytes an element, elements)`` of every array the entry
+    computation's own instructions produce (a tuple's members each; the
+    fused computations' insides are not results anyone stores)."""
+    import re
+    entry = hlo[hlo.index("\nENTRY "):]
+    line = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) ([\w\-]+)\(", re.M)
+    shape = re.compile(r"\b[a-z]+(\d+)\[([\d,]+)\]")
+    return [(m.group(2), int(bits) // 8,
+             int(np.prod([int(x) for x in dims.split(",")])))
+            for m in line.finditer(entry)
+            for bits, dims in shape.findall(m.group(1))]
+
+
+@pytest.mark.parametrize("fixture", ["gpt2_large_engine_args",
+                                     "jamba_engine_args"])
+def test_decode_program_reads_the_gathered_rows_as_stored(one_chip, request,
+                                                          fixture):
+    """The decode program attends over each slot's gathered blocks in
+    the form and dtype the pool stores them (``decode/paged.py::
+    stored_decode_attn``): beyond the gather itself, no instruction of
+    either serving cell's decode program produces an array of a
+    gathered view's elements (``slots * T_cap * H_kv*dh``) or more that
+    is wider than the stored dtype, and none transposes or copies one.
+    As for the pool's stored form, this stands in a counter's place:
+    the arithmetic does not engage sometimes. (The parent of PR 28
+    fails this for GPT-2 large with two ``reshape
+    f32[12,1024,20,64]`` a layer — each slot's rows cast to f32 and
+    split into heads of 64 lanes, written padded to 128 — and passes
+    for the hybrid, whose one KV head of 128 lanes it already read as
+    stored. ``temp_size_in_bytes`` of this 2-layer GPT-2 program: 152
+    MB on the parent, 0.8 MB now; one layer's attention alone 151 MB
+    against 0.)"""
+    eng, programs = request.getfixturevalue(fixture)
+    bucket, args = programs["decode"]
+    compiled = eng._program("decode", bucket).lower(
+        *_shapes_of(args, one_chip)).compile()
+    pool = eng.pool
+    view = (bucket * eng.cfg.max_blocks_per_seq * pool.block_size
+            * pool.k.shape[-1])
+    passed = ("parameter", "get-tuple-element", "tuple", "bitcast")
+    held = {(x.dtype.itemsize, x.size)              # updated in place
+            for x in jax.tree_util.tree_leaves(args[:2])}
+    big = [r for r in _entry_results(compiled.as_text())
+           if r[2] >= view and r[0] not in passed and r[1:] not in held]
+    assert big, "the gather's own results are of the view's size"
+    wide = [r for r in big if r[1] > pool.k.dtype.itemsize]
+    assert not wide, wide
+    moved = [r for r in big if r[0] in ("transpose", "copy")]
+    assert not moved, moved
+    if fixture == "gpt2_large_engine_args":
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 22
+
+
 @pytest.mark.parametrize("trace", [0, 1])
 def test_hybrid_cell_rehearsal_on_the_cpu(monkeypatch, trace):
     """The new cell's whole control flow on the CPU at toy size, as

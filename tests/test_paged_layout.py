@@ -7,17 +7,22 @@ reads, copies or exports the pool is held here to a plain model —
 rows come back from the gather at the right (position, head), a block
 document is still head-major ``[L, n, H_kv, block, dh]`` and implants
 bit-identically, and the block-level edits touch the named block in
-every layer and nothing else.
+every layer and nothing else. The decode programs' attention over the
+gathered rows as stored (``stored_decode_attn``) is held to the oracle
+``decode_attn(q, *vmap(gather_layer))`` and to a NumPy model of its own
+arithmetic.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from distributed_llm_code_samples_tpu.decode.paged import (
-    KV_DTYPES, copy_block, copy_block_rows, corrupt_block, extract_blocks,
-    gather_layer, implant_block, init_pool, scrub_blocks, write_chunk,
-    write_rows)
+    KV_DTYPES, _quantize, _rows_major, copy_block, copy_block_rows,
+    corrupt_block, extract_blocks, gather_layer, implant_block, init_pool,
+    scrub_blocks, stored_decode_attn, write_chunk, write_rows)
+from distributed_llm_code_samples_tpu.models.lm import decode_attn
 
 L, NB, HKV, BLK, DH = 2, 7, 3, 4, 8
 
@@ -205,3 +210,144 @@ def test_block_edit_touches_exactly_the_named_block(op, kv_dtype):
     # something to change in every layer
     assert all(np.asarray(pool.k[layer, b]).any()
                for layer in range(L) for b in (1, 3, 5))
+
+
+# ---------------------------------------------------------------------
+# the decode programs' attention over the rows as stored, against the
+# oracle: (query heads, KV heads, head dim) of GPT-2 large's MHA, a GQA
+# group and the hybrid's 20 heads over one KV head of 128 lanes
+ATTN_SHAPES = {"mha20x64": (20, 20, 64), "gqa8over2": (8, 2, 16),
+               "mqa20over1x128": (20, 1, 128)}
+A_B, A_MB, A_BLK = 4, 5, 8
+# ragged: one position, mid-block, the whole capacity, a block boundary
+A_LENGTHS = np.asarray([1, A_BLK + 3, A_MB * A_BLK, 2 * A_BLK], np.int32)
+
+
+def _bf16(x):
+    return np.asarray(jnp.asarray(x, jnp.bfloat16).astype(jnp.float32))
+
+
+def _attn_case(shape, kv_dtype, stale, seed=0):
+    """A one-layer pool of ``A_B`` sequences with disjoint tables,
+    random content at positions below ``A_LENGTHS`` and ``stale`` beyond
+    (``"large"``: the largest finite bytes a freed sequence could leave,
+    ``"zero"``). Returns the pool, ``q``, the tables and, for the model,
+    the stored values ``[b, H_kv, T, dh]`` as floats with their per-
+    position scales ``[b, H_kv, T]`` (ones unless int8)."""
+    hq, hkv, dh = ATTN_SHAPES[shape]
+    rng = np.random.default_rng(seed)
+    nb, t_cap = 1 + A_B * A_MB, A_MB * A_BLK
+    tables = 1 + rng.permutation(A_B * A_MB).reshape(A_B, A_MB)
+    live = np.zeros((nb, A_BLK), bool)          # by (block, offset)
+    for b in range(A_B):
+        pos = np.arange(t_cap)[: A_LENGTHS[b]]
+        live[tables[b][pos // A_BLK], pos % A_BLK] = True
+    pool = init_pool(1, nb, hkv, A_BLK, dh, kv_dtype)
+    sides, model = [], []
+    for _ in "kv":
+        src = rng.normal(size=(nb, hkv, A_BLK, dh)).astype(np.float32)
+        mask = live[:, None, :, None]
+        if kv_dtype == "int8":
+            codes, scale = _quantize(jnp.asarray(src),
+                                     jnp.ones((nb, hkv, A_BLK), bool))
+            codes = np.where(mask, np.asarray(codes),
+                             127 if stale == "large" else 0).astype(np.int8)
+            sides.append((_rows_major(jnp.asarray(codes))[None],
+                          scale[None]))
+            vals, sc = codes.astype(np.float32), np.asarray(scale)
+        else:
+            dt = pool.k.dtype
+            big = 3e4 if stale == "large" else 0.0
+            stored = jnp.asarray(np.where(mask, src, big), dt)
+            sides.append((_rows_major(stored)[None], None))
+            vals = np.asarray(stored.astype(jnp.float32))
+            sc = np.ones((nb, hkv), np.float32)
+        # [b, MB, H_kv, blk, dh] -> [b, H_kv, T, dh]
+        model.append((
+            vals[tables].transpose(0, 2, 1, 3, 4).reshape(A_B, hkv, t_cap,
+                                                          dh),
+            np.repeat(sc[tables].transpose(0, 2, 1), A_BLK, axis=2)))
+    (k, ks), (v, vs) = sides
+    pool = pool._replace(k=k, v=v, k_scale=ks, v_scale=vs)
+    q = jnp.asarray(rng.normal(size=(A_B, hq, dh)), jnp.float32)
+    return pool, q, jnp.asarray(tables, jnp.int32), model
+
+
+def _stored(pool, q, tables):
+    return np.asarray(jax.jit(lambda q: stored_decode_attn(
+        pool, 0, q, tables, jnp.asarray(A_LENGTHS)))(q))
+
+
+@pytest.mark.parametrize("kv_dtype", KV_DTYPES)
+@pytest.mark.parametrize("shape", sorted(ATTN_SHAPES))
+def test_stored_decode_attn_matches_the_oracle(shape, kv_dtype):
+    """``stored_decode_attn`` against ``decode_attn(q,
+    *vmap(gather_layer))`` on the same pool bytes, ragged lengths from 1
+    to the capacity, stale rows beyond them at the largest finite
+    values. An f32 pool: the same f32 products in another order, 1e-5
+    of the output's scale. A bf16 or int8 pool: the function rounds its
+    two small operands to bf16 (``u = 2**-9``) where the oracle keeps
+    them f32, and nothing else differs —
+
+    - against a NumPy model of exactly that arithmetic (``q`` rounded,
+      exact products, the probabilities times the value scales
+      rounded): 1e-4 of the output's scale, 40 times under the
+      roundings' own effect;
+    - against the oracle, the bound the two roundings give: a score
+      moves by at most ``ds = u * max_t sum_j |q_j k_tj| / sqrt(dh)``,
+      so a probability by a factor within ``exp(+-2 ds)``, and its own
+      rounding adds ``u``: ``|y - oracle| <= (exp(2 ds) - 1 + u) *
+      max_t |v_t|`` per head."""
+    pool, q, tables, ((kc, ks), (vc, vs)) = _attn_case(shape, kv_dtype,
+                                                       "large")
+    got = _stored(pool, q, tables)
+    lengths = jnp.asarray(A_LENGTHS)
+    want = np.asarray(jax.jit(lambda q: decode_attn(
+        q, *jax.vmap(lambda t: gather_layer(pool, 0, t))(tables),
+        lengths))(q))
+    assert got.shape == want.shape and got.dtype == np.float32
+    scale = np.abs(want).max()
+    if kv_dtype == "f32":
+        assert np.abs(got - want).max() <= 1e-5 * scale
+        return
+    b, h, dh = q.shape
+    hkv = kc.shape[1]
+    qg = np.asarray(q, np.float64).reshape(b, hkv, h // hkv, dh)
+    live = np.arange(kc.shape[2]) < A_LENGTHS[:, None]          # [b, T]
+    mask = live[:, None, None, :]
+
+    def attend(qg, round_p):
+        s = np.einsum("bkgd,bktd->bkgt", qg, kc) * ks[:, :, None] \
+            / np.sqrt(dh)
+        s = np.where(mask, s, -1e30)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p = round_p(p / p.sum(-1, keepdims=True) * vs[:, :, None])
+        return np.einsum("bkgt,bktd->bkgd", p, vc).reshape(b, h, dh)
+
+    model = attend(_bf16(qg).astype(np.float64), _bf16)
+    assert np.abs(got - model).max() <= 1e-4 * scale
+    # the model with nothing rounded is the oracle
+    assert np.abs(attend(qg, lambda p: p) - want).max() <= 1e-5 * scale
+    u = 2.0 ** -9
+    ds = u * np.where(mask, np.einsum(
+        "bkgd,bktd->bkgt", np.abs(qg), np.abs(kc)) * ks[:, :, None],
+        0).max(-1) / np.sqrt(dh)                            # [b, k, g]
+    vmax = np.where(live[:, None, :, None], np.abs(vc) * vs[..., None],
+                    0).max((2, 3))                          # [b, k]
+    bound = (np.expm1(2 * ds) + u) * vmax[:, :, None]
+    err = np.abs(got - want).reshape(b, hkv, h // hkv, dh).max(-1)
+    assert (err <= bound + 1e-5 * scale).all()
+    # the roundings are really there (bf16 operands, not an f32 upcast
+    # of the cache): the difference is far above f32 reduction noise
+    assert err.max() > 1e-4 * scale
+
+
+@pytest.mark.parametrize("kv_dtype", KV_DTYPES)
+def test_stale_bytes_beyond_the_length_carry_no_mass(kv_dtype):
+    """Positions at and beyond ``lengths`` meet a probability that is
+    exactly 0: the largest finite bytes there (a freed sequence's rows,
+    int8 codes of 127) and zeros give the same output, bit for bit."""
+    large = _stored(*_attn_case("mha20x64", kv_dtype, "large")[:3])
+    zero = _stored(*_attn_case("mha20x64", kv_dtype, "zero")[:3])
+    assert np.isfinite(large).all()
+    np.testing.assert_array_equal(large, zero)
