@@ -547,60 +547,6 @@ def main() -> int:
     if not tp_only and os.environ.get("DECODE_ENGINE", "1") != "0":
         guarded("kv_spill_tokens_per_sec", kv_spill_rows)
 
-    # Fused-vs-gather kernel ratio (round 12): the same engine workload
-    # through EngineConfig(kernel=...) per KV dtype. Off-chip this runs
-    # the Pallas INTERPRETER (a correctness lane, orders of magnitude
-    # slower than compiled XLA — the ratio is honest but meaningless
-    # for perf); the real-chip ratio has not been measured.
-    # BENCH_FUSED_NEW bounds the interpret-lane cost.
-    def fused_rows():
-        import numpy as np
-
-        from distributed_llm_code_samples_tpu.decode import (
-            DecodeEngine, EngineConfig)
-
-        on_tpu = jax.default_backend() == "tpu"
-        new = int(os.environ.get("BENCH_FUSED_NEW",
-                                 NEW if on_tpu else min(NEW, 24)))
-        n_seq = B if on_tpu else min(B, 2)
-        block = int(os.environ.get("BENCH_ENGINE_BLOCK", 16))
-        mbps = -(-(T0 + new) // block)
-        rng = np.random.default_rng(0)
-        fr_prompts = [rng.integers(0, V, size=T0).tolist()
-                      for _ in range(n_seq)]
-
-        def run(kv_dtype, kernel):
-            cfg = EngineConfig(
-                block_size=block, n_blocks=1 + n_seq * mbps,
-                max_slots=n_seq, max_blocks_per_seq=mbps,
-                prefill_chunk=min(block, 1 << (T0.bit_length() - 1)),
-                kv_dtype=kv_dtype, kernel=kernel)
-            eng = DecodeEngine(params, H, cfg)
-            t0 = time.perf_counter()
-            outs = eng.generate(fr_prompts, new)
-            return outs, eng.tokens_generated / (time.perf_counter()
-                                                - t0)
-
-        ratios = {}
-        for dt_name in ("f32", "bf16", "int8"):
-            outs_g, tps_g = run(dt_name, "gather")
-            outs_f, tps_f = run(dt_name, "fused")
-            if outs_f != outs_g:
-                raise RuntimeError(f"fused != gather tokens at "
-                                   f"{dt_name}")
-            ratios[dt_name] = round(tps_f / tps_g, 4)
-            paths[f"engine_fused_{dt_name}_tokens_per_sec"] = round(
-                tps_f, 1)
-        paths["fused_vs_gather"] = ratios
-        if not on_tpu:
-            paths["fused_vs_gather_note"] = (
-                "CPU interpret lane: fused runs the Pallas interpreter "
-                "(correctness only; expect << 1). Real-chip ratio: not "
-                "measured.")
-
-    if not tp_only and os.environ.get("DECODE_FUSED", "1") != "0":
-        guarded("fused_vs_gather", fused_rows)
-
     # Fleet rows (round 14): the multi-engine router (decode/fleet.py)
     # across N = 1/2/3 replicas. The engines are stepped round-robin in
     # ONE process, so CPU wall clock cannot show the speedup — the
@@ -1714,10 +1660,8 @@ def main() -> int:
         "roofline_levers_note": (
             "round-12 levers against the same ceiling: "
             "spec_tokens_per_step multiplies tokens per dispatch at "
-            "equal outputs (engine_spec_* rows), and kernel='fused' "
-            "walks the pool at the storage dtype with no gathered-"
-            "layout round-trip (fused_vs_gather rows; kv int8 cuts "
-            "the streamed bytes 4x, not just the stored bytes)"),
+            "equal outputs (engine_spec_* rows); kv int8 cuts the "
+            "stored and the streamed bytes 4x"),
         "param_bytes": param_bytes,
         "kv_bytes_avg_per_seq": int(kv_bytes_avg),
         "hbm_bw_gbps": round(bw / 1e9, 1),

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """The quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py            # one chip: serve, fused, hybrid, train
+    python chip_smoke.py            # one chip: serve, hybrid, train
     python chip_smoke.py --chips 4  # four chips: the cross-chip paths only
 
 One process. It imports JAX once and drives the program through the
@@ -53,10 +53,7 @@ SERVE = dict(model=["-d", "768", "-l", "12", "--heads", "12",
              # axis: GPT-2's padded vocabulary, on both sides of the
              # --tp comparison
              vocab_tp=50304,
-             prompt_lens="48,137,290,512", max_new=32,
-             # the engine's default block: the fused walk must compile
-             # at it (tests/test_chip_compile.py), not at a special one
-             block_size=16)
+             prompt_lens="48,137,290,512", max_new=32)
 # The paper's model at the paper's width (train_ffns.py's docstring
 # shape; 2 GiB of f32 parameters), then BASELINE.json config 5's shape,
 # the one with chip history. Both at the reference's own learning rate
@@ -94,9 +91,6 @@ HYBRID = dict(config=dict(
     # a served token has to be the reference's first wherever its top
     # two logits lie further apart than this (float32 on both sides)
     tie=1e-3)
-
-MOSAIC = "tpu_custom_call"
-
 
 def require_tpu() -> dict:
     """The device as JAX reports it — or an error where it is no TPU."""
@@ -216,73 +210,12 @@ def phase_serve(out_dir: str) -> None:
     emit(rec3)
 
 
-class _UntilMosaic(list):
-    """A ``launcher.CAPTURE_COMPILED`` sink that keeps compiled programs
-    until one carries the Mosaic call, then disarms the hook (the engine
-    re-compiles every dispatch while it is armed)."""
-
-    def append(self, hlo: str) -> None:
-        if MOSAIC in hlo:
-            super().append(hlo)
-            launcher.CAPTURE_COMPILED = None
-
-
 def first_difference(a: list, b: list):
     for uid, (x, y) in enumerate(zip(a, b)):
         for pos, (t, u) in enumerate(zip(x, y)):
             if t != u:
                 return {"uid": uid, "position": pos, "tokens": [t, u]}
     return None
-
-
-def phase_fused(out_dir: str) -> None:
-    """Phase 2: the fused Pallas block-table walk against the gather
-    path — same prompts, same block size, f32 KV, greedy. The compiled
-    decode program must carry the Mosaic call, so an interpreted kernel
-    cannot pass for a compiled one.
-
-    Same tokens is the bar at float32 matmul precision. At the default
-    — how ``generate`` runs — XLA's f32 dot on the MXU and Mosaic's
-    round their operands differently, and a random-weight model's
-    near-tied logits turn that into different greedy picks: that pair
-    runs too, and where it first differs is RECORDED, not asserted (the
-    kernel module's docstring states the contract per backend)."""
-    del out_dir
-    shape = {"model": SERVE["model"], "block_size": SERVE["block_size"],
-             "kv_dtype": "f32"}
-    common = serve_argv(SERVE["vocab"], "--kv_dtype", "f32",
-                        "--block_size", str(SERVE["block_size"]))
-
-    def pair(tag: str) -> tuple[dict, bool]:
-        want, rec = generate(f"serve_f32_gather{tag}",
-                             [*common, "--kernel", "gather"], SERVE["vocab"])
-        emit(rec)
-        launcher.CAPTURE_COMPILED = captured = _UntilMosaic()
-        try:
-            got, rec = generate(f"serve_f32_fused{tag}",
-                                [*common, "--kernel", "fused"],
-                                SERVE["vocab"])
-        finally:
-            launcher.CAPTURE_COMPILED = None
-        rec["fused_shape"] = shape
-        rec["mosaic_call_in_compiled_decode"] = bool(captured)
-        rec["first_difference_from_gather"] = first_difference(
-            tokens_of(got), tokens_of(want))
-        return rec, tokens_of(got) == tokens_of(want)
-
-    rec, _ = pair("_default_precision")
-    rec["matmul_precision"] = "default"
-    emit(rec)
-    with jax.default_matmul_precision("highest"):
-        rec, same = pair("")
-    rec["matmul_precision"] = "highest"
-    emit(rec)
-    if not rec["mosaic_call_in_compiled_decode"]:
-        raise RuntimeError(f"fused: no compiled engine program contains "
-                           f"{MOSAIC} — the kernel did not reach Mosaic")
-    if not same:
-        raise RuntimeError(f"fused: tokens differ from the gather path's: "
-                           f"{rec['first_difference_from_gather']}")
 
 
 def phase_hybrid(out_dir: str) -> None:
@@ -479,7 +412,7 @@ def main(argv=None) -> int:
     shutil.rmtree(out_dir, ignore_errors=True)  # metrics streams append
     os.makedirs(out_dir)
     phases = ([phase_cross_chip] if args.chips == 4
-              else [phase_serve, phase_fused, phase_hybrid, phase_train])
+              else [phase_serve, phase_hybrid, phase_train])
     for phase in phases:
         try:
             phase(out_dir)

@@ -12,7 +12,7 @@ from .engine import (AdmissionError, DecodeEngine, EngineConfig,
 from .fleet import (EngineHandle, FleetRouter, HandoffRef,
                     TransportDead, TransportError, TransportTimeout)
 from .paged import (KV_DTYPES, PagedKV, SCRATCH_BLOCK, copy_block,
-                    corrupt_block, extract_blocks, fused_decode_attn,
+                    corrupt_block, extract_blocks,
                     gather_layer, implant_block, init_pool,
                     kv_bytes_per_token, pool_bytes, scrub_blocks,
                     stored_decode_attn, write_chunk, write_rows)
@@ -32,7 +32,6 @@ __all__ = [
     "POISON_ALL", "POISON_NONE", "REQUEST_EVENTS", "ServePolicy",
     "KV_DTYPES", "PagedKV", "SCRATCH_BLOCK", "copy_block",
     "corrupt_block", "draft_tokens", "extract_blocks",
-    "fused_decode_attn",
     "gather_layer", "implant_block", "init_pool",
     "kv_bytes_per_token", "pool_bytes",
     "PrefixCache", "PrefixNode",

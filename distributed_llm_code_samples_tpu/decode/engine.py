@@ -16,8 +16,7 @@ wrappers):
   prefill program per power-of-two chunk bucket — so steady-state steps
   are dispatch-only and the compile count is bounded by the bucket
   count (the ``--log_every`` chunk discipline, recompile-guard-tested).
-- **Chunked prefill**: long prompts enter in bounded chunks
-  (``models.attention.chunk_attn`` over the gathered cache), so a new
+- **Chunked prefill**: long prompts enter in bounded chunks, so a new
   long prompt costs one chunk per engine step instead of stalling every
   running decode behind a full-prompt pass.
 - **Fused sampling** (``decode/sampling.py``): temperature / top-k /
@@ -25,22 +24,18 @@ wrappers):
   ``(engine seed, sequence uid, position)`` — continuous-batching
   output is token-identical to decoding each sequence alone.
 
-Models: ``LMParams`` (the GPT-2-shaped block, every layer attention)
-and ``HybridLMParams`` (``models/hybrid_lm.py``: Mamba-1 and attention
-layers in one stack). A model says, per layer, which kind it is and
-which index of its kind's weights and cache it owns; ``_trunk`` walks
-that. A recurrent layer's state lives beside the pool, by slot
-(``paged.RecurrentState``), donated and updated in place like it; a
-slot's state is zero at position 0 inside the prefill program. What
-cannot carry that state yet — prefix hits, speculation, a mesh, the KV
-handoff, snapshots, the spill tier — refuses in one line for such a
-model (``_refuse_recurrent``), by what the model is and under no flag.
-
-Strategies: ``mesh=None`` runs single-device (the ``lm`` family);
-passing a model-axis mesh runs the Megatron decode layout
-(``parallel.lm``): head-sharded KV pool (each shard caches its own
-``H/n`` heads), vocab-parallel tied head, and an in-graph logits
-gather feeding the same fused pick on every shard.
+This module is the SCHEDULER. What a step program is made of is
+``decode/programs.py``'s, built from the model's face (``models/face.py``:
+a family is one file) and the cache (``decode/paged.py``); the engine
+calls ``_program(kind, bucket)(params, cache, host operands) -> (cache,
+picks, flags)`` and reads sizes only from the model: ``vocab``,
+``max_seq_len``, the kinds of its ``layers``, its ``cache_spec``. A
+recurrent layer's state lives beside the pool, by slot; what cannot
+carry it yet — prefix hits, speculation, a mesh, the KV handoff,
+snapshots, the spill tier — refuses in one line for such a model
+(``_refuse_recurrent``), by what the model is and under no flag.
+``mesh=None`` runs single-device; a model-axis mesh the Megatron decode
+layout (``parallel.lm``; the collectives are in ``decode/programs.py``).
 
 Determinism contract: a sequence's output depends only on
 ``(params, engine seed, uid, prompt, sampling config)`` — never on slot
@@ -74,38 +69,21 @@ counterpart of the self-healing training ladder):
   ``request`` record per transition (admitted / preempted / retried /
   quarantined / completed / rejected / expired).
 
-Raw-latency layer (round 12, DESIGN.md section 18 — two compounding
-attacks on per-token cost):
+Raw-latency layer (round 12, DESIGN.md section 18):
 
 - **Speculative decoding** (``EngineConfig(speculate=k)``): an n-gram
   prompt-copy drafter (``decode/draft.py`` — no second model, state a
   pure function of ``prompt + out``) proposes up to ``k`` tokens per
-  slot; ONE compiled verify dispatch chains ``k+1`` single-token
-  sub-steps (the decode body unrolled) and accepts the matched greedy
-  prefix, so a step emits ``1 + accepted`` tokens per sequence at one
-  dispatch's host/scheduler cost. Verification is greedy and the KV
-  write of a drafted row is MASKED by its own acceptance (a rejected
-  row's scatter is redirected to the scratch block — the existing pad
-  idiom), so the pool's write history contains exactly the rows the
-  non-speculative engine would have written: token identity holds
-  BIT-FOR-BIT at every kv_dtype, int8 requant history included, and
-  rollback of a rejected tail is literally nothing (the rows never
-  landed). Replay teacher-forces recorded tokens as drafts (all
+  slot; ONE compiled verify dispatch (``programs.py::_verify_fn``)
+  accepts the matched greedy prefix, so a step emits ``1 + accepted``
+  tokens per sequence at one dispatch's host/scheduler cost, and the
+  pool's write history holds exactly the rows the non-speculative
+  engine would have written: token identity BIT-FOR-BIT at every
+  kv_dtype, and nothing to roll back. Replay teacher-forces recorded tokens as drafts (all
   accepted on a healthy replay), so quarantine/preempt/crash-resume
   re-draft identically; teacher-forced tokens stay OUT of the
   ``drafted_tokens``/``accepted_tokens`` telemetry pair, which scores
   the live n-gram drafter only.
-- **Fused paged-attention kernel** (``EngineConfig(kernel="fused")``):
-  the decode/verify cache read runs the Pallas block-table walk
-  (``ops/pallas_paged_attention.py``) instead of the gather and the
-  two products over the gathered rows — pool bytes cross the bus once,
-  at the storage dtype, int8 dequant folded in. ``gather_layer`` →
-  ``decode_attn`` stays the differential oracle of both: the walk is on
-  the CPU interpreter within a stated ULP bound of it and
-  token-identical to the gather engine; on the chip
-  token-identical at float32 matmul precision only — at the default
-  precision the two diverge on near-tied logits (the kernel module's
-  docstring has the per-backend contract).
 
 Shared-prefix layer (round 13, DESIGN.md section 19 — the capacity
 multiplier: most requests share a long system prompt, so N admissions
@@ -169,7 +147,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import json
 import os
 import time
 from dataclasses import dataclass, field
@@ -177,15 +154,9 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models import hybrid_lm
-from ..models.attention import chunk_attn, rope
-from ..models.hybrid_lm import ATTN, HybridLMParams, mm
-from ..models.lm import LMParams
-from ..ops.norm import layernorm
+from ..models.face import ATTN, ServedModel
 from ..parallel import launcher
-from ..runtime.guardrails import rows_finite
 from ..runtime.policy import QosPolicy
 from ..runtime.telemetry import FLIGHT_FILENAME, STEP_SPAN
 from ..runtime.tracing import PhaseTimer, SpanTracer
@@ -194,21 +165,13 @@ from ..runtime.weights import (BOOT_VERSION, architecture_diff,
                                model_fingerprint, same_architecture)
 from ..runtime import wire
 from .draft import draft_tokens
-from .paged import (PagedKV, RecurrentState, SCRATCH_BLOCK, copy_block,
-                    copy_block_rows, corrupt_block as
-                    _pool_corrupt_block, extract_blocks,
-                    fused_decode_attn, gather_layer, implant_block,
-                    init_pool, init_state, kv_bytes_per_token, pool_bytes,
-                    scrub_blocks, stored_decode_attn, write_chunk,
-                    write_rows)
+from .paged import (SCRATCH_BLOCK, corrupt_block as _pool_corrupt_block,
+                    extract_blocks, kv_bytes_per_token, pool_bytes,
+                    scrub_blocks)
+from .programs import POISON_ALL, POISON_NONE, StepPrograms
 from .prefix import PrefixCache
 from .spill import SpillTier
-from .sampling import check_sampling, check_speculation, make_pick
-
-# poison operand values for the compiled steps (chaos nan_logits
-# injection rides a runtime operand, so arming a fault never recompiles)
-POISON_NONE = -1
-POISON_ALL = -2
+from .sampling import check_sampling, check_speculation
 
 # the request-record event vocabulary (telemetry schema v4 ``request``
 # kind; runtime/telemetry.py REQUEST_REQUIRED pins the KEY set, this
@@ -257,7 +220,7 @@ HANDOFF_VERSION = 7
 # device pool shape AND the host spill tier behind it (a spilled block
 # restores bit-identically, so tier sizing never touches numerics).
 # Every other key participates in the token-identity proof (sampling
-# keys, chunk grouping — hence int8 requant history — kernel and
+# keys, chunk grouping — hence int8 requant history — and
 # speculation paths) and must match exactly; ``prefix_partial`` is
 # deliberately NOT here — at int8 a sub-block share carries the
 # donor's frozen scale, so the flag is a numerics key.
@@ -329,11 +292,7 @@ class EngineConfig:
     ``speculate`` is the per-step draft budget (0 = off): each decode
     dispatch becomes a ``speculate+1``-token verify program emitting
     the accepted greedy prefix (requires ``temperature == 0``;
-    ``decode/draft.py``). ``kernel`` selects the cache-read path for
-    decode/verify steps: ``"gather"`` (each slot's blocks gathered and
-    attended as stored: ``paged.stored_decode_attn``) or ``"fused"`` (the
-    Pallas block-table walk, single-device only — prefill keeps its
-    chunked gather attention either way). ``prefix_cache`` enables the
+    ``decode/draft.py``). ``prefix_cache`` enables the
     shared-prefix radix cache (``decode/prefix.py``) — host-side only,
     so the flag never changes a compiled program; it lives in the
     config because snapshot-resume must restore onto the same sharing
@@ -369,7 +328,6 @@ class EngineConfig:
     seed: int = 0
     use_rope: bool = False
     speculate: int = 0
-    kernel: str = "gather"
     prefix_cache: bool = True
     spill_blocks: int = 0
     spill_restore_per_step: int = 2
@@ -492,7 +450,7 @@ class DecodeEngine:
     returns ``{uid: full token list}``. See the module docstring for the
     design; DESIGN.md section 15 for the state machine."""
 
-    def __init__(self, params: LMParams | HybridLMParams, n_heads: int,
+    def __init__(self, params: ServedModel, n_heads: int,
                  config: EngineConfig | None = None, mesh=None,
                  policy: ServePolicy | None = None, metrics=None,
                  qos: QosPolicy | None = None):
@@ -528,7 +486,7 @@ class DecodeEngine:
         check_sampling(cfg.temperature, cfg.top_k, cfg.top_p, params.vocab)
         check_speculation(cfg.speculate, cfg.temperature)
         # the kinds of layer that carry a recurrent state beside the KV
-        # blocks (``models/hybrid_lm.py``; none for ``LMParams``). What
+        # blocks (none for a model whose layers are all attention). What
         # cannot carry that state yet refuses, here and at the entry of
         # every later call, by what the model is: no flag turns it off
         self.recurrent = sorted({kind for kind, _ in params.layers}
@@ -541,38 +499,17 @@ class DecodeEngine:
         if cfg.spill_blocks or cfg.prefix_partial:
             self._refuse_recurrent("spill_blocks / prefix_partial (they "
                                    "extend the prefix cache, which is off)")
-        if cfg.kernel not in ("gather", "fused"):
-            raise ValueError(f"kernel must be 'gather' or 'fused', got "
-                             f"{cfg.kernel!r}")
-        if cfg.kernel == "fused":
-            if mesh is not None:
-                raise ValueError(
-                    "kernel='fused' is single-device (the head-sharded "
-                    "TP pool runs the gather path); pass mesh=None or "
-                    "kernel='gather'")
         self.params = params
         self.n_heads = n_heads
         self.cfg = cfg
         self.mesh = mesh
-        self.dh = getattr(params, "head_dim", params.d_model // n_heads)
-        self.kv_heads = params.attn.wk.shape[1] // self.dh
-        if cfg.kernel == "fused":
-            # a shape Mosaic would refuse is refused here, on every
-            # backend — never at the first decode step on the chip
-            from ..ops.pallas_paged_attention import check_fused_shape
-            check_fused_shape(n_heads // self.kv_heads, cfg.block_size,
-                              self.dh, cfg.max_blocks_per_seq)
+        # what the model keeps per sequence, as sizes (no weight is read)
+        self.spec = params.cache_spec(n_heads)
         if mesh is not None:
             from ..parallel.lm import tp_shard_params
-            from ..parallel.mesh import MODEL_AXIS, require_axes
-            from ..parallel.transformer import _validate_tp
-            require_axes(mesh, MODEL_AXIS)
-            n = mesh.shape[MODEL_AXIS]
-            _validate_tp(params.blocks, n_heads, n)
-            if params.vocab % n:
-                raise ValueError(f"vocab={params.vocab} not divisible by "
-                                 f"model-axis size {n}")
-            self.params = tp_shard_params(params, mesh)
+            self.params = tp_shard_params(params, mesh, n_heads)
+        # the builder of step programs; ``_program`` keeps the built ones
+        self.programs = StepPrograms(cfg, self.spec, params.vocab, mesh)
         # -- live weight hot-swap (round 17, DESIGN.md section 23) --
         # double-buffered weights: version id -> params. The BOOT
         # weights are version 0; a deploy loads a checkpoint step as a
@@ -581,7 +518,7 @@ class DecodeEngine:
         # started on (their ``_Seq.weights_version`` pin) while new
         # admissions take ``serving_version``. Every compiled program
         # takes params as a traced operand, so a swap never recompiles.
-        self.weights: dict[int, LMParams] = {BOOT_VERSION: self.params}
+        self.weights: dict[int, ServedModel] = {BOOT_VERSION: self.params}
         self.serving_version = BOOT_VERSION
         # the architecture anchor for load_weights: held VERSIONS come
         # and go (retirement), but the engine's shape never does — a
@@ -607,11 +544,10 @@ class DecodeEngine:
         # attribution key every request/span record for the uid pins
         # (None single-tenant) — host metadata only, like _traces
         self._tenants: dict[int, str | None] = {}
-        self.pool = self._init_pool()
-        # the recurrent layers' state, by slot, beside the pool (None
-        # for a model that has none): donated into the step programs
-        # with it and updated in place
-        self.state = self._init_state()
+        # the pool, and the recurrent layers' state by slot beside it
+        # (None for a model that has none): donated into the step
+        # programs together and updated in place
+        self.pool, self.state = self.programs.init_cache()
         s, mb = cfg.max_slots, cfg.max_blocks_per_seq
         self.tables = np.full((s, mb), SCRATCH_BLOCK, np.int32)
         self.lengths = np.zeros((s,), np.int32)
@@ -766,36 +702,10 @@ class DecodeEngine:
                 f"{'/'.join(self.recurrent)} layers: it cannot carry "
                 "their recurrent state yet")
 
-    def _init_pool(self) -> PagedKV:
-        cfg = self.cfg
-        # the pool's layer axis is the layers that own a KV cache index
-        # (all of an LMParams' layers; a hybrid's attention layers only)
-        kv_layers = sum(kind == ATTN for kind, _ in self.params.layers)
-        pool = init_pool(kv_layers, cfg.n_blocks,
-                         self.kv_heads, cfg.block_size, self.dh,
-                         cfg.kv_dtype)
-        if self.mesh is None:
-            return pool
-        from ..parallel.mesh import MODEL_AXIS
-        # head-sharded pool: each model shard caches its own KV heads
-        return jax.tree.map(
-            lambda x, spec: jax.device_put(x, NamedSharding(self.mesh,
-                                                            spec)),
-            pool, self._pool_specs())
-
-    def _init_state(self) -> RecurrentState | None:
-        if not self.recurrent:
-            return None
-        m = self.params.mamba
-        return init_state(m.w_in.shape[0], self.cfg.max_slots,
-                          d_inner=m.conv_w.shape[2],
-                          d_state=m.a_log.shape[1],
-                          d_conv=m.conv_w.shape[1])
-
     def _cache(self):
         """The donated operand of every step program: the pool, and for
         a model with recurrent layers the pair (pool, recurrent
-        state) — ``_trunk`` takes it apart and puts it together."""
+        state)."""
         return self.pool if self.state is None else (self.pool,
                                                       self.state)
 
@@ -807,30 +717,18 @@ class DecodeEngine:
         else:
             self.pool, self.state = cache
 
-    def _pool_specs(self) -> PagedKV:
-        """Heads are contiguous in a stored row's minor axis
-        (``H_kv*dh``), so sharding that axis shards the heads."""
-        from ..parallel.mesh import MODEL_AXIS
-        arr = P(None, None, None, MODEL_AXIS)
-        sc = (P(None, None, MODEL_AXIS) if self.cfg.kv_dtype == "int8"
-              else None)
-        return PagedKV(arr, arr, sc, sc, self.dh)
-
     # -- compiled programs (one per (kind, bucket); bounded) -----------
 
     def _program(self, kind: str, bucket: int):
+        """The engine's cache of built programs. ``kind``: decode /
+        prefill / verify (bucketed), cow / cow_rows / implant (one each,
+        built on first use: steady state never builds them, so the
+        recompile-guard tests hold with the write barrier armed)."""
         key = (kind, bucket)
         fn = self._programs.get(key)
         if fn is None:
             self.compile_count += 1
-            builder = {"decode": self._build_decode,
-                       "prefill": self._build_prefill,
-                       "verify": self._build_verify,
-                       "cow": self._build_cow,
-                       "cow_rows": self._build_cow_rows,
-                       "implant": self._build_implant}[kind]
-            fn = builder(bucket)
-            self._programs[key] = fn
+            fn = self._programs[key] = self.programs.build(kind, bucket)
         self.dispatch_count += 1
         return fn
 
@@ -850,401 +748,6 @@ class DecodeEngine:
         self._program("implant", 0)
         return self.compile_count
 
-    def _attn_qkv(self, p, l: int, a, positions):
-        """Shared q/k/v projection + rotary for attention layer ``l`` of
-        the model's attention stack: ``a [N, d]``
-        -> ``q [N, h_loc, dh], k/v [N, kv_loc, dh]`` (local head counts
-        read off the — possibly sharded — weight shapes, the
-        ``cached_attn_step`` convention)."""
-        blk = p.attn
-        dh = self.dh
-        h_loc = blk.wq.shape[1] // dh
-        kv_loc = blk.wk.shape[1] // dh
-        q = mm(a, blk.wq[l]).reshape(-1, h_loc, dh)
-        k = mm(a, blk.wk[l]).reshape(-1, kv_loc, dh)
-        v = mm(a, blk.wv[l]).reshape(-1, kv_loc, dh)
-        if self.cfg.use_rope:
-            rot = jax.vmap(lambda x, pos: rope(x[:, None, :],
-                                               pos[None])[:, 0, :])
-            q = rot(q, positions)
-            k = rot(k, positions)
-        return q, k, v
-
-    def _embed(self, p, tokens, positions):
-        if isinstance(p, HybridLMParams):
-            # no position of any kind: the recurrent layers carry order
-            return p.wte[tokens].astype(jnp.float32)
-        if self.mesh is not None:
-            from ..parallel.lm import vp_embed
-            return vp_embed(p.wte, tokens) + p.wpe[positions]
-        return p.wte[tokens] + p.wpe[positions]
-
-    @staticmethod
-    def _norm(p, g, x):
-        """The model's norm with gain ``g``: RMSNorm for the hybrid
-        family, the gain-only LayerNorm for ``LMParams``."""
-        if isinstance(p, HybridLMParams):
-            return hybrid_lm.rmsnorm(g, x, p.eps)
-        return layernorm(g, x)
-
-    def _trunk(self, p, pool, x, positions, write_attn, mix=None):
-        """The shared per-layer forward both compiled programs run —
-        ONE definition, so prefill and decode numerics can never drift.
-        The model says, per layer, which kind it is and which index of
-        its kind's weights and cache it owns (``p.layers``). An
-        attention layer: norm, q/k/v, then the caller's ``write_attn(i,
-        pool, q, k, v) -> (pool, y [N, h_loc, dh])`` (the only step
-        where the two programs differ: batched single-token writes +
-        per-slot gathers vs one slot's chunk write + chunk attention),
-        output projection. A recurrent layer: norm, then the caller's
-        ``mix(i, state, a) -> (state, y [N, d])`` (one token for each
-        row's own state vs a chunk scanned through one slot's). Then
-        the FFN — with the Megatron psums when a mesh is set.
-
-        ``pool`` is the program's donated cache operand
-        (``_cache()``): the ``PagedKV``, or for a model with recurrent
-        layers the pair of it and the ``RecurrentState``; it is
-        returned in the same form."""
-        tp = self.mesh is not None
-        if tp:
-            from ..parallel.collectives import all_reduce
-            from ..parallel.mesh import MODEL_AXIS
-        state = None
-        if self.recurrent:
-            pool, state = pool
-        n = x.shape[0]
-        for l, (kind, i) in enumerate(p.layers):
-            a = self._norm(p, p.norm_in[l], x)
-            if kind == ATTN:
-                q, k, v = self._attn_qkv(p, i, a, positions)
-                pool, y = write_attn(i, pool, q, k, v)
-                y = mm(y.reshape(n, -1), p.attn.wo[i])
-            else:
-                state, y = mix(i, state, a)
-            x = x + (all_reduce(y, MODEL_AXIS) if tp else y)
-            h = self._norm(p, p.norm_ff[l], x)
-            if isinstance(p, HybridLMParams):
-                f = hybrid_lm.gated_mlp(p, l, h)
-            else:
-                blk = p.blocks
-                f = jnp.maximum(h @ blk.w1[l].T, 0.0) @ blk.w2[l].T
-            x = x + (all_reduce(f, MODEL_AXIS) if tp else f)
-        return (pool if state is None else (pool, state)), x
-
-    def _logits(self, p, h):
-        """Tied head; under TP each shard scores its V/n vocab rows and
-        the in-graph gather re-assembles the full row so the fused pick
-        (keys fold uid/position, never the shard) draws identically
-        everywhere — the output is replicated."""
-        logits = mm(h, p.wte)
-        if self.mesh is not None:
-            from ..parallel.collectives import all_gather
-            from ..parallel.mesh import MODEL_AXIS
-            logits = all_gather(logits, MODEL_AXIS, dim=1)
-        return logits
-
-    def _wrap(self, run, n_aux: int = 5, n_out: int = 3):
-        """The (possibly shard_mapped) callable a compiled program is
-        built from — split from ``_jit`` so the static attribution path
-        (``decode_static_report``) can lower the SAME program without a
-        second donation annotation. ``n_aux`` counts the replicated
-        host operands after ``(params, pool)`` and ``n_out`` the
-        returned arrays (the verify program carries two extra operands
-        — drafts, draft lengths — and one extra output — the accepted
-        counts — over decode/prefill's 5/3)."""
-        if self.mesh is None:
-            return run
-        from ..parallel.lm import tp_decode_specs
-        return jax.shard_map(
-            run, mesh=self.mesh,
-            in_specs=(tp_decode_specs(), self._pool_specs())
-            + (P(),) * n_aux,
-            out_specs=(self._pool_specs(),) + (P(),) * (n_out - 1),
-            check_vma=False)
-
-    def _jit(self, run, n_aux: int = 5, n_out: int = 3):
-        """jit (or shard_map+jit under TP) with the pool donated: the
-        engine replaces ``self.pool`` with the returned pool after every
-        dispatch, and XLA updates the blocks in place — without
-        donation each decode step would pay a full-pool allocate+copy,
-        swamping the kv_bytes roofline term this engine exists to
-        shrink. Donation alone does not make that so: the buffer also
-        has to cross the program boundary in the layout the program's
-        scatters and gathers work in. The pool's stored form
-        (``decode/paged.py``: a token's row holds its heads, ``(block,
-        H_kv*dh)`` minor) is the one the chip keeps row-major, so the
-        program takes and returns it as it lies; the head-major form
-        before it was converted whole on the way in and again on the
-        way out, four pool-sized copies a program
-        (``tests/test_chip_compile.py`` pins the compiled module)."""
-        return jax.jit(self._wrap(run, n_aux, n_out), donate_argnums=(1,))
-
-    def _cached_attn(self, pool: PagedKV, l: int, q, tables, n_attend):
-        """One single-query attention over the block-table cache, for
-        the decode and the speculation verify programs (and a TP
-        shard's local heads) — the ``kernel=`` knob. ``gather``: each
-        slot's blocks gathered as they are stored and attended as two
-        matrix products over whole rows in the pool's dtype
-        (``paged.stored_decode_attn``: no f32 copy of the view, no
-        split into heads). ``fused``: the Pallas block-table walk
-        (``ops/pallas_paged_attention.py``), dequant folded in, no
-        gathered layout in HBM. Both are held to ONE differential
-        oracle, ``decode_attn(q, *vmap(gather_layer))`` — what the
-        lockstep ``generate`` computes and the prefill chunk still
-        reads through (``gather_layer`` + ``chunk_attn``); the engine's
-        decode side does not run it. ``gather`` agrees with it to the
-        pool's operand precision (``tests/test_paged_layout.py``),
-        ``fused`` per backend as the kernel module states (no token
-        identity on the chip at the default matmul precision).
-        ``n_attend [b]`` is the per-slot attendable-position count
-        (always >= 1)."""
-        if self.cfg.kernel == "fused":
-            with jax.named_scope("attn"):
-                return fused_decode_attn(pool, l, q, tables, n_attend)
-        return stored_decode_attn(pool, l, q, tables, n_attend)
-
-    def _decode_hidden(self, b: int, p, pool, tables, lengths, tokens,
-                       rows=None):
-        """The decode program up to the head: each of ``b`` rows' input
-        token embedded, written at its own position and attended over
-        its gathered blocks; in a recurrent layer each row's own state
-        (``rows [b]``: its slot's state row, the scratch row for a
-        padded one) advanced by one token. Returns ``(pool, x [b,
-        d])``."""
-        cfg = self.cfg
-        x = self._embed(p, tokens, lengths)             # [b, d]
-        slot_phys = lengths // cfg.block_size
-        off = lengths % cfg.block_size
-
-        def write_attn(l, pool, q, k, v):
-            phys = tables[jnp.arange(b), slot_phys]
-            pool = write_rows(pool, l, phys, off, k, v, cfg.kv_dtype)
-            return pool, self._cached_attn(pool, l, q, tables,
-                                           lengths + 1)
-
-        def mix(i, state, a):
-            with jax.named_scope("ssm"):
-                tail = state.conv[i, rows]
-                y, tail, s = hybrid_lm.mamba_step(
-                    p, i, a, tail.reshape(b, -1, state.ssm.shape[-1]),
-                    state.ssm[i, rows])
-                state = state._replace(
-                    conv=state.conv.at[i, rows].set(tail.reshape(b, -1)),
-                    ssm=state.ssm.at[i, rows].set(s))
-            return state, y
-
-        return self._trunk(p, pool, x, lengths, write_attn, mix)
-
-    def _prefill_hidden(self, c: int, p, pool, table, pos0, tokens,
-                        row=None):
-        """The prefill program up to the head: ``c`` prompt tokens of
-        ONE slot enter the cache through its block table and attend
-        causally over the gathered view; in a recurrent layer the chunk
-        is scanned through the slot's state (``row``: its state row),
-        which is zero at position 0 whatever the row still holds from
-        the sequence before (admission, and the replay after a
-        preemption or a retry: every prefill starts at 0). Returns
-        ``(pool, x [c, d])``."""
-        cfg = self.cfg
-        positions = pos0 + jnp.arange(c)
-        x = self._embed(p, tokens, positions)           # [c, d]
-
-        def write_attn(l, pool, q, k, v):
-            pool = write_chunk(pool, l, table, pos0, k, v,
-                               cfg.kv_dtype)
-            ck, cv = gather_layer(pool, l, table)
-            with jax.named_scope("attn"):
-                y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0)
-            return pool, y.transpose(1, 0, 2)
-
-        def mix(i, state, a):
-            with jax.named_scope("ssm"):
-                fresh = pos0 == 0
-                tail = jnp.where(fresh, 0.0, state.conv[i, row])
-                y, tail, s = hybrid_lm.mamba_chunk(
-                    p, i, a, tail.reshape(-1, state.ssm.shape[-1]),
-                    jnp.where(fresh, 0.0, state.ssm[i, row]))
-                state = state._replace(
-                    conv=state.conv.at[i, row].set(tail.reshape(-1)),
-                    ssm=state.ssm.at[i, row].set(s))
-            return state, y
-
-        return self._trunk(p, pool, x, positions, write_attn, mix)
-
-    def _decode_fn(self, b: int):
-        """The raw (un-jitted) decode-step body for a ``b``-slot bucket:
-        write each slot's input token at its own position, attend over
-        its gathered blocks, pick the next token in-graph — and return
-        each row's all-finite logits flag (the serving guardrail: a
-        poisoned sequence is detected the step it happens, on the same
-        readback as the picks). ``poison`` is the chaos nan_logits
-        operand: a uid (or POISON_ALL) whose row's logits are NaN'd
-        in-graph; POISON_NONE leaves every row bit-identical (a false
-        ``where`` selects the original value).
-
-        Cost-attribution scopes (utils/trace_analysis ``SCOPES``): the
-        body runs under ``decode/``, with ``gather``/``requant`` tagged
-        inside the paged pool ops, ``attn`` on the score+AV math,
-        ``head`` on the final LN + tied head (+ TP logits gather), and
-        ``sample`` on the fused pick — so a hardware trace (or an HLO
-        dump) splits one decode step's time by the roofline's own
-        terms. Scopes are metadata only: the compiled program set is
-        unchanged (the recompile guard pins it)."""
-        cfg = self.cfg
-        pick = make_pick(cfg.temperature, cfg.top_k, cfg.top_p,
-                         self.params.vocab, cfg.seed)
-
-        @jax.named_scope("decode")
-        def run(p, pool, tables, lengths, tokens, uids, poison, *rows):
-            pool, x = self._decode_hidden(b, p, pool, tables, lengths,
-                                          tokens, *rows)
-            with jax.named_scope("head"):
-                logits = self._logits(p, self._norm(p, p.ln_f, x))
-            bad = jnp.logical_or(uids == poison, poison == POISON_ALL)
-            logits = jnp.where(bad[:, None],
-                               jnp.asarray(jnp.nan, logits.dtype), logits)
-            with jax.named_scope("sample"):
-                picks = pick(logits, uids, lengths + 1)
-            return pool, picks, rows_finite(logits)
-
-        return run
-
-    def _build_decode(self, b: int):
-        return self._jit(self._decode_fn(b))
-
-    def _verify_fn(self, b: int):
-        """The speculative verify body for a ``b``-slot bucket:
-        ``speculate + 1`` single-token decode sub-steps UNROLLED into
-        one program — sub-step 0 feeds each slot's pending token, every
-        later sub-step feeds the next drafted token, and the in-graph
-        acceptance chain ``alive_i = alive_{i-1} and draft_i == pick_{i-1}``
-        masks each drafted row's KV WRITE by its own acceptance (a dead
-        row's scatter is redirected to the scratch block, the pad
-        idiom). The sub-steps are sequential on purpose: each one reads
-        the cache state its predecessor wrote — the same bytes the
-        non-speculative engine would have read at that position — which
-        is what makes speculative output bit-identical at every
-        kv_dtype (int8's cross-row requant coupling rules out a
-        position-parallel verify; the win here is one dispatch + one
-        scheduler pass per ``1 + accepted`` tokens, and the rejected
-        tail needs no rollback because it never landed).
-
-        Returns ``(pool, picks [b, k+1], accepted [b], finite
-        [b, k+1])``; the host emits ``picks[:, :accepted+1]`` and
-        advances lengths by the same count."""
-        cfg = self.cfg
-        k = cfg.speculate
-        pick = make_pick(cfg.temperature, cfg.top_k, cfg.top_p,
-                         self.params.vocab, cfg.seed)
-
-        @jax.named_scope("decode")
-        def run(p, pool, tables, lengths, tokens, uids, drafts, dlens,
-                poison):
-            rows = jnp.arange(b)
-            alive = jnp.ones((b,), bool)
-            acc = jnp.zeros((b,), jnp.int32)
-            cur = tokens
-            picks_all, finite_all = [], []
-            for i in range(k + 1):
-                pos = lengths + i
-                x = self._embed(p, cur, pos)                 # [b, d]
-                slot_phys = pos // cfg.block_size
-                off = pos % cfg.block_size
-
-                def write_attn(l, pool, q, kk, vv, _off=off,
-                               _sp=slot_phys, _keep=alive, _i=i):
-                    phys = tables[rows, _sp]
-                    phys = jnp.where(_keep, phys, SCRATCH_BLOCK)
-                    pool = write_rows(pool, l, phys, _off, kk, vv,
-                                      cfg.kv_dtype)
-                    return pool, self._cached_attn(pool, l, q, tables,
-                                                   lengths + _i + 1)
-
-                pool, x = self._trunk(p, pool, x, pos, write_attn)
-                with jax.named_scope("head"):
-                    logits = self._logits(p, self._norm(p, p.ln_f, x))
-                bad = jnp.logical_or(uids == poison,
-                                     poison == POISON_ALL)
-                logits = jnp.where(bad[:, None],
-                                   jnp.asarray(jnp.nan, logits.dtype),
-                                   logits)
-                with jax.named_scope("sample"):
-                    pk = pick(logits, uids, pos + 1)
-                picks_all.append(pk)
-                finite_all.append(rows_finite(logits))
-                if i < k:
-                    d = drafts[:, i]
-                    alive = jnp.logical_and(
-                        alive, jnp.logical_and(i < dlens, d == pk))
-                    acc = acc + alive.astype(jnp.int32)
-                    cur = d
-            return (pool, jnp.stack(picks_all, 1), acc,
-                    jnp.stack(finite_all, 1))
-
-        return run
-
-    def _build_verify(self, b: int):
-        return self._jit(self._verify_fn(b), n_aux=7, n_out=4)
-
-    def _prefill_fn(self, c: int):
-        """The raw prefill-chunk body for one slot: ``c`` prompt tokens
-        enter the cache through the block table; the chunk's own causal
-        attention runs against the gathered view
-        (``models.attention.chunk_attn``). Returns the in-graph pick
-        from the final row — used by the host only when the chunk
-        completes the prompt. Same attribution scopes as the decode
-        body, under ``prefill/``."""
-        cfg = self.cfg
-        pick = make_pick(cfg.temperature, cfg.top_k, cfg.top_p,
-                         self.params.vocab, cfg.seed)
-
-        @jax.named_scope("prefill")
-        def run(p, pool, table, pos0, tokens, uid, poison, *row):
-            pool, x = self._prefill_hidden(c, p, pool, table, pos0,
-                                           tokens, *row)
-            with jax.named_scope("head"):
-                h = self._norm(p, p.ln_f, x[-1:])          # last row
-                logits = self._logits(p, h)
-            bad = jnp.logical_or(uid == poison, poison == POISON_ALL)
-            logits = jnp.where(bad,
-                               jnp.asarray(jnp.nan, logits.dtype), logits)
-            with jax.named_scope("sample"):
-                nxt = pick(logits, uid[None], (pos0 + c)[None])
-            return pool, nxt[0], rows_finite(logits)[0]
-
-        return run
-
-    def _build_prefill(self, c: int):
-        return self._jit(self._prefill_fn(c))
-
-    def _build_cow(self, _bucket: int):
-        """The copy-on-write block copy (``paged.copy_block``) as one
-        compiled program for every (src, dst) pair — block ids are
-        traced operands, so privatizing never recompiles. Donated like
-        the step programs (the copy must not pay a whole-pool
-        allocate). Built lazily and only when a CoW actually fires,
-        which steady state never does — the recompile-guard tests keep
-        holding with the barrier armed."""
-        return jax.jit(copy_block, donate_argnums=(0,))
-
-    def _build_cow_rows(self, _bucket: int):
-        """The sub-block share copy (``paged.copy_block_rows``) as one
-        compiled program for every (src, dst, rows) triple — all three
-        are traced operands, so a partial hit never recompiles. Donated
-        like the step programs; built lazily on the first partial hit
-        (``prefix_partial`` off keeps the program set byte-identical to
-        the round-13 engine's)."""
-        return jax.jit(copy_block_rows, donate_argnums=(0,))
-
-    def _build_implant(self, _bucket: int):
-        """The KV-handoff import copy (``paged.implant_block``) as one
-        compiled program for every destination block — the block id is
-        a traced operand, so importing a sequence never recompiles past
-        the first handoff. Donated like the step programs. Built lazily
-        on the first import (the "first migration wave" — the
-        zero-new-compiles-after contract starts there)."""
-        return jax.jit(implant_block, donate_argnums=(0,))
-
     # -- model identity (snapshots + KV handoff pin it) ----------------
 
     def model_meta(self, version: int | None = None) -> dict:
@@ -1262,7 +765,7 @@ class DecodeEngine:
 
     # -- live weight hot-swap (round 17, DESIGN.md section 23) ---------
 
-    def _params_for(self, version: int) -> LMParams:
+    def _params_for(self, version: int) -> ServedModel:
         try:
             return self.weights[int(version)]
         except KeyError:
@@ -1281,7 +784,7 @@ class DecodeEngine:
                  if s.weights_version is not None}
         return pins
 
-    def load_weights(self, version: int, params: LMParams) -> dict:
+    def load_weights(self, version: int, params: ServedModel) -> dict:
         """Install ``params`` as weights version ``version`` —
         double-buffered: the previous versions stay resident while any
         live sequence pins them (an in-flight request must finish on
@@ -3078,9 +2581,12 @@ class DecodeEngine:
     def kv_bytes_stored(self) -> int:
         """Live-token KV bytes at the engine's storage dtype — the
         measured form of the roofline's ``B * kv_bytes`` term."""
-        return int(self.live_tokens() * kv_bytes_per_token(
-            self.cfg.kv_dtype, self.pool.k.shape[0], self.kv_heads,
-            self.dh))
+        return int(self.live_tokens() * self._kv_bytes_per_token())
+
+    def _kv_bytes_per_token(self) -> float:
+        spec = self.spec
+        return kv_bytes_per_token(self.cfg.kv_dtype, spec.kv_layers,
+                                  spec.kv_heads, spec.head_dim)
 
     def telemetry_record(self, tokens_per_sec=None) -> dict:
         """One schema-v5 ``decode`` record (``runtime/telemetry.py``
@@ -3249,12 +2755,10 @@ class DecodeEngine:
                           SCRATCH_BLOCK, jnp.int32)
         z = jnp.zeros((b,), jnp.int32)
         rows = () if self.state is None else (z,)
-        rep = StepReport.of(self._wrap(self._decode_fn(b)), self.params,
+        rep = StepReport.of(self.programs.body("decode", b), self.params,
                             self._cache(), tables, z, z, z,
                             jnp.int32(POISON_NONE), *rows)
-        per_tok = kv_bytes_per_token(self.cfg.kv_dtype,
-                                     self.pool.k.shape[0],
-                                     self.kv_heads, self.dh)
+        per_tok = self._kv_bytes_per_token()
         kv_bytes, scale_bytes = pool_bytes(self.pool)
         return {
             "slot_bucket": b,

@@ -135,12 +135,6 @@ def build_generate_parser() -> argparse.ArgumentParser:
                         "step from the n-gram prompt-copy drafter "
                         "(greedy verification — requires temperature "
                         "0; a step emits 1 + accepted tokens; 0 = off)")
-    p.add_argument("--kernel", choices=["gather", "fused"],
-                   default="gather",
-                   help="decode attention path: 'gather' (each slot's "
-                        "blocks gathered and attended as stored, two MXU "
-                        "products) or 'fused' (Pallas block-table walk, "
-                        "single-device; ops/pallas_paged_attention.py)")
     # shared-prefix KV reuse (round 13, DESIGN.md section 19)
     p.add_argument("--prefix_cache", default=True,
                    action=argparse.BooleanOptionalAction,
@@ -940,7 +934,7 @@ def generate_main(argv=None) -> int:
             temperature=args.temperature, top_k=args.top_k,
             top_p=args.top_p, seed=args.sample_seed,
             use_rope=args.use_rope, speculate=args.speculate,
-            kernel=args.kernel, prefix_cache=args.prefix_cache,
+            prefix_cache=args.prefix_cache,
             spill_blocks=args.spill_blocks,
             spill_restore_per_step=args.spill_restore_per_step,
             prefix_partial=args.prefix_partial)
@@ -1065,7 +1059,7 @@ def generate_main(argv=None) -> int:
             "layers": args.layers, "heads": args.heads,
             "kv_dtype": args.kv_dtype, "max_slots": args.max_slots,
             "block_size": args.block_size, "tp": tp,
-            "speculate": args.speculate, "kernel": args.kernel,
+            "speculate": args.speculate,
             "prefix_cache": args.prefix_cache,
             "n_prompts": len(prompts), "max_new": args.max_new,
             "device_kind": jax.devices()[0].device_kind}
@@ -1165,7 +1159,6 @@ def generate_main(argv=None) -> int:
         "kv_dtype": args.kv_dtype,
         "tp": tp,
         "speculate": args.speculate,
-        "kernel": args.kernel,
         "drafted_tokens": engine.drafted_tokens,
         "accepted_tokens": engine.accepted_tokens,
         "accept_rate": (round(engine.accepted_tokens

@@ -161,8 +161,8 @@ def init_state(n_layers: int, slots: int, d_inner: int, d_state: int,
 
 def _heads_major(x, head_dim: int):
     """``[..., block, H_kv*dh] -> [..., H_kv, block, dh]``: stored rows
-    to the head-major block the int8 quantizer, the Pallas walk and the
-    host documents speak. Works on numpy and jax arrays alike."""
+    to the head-major block the int8 quantizer and the host documents
+    speak. Works on numpy and jax arrays alike."""
     *lead, blk, m = x.shape
     return x.reshape(*lead, blk, m // head_dim, head_dim).swapaxes(-3, -2)
 
@@ -506,41 +506,12 @@ def corrupt_block(pool: PagedKV, block: int) -> PagedKV:
                          v=pool.v.at[:, block].set(bad))
 
 
-def fused_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
-                      tables: jax.Array, lengths: jax.Array,
-                      interpret: bool | None = None) -> jax.Array:
-    """Single-query attention for one layer, fused over the block
-    tables (``ops/pallas_paged_attention.py``): the Pallas kernel walks
-    each slot's table directly and streams pool blocks through VMEM
-    with the int8 per-block dequant folded in — no gathered
-    ``[B, H_kv, T_cap, dh]`` layout ever reaches HBM. ``q [B, H, dh]``
-    f32, ``tables [B, MB]`` int32, ``lengths [B]`` attendable positions
-    (the engine passes ``lengths + 1``). Differential oracle:
-    ``decode_attn(q, *vmap(gather_layer), lengths)`` — on the CPU
-    interpreter within 8 ULP of the row's scale at every pool dtype
-    (tests/test_pallas_paged_attention.py); on the chip no ULP bound
-    is measured, and greedy tokens match the oracle's at float32 matmul
-    precision only (the kernel module states the contract).
-
-    The kernel's ``BlockSpec``s walk ``[n_blocks, H_kv, block, dh]``
-    (a 64-lane block of a wider stored row is not a legal Mosaic
-    block), so this ONE layer is re-formed in front of the call: a
-    slab-sized copy a layer, on the opt-in path only."""
-    from ..ops.pallas_paged_attention import paged_decode_attn
-    ks = None if pool.k_scale is None else pool.k_scale[layer]
-    vs = None if pool.v_scale is None else pool.v_scale[layer]
-    return paged_decode_attn(q, _heads_major(pool.k[layer], pool.head_dim),
-                             _heads_major(pool.v[layer], pool.head_dim),
-                             ks, vs, tables, lengths, interpret=interpret)
-
-
 def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                        tables: jax.Array, lengths: jax.Array) -> jax.Array:
     """Single-query attention for one layer over the gathered rows AS
-    STORED — what the engine's decode-side programs run
-    (``kernel="gather"``). ``q [B, H, dh]`` f32, ``tables [B, MB]``
-    int32, ``lengths [B]`` attendable positions; returns ``[B, H, dh]``
-    f32. The same mathematics as the oracle ``decode_attn(q,
+    STORED — what the engine's decode-side programs run. ``q [B, H,
+    dh]`` f32, ``tables [B, MB]`` int32, ``lengths [B]`` attendable
+    positions; returns ``[B, H, dh]`` f32. The same mathematics as the oracle ``decode_attn(q,
     *vmap(gather_layer), lengths)`` — same mask, scale and f32 softmax —
     as two matrix products over ``[B, T_cap, H_kv*dh]`` in the pool's
     dtype, accumulated in f32:
@@ -609,7 +580,7 @@ def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
     ``models.attention.gather_paged_kv`` — the attention read against a
     block table; this wrapper only adds the dtype story. With
     ``decode_attn`` this is the ORACLE the tests hold
-    ``stored_decode_attn`` and the Pallas walk to; in the engine only
+    ``stored_decode_attn`` to; in the engine only
     the prefill chunk reads through it (one slot's view), the decode
     side attends over the rows as stored."""
     from ..models.attention import gather_paged_kv
@@ -630,3 +601,17 @@ def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
         vs = jnp.repeat(pool.v_scale[layer, table].T, blk, axis=1)
         return (k.astype(jnp.float32) * ks[..., None],
                 v.astype(jnp.float32) * vs[..., None])
+
+
+def gathered_chunk_attn(pool: PagedKV, layer: int, q: jax.Array,
+                        table: jax.Array, pos0) -> jax.Array:
+    """A prefill chunk's read: ``q [C, H, dh]`` at positions ``pos0 ..
+    pos0+C-1`` of ONE sequence attends causally over its gathered view
+    (``gather_layer`` + ``models.attention.chunk_attn``, the oracle's
+    arithmetic: one slot's f32 head-split view is small). Returns
+    ``[C, H, dh]``."""
+    from ..models.attention import chunk_attn
+    ck, cv = gather_layer(pool, layer, table)
+    with jax.named_scope("attn"):
+        y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0)
+    return y.transpose(1, 0, 2)

@@ -164,17 +164,12 @@ def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array, layer: int,
     them, as with the zero tail of a contiguous cache.
 
     This gather + ``decode_attn`` two-pass is the DIFFERENTIAL ORACLE
-    of the decode engine's two cache reads, and what the lockstep
+    of the decode engine's cache read, and what the lockstep
     ``generate`` and the engine's prefill chunk (``chunk_attn`` over
     one slot's view) compute. The engine's decode-side programs do not
     run it: they attend over the gathered rows as stored
     (``decode/paged.py::stored_decode_attn`` — no f32 head-split copy
-    of the view; held to this oracle in tests/test_paged_layout.py).
-    The fused Pallas block-walk kernel
-    (``ops/pallas_paged_attention.py``, ``EngineConfig(kernel=``)
-    streams the same blocks through VMEM without ever materializing
-    this layout in HBM, and must match this path bit-for-bit at f32
-    under jit (tests/test_pallas_paged_attention.py pins it)."""
+    of the view; held to this oracle in tests/test_paged_layout.py)."""
     layers = jnp.full_like(table, layer)
     k = pool_k[layers, table]              # [MB, block, H_kv*dh]
     v = pool_v[layers, table]

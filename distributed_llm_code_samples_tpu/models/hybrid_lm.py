@@ -14,9 +14,9 @@ layer kind — Mamba leaves ``[L_m, ...]``, attention leaves ``[L_a,
 The pattern is static (pytree metadata): ``layers`` says, per model
 layer, which kind it is and which index of its kind's stack — and of its
 kind's CACHE (``decode/paged.py``: KV blocks for attention, a recurrent
-state row for Mamba) — it owns. ``models.lm.LMParams`` answers the same
-two questions (every layer attention, index = layer), which is the whole
-of the seam ``decode/engine.py::_trunk`` needs.
+state row for Mamba) — it owns. ``HybridLMParams``' methods are the
+family's answers to ``models/face.py::ServedModel``, from which
+``decode/programs.py`` builds the serving programs.
 
 Precision: the residual stream, the norms, the convolution and the
 recurrence are float32 whatever the weights' type; a matrix product
@@ -38,8 +38,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import ssm
+from .face import ATTN, CacheSpec, mm, qkv_heads
 
-ATTN, MAMBA = "attn", "mamba"
+MAMBA = "mamba"
 
 
 class MambaStack(NamedTuple):
@@ -126,6 +127,53 @@ class HybridLMParams:
     def num_params(self) -> int:
         """Parameters, the tied embedding counted once."""
         return sum(x.size for x in jax.tree_util.tree_leaves(self))
+
+    # -- the model face (``models/face.py::ServedModel``) --------------
+
+    def cache_spec(self, n_heads: int) -> CacheSpec:
+        m = self.mamba
+        return CacheSpec(
+            kv_layers=self.attn.wq.shape[0],
+            kv_heads=self.attn.wk.shape[1] // self.head_dim,
+            head_dim=self.head_dim, rec_layers=m.w_in.shape[0],
+            d_inner=m.conv_w.shape[2], d_state=m.a_log.shape[1],
+            d_conv=m.conv_w.shape[1])
+
+    def embed(self, tokens, positions, lookup):
+        # no position of any kind: the recurrent layers carry order
+        return lookup(self.wte, tokens).astype(jnp.float32)
+
+    def norm(self, g, x):
+        return rmsnorm(g, x, self.eps)
+
+    def attn_qkv(self, i, a, positions, head_dim, use_rope):
+        return qkv_heads(self.attn.wq, self.attn.wk, self.attn.wv, i, a,
+                         positions, head_dim, use_rope)
+
+    def attn_out(self, i, y):
+        return mm(y, self.attn.wo[i])
+
+    def recurrent_step(self, i, a, tail, state):
+        """Mamba mixer ``i`` for one token of each of ``b`` sequences:
+        ``a [b, d]``, ``tail [b, K-1, D]``, ``state [b, N, D]``."""
+        return _mamba(self, i, a, tail, state, ssm.conv_step,
+                      ssm.scan_step)
+
+    def recurrent_chunk(self, i, a, tail, state):
+        """Mamba mixer ``i`` over a chunk of ONE sequence: ``a [c, d]``
+        the normed residual, ``tail [K-1, D]`` and ``state [N, D]`` what
+        the sequence carries (zeros at position 0). Returns ``(out [c,
+        d], tail, state)`` after the chunk."""
+        return _mamba(self, i, a, tail, state, ssm.conv_chunk,
+                      ssm.scan_chunk)
+
+    def ffn(self, l, h):
+        gate = mm(h, self.mlp.w_gate[l])
+        return mm(jax.nn.silu(gate) * mm(h, self.mlp.w_up[l]),
+                  self.mlp.w_down[l])
+
+    def head(self, x):
+        return mm(rmsnorm(self.ln_f, x, self.eps), self.wte)
 
 
 class HybridSpec(NamedTuple):
@@ -244,18 +292,7 @@ def init_hybrid_lm(key: jax.Array, spec: HybridSpec, dtype=jnp.float32,
         max_seq_len=spec.max_seq_len)
 
 
-# -- the block's pieces (decode/engine.py::_trunk puts them together) ----
-
-
-def mm(x: jax.Array, w: jax.Array) -> jax.Array:
-    """``x [.., in] @ w[out, in].T``. Operands of one type multiply as
-    they are (``LMParams`` and its float32 weights: the program the
-    engine always built). Otherwise the activations take the weights'
-    type and the product accumulates in float32."""
-    if x.dtype == w.dtype:
-        return x @ w.T
-    return jnp.matmul(x.astype(w.dtype), w.T,
-                      preferred_element_type=jnp.float32)
+# -- the block's pieces (the face's methods above put them together) ----
 
 
 def rmsnorm(g: jax.Array, x: jax.Array, eps: float) -> jax.Array:
@@ -263,11 +300,6 @@ def rmsnorm(g: jax.Array, x: jax.Array, eps: float) -> jax.Array:
     x = x.astype(jnp.float32)
     ms = jnp.mean(x * x, axis=-1, keepdims=True)
     return g.astype(jnp.float32) * (x * jax.lax.rsqrt(ms + eps))
-
-
-def gated_mlp(p: HybridLMParams, l: int, a: jax.Array) -> jax.Array:
-    gate = mm(a, p.mlp.w_gate[l])
-    return mm(jax.nn.silu(gate) * mm(a, p.mlp.w_up[l]), p.mlp.w_down[l])
 
 
 def _mamba_dt_b_c(p: HybridLMParams, i: int, x: jax.Array):
@@ -295,17 +327,3 @@ def _mamba(p: HybridLMParams, i: int, a, tail, s, conv, scan):
     y, s = scan(x, dt, -jnp.exp(m.a_log[i].astype(f32)), b, c,
                 m.d[i].astype(f32), s)
     return mm(y * jax.nn.silu(z), m.w_out[i]), tail, s
-
-
-def mamba_chunk(p: HybridLMParams, i: int, a, tail, s):
-    """Mamba mixer ``i`` over a chunk of ONE sequence: ``a [c, d]`` the
-    normed residual, ``tail [K-1, D]`` and ``s [N, D]`` the state the
-    sequence carries (zeros at position 0). Returns ``(out [c, d], tail,
-    s)`` with the state after the chunk."""
-    return _mamba(p, i, a, tail, s, ssm.conv_chunk, ssm.scan_chunk)
-
-
-def mamba_step(p: HybridLMParams, i: int, a, tail, s):
-    """Mamba mixer ``i`` for one token of each of ``b`` sequences:
-    ``a [b, d]``, ``tail [b, K-1, D]``, ``s [b, N, D]``."""
-    return _mamba(p, i, a, tail, s, ssm.conv_step, ssm.scan_step)

@@ -30,6 +30,7 @@ from jax import lax
 
 from ..ops.norm import layernorm
 from ..ops.xent import xent_loss
+from .face import ATTN, CacheSpec, mm, qkv_heads
 from .transformer import (TransformerParams, init_transformer,
                           transformer_fwd)
 
@@ -63,20 +64,14 @@ class LMParams(NamedTuple):
         return (self.wte.size + self.wpe.size + self.ln_f.size +
                 self.blocks.num_params())
 
-    # What the decode engine's trunk asks of a model
-    # (``decode/engine.py::_trunk``; ``models/hybrid_lm.py`` answers the
-    # same for a stack of mixed layer kinds): per layer its kind and the
-    # index of its weights and cache, the attention stack, the gains of
-    # the norm before each mixer and each FFN.
+    # The model face (``models/face.py::ServedModel``): what
+    # ``decode/programs.py`` builds the serving programs from. GPT-2's
+    # answers: learned positions, the gain-only LayerNorm, the ReLU
+    # FFN, every layer attention with the cache index of its own
+    # number.
     @property
     def layers(self) -> tuple:
-        """Every layer is attention and owns the cache index of its
-        own number."""
-        return tuple(("attn", l) for l in range(self.n_layers))
-
-    @property
-    def attn(self) -> TransformerParams:
-        return self.blocks
+        return tuple((ATTN, l) for l in range(self.n_layers))
 
     @property
     def norm_in(self) -> jax.Array:
@@ -85,6 +80,31 @@ class LMParams(NamedTuple):
     @property
     def norm_ff(self) -> jax.Array:
         return self.blocks.ln2
+
+    def cache_spec(self, n_heads: int) -> CacheSpec:
+        dh = self.d_model // n_heads
+        return CacheSpec(self.n_layers, self.blocks.wk.shape[1] // dh, dh)
+
+    def embed(self, tokens, positions, lookup):
+        return lookup(self.wte, tokens) + self.wpe[positions]
+
+    def norm(self, g, x):
+        return layernorm(g, x)
+
+    def attn_qkv(self, i, a, positions, head_dim, use_rope):
+        blk = self.blocks
+        return qkv_heads(blk.wq, blk.wk, blk.wv, i, a, positions,
+                         head_dim, use_rope)
+
+    def attn_out(self, i, y):
+        return mm(y, self.blocks.wo[i])
+
+    def ffn(self, l, h):
+        blk = self.blocks
+        return jnp.maximum(h @ blk.w1[l].T, 0.0) @ blk.w2[l].T
+
+    def head(self, x):
+        return mm(layernorm(self.ln_f, x), self.wte)
 
     # The CLI's uniform per-layer report reads ``.w1``/``.w2``
     # (train_ffns.py:370-371 prints layers_params[0]); delegate to the

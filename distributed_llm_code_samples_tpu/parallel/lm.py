@@ -688,13 +688,32 @@ def tp_decode_specs() -> LMParams:
     return _lm_tp_specs()
 
 
-def tp_shard_params(params: LMParams, mesh) -> LMParams:
+def _validate_tp_decode(params: LMParams, n_heads: int, mesh) -> int:
+    """What the Megatron decode layout needs of a model, checked before
+    anything is laid out: heads, KV heads, FFN width and vocabulary all
+    divide by the model axis. Returns the axis size."""
+    require_axes(mesh, MODEL_AXIS)
+    n = mesh.shape[MODEL_AXIS]
+    _validate_tp(params.blocks, n_heads, n)
+    if params.vocab % n:
+        raise ValueError(f"vocab={params.vocab} not divisible by "
+                         f"model-axis size {n}")
+    return n
+
+
+def tp_shard_params(params: LMParams, mesh,
+                    n_heads: int | None = None) -> LMParams:
     """Lay the LM params out in the Megatron decode layout (vocab/head
     sharded) ONCE. ``tp_generate``/``tp_sample`` and the decode engine
     detect the layout and skip their per-call reshard copy, so repeat
     decodes (serving loops, ``bench_decode``) pay neither a retrace
-    (the program is cached) nor a per-call host-side param copy."""
-    require_axes(mesh, MODEL_AXIS)
+    (the program is cached) nor a per-call host-side param copy. With
+    ``n_heads`` (the serving engine's ``--tp`` set-up) the layout's
+    divisibility is checked first."""
+    if n_heads is None:
+        require_axes(mesh, MODEL_AXIS)
+    else:
+        _validate_tp_decode(params, n_heads, mesh)
     if _tp_sharded_already(params, mesh):
         return params
     return _shard(params, mesh, _lm_tp_specs())
@@ -717,12 +736,7 @@ def _tp_decode(params, prompt, n_new, mesh, n_heads, use_rope,
     RUNTIME operand (new seeds draw new continuations from the SAME
     compiled program — no retrace, no cache thrash). Params already in
     the ``tp_shard_params`` layout skip the reshard copy."""
-    require_axes(mesh, MODEL_AXIS)
-    n = mesh.shape[MODEL_AXIS]
-    _validate_tp(params.blocks, n_heads, n)  # heads/kv/ffn divisibility
-    if params.vocab % n:
-        raise ValueError(f"vocab={params.vocab} not divisible by "
-                         f"model-axis size {n}")
+    n = _validate_tp_decode(params, n_heads, mesh)
     fn = _tp_decode_program(mesh, n_new, n_heads, params.vocab // n,
                             params.max_seq_len,
                             params.d_model // n_heads, use_rope,

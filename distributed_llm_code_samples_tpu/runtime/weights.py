@@ -63,14 +63,13 @@ def model_fingerprint(params, n_heads: int) -> dict:
     the float reduction order, which legitimately varies across TP
     layouts, can't cause a false mismatch."""
     import jax.numpy as jnp
-    dh = params.d_model // int(n_heads)
     return {
         "vocab": int(params.vocab),
         "d_model": int(params.d_model),
         "n_layers": int(params.n_layers),
         "max_seq_len": int(params.max_seq_len),
         "n_heads": int(n_heads),
-        "kv_heads": int(params.attn.wk.shape[1] // dh),
+        "kv_heads": int(params.cache_spec(int(n_heads)).kv_heads),
         "wte0_sum": round(float(jnp.sum(params.wte[0])), 2),
     }
 

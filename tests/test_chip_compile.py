@@ -106,47 +106,6 @@ def _total_bytes(compiled) -> int:
 GPT2 = dict(vocab=50257, d=768, layers=12, heads=12, dh=64, max_seq=1024)
 
 
-@pytest.mark.parametrize("kv_dtype", ["f32", "bf16", "int8"])
-def test_fused_paged_walk_compiles_at_engine_block(one_chip, kv_dtype):
-    """The fused block-table walk at the engine's DEFAULT block size
-    (16) and GPT-2-small heads (H12, dh64, 64 blocks per sequence =
-    max_seq_len 1024), every pool dtype — the shape Mosaic used to
-    refuse ("cannot statically prove that index in dimension 1 is a
-    multiple of 128") while every interpret-mode test passed."""
-    from distributed_llm_code_samples_tpu.ops.pallas_paged_attention import (
-        paged_decode_attn)
-    b, h, dh, blk, mb = 4, GPT2["heads"], GPT2["dh"], 16, 64
-    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}[
-        kv_dtype]
-    nb = 1 + b * mb
-
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    scale = s((nb, h), jnp.float32) if kv_dtype == "int8" else None
-    pool = s((nb, h, blk, dh), dt)
-    compiled = jax.jit(
-        functools.partial(paged_decode_attn, interpret=False)).lower(
-            s((b, h, dh), jnp.float32), pool, pool, scale, scale,
-            s((b, mb), jnp.int32), s((b,), jnp.int32)).compile()
-    assert MOSAIC in compiled.as_text()
-
-
-def test_fused_paged_walk_refused_past_vmem_up_front():
-    """What Mosaic would refuse (the whole row no longer fits scoped
-    VMEM) the engine refuses at construction, limit named, on every
-    backend — never a Mosaic error in the first decode step."""
-    from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
-                                                         EngineConfig)
-    from distributed_llm_code_samples_tpu.models import init_lm
-    params = init_lm(jax.random.PRNGKey(0), 64, 32, 1, max_seq_len=64,
-                     n_heads=2)
-    cfg = EngineConfig(block_size=16, max_blocks_per_seq=2048,
-                       n_blocks=2049, max_slots=1, kernel="fused")
-    with pytest.raises(ValueError, match="MiB of scratch, over the 12 MiB"):
-        DecodeEngine(params, 2, cfg)
-
-
 def test_flash_attention_compiles_at_gpt2_shape(one_chip):
     """Flash forward and both backward kernels at T=1024, dh=64."""
     from distributed_llm_code_samples_tpu.ops.pallas_attention import (
@@ -195,8 +154,8 @@ def _step_args(params, pool, slots, mbps, chunk):
 
 @pytest.fixture(scope="module")
 def gpt2_engine_args():
-    """``kernel -> (engine, decode args, prefill args)`` for the engine
-    chip_smoke's serving phases build (GPT-2 small, 4 slots, block 16,
+    """``kv_dtype -> (engine, decode args, prefill args)`` for the engine
+    chip_smoke's serving phase builds (GPT-2 small, 4 slots, block 16,
     34 blocks per sequence). The engine fingerprints its weights, so
     they are real (on the CPU); what is lowered is their shapes."""
     from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
@@ -206,40 +165,49 @@ def gpt2_engine_args():
                      GPT2["layers"], max_seq_len=GPT2["max_seq"],
                      n_heads=GPT2["heads"])
 
-    def build(kernel, kv_dtype):
+    def build(kv_dtype):
         slots, mbps, chunk = 4, 34, 16
         eng = DecodeEngine(params, GPT2["heads"], EngineConfig(
             block_size=16, n_blocks=1 + slots * mbps, max_slots=slots,
             max_blocks_per_seq=mbps, prefill_chunk=chunk,
-            kv_dtype=kv_dtype, kernel=kernel))
+            kv_dtype=kv_dtype))
         decode, prefill = _step_args(params, eng.pool, slots, mbps, chunk)
         return eng, decode, prefill, slots, chunk
 
     return build
 
 
-@pytest.mark.parametrize("kernel,kv_dtype", [("gather", "bf16"),
-                                             ("fused", "f32")])
-def test_engine_decode_step_compiles(one_chip, gpt2_engine_args,
-                                     monkeypatch, kernel, kv_dtype):
-    """The engine's own decode program at chip_smoke's phase-1 (gather,
-    bf16 KV) and phase-2 (fused, f32 KV) shapes: compiles for one v5e
-    chip and fits it; the fused one carries the Mosaic call."""
-    from distributed_llm_code_samples_tpu.ops import pallas_paged_attention
-    # the engine asks jax.default_backend(), which here is the CPU:
-    # steer its kernel to Mosaic, as it goes on the chip
-    monkeypatch.setattr(pallas_paged_attention, "_interpret_arg",
-                        lambda interpret: False)
-    eng, decode, _, slots, _ = gpt2_engine_args(kernel, kv_dtype)
+@pytest.mark.parametrize("kv_dtype", ["bf16", "f32", "int8"])
+def test_engine_decode_step_compiles(one_chip, gpt2_engine_args, kv_dtype):
+    """The engine's own decode program at chip_smoke's serving shape,
+    every pool dtype: compiles for one v5e chip and fits it, is XLA's
+    alone (no Mosaic call: the one cache read is
+    ``paged.stored_decode_attn``), and beyond the gather holds no array
+    of the gathered view's size that is wider than the operand the
+    products take (the pool's own dtype; bf16 for int8 codes, which are
+    exact in it)."""
+    eng, decode, _, slots, _ = gpt2_engine_args(kv_dtype)
     compiled = eng._program("decode", slots).lower(
         *_shapes_of(decode, one_chip)).compile()
-    assert (MOSAIC in compiled.as_text()) == (kernel == "fused")
+    hlo = compiled.as_text()
+    assert MOSAIC not in hlo
     assert _total_bytes(compiled) < HBM_V5E
+    pool = eng.pool
+    view = (slots * eng.cfg.max_blocks_per_seq * pool.block_size
+            * pool.k.shape[-1])
+    operand = max(pool.k.dtype.itemsize, 2 if kv_dtype == "int8" else 0)
+    wide = [r for r in _entry_results(hlo)
+            if r[2] >= view and r[1] > operand
+            and r[0] not in ("parameter", "get-tuple-element", "tuple",
+                             "bitcast")
+            and (r[1], r[2]) not in {(x.dtype.itemsize, x.size) for x in
+                                     jax.tree_util.tree_leaves(decode[:2])}]
+    assert not wide, wide
 
 
 def test_engine_prefill_chunk_compiles(one_chip, gpt2_engine_args):
     """One prefill chunk program (16 tokens) of the phase-1 engine."""
-    eng, _, prefill, _, chunk = gpt2_engine_args("gather", "bf16")
+    eng, _, prefill, _, chunk = gpt2_engine_args("bf16")
     compiled = eng._program("prefill", chunk).lower(
         *_shapes_of(prefill, one_chip)).compile()
     assert _total_bytes(compiled) < HBM_V5E

@@ -274,7 +274,7 @@ def test_gqa_and_rope_engine_match_lockstep(prompts):
     gqa = init_lm(jax.random.PRNGKey(3), V, D, L, max_seq_len=64,
                   n_heads=H, n_kv_heads=2)
     eng = DecodeEngine(gqa, H, EngineConfig(**BASE))
-    assert eng.kv_heads == 2                     # pool shrinks with GQA
+    assert eng.spec.kv_heads == 2                # pool shrinks with GQA
     outs = eng.generate(prompts, 6)
     for p, out in zip(prompts, outs):
         ref = np.asarray(generate(gqa, jnp.asarray([p]), 6,

@@ -109,7 +109,7 @@ def cached_logits(eng, tokens, chunks, decode_from=None):
     bodies = {}                     # one jitted body a chunk size
 
     def head(x):
-        return eng._logits(p, eng._norm(p, p.ln_f, x))
+        return eng.programs.logits(p, x)
 
     while pos < decode_from:
         c = min(chunks, decode_from - pos)
@@ -135,16 +135,17 @@ def cached_logits(eng, tokens, chunks, decode_from=None):
 
 
 def _prefill_rows(eng, c):
-    """``_prefill_fn``'s trunk with every row's hidden state returned
-    (the program keeps the last row and picks from it)."""
+    """The prefill program up to the head, every row's hidden state
+    returned (the program keeps the last row and picks from it)."""
     return jax.jit(lambda p, cache, table, pos0, toks, row:
-                   eng._prefill_hidden(c, p, cache, table, pos0, toks, row))
+                   eng.programs.prefill_hidden(c, p, cache, table, pos0,
+                                               toks, row))
 
 
 def _decode_rows(eng, b):
     return jax.jit(lambda p, cache, tables, lengths, toks, rows:
-                   eng._decode_hidden(b, p, cache, tables, lengths, toks,
-                                      rows))
+                   eng.programs.decode_hidden(b, p, cache, tables, lengths,
+                                              toks, rows))
 
 
 # -- (a) model against the reference, on logits ---------------------------
@@ -423,48 +424,161 @@ def test_weights_come_in_the_type_the_config_states():
         hybrid_lm.spec_from_config(dict(TOY, model_type="gpt2"))
 
 
-# -- (g) the LMParams programs are unchanged by the seam --------------------
+# -- (g) the step programs are the inline references -----------------------
+#
+# What ``decode/programs.py`` builds from the model face lowers, op for
+# op, to the programs written around each family's weights by name: the
+# GPT-2 block as the engine inlined it before PR 27, the hybrid block as
+# PR 27 inlined it, with the Megatron collectives where a mesh is set.
+# The references live here, plain; the builder's own ``_embed``,
+# ``_trunk`` and ``logits`` are stood in for by them, and the cache
+# writes and reads, the head's poison / pick / flags and the wrapping
+# stay the builder's on both sides.
 
 
-def _inline_gpt2_trunk(self, p, pool, x, positions, write_attn, mix=None):
-    """``DecodeEngine._trunk`` as it stood before the seam (PR 26): the
-    GPT-2 block written around ``LMParams``."""
-    from distributed_llm_code_samples_tpu.ops.norm import layernorm
-    blk = p.blocks
-    n = x.shape[0]
-    for l in range(p.n_layers):
-        a = layernorm(blk.ln1[l], x)
-        q, k, v = self._attn_qkv(p, l, a, positions)
-        pool, y = write_attn(l, pool, q, k, v)
-        y = y.reshape(n, -1) @ blk.wo[l].T
-        x = x + y
-        h = layernorm(blk.ln2[l], x)
-        f = jnp.maximum(h @ blk.w1[l].T, 0.0) @ blk.w2[l].T
-        x = x + f
-    return pool, x
+def _rot(q, positions):
+    from distributed_llm_code_samples_tpu.models.attention import rope
+    return jax.vmap(lambda x, pos: rope(x[:, None, :],
+                                        pos[None])[:, 0, :])(q, positions)
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill"])
-def test_lm_programs_are_unchanged_by_the_seam(monkeypatch, kind):
-    """An ``LMParams`` engine's decode and prefill programs lower, op
-    for op, to what the inline GPT-2 trunk lowered to: same operands
-    (the pool alone at argument 1, no state row), same StableHLO."""
-    params = init_lm(jax.random.PRNGKey(0), 96, 32, 2, 64, n_heads=4,
-                     n_kv_heads=2)
+class InlineGPT2:
+    """``LMParams`` served as the engine wrote it before any seam."""
+
+    def _embed(self, p, tokens, positions):
+        from distributed_llm_code_samples_tpu.parallel.lm import vp_embed
+        rows = (p.wte[tokens] if self.mesh is None
+                else vp_embed(p.wte, tokens))
+        return rows + p.wpe[positions]
+
+    def _trunk(self, p, pool, x, positions, write_attn, mix=None):
+        from distributed_llm_code_samples_tpu.ops.norm import layernorm
+        from distributed_llm_code_samples_tpu.parallel.collectives import (
+            all_reduce)
+        from distributed_llm_code_samples_tpu.parallel.mesh import MODEL_AXIS
+        reduce = ((lambda y: y) if self.mesh is None
+                  else (lambda y: all_reduce(y, MODEL_AXIS)))
+        blk = p.blocks
+        n = x.shape[0]
+        for l in range(p.n_layers):
+            a = layernorm(blk.ln1[l], x)
+            # the projections one at a time: each weight is sliced where
+            # it is used
+            dh = self.spec.head_dim
+            q = (a @ blk.wq[l].T).reshape(-1, blk.wq.shape[1] // dh, dh)
+            k = (a @ blk.wk[l].T).reshape(-1, blk.wk.shape[1] // dh, dh)
+            v = (a @ blk.wv[l].T).reshape(-1, blk.wv.shape[1] // dh, dh)
+            if self.cfg.use_rope:
+                q, k = _rot(q, positions), _rot(k, positions)
+            pool, y = write_attn(l, pool, q, k, v)
+            x = x + reduce(y.reshape(n, -1) @ blk.wo[l].T)
+            h = layernorm(blk.ln2[l], x)
+            x = x + reduce(jnp.maximum(h @ blk.w1[l].T, 0.0) @ blk.w2[l].T)
+        return pool, x
+
+    def logits(self, p, x):
+        from distributed_llm_code_samples_tpu.ops.norm import layernorm
+        from distributed_llm_code_samples_tpu.parallel.collectives import (
+            all_gather)
+        from distributed_llm_code_samples_tpu.parallel.mesh import MODEL_AXIS
+        logits = layernorm(p.ln_f, x) @ p.wte.T
+        if self.mesh is not None:
+            logits = all_gather(logits, MODEL_AXIS, dim=1)
+        return logits
+
+
+class InlineHybrid:
+    """``HybridLMParams`` as PR 27 inlined it (float32 weights: every
+    product is ``x @ w.T``); a recurrent layer's cache closure stays the
+    builder's and reaches the mixer (``hybrid_lm._mamba``) as it did."""
+
+    def _embed(self, p, tokens, positions):
+        return p.wte[tokens].astype(jnp.float32)
+
+    def _trunk(self, p, cache, x, positions, write_attn, mix=None):
+        pool, state = cache
+        n = x.shape[0]
+        dh = p.head_dim
+        for l, (kind, i) in enumerate(p.layers):
+            a = hybrid_lm.rmsnorm(p.norm_in[l], x, p.eps)
+            if kind == "attn":
+                t = p.attn
+                q = (a @ t.wq[i].T).reshape(-1, t.wq.shape[1] // dh, dh)
+                k = (a @ t.wk[i].T).reshape(-1, t.wk.shape[1] // dh, dh)
+                v = (a @ t.wv[i].T).reshape(-1, t.wv.shape[1] // dh, dh)
+                pool, y = write_attn(i, pool, q, k, v)
+                y = y.reshape(n, -1) @ t.wo[i].T
+            else:
+                state, y = mix(i, state, a)
+            x = x + y
+            h = hybrid_lm.rmsnorm(p.norm_ff[l], x, p.eps)
+            gate = h @ p.mlp.w_gate[l].T
+            x = x + (jax.nn.silu(gate) * (h @ p.mlp.w_up[l].T)
+                     ) @ p.mlp.w_down[l].T
+        return (pool, state), x
+
+    def logits(self, p, x):
+        return hybrid_lm.rmsnorm(p.ln_f, x, p.eps) @ p.wte.T
+
+
+def _gpt2(**kw):
+    return init_lm(jax.random.PRNGKey(0), 96, 32, 2, 64, n_heads=4, **kw)
+
+
+def _mesh4():
+    from distributed_llm_code_samples_tpu.parallel import (MODEL_AXIS,
+                                                           make_mesh)
+    return make_mesh({MODEL_AXIS: 4})
+
+
+# case -> (inline reference, params, EngineConfig fields, mesh, kind)
+PROGRAMS = {
+    "gpt2-decode": (InlineGPT2, _gpt2, {}, None, "decode"),
+    "gpt2-prefill": (InlineGPT2, _gpt2, {}, None, "prefill"),
+    "gpt2-verify": (InlineGPT2, _gpt2, {"speculate": 2}, None, "verify"),
+    "gpt2-rope-gqa-decode": (InlineGPT2, lambda: _gpt2(n_kv_heads=2),
+                             {"use_rope": True, "kv_dtype": "bf16"}, None,
+                             "decode"),
+    "gpt2-int8-decode": (InlineGPT2, _gpt2, {"kv_dtype": "int8"}, None,
+                         "decode"),
+    "gpt2-tp4-decode": (InlineGPT2, _gpt2, {}, _mesh4, "decode"),
+    "gpt2-tp4-prefill": (InlineGPT2, _gpt2, {}, _mesh4, "prefill"),
+    "hybrid-decode": (InlineHybrid, lambda: params_from_config(TOY, 1), {},
+                      None, "decode"),
+    "hybrid-prefill": (InlineHybrid, lambda: params_from_config(TOY, 1),
+                       {}, None, "prefill"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PROGRAMS))
+def test_step_programs_are_the_inline_reference(monkeypatch, case):
+    """Same operands, same StableHLO: for both families, every kind of
+    step program, under the mesh and for verify."""
+    from distributed_llm_code_samples_tpu.decode.programs import (
+        StepPrograms)
+    inline, make, fields, mesh, kind = PROGRAMS[case]
+    params = make()
     cfg = EngineConfig(max_slots=2, n_blocks=9, max_blocks_per_seq=4,
-                       kv_dtype="bf16")
+                       **fields)
     i32 = jnp.int32
     z = jnp.zeros((2,), i32)
-    args = {"decode": (jnp.zeros((2, 4), i32), z, z, z, i32(-1)),
-            "prefill": (jnp.zeros((4,), i32), i32(0),
-                        jnp.zeros((16,), i32), i32(0), i32(-1))}[kind]
-    bucket = {"decode": 2, "prefill": 16}[kind]
+    tables = jnp.zeros((2, 4), i32)
+    bucket, args = {
+        "decode": (2, (tables, z, z, z, i32(-1))),
+        "verify": (2, (tables, z, z, z, jnp.zeros((2, 2), i32), z,
+                       i32(-1))),
+        "prefill": (16, (jnp.zeros((4,), i32), i32(0),
+                         jnp.zeros((16,), i32), i32(0), i32(-1))),
+    }[kind]
 
     def lowered():
-        eng = DecodeEngine(params, 4, cfg)
-        return eng._program(kind, bucket).lower(params, eng.pool,
-                                                *args).as_text()
+        eng = DecodeEngine(params, 4, cfg, mesh=mesh and mesh())
+        rows = () if eng.state is None else (z if kind == "decode"
+                                             else i32(0),)
+        return eng._program(kind, bucket).lower(
+            eng.params, eng._cache(), *args, *rows).as_text()
 
-    seam = lowered()
-    monkeypatch.setattr(DecodeEngine, "_trunk", _inline_gpt2_trunk)
-    assert lowered() == seam
+    built = lowered()
+    for name in ("_embed", "_trunk", "logits"):
+        monkeypatch.setattr(StepPrograms, name, getattr(inline, name))
+    assert lowered() == built

@@ -90,8 +90,6 @@ def test_speculate_validation(lm_params):
                      EngineConfig(**BASE, temperature=0.9, speculate=2))
     with pytest.raises(ValueError, match="speculate"):
         DecodeEngine(lm_params, H, EngineConfig(**BASE, speculate=-1))
-    with pytest.raises(ValueError, match="kernel"):
-        DecodeEngine(lm_params, H, EngineConfig(**BASE, kernel="warp"))
 
 
 # ---------------------------------------------------------------------------
