@@ -27,8 +27,11 @@ wrappers):
 This module is the SCHEDULER. What a step program is made of is
 ``decode/programs.py``'s, built from the model's face (``models/face.py``:
 a family is one file) and the cache (``decode/paged.py``); the engine
-calls ``_program(kind, bucket)(params, cache, host operands) -> (cache,
-picks, flags)`` and reads sizes only from the model: ``vocab``,
+calls ``_program(kind, bucket)(params, cache, operand) -> (cache,
+result)`` — ONE packed ``int32`` vector in (``programs.pack``: one
+host-to-device transfer a dispatch), ONE packed ``int32`` array out
+(one blocking read; a row whose logits were not finite reads negative)
+— and reads sizes only from the model: ``vocab``,
 ``max_seq_len``, the kinds of its ``layers``, its ``cache_spec``. A
 recurrent layer's state lives beside the pool, by slot; what cannot
 carry it yet — prefix hits, speculation, a mesh, the KV handoff,
@@ -2141,6 +2144,21 @@ class DecodeEngine:
         finally:
             jax.config.update("jax_compilation_cache_dir", old)
 
+    def _dispatch(self, phase: str, fn, params: ServedModel,
+                  operand: np.ndarray) -> np.ndarray:
+        """Launch one step program on its packed operand and read its
+        packed result (``decode/programs.py`` has the format): one
+        host-to-device transfer, the vector handed to the jitted call
+        as it is, and one blocking read, in the phases
+        ``<phase>.dispatch`` and ``<phase>.readback``."""
+        args = (params, self._cache(), operand)
+        self._maybe_capture(fn, *args)
+        with self.phases.phase(phase + ".dispatch"):
+            cache, result = fn(*args)
+        with self.phases.phase(phase + ".readback"):
+            self._keep(cache)
+            return np.asarray(result)
+
     def _prefill_step(self, slot: int) -> None:
         seq = self.slots[slot]
         phase = self.phases.phase
@@ -2152,24 +2170,21 @@ class DecodeEngine:
         with phase("prefill.upload"):
             self.prefill_dispatches += 1
             fn = self._program("prefill", c)
-            chunk = np.asarray(
-                seq.prompt[seq.prefilled:seq.prefilled + c], np.int32)
-            args = (self._params_for(seq.weights_version), self._cache(),
-                    jnp.asarray(self.tables[slot]),
-                    jnp.int32(seq.prefilled), jnp.asarray(chunk),
-                    jnp.int32(seq.uid), jnp.int32(self._poison_uid))
+            fields = dict(
+                table=self.tables[slot], pos0=seq.prefilled,
+                tokens=seq.prompt[seq.prefilled:seq.prefilled + c],
+                uid=seq.uid, poison=self._poison_uid)
             if self.state is not None:
-                args += (jnp.int32(slot),)      # the slot's state row
-        self._maybe_capture(fn, *args)
-        with phase("prefill.dispatch"):
-            pool, nxt, ok = fn(*args)
-        with phase("prefill.readback"):
-            self._keep(pool)
-            fine = bool(ok)
-            # the pick is read only where the chunk completes the prompt
-            pick = (int(nxt) if fine and seq.prefilled + c
-                    == len(seq.prompt) else None)
+                fields["row"] = slot            # the slot's state row
+            operand = self.programs.pack("prefill", c, **fields)
+        result = self._dispatch(
+            "prefill", fn, self._params_for(seq.weights_version), operand)
         with phase("prefill.book"):
+            nxt = int(result[0])
+            fine = nxt >= 0
+            # the pick is used only where the chunk completes the prompt
+            pick = (nxt if fine and seq.prefilled + c
+                    == len(seq.prompt) else None)
             self._prefill_book(slot, seq, c, fine, pick)
 
     def _prefill_chunk(self, seq: _Seq) -> int:
@@ -2273,26 +2288,20 @@ class DecodeEngine:
             b, tables, lengths, tokens, uids = self._marshal(ready)
             fn = self._program("decode", b)
         with phase("decode.upload"):
-            args = (params, self._cache(), jnp.asarray(tables),
-                    jnp.asarray(lengths), jnp.asarray(tokens),
-                    jnp.asarray(uids), jnp.int32(self._poison_uid))
+            fields = dict(tables=tables, lengths=lengths, tokens=tokens,
+                          uids=uids, poison=self._poison_uid)
             if self.state is not None:
                 # each batch row's state row: its slot, and the scratch
                 # row for the bucket's padded rows
-                rows = ready + [self.state.scratch_row] * (b - len(ready))
-                args += (jnp.asarray(rows, jnp.int32),)
+                fields["rows"] = ready + [self.state.scratch_row] * (
+                    b - len(ready))
                 self._step_state_bytes += (len(ready)
                                            * self.state.bytes_per_slot)
-        self._maybe_capture(fn, *args)
-        with phase("decode.dispatch"):
-            pool, picks, ok = fn(*args)
-        with phase("decode.readback"):
-            self._keep(pool)
-            picks = np.asarray(picks)
-            ok = np.asarray(ok)
+            operand = self.programs.pack("decode", b, **fields)
+        picks = self._dispatch("decode", fn, params, operand)
         with phase("decode.emit"):
             self._step_decode_uids += [self.slots[s].uid for s in ready]
-            flags = [bool(ok[j]) for j in range(len(ready))]
+            flags = (picks[:len(ready)] >= 0).tolist()
             self._step_finite = (flags if self._step_finite is None
                                  else self._step_finite + flags)
             for j, slot in enumerate(ready):
@@ -2369,19 +2378,14 @@ class DecodeEngine:
             params = self._params_for(
                 self.slots[ready[0]].weights_version)
         with phase("decode.upload"):
-            args = (params, self.pool, jnp.asarray(tables),
-                    jnp.asarray(lengths), jnp.asarray(tokens),
-                    jnp.asarray(uids), jnp.asarray(drafts),
-                    jnp.asarray(dlens), jnp.int32(self._poison_uid))
-        self._maybe_capture(fn, *args)
-        with phase("decode.dispatch"):
-            pool, picks, acc, ok = fn(*args)
-        with phase("decode.readback"):
-            self.pool = pool
-            picks = np.asarray(picks)
-            acc = np.asarray(acc)
-            ok = np.asarray(ok)
+            operand = self.programs.pack(
+                "verify", b, tables=tables, lengths=lengths, tokens=tokens,
+                uids=uids, poison=self._poison_uid, drafts=drafts,
+                dlens=dlens)
+        result = self._dispatch("decode", fn, params, operand)
         with phase("decode.emit"):
+            picks, acc = result[:, :k + 1], result[:, k + 1]
+            ok = picks >= 0
             self._step_decode_uids += [self.slots[s].uid for s in ready]
             flags = []
             for j, slot in enumerate(ready):
@@ -2751,13 +2755,14 @@ class DecodeEngine:
         if b not in self.slot_buckets:
             raise ValueError(f"bucket {b} not in the engine's slot "
                              f"buckets {self.slot_buckets}")
-        tables = jnp.full((b, self.cfg.max_blocks_per_seq),
-                          SCRATCH_BLOCK, jnp.int32)
-        z = jnp.zeros((b,), jnp.int32)
-        rows = () if self.state is None else (z,)
+        z = np.zeros((b,), np.int32)
+        rows = {} if self.state is None else {"rows": z}
+        operand = self.programs.pack(
+            "decode", b, tables=np.full((b, self.cfg.max_blocks_per_seq),
+                                        SCRATCH_BLOCK),
+            lengths=z, tokens=z, uids=z, poison=POISON_NONE, **rows)
         rep = StepReport.of(self.programs.body("decode", b), self.params,
-                            self._cache(), tables, z, z, z,
-                            jnp.int32(POISON_NONE), *rows)
+                            self._cache(), operand)
         per_tok = self._kv_bytes_per_token()
         kv_bytes, scale_bytes = pool_bytes(self.pool)
         return {

@@ -16,16 +16,23 @@ mesh, ``jit``, the donated cache). The builder gets ``cfg``, the
 model's ``CacheSpec``, the vocabulary and the mesh as plain values: it
 never sees a sequence, a slot table or a metrics writer.
 
-A step program is ``run(params, cache, *host operands) -> (cache,
-picks, ...flags)``. ``cache`` is the donated operand — the ``PagedKV``,
-or for a model with recurrent layers the pair ``(PagedKV,
-RecurrentState)`` — and comes back in the same form. The jitted
-callable keeps the name ``run`` (the profiler's ``jit_run``, which
-``benchmark/engine_trace.py`` reads). Under a mesh (the Megatron decode
-layout, ``parallel/lm.py``) the pool is head-sharded, the embedding
-vocab-parallel, and the local logits are gathered in-graph so the pick
-(keys fold uid and position, never the shard) draws the same
-everywhere.
+A step program is ``run(params, cache, operand) -> (cache, result)``.
+``cache`` is the donated operand — the ``PagedKV``, or for a model with
+recurrent layers the pair ``(PagedKV, RecurrentState)`` — and comes
+back in the same form. ``operand`` is ONE ``int32`` vector holding
+every host operand of the dispatch and ``result`` ONE ``int32`` array
+holding all the host reads, so a dispatch costs one host-to-device
+transfer and one blocking read. The wire format is written once, here:
+``Wire`` lays a kind's fields out (``StepPrograms.wire``), ``pack``
+fills the vector in numpy on the host, each body starts with the
+in-graph ``unpack`` (static slices) and ends with ``_fold`` (a pick
+whose logits were not finite reads negative; vocabulary ids never do).
+The jitted callable keeps the name ``run`` (the profiler's ``jit_run``,
+which ``benchmark/engine_trace.py`` reads). Under a mesh (the Megatron
+decode layout, ``parallel/lm.py``) the pool is head-sharded, the
+embedding vocab-parallel, the operand and the result replicated, and
+the local logits are gathered in-graph so the pick (keys fold uid and
+position, never the shard) draws the same everywhere.
 
 ``jax.named_scope`` names (``decode`` / ``prefill``, ``ssm``, ``head``,
 ``sample``) are metadata only (``utils/trace_analysis`` ``SCOPES``).
@@ -33,8 +40,11 @@ everywhere.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.face import ATTN, CacheSpec, take
@@ -59,10 +69,47 @@ _POOL_OPS = {"cow": copy_block, "cow_rows": copy_block_rows,
              "implant": implant_block}
 
 
+class Wire:
+    """The layout of one step program's packed operand: named ``int32``
+    fields, each a fixed shape, end to end in one vector. ``pack`` is
+    the host side (numpy), ``unpack`` the in-graph side (static
+    slices); nobody else knows an offset."""
+
+    def __init__(self, fields: dict[str, tuple[int, ...]]):
+        self.fields = {}
+        self.size = 0
+        for name, shape in fields.items():
+            end = self.size + math.prod(shape)
+            self.fields[name] = (self.size, end, shape)
+            self.size = end
+
+    def pack(self, **values) -> np.ndarray:
+        """The operand of one dispatch: every field, by name, as the
+        host holds it (arrays, lists or plain ints)."""
+        if values.keys() != self.fields.keys():
+            raise TypeError(f"operand fields {sorted(values)} are not the "
+                            f"program's {sorted(self.fields)}")
+        out = np.empty((self.size,), np.int32)
+        for name, (lo, hi, _) in self.fields.items():
+            out[lo:hi] = np.reshape(values[name], hi - lo)
+        return out
+
+    def unpack(self, operand) -> dict:
+        return {name: operand[lo:hi].reshape(shape)
+                for name, (lo, hi, shape) in self.fields.items()}
+
+
+def _fold(picks, finite):
+    """Picks and their all-finite flags as one array: a row whose logits
+    were not finite reads -1 (the host never used such a row's pick)."""
+    return jnp.where(finite, picks, -1)
+
+
 class StepPrograms:
     """The programs of one engine configuration over one model's face:
     ``build(kind, bucket)`` is what the engine dispatches, ``body`` the
-    same callable before ``jit`` (what the static report lowers)."""
+    same callable before ``jit`` (what the static report lowers),
+    ``pack(kind, bucket, **fields)`` the operand it takes."""
 
     def __init__(self, cfg, spec: CacheSpec, vocab: int, mesh=None):
         self.cfg = cfg
@@ -70,6 +117,39 @@ class StepPrograms:
         self.mesh = mesh
         self.pick = make_pick(cfg.temperature, cfg.top_k, cfg.top_p, vocab,
                               cfg.seed)
+        self._wires: dict = {}
+
+    # -- the wire format ---------------------------------------------------
+
+    def wire(self, kind: str, bucket: int) -> Wire:
+        """The operand's layout for ``(kind, bucket)``. Decode: a
+        ``bucket``-row batch's block tables, lengths, tokens and uids,
+        the poison and, where the model has recurrent layers, each
+        row's state row. Verify: decode's plus the drafts and their
+        lengths. Prefill: ONE slot's table, start position, ``bucket``
+        tokens, uid, the poison and its state row likewise."""
+        w = self._wires.get((kind, bucket))
+        if w is None:
+            t, rec = self.cfg.max_blocks_per_seq, bool(self.spec.rec_layers)
+            if kind == "prefill":
+                fields = {"table": (t,), "pos0": (), "tokens": (bucket,),
+                          "uid": (1,), "poison": ()}
+                if rec:
+                    fields["row"] = ()
+            else:
+                fields = {"tables": (bucket, t), "lengths": (bucket,),
+                          "tokens": (bucket,), "uids": (bucket,),
+                          "poison": ()}
+                if rec:
+                    fields["rows"] = (bucket,)
+                if kind == "verify":
+                    fields["drafts"] = (bucket, self.cfg.speculate)
+                    fields["dlens"] = (bucket,)
+            w = self._wires[kind, bucket] = Wire(fields)
+        return w
+
+    def pack(self, kind: str, bucket: int, **fields) -> np.ndarray:
+        return self.wire(kind, bucket).pack(**fields)
 
     # -- the cache -------------------------------------------------------
 
@@ -204,32 +284,30 @@ class StepPrograms:
         operand names the row's uid (or is ``POISON_ALL``; a false
         ``where`` leaves a row bit-identical), the in-graph pick keyed
         on ``(uid, pos + ahead)``, and each row's all-finite flag (the
-        serving guardrail, on the same readback as the picks). The
-        prefill's one row passes scalars and gets scalars."""
-        one = jnp.ndim(uids) == 0
+        serving guardrail, on the same readback as the picks)."""
         with jax.named_scope("head"):
             logits = self.logits(p, x)
         bad = jnp.logical_or(uids == poison, poison == POISON_ALL)
-        logits = jnp.where(bad if one else bad[:, None],
+        logits = jnp.where(bad[:, None],
                            jnp.asarray(jnp.nan, logits.dtype), logits)
         with jax.named_scope("sample"):
-            picks = (self.pick(logits, uids[None], (pos + ahead)[None])
-                     if one else self.pick(logits, uids, pos + ahead))
-        if one:
-            return picks[0], rows_finite(logits)[0]
+            picks = self.pick(logits, uids, pos + ahead)
         return picks, rows_finite(logits)
 
     # -- the three bodies --------------------------------------------------
 
     def _decode_fn(self, b: int):
-        """A ``b``-slot bucket's decode step."""
+        """A ``b``-slot bucket's decode step: ``result [b]``."""
+        wire = self.wire("decode", b)
 
         @jax.named_scope("decode")
-        def run(p, cache, tables, lengths, tokens, uids, poison, *rows):
-            cache, x = self.decode_hidden(b, p, cache, tables, lengths,
-                                          tokens, *rows)
-            return (cache,) + self._head_pick(p, x, uids, poison,
-                                              lengths, 1)
+        def run(p, cache, operand):
+            f = wire.unpack(operand)
+            cache, x = self.decode_hidden(b, p, cache, f["tables"],
+                                          f["lengths"], f["tokens"],
+                                          f.get("rows"))
+            return cache, _fold(*self._head_pick(
+                p, x, f["uids"], f["poison"], f["lengths"], 1))
 
         return run
 
@@ -240,14 +318,18 @@ class StepPrograms:
         a position-parallel verify); the acceptance chain ``alive_i = alive_{i-1} and draft_i ==
         pick_{i-1}`` masks each drafted row's KV WRITE by redirecting a
         dead row's scatter to the scratch block, so a rejected tail
-        never lands. Returns ``(pool, picks [b, k+1], accepted [b],
-        finite [b, k+1])``."""
+        never lands. ``result [b, k+2]``: the ``k+1`` sub-steps' folded
+        picks, then the accepted count."""
         cfg = self.cfg
         k = cfg.speculate
+        wire = self.wire("verify", b)
 
         @jax.named_scope("decode")
-        def run(p, pool, tables, lengths, tokens, uids, drafts, dlens,
-                poison):
+        def run(p, pool, operand):
+            f = wire.unpack(operand)
+            tables, lengths, tokens = f["tables"], f["lengths"], f["tokens"]
+            uids, poison = f["uids"], f["poison"]
+            drafts, dlens = f["drafts"], f["dlens"]
             rows = jnp.arange(b)
             alive = jnp.ones((b,), bool)
             acc = jnp.zeros((b,), jnp.int32)
@@ -278,21 +360,25 @@ class StepPrograms:
                         alive, jnp.logical_and(i < dlens, d == pk))
                     acc = acc + alive.astype(jnp.int32)
                     cur = d
-            return (pool, jnp.stack(picks_all, 1), acc,
-                    jnp.stack(finite_all, 1))
+            picks = _fold(jnp.stack(picks_all, 1), jnp.stack(finite_all, 1))
+            return pool, jnp.concatenate([picks, acc[:, None]], 1)
 
         return run
 
     def _prefill_fn(self, c: int):
         """One slot's prefill chunk of ``c`` tokens; the host uses the
-        final row's pick only when the chunk completes the prompt."""
+        final row's pick only when the chunk completes the prompt:
+        ``result [1]``."""
+        wire = self.wire("prefill", c)
 
         @jax.named_scope("prefill")
-        def run(p, cache, table, pos0, tokens, uid, poison, *row):
-            cache, x = self.prefill_hidden(c, p, cache, table, pos0,
-                                           tokens, *row)
-            return (cache,) + self._head_pick(p, x[-1:], uid, poison,
-                                              pos0, c)
+        def run(p, cache, operand):
+            f = wire.unpack(operand)
+            cache, x = self.prefill_hidden(c, p, cache, f["table"],
+                                           f["pos0"], f["tokens"],
+                                           f.get("row"))
+            return cache, _fold(*self._head_pick(
+                p, x[-1:], f["uid"], f["poison"], f["pos0"][None], c))
 
         return run
 
@@ -300,20 +386,15 @@ class StepPrograms:
 
     def body(self, kind: str, bucket: int):
         """The callable ``build`` jits; under a mesh shard_mapped, the
-        host operands (5; verify adds drafts and their lengths) and the
-        picks and flags (2; verify adds the accepted counts)
-        replicated."""
+        operand and the result replicated."""
         run = {"decode": self._decode_fn, "prefill": self._prefill_fn,
                "verify": self._verify_fn}[kind](bucket)
         if self.mesh is None:
             return run
-        n_aux, n_out = (7, 4) if kind == "verify" else (5, 3)
         return jax.shard_map(
             run, mesh=self.mesh,
-            in_specs=(tp_decode_specs(), self.pool_specs())
-            + (P(),) * n_aux,
-            out_specs=(self.pool_specs(),) + (P(),) * (n_out - 1),
-            check_vma=False)
+            in_specs=(tp_decode_specs(), self.pool_specs(), P()),
+            out_specs=(self.pool_specs(), P()), check_vma=False)
 
     def build(self, kind: str, bucket: int):
         """The compiled program, the cache donated: XLA updates the

@@ -57,6 +57,23 @@ def load_scaled_timeout(base_s: float, cap: float = 4.0) -> float:
 
 
 @pytest.fixture(scope="session")
+def toy_hybrid_config():
+    """A published-style ``config.json`` of a toy hybrid (``model_type:
+    jamba``): three layers, the middle one attention over one KV head
+    (``tests/test_hybrid_lm.py::TOY``'s widths, half its depth)."""
+    return dict(model_type="jamba", hidden_size=64, intermediate_size=128,
+                mamba_expand=2, mamba_d_state=4, mamba_d_conv=4,
+                mamba_dt_rank=8, mamba_conv_bias=True,
+                mamba_proj_bias=False, num_hidden_layers=3,
+                attn_layer_period=3, attn_layer_offset=1,
+                num_attention_heads=4, num_key_value_heads=1,
+                vocab_size=96, rms_norm_eps=1e-6,
+                max_position_embeddings=256, num_experts=1,
+                hidden_act="silu", tie_word_embeddings=True,
+                sliding_window=None, initializer_range=0.2)
+
+
+@pytest.fixture(scope="session")
 def mesh8():
     return make_mesh({DATA_AXIS: 8})
 

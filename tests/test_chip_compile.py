@@ -139,17 +139,21 @@ def test_fused_head_compiles_at_gpt2_shape(one_chip):
     assert _total_bytes(compiled) < HBM_V5E
 
 
-def _step_args(params, pool, slots, mbps, chunk):
-    """The operands of one decode dispatch over ``slots`` rows and of
+def _operand(eng, kind, bucket):
+    """A zero operand of the engine's ``(kind, bucket)`` program, every
+    field through the program's own ``pack`` (only its shape is
+    lowered)."""
+    wire = eng.programs.wire(kind, bucket)
+    return wire.pack(**{name: np.zeros(shape, np.int32)
+                        for name, (_, _, shape) in wire.fields.items()})
+
+
+def _step_args(eng, slots, chunk):
+    """The arguments of one decode dispatch over ``slots`` rows and of
     one prefill dispatch of ``chunk`` tokens, as the engine passes
-    them (only their shapes are lowered)."""
-    i32 = jnp.int32
-    decode = (params, pool, np.zeros((slots, mbps), i32),
-              np.zeros((slots,), i32), np.zeros((slots,), i32),
-              np.zeros((slots,), i32), i32(0))
-    prefill = (params, pool, np.zeros((mbps,), i32), i32(0),
-               np.zeros((chunk,), i32), i32(0), i32(0))
-    return decode, prefill
+    them: the params, the cache and the one packed operand."""
+    return tuple((eng.params, eng._cache(), _operand(eng, kind, bucket))
+                 for kind, bucket in (("decode", slots), ("prefill", chunk)))
 
 
 @pytest.fixture(scope="module")
@@ -171,7 +175,7 @@ def gpt2_engine_args():
             block_size=16, n_blocks=1 + slots * mbps, max_slots=slots,
             max_blocks_per_seq=mbps, prefill_chunk=chunk,
             kv_dtype=kv_dtype))
-        decode, prefill = _step_args(params, eng.pool, slots, mbps, chunk)
+        decode, prefill = _step_args(eng, slots, chunk)
         return eng, decode, prefill, slots, chunk
 
     return build
@@ -235,7 +239,7 @@ def gpt2_large_engine_args():
     eng = DecodeEngine(params, g["heads"], EngineConfig(
         n_blocks=1 + slots * mbps, max_slots=slots,
         max_blocks_per_seq=mbps, prefill_chunk=chunk, kv_dtype="bf16"))
-    decode, prefill = _step_args(params, eng.pool, slots, mbps, chunk)
+    decode, prefill = _step_args(eng, slots, chunk)
     return eng, {"decode": (slots, decode), "prefill": (chunk, prefill)}
 
 
@@ -317,11 +321,8 @@ def jamba_engine_args():
     eng = engine_from_config(config, seed=7, engine_config=EngineConfig(
         n_blocks=1 + slots * mbps, max_slots=slots,
         max_blocks_per_seq=mbps, prefill_chunk=chunk, kv_dtype="bf16"))
-    decode, prefill = _step_args(eng.params, eng._cache(), slots, mbps,
-                                 chunk)
-    i32 = jnp.int32
-    return eng, {"decode": (slots, decode + (np.zeros((slots,), i32),)),
-                 "prefill": (chunk, prefill + (i32(0),))}
+    decode, prefill = _step_args(eng, slots, chunk)
+    return eng, {"decode": (slots, decode), "prefill": (chunk, prefill)}
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
@@ -359,6 +360,67 @@ def test_hybrid_step_program_keeps_the_state_as_stored(one_chip,
     logical = sum(x.nbytes for x in jax.tree_util.tree_leaves(args))
     assert m.argument_size_in_bytes - logical < state.conv.nbytes // 8
     assert _total_bytes(compiled) < HBM_V5E
+
+
+def _toy_engine(family, ways, speculate, hybrid_config):
+    """A GPT-2-shaped toy or the toy hybrid, as small as they compile."""
+    from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
+                                                         EngineConfig)
+    from distributed_llm_code_samples_tpu.decode.model_config import (
+        engine_from_config)
+    from distributed_llm_code_samples_tpu.models import init_lm
+    from distributed_llm_code_samples_tpu.parallel import make_mesh
+    cfg = EngineConfig(max_slots=2, n_blocks=9, max_blocks_per_seq=4,
+                       speculate=speculate)
+    if family == "hybrid":
+        return engine_from_config(hybrid_config, seed=1, engine_config=cfg)
+    params = init_lm(jax.random.PRNGKey(0), 96, 32, 2, 64, n_heads=4)
+    return DecodeEngine(params, 4, cfg,
+                        mesh=make_mesh({MODEL_AXIS: ways}) if ways else None)
+
+
+@pytest.mark.parametrize("family,kind,ways", [
+    ("gpt2", "decode", 0), ("gpt2", "prefill", 0), ("gpt2", "verify", 0),
+    ("gpt2", "decode", 2), ("gpt2", "prefill", 2), ("gpt2", "verify", 2),
+    ("hybrid", "decode", 0), ("hybrid", "prefill", 0)])
+def test_step_program_boundary_is_one_operand_and_one_result(
+        toy_hybrid_config, family, kind, ways):
+    """The wire format of a step program (``decode/programs.py``):
+    beyond the params' leaves and the cache's leaves it takes exactly
+    ONE argument, an ``int32`` vector, and beyond the cache it returns
+    exactly ONE result, an ``int32`` array — one host-to-device
+    transfer and one blocking read a dispatch — and the cache is still
+    donated and aliased whole, leaf for leaf (a hybrid's recurrent
+    state with its pool; under a 2-way model mesh each shard's half).
+    Compiled here, for the host's virtual devices: no topology is
+    described."""
+    import re
+    eng = _toy_engine(family, ways, 2 if kind == "verify" else 0,
+                      toy_hybrid_config)
+    bucket = 16 if kind == "prefill" else 2
+    lowered = eng._program(kind, bucket).lower(
+        eng.params, eng._cache(), _operand(eng, kind, bucket))
+    (params, cache, *rest), kwargs = lowered.args_info
+    leaves = jax.tree_util.tree_leaves
+    assert not kwargs and len(rest) == 1
+    assert len(leaves(params)) == len(leaves(eng.params))
+    assert len(leaves(cache)) == len(leaves(eng._cache()))
+    (operand,) = leaves(rest)
+    assert operand.dtype == np.int32 and len(operand.shape) == 1
+    cache_out, *results = lowered.out_info
+    assert ([(x.shape, x.dtype) for x in leaves(cache_out)]
+            == [(x.shape, x.dtype) for x in leaves(eng._cache())])
+    (result,) = leaves(results)
+    assert result.dtype == np.int32
+    assert result.shape == {"decode": (2,), "prefill": (1,),
+                            "verify": (2, 4)}[kind]
+    compiled = lowered.compile()
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry",
+                        compiled.as_text()).group(1)
+    assert aliased.count("alias)") == len(leaves(cache))
+    held = sum(x.nbytes for x in leaves(eng._cache()))
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            == held // max(ways, 1))
 
 
 def _entry_results(hlo: str):

@@ -560,23 +560,15 @@ def test_step_programs_are_the_inline_reference(monkeypatch, case):
     params = make()
     cfg = EngineConfig(max_slots=2, n_blocks=9, max_blocks_per_seq=4,
                        **fields)
-    i32 = jnp.int32
-    z = jnp.zeros((2,), i32)
-    tables = jnp.zeros((2, 4), i32)
-    bucket, args = {
-        "decode": (2, (tables, z, z, z, i32(-1))),
-        "verify": (2, (tables, z, z, z, jnp.zeros((2, 2), i32), z,
-                       i32(-1))),
-        "prefill": (16, (jnp.zeros((4,), i32), i32(0),
-                         jnp.zeros((16,), i32), i32(0), i32(-1))),
-    }[kind]
+    bucket = 16 if kind == "prefill" else 2
 
     def lowered():
         eng = DecodeEngine(params, 4, cfg, mesh=mesh and mesh())
-        rows = () if eng.state is None else (z if kind == "decode"
-                                             else i32(0),)
+        wire = eng.programs.wire(kind, bucket)
+        operand = wire.pack(**{name: np.zeros(shape, np.int32) for
+                               name, (_, _, shape) in wire.fields.items()})
         return eng._program(kind, bucket).lower(
-            eng.params, eng._cache(), *args, *rows).as_text()
+            eng.params, eng._cache(), operand).as_text()
 
     built = lowered()
     for name in ("_embed", "_trunk", "logits"):
