@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """The quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py            # one chip: serve, hybrid, train
+    python chip_smoke.py            # one chip: serve, hybrid, latent + experts, train
     python chip_smoke.py --chips 4  # four chips: the cross-chip paths only
 
 One process. It imports JAX once and drives the program through the
@@ -90,7 +90,28 @@ HYBRID = dict(config=dict(
     prompt_lens="5,19,40,64,11", max_new=24, max_slots=3,
     # a served token has to be the reference's first wherever its top
     # two logits lie further apart than this (float32 on both sides)
-    tie=1e-3)
+    tie=1e-3, phase="serve_hybrid", reference="jamba_lm_reference",
+    driver="jamba_engine_driver")
+# Latent attention and sparse experts in small (``models/mla_moe_lm.py``):
+# the shape of the benchmark's glm47-flash-serve at toy widths — a latent
+# cache row of 32 + 8 lanes, one dense layer, three layers of 16 experts
+# with the top 4 and a shared one. The same proof, for the latent rows in
+# the pool and the dropless expert layer.
+LATENT = dict(config=dict(
+    model_type="glm4_moe_lite", hidden_size=64, intermediate_size=160,
+    moe_intermediate_size=48, num_attention_heads=4, num_key_value_heads=4,
+    n_routed_experts=16, n_shared_experts=1, num_experts_per_tok=4,
+    routed_scaling_factor=1.8, first_k_dense_replace=1,
+    num_hidden_layers=4, q_lora_rank=24, kv_lora_rank=32,
+    qk_nope_head_dim=12, qk_rope_head_dim=8, v_head_dim=16, vocab_size=96,
+    rms_norm_eps=1e-5, rope_theta=1000000, rope_scaling=None,
+    partial_rotary_factor=1, tie_word_embeddings=False,
+    topk_method="noaux_tc", n_group=1, topk_group=1, norm_topk_prob=True,
+    hidden_act="silu", attention_bias=False, max_position_embeddings=256,
+    initializer_range=0.2),
+    prompt_lens="5,19,40,64,11", max_new=24, max_slots=3, tie=1e-3,
+    phase="serve_latent_moe", reference="glm_moe_lm_reference",
+    driver="glm_moe_engine_driver")
 
 def require_tpu() -> dict:
     """The device as JAX reports it — or an error where it is no TPU."""
@@ -218,27 +239,34 @@ def first_difference(a: list, b: list):
     return None
 
 
-def phase_hybrid(out_dir: str) -> None:
-    """Phase 3: the toy hybrid through ``generate --model_config`` at
-    float32 matmul precision (more requests than slots, so rows of the
-    recurrent state are reused), against the plain reference
-    teacher-forced on what was served: every served token is the
-    reference's first wherever its top two logits are not tied."""
+def phase_model_config(out_dir: str) -> None:
+    """Phase 3: the toy hybrid, then the toy latent-attention expert
+    model, each through ``generate --model_config``."""
+    for toy in (HYBRID, LATENT):
+        serve_model_config(out_dir, toy)
+
+
+def serve_model_config(out_dir: str, toy: dict) -> None:
+    """One toy family through ``generate --model_config`` at float32
+    matmul precision (more requests than slots, so slots — rows of the
+    recurrent state, blocks of the pool — are reused), against the plain
+    reference teacher-forced on what was served: every served token is
+    the reference's first wherever its top two logits are not tied."""
     import numpy as np
-    config = HYBRID["config"]
-    path = os.path.join(out_dir, "hybrid_config.json")
+    config, name = toy["config"], toy["phase"]
+    path = os.path.join(out_dir, name + "_config.json")
     with open(path, "w") as f:
         json.dump(config, f)
     argv = ["--model_config", path, "-r", "7", "--prompt_lens",
-            HYBRID["prompt_lens"], "--max_new", str(HYBRID["max_new"]),
-            "--max_slots", str(HYBRID["max_slots"])]
+            toy["prompt_lens"], "--max_new", str(toy["max_new"]),
+            "--max_slots", str(toy["max_slots"])]
     # the benchmark's plain reference and the weights its driver file
     # makes, found by name as the benchmark finds them
     from benchmark import harness
-    ref = harness.reference_module({"reference": "jamba_lm_reference"})
-    driver = harness.driver_module({"driver": "jamba_engine_driver"})
+    ref = harness.reference_module(toy)
+    driver = harness.driver_module(toy)
     with jax.default_matmul_precision("highest"):
-        payload, rec = generate("serve_hybrid", argv, config["vocab_size"])
+        payload, rec = generate(name, argv, config["vocab_size"])
         w = driver.make_weights(config, 7)
         clear = total = 0
         for seq in payload["sequences"]:
@@ -247,11 +275,11 @@ def phase_hybrid(out_dir: str) -> None:
                 plen - 1:len(full) - 1]
             served = np.asarray(full[plen:])
             top2 = np.sort(rows, -1)[:, -2:]
-            sure = top2[:, 1] - top2[:, 0] > HYBRID["tie"]
+            sure = top2[:, 1] - top2[:, 0] > toy["tie"]
             wrong = np.flatnonzero(sure & (rows.argmax(-1) != served))
             if wrong.size:
                 raise RuntimeError(
-                    f"hybrid: uid {seq['uid']} output token "
+                    f"{name}: uid {seq['uid']} output token "
                     f"{int(wrong[0])} is {int(served[wrong[0]])}, the "
                     f"reference's first is "
                     f"{int(rows.argmax(-1)[wrong[0]])}")
@@ -262,7 +290,7 @@ def phase_hybrid(out_dir: str) -> None:
     rec["tokens_tied"] = total - clear
     emit(rec)
     if clear < 0.9 * total:
-        raise RuntimeError(f"hybrid: only {clear} of {total} tokens had "
+        raise RuntimeError(f"{name}: only {clear} of {total} tokens had "
                            "a clear first: the check compared too little")
 
 
@@ -412,7 +440,7 @@ def main(argv=None) -> int:
     shutil.rmtree(out_dir, ignore_errors=True)  # metrics streams append
     os.makedirs(out_dir)
     phases = ([phase_cross_chip] if args.chips == 4
-              else [phase_serve, phase_hybrid, phase_train])
+              else [phase_serve, phase_model_config, phase_train])
     for phase in phases:
         try:
             phase(out_dir)

@@ -36,7 +36,12 @@ host-to-device transfer a dispatch), ONE packed ``int32`` array out
 recurrent layer's state lives beside the pool, by slot; what cannot
 carry it yet — prefix hits, speculation, a mesh, the KV handoff,
 snapshots, the spill tier — refuses in one line for such a model
-(``_refuse_recurrent``), by what the model is and under no flag.
+(``_refuse_recurrent``), by what the model is and under no flag. A
+latent-cache layer's rows live IN the pool (one row a token, no heads:
+``paged.py``), so all of those carry them unchanged and only what needs
+KV heads refuses: int8 scales (``paged.init_pool``) and a mesh (here).
+An expert model's step programs return their layers' counters after the
+picks; the step's digest folds them (``EXPERT_COUNTERS``).
 ``mesh=None`` runs single-device; a model-axis mesh the Megatron decode
 layout (``parallel.lm``; the collectives are in ``decode/programs.py``).
 
@@ -158,7 +163,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.face import ATTN, ServedModel
+from ..models.face import ATTN, LATENT, ServedModel
 from ..parallel import launcher
 from ..runtime.policy import QosPolicy
 from ..runtime.telemetry import FLIGHT_FILENAME, STEP_SPAN
@@ -241,6 +246,10 @@ _HANDOFF_POOL_KEYS = ("n_blocks", "max_slots", "max_blocks_per_seq",
 # report --postmortem can discover the file without importing this
 # (jax-heavy) module.
 FLIGHT_RECORDER_STEPS = 256
+
+# the expert layers' counters a step's ``engine_step`` record and flight
+# digest carry (``DecodeEngine._fold_expert_rows``)
+EXPERT_COUNTERS = ("expert_rows", "experts_touched", "expert_rows_max")
 
 
 class AdmissionError(RuntimeError):
@@ -492,10 +501,15 @@ class DecodeEngine:
         # blocks (none for a model whose layers are all attention). What
         # cannot carry that state yet refuses, here and at the entry of
         # every later call, by what the model is: no flag turns it off
-        self.recurrent = sorted({kind for kind, _ in params.layers}
-                                - {ATTN})
+        kinds = {kind for kind, _ in params.layers}
+        self.recurrent = sorted(kinds - {ATTN, LATENT})
         if mesh is not None:
             self._refuse_recurrent("a model-axis mesh (--tp)")
+            if LATENT in kinds:
+                raise ValueError(
+                    "a model-axis mesh (--tp) is not served for a model "
+                    "with latent-cache layers: the pool is sharded by "
+                    "KV heads, and a latent row has none")
         if cfg.speculate:
             self._refuse_recurrent("speculate > 0 (a rejected draft "
                                    "would have to be undone in the state)")
@@ -687,6 +701,12 @@ class DecodeEngine:
         # row a ready slot; written back the same size): the engine_step
         # record's and the digest's ``state_bytes``
         self._step_state_bytes = 0
+        # the expert layers' counters of this step's dispatches, as the
+        # step programs returned them (``[expert_layers, n_experts]``
+        # each; none for a model with no expert layer), folded in the
+        # step's digest phase into ``_step_experts``
+        self._step_expert_rows: list[np.ndarray] = []
+        self._step_experts = dict.fromkeys(EXPERT_COUNTERS, 0)
         self._dump_reason: str | None = None
         # host phases of the current step (runtime/tracing.py): always
         # stamped, summed into the digest's ``phase_ms``; with a writer
@@ -2150,14 +2170,23 @@ class DecodeEngine:
         packed result (``decode/programs.py`` has the format): one
         host-to-device transfer, the vector handed to the jitted call
         as it is, and one blocking read, in the phases
-        ``<phase>.dispatch`` and ``<phase>.readback``."""
+        ``<phase>.dispatch`` and ``<phase>.readback``. Returns the
+        picks; an expert model's counters, which came on the same read,
+        are kept for the step's digest."""
         args = (params, self._cache(), operand)
         self._maybe_capture(fn, *args)
         with self.phases.phase(phase + ".dispatch"):
             cache, result = fn(*args)
         with self.phases.phase(phase + ".readback"):
             self._keep(cache)
-            return np.asarray(result)
+            result = np.asarray(result)
+        # with speculation on every decode dispatch is a verify dispatch
+        kind = ("verify" if phase == "decode" and self.cfg.speculate
+                else phase)
+        picks, rows = self.programs.split(kind, result)
+        if rows is not None:
+            self._step_expert_rows.append(rows)
+        return picks
 
     def _prefill_step(self, slot: int) -> None:
         seq = self.slots[slot]
@@ -2440,6 +2469,7 @@ class DecodeEngine:
         self._step_prefill_uid = None
         self._step_decode_uids = []
         self._step_state_bytes = 0
+        self._step_expert_rows = []
         with phase("expire"):
             # spill-tier housekeeping: a fresh promotion budget each
             # step (the restore analogue of one-prefill-chunk-per-step),
@@ -2476,6 +2506,7 @@ class DecodeEngine:
             dispatch(group)
             did = True
         with phase("digest"):
+            self._step_experts = self._fold_expert_rows()
             if self._step_restores:
                 # budget-deferred admission: restores ran compiled
                 # implant work this step even if no prefill/decode
@@ -2521,7 +2552,25 @@ class DecodeEngine:
             "phases": self.phases.stamps,
             "tokens_generated": self.tokens_generated,
             "state_bytes": self._step_state_bytes,
+            **self._step_experts,
         }
+
+    def _fold_expert_rows(self) -> dict:
+        """This step's expert counters (``EXPERT_COUNTERS``), over all
+        its dispatches: ``expert_rows`` the (row, choice) pairs the held
+        experts received (a bucket's padded rows route too: they are
+        rows the device multiplied), ``experts_touched`` the experts
+        that received at least one, summed over layers and dispatches
+        (each is one expert's weights read), ``expert_rows_max`` the
+        fullest expert's rows in any one layer of one dispatch. All 0
+        for a model with no expert layer."""
+        got = self._step_expert_rows
+        if not got:
+            return dict.fromkeys(EXPERT_COUNTERS, 0)
+        rows = np.stack(got)
+        return {"expert_rows": int(rows.sum()),
+                "experts_touched": int(np.count_nonzero(rows)),
+                "expert_rows_max": int(rows.max())}
 
     @property
     def active(self) -> int:
@@ -2590,7 +2639,8 @@ class DecodeEngine:
     def _kv_bytes_per_token(self) -> float:
         spec = self.spec
         return kv_bytes_per_token(self.cfg.kv_dtype, spec.kv_layers,
-                                  spec.kv_heads, spec.head_dim)
+                                  spec.kv_heads, spec.head_dim,
+                                  latent=bool(spec.latent_rank))
 
     def telemetry_record(self, tokens_per_sec=None) -> dict:
         """One schema-v5 ``decode`` record (``runtime/telemetry.py``
@@ -2706,6 +2756,9 @@ class DecodeEngine:
             # with no recurrent layer)
             "state_slots": self.active if self.state is not None else 0,
             "state_bytes": self._step_state_bytes,
+            # the expert layers' counters of this step's dispatches
+            # (0 for a model with no expert layer)
+            **self._step_experts,
             # where the step's host time went up to this digest
             # (runtime/tracing.py PhaseTimer): what an UNTRACED run's
             # ring says about a slow step

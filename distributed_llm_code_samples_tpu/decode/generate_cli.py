@@ -61,7 +61,9 @@ def build_generate_parser() -> argparse.ArgumentParser:
     p.add_argument("--model_config", default=None, metavar="FILE",
                    help="a published-style config.json (model_type "
                         "jamba: Mamba and attention layers in one "
-                        "stack, models/hybrid_lm.py). The model comes "
+                        "stack, models/hybrid_lm.py; or model_type "
+                        "glm4_moe_lite: latent attention and sparse "
+                        "experts, models/mla_moe_lm.py). The model comes "
                         "from its keys, in the type it states; "
                         "-d/-l/--heads/--kv_heads/--vocab/--max_seq_len "
                         "are then ignored, the weights come from -r or "
@@ -978,7 +980,8 @@ def generate_main(argv=None) -> int:
         params = None
         if model_config is not None:
             params = params_from_config(model_config, args.random_seed)
-            kinds = sorted({k for k, _ in params.layers} - {"attn"})
+            kinds = sorted({k for k, _ in params.layers}
+                           - {"attn", "latent"})
             if kinds and (args.fleet or args.snapshot_dir):
                 # both move a sequence by its KV blocks alone (handoff,
                 # snapshot-resume): refused up front, by what the model

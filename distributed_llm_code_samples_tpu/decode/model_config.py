@@ -2,9 +2,10 @@
 
 ONE function builds the engine for ``train_ffns.py generate
 --model_config FILE`` and for the benchmark's driver file
-(``benchmark/configs/jamba_engine_driver.py``): the published keys say
-what the model is (``models/hybrid_lm.py::spec_from_config``), the
-weights come from a seed or from the caller (a checkpoint restored into
+(``benchmark/configs/jamba_engine_driver.py``,
+``glm_moe_engine_driver.py``): the published keys say what the model is
+(``model_type`` picks the family's file under ``models/``, its
+``spec_from_config`` reads the rest), the weights come from a seed or from the caller (a checkpoint restored into
 the seeded tree, the benchmark's own arrays), and every engine tunable
 keeps the program's default unless the caller's ``EngineConfig`` says
 otherwise.
@@ -15,10 +16,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..models import hybrid_lm
+from ..models import hybrid_lm, mla_moe_lm
 from .engine import DecodeEngine, EngineConfig
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
+
+# model_type -> (the published keys as sizes, seeded weights of them)
+FAMILIES = {
+    "jamba": (hybrid_lm.spec_from_config, hybrid_lm.init_hybrid_lm),
+    "glm4_moe_lite": (mla_moe_lm.spec_from_config,
+                      mla_moe_lm.init_mla_moe_lm),
+}
 
 
 def weights_dtype(config: dict):
@@ -34,11 +42,16 @@ def weights_dtype(config: dict):
 
 def params_from_config(config: dict, seed: int = 0):
     """Seeded weights of the configured model, in its stated type."""
-    spec = hybrid_lm.spec_from_config(config)
+    family = FAMILIES.get(config.get("model_type"))
+    if family is None:
+        raise ValueError(f"model_type {config.get('model_type')!r}: "
+                         f"served are {sorted(FAMILIES)}")
+    spec_from_config, init = family
+    spec = spec_from_config(config)
     seed = int(seed)
     key = jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF),
                              seed >> 31)
-    return hybrid_lm.init_hybrid_lm(
+    return init(
         key, spec, dtype=weights_dtype(config),
         scale=float(config.get("initializer_range", 2e-2)))
 

@@ -53,6 +53,18 @@ Quantization (``kv_dtype``):
   sequence's write history, so continuous batching stays token-identical
   to sequential decode at any dtype (tests/test_decode_engine.py).
 
+A **latent** pool (``latent_rank > 0``; ``models/face.py::LATENT``)
+keeps ONE row a token a layer with no head axis and no K/V pair: ``k
+[L, n_blocks, block, m]`` holds the rows (multi-head latent attention's
+``[c_t | k_rope_t]``, ``m = rank + rope`` lanes) and ``v`` is the same
+shape with a minor axis of 0 — no bytes, so everything that moves a
+block by its id (the scatters, copy-on-write, the prefix cache's
+sharing, scrub, the chaos block) runs on it unchanged. The two reads
+take every head's query against the row as ONE KV head: scores over the
+whole row, values over its first ``latent_rank`` lanes, so one gather
+serves both products. int8 has per-head scales and a latent row no
+heads: refused.
+
 The pool's layer axis counts the layers that own a KV cache index: all
 of an ``LMParams``' layers, the attention layers only of a hybrid
 (``models/hybrid_lm.py``: 2 of 28). What such a model's other layers
@@ -81,7 +93,7 @@ SCRATCH_BLOCK = 0
 
 @functools.partial(jax.tree_util.register_dataclass,
                    data_fields=["k", "v", "k_scale", "v_scale"],
-                   meta_fields=["head_dim"])
+                   meta_fields=["head_dim", "latent_rank"])
 @dataclasses.dataclass(frozen=True)
 class PagedKV:
     """The block pool. ``k/v [L, n_blocks, block, H_kv*dh]`` in the
@@ -89,12 +101,16 @@ class PagedKV:
     module docstring says why); ``k_scale/v_scale [L, n_blocks, H_kv]``
     f32 per-block dequantization scales (``None`` unless
     ``kv_dtype="int8"``). ``head_dim`` is static (pytree metadata, not
-    a leaf): it is what splits a row back into heads."""
+    a leaf): it is what splits a row back into heads. ``latent_rank``
+    > 0 marks a pool of latent rows (the module docstring): ``k`` holds
+    them whole (``head_dim`` their lanes), ``v`` is zero lanes wide,
+    and a row's first ``latent_rank`` lanes are its values."""
     k: jax.Array
     v: jax.Array
     k_scale: jax.Array | None
     v_scale: jax.Array | None
     head_dim: int
+    latent_rank: int = 0
 
     def _replace(self, **fields) -> "PagedKV":
         return dataclasses.replace(self, **fields)
@@ -182,13 +198,15 @@ def storage_dtype(kv_dtype: str):
 
 
 def kv_bytes_per_token(kv_dtype: str, n_layers: int, kv_heads: int,
-                       head_dim: int) -> float:
+                       head_dim: int, latent: bool = False) -> float:
     """Stored KV bytes per cached token position — the roofline's
     ``kv_bytes`` knob. int8 adds the amortized per-block scale pair
     (negligible; counted as 0 here, the bench reports block overheads
-    separately)."""
+    separately). A ``latent`` row is one vector of ``head_dim`` lanes,
+    not a K/V pair."""
     per_elt = {"f32": 4, "bf16": 2, "int8": 1}[kv_dtype]
-    return 2 * n_layers * kv_heads * head_dim * per_elt
+    return ((1 if latent else 2) * n_layers * kv_heads * head_dim
+            * per_elt)
 
 
 def pool_bytes(pool: PagedKV) -> tuple[int, int]:
@@ -204,8 +222,8 @@ def pool_bytes(pool: PagedKV) -> tuple[int, int]:
 
 
 def init_pool(n_layers: int, n_blocks: int, kv_heads: int,
-              block_size: int, head_dim: int,
-              kv_dtype: str = "f32") -> PagedKV:
+              block_size: int, head_dim: int, kv_dtype: str = "f32",
+              latent_rank: int = 0) -> PagedKV:
     """Zero-filled pool. ``n_blocks`` includes the reserved scratch
     block, so at least 2 are required for any real sequence."""
     if n_blocks < 2:
@@ -213,6 +231,15 @@ def init_pool(n_layers: int, n_blocks: int, kv_heads: int,
                          f"is the reserved scratch block), got {n_blocks}")
     shape = (n_layers, n_blocks, block_size, kv_heads * head_dim)
     dt = storage_dtype(kv_dtype)
+    if latent_rank:
+        if kv_dtype == "int8":
+            raise ValueError("kv_dtype int8 is not served for a latent "
+                             "cache: its scales are per KV head, and a "
+                             "latent row has none")
+        return PagedKV(k=jnp.zeros(shape, dt),
+                       v=jnp.zeros(shape[:3] + (0,), dt), k_scale=None,
+                       v_scale=None, head_dim=head_dim,
+                       latent_rank=latent_rank)
 
     def scale():
         # distinct arrays per field: the engine donates the whole pool
@@ -539,6 +566,8 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     states the bound). Stale bytes beyond ``lengths`` meet a
     probability that is exactly 0, as in the oracle (and a NaN there
     still poisons the row: ``corrupt_block``)."""
+    if pool.latent_rank:
+        return _latent_decode_attn(pool, layer, q, tables, lengths)
     b, h, dh = q.shape
     hkv, blk = pool.kv_heads, pool.block_size
     g = h // hkv
@@ -571,6 +600,52 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                           preferred_element_type=jnp.float32)
         y = jnp.einsum("bkgkd->bkgd", full.reshape(b, hkv, g, hkv, dh))
     return y.reshape(b, h, dh)
+
+
+def _latent_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
+                        tables: jax.Array, lengths: jax.Array) -> jax.Array:
+    """``stored_decode_attn`` over latent rows: ``q [B, H, m]`` is each
+    head's query FOR the stored row, scaled by the model
+    (``models/face.py::latent_qrow``); returns ``[B, H, latent_rank]``.
+    ONE gather, and both products run over the whole row as stored —
+    ``s[b,h,t] = rows[b,t,:] . q[b,h,:]`` and ``full[b,h,:] = sum_t
+    p[b,h,t] rows[b,t,:]``, of which the first ``latent_rank`` lanes are
+    the result (the rotary lanes ride along: an eighth more MXU work,
+    and no slice of the gathered view is ever written). The small
+    operands take the rows' dtype, sums are float32."""
+    b, h, m = q.shape
+    with jax.named_scope("gather"):
+        layers = jnp.full_like(tables, layer)
+        rows = pool.k[layers, tables].reshape(b, -1, m)
+    dt = rows.dtype
+    with jax.named_scope("attn"):
+        s = jnp.einsum("btj,bhj->bht", rows, q.astype(dt),
+                       preferred_element_type=jnp.float32)
+        mask = jnp.arange(rows.shape[1]) < lengths[:, None, None]
+        p = jax.nn.softmax(jnp.where(mask, s, jnp.float32(-1e30)), axis=-1)
+        full = jnp.einsum("bht,btj->bhj", p.astype(dt), rows,
+                          preferred_element_type=jnp.float32)
+    return full[..., :pool.latent_rank]
+
+
+def _latent_chunk_attn(pool: PagedKV, layer: int, q: jax.Array,
+                       table: jax.Array, pos0) -> jax.Array:
+    """``gathered_chunk_attn`` over latent rows: ``q [C, H, m]`` at
+    positions ``pos0 .. pos0+C-1`` of ONE sequence, causal over its
+    rows, the same two products as the decode read over the slot's
+    view in float32 (one slot's view is small, as in
+    ``gathered_chunk_attn``). Returns ``[C, H, latent_rank]``."""
+    from ..models.attention import causal_mask
+    c, h, m = q.shape
+    with jax.named_scope("gather"):
+        rows = pool.k[jnp.full_like(table, layer), table].reshape(
+            -1, m).astype(jnp.float32)
+    with jax.named_scope("attn"):
+        s = jnp.einsum("tj,chj->hct", rows, q)
+        mask = causal_mask(c, rows.shape[0], q_offset=pos0)
+        p = jax.nn.softmax(jnp.where(mask, s, jnp.float32(-1e30)), axis=-1)
+        full = jnp.einsum("hct,tj->chj", p, rows)
+    return full[..., :pool.latent_rank]
 
 
 def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
@@ -611,6 +686,8 @@ def gathered_chunk_attn(pool: PagedKV, layer: int, q: jax.Array,
     arithmetic: one slot's f32 head-split view is small). Returns
     ``[C, H, dh]``."""
     from ..models.attention import chunk_attn
+    if pool.latent_rank:
+        return _latent_chunk_attn(pool, layer, q, table, pos0)
     ck, cv = gather_layer(pool, layer, table)
     with jax.named_scope("attn"):
         y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0)

@@ -3,10 +3,11 @@
 ``DecodeEngine`` (``decode/engine.py``) is the scheduler; what a step
 program is made of lives here, put together from two sides. The model
 (``models/face.py::ServedModel``): embedding, norm, an attention
-layer's q/k/v and output projection, a recurrent layer's step and
-chunk, FFN, final norm and tied head — asked of the params, never of
-their class. The cache (``decode/paged.py``): the pool and the
-recurrent state, the writes and the ONE read of each side
+layer's q/k/v and output projection (a latent-cache layer's query and
+row, and its output), a recurrent layer's step and chunk, FFN, final
+norm and head — asked of the params, never of their class. The cache
+(``decode/paged.py``): the pool and the recurrent state, the writes and
+the ONE read of each side
 (``stored_decode_attn`` for decode and verify rows,
 ``gathered_chunk_attn`` for a prefill chunk). Between them, written
 once: the walk over the model's layers (``_trunk``, so prefill and
@@ -27,6 +28,10 @@ transfer and one blocking read. The wire format is written once, here:
 fills the vector in numpy on the host, each body starts with the
 in-graph ``unpack`` (static slices) and ends with ``_fold`` (a pick
 whose logits were not finite reads negative; vocabulary ids never do).
+A model with expert layers gets its counters back on the same array:
+the picks flattened, then ``expert_rows [expert_layers, n_experts]``,
+the rows each held expert received in this dispatch (``split`` is the
+host side; every other model's result is the picks as they were).
 The jitted callable keeps the name ``run`` (the profiler's ``jit_run``,
 which ``benchmark/engine_trace.py`` reads). Under a mesh (the Megatron
 decode layout, ``parallel/lm.py``) the pool is head-sharded, the
@@ -34,8 +39,9 @@ embedding vocab-parallel, the operand and the result replicated, and
 the local logits are gathered in-graph so the pick (keys fold uid and
 position, never the shard) draws the same everywhere.
 
-``jax.named_scope`` names (``decode`` / ``prefill``, ``ssm``, ``head``,
-``sample``) are metadata only (``utils/trace_analysis`` ``SCOPES``).
+``jax.named_scope`` names (``decode`` / ``prefill``, ``ssm``, ``mla``,
+``moe``, ``head``, ``sample``) are metadata only
+(``utils/trace_analysis`` ``SCOPES``).
 """
 
 from __future__ import annotations
@@ -47,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.face import ATTN, CacheSpec, take
+from ..models.face import ATTN, LATENT, CacheSpec, take
 from ..parallel.collectives import all_gather, all_reduce
 from ..parallel.lm import tp_decode_specs, vp_embed
 from ..parallel.mesh import MODEL_AXIS
@@ -105,6 +111,15 @@ def _fold(picks, finite):
     return jnp.where(finite, picks, -1)
 
 
+def _with_counts(picks, counts):
+    """A program's result: the folded picks as they are, or for a model
+    with expert layers ONE flat array, the picks and then the counters
+    (``StepPrograms.split`` is the host side)."""
+    if counts is None:
+        return picks
+    return jnp.concatenate([picks.reshape(-1), counts.reshape(-1)])
+
+
 class StepPrograms:
     """The programs of one engine configuration over one model's face:
     ``build(kind, bucket)`` is what the engine dispatches, ``body`` the
@@ -151,6 +166,19 @@ class StepPrograms:
     def pack(self, kind: str, bucket: int, **fields) -> np.ndarray:
         return self.wire(kind, bucket).pack(**fields)
 
+    def split(self, kind: str, result: np.ndarray):
+        """A dispatch's result on the host: ``(picks, expert_rows)`` —
+        the picks in the shape the kind gives them (``[b]``, ``[1]``, a
+        verify's ``[b, k+2]``) and the expert layers' counters
+        ``[expert_layers, n_experts]``, None for a model with none."""
+        n = self.spec.expert_layers * self.spec.n_experts
+        if not n:
+            return result, None
+        picks = result[:-n]
+        if kind == "verify":
+            picks = picks.reshape(-1, self.cfg.speculate + 2)
+        return picks, result[-n:].reshape(self.spec.expert_layers, -1)
+
     # -- the cache -------------------------------------------------------
 
     def init_cache(self) -> tuple[PagedKV, RecurrentState | None]:
@@ -158,7 +186,8 @@ class StepPrograms:
         layers' state by slot beside it (None for a model with none)."""
         cfg, spec = self.cfg, self.spec
         pool = init_pool(spec.kv_layers, cfg.n_blocks, spec.kv_heads,
-                         cfg.block_size, spec.head_dim, cfg.kv_dtype)
+                         cfg.block_size, spec.head_dim, cfg.kv_dtype,
+                         latent_rank=spec.latent_rank)
         if self.mesh is not None:
             pool = jax.tree.map(
                 lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
@@ -172,7 +201,8 @@ class StepPrograms:
 
     def pool_specs(self) -> PagedKV:
         """Heads are contiguous in a stored row's minor axis
-        (``H_kv*dh``), so sharding that axis shards the heads."""
+        (``H_kv*dh``), so sharding that axis shards the heads (a latent
+        row has none: the engine refuses a mesh for such a model)."""
         arr = P(None, None, None, MODEL_AXIS)
         sc = (P(None, None, MODEL_AXIS) if self.cfg.kv_dtype == "int8"
               else None)
@@ -189,12 +219,19 @@ class StepPrograms:
         norm, q/k/v, the caller's ``write_attn(i, pool, q, k, v) ->
         (pool, y [N, h_loc, dh])`` (where the programs differ: batched
         single-token writes and per-slot reads, or one slot's chunk),
-        output projection. Recurrent: norm, the caller's ``mix(i,
-        state, a) -> (state, y [N, d])``. Then the FFN; under a mesh
-        both residual adds take the Megatron all-reduce."""
+        output projection. Latent: the same seam — the query for the
+        stored row as ``q``, the row as the one "key" of one head and
+        no value (the pool's ``v`` is zero lanes wide); the read's ``[N,
+        h, latent_rank]`` goes to the model's ``latent_out``. Recurrent:
+        norm, the caller's ``mix(i, state, a) -> (state, y [N, d])``.
+        Then the FFN; under a mesh both residual adds take the Megatron
+        all-reduce. Returns ``(cache, x, counts)``: ``counts
+        [expert_layers, n_experts]`` the rows each held expert received,
+        None for a model with no expert layer."""
         tp = self.mesh is not None
         pool, state = cache if self.spec.rec_layers else (cache, None)
         n = x.shape[0]
+        counts = []
         for l, (kind, i) in enumerate(p.layers):
             a = p.norm(p.norm_in[l], x)
             if kind == ATTN:
@@ -202,12 +239,25 @@ class StepPrograms:
                                      self.cfg.use_rope)
                 pool, y = write_attn(i, pool, q, k, v)
                 y = p.attn_out(i, y.reshape(n, -1))
+            elif kind == LATENT:
+                with jax.named_scope("mla"):
+                    q, row = p.latent_qrow(i, a, positions)
+                    pool, y = write_attn(i, pool, q, row[:, None, :],
+                                         row[:, None, :0])
+                    y = p.latent_out(i, y)
             else:
                 state, y = mix(i, state, a)
             x = x + (all_reduce(y, MODEL_AXIS) if tp else y)
-            f = p.ffn(l, p.norm(p.norm_ff[l], x))
+            h = p.norm(p.norm_ff[l], x)
+            if self.spec.expert_layers:
+                f, rows = p.ffn_counted(l, h)
+                if rows is not None:
+                    counts.append(rows)
+            else:
+                f = p.ffn(l, h)
             x = x + (all_reduce(f, MODEL_AXIS) if tp else f)
-        return (pool if state is None else (pool, state)), x
+        return ((pool if state is None else (pool, state)), x,
+                jnp.stack(counts) if counts else None)
 
     def logits(self, p, x):
         """Final norm and tied head of ``x [N, d]``; under a mesh each
@@ -223,7 +273,7 @@ class StepPrograms:
         written at its own position and attended over its blocks as
         stored; a recurrent layer advances each row's own state
         (``rows [b]``: the slot's state row, the scratch row for a
-        padded one). Returns ``(cache, x [b, d])``."""
+        padded one). Returns ``(cache, x [b, d], counts)``."""
         cfg = self.cfg
         x = self._embed(p, tokens, lengths)             # [b, d]
         slot_phys = lengths // cfg.block_size
@@ -303,11 +353,11 @@ class StepPrograms:
         @jax.named_scope("decode")
         def run(p, cache, operand):
             f = wire.unpack(operand)
-            cache, x = self.decode_hidden(b, p, cache, f["tables"],
-                                          f["lengths"], f["tokens"],
-                                          f.get("rows"))
-            return cache, _fold(*self._head_pick(
-                p, x, f["uids"], f["poison"], f["lengths"], 1))
+            cache, x, counts = self.decode_hidden(
+                b, p, cache, f["tables"], f["lengths"], f["tokens"],
+                f.get("rows"))
+            return cache, _with_counts(_fold(*self._head_pick(
+                p, x, f["uids"], f["poison"], f["lengths"], 1)), counts)
 
         return run
 
@@ -334,7 +384,7 @@ class StepPrograms:
             alive = jnp.ones((b,), bool)
             acc = jnp.zeros((b,), jnp.int32)
             cur = tokens
-            picks_all, finite_all = [], []
+            picks_all, finite_all, counts = [], [], None
             for i in range(k + 1):
                 pos = lengths + i
                 x = self._embed(p, cur, pos)                 # [b, d]
@@ -350,7 +400,9 @@ class StepPrograms:
                     return pool, stored_decode_attn(pool, l, q, tables,
                                                     lengths + _i + 1)
 
-                pool, x = self._trunk(p, pool, x, pos, write_attn)
+                pool, x, cnt = self._trunk(p, pool, x, pos, write_attn)
+                if cnt is not None:     # summed over the sub-steps
+                    counts = cnt if counts is None else counts + cnt
                 pk, finite = self._head_pick(p, x, uids, poison, pos, 1)
                 picks_all.append(pk)
                 finite_all.append(finite)
@@ -361,7 +413,8 @@ class StepPrograms:
                     acc = acc + alive.astype(jnp.int32)
                     cur = d
             picks = _fold(jnp.stack(picks_all, 1), jnp.stack(finite_all, 1))
-            return pool, jnp.concatenate([picks, acc[:, None]], 1)
+            return pool, _with_counts(
+                jnp.concatenate([picks, acc[:, None]], 1), counts)
 
         return run
 
@@ -374,11 +427,12 @@ class StepPrograms:
         @jax.named_scope("prefill")
         def run(p, cache, operand):
             f = wire.unpack(operand)
-            cache, x = self.prefill_hidden(c, p, cache, f["table"],
-                                           f["pos0"], f["tokens"],
-                                           f.get("row"))
-            return cache, _fold(*self._head_pick(
-                p, x[-1:], f["uid"], f["poison"], f["pos0"][None], c))
+            cache, x, counts = self.prefill_hidden(
+                c, p, cache, f["table"], f["pos0"], f["tokens"],
+                f.get("row"))
+            return cache, _with_counts(_fold(*self._head_pick(
+                p, x[-1:], f["uid"], f["poison"], f["pos0"][None], c)),
+                counts)
 
         return run
 

@@ -5,12 +5,17 @@ weights) with the methods of ``ServedModel``. ``decode/programs.py``
 builds every compiled step program from those answers and from the
 cache (``decode/paged.py``); neither it nor the scheduler asks which
 class the params are, reads a weight by name or calls a family's
-arithmetic. ``models/lm.py`` and ``models/hybrid_lm.py`` are the two
-families that exist; ``tests/test_model_face.py`` serves a third that
-lives in the test alone. The builder keeps the cache write and read of
-an attention layer (between ``attn_qkv`` and ``attn_out``), the row of
-the recurrent state a sequence owns, the residual adds and, under a
-mesh, the collectives.
+arithmetic. ``models/lm.py``, ``models/hybrid_lm.py`` and
+``models/mla_moe_lm.py`` are the families that exist;
+``tests/test_model_face.py`` serves one more that lives in the test
+alone. The builder keeps the cache write and read of an attention layer
+(between ``attn_qkv`` and ``attn_out``, or for a latent-cache layer
+between ``latent_qrow`` and ``latent_out``), the row of the recurrent
+state a sequence owns, the residual adds, the expert layers' counters
+and, under a mesh, the collectives.
+
+What more than one family is written from lives below the face: ``mm``,
+``rmsnorm``, the gated SiLU MLP.
 """
 
 from __future__ import annotations
@@ -23,6 +28,9 @@ import jax.numpy as jnp
 from .attention import rope
 
 ATTN = "attn"           # the layer kind whose cache is paged KV blocks
+# ... and the kind whose paged cache row is ONE latent vector a token:
+# no head axis and no K/V pair (multi-head latent attention, absorbed)
+LATENT = "latent"
 
 
 class CacheSpec(NamedTuple):
@@ -30,7 +38,13 @@ class CacheSpec(NamedTuple):
     layers own paged KV of ``kv_heads`` heads of ``head_dim`` lanes;
     ``rec_layers`` layers own a recurrent state of inner width
     ``d_inner``, state size ``d_state`` and ``d_conv`` convolution taps
-    (0 for a model with none). Pool and state are built from this."""
+    (0 for a model with none). ``latent_rank`` > 0 says the ``kv_layers``
+    are ``LATENT`` ones: a token's row is one vector of ``head_dim``
+    lanes (``kv_heads`` 1) whose first ``latent_rank`` are also its
+    values. Pool and state are built from this. Beside what is kept:
+    ``expert_layers`` layers route their rows over ``n_experts`` held
+    experts and count them, which sizes the counters a step program
+    returns after its picks (0 for a model with no expert layer)."""
     kv_layers: int
     kv_heads: int
     head_dim: int
@@ -38,13 +52,19 @@ class CacheSpec(NamedTuple):
     d_inner: int = 0
     d_state: int = 0
     d_conv: int = 0
+    latent_rank: int = 0
+    expert_layers: int = 0
+    n_experts: int = 0
 
 
 class ServedModel(Protocol):
     """The face. ``l`` is a model layer, ``i`` the index a layer has in
     its own kind's weights and cache (``layers`` gives both), ``a`` the
     normed residual stream ``[N, d]``. A family without recurrent
-    layers is never asked for the two ``recurrent_`` methods."""
+    layers is never asked for the two ``recurrent_`` methods, one
+    without ``LATENT`` layers never for the two ``latent_`` ones, one
+    whose ``cache_spec`` names no expert layer never for
+    ``ffn_counted``."""
     vocab: int
     d_model: int
     n_layers: int
@@ -68,6 +88,15 @@ class ServedModel(Protocol):
 
     def attn_out(self, i, y): ...   # y [N, H*dh] -> [N, d]
 
+    # a LATENT layer: -> (q [N, H, m], row [N, m]), ``m`` the stored
+    # row's lanes. ``row`` is what the cache keeps of each token, ``q``
+    # the query FOR the stored rows, rotated and scaled already: the
+    # read is ``p = softmax_t(q . row_t)`` and ``o = sum_t p_t
+    # row_t[:latent_rank]``, no factor added
+    def latent_qrow(self, i, a, positions): ...
+
+    def latent_out(self, i, o): ...  # o [N, H, latent_rank] -> [N, d]
+
     # one token of b rows: a [b, d], tail [b, K-1, D], state [b, N, D];
     # a chunk of one row: a [c, d], tail [K-1, D], state [N, D]
     # -> (y, tail, state)
@@ -76,7 +105,12 @@ class ServedModel(Protocol):
 
     def ffn(self, l, h): ...
 
-    # final norm and tied head: [N, d] -> [N, V] (the local V/n columns
+    # the same for a family with expert layers: -> (y, rows), ``rows
+    # [n_experts]`` int32 the rows of ``h`` each held expert received,
+    # None for a layer that routes nothing
+    def ffn_counted(self, l, h): ...
+
+    # final norm and head: [N, d] -> [N, V] (the local V/n columns
     # of a vocab-sharded embedding)
     def head(self, x): ...
 
@@ -93,6 +127,26 @@ def mm(x: jax.Array, w: jax.Array) -> jax.Array:
         return x @ w.T
     return jnp.matmul(x.astype(w.dtype), w.T,
                       preferred_element_type=jnp.float32)
+
+
+def rmsnorm(g: jax.Array, x: jax.Array, eps: float) -> jax.Array:
+    """Gain-only RMSNorm over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    ms = jnp.mean(x * x, axis=-1, keepdims=True)
+    return g.astype(jnp.float32) * (x * jax.lax.rsqrt(ms + eps))
+
+
+class MLPStack(NamedTuple):
+    """Gated SiLU MLPs, stacked ``[L, ...]``."""
+    w_gate: jax.Array    # [L, F, d]
+    w_up: jax.Array      # [L, F, d]
+    w_down: jax.Array    # [L, d, F]
+
+
+def gated_mlp(mlp: MLPStack, l: int, h: jax.Array) -> jax.Array:
+    """``W_down (silu(W_gate h) * W_up h)`` of stack entry ``l``."""
+    return mm(jax.nn.silu(mm(h, mlp.w_gate[l])) * mm(h, mlp.w_up[l]),
+              mlp.w_down[l])
 
 
 def qkv_heads(wq, wk, wv, i: int, a, positions, head_dim: int,

@@ -38,7 +38,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import ssm
-from .face import ATTN, CacheSpec, mm, qkv_heads
+from .face import (ATTN, CacheSpec, MLPStack, gated_mlp, mm, qkv_heads,
+                   rmsnorm)
 
 MAMBA = "mamba"
 
@@ -68,13 +69,6 @@ class AttnStack(NamedTuple):
     wk: jax.Array        # [L_a, H_kv*dh, d]
     wv: jax.Array        # [L_a, H_kv*dh, d]
     wo: jax.Array        # [L_a, d, H*dh]
-
-
-class MLPStack(NamedTuple):
-    """The gated SiLU MLP of every layer, stacked ``[L, ...]``."""
-    w_gate: jax.Array    # [L, F, d]
-    w_up: jax.Array      # [L, F, d]
-    w_down: jax.Array    # [L, d, F]
 
 
 @functools.partial(
@@ -168,9 +162,7 @@ class HybridLMParams:
                       ssm.scan_chunk)
 
     def ffn(self, l, h):
-        gate = mm(h, self.mlp.w_gate[l])
-        return mm(jax.nn.silu(gate) * mm(h, self.mlp.w_up[l]),
-                  self.mlp.w_down[l])
+        return gated_mlp(self.mlp, l, h)
 
     def head(self, x):
         return mm(rmsnorm(self.ln_f, x, self.eps), self.wte)
@@ -293,13 +285,6 @@ def init_hybrid_lm(key: jax.Array, spec: HybridSpec, dtype=jnp.float32,
 
 
 # -- the block's pieces (the face's methods above put them together) ----
-
-
-def rmsnorm(g: jax.Array, x: jax.Array, eps: float) -> jax.Array:
-    """Gain-only RMSNorm over the last axis, in float32."""
-    x = x.astype(jnp.float32)
-    ms = jnp.mean(x * x, axis=-1, keepdims=True)
-    return g.astype(jnp.float32) * (x * jax.lax.rsqrt(ms + eps))
 
 
 def _mamba_dt_b_c(p: HybridLMParams, i: int, x: jax.Array):

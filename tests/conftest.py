@@ -74,6 +74,25 @@ def toy_hybrid_config():
 
 
 @pytest.fixture(scope="session")
+def toy_latent_config():
+    """A published-style ``config.json`` of a toy latent-attention,
+    sparse-expert model (``model_type: glm4_moe_lite``;
+    ``tests/test_mla_moe_lm.py::TOY``'s widths): a cache row of 32 + 8
+    lanes, one dense layer, three layers of 16 experts with the top 4
+    and a shared one."""
+    return dict(
+        model_type="glm4_moe_lite", hidden_size=64, intermediate_size=160,
+        moe_intermediate_size=48, num_attention_heads=4,
+        num_key_value_heads=4, n_routed_experts=16, n_shared_experts=1,
+        num_experts_per_tok=4, routed_scaling_factor=1.8,
+        first_k_dense_replace=1, num_hidden_layers=4, q_lora_rank=24,
+        kv_lora_rank=32, qk_nope_head_dim=12, qk_rope_head_dim=8,
+        v_head_dim=16, vocab_size=96, rms_norm_eps=1e-5,
+        rope_theta=1000000, rope_scaling=None, tie_word_embeddings=False,
+        max_position_embeddings=256, initializer_range=0.2)
+
+
+@pytest.fixture(scope="session")
 def mesh8():
     return make_mesh({DATA_AXIS: 8})
 

@@ -362,6 +362,105 @@ def test_hybrid_step_program_keeps_the_state_as_stored(one_chip,
     assert _total_bytes(compiled) < HBM_V5E
 
 
+
+# the latent-attention, sparse-expert cell of the benchmark
+# (glm47-flash.reasoning-offline): GLM-4.7-Flash's widths, all 64 experts
+# and the whole vocabulary, 64 slots x 2048 positions, bf16 weights and
+# latent rows, cut to 3 layers (the dense one and two expert layers) so
+# the compile stays short
+GLM = dict(layers=3, slots=64, mbps=128, chunk=16)
+
+
+class _ShapesEngine:
+    """What the static pins read of an engine, over SHAPES alone (9 GB
+    of seeded weights would be made for their shapes): the builder's
+    programs, the params and the pool as ``jax.eval_shape`` gives
+    them."""
+
+    def __init__(self, programs, params, pool):
+        self.programs, self.cfg = programs, programs.cfg
+        self.params, self.pool = params, pool
+
+    def _program(self, kind, bucket):
+        return self.programs.build(kind, bucket)
+
+    def _cache(self):
+        return self.pool
+
+
+@pytest.fixture(scope="module")
+def glm_engine_args():
+    """``(engine, {kind: (bucket, args)})`` at the new cell's widths:
+    the configuration's own file through the family's
+    ``spec_from_config``, the step programs as ``DecodeEngine`` builds
+    them (``StepPrograms`` over the model's ``cache_spec``)."""
+    import json
+    from distributed_llm_code_samples_tpu.decode import EngineConfig
+    from distributed_llm_code_samples_tpu.decode.programs import StepPrograms
+    from distributed_llm_code_samples_tpu.models import mla_moe_lm
+    g = GLM
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm47-flash-serve.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=g["layers"])
+    spec = mla_moe_lm.spec_from_config(config)
+    params = jax.eval_shape(
+        lambda k: mla_moe_lm.init_mla_moe_lm(k, spec, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    slots, mbps, chunk = g["slots"], g["mbps"], g["chunk"]
+    programs = StepPrograms(
+        EngineConfig(n_blocks=1 + slots * mbps, max_slots=slots,
+                     max_blocks_per_seq=mbps, prefill_chunk=chunk,
+                     kv_dtype="bf16"),
+        params.cache_spec(spec.n_heads), params.vocab)
+    eng = _ShapesEngine(programs, params,
+                        jax.eval_shape(lambda: programs.init_cache()[0]))
+    decode, prefill = _step_args(eng, slots, chunk)
+    return eng, {"decode": (slots, decode), "prefill": (chunk, prefill)}
+
+
+def _nbytes(x) -> int:
+    return x.size * x.dtype.itemsize
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_latent_step_program_keeps_the_pool_as_stored(one_chip,
+                                                      glm_engine_args, kind):
+    """A pool of latent rows (``decode/paged.py``: ``k`` the rows, ``v``
+    no lanes) is to a step program what the K/V pool is: taken row-major
+    and unpadded, updated in place, never copied and no layer's slab
+    sliced out. It holds because the model fills its 576-lane row to 640
+    (``models/mla_moe_lm.py::ROW_LANES``): at 576 the chip keeps the
+    pool block-index innermost and copies all of it twice a program
+    (read on this compiler at PR 31, as PR 26 read it at 320 lanes). The
+    result carries the experts' counters after the picks."""
+    eng, programs = glm_engine_args
+    bucket, args = programs[kind]
+    compiled = eng._program(kind, bucket).lower(
+        *_shapes_of(args, one_chip)).compile()
+    pool = eng.pool
+    assert pool.k.shape == (3, 8193, 16, 640) and pool.v.shape[-1] == 0
+    slab = pool.k.size // pool.k.shape[0]
+    # whole slabs of the POOL: a layer's expert matrices are larger than
+    # one (64 x 1536 x 2048) and are sliced out of their stack inside
+    # the fusion that multiplies them, which writes nothing
+    moved = [r for r in _hlo_results(
+        compiled.as_text(), ("copy", "slice", "dynamic-slice"), "bf16")
+        if r[1] >= slab and r[1] % slab == 0]
+    assert not moved, moved
+    fmt = compiled.input_formats[0][1].k
+    assert fmt.layout.major_to_minor == tuple(range(pool.k.ndim)), fmt
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= _nbytes(pool.k)
+    logical = sum(_nbytes(x) for x in jax.tree_util.tree_leaves(args[:2]))
+    assert m.argument_size_in_bytes - logical < _nbytes(pool.k) // 100
+    assert _total_bytes(compiled) < HBM_V5E
+    picks = bucket if kind == "decode" else 1
+    assert compiled.output_shardings is not None
+    out = jax.eval_shape(eng.programs.body(kind, bucket), *args)[1]
+    assert out.shape == (picks + 2 * 64,) and out.dtype == jnp.int32
+
+
 def _toy_engine(family, ways, speculate, hybrid_config):
     """A GPT-2-shaped toy or the toy hybrid, as small as they compile."""
     from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
@@ -438,7 +537,8 @@ def _entry_results(hlo: str):
 
 
 @pytest.mark.parametrize("fixture", ["gpt2_large_engine_args",
-                                     "jamba_engine_args"])
+                                     "jamba_engine_args",
+                                     "glm_engine_args"])
 def test_decode_program_reads_the_gathered_rows_as_stored(one_chip, request,
                                                           fixture):
     """The decode program attends over each slot's gathered blocks in
@@ -455,7 +555,9 @@ def test_decode_program_reads_the_gathered_rows_as_stored(one_chip, request,
     for the hybrid, whose one KV head of 128 lanes it already read as
     stored. ``temp_size_in_bytes`` of this 2-layer GPT-2 program: 152
     MB on the parent, 0.8 MB now; one layer's attention alone 151 MB
-    against 0.)"""
+    against 0.) The latent cell's rows are read the same way: one
+    gather of 640-lane rows a layer, both products over them as stored,
+    no slice of the view for the values' 512 lanes.)"""
     eng, programs = request.getfixturevalue(fixture)
     bucket, args = programs["decode"]
     compiled = eng._program("decode", bucket).lower(
@@ -477,22 +579,34 @@ def test_decode_program_reads_the_gathered_rows_as_stored(one_chip, request,
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 22
 
 
+# cell -> (its shrink under benchmark/tests, the per-layer metric that
+# reads one of the program's own counters: readable with no device trace)
+REHEARSED = {
+    "jamba2-3b.reasoning-offline": ("shrink_jamba", "state_bytes_live"),
+    "glm47-flash.reasoning-offline": ("shrink_glm",
+                                      "expert_rows_max_over_mean"),
+}
+
+
 @pytest.mark.parametrize("trace", [0, 1])
-def test_hybrid_cell_rehearsal_on_the_cpu(monkeypatch, trace):
-    """The new cell's whole control flow on the CPU at toy size, as
+@pytest.mark.parametrize("name", sorted(REHEARSED))
+def test_cell_rehearsal_on_the_cpu(monkeypatch, name, trace):
+    """A newer cell's whole control flow on the CPU at toy size, as
     ``benchmark/tests/test_rehearsal.py`` rehearses the older cells
-    (its ``shrink.py`` knows those only; the hybrid cell's shrink is
-    ``benchmark/tests/shrink_jamba.py``). Nothing here is a measurement."""
+    (its ``shrink.py`` knows those only; these cells' shrinks are
+    ``benchmark/tests/shrink_jamba.py`` and ``shrink_glm.py``). Nothing
+    here is a measurement."""
+    import importlib
     import json
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     monkeypatch.syspath_prepend(root)
     from benchmark import flops, run
-    from benchmark.tests import shrink_jamba
+    shrink, counter = REHEARSED[name]
+    shrink = importlib.import_module("benchmark.tests." + shrink)
     real = flops.peaks
     monkeypatch.setattr(flops, "peaks", lambda kind: real("TPU v5 lite"))
-    name = "jamba2-3b.reasoning-offline"
     line = run.run_cell(name, 2**31 + 4242, 1.5, bool(trace),
-                        check_device=False, shrink=shrink_jamba.serve)
+                        check_device=False, shrink=shrink.serve)
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     with open(os.path.join(root, "BENCHMARK.json")) as f:
@@ -506,7 +620,7 @@ def test_hybrid_cell_rehearsal_on_the_cpu(monkeypatch, trace):
             assert body["unit"] == listed[metric]["unit"]
         # the program's counter has its reader (device metrics need a
         # device trace: none on the CPU)
-        assert line["metrics"]["state_bytes_live"]["value"] > 0
+        assert line["metrics"][counter]["value"] > 0
 
 
 def test_train_single_step_compiles_at_paper_width(one_chip):

@@ -116,17 +116,17 @@ def cached_logits(eng, tokens, chunks, decode_from=None):
         c = 1 << (c.bit_length() - 1)              # power-of-two chunks
         # the prefill body returns picks only: run its trunk as it does
         body = bodies.setdefault(c, _prefill_rows(eng, c))
-        cache, x = body(p, cache, jnp.asarray(table), jnp.int32(pos),
-                        jnp.asarray(tokens[pos:pos + c], jnp.int32),
-                        jnp.int32(slot))
+        cache, x, _ = body(p, cache, jnp.asarray(table), jnp.int32(pos),
+                           jnp.asarray(tokens[pos:pos + c], jnp.int32),
+                           jnp.int32(slot))
         rows.append(head(x))
         pos += c
     body = _decode_rows(eng, 1)
     while pos < t:
-        cache, x = body(p, cache, jnp.asarray(table[None]),
-                        jnp.asarray([pos], jnp.int32),
-                        jnp.asarray(tokens[pos:pos + 1], jnp.int32),
-                        jnp.asarray([slot], jnp.int32))
+        cache, x, _ = body(p, cache, jnp.asarray(table[None]),
+                           jnp.asarray([pos], jnp.int32),
+                           jnp.asarray(tokens[pos:pos + 1], jnp.int32),
+                           jnp.asarray([slot], jnp.int32))
         rows.append(head(x))
         pos += 1
     state = cache[1]
@@ -474,7 +474,7 @@ class InlineGPT2:
             x = x + reduce(y.reshape(n, -1) @ blk.wo[l].T)
             h = layernorm(blk.ln2[l], x)
             x = x + reduce(jnp.maximum(h @ blk.w1[l].T, 0.0) @ blk.w2[l].T)
-        return pool, x
+        return pool, x, None
 
     def logits(self, p, x):
         from distributed_llm_code_samples_tpu.ops.norm import layernorm
@@ -515,7 +515,7 @@ class InlineHybrid:
             gate = h @ p.mlp.w_gate[l].T
             x = x + (jax.nn.silu(gate) * (h @ p.mlp.w_up[l].T)
                      ) @ p.mlp.w_down[l].T
-        return (pool, state), x
+        return (pool, state), x, None
 
     def logits(self, p, x):
         return hybrid_lm.rmsnorm(p.ln_f, x, p.eps) @ p.wte.T

@@ -32,7 +32,8 @@ from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
 from distributed_llm_code_samples_tpu.decode.model_config import (
     params_from_config)
 from distributed_llm_code_samples_tpu.models import init_lm
-from distributed_llm_code_samples_tpu.models.face import ATTN, CacheSpec
+from distributed_llm_code_samples_tpu.models.face import (ATTN, LATENT,
+                                                          CacheSpec)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG = os.path.join(ROOT, "distributed_llm_code_samples_tpu")
@@ -198,6 +199,35 @@ SPECS = {
 }
 
 
+def test_cache_spec_sizes_a_latent_pool(toy_latent_config):
+    """A latent-cache, sparse-expert model in small
+    (``models/mla_moe_lm.py``: one row of 32 + 8 lanes a token a layer,
+    filled to a whole 128-lane tile). Its spec gives the pool ONE row a token a
+    layer: ``k`` holds it, ``v`` has the same shape with no lanes (the
+    harness's byte count over ``pool.k`` and ``pool.v`` is the row's),
+    no state beside it, and the result's counters their size."""
+    params = params_from_config(toy_latent_config, 1)
+    want = CacheSpec(4, 1, 128, latent_rank=32, expert_layers=3,
+                     n_experts=16)
+    assert params.cache_spec(4) == want
+    assert {kind for kind, _ in params.layers} == {LATENT}
+    eng = DecodeEngine(params, 4, EngineConfig(
+        block_size=8, n_blocks=11, max_slots=2, max_blocks_per_seq=5,
+        kv_dtype="bf16"))
+    pool = eng.pool
+    assert eng.spec == want and eng.state is None and eng.recurrent == []
+    assert pool.k.shape == (4, 11, 8, 128) and pool.v.shape == (4, 11, 8, 0)
+    assert (pool.latent_rank, pool.kv_heads, pool.head_dim) == (32, 1, 128)
+    per_token = ((pool.k.size * pool.k.dtype.itemsize
+                  + pool.v.size * pool.v.dtype.itemsize)
+                 / (pool.n_blocks * pool.block_size))
+    assert per_token == eng._kv_bytes_per_token() == 4 * 128 * 2
+    assert eng.programs.wire("decode", 2).fields.keys() == {
+        "tables", "lengths", "tokens", "uids", "poison"}
+    picks, rows = eng.programs.split("decode", np.arange(2 + 3 * 16))
+    assert picks.tolist() == [0, 1] and rows.shape == (3, 16)
+
+
 @pytest.mark.parametrize("family", sorted(SPECS))
 def test_cache_spec_sizes_pool_and_state(family):
     """What the model says it keeps per sequence is what the engine
@@ -229,9 +259,10 @@ def test_cache_spec_sizes_pool_and_state(family):
 SCHEDULER_AND_BUILDER = [os.path.join(PKG, "decode", name)
                          for name in ("engine.py", "programs.py")]
 # a family's arithmetic, and what it is written from
-ARITHMETIC = re.compile(r"(^|\.)(models\.(lm|hybrid_lm|attention|transformer"
-                        r"|moe\w*|ffn_stack)|ops\.(norm|ssm|ffn|activations))$")
-FACE_NAMES = {"ATTN", "CacheSpec", "ServedModel", "take"}
+ARITHMETIC = re.compile(r"(^|\.)(models\.(lm|hybrid_lm|mla_moe_lm|attention"
+                        r"|transformer|moe\w*|ffn_stack)"
+                        r"|ops\.(norm|ssm|ffn|activations|moe\w*))$")
+FACE_NAMES = {"ATTN", "LATENT", "CacheSpec", "ServedModel", "take"}
 
 
 def _imports(path):
@@ -264,7 +295,8 @@ def test_scheduler_and_builder_know_no_family(path):
     # the acceptance criteria's own three lines
     assert not re.search(r"isinstance\([^)]*Params", src)
     assert not re.search(r"hybrid_lm\.[a-z_]*\(|layernorm\(|rope\(|"
-                         r"params\.mamba|params\.blocks|p\.blocks", src)
+                         r"params\.mamba|params\.blocks|p\.blocks|"
+                         r"mla_moe_lm|moe_serve|p\.experts|p\.mla", src)
 
 
 def test_models_import_nothing_from_decode_or_parallel():

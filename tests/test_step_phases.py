@@ -261,8 +261,10 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
         assert set(rec) == {"uid", "span", "start_step", "step",
                             "start_ns", "end_ns", "t", "duration_s",
                             "phases", "tokens_generated",
-                            "state_bytes"}
+                            "state_bytes", "expert_rows",
+                            "experts_touched", "expert_rows_max"}
         assert rec["state_bytes"] == 0      # no recurrent layer here
+        assert rec["expert_rows"] == rec["experts_touched"] == 0  # nor expert
         assert rec["step"] == eng.global_step == eng.flight[-1]["step"]
         assert rec["tokens_generated"] == eng.tokens_generated
         n_pre = eng.prefill_dispatches - pre
