@@ -136,15 +136,27 @@ class RecurrentState:
     """The second kind of per-sequence state: what a recurrent
     (state-space) layer carries, which does not grow with the sequence.
     Indexed by SLOT, not through a block table: ``conv [L_r, slots+1,
-    (K-1)*D]`` the convolution's last ``K-1`` inputs, oldest first, and
-    ``ssm [L_r, slots+1, N, D]`` the scan state (``ops/ssm.py``), both
-    float32, ``L_r`` the model's recurrent layers. The inner width is
-    the minor axis of both, so the chip keeps them unpadded (a ``[D,
+    1, (K-1)*D]`` the convolution's last ``K-1`` inputs, oldest first,
+    and ``ssm [L_r, slots+1, N, D]`` the scan state (``ops/ssm.py``),
+    both float32, ``L_r`` the model's recurrent layers. The inner width
+    is the minor axis of both, so the chip keeps them unpadded (a ``[D,
     N]`` state would pad ``N = 16`` up to 128 lanes). Row ``slots`` is
     the scratch row — the pool's idiom: padded bucket rows read and
     write it, nothing else does. A slot's row is never cleared: the
     prefill program takes zeros in its place at position 0. Donated
-    into the step programs beside the pool and updated in place."""
+    into the step programs beside the pool and updated in place: the
+    decode program advances a batch's rows WHERE THEY LIE (PR 32,
+    ``ops/ssm.py::conv_step_in_place`` / ``scan_step_in_place``: no
+    gathered copy, no scatter), the prefill chunk slices its one row.
+
+    The tail is FLAT, ``(K-1)*D`` lanes a row, taps end to end, under
+    an axis of one: a tap is then ``D`` whole lanes of the row, which
+    an op slices without a re-layout, and a row is a legal block of a
+    kernel (``[1, (K-1)*D]``), which the chip keeps row-major in
+    one-row tiles with no padding (without the axis of one its 8-row
+    tiles pad 65 rows to 72). ``[slots+1, K-1, D]`` the chip keeps
+    tap-major, and every program re-lays the whole store out on the way
+    in and on the way out (static, this compiler: PERF.md §6, PR 32)."""
     conv: jax.Array
     ssm: jax.Array
 
@@ -170,7 +182,7 @@ def init_state(n_layers: int, slots: int, d_inner: int, d_state: int,
     scratch row) over ``n_layers`` recurrent layers."""
     rows = slots + 1
     return RecurrentState(
-        conv=jnp.zeros((n_layers, rows, (d_conv - 1) * d_inner),
+        conv=jnp.zeros((n_layers, rows, 1, (d_conv - 1) * d_inner),
                        jnp.float32),
         ssm=jnp.zeros((n_layers, rows, d_state, d_inner), jnp.float32))
 

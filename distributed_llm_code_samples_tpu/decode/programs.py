@@ -287,14 +287,9 @@ class StepPrograms:
 
         def mix(i, state, a):
             with jax.named_scope("ssm"):
-                tail = state.conv[i, rows]
-                y, tail, s = p.recurrent_step(
-                    i, a, tail.reshape(b, -1, state.ssm.shape[-1]),
-                    state.ssm[i, rows])
-                state = state._replace(
-                    conv=state.conv.at[i, rows].set(tail.reshape(b, -1)),
-                    ssm=state.ssm.at[i, rows].set(s))
-            return state, y
+                y, conv, ssm = p.recurrent_step(i, a, state.conv,
+                                                state.ssm, rows)
+            return RecurrentState(conv, ssm), y
 
         return self._trunk(p, cache, x, lengths, write_attn, mix)
 
@@ -322,7 +317,7 @@ class StepPrograms:
                     i, a, tail.reshape(-1, state.ssm.shape[-1]),
                     jnp.where(fresh, 0.0, state.ssm[i, row]))
                 state = state._replace(
-                    conv=state.conv.at[i, row].set(tail.reshape(-1)),
+                    conv=state.conv.at[i, row].set(tail.reshape(1, -1)),
                     ssm=state.ssm.at[i, row].set(s))
             return state, y
 

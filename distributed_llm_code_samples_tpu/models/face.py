@@ -97,10 +97,14 @@ class ServedModel(Protocol):
 
     def latent_out(self, i, o): ...  # o [N, H, latent_rank] -> [N, d]
 
-    # one token of b rows: a [b, d], tail [b, K-1, D], state [b, N, D];
+    # one token of b rows, a [b, d]: the state is advanced where it is
+    # stored (``decode/paged.py::RecurrentState``: conv [L_r, S, 1,
+    # (K-1)*D], ssm [L_r, S, N, D], both WHOLE), rows [b] naming each
+    # row's slot -> (y, conv, ssm)
+    def recurrent_step(self, i, a, conv, ssm, rows): ...
+
     # a chunk of one row: a [c, d], tail [K-1, D], state [N, D]
     # -> (y, tail, state)
-    def recurrent_step(self, i, a, tail, state): ...
     def recurrent_chunk(self, i, a, tail, state): ...
 
     def ffn(self, l, h): ...

@@ -147,11 +147,15 @@ class HybridLMParams:
     def attn_out(self, i, y):
         return mm(y, self.attn.wo[i])
 
-    def recurrent_step(self, i, a, tail, state):
-        """Mamba mixer ``i`` for one token of each of ``b`` sequences:
-        ``a [b, d]``, ``tail [b, K-1, D]``, ``state [b, N, D]``."""
-        return _mamba(self, i, a, tail, state, ssm.conv_step,
-                      ssm.scan_step)
+    def recurrent_step(self, i, a, conv, state, rows):
+        """Mamba mixer ``i`` for one token of each of ``b`` sequences,
+        ``a [b, d]``: ``conv`` and ``state`` are the WHOLE stores
+        (``decode/paged.py::RecurrentState``), of which rows ``rows
+        [b]`` of layer ``i`` are advanced in place."""
+        at = dict(layer=i, rows=rows)
+        return _mamba(self, i, a, conv, state,
+                      functools.partial(ssm.conv_step_in_place, **at),
+                      functools.partial(ssm.scan_step_in_place, **at))
 
     def recurrent_chunk(self, i, a, tail, state):
         """Mamba mixer ``i`` over a chunk of ONE sequence: ``a [c, d]``

@@ -325,18 +325,31 @@ def jamba_engine_args():
     return eng, {"decode": (slots, decode), "prefill": (chunk, prefill)}
 
 
+@pytest.fixture
+def kernels_for_the_chip(monkeypatch):
+    """``ops/ssm.py`` runs its kernel in the interpreter wherever the
+    process's default backend is no TPU — here, though what is lowered
+    is for the described chip. A test that compiles a hybrid DECODE
+    program takes this fixture, so that the program holds the kernel
+    the chip would run (steered in the test: the program has no option
+    for it)."""
+    from distributed_llm_code_samples_tpu.ops import ssm
+    monkeypatch.setattr(ssm, "_interpreted", lambda: False)
+
+
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
 def test_hybrid_step_program_keeps_the_state_as_stored(one_chip,
                                                        jamba_engine_args,
+                                                       kernels_for_the_chip,
                                                        kind):
     """The recurrent state beside the pool (``decode/paged.py::
     RecurrentState``, inner width minor) is to a step program what the
     pool is: taken as it is stored, row-major and all but unpadded,
     updated in place (aliased whole, with the pool), and never copied or
-    sliced out whole — a decode batch gathers its rows, a prefill chunk
-    slices its one. (The compiler may stage the store through its fast
-    memory, ``copy-start`` / ``copy-done``; that is no second copy in
-    HBM and is not counted.)"""
+    sliced out whole — a decode batch advances its rows where they lie
+    (PR 32), a prefill chunk slices its one. (The compiler may stage
+    the store through its fast memory, ``copy-start`` / ``copy-done``;
+    that is no second copy in HBM and is not counted.)"""
     eng, programs = jamba_engine_args
     bucket, args = programs[kind]
     compiled = eng._program(kind, bucket).lower(
@@ -356,11 +369,86 @@ def test_hybrid_step_program_keeps_the_state_as_stored(one_chip,
     held = (pool.k.nbytes + pool.v.nbytes + state.conv.nbytes
             + state.ssm.nbytes)
     assert m.alias_size_in_bytes >= held
-    # unpadded but for the convolution tail's 65 rows (8-row tiles: 72)
+    # unpadded: the tail's 65 rows lie under an axis of one, in one-row
+    # tiles (PR 32; flat ``[65, (K-1)*D]`` the chip padded them to 72 in
+    # its 8-row tiles, the allowance this line had: an eighth of the
+    # tail store. ``[65, K-1, D]`` it keeps tap-major and re-lays out
+    # around every update). What is left is the small operands' tiles
     logical = sum(x.nbytes for x in jax.tree_util.tree_leaves(args))
-    assert m.argument_size_in_bytes - logical < state.conv.nbytes // 8
+    assert m.argument_size_in_bytes - logical < state.conv.nbytes // 64
     assert _total_bytes(compiled) < HBM_V5E
 
+
+def test_hybrid_decode_program_updates_the_state_where_it_lies(
+        one_chip, jamba_engine_args, kernels_for_the_chip):
+    """What stands in a counter's place (the update has no miss path):
+    the hybrid cell's decode program holds TWO kernel calls a recurrent
+    layer (``ops/ssm.py::conv_step_in_place``, ``scan_step_in_place``),
+    each of which takes its store whole and gives it back; and there is
+    NO array of a batch's gathered state — nothing shaped ``f32[b, N,
+    D]``, ``f32[b, K-1, D]`` or ``f32[b, (K-1)*D]`` (the parent: a
+    gather, the recurrence and a scatter over such copies a layer) —
+    and no reshape, copy, pad or transpose of a tail-sized array (the
+    parent split the flat tail to ``[K-1, D]`` by all three)."""
+    import re
+    eng, programs = jamba_engine_args
+    bucket, args = programs["decode"]
+    hlo = eng._program("decode", bucket).lower(
+        *_shapes_of(args, one_chip)).compile().as_text()
+    state, spec = eng.state, eng.spec
+    calls = [l.split(" custom-call(")[0] for l in hlo.splitlines()
+             if MOSAIC in l]
+    assert len(calls) == 2 * spec.rec_layers
+    for store in (state.conv, state.ssm):   # a result of a call a layer
+        shape = "f32[%s]" % ",".join(map(str, store.shape))
+        assert sum(shape in l for l in calls) == spec.rec_layers, shape
+    n, d, k1 = spec.d_state, spec.d_inner, spec.d_conv - 1
+    gathered = re.compile(r"= \(?[^=]*\bf32\[%d,(%d,%d|%d,%d|%d)\]"
+                          % (bucket, n, d, k1, d, k1 * d))
+    assert not [l for l in hlo.splitlines() if gathered.search(l)]
+    # ... as an instruction of the program's own (inside a fusion a pad
+    # is how the compiler writes the shifted tail's concatenation: no
+    # array of its own)
+    relaid = [r for r in _entry_results(hlo)
+              if r[0] in ("reshape", "copy", "pad", "transpose")
+              and r[1] == 4 and r[2] >= bucket * k1 * d]
+    assert not relaid, relaid
+
+
+@pytest.mark.parametrize("b", [8, 64])
+@pytest.mark.parametrize("kernel", ["conv", "scan"])
+def test_state_kernels_compile_at_the_hybrid_cells_widths(
+        one_chip, kernels_for_the_chip, kernel, b):
+    """The in-place kernels alone, for the described v5e, at AI21-
+    Jamba2-3B's widths over the cell's 64 slots: Mosaic takes each at
+    the smallest and the largest decode bucket, the store is aliased
+    whole and enters row-major and unpadded as it is stored, and nothing
+    is held beside it."""
+    from distributed_llm_code_samples_tpu.decode.paged import init_state
+    from distributed_llm_code_samples_tpu.ops import ssm
+    n, d, k, layers = 16, 5120, 4, 26
+    state = jax.eval_shape(lambda: init_state(layers, 64, d, n, k))
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                            sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+    if kernel == "conv":
+        fn, store, at = ssm.conv_step_in_place, state.conv, 1
+        operands = (f32((b, d)), f32(store.shape), f32((k, d)), f32((d,)))
+    else:
+        fn, store, at = ssm.scan_step_in_place, state.ssm, 6
+        operands = (f32((b, d)), f32((b, d)), f32((n, d)), f32((b, n)),
+                    f32((b, n)), f32((d,)), f32(store.shape))
+    compiled = jax.jit(
+        functools.partial(fn, layer=layers - 1),
+        donate_argnums=(at,)).lower(*operands, rows=rows).compile()
+    assert MOSAIC in compiled.as_text()
+    m = compiled.memory_analysis()
+    nbytes = int(np.prod(store.shape)) * 4
+    assert m.alias_size_in_bytes == nbytes
+    assert m.argument_size_in_bytes - nbytes < 4 * 2 ** 20
+    assert m.temp_size_in_bytes < 2 ** 20
+    fmt = compiled.input_formats[0][at]
+    assert fmt.layout.major_to_minor == tuple(range(len(store.shape)))
 
 
 # the latent-attention, sparse-expert cell of the benchmark
@@ -539,8 +627,8 @@ def _entry_results(hlo: str):
 @pytest.mark.parametrize("fixture", ["gpt2_large_engine_args",
                                      "jamba_engine_args",
                                      "glm_engine_args"])
-def test_decode_program_reads_the_gathered_rows_as_stored(one_chip, request,
-                                                          fixture):
+def test_decode_program_reads_the_gathered_rows_as_stored(
+        one_chip, request, kernels_for_the_chip, fixture):
     """The decode program attends over each slot's gathered blocks in
     the form and dtype the pool stores them (``decode/paged.py::
     stored_decode_attn``): beyond the gather itself, no instruction of

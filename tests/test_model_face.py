@@ -250,7 +250,8 @@ def test_cache_spec_sizes_pool_and_state(family):
         assert eng.state is None and eng.recurrent == []
         return
     assert eng.recurrent == ["mamba"]
-    assert eng.state.conv.shape == (4, 3, (want.d_conv - 1) * want.d_inner)
+    assert eng.state.conv.shape == (4, 3, 1,
+                                    (want.d_conv - 1) * want.d_inner)
     assert eng.state.ssm.shape == (4, 3, want.d_state, want.d_inner)
 
 
