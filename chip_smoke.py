@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """The quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py            # one chip: serve, hybrid, latent + experts, train
+    python chip_smoke.py            # one chip: serve, hybrid, latent + experts, conv + experts, train
     python chip_smoke.py --chips 4  # four chips: the cross-chip paths only
 
 One process. It imports JAX once and drives the program through the
@@ -112,6 +112,28 @@ LATENT = dict(config=dict(
     prompt_lens="5,19,40,64,11", max_new=24, max_slots=3, tie=1e-3,
     phase="serve_latent_moe", reference="glm_moe_lm_reference",
     driver="glm_moe_engine_driver")
+
+# Gated short convolutions, grouped-query attention with QK-norm and
+# rotary, and sparse experts in small (``models/lfm2_moe_lm.py``): the
+# shape of the benchmark's lfm2-24b-a2b-serve at toy widths — all three
+# of the trunk's seams in one step program. d 128: the convolution runs
+# at the model's width, and its kernel takes whole 128-lane tiles on the
+# chip. The same proof, for the tails by slot with no scan state beside
+# paged K/V and counted experts.
+CONV_MOE = dict(config=dict(
+    model_type="lfm2_moe", hidden_size=128, intermediate_size=160,
+    moe_intermediate_size=48, num_attention_heads=4, num_key_value_heads=2,
+    num_experts=16, num_experts_per_tok=4, num_dense_layers=1,
+    num_hidden_layers=6,
+    layer_types=["conv", "full_attention", "conv", "conv", "full_attention",
+                 "conv"],
+    conv_L_cache=3, conv_bias=False, norm_eps=1e-5, norm_topk_prob=True,
+    use_expert_bias=True, routed_scaling_factor=1,
+    rope_parameters={"rope_theta": 1000000, "rope_type": "default"},
+    vocab_size=96, max_position_embeddings=256, initializer_range=0.2),
+    prompt_lens="5,19,40,64,11", max_new=24, max_slots=3, tie=1e-3,
+    phase="serve_conv_moe", reference="lfm2_moe_lm_reference",
+    driver="lfm2_moe_engine_driver")
 
 def require_tpu() -> dict:
     """The device as JAX reports it — or an error where it is no TPU."""
@@ -240,9 +262,10 @@ def first_difference(a: list, b: list):
 
 
 def phase_model_config(out_dir: str) -> None:
-    """Phase 3: the toy hybrid, then the toy latent-attention expert
-    model, each through ``generate --model_config``."""
-    for toy in (HYBRID, LATENT):
+    """Phase 3: the toy hybrid, the toy latent-attention expert model,
+    then the toy gated-convolution expert model, each through
+    ``generate --model_config``."""
+    for toy in (HYBRID, LATENT, CONV_MOE):
         serve_model_config(out_dir, toy)
 
 

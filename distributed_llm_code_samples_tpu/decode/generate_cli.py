@@ -61,9 +61,12 @@ def build_generate_parser() -> argparse.ArgumentParser:
     p.add_argument("--model_config", default=None, metavar="FILE",
                    help="a published-style config.json (model_type "
                         "jamba: Mamba and attention layers in one "
-                        "stack, models/hybrid_lm.py; or model_type "
+                        "stack, models/hybrid_lm.py; model_type "
                         "glm4_moe_lite: latent attention and sparse "
-                        "experts, models/mla_moe_lm.py). The model comes "
+                        "experts, models/mla_moe_lm.py; or model_type "
+                        "lfm2_moe: gated short convolutions, grouped-"
+                        "query attention and sparse experts, "
+                        "models/lfm2_moe_lm.py). The model comes "
                         "from its keys, in the type it states; "
                         "-d/-l/--heads/--kv_heads/--vocab/--max_seq_len "
                         "are then ignored, the weights come from -r or "

@@ -3,7 +3,8 @@
 ONE function builds the engine for ``train_ffns.py generate
 --model_config FILE`` and for the benchmark's driver file
 (``benchmark/configs/jamba_engine_driver.py``,
-``glm_moe_engine_driver.py``): the published keys say what the model is
+``glm_moe_engine_driver.py``, ``lfm2_moe_engine_driver.py``): the
+published keys say what the model is
 (``model_type`` picks the family's file under ``models/``, its
 ``spec_from_config`` reads the rest), the weights come from a seed or from the caller (a checkpoint restored into
 the seeded tree, the benchmark's own arrays), and every engine tunable
@@ -16,7 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..models import hybrid_lm, mla_moe_lm
+from ..models import hybrid_lm, lfm2_moe_lm, mla_moe_lm
 from .engine import DecodeEngine, EngineConfig
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -26,6 +27,8 @@ FAMILIES = {
     "jamba": (hybrid_lm.spec_from_config, hybrid_lm.init_hybrid_lm),
     "glm4_moe_lite": (mla_moe_lm.spec_from_config,
                       mla_moe_lm.init_mla_moe_lm),
+    "lfm2_moe": (lfm2_moe_lm.spec_from_config,
+                 lfm2_moe_lm.init_lfm2_moe_lm),
 }
 
 

@@ -138,7 +138,11 @@ class RecurrentState:
     Indexed by SLOT, not through a block table: ``conv [L_r, slots+1,
     1, (K-1)*D]`` the convolution's last ``K-1`` inputs, oldest first,
     and ``ssm [L_r, slots+1, N, D]`` the scan state (``ops/ssm.py``),
-    both float32, ``L_r`` the model's recurrent layers. The inner width
+    both float32, ``L_r`` the model's recurrent layers. A layer kind
+    that carries the convolution's tail and NO scan state (a gated
+    short convolution, ``models/lfm2_moe_lm.py``: ``d_state`` 0) has
+    ``ssm`` None: no leaf, no operand, no bytes — never an array of no
+    elements handed to a program or a kernel. The inner width
     is the minor axis of both, so the chip keeps them unpadded (a ``[D,
     N]`` state would pad ``N = 16`` up to 128 lanes). Row ``slots`` is
     the scratch row — the pool's idiom: padded bucket rows read and
@@ -158,33 +162,36 @@ class RecurrentState:
     tap-major, and every program re-lays the whole store out on the way
     in and on the way out (static, this compiler: PERF.md §6, PR 32)."""
     conv: jax.Array
-    ssm: jax.Array
+    ssm: jax.Array | None
 
     def _replace(self, **fields) -> "RecurrentState":
         return dataclasses.replace(self, **fields)
 
     @property
     def scratch_row(self) -> int:
-        return self.ssm.shape[1] - 1
+        return self.conv.shape[1] - 1
 
     @property
     def bytes_per_slot(self) -> int:
         """Bytes one sequence's state holds over all recurrent layers
         (what a decode dispatch reads, and writes, for each ready
         slot)."""
-        return int((self.conv.nbytes + self.ssm.nbytes)
-                   // self.ssm.shape[1])
+        held = self.conv.nbytes + (0 if self.ssm is None
+                                   else self.ssm.nbytes)
+        return int(held // self.conv.shape[1])
 
 
 def init_state(n_layers: int, slots: int, d_inner: int, d_state: int,
                d_conv: int) -> RecurrentState:
     """Zero-filled recurrent state for ``slots`` sequences (+ the
-    scratch row) over ``n_layers`` recurrent layers."""
+    scratch row) over ``n_layers`` recurrent layers; ``d_state`` 0 is a
+    layer kind with no scan state (``ssm`` None)."""
     rows = slots + 1
     return RecurrentState(
         conv=jnp.zeros((n_layers, rows, 1, (d_conv - 1) * d_inner),
                        jnp.float32),
-        ssm=jnp.zeros((n_layers, rows, d_state, d_inner), jnp.float32))
+        ssm=(jnp.zeros((n_layers, rows, d_state, d_inner), jnp.float32)
+             if d_state else None))
 
 
 def _heads_major(x, head_dim: int):

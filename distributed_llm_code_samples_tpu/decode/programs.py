@@ -298,7 +298,8 @@ class StepPrograms:
         """The prefill program up to the head: ``c`` prompt tokens of
         ONE slot enter the cache through its block table and attend
         causally over the gathered view; a recurrent layer scans the
-        chunk through the slot's state (``row``), which is zero at
+        chunk through the slot's state (``row``: the convolution's tail
+        and, where the model has one, the scan state), which is zero at
         position 0 whatever the row still holds (every prefill, and
         every replay, starts at 0). Returns ``(cache, x [c, d])``."""
         cfg = self.cfg
@@ -312,13 +313,15 @@ class StepPrograms:
         def mix(i, state, a):
             with jax.named_scope("ssm"):
                 fresh = pos0 == 0
-                tail = jnp.where(fresh, 0.0, state.conv[i, row])
-                y, tail, s = p.recurrent_chunk(
-                    i, a, tail.reshape(-1, state.ssm.shape[-1]),
-                    jnp.where(fresh, 0.0, state.ssm[i, row]))
+                tail = jnp.where(fresh, 0.0, state.conv[i, row]).reshape(
+                    -1, self.spec.d_inner)
+                # a layer kind with no scan state carries None for it
+                s = (None if state.ssm is None
+                     else jnp.where(fresh, 0.0, state.ssm[i, row]))
+                y, tail, s = p.recurrent_chunk(i, a, tail, s)
                 state = state._replace(
                     conv=state.conv.at[i, row].set(tail.reshape(1, -1)),
-                    ssm=state.ssm.at[i, row].set(s))
+                    ssm=None if s is None else state.ssm.at[i, row].set(s))
             return state, y
 
         return self._trunk(p, cache, x, positions, write_attn, mix)

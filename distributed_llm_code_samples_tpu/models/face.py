@@ -15,11 +15,14 @@ state a sequence owns, the residual adds, the expert layers' counters
 and, under a mesh, the collectives.
 
 What more than one family is written from lives below the face: ``mm``,
-``rmsnorm``, the gated SiLU MLP.
+``rmsnorm``, the gated SiLU MLP, the attention stack and its q/k/v
+(``qkv_heads``: QK-norm and the rotary base where the model has them);
+the expert layer's is ``ops/moe_serve.py``.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Protocol
 
 import jax
@@ -38,7 +41,9 @@ class CacheSpec(NamedTuple):
     layers own paged KV of ``kv_heads`` heads of ``head_dim`` lanes;
     ``rec_layers`` layers own a recurrent state of inner width
     ``d_inner``, state size ``d_state`` and ``d_conv`` convolution taps
-    (0 for a model with none). ``latent_rank`` > 0 says the ``kv_layers``
+    (0 for a model with none; ``d_state`` 0 with ``rec_layers`` > 0 is
+    a recurrent layer that carries its convolution's tail and NO scan
+    state). ``latent_rank`` > 0 says the ``kv_layers``
     are ``LATENT`` ones: a token's row is one vector of ``head_dim``
     lanes (``kv_heads`` 1) whose first ``latent_rank`` are also its
     values. Pool and state are built from this. Beside what is kept:
@@ -100,11 +105,12 @@ class ServedModel(Protocol):
     # one token of b rows, a [b, d]: the state is advanced where it is
     # stored (``decode/paged.py::RecurrentState``: conv [L_r, S, 1,
     # (K-1)*D], ssm [L_r, S, N, D], both WHOLE), rows [b] naming each
-    # row's slot -> (y, conv, ssm)
+    # row's slot -> (y, conv, ssm). Where the model's ``d_state`` is 0
+    # there is no scan state: ``ssm`` is None in and out
     def recurrent_step(self, i, a, conv, ssm, rows): ...
 
-    # a chunk of one row: a [c, d], tail [K-1, D], state [N, D]
-    # -> (y, tail, state)
+    # a chunk of one row: a [c, d], tail [K-1, D], state [N, D] (None
+    # where ``d_state`` is 0) -> (y, tail, state)
     def recurrent_chunk(self, i, a, tail, state): ...
 
     def ffn(self, l, h): ...
@@ -117,6 +123,18 @@ class ServedModel(Protocol):
     # final norm and head: [N, d] -> [N, V] (the local V/n columns
     # of a vocab-sharded embedding)
     def head(self, x): ...
+
+
+def layers_of(kinds: tuple) -> tuple:
+    """``(kind, index)`` per model layer of a stack whose kinds mix: the
+    index is the layer's place in its own kind's stack and in its
+    kind's cache."""
+    seen: dict = {}
+    out = []
+    for kind in kinds:
+        out.append((kind, seen.get(kind, 0)))
+        seen[kind] = seen.get(kind, 0) + 1
+    return tuple(out)
 
 
 def take(table: jax.Array, tokens: jax.Array) -> jax.Array:
@@ -153,18 +171,33 @@ def gated_mlp(mlp: MLPStack, l: int, h: jax.Array) -> jax.Array:
               mlp.w_down[l])
 
 
+class AttnStack(NamedTuple):
+    """The attention mixers, stacked ``[L_a, ...]`` (GQA by shape)."""
+    wq: jax.Array        # [L_a, H*dh, d]
+    wk: jax.Array        # [L_a, H_kv*dh, d]
+    wv: jax.Array        # [L_a, H_kv*dh, d]
+    wo: jax.Array        # [L_a, d, H*dh]
+
+
 def qkv_heads(wq, wk, wv, i: int, a, positions, head_dim: int,
-              use_rope: bool):
+              use_rope: bool, theta: float | None = None, qk_norm=None):
     """Attention layer ``i`` of stacks ``[L_a, out, d]``: ``a [N, d] ->
     q [N, h_loc, dh], k/v [N, kv_loc, dh]``, rotated by ``positions
-    [N]`` when asked; the local head counts come off the (possibly
-    head-sharded) weights' shapes."""
+    [N]`` when asked, at the base ``theta`` where the model states one
+    (``rope``'s own otherwise); the local head counts come off the
+    (possibly head-sharded) weights' shapes. ``qk_norm = (g_q [dh], g_k
+    [dh], eps)`` norms every head of ``q`` and of ``k`` over its own
+    lanes (gain-only RMSNorm, float32) between the projection and the
+    rotation."""
     q = mm(a, wq[i]).reshape(-1, wq.shape[1] // head_dim, head_dim)
     k = mm(a, wk[i]).reshape(-1, wk.shape[1] // head_dim, head_dim)
     v = mm(a, wv[i]).reshape(-1, wv.shape[1] // head_dim, head_dim)
+    if qk_norm is not None:
+        g_q, g_k, eps = qk_norm
+        q, k = rmsnorm(g_q, q, eps), rmsnorm(g_k, k, eps)
     if use_rope:
-        rot = jax.vmap(lambda x, pos: rope(x[:, None, :],
-                                           pos[None])[:, 0, :])
+        at = rope if theta is None else functools.partial(rope, base=theta)
+        rot = jax.vmap(lambda x, pos: at(x[:, None, :], pos[None])[:, 0, :])
         q = rot(q, positions)
         k = rot(k, positions)
     return q, k, v

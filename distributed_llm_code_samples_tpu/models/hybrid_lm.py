@@ -38,8 +38,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import ssm
-from .face import (ATTN, CacheSpec, MLPStack, gated_mlp, mm, qkv_heads,
-                   rmsnorm)
+from .face import (ATTN, AttnStack, CacheSpec, MLPStack, gated_mlp,
+                   layers_of, mm, qkv_heads, rmsnorm)
 
 MAMBA = "mamba"
 
@@ -61,14 +61,6 @@ class MambaStack(NamedTuple):
     a_log: jax.Array     # [L_m, N, D]
     d: jax.Array         # [L_m, D]
     w_out: jax.Array     # [L_m, d, D]
-
-
-class AttnStack(NamedTuple):
-    """The attention mixers, stacked ``[L_a, ...]`` (GQA by shape)."""
-    wq: jax.Array        # [L_a, H*dh, d]
-    wk: jax.Array        # [L_a, H_kv*dh, d]
-    wv: jax.Array        # [L_a, H_kv*dh, d]
-    wo: jax.Array        # [L_a, d, H*dh]
 
 
 @functools.partial(
@@ -111,12 +103,7 @@ class HybridLMParams:
     def layers(self) -> tuple:
         """``(kind, index)`` per model layer: the index is the layer's
         place in its own kind's stack and in its kind's cache."""
-        seen = {ATTN: 0, MAMBA: 0}
-        out = []
-        for kind in self.kinds:
-            out.append((kind, seen[kind]))
-            seen[kind] += 1
-        return tuple(out)
+        return layers_of(self.kinds)
 
     def num_params(self) -> int:
         """Parameters, the tied embedding counted once."""

@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import moe_serve
+from ..ops.moe_serve import ExpertStack, holder  # noqa: F401  (the family's names)
 from .attention import rope
 from .face import LATENT, CacheSpec, MLPStack, gated_mlp, mm, rmsnorm
 
@@ -84,16 +85,6 @@ class MLAStack(NamedTuple):
     w_uk: jax.Array      # [L, H, dn, R]
     w_uv: jax.Array      # [L, H, dv, R]
     w_o: jax.Array       # [L, d, H*dv]
-
-
-class ExpertStack(NamedTuple):
-    """The expert layers' routed part, stacked ``[L_e, ...]``: the
-    router over all ``E`` experts (float32) and the ``E_held`` held."""
-    w_router: jax.Array  # [L_e, E, d] float32
-    bias: jax.Array      # [L_e, E]    float32, used for the choice only
-    w_gate: jax.Array    # [L_e, E_held, F, d]
-    w_up: jax.Array      # [L_e, E_held, F, d]
-    w_down: jax.Array    # [L_e, E_held, d, F]
 
 
 @functools.partial(
@@ -200,12 +191,10 @@ class MlaMoeLMParams:
         first_dense = self.dense.w_gate.shape[0]
         if l < first_dense:
             return gated_mlp(self.dense, l, h), None
-        e, x = self.experts, l - first_dense
+        x = l - first_dense
         with jax.named_scope("moe"):
-            idx, w = moe_serve.route(h, e.w_router[x], e.bias[x],
-                                     self.top_k, self.routed_scale)
-            y, rows = moe_serve.held_part(h, idx, w, e.w_gate[x], e.w_up[x],
-                                          e.w_down[x], self.expert_first)
+            y, rows = moe_serve.routed(self.experts, x, h, self.top_k,
+                                       self.routed_scale, self.expert_first)
             return y + gated_mlp(self.shared, x, h), rows
 
     def ffn(self, l, h):
@@ -344,14 +333,3 @@ def init_mla_moe_lm(key: jax.Array, spec: MlaMoeSpec, dtype=jnp.float32,
             w_down=w(le, s.n_routed, d, f)),
         top_k=s.top_k, routed_scale=s.routed_scale,
         rope_theta=s.rope_theta, eps=s.eps, max_seq_len=s.max_seq_len)
-
-
-def holder(p: MlaMoeLMParams, first: int, count: int) -> MlaMoeLMParams:
-    """The same model holding experts ``[first, first + count)`` of
-    every expert layer: what one of ``n_routed / count`` chips that
-    share the layers would be given (the router stays whole)."""
-    lo = first - p.expert_first
-    e = p.experts
-    cut = e._replace(**{k: getattr(e, k)[:, lo:lo + count]
-                        for k in ("w_gate", "w_up", "w_down")})
-    return dataclasses.replace(p, experts=cut, expert_first=first)

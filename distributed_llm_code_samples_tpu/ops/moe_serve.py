@@ -16,7 +16,14 @@ range is the chip's share), so the layer here is in two parts:
   ``W_down (silu(W_gate a) * W_up a)``, and the rows each of them
   received. A choice that falls outside the range adds nothing here: it
   is another holder's. The parts of all holders add up to the layer
-  (``tests/test_mla_moe_lm.py``); a shared expert is the caller's, once.
+  (``tests/test_mla_moe_lm.py``, ``tests/test_lfm2_moe_lm.py``); a shared
+  expert is the caller's, once.
+
+What every served expert family keeps of the layer is here too, once:
+the stack of an expert layer's weights (``ExpertStack``), the routed
+part of layer ``x`` over it (``routed``) and the cut of a model to one
+holder's range (``holder``). ``models/mla_moe_lm.py`` adds a shared
+expert beside the routed part; ``models/lfm2_moe_lm.py`` has none.
 
 ONE formulation, chosen on the chip (``PERF.md`` section 6 has both
 readings): every held expert runs over every row and the gates, zero
@@ -29,10 +36,23 @@ bound by those bytes, and the MXU has the room.
 
 from __future__ import annotations
 
+import dataclasses
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
 HI = jax.lax.Precision.HIGHEST
+
+
+class ExpertStack(NamedTuple):
+    """The expert layers' routed part, stacked ``[L_e, ...]``: the
+    router over all ``E`` experts (float32) and the ``E_held`` held."""
+    w_router: jax.Array  # [L_e, E, d] float32
+    bias: jax.Array      # [L_e, E]    float32, used for the choice only
+    w_gate: jax.Array    # [L_e, E_held, F, d]
+    w_up: jax.Array      # [L_e, E_held, F, d]
+    w_down: jax.Array    # [L_e, E_held, d, F]
 
 
 def route(a: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
@@ -70,3 +90,25 @@ def held_part(a: jax.Array, idx: jax.Array, w: jax.Array,
     y = jnp.einsum("nef,edf->nd", act.astype(w_down.dtype), w_down,
                    preferred_element_type=jnp.float32)
     return y, rows
+
+
+def routed(e: ExpertStack, x: int, h: jax.Array, top_k: int, scale: float,
+           first: int = 0):
+    """Expert layer ``x`` of the stack over ``h [N, d]``: ``route`` over
+    all experts, then the held experts' part -> ``(y [N, d], rows
+    [E_held])``, ``first`` the global id of the first held expert."""
+    idx, w = route(h, e.w_router[x], e.bias[x], top_k, scale)
+    return held_part(h, idx, w, e.w_gate[x], e.w_up[x], e.w_down[x], first)
+
+
+def holder(p, first: int, count: int):
+    """The same model holding experts ``[first, first + count)`` of
+    every expert layer: what one of ``E / count`` chips that share the
+    layers would be given (the router stays whole). ``p`` is a family's
+    params: a dataclass with an ``experts: ExpertStack`` and the global
+    id of its first held expert, ``expert_first``."""
+    lo = first - p.expert_first
+    e = p.experts
+    cut = e._replace(**{k: getattr(e, k)[:, lo:lo + count]
+                        for k in ("w_gate", "w_up", "w_down")})
+    return dataclasses.replace(p, experts=cut, expert_first=first)

@@ -1,4 +1,8 @@
-"""The selective scan of a Mamba-1 mixer, in the forms serving needs.
+"""The selective scan of a Mamba-1 mixer, in the forms serving needs;
+its depthwise causal convolution alone is also the core of a gated
+short-convolution layer (``models/lfm2_moe_lm.py``: ``K = 3``, no bias,
+no scan state), which runs the same ``conv_chunk`` and
+``conv_step_in_place``.
 
 A recurrent layer carries, per sequence, a state that does not grow with
 the sequence: the scan state ``s [N, D]`` and the last ``K-1`` inputs of
@@ -50,27 +54,28 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def conv_chunk(x: jax.Array, tail: jax.Array, w: jax.Array,
-               bias: jax.Array):
+               bias: jax.Array | None):
     """Depthwise causal convolution over a chunk: ``x [c, D]`` the new
     inputs, ``tail [K-1, D]`` the inputs just before them (zeros at the
     start of a sequence), ``w [K, D]`` with tap ``K-1`` on the current
-    token, ``bias [D]``. Returns ``(y [c, D], new tail [K-1, D])``.
-    The taps are summed oldest first, whatever the chunk size."""
+    token, ``bias [D]`` (None for a convolution without one). Returns
+    ``(y [c, D], new tail [K-1, D])``. The taps are summed oldest
+    first, whatever the chunk size."""
     k = w.shape[0]
     c = x.shape[0]
     full = jnp.concatenate([tail, x], axis=0)           # [K-1+c, D]
-    y = bias + sum(w[j] * full[j:j + c] for j in range(k))
-    return y, full[c:]
+    y = sum(w[j] * full[j:j + c] for j in range(k))
+    return (y if bias is None else bias + y), full[c:]
 
 
 def conv_step(x: jax.Array, tail: jax.Array, w: jax.Array,
-              bias: jax.Array):
+              bias: jax.Array | None):
     """``conv_chunk`` for one token of each of ``b`` sequences:
     ``x [b, D]``, ``tail [b, K-1, D]``."""
     k = w.shape[0]
     full = jnp.concatenate([tail, x[:, None, :]], axis=1)  # [b, K, D]
-    y = bias + sum(w[j] * full[:, j] for j in range(k))
-    return y, full[:, 1:]
+    y = sum(w[j] * full[:, j] for j in range(k))
+    return (y if bias is None else bias + y), full[:, 1:]
 
 
 def scan_chunk(x, dt, a, b, c, d, s0):
@@ -131,17 +136,19 @@ def _tile(d: int, rows: int) -> int:
                                   or 2 * rows * t * 4 <= _VMEM_BUDGET))
 
 
-def _conv_kernel(rows_ref, x_ref, w_ref, bias_ref, tail_ref, y_ref,
-                 new_ref):
+def _conv_kernel(rows_ref, x_ref, w_ref, *refs):
     del rows_ref                    # it placed the blocks; nothing more
+    # ``refs``: the bias where the convolution has one, then the tail
+    # in, ``y`` out and the tail out
+    *bias_ref, tail_ref, y_ref, new_ref = refs
     d = x_ref.shape[-1]
     k1 = tail_ref.shape[-1] // d
     # ``conv_step``'s sum, term for term: the taps oldest first, each
     # ``D`` whole lanes of the row as stored
     taps = [tail_ref[:, j * d:(j + 1) * d] for j in range(k1)]
     taps.append(x_ref[...])                             # [1, D]
-    y_ref[...] = bias_ref[...] + sum(
-        w_ref[j:j + 1, :] * tap for j, tap in enumerate(taps))
+    y = sum(w_ref[j:j + 1, :] * tap for j, tap in enumerate(taps))
+    y_ref[...] = bias_ref[0][...] + y if bias_ref else y
     for j in range(k1):
         new_ref[:, j * d:(j + 1) * d] = taps[j + 1]
 
@@ -151,8 +158,9 @@ def conv_step_in_place(x, store, w, bias, *, layer: int, rows):
     [b]`` of layer ``layer`` of ``store [L, S, 1, (K-1)*D]``
     (``RecurrentState.conv``: a row's taps oldest first, end to end)
     are read once and written once, in place. ``x [b, D]``, ``w [K,
-    D]``, ``bias [D]`` as ``conv_step``'s; returns ``(y [b, D],
-    store)``, every other row of the store with the bits it had.
+    D]``, ``bias [D]`` (or None: the kernel then takes no operand in
+    its place) as ``conv_step``'s; returns ``(y [b, D], store)``, every
+    other row of the store with the bits it had.
 
     The same kernel form as ``scan_step_in_place`` below: the store
     aliased in to out, ``rows`` scalar-prefetched, grid step ``r``
@@ -168,21 +176,24 @@ def conv_step_in_place(x, store, w, bias, *, layer: int, rows):
     row = pl.BlockSpec((None, 1, d), lambda r, rows: (r, 0, 0))
     tail = pl.BlockSpec((None, None, 1, store.shape[-1]),
                         lambda r, rows: (layer, rows[r], 0, 0))
+    x = x[:, None, :]
+    small = [(w, pl.BlockSpec(w.shape, lambda r, rows: (0, 0)))]
+    if bias is not None:
+        small.append((bias[None, :],
+                      pl.BlockSpec((1, d), lambda r, rows: (0, 0))))
     y, store = pl.pallas_call(
         _conv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(n_b,),
-            in_specs=[row,
-                      pl.BlockSpec(w.shape, lambda r, rows: (0, 0)),
-                      pl.BlockSpec((1, d), lambda r, rows: (0, 0)),
-                      tail],
+            in_specs=[row, *(spec for _, spec in small), tail],
             out_specs=[row, tail]),
         out_shape=[jax.ShapeDtypeStruct((n_b, 1, d), jnp.float32),
                    jax.ShapeDtypeStruct(store.shape, store.dtype)],
-        # operands count from the prefetched ``rows``: the store is 4th
-        input_output_aliases={4: 1},
+        # operands count from the prefetched ``rows``: the store is
+        # last, after ``x`` and the small ones
+        input_output_aliases={2 + len(small): 1},
         interpret=interpret,
-    )(rows, x[:, None, :], w, bias[None, :], store)
+    )(rows, x, *(a for a, _ in small), store)
     return y[:, 0], store
 
 

@@ -465,15 +465,15 @@ class _ShapesEngine:
     programs, the params and the pool as ``jax.eval_shape`` gives
     them."""
 
-    def __init__(self, programs, params, pool):
+    def __init__(self, programs, params, pool, state=None):
         self.programs, self.cfg = programs, programs.cfg
-        self.params, self.pool = params, pool
+        self.params, self.pool, self.state = params, pool, state
 
     def _program(self, kind, bucket):
         return self.programs.build(kind, bucket)
 
     def _cache(self):
-        return self.pool
+        return self.pool if self.state is None else (self.pool, self.state)
 
 
 @pytest.fixture(scope="module")
@@ -547,6 +547,102 @@ def test_latent_step_program_keeps_the_pool_as_stored(one_chip,
     assert compiled.output_shardings is not None
     out = jax.eval_shape(eng.programs.body(kind, bucket), *args)[1]
     assert out.shape == (picks + 2 * 64,) and out.dtype == jnp.int32
+
+
+# the gated-convolution, sparse-expert cell of the benchmark
+# (lfm2-24b-a2b.reasoning-offline): LFM2-24B-A2B's widths, all 64 experts,
+# 32 heads over 8 KV heads and the whole vocabulary, 64 slots x 2048
+# positions, bf16 weights and K/V, float32 tails, cut to 4 layers (the
+# dense convolution layer, an attention and two convolution layers with
+# experts) so the compile stays short
+LFM2 = dict(layers=4, slots=64, mbps=128, chunk=16)
+
+
+@pytest.fixture(scope="module")
+def lfm2_engine_args():
+    """``(engine, {kind: (bucket, args)})`` at the cell's widths, over
+    shapes alone: the configuration's own file through the family's
+    ``spec_from_config``, the step programs as ``DecodeEngine`` builds
+    them."""
+    import json
+    from distributed_llm_code_samples_tpu.decode import EngineConfig
+    from distributed_llm_code_samples_tpu.decode.programs import StepPrograms
+    from distributed_llm_code_samples_tpu.models import lfm2_moe_lm
+    g = LFM2
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-24b-a2b-serve.json")) as f:
+        config = json.load(f)
+    config = dict(config, num_hidden_layers=g["layers"],
+                  layer_types=config["layer_types"][:g["layers"]])
+    spec = lfm2_moe_lm.spec_from_config(config)
+    params = jax.eval_shape(
+        lambda k: lfm2_moe_lm.init_lfm2_moe_lm(k, spec, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    slots, mbps, chunk = g["slots"], g["mbps"], g["chunk"]
+    programs = StepPrograms(
+        EngineConfig(n_blocks=1 + slots * mbps, max_slots=slots,
+                     max_blocks_per_seq=mbps, prefill_chunk=chunk,
+                     kv_dtype="bf16"),
+        params.cache_spec(spec.n_heads), params.vocab)
+    eng = _ShapesEngine(programs, params,
+                        *jax.eval_shape(lambda: programs.init_cache()))
+    decode, prefill = _step_args(eng, slots, chunk)
+    return eng, {"decode": (slots, decode), "prefill": (chunk, prefill)}
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_conv_moe_step_program_keeps_pool_and_state_as_stored(
+        one_chip, lfm2_engine_args, kernels_for_the_chip, kind):
+    """All three seams in ONE step program (K/V blocks of 8 KV heads x
+    64 lanes, the convolutions' tails by slot with no scan state, the
+    experts' counters after the picks): pool and tails are taken
+    row-major and unpadded, aliased whole and updated in place, never
+    copied and no layer's slab sliced out; the state is ONE leaf (no
+    zero-sized scan store is handed in); the decode program holds ONE
+    kernel call a convolution layer (``ops/ssm.py::conv_step_in_place``
+    at ``K = 3``, ``D = 2048``, no bias operand) and no array of a
+    batch's gathered tails."""
+    import re
+    eng, programs = lfm2_engine_args
+    bucket, args = programs[kind]
+    compiled = eng._program(kind, bucket).lower(
+        *_shapes_of(args, one_chip)).compile()
+    hlo = compiled.as_text()
+    pool, state = eng.pool, eng.state
+    assert pool.k.shape == (1, 8193, 16, 512) == pool.v.shape
+    assert state.ssm is None and state.conv.shape == (3, 65, 1, 4096)
+    assert len(jax.tree_util.tree_leaves(state)) == 1
+    slab = pool.k.size // pool.k.shape[0]
+    moved = [r for r in _hlo_results(
+        hlo, ("copy", "slice", "dynamic-slice"), "bf16")
+        if r[1] >= slab and r[1] % slab == 0]
+    moved += [r for r in _hlo_results(
+        hlo, ("copy", "slice", "dynamic-slice"), "f32")
+        if r[1] >= state.conv.size]
+    assert not moved, moved
+    cache_formats = compiled.input_formats[0][1]
+    for fmt, arr in ((cache_formats[0].k, pool.k), (cache_formats[0].v, pool.v),
+                     (cache_formats[1].conv, state.conv)):
+        assert fmt.layout.major_to_minor == tuple(range(arr.ndim)), fmt
+    m = compiled.memory_analysis()
+    held = _nbytes(pool.k) + _nbytes(pool.v) + _nbytes(state.conv)
+    assert m.alias_size_in_bytes >= held
+    logical = sum(_nbytes(x) for x in jax.tree_util.tree_leaves(args[:2]))
+    assert m.argument_size_in_bytes - logical < _nbytes(pool.k) // 100
+    assert _total_bytes(compiled) < HBM_V5E
+    picks = bucket if kind == "decode" else 1
+    out = jax.eval_shape(eng.programs.body(kind, bucket), *args)[1]
+    assert out.shape == (picks + 3 * 64,) and out.dtype == jnp.int32
+    calls = [l for l in hlo.splitlines() if MOSAIC in l]
+    if kind == "prefill":
+        assert not calls            # the chunk's convolution is plain ops
+        return
+    assert len(calls) == eng.programs.spec.rec_layers == 3
+    assert all("f32[3,65,1,4096]" in l.split(" custom-call(")[0]
+               for l in calls)
+    gathered = re.compile(r"= \(?[^=]*\bf32\[%d,(2,2048|4096)\]" % bucket)
+    assert not [l for l in hlo.splitlines() if gathered.search(l)]
 
 
 def _toy_engine(family, ways, speculate, hybrid_config):
@@ -626,7 +722,8 @@ def _entry_results(hlo: str):
 
 @pytest.mark.parametrize("fixture", ["gpt2_large_engine_args",
                                      "jamba_engine_args",
-                                     "glm_engine_args"])
+                                     "glm_engine_args",
+                                     "lfm2_engine_args"])
 def test_decode_program_reads_the_gathered_rows_as_stored(
         one_chip, request, kernels_for_the_chip, fixture):
     """The decode program attends over each slot's gathered blocks in
@@ -645,7 +742,9 @@ def test_decode_program_reads_the_gathered_rows_as_stored(
     MB on the parent, 0.8 MB now; one layer's attention alone 151 MB
     against 0.) The latent cell's rows are read the same way: one
     gather of 640-lane rows a layer, both products over them as stored,
-    no slice of the view for the values' 512 lanes.)"""
+    no slice of the view for the values' 512 lanes. And the gated
+    convolution cell's: 8 KV heads x 64 lanes with four query heads a
+    group, a 512-lane row of whole tiles.)"""
     eng, programs = request.getfixturevalue(fixture)
     bucket, args = programs["decode"]
     compiled = eng._program("decode", bucket).lower(
@@ -673,6 +772,8 @@ REHEARSED = {
     "jamba2-3b.reasoning-offline": ("shrink_jamba", "state_bytes_live"),
     "glm47-flash.reasoning-offline": ("shrink_glm",
                                       "expert_rows_max_over_mean"),
+    "lfm2-24b-a2b.reasoning-offline": ("shrink_lfm2",
+                                       "routed_rows_max_over_mean"),
 }
 
 
@@ -682,8 +783,8 @@ def test_cell_rehearsal_on_the_cpu(monkeypatch, name, trace):
     """A newer cell's whole control flow on the CPU at toy size, as
     ``benchmark/tests/test_rehearsal.py`` rehearses the older cells
     (its ``shrink.py`` knows those only; these cells' shrinks are
-    ``benchmark/tests/shrink_jamba.py`` and ``shrink_glm.py``). Nothing
-    here is a measurement."""
+    ``benchmark/tests/shrink_jamba.py``, ``shrink_glm.py`` and
+    ``shrink_lfm2.py``). Nothing here is a measurement."""
     import importlib
     import json
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

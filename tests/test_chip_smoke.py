@@ -72,8 +72,9 @@ def _assert_result_line(rec: dict, count: int) -> None:
 
 def test_default_phases_at_toy_size(tmp_path):
     """serve (twice, same tokens, nothing compiled the second time,
-    report rc 0) -> the toy hybrid, then the toy latent-attention expert
-    model, each against its plain reference -> train
+    report rc 0) -> the toy hybrid, the toy latent-attention expert
+    model, then the toy gated-convolution expert model, each against its
+    plain reference -> train
     at both shapes; every stdout line is one JSON object and the last
     is the fixed one."""
     r = _child("sys.exit(cs.main([]))", 1, tmp_path)
@@ -81,15 +82,15 @@ def test_default_phases_at_toy_size(tmp_path):
     recs = _records(r.stdout)
     assert [x["phase"] for x in recs[:-1]] == [
         "serve_gather", "serve_gather_again", "serve_report",
-        "serve_hybrid", "serve_latent_moe", "train_single_0",
-        "train_single_1", "total"]
+        "serve_hybrid", "serve_latent_moe", "serve_conv_moe",
+        "train_single_0", "train_single_1", "total"]
     _assert_result_line(recs[-1], 1)
     again = recs[1]
     assert again["cache_misses"] == 0 and again["tokens"] == 4 * 6
-    for served in recs[3:5]:            # the hybrid, the latent + experts
+    for served in recs[3:6]:    # the hybrid, latent + experts, conv + experts
         assert served["tokens"] == 5 * 24
         assert served["tokens_compared"] > 100
-    assert all(m > 0 for x in recs[5:7] for m in x["mfu"])
+    assert all(m > 0 for x in recs[6:8] for m in x["mfu"])
     assert "devices: platform=cpu" in r.stderr     # each entry says where
 
 

@@ -24,7 +24,10 @@ from distributed_llm_code_samples_tpu.ops import ssm
 TOL = 2e-4
 LAYERS, SLOTS, N, D = 3, 6, 4, 128
 
-# case -> (the batch's rows, d_conv); row SLOTS is the scratch row
+# case -> (the batch's rows, d_conv); row SLOTS is the scratch row. A
+# case named ``no-bias`` runs the convolution without one (None in the
+# bias's place: the gated short convolution of ``models/lfm2_moe_lm.py``,
+# ``K = 3``)
 CASES = {
     "permuted-rows": ([4, 1, 5, 0, 3, 2], 4),
     "bucket-smaller-than-the-slots": ([3, 0], 4),
@@ -33,10 +36,13 @@ CASES = {
     "one-row": ([1], 4),
     "two-taps-permuted": ([5, 2, 0, 1], 2),
     "two-taps-padded": ([4, SLOTS, SLOTS, SLOTS], 2),
+    "three-taps-no-bias-permuted": ([5, 2, 0, 1, 4, 3], 3),
+    "three-taps-no-bias-padded": ([4, 1, SLOTS, SLOTS], 3),
+    "three-taps-no-bias-one-row": ([2], 3),
 }
 
 
-def _operands(rows, k, seed=0):
+def _operands(rows, k, seed=0, bias=True):
     ks = iter(jax.random.split(jax.random.PRNGKey(seed), 12))
 
     def normal(*shape):
@@ -48,7 +54,7 @@ def _operands(rows, k, seed=0):
                           ssm=normal(*zero.ssm.shape))
     return dict(
         state=state, rows=jnp.asarray(rows, jnp.int32), x=normal(b, D),
-        w=normal(k, D), bias=normal(D),
+        w=normal(k, D), bias=normal(D) if bias else None,
         dt=jax.nn.softplus(normal(b, D)), a=-jnp.exp(normal(N, D)),
         b=normal(b, N), c=normal(b, N), d=normal(D))
 
@@ -64,7 +70,7 @@ def _untouched(store, rows, layer):
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_the_tail_in_place_is_conv_step_on_gathered_copies(case, layer):
     rows, k = CASES[case]
-    o = _operands(rows, k)
+    o = _operands(rows, k, bias="no-bias" not in case)
     store = o["state"].conv
     assert store.shape == (LAYERS, SLOTS + 1, 1, (k - 1) * D)
     want_y, want_tail = ssm.conv_step(
@@ -102,6 +108,27 @@ def test_the_scan_state_in_place_is_scan_step_on_gathered_copies(case,
                              - store[layer, o["rows"][real]])).max() > 0.1
     assert np.array_equal(_untouched(new, rows, layer),
                           _untouched(store, rows, layer))
+
+
+@pytest.mark.parametrize("k,bias", [(4, True), (3, False), (2, True)])
+def test_the_chunk_is_the_step_token_by_token(k, bias):
+    """``conv_chunk`` over 9 tokens of one sequence is ``conv_step``
+    nine times on a batch of that one row — output and the tail carried
+    on — with a bias and without (``K = 3``: the short convolution's)."""
+    o = _operands([0], k, seed=3, bias=bias)
+    x = jax.random.normal(jax.random.PRNGKey(4), (9, D), jnp.float32)
+    tail = jax.random.normal(jax.random.PRNGKey(5), (k - 1, D), jnp.float32)
+    y, new = ssm.conv_chunk(x, tail, o["w"], o["bias"])
+    step_tail, ys = tail[None], []
+    for t in range(x.shape[0]):
+        y_t, step_tail = ssm.conv_step(x[t:t + 1], step_tail, o["w"],
+                                       o["bias"])
+        ys.append(y_t[0])
+    np.testing.assert_allclose(y, jnp.stack(ys), atol=TOL, rtol=0)
+    np.testing.assert_allclose(new, step_tail[0], atol=TOL, rtol=0)
+    if not bias:        # a zero input then reads zero: nothing is added
+        zero, _ = ssm.conv_chunk(0 * x, 0 * tail, o["w"], None)
+        assert not np.asarray(zero).any()
 
 
 @pytest.mark.parametrize("k", [4, 2])
