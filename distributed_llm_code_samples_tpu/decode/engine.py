@@ -13,12 +13,17 @@ wrappers):
 - **Continuous batching**: a host scheduler admits queued prompts into
   freed slots *between* compiled steps. The compiled surface is a small
   static set — one decode program per power-of-two slot bucket, one
-  prefill program per power-of-two chunk bucket — so steady-state steps
-  are dispatch-only and the compile count is bounded by the bucket
-  count (the ``--log_every`` chunk discipline, recompile-guard-tested).
+  prefill program per power-of-two chunk bucket, one mixed program —
+  so steady-state steps are dispatch-only and the compile count is
+  bounded by the bucket count (the ``--log_every`` chunk discipline,
+  recompile-guard-tested).
 - **Chunked prefill**: long prompts enter in bounded chunks, so a new
   long prompt costs one chunk per engine step instead of stalling every
-  running decode behind a full-prompt pass.
+  running decode behind a full-prompt pass. A chunk of the full size
+  RIDES with the step's decode batch in one ``mixed`` program
+  (``_mixed_batch`` says when), so the weights are read once for both
+  kinds of row; a prompt's tail chunks run in a program of their own
+  before the batch's.
 - **Fused sampling** (``decode/sampling.py``): temperature / top-k /
   top-p picked inside the compiled step, keyed on
   ``(engine seed, sequence uid, position)`` — continuous-batching
@@ -681,6 +686,9 @@ class DecodeEngine:
         # as a dispatch count: N sharers run ~1 prefill pass over the
         # shared prefix, not N); snapshot-persisted
         self.prefill_dispatches = 0
+        # ... of which the chunk rode with the step's decode batch in
+        # ONE ``mixed`` program (``_mixed_dispatch``)
+        self.mixed_dispatches = 0
         # tokens emitted inside the CURRENT span per uid (decode/replay
         # segments emit many tokens per step under speculation; the
         # span record carries the count so a waterfall shows work, not
@@ -744,9 +752,9 @@ class DecodeEngine:
 
     def _program(self, kind: str, bucket: int):
         """The engine's cache of built programs. ``kind``: decode /
-        prefill / verify (bucketed), cow / cow_rows / implant (one each,
-        built on first use: steady state never builds them, so the
-        recompile-guard tests hold with the write barrier armed)."""
+        prefill / verify / mixed (bucketed), cow / cow_rows / implant (one
+        each, built on first use: steady state never builds them, so
+        the recompile-guard tests hold with the write barrier armed)."""
         key = (kind, bucket)
         fn = self._programs.get(key)
         if fn is None:
@@ -757,15 +765,19 @@ class DecodeEngine:
 
     def warm(self) -> int:
         """Prebuild the engine's full program set — every decode (and
-        verify, when speculating) slot bucket, every prefill chunk
-        bucket, and the implant program — so a freshly spawned engine
-        pays its compiles BEFORE it takes traffic (the autoscaler's
-        warm-before-traffic contract; also the worker protocol's
-        ``warm`` op). Idempotent; returns ``compile_count``."""
+        verify, when speculating) slot bucket, the one mixed program
+        (when not), every prefill chunk bucket, and the implant program
+        — so a freshly
+        spawned engine pays its compiles BEFORE it takes traffic (the
+        autoscaler's warm-before-traffic contract; also the worker
+        protocol's ``warm`` op). Idempotent; returns
+        ``compile_count``."""
         for b in self.slot_buckets:
             self._program("decode", b)
             if self.cfg.speculate:
                 self._program("verify", b)
+        if not self.cfg.speculate:
+            self._program("mixed", self.slot_buckets[-1])
         for c in self.chunk_buckets:
             self._program("prefill", c)
         self._program("implant", 0)
@@ -2193,28 +2205,37 @@ class DecodeEngine:
         phase = self.phases.phase
         with phase("prefill.cow"):
             c = self._prefill_chunk(seq)
-            bs = self.cfg.block_size
-            self._cow_private(slot, seq.prefilled // bs,
-                              (seq.prefilled + c - 1) // bs)
+            self._cow_chunk(slot, seq, c)
         with phase("prefill.upload"):
             self.prefill_dispatches += 1
             fn = self._program("prefill", c)
-            fields = dict(
-                table=self.tables[slot], pos0=seq.prefilled,
-                tokens=seq.prompt[seq.prefilled:seq.prefilled + c],
-                uid=seq.uid, poison=self._poison_uid)
-            if self.state is not None:
-                fields["row"] = slot            # the slot's state row
-            operand = self.programs.pack("prefill", c, **fields)
+            operand = self.programs.pack(
+                "prefill", c, poison=self._poison_uid,
+                **self._chunk_fields(slot, seq, c, "tokens"))
         result = self._dispatch(
             "prefill", fn, self._params_for(seq.weights_version), operand)
         with phase("prefill.book"):
-            nxt = int(result[0])
-            fine = nxt >= 0
-            # the pick is used only where the chunk completes the prompt
-            pick = (nxt if fine and seq.prefilled + c
-                    == len(seq.prompt) else None)
-            self._prefill_book(slot, seq, c, fine, pick)
+            self._prefill_book(slot, seq, c, int(result[0]))
+
+    def _cow_chunk(self, slot: int, seq: _Seq, c: int) -> None:
+        """The CoW write barrier over the blocks ``seq``'s next chunk
+        of ``c`` tokens writes."""
+        bs = self.cfg.block_size
+        self._cow_private(slot, seq.prefilled // bs,
+                          (seq.prefilled + c - 1) // bs)
+
+    def _chunk_fields(self, slot: int, seq: _Seq, c: int,
+                      tokens: str) -> dict:
+        """One slot's chunk in a step program's operand: its table,
+        start, the next ``c`` prompt tokens (under the name the program
+        gives them), uid and, where the model has recurrent layers, the
+        slot's state row."""
+        fields = {"table": self.tables[slot], "pos0": seq.prefilled,
+                  tokens: seq.prompt[seq.prefilled:seq.prefilled + c],
+                  "uid": seq.uid}
+        if self.state is not None:
+            fields["row"] = slot
+        return fields
 
     def _prefill_chunk(self, seq: _Seq) -> int:
         """The next chunk's size for ``seq``."""
@@ -2235,9 +2256,14 @@ class DecodeEngine:
             c = max(b for b in self.chunk_buckets if b <= min(c, gap))
         return c
 
-    def _prefill_book(self, slot: int, seq: _Seq, c: int, fine: bool,
-                      pick: int | None) -> None:
-        """Fold a dispatched chunk's result into the slot."""
+    def _prefill_book(self, slot: int, seq: _Seq, c: int,
+                      nxt: int) -> None:
+        """Fold a dispatched chunk's result (its last row's folded
+        pick) into the slot."""
+        fine = nxt >= 0
+        # the pick is used only where the chunk completes the prompt
+        pick = (nxt if fine and seq.prefilled + c == len(seq.prompt)
+                else None)
         self._step_prefill_uid = seq.uid
         self._step_finite = [fine]
         if not fine:
@@ -2271,12 +2297,15 @@ class DecodeEngine:
             self.tracer.transition(seq.uid, "prefill", self.global_step,
                                    tokens=c)
 
-    def _marshal(self, ready: list[int]):
-        """Bucket-pad the dispatch operands for ``ready``: pad rows
-        point at the scratch block with zeroed length/token/uid, so
-        their writes land in the pad row's designated dump and their
-        idle uid never matches a poison operand."""
-        b = _bucket_for(len(ready), self.slot_buckets)
+    def _marshal(self, ready: list[int], b: int | None = None):
+        """Bucket-pad the dispatch operands for ``ready`` (to ``b``
+        rows where given, else to the smallest slot bucket that holds
+        them): pad rows point at the scratch block with zeroed
+        length/token/uid, so their writes land in the pad row's
+        designated dump and their idle uid never matches a poison
+        operand."""
+        if b is None:
+            b = _bucket_for(len(ready), self.slot_buckets)
         idx = ready + [0] * (b - len(ready))        # pad rows
         tables = self.tables[idx].copy()
         lengths = self.lengths[idx].copy()
@@ -2304,41 +2333,118 @@ class DecodeEngine:
                               []).append(slot)
         return [groups[v] for v in sorted(groups)]
 
+    def _cow_batch(self, ready: list[int]) -> None:
+        """The CoW write barrier over the block each ready slot's next
+        token lands in."""
+        bs = self.cfg.block_size
+        for slot in ready:
+            self._cow_private(slot, int(self.lengths[slot]) // bs,
+                              int(self.lengths[slot]) // bs)
+
+    def _batch_fields(self, ready: list[int], b: int, tables, lengths,
+                      tokens, uids) -> dict:
+        """A marshalled decode batch in a step program's operand, with
+        the poison; counts the state bytes its rows read."""
+        fields = dict(tables=tables, lengths=lengths, tokens=tokens,
+                      uids=uids, poison=self._poison_uid)
+        if self.state is not None:
+            # each batch row's state row: its slot, and the scratch
+            # row for the bucket's padded rows
+            fields["rows"] = ready + [self.state.scratch_row] * (
+                b - len(ready))
+            self._step_state_bytes += (len(ready)
+                                       * self.state.bytes_per_slot)
+        return fields
+
+    def _emit_batch(self, ready: list[int], picks) -> None:
+        """Fold a decode batch's folded picks into its slots."""
+        self._step_decode_uids += [self.slots[s].uid for s in ready]
+        flags = (picks[:len(ready)] >= 0).tolist()
+        self._step_finite = (flags if self._step_finite is None
+                             else self._step_finite + flags)
+        for j, slot in enumerate(ready):
+            if not flags[j]:     # pad rows are never in `ready`
+                self._quarantine(slot, "nonfinite_logits")
+                continue
+            self.lengths[slot] += 1
+            self._emit(slot, int(picks[j]))
+
     def _decode_dispatch(self, ready: list[int]) -> None:
         phase = self.phases.phase
-        bs = self.cfg.block_size
         with phase("decode.cow"):
-            for slot in ready:              # the CoW write barrier
-                self._cow_private(slot, int(self.lengths[slot]) // bs,
-                                  int(self.lengths[slot]) // bs)
+            self._cow_batch(ready)
         with phase("decode.marshal"):
             params = self._params_for(
                 self.slots[ready[0]].weights_version)
-            b, tables, lengths, tokens, uids = self._marshal(ready)
+            b, *batch = self._marshal(ready)
             fn = self._program("decode", b)
         with phase("decode.upload"):
-            fields = dict(tables=tables, lengths=lengths, tokens=tokens,
-                          uids=uids, poison=self._poison_uid)
-            if self.state is not None:
-                # each batch row's state row: its slot, and the scratch
-                # row for the bucket's padded rows
-                fields["rows"] = ready + [self.state.scratch_row] * (
-                    b - len(ready))
-                self._step_state_bytes += (len(ready)
-                                           * self.state.bytes_per_slot)
-            operand = self.programs.pack("decode", b, **fields)
+            operand = self.programs.pack(
+                "decode", b, **self._batch_fields(ready, b, *batch))
         picks = self._dispatch("decode", fn, params, operand)
         with phase("decode.emit"):
-            self._step_decode_uids += [self.slots[s].uid for s in ready]
-            flags = (picks[:len(ready)] >= 0).tolist()
-            self._step_finite = (flags if self._step_finite is None
-                                 else self._step_finite + flags)
-            for j, slot in enumerate(ready):
-                if not flags[j]:     # pad rows are never in `ready`
-                    self._quarantine(slot, "nonfinite_logits")
-                    continue
-                self.lengths[slot] += 1
-                self._emit(slot, int(picks[j]))
+            self._emit_batch(ready, picks)
+
+    # -- the chunk rides with the batch ---------------------------------
+
+    def _mixed_batch(self, pre: int | None,
+                     prefill_only: bool) -> list[int]:
+        """The ready slots this step's prefill chunk rides with in ONE
+        ``mixed`` program, or none where the step runs as today (a
+        ``prefill`` program, then ``decode``): decided by what the step
+        can see. It rides when the chunk is of the FULL size (a
+        prompt's tails and a chunk cut by a mid-block prefix hit take
+        the old path), there is a ready slot, every ready slot and the
+        chunk's are on one weights version (a program runs one params
+        operand), and the engine neither speculates nor serves the
+        prefill tier. ONE program serves them all — the batch padded to
+        the LARGEST slot bucket, not a program a bucket and not a
+        bucket x chunk grid: a step program costs seconds of every
+        start-up (its layers are traced, fetched and loaded one by
+        one), and padded rows cost a ride little (they read no weights;
+        their cache reads are what the full batch's are)."""
+        if pre is None or prefill_only or self.cfg.speculate:
+            return []
+        seq = self.slots[pre]
+        if self._prefill_chunk(seq) != self.cfg.prefill_chunk:
+            return []
+        ready = [i for i, s in enumerate(self.slots)
+                 if s is not None and s.prompt_done]
+        if any(self.slots[i].weights_version != seq.weights_version
+               for i in ready):
+            return []
+        return ready
+
+    def _mixed_dispatch(self, slot: int, ready: list[int]) -> None:
+        """One dispatch for the step's chunk (``slot``) and its decode
+        batch (``ready``): the host halves of ``_prefill_step`` and
+        ``_decode_dispatch`` under their own phase names round ONE
+        ``mixed.upload`` / ``.dispatch`` / ``.readback``. Counted as one
+        dispatch that carried a chunk. A prompt the chunk completes
+        emits its first token here and joins the batch in the next
+        step."""
+        seq = self.slots[slot]
+        phase = self.phases.phase
+        c = self.cfg.prefill_chunk
+        with phase("prefill.cow"):
+            self._cow_chunk(slot, seq, c)
+        with phase("decode.cow"):
+            self._cow_batch(ready)
+        with phase("decode.marshal"):
+            b, *batch = self._marshal(ready, self.slot_buckets[-1])
+            fn = self._program("mixed", b)
+        with phase("mixed.upload"):
+            self.prefill_dispatches += 1
+            self.mixed_dispatches += 1
+            operand = self.programs.pack(
+                "mixed", b, **self._batch_fields(ready, b, *batch),
+                **self._chunk_fields(slot, seq, c, "chunk"))
+        picks = self._dispatch(
+            "mixed", fn, self._params_for(seq.weights_version), operand)
+        with phase("prefill.book"):
+            self._prefill_book(slot, seq, c, int(picks[-1]))
+        with phase("decode.emit"):
+            self._emit_batch(ready, picks)
 
     # -- speculative decoding (DESIGN.md section 18) -------------------
 
@@ -2437,7 +2543,9 @@ class DecodeEngine:
         """One scheduler iteration: expire deadlines, admit (with
         pool-pressure preemption when armed), at most ONE prefill chunk
         (so a long prompt never stalls running decodes for more than a
-        chunk), then one decode dispatch over every ready slot. Returns
+        chunk) and one decode dispatch over every ready slot — as ONE
+        mixed dispatch where ``_mixed_batch`` finds the chunk can ride
+        with the batch, else the chunk's and then the batch's. Returns
         whether any work ran. An armed chaos poison operand applies to
         exactly this step's dispatches.
 
@@ -2487,12 +2595,15 @@ class DecodeEngine:
             self._admit()
             pre = next((i for i, s in enumerate(self.slots)
                         if s is not None and not s.prompt_done), None)
-        did = False
-        if pre is not None:
-            self._prefill_step(pre)
-            did = True
         with phase("decode.marshal"):
-            ready = ([] if prefill_only else
+            riders = self._mixed_batch(pre, prefill_only)
+        did = pre is not None
+        if riders:
+            self._mixed_dispatch(pre, riders)
+        elif pre is not None:
+            self._prefill_step(pre)
+        with phase("decode.marshal"):
+            ready = ([] if prefill_only or riders else
                      [i for i, s in enumerate(self.slots)
                       if s is not None and s.prompt_done])
             groups = self._version_groups(ready)
@@ -2703,6 +2814,8 @@ class DecodeEngine:
                                         else
                                         self.prefix.evictable_blocks()),
             "prefill_dispatches": self.prefill_dispatches,
+            # extra: ... of which the chunk rode with the decode batch
+            "mixed_dispatches": self.mixed_dispatches,
             # v17 KV-memory-hierarchy keys (pinned): demotion volume
             # (cumulative blocks + wire bytes), promotion wins
             # (restores, the prompt tokens they kept off the prefill
@@ -2742,6 +2855,8 @@ class DecodeEngine:
             "events": list(self._step_events),
             "prefill_uid": self._step_prefill_uid,
             "decode_uids": list(self._step_decode_uids),
+            # cumulative: steps whose chunk rode with the decode batch
+            "mixed_dispatches": self.mixed_dispatches,
             "finite": self._step_finite,
             "slots": [None if s is None else
                       {"uid": s.uid, "pos": int(self.lengths[i]),

@@ -11,7 +11,9 @@ the ONE read of each side
 (``stored_decode_attn`` for decode and verify rows,
 ``gathered_chunk_attn`` for a prefill chunk). Between them, written
 once: the walk over the model's layers (``_trunk``, so prefill and
-decode numerics cannot drift), head -> poison -> pick -> finite flags
+decode numerics cannot drift; in the ``mixed`` body a decode batch and
+ONE slot's full chunk are rows of one walk, the weights read once, and
+only the cache seams split by row kind), head -> poison -> pick -> finite flags
 (``_head_pick``), and the wrapping (``shard_map`` under a model-axis
 mesh, ``jit``, the donated cache). The builder gets ``cfg``, the
 model's ``CacheSpec``, the vocabulary and the mesh as plain values: it
@@ -142,7 +144,10 @@ class StepPrograms:
         the poison and, where the model has recurrent layers, each
         row's state row. Verify: decode's plus the drafts and their
         lengths. Prefill: ONE slot's table, start position, ``bucket``
-        tokens, uid, the poison and its state row likewise."""
+        tokens, uid, the poison and its state row likewise. Mixed:
+        decode's for the ``bucket``-row batch plus ONE slot's table,
+        start position, full chunk (``cfg.prefill_chunk`` tokens), uid
+        and state row."""
         w = self._wires.get((kind, bucket))
         if w is None:
             t, rec = self.cfg.max_blocks_per_seq, bool(self.spec.rec_layers)
@@ -160,6 +165,11 @@ class StepPrograms:
                 if kind == "verify":
                     fields["drafts"] = (bucket, self.cfg.speculate)
                     fields["dlens"] = (bucket,)
+                elif kind == "mixed":
+                    fields.update(table=(t,), pos0=(),
+                                  chunk=(self.cfg.prefill_chunk,), uid=(1,))
+                    if rec:
+                        fields["row"] = ()
             w = self._wires[kind, bucket] = Wire(fields)
         return w
 
@@ -169,7 +179,8 @@ class StepPrograms:
     def split(self, kind: str, result: np.ndarray):
         """A dispatch's result on the host: ``(picks, expert_rows)`` —
         the picks in the shape the kind gives them (``[b]``, ``[1]``, a
-        verify's ``[b, k+2]``) and the expert layers' counters
+        verify's ``[b, k+2]``, a mixed step's ``[b + 1]``: the batch's
+        and then the chunk's last row's) and the expert layers' counters
         ``[expert_layers, n_experts]``, None for a model with none."""
         n = self.spec.expert_layers * self.spec.n_experts
         if not n:
@@ -267,15 +278,13 @@ class StepPrograms:
             logits = all_gather(logits, MODEL_AXIS, dim=1)
         return logits
 
-    def decode_hidden(self, b: int, p, cache, tables, lengths, tokens,
-                      rows=None):
-        """The decode program up to the head: each of ``b`` rows' token
+    def _batch_seams(self, b: int, p, tables, lengths, rows):
+        """``(write_attn, mix)`` of ``b`` decode rows: each row's token
         written at its own position and attended over its blocks as
-        stored; a recurrent layer advances each row's own state
-        (``rows [b]``: the slot's state row, the scratch row for a
-        padded one). Returns ``(cache, x [b, d], counts)``."""
+        stored; a recurrent layer advances each row's own state where
+        it lies (``rows [b]``: the slot's state row, the scratch row
+        for a padded one)."""
         cfg = self.cfg
-        x = self._embed(p, tokens, lengths)             # [b, d]
         slot_phys = lengths // cfg.block_size
         off = lengths % cfg.block_size
 
@@ -291,20 +300,17 @@ class StepPrograms:
                                                 state.ssm, rows)
             return RecurrentState(conv, ssm), y
 
-        return self._trunk(p, cache, x, lengths, write_attn, mix)
+        return write_attn, mix
 
-    def prefill_hidden(self, c: int, p, cache, table, pos0, tokens,
-                       row=None):
-        """The prefill program up to the head: ``c`` prompt tokens of
-        ONE slot enter the cache through its block table and attend
+    def _chunk_seams(self, p, table, pos0, row):
+        """``(write_attn, mix)`` of ONE slot's chunk of prompt tokens:
+        they enter the cache through its block table and attend
         causally over the gathered view; a recurrent layer scans the
         chunk through the slot's state (``row``: the convolution's tail
         and, where the model has one, the scan state), which is zero at
         position 0 whatever the row still holds (every prefill, and
-        every replay, starts at 0). Returns ``(cache, x [c, d])``."""
+        every replay, starts at 0)."""
         cfg = self.cfg
-        positions = pos0 + jnp.arange(c)
-        x = self._embed(p, tokens, positions)           # [c, d]
 
         def write_attn(l, pool, q, k, v):
             pool = write_chunk(pool, l, table, pos0, k, v, cfg.kv_dtype)
@@ -312,22 +318,89 @@ class StepPrograms:
 
         def mix(i, state, a):
             with jax.named_scope("ssm"):
-                fresh = pos0 == 0
-                tail = jnp.where(fresh, 0.0, state.conv[i, row]).reshape(
-                    -1, self.spec.d_inner)
-                # a layer kind with no scan state carries None for it
-                s = (None if state.ssm is None
-                     else jnp.where(fresh, 0.0, state.ssm[i, row]))
-                y, tail, s = p.recurrent_chunk(i, a, tail, s)
-                state = state._replace(
-                    conv=state.conv.at[i, row].set(tail.reshape(1, -1)),
-                    ssm=None if s is None else state.ssm.at[i, row].set(s))
+                y, tail, s = p.recurrent_chunk(
+                    i, a, *self._slot_state(state, i, row, pos0))
+                state = self._keep_slot_state(state, i, row, tail, s)
+            return state, y
+
+        return write_attn, mix
+
+    def _slot_state(self, state, i, row, pos0):
+        """``(tail [K-1, D], s [N, D])`` of recurrent layer ``i`` for
+        the sequence in state row ``row`` whose chunk starts at
+        ``pos0``: zeros at position 0, whatever the row still holds. A
+        layer kind with no scan state carries None for it."""
+        fresh = pos0 == 0
+        tail = jnp.where(fresh, 0.0, state.conv[i, row]).reshape(
+            -1, self.spec.d_inner)
+        s = (None if state.ssm is None
+             else jnp.where(fresh, 0.0, state.ssm[i, row]))
+        return tail, s
+
+    @staticmethod
+    def _keep_slot_state(state, i, row, tail, s):
+        """``state`` with the sequence's row of layer ``i`` written."""
+        return state._replace(
+            conv=state.conv.at[i, row].set(tail.reshape(1, -1)),
+            ssm=None if s is None else state.ssm.at[i, row].set(s))
+
+    def decode_hidden(self, b: int, p, cache, tables, lengths, tokens,
+                      rows=None):
+        """The decode program up to the head (``_batch_seams``).
+        Returns ``(cache, x [b, d], counts)``."""
+        x = self._embed(p, tokens, lengths)             # [b, d]
+        return self._trunk(p, cache, x, lengths,
+                           *self._batch_seams(b, p, tables, lengths, rows))
+
+    def prefill_hidden(self, c: int, p, cache, table, pos0, tokens,
+                       row=None):
+        """The prefill program up to the head (``_chunk_seams``).
+        Returns ``(cache, x [c, d], counts)``."""
+        positions = pos0 + jnp.arange(c)
+        x = self._embed(p, tokens, positions)           # [c, d]
+        return self._trunk(p, cache, x, positions,
+                           *self._chunk_seams(p, table, pos0, row))
+
+    def mixed_hidden(self, b: int, p, cache, f: dict):
+        """The mixed program up to the head: the batch's ``b`` rows and
+        then ONE slot's full chunk are rows of one walk, so whatever
+        reads weights (embedding, norms, projections, FFN) runs once
+        over all ``b + c`` of them; only the seams split by row kind —
+        the cache write and read here, each half what its own program
+        runs; the state update inside the model's ``recurrent_mixed``,
+        between the mixer's weight products — and the chunk's slot is
+        never among the batch's rows, so neither half reads what the
+        other writes. ``f``: the unpacked operand. Returns ``(cache, x
+        [b + c, d], counts)``."""
+        lengths, pos0 = f["lengths"], f["pos0"]
+        rows, row = f.get("rows"), f.get("row")
+        positions = jnp.concatenate(
+            [lengths, pos0 + jnp.arange(self.cfg.prefill_chunk)])
+        x = self._embed(p, jnp.concatenate([f["tokens"], f["chunk"]]),
+                        positions)
+        batch_attn, _ = self._batch_seams(b, p, f["tables"], lengths, rows)
+        chunk_attn, _ = self._chunk_seams(p, f["table"], pos0, row)
+
+        def write_attn(l, pool, q, k, v):
+            pool, yb = batch_attn(l, pool, q[:b], k[:b], v[:b])
+            pool, yc = chunk_attn(l, pool, q[b:], k[b:], v[b:])
+            return pool, jnp.concatenate([yb, yc])
+
+        def mix(i, state, a):
+            # the mixer's own weights are read once too: the model
+            # takes both kinds of row in one call
+            with jax.named_scope("ssm"):
+                y, conv, ssm, tail, s = p.recurrent_mixed(
+                    i, a, state.conv, state.ssm, rows,
+                    *self._slot_state(state, i, row, pos0))
+                state = self._keep_slot_state(
+                    RecurrentState(conv, ssm), i, row, tail, s)
             return state, y
 
         return self._trunk(p, cache, x, positions, write_attn, mix)
 
     def _head_pick(self, p, x, uids, poison, pos, ahead: int):
-        """head -> poison -> pick -> finite flags, for all three
+        """head -> poison -> pick -> finite flags, for all four
         bodies: the logits of ``x [n, d]``, NaN'd where the chaos
         operand names the row's uid (or is ``POISON_ALL``; a false
         ``where`` leaves a row bit-identical), the in-graph pick keyed
@@ -342,7 +415,7 @@ class StepPrograms:
             picks = self.pick(logits, uids, pos + ahead)
         return picks, rows_finite(logits)
 
-    # -- the three bodies --------------------------------------------------
+    # -- the four bodies ---------------------------------------------------
 
     def _decode_fn(self, b: int):
         """A ``b``-slot bucket's decode step: ``result [b]``."""
@@ -434,13 +507,34 @@ class StepPrograms:
 
         return run
 
+    def _mixed_fn(self, b: int):
+        """A ``b``-slot bucket's decode step with ONE slot's full
+        prefill chunk riding in it: ``result [b + 1]``, the batch's
+        picks and then the chunk's last row's (which the host uses only
+        when the chunk completes the prompt)."""
+        wire = self.wire("mixed", b)
+        c = self.cfg.prefill_chunk
+
+        @jax.named_scope("decode")
+        def run(p, cache, operand):
+            f = wire.unpack(operand)
+            cache, x, counts = self.mixed_hidden(b, p, cache, f)
+            return cache, _with_counts(_fold(*self._head_pick(
+                p, jnp.concatenate([x[:b], x[-1:]]),
+                jnp.concatenate([f["uids"], f["uid"]]), f["poison"],
+                jnp.concatenate([f["lengths"], f["pos0"][None] + c - 1]),
+                1)), counts)
+
+        return run
+
     # -- wrapping -----------------------------------------------------------
 
     def body(self, kind: str, bucket: int):
         """The callable ``build`` jits; under a mesh shard_mapped, the
         operand and the result replicated."""
         run = {"decode": self._decode_fn, "prefill": self._prefill_fn,
-               "verify": self._verify_fn}[kind](bucket)
+               "verify": self._verify_fn,
+               "mixed": self._mixed_fn}[kind](bucket)
         if self.mesh is None:
             return run
         return jax.shard_map(

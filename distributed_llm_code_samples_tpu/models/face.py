@@ -66,7 +66,7 @@ class ServedModel(Protocol):
     """The face. ``l`` is a model layer, ``i`` the index a layer has in
     its own kind's weights and cache (``layers`` gives both), ``a`` the
     normed residual stream ``[N, d]``. A family without recurrent
-    layers is never asked for the two ``recurrent_`` methods, one
+    layers is never asked for the three ``recurrent_`` methods, one
     without ``LATENT`` layers never for the two ``latent_`` ones, one
     whose ``cache_spec`` names no expert layer never for
     ``ffn_counted``."""
@@ -112,6 +112,12 @@ class ServedModel(Protocol):
     # a chunk of one row: a [c, d], tail [K-1, D], state [N, D] (None
     # where ``d_state`` is 0) -> (y, tail, state)
     def recurrent_chunk(self, i, a, tail, state): ...
+
+    # a decode batch's b rows and then ONE sequence's chunk, a [b + c,
+    # d], with the mixer's weights read once: the first ``len(rows)``
+    # rows as ``recurrent_step``, the rest as ``recurrent_chunk`` from
+    # ``tail, state`` -> (y, conv, ssm, tail, state)
+    def recurrent_mixed(self, i, a, conv, ssm, rows, tail, state): ...
 
     def ffn(self, l, h): ...
 

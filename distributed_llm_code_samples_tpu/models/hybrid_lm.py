@@ -152,6 +152,19 @@ class HybridLMParams:
         return _mamba(self, i, a, tail, state, ssm.conv_chunk,
                       ssm.scan_chunk)
 
+    def recurrent_mixed(self, i, a, conv, state, rows, tail, s):
+        """Mamba mixer ``i`` over a decode batch's ``len(rows)`` rows
+        and then ONE sequence's chunk, ``a [b + c, d]``, its weights
+        read once: the first rows advance the stores as
+        ``recurrent_step`` does, the rest scan from ``tail, s`` as
+        ``recurrent_chunk`` does."""
+        at = dict(layer=i, rows=rows)
+        y, (conv, tail), (state, s) = _mamba(
+            self, i, a, (conv, tail), (state, s),
+            functools.partial(ssm.conv_mixed, **at),
+            functools.partial(ssm.scan_mixed, **at))
+        return y, conv, state, tail, s
+
     def ffn(self, l, h):
         return gated_mlp(self.mlp, l, h)
 

@@ -188,6 +188,15 @@ class Lfm2MoeLMParams:
         y, tail = _gated_conv(self, i, a, tail, ssm.conv_chunk)
         return y, tail, state
 
+    def recurrent_mixed(self, i, a, conv, state, rows, tail, s):
+        """Convolution mixer ``i`` over a decode batch's ``len(rows)``
+        rows and then ONE sequence's chunk, ``a [b + c, d]``, its
+        weights read once (``state`` and ``s`` None as they came)."""
+        y, (conv, tail) = _gated_conv(
+            self, i, a, (conv, tail), functools.partial(
+                ssm.conv_mixed, layer=i, rows=rows))
+        return y, conv, state, tail, s
+
     def ffn_counted(self, l, h):
         first_dense = self.dense.w_gate.shape[0]
         if l < first_dense:

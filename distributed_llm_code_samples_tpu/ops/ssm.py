@@ -34,6 +34,11 @@ forms its callers need:
   on either side. The bytes follow the batch, not the slots. On the
   chip the parent's gather, recurrence and scatter over copies took
   10.2 ms a program of 26 layers and 64 rows, these 2.7 (PERF.md §6).
+- ``conv_mixed`` / ``scan_mixed``: a decode batch's ``b`` rows and then
+  ONE sequence's chunk as rows of one array — the mixed program
+  (PR 36), where a mixer's weight products run once over both kinds of
+  row: the first ``b`` rows go through the ``*_step_in_place`` kernel,
+  the rest through the ``*_chunk`` form.
 - ``conv_step`` / ``scan_step``: the same token for ``b`` sequences on
   GATHERED copies of their rows — the oracle of the tests and of the
   plain references, on no program's path.
@@ -257,3 +262,30 @@ def scan_step_in_place(x, dt, a, b, c, d, store, *, layer: int, rows):
     )(rows, x[:, None, :], dt[:, None, :], jnp.stack([b, c], axis=-1), a,
       d[None, :], store)
     return y[:, 0], store
+
+
+def conv_mixed(x, carried, w, bias, *, layer: int, rows):
+    """The convolution over a decode batch's rows and then ONE
+    sequence's chunk: ``x [b + c, D]``, ``carried = (store, tail)`` the
+    WHOLE store (of which rows ``rows [b]`` advance in place) and the
+    chunk's sequence's tail. Returns ``(y [b + c, D], (store, tail))``."""
+    b = rows.shape[0]
+    store, tail = carried
+    yb, store = conv_step_in_place(x[:b], store, w, bias, layer=layer,
+                                   rows=rows)
+    yc, tail = conv_chunk(x[b:], tail, w, bias)
+    return jnp.concatenate([yb, yc]), (store, tail)
+
+
+def scan_mixed(x, dt, a, b, c, d, carried, *, layer: int, rows):
+    """The recurrence likewise: the first ``len(rows)`` rows of ``x, dt
+    [n, D]`` and ``b, c [n, N]`` one token each of as many sequences, on
+    the store where it lies; the rest a chunk of ONE sequence from its
+    state. ``carried = (store, s)``; returns ``(y [n, D], (store,
+    s))``."""
+    n = rows.shape[0]
+    store, s = carried
+    yb, store = scan_step_in_place(x[:n], dt[:n], a, b[:n], c[:n], d, store,
+                                   layer=layer, rows=rows)
+    yc, s = scan_chunk(x[n:], dt[n:], a, b[n:], c[n:], d, s)
+    return jnp.concatenate([yb, yc]), (store, s)

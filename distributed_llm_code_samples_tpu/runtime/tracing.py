@@ -190,16 +190,20 @@ class SpanTracer:
 #
 # The engine's vocabulary (``decode/engine.py::step``; ``engine:step``
 # is the parent of the rest, ``prefill.*`` / ``decode.*`` repeat per
-# dispatch, the speculative verify path takes the ``decode.*`` names):
+# dispatch, the speculative verify path takes the ``decode.*`` names; a
+# step whose chunk rides with its decode batch runs both dispatches'
+# host phases under their own names round ONE ``mixed.*`` launch and
+# wait):
 #
 #   host    expire  admit  prefill.cow  prefill.book  decode.marshal
 #           decode.cow  decode.emit  digest
 #   launch  prefill.upload  prefill.dispatch  decode.upload
-#           decode.dispatch
-#   wait    prefill.readback  decode.readback
+#           decode.dispatch  mixed.upload  mixed.dispatch
+#   wait    prefill.readback  decode.readback  mixed.readback
 #
 # ``host`` neither feeds nor waits for the device, ``launch`` hands it
-# operands and a program, ``wait`` blocks on its results. The trainer's
+# operands and a program, ``wait`` blocks on its results (the classes
+# are told by the suffix: ``benchmark/engine_phases.py::phase_class``). The trainer's
 # sites are annotations only: ``train:clone`` / ``train:run``
 # (``parallel/single.py``), ``launch:build`` / ``launch:run``
 # (``parallel/launcher.py``).

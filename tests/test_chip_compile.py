@@ -156,6 +156,16 @@ def _step_args(eng, slots, chunk):
                  for kind, bucket in (("decode", slots), ("prefill", chunk)))
 
 
+def _cell_programs(eng, slots, chunk):
+    """``{kind: (bucket, args)}`` of a serving cell's three step
+    programs at its full batch: the decode batch, a full prefill chunk,
+    and the mixed program in which that chunk rides with the batch."""
+    decode, prefill = _step_args(eng, slots, chunk)
+    mixed = (eng.params, eng._cache(), _operand(eng, "mixed", slots))
+    return {"decode": (slots, decode), "prefill": (chunk, prefill),
+            "mixed": (slots, mixed)}
+
+
 @pytest.fixture(scope="module")
 def gpt2_engine_args():
     """``kv_dtype -> (engine, decode args, prefill args)`` for the engine
@@ -239,8 +249,7 @@ def gpt2_large_engine_args():
     eng = DecodeEngine(params, g["heads"], EngineConfig(
         n_blocks=1 + slots * mbps, max_slots=slots,
         max_blocks_per_seq=mbps, prefill_chunk=chunk, kv_dtype="bf16"))
-    decode, prefill = _step_args(eng, slots, chunk)
-    return eng, {"decode": (slots, decode), "prefill": (chunk, prefill)}
+    return eng, _cell_programs(eng, slots, chunk)
 
 
 def _hlo_results(hlo: str, ops: tuple[str, ...], dtype: str):
@@ -255,7 +264,7 @@ def _hlo_results(hlo: str, ops: tuple[str, ...], dtype: str):
             for m in pat.finditer(hlo)]
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill"])
+@pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
 def test_step_program_keeps_the_pool_as_stored(one_chip,
                                                gpt2_large_engine_args, kind):
     """The stored form is the form the chip keeps (``decode/paged.py``):
@@ -321,8 +330,7 @@ def jamba_engine_args():
     eng = engine_from_config(config, seed=7, engine_config=EngineConfig(
         n_blocks=1 + slots * mbps, max_slots=slots,
         max_blocks_per_seq=mbps, prefill_chunk=chunk, kv_dtype="bf16"))
-    decode, prefill = _step_args(eng, slots, chunk)
-    return eng, {"decode": (slots, decode), "prefill": (chunk, prefill)}
+    return eng, _cell_programs(eng, slots, chunk)
 
 
 @pytest.fixture
@@ -337,7 +345,7 @@ def kernels_for_the_chip(monkeypatch):
     monkeypatch.setattr(ssm, "_interpreted", lambda: False)
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill"])
+@pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
 def test_hybrid_step_program_keeps_the_state_as_stored(one_chip,
                                                        jamba_engine_args,
                                                        kernels_for_the_chip,
@@ -379,8 +387,9 @@ def test_hybrid_step_program_keeps_the_state_as_stored(one_chip,
     assert _total_bytes(compiled) < HBM_V5E
 
 
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
 def test_hybrid_decode_program_updates_the_state_where_it_lies(
-        one_chip, jamba_engine_args, kernels_for_the_chip):
+        one_chip, jamba_engine_args, kernels_for_the_chip, kind):
     """What stands in a counter's place (the update has no miss path):
     the hybrid cell's decode program holds TWO kernel calls a recurrent
     layer (``ops/ssm.py::conv_step_in_place``, ``scan_step_in_place``),
@@ -392,8 +401,8 @@ def test_hybrid_decode_program_updates_the_state_where_it_lies(
     parent split the flat tail to ``[K-1, D]`` by all three)."""
     import re
     eng, programs = jamba_engine_args
-    bucket, args = programs["decode"]
-    hlo = eng._program("decode", bucket).lower(
+    bucket, args = programs[kind]
+    hlo = eng._program(kind, bucket).lower(
         *_shapes_of(args, one_chip)).compile().as_text()
     state, spec = eng.state, eng.spec
     calls = [l.split(" custom-call(")[0] for l in hlo.splitlines()
@@ -503,15 +512,14 @@ def glm_engine_args():
         params.cache_spec(spec.n_heads), params.vocab)
     eng = _ShapesEngine(programs, params,
                         jax.eval_shape(lambda: programs.init_cache()[0]))
-    decode, prefill = _step_args(eng, slots, chunk)
-    return eng, {"decode": (slots, decode), "prefill": (chunk, prefill)}
+    return eng, _cell_programs(eng, slots, chunk)
 
 
 def _nbytes(x) -> int:
     return x.size * x.dtype.itemsize
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill"])
+@pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
 def test_latent_step_program_keeps_the_pool_as_stored(one_chip,
                                                       glm_engine_args, kind):
     """A pool of latent rows (``decode/paged.py``: ``k`` the rows, ``v``
@@ -543,7 +551,7 @@ def test_latent_step_program_keeps_the_pool_as_stored(one_chip,
     logical = sum(_nbytes(x) for x in jax.tree_util.tree_leaves(args[:2]))
     assert m.argument_size_in_bytes - logical < _nbytes(pool.k) // 100
     assert _total_bytes(compiled) < HBM_V5E
-    picks = bucket if kind == "decode" else 1
+    picks = {"decode": bucket, "prefill": 1, "mixed": bucket + 1}[kind]
     assert compiled.output_shardings is not None
     out = jax.eval_shape(eng.programs.body(kind, bucket), *args)[1]
     assert out.shape == (picks + 2 * 64,) and out.dtype == jnp.int32
@@ -587,11 +595,10 @@ def lfm2_engine_args():
         params.cache_spec(spec.n_heads), params.vocab)
     eng = _ShapesEngine(programs, params,
                         *jax.eval_shape(lambda: programs.init_cache()))
-    decode, prefill = _step_args(eng, slots, chunk)
-    return eng, {"decode": (slots, decode), "prefill": (chunk, prefill)}
+    return eng, _cell_programs(eng, slots, chunk)
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill"])
+@pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
 def test_conv_moe_step_program_keeps_pool_and_state_as_stored(
         one_chip, lfm2_engine_args, kernels_for_the_chip, kind):
     """All three seams in ONE step program (K/V blocks of 8 KV heads x
@@ -631,13 +638,14 @@ def test_conv_moe_step_program_keeps_pool_and_state_as_stored(
     logical = sum(_nbytes(x) for x in jax.tree_util.tree_leaves(args[:2]))
     assert m.argument_size_in_bytes - logical < _nbytes(pool.k) // 100
     assert _total_bytes(compiled) < HBM_V5E
-    picks = bucket if kind == "decode" else 1
+    picks = {"decode": bucket, "prefill": 1, "mixed": bucket + 1}[kind]
     out = jax.eval_shape(eng.programs.body(kind, bucket), *args)[1]
     assert out.shape == (picks + 3 * 64,) and out.dtype == jnp.int32
     calls = [l for l in hlo.splitlines() if MOSAIC in l]
     if kind == "prefill":
         assert not calls            # the chunk's convolution is plain ops
         return
+    # ... in the mixed program too: the kernel is the batch's rows'
     assert len(calls) == eng.programs.spec.rec_layers == 3
     assert all("f32[3,65,1,4096]" in l.split(" custom-call(")[0]
                for l in calls)
@@ -646,7 +654,9 @@ def test_conv_moe_step_program_keeps_pool_and_state_as_stored(
 
 
 def _toy_engine(family, ways, speculate, hybrid_config):
-    """A GPT-2-shaped toy or the toy hybrid, as small as they compile."""
+    """A GPT-2-shaped toy, or the toy whose ``config.json`` is handed in
+    (the hybrid's, the latent-attention expert model's), as small as
+    they compile."""
     from distributed_llm_code_samples_tpu.decode import (DecodeEngine,
                                                          EngineConfig)
     from distributed_llm_code_samples_tpu.decode.model_config import (
@@ -655,7 +665,7 @@ def _toy_engine(family, ways, speculate, hybrid_config):
     from distributed_llm_code_samples_tpu.parallel import make_mesh
     cfg = EngineConfig(max_slots=2, n_blocks=9, max_blocks_per_seq=4,
                        speculate=speculate)
-    if family == "hybrid":
+    if family != "gpt2":    # the family's published-style config.json
         return engine_from_config(hybrid_config, seed=1, engine_config=cfg)
     params = init_lm(jax.random.PRNGKey(0), 96, 32, 2, 64, n_heads=4)
     return DecodeEngine(params, 4, cfg,
@@ -665,7 +675,8 @@ def _toy_engine(family, ways, speculate, hybrid_config):
 @pytest.mark.parametrize("family,kind,ways", [
     ("gpt2", "decode", 0), ("gpt2", "prefill", 0), ("gpt2", "verify", 0),
     ("gpt2", "decode", 2), ("gpt2", "prefill", 2), ("gpt2", "verify", 2),
-    ("hybrid", "decode", 0), ("hybrid", "prefill", 0)])
+    ("hybrid", "decode", 0), ("hybrid", "prefill", 0),
+    ("gpt2", "mixed", 0), ("gpt2", "mixed", 2), ("hybrid", "mixed", 0)])
 def test_step_program_boundary_is_one_operand_and_one_result(
         toy_hybrid_config, family, kind, ways):
     """The wire format of a step program (``decode/programs.py``):
@@ -696,7 +707,7 @@ def test_step_program_boundary_is_one_operand_and_one_result(
     (result,) = leaves(results)
     assert result.dtype == np.int32
     assert result.shape == {"decode": (2,), "prefill": (1,),
-                            "verify": (2, 4)}[kind]
+                            "verify": (2, 4), "mixed": (3,)}[kind]
     compiled = lowered.compile()
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry",
                         compiled.as_text()).group(1)
@@ -704,6 +715,92 @@ def test_step_program_boundary_is_one_operand_and_one_result(
     held = sum(x.nbytes for x in leaves(eng._cache()))
     assert (compiled.memory_analysis().alias_size_in_bytes
             == held // max(ways, 1))
+
+
+class _BodiesOfThePredecessor:
+    """``decode_hidden`` and ``prefill_hidden`` as they stood before the
+    mixed program (PR 36) drew their seams out into ``_batch_seams`` /
+    ``_chunk_seams``: the closures written in place."""
+
+    def decode_hidden(self, b, p, cache, tables, lengths, tokens,
+                      rows=None):
+        from distributed_llm_code_samples_tpu.decode import paged
+        cfg = self.cfg
+        x = self._embed(p, tokens, lengths)
+        slot_phys = lengths // cfg.block_size
+        off = lengths % cfg.block_size
+
+        def write_attn(l, pool, q, k, v):
+            phys = tables[jnp.arange(b), slot_phys]
+            pool = paged.write_rows(pool, l, phys, off, k, v, cfg.kv_dtype)
+            return pool, paged.stored_decode_attn(pool, l, q, tables,
+                                                  lengths + 1)
+
+        def mix(i, state, a):
+            with jax.named_scope("ssm"):
+                y, conv, ssm = p.recurrent_step(i, a, state.conv,
+                                                state.ssm, rows)
+            return paged.RecurrentState(conv, ssm), y
+
+        return self._trunk(p, cache, x, lengths, write_attn, mix)
+
+    def prefill_hidden(self, c, p, cache, table, pos0, tokens, row=None):
+        from distributed_llm_code_samples_tpu.decode import paged
+        cfg = self.cfg
+        positions = pos0 + jnp.arange(c)
+        x = self._embed(p, tokens, positions)
+
+        def write_attn(l, pool, q, k, v):
+            pool = paged.write_chunk(pool, l, table, pos0, k, v,
+                                     cfg.kv_dtype)
+            return pool, paged.gathered_chunk_attn(pool, l, q, table, pos0)
+
+        def mix(i, state, a):
+            with jax.named_scope("ssm"):
+                fresh = pos0 == 0
+                tail = jnp.where(fresh, 0.0, state.conv[i, row]).reshape(
+                    -1, self.spec.d_inner)
+                s = (None if state.ssm is None
+                     else jnp.where(fresh, 0.0, state.ssm[i, row]))
+                y, tail, s = p.recurrent_chunk(i, a, tail, s)
+                state = state._replace(
+                    conv=state.conv.at[i, row].set(tail.reshape(1, -1)),
+                    ssm=None if s is None else state.ssm.at[i, row].set(s))
+            return state, y
+
+        return self._trunk(p, cache, x, positions, write_attn, mix)
+
+
+@pytest.mark.parametrize("family,kind,ways", [
+    ("gpt2", "decode", 0), ("gpt2", "prefill", 0), ("gpt2", "verify", 0),
+    ("gpt2", "decode", 2), ("gpt2", "prefill", 2),
+    ("hybrid", "decode", 0), ("hybrid", "prefill", 0),
+    ("latent", "decode", 0), ("latent", "prefill", 0)])
+def test_older_programs_lower_as_before_the_mixed_program(
+        monkeypatch, toy_hybrid_config, toy_latent_config, family, kind,
+        ways):
+    """The fourth body shares the other bodies' seams; drawing them out
+    changed nothing of what ``decode``, ``prefill`` and ``verify``
+    lower to: same operands, same StableHLO, byte for byte, with the
+    predecessor's bodies in place (the expert and the latent seam, a
+    recurrent state and a 2-way model mesh among them). No topology is
+    described: the lowering is the host's."""
+    from distributed_llm_code_samples_tpu.decode.programs import (
+        StepPrograms)
+    bucket = 16 if kind == "prefill" else 2
+
+    def lowered():
+        eng = _toy_engine(family, ways, 2 if kind == "verify" else 0,
+                          toy_latent_config if family == "latent"
+                          else toy_hybrid_config)
+        return eng._program(kind, bucket).lower(
+            eng.params, eng._cache(), _operand(eng, kind, bucket)).as_text()
+
+    built = lowered()
+    for name in ("decode_hidden", "prefill_hidden"):
+        monkeypatch.setattr(StepPrograms, name,
+                            getattr(_BodiesOfThePredecessor, name))
+    assert lowered() == built
 
 
 def _entry_results(hlo: str):
@@ -720,12 +817,13 @@ def _entry_results(hlo: str):
             for bits, dims in shape.findall(m.group(1))]
 
 
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
 @pytest.mark.parametrize("fixture", ["gpt2_large_engine_args",
                                      "jamba_engine_args",
                                      "glm_engine_args",
                                      "lfm2_engine_args"])
 def test_decode_program_reads_the_gathered_rows_as_stored(
-        one_chip, request, kernels_for_the_chip, fixture):
+        one_chip, request, kernels_for_the_chip, fixture, kind):
     """The decode program attends over each slot's gathered blocks in
     the form and dtype the pool stores them (``decode/paged.py::
     stored_decode_attn``): beyond the gather itself, no instruction of
@@ -746,8 +844,8 @@ def test_decode_program_reads_the_gathered_rows_as_stored(
     convolution cell's: 8 KV heads x 64 lanes with four query heads a
     group, a 512-lane row of whole tiles.)"""
     eng, programs = request.getfixturevalue(fixture)
-    bucket, args = programs["decode"]
-    compiled = eng._program("decode", bucket).lower(
+    bucket, args = programs[kind]
+    compiled = eng._program(kind, bucket).lower(
         *_shapes_of(args, one_chip)).compile()
     pool = eng.pool
     view = (bucket * eng.cfg.max_blocks_per_seq * pool.block_size
@@ -810,6 +908,9 @@ def test_cell_rehearsal_on_the_cpu(monkeypatch, name, trace):
         # the program's counter has its reader (device metrics need a
         # device trace: none on the CPU)
         assert line["metrics"][counter]["value"] > 0
+        # ... and the traced steps that carried a chunk say how many of
+        # them rode with the batch (the mixed path is on: no switch)
+        assert 0 <= line["metrics"]["chunk_ride_share.offline"]["value"] <= 100
 
 
 def test_train_single_step_compiles_at_paper_width(one_chip):

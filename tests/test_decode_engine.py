@@ -328,20 +328,26 @@ def test_admission_blocked_on_pool_not_slots(lm_params):
 
 def test_recompile_guard_bounded_by_buckets(lm_params):
     """Acceptance: steady-state decode steps are dispatch-only — the
-    compiled-program count is bounded by the bucket count and STOPS
-    GROWING once every bucket has been seen, however much more traffic
-    flows (the --log_every chunk discipline applied to serving)."""
+    compiled-program count is bounded by the bucket count (a decode
+    program a slot bucket, a prefill program a chunk bucket, ONE mixed
+    program) and STOPS GROWING once every bucket has been seen, however much
+    more traffic flows (the --log_every chunk discipline applied to
+    serving)."""
     eng = DecodeEngine(lm_params, H, EngineConfig(**BASE))
-    bound = len(_buckets(BASE["max_slots"])) + len(
-        _buckets(BASE["prefill_chunk"]))
+    slot_buckets = _buckets(BASE["max_slots"])
+    bound = len(slot_buckets) + len(_buckets(BASE["prefill_chunk"])) + 1
     rng = np.random.default_rng(5)
     first = [rng.integers(0, V, size=n).tolist()
-             for n in (1, 2, 3, 5, 8, 13)]
+             for n in (1, 2, 3, 5, 8, 13, 9, 21)]
     eng.generate(first, 5)
     assert eng.compile_count <= bound, (eng.compile_count, bound)
+    # full chunks rode, whatever the ready count, in the one program
+    assert {b for kind, b in eng._programs if kind == "mixed"} == {
+        slot_buckets[-1]}
+    assert eng.mixed_dispatches >= 3
     warm = eng.compile_count
     dispatches = eng.dispatch_count
-    more = [rng.integers(0, V, size=n).tolist() for n in (4, 7, 11, 2)]
+    more = [rng.integers(0, V, size=n).tolist() for n in (4, 7, 11, 2, 16)]
     eng.generate(more, 7)
     assert eng.compile_count == warm            # zero new compiles
     assert eng.dispatch_count > dispatches

@@ -131,6 +131,51 @@ def test_the_chunk_is_the_step_token_by_token(k, bias):
         assert not np.asarray(zero).any()
 
 
+@pytest.mark.parametrize("case", ["permuted-rows",
+                                  "padded-rows-on-the-scratch-row",
+                                  "three-taps-no-bias-padded"])
+def test_the_mixed_forms_are_the_step_on_the_batch_and_the_chunk_on_the_rest(
+        case):
+    """``conv_mixed`` / ``scan_mixed`` over a batch's rows and then one
+    sequence's 5-token chunk (the mixed program's state seam, where a
+    mixer's weight products run once over both kinds of row) are the
+    in-place step on the first rows and the chunk form on the rest:
+    outputs, the store and the sequence's own tail and state, bit for
+    bit (the same calls on the same rows)."""
+    rows, k = CASES[case]
+    o = _operands(rows, k, seed=7, bias="no-bias" not in case)
+    b, at = len(rows), dict(layer=1, rows=o["rows"])
+    extra = iter(jax.random.split(jax.random.PRNGKey(8), 8))
+
+    def more(x, n=5):       # the chunk's rows after the batch's
+        return jnp.concatenate([x, jax.random.normal(
+            next(extra), (n,) + x.shape[1:], jnp.float32)])
+
+    x, tail = more(o["x"]), jax.random.normal(next(extra), (k - 1, D))
+    y, (store, new_tail) = ssm.conv_mixed(
+        x, (o["state"].conv, tail), o["w"], o["bias"], **at)
+    yb, want_store = ssm.conv_step_in_place(
+        x[:b], o["state"].conv, o["w"], o["bias"], **at)
+    yc, want_tail = ssm.conv_chunk(x[b:], tail, o["w"], o["bias"])
+    assert np.array_equal(y, jnp.concatenate([yb, yc]))
+    assert np.array_equal(store, want_store)
+    assert np.array_equal(new_tail, want_tail)
+
+    dt, bm, cm = (more(o[name]) for name in ("dt", "b", "c"))
+    dt = jnp.abs(dt)
+    s = jax.random.normal(next(extra), (N, D))
+    y, (store, new_s) = ssm.scan_mixed(
+        x, dt, o["a"], bm, cm, o["d"], (o["state"].ssm, s), **at)
+    yb, want_store = ssm.scan_step_in_place(
+        x[:b], dt[:b], o["a"], bm[:b], cm[:b], o["d"], o["state"].ssm, **at)
+    yc, want_s = ssm.scan_chunk(x[b:], dt[b:], o["a"], bm[b:], cm[b:],
+                                o["d"], s)
+    assert np.array_equal(y, jnp.concatenate([yb, yc]))
+    assert np.array_equal(store, want_store)
+    assert np.array_equal(new_s, want_s)
+    assert y.shape == (b + 5, D)
+
+
 @pytest.mark.parametrize("k", [4, 2])
 def test_padded_rows_on_the_scratch_row_neither_fault_nor_leak(k):
     """Several rows of the batch name the one scratch row: several grid

@@ -37,13 +37,18 @@ BASE = dict(block_size=8, n_blocks=33, max_slots=3, max_blocks_per_seq=6,
 HOST = {"expire", "admit", "prefill.cow", "prefill.book",
         "decode.marshal", "decode.cow", "decode.emit", "digest"}
 LAUNCH = {"prefill.upload", "prefill.dispatch", "decode.upload",
-          "decode.dispatch"}
-WAIT = {"prefill.readback", "decode.readback"}
+          "decode.dispatch", "mixed.upload", "mixed.dispatch"}
+WAIT = {"prefill.readback", "decode.readback", "mixed.readback"}
 # one dispatch's phases, in the order the engine runs them
 PREFILL = ["prefill.cow", "prefill.upload", "prefill.dispatch",
            "prefill.readback", "prefill.book"]
 DECODE = ["decode.cow", "decode.marshal", "decode.upload",
           "decode.dispatch", "decode.readback", "decode.emit"]
+# a full chunk riding with the batch: both dispatches' host halves
+# under their own names round ONE launch and ONE wait
+MIXED = ["prefill.cow", "decode.cow", "decode.marshal", "mixed.upload",
+         "mixed.dispatch", "mixed.readback", "prefill.book",
+         "decode.emit"]
 
 
 class Collector:
@@ -93,18 +98,21 @@ def _slow_dispatch(eng, seconds=0.02):
 
 
 def _dispatches(rec):
-    """How many prefill and decode (or verify) programs ``rec``'s step
-    dispatched, by its phases."""
+    """How many prefill, decode (or verify) and mixed programs
+    ``rec``'s step dispatched, by its phases."""
     names = [p[0] for p in rec["phases"]]
-    return names.count("prefill.dispatch"), names.count("decode.dispatch")
+    return tuple(names.count(kind + ".dispatch")
+                 for kind in ("prefill", "decode", "mixed"))
 
 
 def _expected(rec):
     """The phase names a step with ``rec``'s dispatches has to show, in
-    order."""
-    n_prefill, n_decode = _dispatches(rec)
-    return (["expire", "admit"] + PREFILL * n_prefill
-            + ["decode.marshal"] + DECODE * n_decode + ["digest"])
+    order (the first ``decode.marshal`` is where the step looks whether
+    its chunk can ride with the batch)."""
+    n_prefill, n_decode, n_mixed = _dispatches(rec)
+    return (["expire", "admit", "decode.marshal"] + MIXED * n_mixed
+            + PREFILL * n_prefill + ["decode.marshal"]
+            + DECODE * n_decode + ["digest"])
 
 
 def _run_scenario(name, lm_params, prompts):
@@ -126,7 +134,7 @@ def _run_scenario(name, lm_params, prompts):
         while eng.step(prefill_only=True):
             pass
         recs = sink.steps()
-        assert recs and all(_dispatches(r) == (1, 0) for r in recs)
+        assert recs and all(_dispatches(r) == (1, 0, 0) for r in recs)
         return recs
     if name == "two_versions":
         other = init_lm(jax.random.PRNGKey(5), V, D, L, max_seq_len=64)
@@ -141,14 +149,21 @@ def _run_scenario(name, lm_params, prompts):
         eng.set_serving_version(1)
         eng.submit(prompts[1], 12)
         eng.run()
-        recs = [r for r in sink.steps() if _dispatches(r) == (0, 2)]
+        recs = [r for r in sink.steps() if _dispatches(r) == (0, 2, 0)]
         assert recs, "no step dispatched one decode per resident version"
         return recs
     eng.generate(prompts, 8)            # compile outside what is read
+    if name == "mixed":
+        # prompts of several full chunks, admitted behind a ready slot
+        # (and new to the prefix cache)
+        rng = np.random.default_rng(2)
+        prompts = prompts[:1] + [rng.integers(0, V, size=n).tolist()
+                                 for n in (24, 17)]
     sink.spans.clear()
     _slow_dispatch(eng)
     eng.generate(prompts, 8)
-    want = (1, 1) if name == "prefill_decode" else (0, 1)
+    want = {"prefill_decode": (1, 1, 0), "mixed": (0, 0, 1)}.get(
+        name, (0, 1, 0))
     recs = [r for r in sink.steps() if _dispatches(r) == want]
     assert len(recs) >= 3, (name, [_dispatches(r) for r in sink.steps()])
     if name == "verify":
@@ -158,7 +173,7 @@ def _run_scenario(name, lm_params, prompts):
 
 @pytest.mark.parametrize("scenario", [
     "prefill_only", "decode_only", "prefill_decode", "two_versions",
-    "verify"])
+    "verify", "mixed"])
 def test_phases_tile_the_step(scenario, lm_params, prompts):
     """Children in order, inside the parent, none overlapping, named as
     the step's dispatches say; and the time no child covers is within
@@ -254,6 +269,7 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
     n = 0
     while eng.active or eng.waiting:
         pre, dispatches = eng.prefill_dispatches, eng.dispatch_count
+        mixed = eng.mixed_dispatches
         assert eng.step()
         n += 1
         rec = sink.steps()[-1]
@@ -267,9 +283,13 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
         assert rec["expert_rows"] == rec["experts_touched"] == 0  # nor expert
         assert rec["step"] == eng.global_step == eng.flight[-1]["step"]
         assert rec["tokens_generated"] == eng.tokens_generated
+        # a mixed dispatch is ONE dispatch that carried a chunk
         n_pre = eng.prefill_dispatches - pre
+        n_mixed = eng.mixed_dispatches - mixed
         assert _dispatches(rec) == (
-            n_pre, eng.dispatch_count - dispatches - n_pre)
+            n_pre - n_mixed, eng.dispatch_count - dispatches - n_pre,
+            n_mixed)
+    assert eng.mixed_dispatches == 2        # the 9- and 13-token prompts
     assert not eng.step() and len(sink.steps()) == n    # idle: no record
 
 

@@ -221,10 +221,14 @@ def _trace_counts(trace_dir):
     return counts
 
 
+@pytest.mark.parametrize("ride", [True, False])
 @pytest.mark.parametrize("family", ["gpt2", "hybrid"])
-def test_a_step_is_one_transfer_and_one_program_a_dispatch(engine,
-                                                           tmp_path, family):
-    """Over steps that each run a prefill and a decode dispatch, the
+def test_a_step_is_one_transfer_and_one_program_a_dispatch(
+        engine, tmp_path, monkeypatch, family, ride):
+    """Over steps that each carry a full prefill chunk and a decode
+    batch — ONE mixed dispatch where the chunk rides with the batch,
+    a prefill and a decode dispatch where the step's choice is patched
+    out here (``ride`` false: the program has no switch) — the
     runtime executes as many programs as the engine dispatched — no
     ``convert_element_type`` or ``broadcast`` one-off from a scalar
     constructor — and moves one array to the device a dispatch. Counted
@@ -235,6 +239,8 @@ def test_a_step_is_one_transfer_and_one_program_a_dispatch(engine,
     (The parent of PR 30 ran 5-7 transfers and 2-3 one-off programs a
     dispatch.)"""
     eng = engine(family)
+    if not ride:
+        monkeypatch.setattr(eng, "_mixed_batch", lambda pre, only: [])
     rng = np.random.default_rng(5)
     # long prompts: a chunk in every step traced below
     batch = [rng.integers(0, V, size=32).tolist() for _ in range(8)]
@@ -254,7 +260,10 @@ def test_a_step_is_one_transfer_and_one_program_a_dispatch(engine,
     finally:
         jax.profiler.stop_trace()
     dispatched = eng.dispatch_count - before[0]
-    assert eng.prefill_dispatches - before[1] == 4 and dispatched == 8
+    assert eng.prefill_dispatches - before[1] == 4
+    assert dispatched == (4 if ride else 8)
+    assert eng.mixed_dispatches >= 4 * ride and (
+        ride or not eng.mixed_dispatches)
     assert eng.compile_count == before[2]
     counts = _trace_counts(trace_dir)
     executed = sum(n for name, n in counts.items()

@@ -493,6 +493,15 @@ def _cli_shape():
             "--prefill_chunk", "4", "--max_slots", "2"]
 
 
+# the drill at the CLI's shape: chunks of 4, so most chunks are full
+# ones and ride with the other slot's decode row in one mixed dispatch;
+# a prompt whose LAST chunk rode decodes its second token a step later
+# than when the two programs ran in one step, which brings the page
+# forward a round
+CLI_DRILL_HISTORY = ([DRILL_HISTORY[0], (10, "fired", "burn_rate")]
+                     + DRILL_HISTORY[2:])
+
+
 def test_generate_cli_watch_rejections(tmp_path):
     from distributed_llm_code_samples_tpu.decode.generate_cli import (
         generate_main)
@@ -547,7 +556,7 @@ def test_watch_cli_transport_parity(tmp_path):
         assert watch["fired"] == 2 and watch["resolved"] == 2, \
             (transport, watch)
         assert [(h["round"], h["event"], h["detector"])
-                for h in watch["history"]] == DRILL_HISTORY, transport
+                for h in watch["history"]] == CLI_DRILL_HISTORY, transport
     out = io.StringIO()
     with contextlib.redirect_stdout(out):
         rc = report_main([os.path.join(str(tmp_path / "inproc"),
