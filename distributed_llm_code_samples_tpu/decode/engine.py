@@ -709,6 +709,10 @@ class DecodeEngine:
         # row a ready slot; written back the same size): the engine_step
         # record's and the digest's ``state_bytes``
         self._step_state_bytes = 0
+        # the step programs this step launched, ``[kind, bucket]`` in
+        # launch order (``_dispatch``): the engine_step record's and the
+        # digest's ``dispatches``
+        self._step_dispatches: list[list] = []
         # the expert layers' counters of this step's dispatches, as the
         # step programs returned them (``[expert_layers, n_experts]``
         # each; none for a model with no expert layer), folded in the
@@ -2176,15 +2180,21 @@ class DecodeEngine:
         finally:
             jax.config.update("jax_compilation_cache_dir", old)
 
-    def _dispatch(self, phase: str, fn, params: ServedModel,
-                  operand: np.ndarray) -> np.ndarray:
+    def _dispatch(self, phase: str, bucket: int, fn,
+                  params: ServedModel, operand: np.ndarray) -> np.ndarray:
         """Launch one step program on its packed operand and read its
         packed result (``decode/programs.py`` has the format): one
         host-to-device transfer, the vector handed to the jitted call
         as it is, and one blocking read, in the phases
-        ``<phase>.dispatch`` and ``<phase>.readback``. Returns the
-        picks; an expert model's counters, which came on the same read,
-        are kept for the step's digest."""
+        ``<phase>.dispatch`` and ``<phase>.readback``. ``bucket`` is
+        the key ``fn`` was asked of ``_program`` under; the launch is
+        noted in the step's ``dispatches`` as ``[kind, bucket]``.
+        Returns the picks; an expert model's counters, which came on
+        the same read, are kept for the step's digest."""
+        # with speculation on every decode dispatch is a verify dispatch
+        kind = ("verify" if phase == "decode" and self.cfg.speculate
+                else phase)
+        self._step_dispatches.append([kind, bucket])
         args = (params, self._cache(), operand)
         self._maybe_capture(fn, *args)
         with self.phases.phase(phase + ".dispatch"):
@@ -2192,9 +2202,6 @@ class DecodeEngine:
         with self.phases.phase(phase + ".readback"):
             self._keep(cache)
             result = np.asarray(result)
-        # with speculation on every decode dispatch is a verify dispatch
-        kind = ("verify" if phase == "decode" and self.cfg.speculate
-                else phase)
         picks, rows = self.programs.split(kind, result)
         if rows is not None:
             self._step_expert_rows.append(rows)
@@ -2213,7 +2220,8 @@ class DecodeEngine:
                 "prefill", c, poison=self._poison_uid,
                 **self._chunk_fields(slot, seq, c, "tokens"))
         result = self._dispatch(
-            "prefill", fn, self._params_for(seq.weights_version), operand)
+            "prefill", c, fn, self._params_for(seq.weights_version),
+            operand)
         with phase("prefill.book"):
             self._prefill_book(slot, seq, c, int(result[0]))
 
@@ -2381,7 +2389,7 @@ class DecodeEngine:
         with phase("decode.upload"):
             operand = self.programs.pack(
                 "decode", b, **self._batch_fields(ready, b, *batch))
-        picks = self._dispatch("decode", fn, params, operand)
+        picks = self._dispatch("decode", b, fn, params, operand)
         with phase("decode.emit"):
             self._emit_batch(ready, picks)
 
@@ -2440,7 +2448,8 @@ class DecodeEngine:
                 "mixed", b, **self._batch_fields(ready, b, *batch),
                 **self._chunk_fields(slot, seq, c, "chunk"))
         picks = self._dispatch(
-            "mixed", fn, self._params_for(seq.weights_version), operand)
+            "mixed", b, fn, self._params_for(seq.weights_version),
+            operand)
         with phase("prefill.book"):
             self._prefill_book(slot, seq, c, int(picks[-1]))
         with phase("decode.emit"):
@@ -2517,7 +2526,7 @@ class DecodeEngine:
                 "verify", b, tables=tables, lengths=lengths, tokens=tokens,
                 uids=uids, poison=self._poison_uid, drafts=drafts,
                 dlens=dlens)
-        result = self._dispatch("decode", fn, params, operand)
+        result = self._dispatch("decode", b, fn, params, operand)
         with phase("decode.emit"):
             picks, acc = result[:, :k + 1], result[:, k + 1]
             ok = picks >= 0
@@ -2577,6 +2586,7 @@ class DecodeEngine:
         self._step_prefill_uid = None
         self._step_decode_uids = []
         self._step_state_bytes = 0
+        self._step_dispatches = []
         self._step_expert_rows = []
         with phase("expire"):
             # spill-tier housekeeping: a fresh promotion budget each
@@ -2648,8 +2658,10 @@ class DecodeEngine:
 
     def _step_record(self, start_ns: int, end_ns: int) -> dict:
         """The executed step as ONE ``engine_step`` span record
-        (telemetry v18): the parent span and its phases in the order
-        they closed (each a child by being in this list).
+        (telemetry v19): the parent span and its phases in the order
+        they closed (each a child by being in this list), and the step
+        programs it launched (``dispatches``: the i-th entry belongs to
+        the i-th ``*.dispatch`` phase and the ``*.readback`` after it).
         ``tokens_generated`` is what a reader joins a step on."""
         return {
             "uid": None,
@@ -2664,6 +2676,7 @@ class DecodeEngine:
             "tokens_generated": self.tokens_generated,
             "state_bytes": self._step_state_bytes,
             **self._step_experts,
+            "dispatches": self._step_dispatches,
         }
 
     def _fold_expert_rows(self) -> dict:
@@ -2878,6 +2891,9 @@ class DecodeEngine:
             # (runtime/tracing.py PhaseTimer): what an UNTRACED run's
             # ring says about a slow step
             "phase_ms": self.phases.phase_ms(),
+            # the step programs those ``*.dispatch`` phases launched,
+            # ``[kind, bucket]`` in order
+            "dispatches": self._step_dispatches,
         }
 
     def dump_flight_recorder(self, reason: str) -> str | None:

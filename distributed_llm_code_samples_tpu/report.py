@@ -50,8 +50,10 @@ the same dirs render identical output even under equal timestamps.
 
 Output: step-time percentiles, throughput, MFU, HBM high-water, the
 serving summary + reliability block per engine, a **step phases**
-table (schema-v18 ``engine_step`` span records: per host phase of
-``engine.step()`` the count, mean, p99 and share of step time), a
+table (schema-v19 ``engine_step`` span records: per host phase of
+``engine.step()`` the count, mean, p99 and share of step time, and per
+step program, by kind and bucket, its runs and the time from its launch
+to the end of its read), a
 per-request **waterfall** (schema-v5 ``span`` records: queued / prefill / replay /
 decode / quarantine / preempt_gap, whose summed durations RECONCILE
 with each completed request's recorded ``latency_s``), and ONE merged
@@ -541,23 +543,40 @@ class _Stream:
         of the ``engine_step`` records (runtime/tracing.py PhaseTimer)
         how many steps had it, its mean and p99 per step that had it
         (a phase repeated in a step is summed first), and its share of
-        all step time. ``(between phases)`` is what no phase covers."""
+        all step time. ``(between phases)`` is what no phase covers.
+        ``dispatches``: the step programs those steps launched, by
+        kind and bucket (v19: the record's i-th entry is its i-th
+        ``*.dispatch`` phase), each with how often and how long from
+        the launch to the end of the blocking read."""
         if not self.step_spans:
             return None
         per_phase: dict[str, list[float]] = {}
+        per_program: dict[tuple, list[float]] = {}
         total_ms = 0.0
         for rec in self.step_spans:
             step_ms = (rec["end_ns"] - rec["start_ns"]) / 1e6
             total_ms += step_ms
             mine: dict[str, float] = {}
+            launched = iter(rec["dispatches"])
             for name, t0, t1 in rec["phases"]:
                 mine[name] = mine.get(name, 0.0) + (t1 - t0) / 1e6
+                if name.endswith(".dispatch"):
+                    program, t_launch = tuple(next(launched)), t0
+                elif name.endswith(".readback"):
+                    per_program.setdefault(program, []).append(
+                        (t1 - t_launch) / 1e6)
             mine["(between phases)"] = step_ms - sum(mine.values())
             for name, ms in mine.items():
                 per_phase.setdefault(name, []).append(ms)
         return {
             "steps": len(self.step_spans),
             "step_mean_ms": round(total_ms / len(self.step_spans), 4),
+            "dispatches": [
+                {"kind": kind, "bucket": bucket, "count": len(ms),
+                 "mean_ms": round(float(np.mean(ms)), 4),
+                 "p99_ms": round(float(np.percentile(ms, 99)), 4)}
+                for (kind, bucket), ms in sorted(
+                    per_program.items(), key=lambda kv: -len(kv[1]))],
             "phases": {
                 name: {"steps": len(ms),
                        "mean_ms": round(float(np.mean(ms)), 4),
@@ -1810,6 +1829,13 @@ def _render_engine_sections(out: list, doc: dict) -> None:
             out.append(f"  {name:18s} {ph['steps']:6d} "
                        f"{ph['mean_ms']:10.4f} {ph['p99_ms']:10.4f} "
                        f"{100 * (ph['share'] or 0.0):6.2f}%")
+        out.append(f"  {'program (bucket)':18s} {'runs':>6s} "
+                   f"{'mean ms':>10s} {'p99 ms':>10s}   launch to "
+                   "the end of the read")
+        for d in sp["dispatches"]:
+            label = f"{d['kind']} ({d['bucket']})"
+            out.append(f"  {label:18s} {d['count']:6d} "
+                       f"{d['mean_ms']:10.4f} {d['p99_ms']:10.4f}")
     rec = doc.get("recovery", {})
     if (rec.get("attempts_failed") or rec.get("nonfinite_skips")
             or rec.get("attempt_log")

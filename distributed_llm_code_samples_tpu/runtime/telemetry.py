@@ -226,7 +226,15 @@ import numpy as np
 # on the same ``time.time_ns()`` clock — and ``tokens_generated``
 # after the step, what a reader joins a step on. Per-request readers
 # skip the record.
-SCHEMA_VERSION = 18
+# v19 (PR 37): every dispatch says which program it ran. The
+# ``engine_step`` record pins ``dispatches``: the step programs the
+# step launched as ``[kind, bucket]`` in launch order, the i-th entry
+# belonging to the i-th ``*.dispatch`` phase of ``phases`` and the
+# ``*.readback`` after it (``runtime/tracing.py`` has the contract), so
+# as many entries as the step has ``*.dispatch`` phases. Each key of
+# the record has a reader (``PERF.md`` section 3 names it); the bump
+# dropped none.
+SCHEMA_VERSION = 19
 
 METRICS_FILENAME = "metrics.jsonl"
 
@@ -366,7 +374,8 @@ REQUEST_COMPLETED_REQUIRED = ("latency_s", "ttft_s")
 # single-tenant), so per-tenant ITL percentiles come straight off the
 # decode-segment spans.
 # v18: ``engine_step`` spans belong to a step, not a request: null
-# ``uid``, and STEP_SPAN_REQUIRED on top (validate_record).
+# ``uid``, and STEP_SPAN_REQUIRED on top (validate_record); v19 adds
+# ``dispatches`` to it, one entry a ``*.dispatch`` phase.
 # Same version-bump discipline as STEP_KEYS.
 SPAN_REQUIRED = ("step", "uid", "span", "start_step", "duration_s",
                  "trace_id", "tenant")
@@ -377,9 +386,9 @@ SPAN_NAMES = ("queued", "prefill", "replay", "decode", "quarantine",
               "preempt_gap", "engine_step")
 
 # the one span that belongs to a STEP, not a request (v18): null uid,
-# and the extra keys it must carry
+# and the extra keys it must carry (v19: ``dispatches``)
 STEP_SPAN = "engine_step"
-STEP_SPAN_REQUIRED = ("phases", "start_ns", "end_ns")
+STEP_SPAN_REQUIRED = ("phases", "start_ns", "end_ns", "dispatches")
 
 # The router-record contract (``decode/fleet.py``): one record per
 # fleet-router decision. ``step`` is the ROUTER's step clock (fleet
@@ -1033,6 +1042,12 @@ def validate_record(rec: Any) -> tuple[bool, str]:
             if missing:
                 return False, (f"span record (span {STEP_SPAN}) missing "
                                f"required key(s) {missing}")
+            launched = sum(p[0].endswith(".dispatch")
+                           for p in rec["phases"])
+            if len(rec["dispatches"]) != launched:
+                return False, (f"span record (span {STEP_SPAN}) has "
+                               f"{len(rec['dispatches'])} 'dispatches' "
+                               f"for {launched} '*.dispatch' phase(s)")
         elif rec["uid"] is None:
             return False, (f"span record (span {rec['span']}) has a "
                            f"null 'uid': only {STEP_SPAN} belongs to "

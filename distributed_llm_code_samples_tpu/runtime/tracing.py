@@ -203,7 +203,21 @@ class SpanTracer:
 #
 # ``host`` neither feeds nor waits for the device, ``launch`` hands it
 # operands and a program, ``wait`` blocks on its results (the classes
-# are told by the suffix: ``benchmark/engine_phases.py::phase_class``). The trainer's
+# are told by the suffix: ``benchmark/engine_phases.py::phase_class``).
+#
+# Which program a ``*.dispatch`` launched is in the step's
+# ``dispatches`` (``engine._dispatch``; the ``engine_step`` record and
+# the flight digest carry it): ``[kind, bucket]`` a launch, in launch
+# order, ``kind`` one of ``decode`` / ``prefill`` / ``mixed`` /
+# ``verify`` and ``bucket`` the key the program was built under (the
+# batch bucket; the chunk bucket for ``prefill``). The i-th entry
+# belongs to the i-th ``*.dispatch`` phase of the step's ``phases`` and
+# the ``*.readback`` that follows it. One device runs the launches in
+# that order and every one ends in a blocking read, so the k-th entry
+# of a run of steps is also the k-th step program of a device trace of
+# those steps: a reader joins the two by ORDER and needs no clock
+# (``benchmark/dispatch_join.py``). ``cow`` / ``cow_rows`` / ``implant``
+# are not step programs: no phase pair, no entry. The trainer's
 # sites are annotations only: ``train:clone`` / ``train:run``
 # (``parallel/single.py``), ``launch:build`` / ``launch:run``
 # (``parallel/launcher.py``).

@@ -7,7 +7,10 @@ What is proved: the phases TILE the step (every scheduler method runs
 inside the phase named for it, children are ordered, nested, disjoint,
 and what lies between them is small against the step), the record's
 counts are the engine's own counters, tokens do not depend on a writer,
-schema v18 takes the record and refuses a null uid anywhere else,
+every dispatch says which program it ran (``dispatches``, schema v19:
+one entry a ``*.dispatch`` phase, what ``_program`` was asked for, over
+the four served families and the step kinds),
+schema v19 takes the record and refuses a null uid anywhere else,
 ``report`` reads it without disturbing the per-request waterfall, and
 the annotations reach a real ``jax.profiler`` trace by name.
 """
@@ -29,6 +32,9 @@ from distributed_llm_code_samples_tpu.runtime.telemetry import (
     METRICS_FILENAME, SCHEMA_VERSION, SPAN_NAMES, STEP_SPAN,
     TelemetryWriter, read_metrics, validate_record)
 from distributed_llm_code_samples_tpu.runtime.tracing import PhaseTimer
+
+from test_mixed_program import BASE as FAMILY_BASE, FAMILIES, LENS, backlog
+from test_lfm2_moe_lm import TOY as TOY_CONV_MOE
 
 V, D, L, H = 64, 32, 2, 4
 BASE = dict(block_size=8, n_blocks=33, max_slots=3, max_blocks_per_seq=6,
@@ -278,7 +284,8 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
                             "start_ns", "end_ns", "t", "duration_s",
                             "phases", "tokens_generated",
                             "state_bytes", "expert_rows",
-                            "experts_touched", "expert_rows_max"}
+                            "experts_touched", "expert_rows_max",
+                            "dispatches"}
         assert rec["state_bytes"] == 0      # no recurrent layer here
         assert rec["expert_rows"] == rec["experts_touched"] == 0  # nor expert
         assert rec["step"] == eng.global_step == eng.flight[-1]["step"]
@@ -289,8 +296,147 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
         assert _dispatches(rec) == (
             n_pre - n_mixed, eng.dispatch_count - dispatches - n_pre,
             n_mixed)
+        assert len(rec["dispatches"]) == sum(_dispatches(rec))
     assert eng.mixed_dispatches == 2        # the 9- and 13-token prompts
     assert not eng.step() and len(sink.steps()) == n    # idle: no record
+
+
+# -- every dispatch says which program it ran (schema v19) ---------------
+
+STEP_PROGRAMS = ("decode", "prefill", "mixed", "verify")
+# the step kinds, by the programs a step of that kind launches in order
+STEP_KINDS = {"decode_only": ("decode",), "riding_chunk": ("mixed",),
+              "tail_chunk_and_batch": ("prefill", "decode"),
+              "verify": ("verify",),
+              "tail_chunk_and_verify": ("prefill", "verify")}
+
+
+@pytest.fixture(scope="module")
+def served_steps(toy_hybrid_config, toy_latent_config):
+    """``(family, speculate) -> [one observation an executed step]`` of
+    a toy engine of the family serving a backlog whose prompts have
+    full chunks and tails: the step's record, its flight digest, what
+    ``_program`` was asked for during it and how the engine's counters
+    rose over it. Served once a pair and kept."""
+    from distributed_llm_code_samples_tpu.decode.model_config import (
+        engine_from_config)
+    configs = {"hybrid": toy_hybrid_config, "latent": toy_latent_config,
+               "conv_moe": TOY_CONV_MOE}
+    kept = {}
+
+    def serve(family, speculate):
+        if (family, speculate) in kept:
+            return kept[family, speculate]
+        cfg = EngineConfig(**FAMILY_BASE, speculate=speculate)
+        sink = Collector()
+        if family == "gpt2":
+            eng = DecodeEngine(
+                init_lm(jax.random.PRNGKey(0), 96, 32, 2, 128, n_heads=4),
+                4, cfg, metrics=sink)
+        else:
+            eng = engine_from_config(dict(configs[family]), seed=1,
+                                     engine_config=cfg)
+            eng.metrics = sink
+        asked, real = [], eng._program
+
+        def program(kind, bucket):
+            asked.append([kind, bucket])
+            return real(kind, bucket)
+        eng._program = program
+        for p in backlog(LENS):
+            eng.submit(p, 12)
+        seen = []
+        while eng.active or eng.waiting:
+            before = (eng.prefill_dispatches, eng.mixed_dispatches,
+                      eng.dispatch_count)
+            del asked[:]
+            assert eng.step()
+            seen.append({
+                "rec": sink.steps()[-1], "digest": eng.flight[-1],
+                "asked": list(asked),
+                "rose": (eng.prefill_dispatches - before[0],
+                         eng.mixed_dispatches - before[1],
+                         eng.dispatch_count - before[2])})
+        kept[family, speculate] = seen
+        return seen
+
+    return serve
+
+
+@pytest.mark.parametrize("family,step_kind", [
+    (f, k) for f in FAMILIES
+    for k in ("decode_only", "riding_chunk", "tail_chunk_and_batch")
+] + [  # the models that carry a state by slot refuse speculation
+    (f, k) for f in ("gpt2", "latent")
+    for k in ("verify", "tail_chunk_and_verify")])
+def test_dispatches_name_the_programs_the_step_launched(
+        served_steps, family, step_kind):
+    """Every step of the kind: ``dispatches`` has one entry a
+    ``*.dispatch`` phase, in order; kinds and buckets are what
+    ``_program`` was asked for; the entries that carried a chunk are the
+    rise of ``prefill_dispatches``, the ``mixed`` ones that of
+    ``mixed_dispatches``; and the flight digest says the same."""
+    speculate = 2 if "verify" in step_kind else 0
+    steps = [o for o in served_steps(family, speculate)
+             if tuple(k for k, _ in o["rec"]["dispatches"])
+             == STEP_KINDS[step_kind]]
+    assert steps, (family, step_kind)
+    for o in steps:
+        rec, said = o["rec"], o["rec"]["dispatches"]
+        launched = [p[0] for p in rec["phases"]
+                    if p[0].endswith(".dispatch")]
+        # the speculative verify path takes the ``decode.*`` phase names
+        assert launched == [
+            ("decode" if kind == "verify" else kind) + ".dispatch"
+            for kind, _ in said]
+        assert said == [a for a in o["asked"] if a[0] in STEP_PROGRAMS]
+        n_chunk, n_mixed, n_all = o["rose"]
+        assert sum(k in ("prefill", "mixed") for k, _ in said) == n_chunk
+        assert sum(k == "mixed" for k, _ in said) == n_mixed
+        assert len(said) == n_all       # no pool op ran in these steps
+        assert o["digest"]["dispatches"] == said
+        assert o["digest"]["step"] == rec["step"]
+        ok, reason = validate_record(dict(
+            rec, schema=SCHEMA_VERSION, kind="span", trace_id=None,
+            tenant=None))
+        assert ok, reason
+    # a batch's bucket is a slot bucket, a chunk's a chunk bucket; the
+    # one mixed program is built for the largest slot bucket
+    for kind, bucket in (d for o in steps for d in o["rec"]["dispatches"]):
+        assert bucket in ((1, 2, 4, 8, 16) if kind == "prefill"
+                          else (4,) if kind == "mixed" else (1, 2, 4))
+
+
+def test_a_cow_program_adds_no_entry(lm_params):
+    """A step with the write barrier armed: its ``cow`` program is a
+    pool op, counted by ``dispatch_count`` and no step program, so the
+    step's ``dispatches`` and ``*.dispatch`` phases do not know it."""
+    rng = np.random.default_rng(7)
+    stem = rng.integers(0, V, size=19).tolist()     # two shared blocks
+    sink = Collector()
+    eng = DecodeEngine(lm_params, H, EngineConfig(**BASE), metrics=sink)
+    for uid in range(2):
+        eng.submit(stem + [uid], 12, uid=uid)
+        for _ in range(3):
+            eng.step()
+    slot = next(i for i, s in enumerate(eng.slots)
+                if s is not None and s.uid == 1)
+    assert eng.slots[slot].nodes[0].refs == 2
+    real = eng._cow_batch
+
+    def aimed_at_a_shared_block(ready):
+        # no scheduler write ever aims at one: the trigger is by hand
+        eng._cow_private(slot, 0, 0)
+        real(ready)
+    eng._cow_batch = aimed_at_a_shared_block
+    count = eng.dispatch_count
+    assert eng.step()
+    rec = sink.steps()[-1]
+    assert eng.cow_copies == 1 and ("cow", 0) in eng._programs
+    assert rec["dispatches"] == [["decode", 2]]
+    assert _dispatches(rec) == (0, 1, 0)
+    assert eng.dispatch_count - count == 2          # the copy and the batch
+    assert eng.flight[-1]["dispatches"] == rec["dispatches"]
 
 
 def _step_record(**over):
@@ -299,16 +445,17 @@ def _step_record(**over):
            "span": STEP_SPAN, "start_step": 3, "step": 3,
            "duration_s": 1.0, "start_ns": 1_000_000_000,
            "end_ns": 2_000_000_000,
-           "phases": [["admit", 1_000_000_100, 1_000_000_900]]}
+           "phases": [["admit", 1_000_000_100, 1_000_000_900]],
+           "dispatches": []}
     rec.update(over)
     return rec
 
 
-def test_validate_record_takes_v18_engine_step():
-    assert SCHEMA_VERSION >= 18 and STEP_SPAN in SPAN_NAMES
+def test_validate_record_takes_v19_engine_step():
+    assert SCHEMA_VERSION >= 19 and STEP_SPAN in SPAN_NAMES
     ok, reason = validate_record(_step_record())
     assert ok, reason
-    for key in ("phases", "start_ns", "end_ns"):
+    for key in ("phases", "start_ns", "end_ns", "dispatches"):
         rec = _step_record()
         del rec[key]
         ok, reason = validate_record(rec)
@@ -372,13 +519,57 @@ def test_report_reads_step_phases_and_keeps_the_waterfall(
     assert table["phases"]["admit"]["steps"] == eng.steps
     assert sum(p["share"] for p in table["phases"].values()) == \
         pytest.approx(1.0, abs=1e-3)
+    # the step programs beside the waterfall: every dispatch, once
+    assert {d["kind"] for d in table["dispatches"]} == {
+        "prefill", "decode", "mixed"}
+    assert sum(d["count"] for d in table["dispatches"]) == \
+        eng.dispatch_count
     # --trace UID stitches one request's spans and never meets a null uid
     assert report_main([with_dir, "--trace", "0"]) == 0
     capsys.readouterr()
     assert report_main([with_dir]) == 0
     text = capsys.readouterr().out
     assert "step phases:" in text and "decode.readback" in text
+    assert "program (bucket)" in text and "mixed (3)" in text
     assert "per-request waterfalls" in text
+
+
+def test_report_prints_dispatches_by_kind_and_bucket(tmp_path, capsys):
+    """The operator's view of ``dispatches``: per step program, by kind
+    and bucket, its runs and the time from its launch to the end of its
+    read, the tail's two programs of one step told apart."""
+    from distributed_llm_code_samples_tpu.report import report_main
+    ms = 1_000_000
+
+    def step(n, programs):
+        t, phases = n * 100 * ms, []
+        for kind, _, took in programs:
+            phases += [[kind + ".dispatch", t, t + ms],
+                       [kind + ".readback", t + ms, t + took * ms]]
+            t += (took + 1) * ms
+        return _step_record(
+            start_step=n, step=n, start_ns=n * 100 * ms, end_ns=t,
+            duration_s=(t - n * 100 * ms) / 1e9, tokens_generated=n,
+            phases=phases, dispatches=[[k, b] for k, b, _ in programs])
+    mdir = str(tmp_path / "m")
+    with TelemetryWriter(mdir) as w:
+        w.span(step(1, [("mixed", 12, 10)]))
+        w.span(step(2, [("prefill", 4, 4), ("decode", 8, 9)]))
+        w.span(step(3, [("mixed", 12, 12)]))
+        w.span(step(4, [("decode", 8, 9)]))
+    capsys.readouterr()
+    assert report_main([mdir, "--json"]) == 0
+    doc = json.loads(capsys.readouterr().out)
+    assert doc["problems"] == []
+    rows = {(d["kind"], d["bucket"]): d
+            for d in doc["step_phases"]["dispatches"]}
+    assert {k: (d["count"], d["mean_ms"]) for k, d in rows.items()} == {
+        ("mixed", 12): (2, 11.0), ("decode", 8): (2, 9.0),
+        ("prefill", 4): (1, 4.0)}
+    assert report_main([mdir]) == 0
+    text = capsys.readouterr().out
+    for line in ("mixed (12)", "decode (8)", "prefill (4)"):
+        assert line in text
 
 
 def test_phase_timer_keeps_nothing_until_begun():

@@ -66,9 +66,10 @@ def _spy(eng):
     seen = []
     real = eng._dispatch
 
-    def dispatch(phase, fn, params, operand):
+    def dispatch(phase, asked, fn, params, operand):
         (kind, bucket), = [k for k, v in eng._programs.items() if v is fn]
-        result = real(phase, fn, params, operand)
+        assert asked == bucket      # what the step's ``dispatches`` notes
+        result = real(phase, asked, fn, params, operand)
         fields = {k: np.asarray(v) for k, v in
                   eng.programs.wire(kind, bucket).unpack(operand).items()}
         seen.append((kind, bucket, fields, result))

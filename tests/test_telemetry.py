@@ -111,7 +111,12 @@ from distributed_llm_code_samples_tpu.runtime.telemetry import (
 # (one record per executed engine step: null uid, refused under any
 # other span name; pins ``phases`` + ``start_ns`` / ``end_ns``,
 # STEP_SPAN_REQUIRED).
-_PINNED_VERSION = 18
+# v19 (PR 37): every dispatch says which program it ran — the
+# ``engine_step`` record pins ``dispatches`` (``[kind, bucket]`` a step
+# program launched, one entry a ``*.dispatch`` phase, in order).
+_PINNED_VERSION = 19
+_PINNED_STEP_SPAN_REQUIRED = frozenset({"phases", "start_ns", "end_ns",
+                                        "dispatches"})
 _PINNED_STEP_KEYS = frozenset({
     "schema", "kind", "t", "step", "strategy", "loss", "grad_norm",
     "tokens_per_sec", "step_time_s", "mfu", "hbm_high_water_bytes",
@@ -195,8 +200,9 @@ def test_schema_version_bump_discipline():
         REQUEST_COMPLETED_REQUIRED, REQUEST_REQUIRED, REQUIRED_KEYS,
         ROLLBACK_REQUIRED, ROUTER_EVENTS, ROUTER_MIGRATED_REQUIRED,
         ROUTER_MOVE_REQUIRED, ROUTER_REQUIRED, SPAN_REQUIRED,
-        WORKLOAD_REQUIRED)
+        STEP_SPAN_REQUIRED, WORKLOAD_REQUIRED)
     assert SCHEMA_VERSION == _PINNED_VERSION and \
+        frozenset(STEP_SPAN_REQUIRED) == _PINNED_STEP_SPAN_REQUIRED and \
         frozenset(STEP_KEYS) == _PINNED_STEP_KEYS and \
         frozenset(ANOMALY_REQUIRED) == _PINNED_ANOMALY_REQUIRED and \
         frozenset(ROLLBACK_REQUIRED) == _PINNED_ROLLBACK_REQUIRED and \
@@ -310,6 +316,69 @@ def test_anomaly_and_rollback_records_round_trip(tmp_path):
     ok, reason = validate_record({"schema": SCHEMA_VERSION,
                                   "kind": "rollback", "t": 0.0})
     assert not ok and "rung" in reason
+
+
+def _engine_step(**over):
+    """A v19 ``engine_step`` record as ``engine._step_record`` builds
+    it: a tail chunk's program, then the batch's."""
+    rec = {"uid": None, "span": "engine_step", "start_step": 7, "step": 7,
+           "start_ns": 1_000, "end_ns": 9_000, "t": 9e-6,
+           "duration_s": 8e-6, "tokens_generated": 40, "state_bytes": 0,
+           "expert_rows": 0, "experts_touched": 0, "expert_rows_max": 0,
+           "phases": [["admit", 1_000, 1_100],
+                      ["prefill.dispatch", 1_200, 1_300],
+                      ["prefill.readback", 1_300, 4_000],
+                      ["decode.dispatch", 4_200, 4_300],
+                      ["decode.readback", 4_300, 8_000]],
+           "dispatches": [["prefill", 4], ["decode", 8]]}
+    rec.update(over)
+    return rec
+
+
+def test_engine_step_v19_round_trips(tmp_path):
+    """The record goes through the writer and comes back schema-valid
+    with ``dispatches`` entry for entry beside its phases."""
+    w = TelemetryWriter(str(tmp_path))
+    w.span(_engine_step())
+    w.span(_engine_step(step=8, start_step=8, phases=[], dispatches=[]))
+    w.close()
+    records, problems = read_metrics(os.path.join(str(tmp_path),
+                                                  METRICS_FILENAME))
+    assert problems == []
+    first, idle = records
+    assert first["schema"] == SCHEMA_VERSION == 19
+    assert first["dispatches"] == [["prefill", 4], ["decode", 8]]
+    assert [p[0] for p in first["phases"] if p[0].endswith(".dispatch")] \
+        == [k + ".dispatch" for k, _ in first["dispatches"]]
+    assert idle["dispatches"] == []
+
+
+@pytest.mark.parametrize("case,named", [
+    ("v18_stamp", "schema"),            # an older writer's record
+    ("no_dispatches", "dispatches"),    # v18's key set under a v19 stamp
+    ("one_entry_short", "dispatches"),
+    ("one_entry_over", "dispatches"),
+])
+def test_engine_step_v18_shapes_are_refused(case, named):
+    """What schema v18 wrote is refused as the contract says: by the
+    version stamp, and under a v19 stamp by the missing ``dispatches``;
+    entries that do not match the ``*.dispatch`` phases one to one are
+    no record of what the step launched."""
+    rec = dict(_engine_step(), schema=SCHEMA_VERSION, kind="span",
+               trace_id=None, tenant=None)
+    ok, reason = validate_record(rec)
+    assert ok, reason
+    if case == "v18_stamp":
+        rec["schema"] = 18
+        del rec["dispatches"]
+    elif case == "no_dispatches":
+        del rec["dispatches"]
+    elif case == "one_entry_short":
+        rec["dispatches"] = rec["dispatches"][:1]
+    else:
+        rec["dispatches"] = rec["dispatches"] + [["decode", 8]]
+    ok, reason = validate_record(rec)
+    assert not ok and named in reason and "\n" not in reason
 
 
 def test_span_record_round_trip_and_torn_tail(tmp_path):
