@@ -32,12 +32,17 @@ wrappers):
 This module is the SCHEDULER. What a step program is made of is
 ``decode/programs.py``'s, built from the model's face (``models/face.py``:
 a family is one file) and the cache (``decode/paged.py``); the engine
-calls ``_program(kind, bucket)(params, cache, operand) -> (cache,
+calls ``_program(kind, bucket)(params, carry, operand) -> (carry,
 result)`` — ONE packed ``int32`` vector in (``programs.pack``: one
 host-to-device transfer a dispatch), ONE packed ``int32`` array out
 (one blocking read; a row whose logits were not finite reads negative)
 — and reads sizes only from the model: ``vocab``,
-``max_seq_len``, the kinds of its ``layers``, its ``cache_spec``. A
+``max_seq_len``, the kinds of its ``layers``, its ``cache_spec``. The
+blocking read of a step's program comes AFTER the launch of the next
+step's (``_launch`` / ``_collect``; ``step``'s docstring says which
+reads wait and what a caller may read when): the scheduler works from
+counts it has at launch, and a slot's next token is handed from one
+program to the next on the device (the carry's token store). A
 recurrent layer's state lives beside the pool, by slot; what cannot
 carry it yet — prefix hits, speculation, a mesh, the KV handoff,
 snapshots, the spill tier — refuses in one line for such a model
@@ -162,6 +167,7 @@ import collections
 import dataclasses
 import os
 import time
+from collections.abc import Callable
 from dataclasses import dataclass, field
 
 import jax
@@ -181,7 +187,7 @@ from .draft import draft_tokens
 from .paged import (SCRATCH_BLOCK, corrupt_block as _pool_corrupt_block,
                     extract_blocks, kv_bytes_per_token, pool_bytes,
                     scrub_blocks)
-from .programs import POISON_ALL, POISON_NONE, StepPrograms
+from .programs import FROM_SLOT, POISON_ALL, POISON_NONE, StepPrograms
 from .prefix import PrefixCache
 from .spill import SpillTier
 from .sampling import check_sampling, check_speculation
@@ -423,6 +429,12 @@ class _Seq:
     # the prefill frontier).
     nodes: list = field(default_factory=list)
     emitted: int = 0
+    # the ``out`` tokens whose producing row has been LAUNCHED since the
+    # last (re)admission: ``emitted``, or one more while that row's
+    # result is still unread (``DecodeEngine._collect``). What a launch
+    # needs of a sequence is a function of this count; the token values
+    # follow when they land
+    launched: int = 0
     retries: int = 0
     submit_step: int = 0
     admit_index: int = -1
@@ -459,13 +471,45 @@ class _Seq:
     def finished(self) -> bool:
         return len(self.out) >= self.max_new and not self.replaying
 
+    @property
+    def all_launched(self) -> bool:
+        """The row that produces the last token has been launched: no
+        further row is (``finished`` follows when it lands; ``out``
+        never outgrows ``max_new``, so a replay ends here too)."""
+        return self.launched >= self.max_new
+
+    @property
+    def next_token(self) -> int:
+        """The input of the sequence's next decode row: the recorded or
+        landed token, or ``FROM_SLOT`` while the row that produces it is
+        still in flight (its pick is in the slot's entry of the device's
+        token store then)."""
+        n = self.launched
+        return self.out[n - 1] if n <= len(self.out) else FROM_SLOT
+
+
+@dataclasses.dataclass
+class _Launch:
+    """One launched step program whose result the host has not read
+    yet (``DecodeEngine._launch`` / ``_collect``)."""
+    ordinal: int            # which of the engine's launches it is
+    phase: str              # the phase prefix: prefill / decode / mixed
+    kind: str               # the program's kind (``dispatches`` has it)
+    result: jax.Array       # the packed result, still on the device
+    land: Callable          # picks -> the rows' finite flags
+    # the flight digest of the step that launched it, once that step
+    # has closed: where its rows' finite flags go when they land later
+    digest: dict | None = None
+
 
 class DecodeEngine:
     """The serving loop. ``submit()`` queues prompts; ``step()`` runs one
     scheduler iteration (admit -> at most one prefill chunk -> one decode
-    dispatch over every ready slot); ``run()`` drains everything and
-    returns ``{uid: full token list}``. See the module docstring for the
-    design; DESIGN.md section 15 for the state machine."""
+    dispatch over every ready slot, then the read of the LAST step's
+    result); ``collect()`` reads what a step left unread; ``run()``
+    drains everything and returns ``{uid: full token list}``. See the
+    module docstring for the design; DESIGN.md section 15 for the state
+    machine."""
 
     def __init__(self, params: ServedModel, n_heads: int,
                  config: EngineConfig | None = None, mesh=None,
@@ -570,10 +614,18 @@ class DecodeEngine:
         # (None for a model that has none): donated into the step
         # programs together and updated in place
         self.pool, self.state = self.programs.init_cache()
+        # each slot's next token, on the device beside them (and one
+        # scratch row): a row's pick is handed to the slot's next row
+        # there, so a step can be launched before the last one is read
+        self.token_store = self.programs.init_tokens()
+        # the ONE launched step program whose result is still unread
+        # (``_launch`` / ``_collect``), and how many were launched
+        self._inflight: _Launch | None = None
+        self.launches = 0
         s, mb = cfg.max_slots, cfg.max_blocks_per_seq
         self.tables = np.full((s, mb), SCRATCH_BLOCK, np.int32)
+        # cached positions by slot, counted as rows are LAUNCHED
         self.lengths = np.zeros((s,), np.int32)
-        self.next_token = np.zeros((s,), np.int32)
         self.uids = np.zeros((s,), np.int32)
         self.slots: list[_Seq | None] = [None] * s
         self.waiting: collections.deque[_Seq] = collections.deque()
@@ -710,9 +762,13 @@ class DecodeEngine:
         # record's and the digest's ``state_bytes``
         self._step_state_bytes = 0
         # the step programs this step launched, ``[kind, bucket]`` in
-        # launch order (``_dispatch``): the engine_step record's and the
+        # launch order (``_launch``): the engine_step record's and the
         # digest's ``dispatches``
         self._step_dispatches: list[list] = []
+        # the launches whose results this step read, by ordinal, the
+        # i-th the i-th ``*.readback`` phase's: the record's and the
+        # digest's ``readbacks``
+        self._step_readbacks: list[int] = []
         # the expert layers' counters of this step's dispatches, as the
         # step programs returned them (``[expert_layers, n_experts]``
         # each; none for a model with no expert layer), folded in the
@@ -738,15 +794,21 @@ class DecodeEngine:
                 "their recurrent state yet")
 
     def _cache(self):
-        """The donated operand of every step program: the pool, and for
-        a model with recurrent layers the pair (pool, recurrent
-        state)."""
+        """What a model's forward reads and writes of a sequence: the
+        pool, and for a model with recurrent layers the pair (pool,
+        recurrent state)."""
         return self.pool if self.state is None else (self.pool,
                                                       self.state)
 
-    def _keep(self, cache) -> None:
-        """Take back what a step program returned in ``_cache()``'s
+    def _carry(self):
+        """The donated operand of every step program: ``_cache()`` and
+        the slots' token store."""
+        return self._cache(), self.token_store
+
+    def _keep(self, carry) -> None:
+        """Take back what a step program returned in ``_carry()``'s
         place."""
+        cache, self.token_store = carry
         if self.state is None:
             self.pool = cache
         else:
@@ -916,6 +978,7 @@ class DecodeEngine:
                 "KV handoff is single-device (the fleet runs "
                 "single-device replicas; TP engines keep the "
                 "whole-engine snapshot path)")
+        self._collect()     # the document holds token VALUES
         slot = next((i for i, s in enumerate(self.slots)
                      if s is not None and s.uid == uid), None)
         if slot is None:
@@ -960,7 +1023,7 @@ class DecodeEngine:
             "retries": int(seq.retries),
             "t_submit": float(seq.t_submit),
             "position": pos,
-            "next_token": int(self.next_token[slot]),
+            "next_token": int(seq.next_token),
             # the first-token mark travels with the sequence (handoff
             # v2) so the importing engine's completed record reports
             # the TRUE ttft_s, not a restarted clock
@@ -997,6 +1060,7 @@ class DecodeEngine:
         reported (``finished`` / ``failed`` / ``waiting`` / ``gone``)
         and NOTHING is evicted — the request never left this engine,
         and the target discards its staged copy."""
+        self._collect()     # the delta is token VALUES
         slot = next((i for i, s in enumerate(self.slots)
                      if s is not None and s.uid == uid), None)
         if slot is None:
@@ -1115,7 +1179,9 @@ class DecodeEngine:
         self._pins[uid] = ver
         self._traces[uid] = seq.trace_id
         self._tenants[uid] = seq.tenant
-        seq.emitted = int(doc["emitted"])
+        # its next row takes ``out[emitted - 1]`` from the host (the
+        # document's ``next_token``): the token store never held it
+        seq.emitted = seq.launched = int(doc["emitted"])
         seq.t_submit = float(doc["t_submit"])
         seq.prefilled = len(prompt)
         seq.blocks = blocks
@@ -1125,7 +1191,6 @@ class DecodeEngine:
         row[:need] = blocks
         self.tables[slot] = row
         self.lengths[slot] = int(doc["position"])
-        self.next_token[slot] = int(doc["next_token"])
         self.uids[slot] = uid
         self.slots[slot] = seq
         seq.admit_index = self._admit_counter
@@ -1146,7 +1211,7 @@ class DecodeEngine:
         # cross-engine prefix reuse: the imported full prompt blocks
         # enter THIS engine's radix tree (late dedup applies — a local
         # twin already cached wins and the duplicate frees)
-        self._cache_full_blocks(slot)
+        self._cache_full_blocks(slot, len(prompt))
         return uid
 
     def release_request(self, uid: int) -> dict:
@@ -1160,6 +1225,7 @@ class DecodeEngine:
         teacher-forces on the PINNED version, so the moved request's
         remaining tokens stay bit-identical to its unmoved oracle."""
         uid = int(uid)
+        self._collect()     # the entry holds ``out``
         seq = None
         for i, s in enumerate(self.waiting):
             if s.uid == uid:
@@ -1457,7 +1523,7 @@ class DecodeEngine:
         work = max_new
         for s in self.slots:
             if s is not None:
-                work += max(s.max_new - len(s.out), 0)
+                work += max(s.max_new - max(len(s.out), s.launched), 0)
         for s in self.waiting:
             work += s.max_new
         chunks = -(-prompt_len // self.cfg.prefill_chunk)
@@ -1578,6 +1644,13 @@ class DecodeEngine:
                           - sum(1 for n in resident if n.refs == 0))
             if need - n_res > avail:
                 pa = self.policy.preempt_after_steps
+                if pa > 0 and self._inflight is not None:
+                    # a starved head is judged, and a victim replayed
+                    # from ``prompt + out``, on what has LANDED: the
+                    # unread result may finish a sequence and free what
+                    # the head needs
+                    self._collect()
+                    continue
                 if pa > 0:
                     if self._head_blocked_uid != seq.uid:
                         # the streak belongs to ONE head: a new head
@@ -1799,11 +1872,13 @@ class DecodeEngine:
         self._step_restores += 1
         self._restores_left -= 1
 
-    def _cache_full_blocks(self, slot: int) -> None:
+    def _cache_full_blocks(self, slot: int, upto: int) -> None:
         """Transfer a slot's newly fully-prefilled FULL prompt blocks
         into the radix tree (the insert side of the prefix cache; runs
-        after every prefill chunk). Only blocks whose every row came
-        from prompt tokens are cacheable — a partial block's remaining
+        as every prefill chunk lands, ``upto`` the prompt tokens
+        prefilled up to and with that chunk: a chunk launched after it
+        and still unread inserts nothing yet). Only blocks whose every
+        row came from prompt tokens are cacheable — a partial block's remaining
         rows will be decode writes, making its content a function of
         the sampled continuation, not the prompt. The inserting
         sequence keeps using the block and holds one ref (its table
@@ -1817,7 +1892,7 @@ class DecodeEngine:
             return
         seq = self.slots[slot]
         bs = self.cfg.block_size
-        full = min(seq.prefilled, len(seq.prompt)) // bs
+        full = min(upto, len(seq.prompt)) // bs
         step = self.global_step
         while len(seq.nodes) < full:
             i = len(seq.nodes)
@@ -1928,7 +2003,6 @@ class DecodeEngine:
         seq.nodes = []
         self.tables[slot] = SCRATCH_BLOCK
         self.lengths[slot] = 0
-        self.next_token[slot] = 0
         self.uids[slot] = 0
         self.slots[slot] = None
         return seq
@@ -1961,7 +2035,7 @@ class DecodeEngine:
         ORIGINAL submission, so preemption/retry churn cannot extend a
         request's life past its deadline."""
         seq.prefilled = 0
-        seq.emitted = 0
+        seq.emitted = seq.launched = 0
         self.waiting.append(seq)
 
     def _preempt_youngest(self) -> bool:
@@ -2106,6 +2180,10 @@ class DecodeEngine:
         def overdue(seq: _Seq) -> bool:
             return self.global_step - seq.submit_step >= dl
 
+        if any(seq is not None and overdue(seq) for seq in self.slots):
+            # a request whose last token is in flight has finished, not
+            # expired, and an expiry's ``n_out`` counts landed tokens
+            self._collect()
         for slot, seq in enumerate(self.slots):
             if seq is not None and overdue(seq):
                 self._evict(slot)
@@ -2126,20 +2204,19 @@ class DecodeEngine:
         healthy replay — forcing just removes the need to assume it)."""
         seq = self.slots[slot]
         was_replaying = seq.replaying
-        if seq.replaying:
-            tok = seq.out[seq.emitted]
-        else:
-            tok = pick
-            seq.out.append(tok)
+        if not seq.replaying:
+            seq.out.append(pick)
             self.tokens_generated += 1
         seq.emitted += 1
+        # a row that was launched and read in one go (a verify's
+        # accepted drafts) counts as launched here
+        seq.launched = max(seq.launched, seq.emitted)
         # the WFQ virtual clock: every emission (live or teacher-
         # forced replay — a migrated request's service on THIS engine
         # counts as this engine's service) advances its tenant's
         # served-token count
         tk = tenant_key(seq.tenant)
         self._tenant_served[tk] = self._tenant_served.get(tk, 0) + 1
-        self.next_token[slot] = tok
         # the emission belongs to the CURRENT span (replay or decode
         # segment) — speculation makes steps multi-token, so span
         # records carry the count, not just the wall clock
@@ -2180,32 +2257,95 @@ class DecodeEngine:
         finally:
             jax.config.update("jax_compilation_cache_dir", old)
 
-    def _dispatch(self, phase: str, bucket: int, fn,
-                  params: ServedModel, operand: np.ndarray) -> np.ndarray:
-        """Launch one step program on its packed operand and read its
-        packed result (``decode/programs.py`` has the format): one
-        host-to-device transfer, the vector handed to the jitted call
-        as it is, and one blocking read, in the phases
-        ``<phase>.dispatch`` and ``<phase>.readback``. ``bucket`` is
-        the key ``fn`` was asked of ``_program`` under; the launch is
-        noted in the step's ``dispatches`` as ``[kind, bucket]``.
-        Returns the picks; an expert model's counters, which came on
-        the same read, are kept for the step's digest."""
+    def _launch(self, phase: str, bucket: int, fn, params: ServedModel,
+                operand: np.ndarray, land) -> None:
+        """Launch one step program on its packed operand
+        (``decode/programs.py`` has the format): one host-to-device
+        transfer, the vector handed to the jitted call as it is, in the
+        phase ``<phase>.dispatch``; the call returns at once and its
+        result stays on the device. ``bucket`` is the key ``fn`` was
+        asked of ``_program`` under; the launch is noted in the step's
+        ``dispatches`` as ``[kind, bucket]``. ``land(picks)`` is what
+        folds the result's picks into the scheduler, whenever they are
+        read (``_collect``). The launch BEFORE this one, if it is still
+        unread, is read now that the device has its next program
+        queued: at most one result is ever in flight."""
         # with speculation on every decode dispatch is a verify dispatch
         kind = ("verify" if phase == "decode" and self.cfg.speculate
                 else phase)
         self._step_dispatches.append([kind, bucket])
-        args = (params, self._cache(), operand)
+        args = (params, self._carry(), operand)
         self._maybe_capture(fn, *args)
         with self.phases.phase(phase + ".dispatch"):
-            cache, result = fn(*args)
-        with self.phases.phase(phase + ".readback"):
-            self._keep(cache)
-            result = np.asarray(result)
-        picks, rows = self.programs.split(kind, result)
+            carry, result = fn(*args)
+            self._keep(carry)
+        before, self._inflight = self._inflight, _Launch(
+            self.launches, phase, kind, result, land)
+        self.launches += 1
+        self._read(before)
+
+    def _collect(self) -> None:
+        """Read the result that is still in flight, if one is, and fold
+        it into the scheduler: after it ``seq.out``, ``finished``,
+        ``tokens_generated``, the tenant clocks and the request spans
+        say what every launched row produced. Whatever needs token
+        VALUES to go on calls this first."""
+        launch, self._inflight = self._inflight, None
+        self._read(launch)
+
+    def _read(self, launch: _Launch | None) -> None:
+        """One blocking read of ``launch``'s packed result, in the phase
+        ``<its phase>.readback`` (noted in the step's ``readbacks``),
+        then its ``land``. An expert model's counters, which came on
+        the same read, are kept for the step's digest; the rows' finite
+        flags go to the digest of the step that launched them."""
+        if launch is None:
+            return
+        with self.phases.phase(launch.phase + ".readback"):
+            result = np.asarray(launch.result)
+        self._step_readbacks.append(launch.ordinal)
+        picks, rows = self.programs.split(launch.kind, result)
         if rows is not None:
             self._step_expert_rows.append(rows)
-        return picks
+        flags = launch.land(picks)
+        if launch.digest is not None:
+            launch.digest["finite"] = (launch.digest["finite"] or []) + flags
+        else:
+            self._step_finite = (self._step_finite or []) + flags
+
+    def collect(self) -> None:
+        """Bring the host's view up to the device's: read the result of
+        the last launched step, if ``step()`` left it unread (its
+        docstring says when it does). For a caller that steps the
+        engine itself and looks at ``slots[i].out``, ``finished`` or
+        ``tokens_generated`` in between."""
+        self._collect()
+
+    def _row(self, slot: int) -> tuple:
+        """A launched row's owner: the slot, its sequence and WHICH
+        admission of it (a retried sequence may be back in its old slot
+        when the row lands)."""
+        seq = self.slots[slot]
+        return slot, seq, seq.admit_index
+
+    def _holds(self, row: tuple) -> bool:
+        """Whether the sequence a row was launched for still holds its
+        slot under that admission; the row is dropped otherwise."""
+        slot, seq, admitted = row
+        return self.slots[slot] is seq and seq.admit_index == admitted
+
+    def _land(self, chunk: tuple | None, rows: list[tuple], picks) -> list:
+        """Fold a read result into the scheduler: the chunk's last
+        pick, then the batch's. Returns the finite flags in that
+        order."""
+        flags = []
+        if chunk is not None:
+            with self.phases.phase("prefill.book"):
+                flags.append(self._prefill_book(*chunk, int(picks[-1])))
+        if rows:
+            with self.phases.phase("decode.emit"):
+                flags += self._emit_batch(rows, picks)
+        return flags
 
     def _prefill_step(self, slot: int) -> None:
         seq = self.slots[slot]
@@ -2219,11 +2359,10 @@ class DecodeEngine:
             operand = self.programs.pack(
                 "prefill", c, poison=self._poison_uid,
                 **self._chunk_fields(slot, seq, c, "tokens"))
-        result = self._dispatch(
+            chunk = self._count_chunk(slot, seq, c)
+        self._launch(
             "prefill", c, fn, self._params_for(seq.weights_version),
-            operand)
-        with phase("prefill.book"):
-            self._prefill_book(slot, seq, c, int(result[0]))
+            operand, lambda picks: self._land(chunk, [], picks))
 
     def _cow_chunk(self, slot: int, seq: _Seq, c: int) -> None:
         """The CoW write barrier over the blocks ``seq``'s next chunk
@@ -2236,14 +2375,11 @@ class DecodeEngine:
                       tokens: str) -> dict:
         """One slot's chunk in a step program's operand: its table,
         start, the next ``c`` prompt tokens (under the name the program
-        gives them), uid and, where the model has recurrent layers, the
-        slot's state row."""
-        fields = {"table": self.tables[slot], "pos0": seq.prefilled,
-                  tokens: seq.prompt[seq.prefilled:seq.prefilled + c],
-                  "uid": seq.uid}
-        if self.state is not None:
-            fields["row"] = slot
-        return fields
+        gives them), uid and the slot itself (its entry of the token
+        store and its state row)."""
+        return {"table": self.tables[slot], "pos0": seq.prefilled,
+                tokens: seq.prompt[seq.prefilled:seq.prefilled + c],
+                "uid": seq.uid, "row": slot}
 
     def _prefill_chunk(self, seq: _Seq) -> int:
         """The next chunk's size for ``seq``."""
@@ -2264,23 +2400,34 @@ class DecodeEngine:
             c = max(b for b in self.chunk_buckets if b <= min(c, gap))
         return c
 
-    def _prefill_book(self, slot: int, seq: _Seq, c: int,
-                      nxt: int) -> None:
-        """Fold a dispatched chunk's result (its last row's folded
-        pick) into the slot."""
-        fine = nxt >= 0
-        # the pick is used only where the chunk completes the prompt
-        pick = (nxt if fine and seq.prefilled + c == len(seq.prompt)
-                else None)
+    def _count_chunk(self, slot: int, seq: _Seq, c: int) -> tuple:
+        """Advance the COUNTS of a chunk that is about to be launched:
+        the prompt tokens prefilled and, where it completes the prompt,
+        the slot's length and the first launched token, so the slot's
+        first decode row can follow in the next program. Returns what
+        ``_prefill_book`` takes when the chunk's pick lands."""
         self._step_prefill_uid = seq.uid
-        self._step_finite = [fine]
-        if not fine:
-            self._quarantine(slot, "nonfinite_logits")
-            return
+        row = self._row(slot)
         seq.prefilled += c
-        self._cache_full_blocks(slot)
         if seq.prompt_done:
             self.lengths[slot] = len(seq.prompt)
+            seq.launched = 1
+        return row, c, seq.prefilled
+
+    def _prefill_book(self, row: tuple, c: int, end: int,
+                      nxt: int) -> bool:
+        """Fold a launched chunk's result (its last row's folded pick)
+        into the slot: ``c`` tokens that brought the prompt to ``end``.
+        Returns its finite flag."""
+        slot, seq, _ = row
+        fine = nxt >= 0
+        if not self._holds(row):
+            return fine         # quarantined or expired meanwhile
+        if not fine:
+            self._quarantine(slot, "nonfinite_logits")
+            return fine
+        self._cache_full_blocks(slot, end)
+        if end == len(seq.prompt):
             # the chunk that completes the prompt hands the span clock
             # to the next phase BEFORE the emit below may release the
             # sequence outright (max_new == 1). ONE timestamp serves
@@ -2298,12 +2445,14 @@ class DecodeEngine:
             self.tracer.transition(
                 seq.uid, "replay" if seq.replaying else "decode",
                 self.global_step, t=now, tokens=c)
-            self._emit(slot, pick)
+            # the pick is used only where the chunk completes the prompt
+            self._emit(slot, nxt)
         else:
             # one span per prefill chunk, telescoping across the engine
             # steps spent on other slots in between
             self.tracer.transition(seq.uid, "prefill", self.global_step,
                                    tokens=c)
+        return fine
 
     def _marshal(self, ready: list[int], b: int | None = None):
         """Bucket-pad the dispatch operands for ``ready`` (to ``b``
@@ -2311,18 +2460,19 @@ class DecodeEngine:
         them): pad rows point at the scratch block with zeroed
         length/token/uid, so their writes land in the pad row's
         designated dump and their idle uid never matches a poison
-        operand."""
+        operand. A row's token is the host's where the host has it
+        (``_Seq.next_token``)."""
         if b is None:
             b = _bucket_for(len(ready), self.slot_buckets)
         idx = ready + [0] * (b - len(ready))        # pad rows
         tables = self.tables[idx].copy()
         lengths = self.lengths[idx].copy()
-        tokens = self.next_token[idx].copy()
+        tokens = ([self.slots[slot].next_token for slot in ready]
+                  + [0] * (b - len(ready)))
         uids = self.uids[idx].copy()
         for j in range(len(ready), b):              # pads -> scratch
             tables[j] = SCRATCH_BLOCK
             lengths[j] = 0
-            tokens[j] = 0
             uids[j] = 0
         return b, tables, lengths, tokens, uids
 
@@ -2353,29 +2503,39 @@ class DecodeEngine:
                       tokens, uids) -> dict:
         """A marshalled decode batch in a step program's operand, with
         the poison; counts the state bytes its rows read."""
-        fields = dict(tables=tables, lengths=lengths, tokens=tokens,
-                      uids=uids, poison=self._poison_uid)
+        # each batch row's slot (its entry of the token store and its
+        # state row), and the scratch row for the bucket's padded rows
+        rows = ready + [self.cfg.max_slots] * (b - len(ready))
         if self.state is not None:
-            # each batch row's state row: its slot, and the scratch
-            # row for the bucket's padded rows
-            fields["rows"] = ready + [self.state.scratch_row] * (
-                b - len(ready))
             self._step_state_bytes += (len(ready)
                                        * self.state.bytes_per_slot)
-        return fields
+        return dict(tables=tables, lengths=lengths, tokens=tokens,
+                    uids=uids, poison=self._poison_uid, rows=rows)
 
-    def _emit_batch(self, ready: list[int], picks) -> None:
-        """Fold a decode batch's folded picks into its slots."""
-        self._step_decode_uids += [self.slots[s].uid for s in ready]
-        flags = (picks[:len(ready)] >= 0).tolist()
-        self._step_finite = (flags if self._step_finite is None
-                             else self._step_finite + flags)
-        for j, slot in enumerate(ready):
-            if not flags[j]:     # pad rows are never in `ready`
-                self._quarantine(slot, "nonfinite_logits")
+    def _count_batch(self, ready: list[int]) -> list[tuple]:
+        """Advance the COUNTS of a decode batch that is about to be
+        launched: each slot's length and its sequence's launched
+        tokens. Returns the rows ``_emit_batch`` takes when the picks
+        land."""
+        rows = [self._row(slot) for slot in ready]
+        self.lengths[ready] += 1
+        for _, seq, _ in rows:
+            seq.launched += 1
+        self._step_decode_uids += [seq.uid for _, seq, _ in rows]
+        return rows
+
+    def _emit_batch(self, rows: list[tuple], picks) -> list[bool]:
+        """Fold a launched decode batch's folded picks into its slots;
+        returns the rows' finite flags."""
+        flags = (picks[:len(rows)] >= 0).tolist()
+        for j, row in enumerate(rows):  # pad rows are never in `rows`
+            if not self._holds(row):
+                continue        # quarantined or expired meanwhile
+            if not flags[j]:
+                self._quarantine(row[0], "nonfinite_logits")
                 continue
-            self.lengths[slot] += 1
-            self._emit(slot, int(picks[j]))
+            self._emit(row[0], int(picks[j]))
+        return flags
 
     def _decode_dispatch(self, ready: list[int]) -> None:
         phase = self.phases.phase
@@ -2389,11 +2549,18 @@ class DecodeEngine:
         with phase("decode.upload"):
             operand = self.programs.pack(
                 "decode", b, **self._batch_fields(ready, b, *batch))
-        picks = self._dispatch("decode", b, fn, params, operand)
-        with phase("decode.emit"):
-            self._emit_batch(ready, picks)
+            rows = self._count_batch(ready)
+        self._launch("decode", b, fn, params, operand,
+                     lambda picks: self._land(None, rows, picks))
 
     # -- the chunk rides with the batch ---------------------------------
+
+    def _ready(self) -> list[int]:
+        """The slots whose next decode row can be launched: the prompt
+        prefilled (its last chunk launched, at least) and a token still
+        to produce that no launched row is producing already."""
+        return [i for i, s in enumerate(self.slots)
+                if s is not None and s.prompt_done and not s.all_launched]
 
     def _mixed_batch(self, pre: int | None,
                      prefill_only: bool) -> list[int]:
@@ -2416,8 +2583,7 @@ class DecodeEngine:
         seq = self.slots[pre]
         if self._prefill_chunk(seq) != self.cfg.prefill_chunk:
             return []
-        ready = [i for i, s in enumerate(self.slots)
-                 if s is not None and s.prompt_done]
+        ready = self._ready()
         if any(self.slots[i].weights_version != seq.weights_version
                for i in ready):
             return []
@@ -2427,10 +2593,11 @@ class DecodeEngine:
         """One dispatch for the step's chunk (``slot``) and its decode
         batch (``ready``): the host halves of ``_prefill_step`` and
         ``_decode_dispatch`` under their own phase names round ONE
-        ``mixed.upload`` / ``.dispatch`` / ``.readback``. Counted as one
-        dispatch that carried a chunk. A prompt the chunk completes
-        emits its first token here and joins the batch in the next
-        step."""
+        ``mixed.upload`` / ``.dispatch`` and, whenever the result is
+        read, ``mixed.readback`` / ``prefill.book`` / ``decode.emit``.
+        Counted as one dispatch that carried a chunk. A prompt the
+        chunk completes joins the batch in the next step; its first
+        token is emitted when the result lands."""
         seq = self.slots[slot]
         phase = self.phases.phase
         c = self.cfg.prefill_chunk
@@ -2447,13 +2614,11 @@ class DecodeEngine:
             operand = self.programs.pack(
                 "mixed", b, **self._batch_fields(ready, b, *batch),
                 **self._chunk_fields(slot, seq, c, "chunk"))
-        picks = self._dispatch(
+            chunk = self._count_chunk(slot, seq, c)
+            rows = self._count_batch(ready)
+        self._launch(
             "mixed", b, fn, self._params_for(seq.weights_version),
-            operand)
-        with phase("prefill.book"):
-            self._prefill_book(slot, seq, c, int(picks[-1]))
-        with phase("decode.emit"):
-            self._emit_batch(ready, picks)
+            operand, lambda picks: self._land(chunk, rows, picks))
 
     # -- speculative decoding (DESIGN.md section 18) -------------------
 
@@ -2526,27 +2691,31 @@ class DecodeEngine:
                 "verify", b, tables=tables, lengths=lengths, tokens=tokens,
                 uids=uids, poison=self._poison_uid, drafts=drafts,
                 dlens=dlens)
-        result = self._dispatch("decode", b, fn, params, operand)
-        with phase("decode.emit"):
-            picks, acc = result[:, :k + 1], result[:, k + 1]
-            ok = picks >= 0
-            self._step_decode_uids += [self.slots[s].uid for s in ready]
-            flags = []
-            for j, slot in enumerate(ready):
-                m = int(acc[j])
-                fine = bool(ok[j, :m + 1].all())
-                flags.append(fine)
-                if not fine:
-                    self._quarantine(slot, "nonfinite_logits")
-                    continue
-                self.accepted_tokens += max(0, m - int(replayed[j]))
-                self.lengths[slot] += m + 1
-                for t in range(m + 1):
-                    if self.slots[slot] is None:
-                        break       # released at its final emission
-                    self._emit(slot, int(picks[j, t]))
-            self._step_finite = (flags if self._step_finite is None
-                                 else self._step_finite + flags)
+        self._step_decode_uids += [self.slots[s].uid for s in ready]
+
+        def land(result) -> list[bool]:
+            with phase("decode.emit"):
+                picks, acc = result[:, :k + 1], result[:, k + 1]
+                ok = picks >= 0
+                flags = []
+                for j, slot in enumerate(ready):
+                    m = int(acc[j])
+                    fine = bool(ok[j, :m + 1].all())
+                    flags.append(fine)
+                    if not fine:
+                        self._quarantine(slot, "nonfinite_logits")
+                        continue
+                    self.accepted_tokens += max(0, m - int(replayed[j]))
+                    self.lengths[slot] += m + 1
+                    for t in range(m + 1):
+                        if self.slots[slot] is None:
+                            break   # released at its final emission
+                        self._emit(slot, int(picks[j, t]))
+            return flags
+
+        # the accepted count sets the lengths: no verify's read waits
+        # (``_may_defer``), so ``land`` runs before the step ends
+        self._launch("decode", b, fn, params, operand, land)
 
     def step(self, prefill_only: bool = False) -> bool:
         """One scheduler iteration: expire deadlines, admit (with
@@ -2557,6 +2726,43 @@ class DecodeEngine:
         with the batch, else the chunk's and then the batch's. Returns
         whether any work ran. An armed chaos poison operand applies to
         exactly this step's dispatches.
+
+        **Launch, then collect.** A step launches its program and only
+        then reads the result of the program launched BEFORE it, so the
+        device always has the next program queued behind the running
+        one. What a launch needs of a sequence are counts the host has
+        at launch (``lengths``, the prompt tokens prefilled, the tokens
+        launched, the ready set, all advanced as a row is launched); a
+        row's pick reaches the slot's next row on the device (the token
+        store). The VALUES land one step late, together: ``seq.out``,
+        ``tokens_generated``, the tenant clock, ``finished[uid]``, the
+        request records and spans, a non-finite row's quarantine (its
+        row in the program launched meanwhile is dropped when it
+        lands), and the launching step's ``finite`` flags in its flight
+        digest. So **after ``step()`` returns a caller may read**
+        ``waiting``, ``active``, ``lengths``, ``steps``, the counters of
+        launches (``dispatch_count``, ``prefill_dispatches``) and of
+        everything that had landed; ``slots[i].out``, ``finished``,
+        ``failed`` and ``tokens_generated`` may lack the LAST launched
+        program's tokens until the next ``step()`` or ``collect()``.
+        ``active`` stays non-zero while a result is unread, so ``while
+        eng.active or eng.waiting: eng.step()`` ends with everything
+        landed; a step that only reads returns True.
+
+        Which read waits is decided by what the step can see, by no
+        field or flag (``_may_defer``): that of a ``decode`` or
+        ``mixed`` program on one weights version, while some resident
+        sequence still has a row to launch. Every other result is read
+        in the step that launched it, as before: a speculative verify
+        (the accepted count sets the lengths), the prefill tier's
+        chunks (``prefill_only``: the first pick is the router's), a
+        chunk with no batch to follow, several weights versions in one
+        step, the last program of a draining engine. And whatever needs
+        values reads first (``_collect``): ``export_sequence`` /
+        ``finish_export`` / ``release_request``, an overdue deadline,
+        pool-pressure preemption (a replay needs ``prompt + out``),
+        ``telemetry_record``, ``dump_flight_recorder``, ``run()``'s
+        return.
 
         ``prefill_only`` skips the decode dispatch — the fleet's
         prefill tier (``decode/fleet.py``): a prompt that completes
@@ -2572,7 +2778,19 @@ class DecodeEngine:
         _, start_ns, end_ns = phases.stamps.pop()   # the parent closed last
         if did and self.metrics is not None:
             self.metrics.span(self._step_record(start_ns, end_ns))
+        phases.end()
         return did
+
+    def _may_defer(self, groups: int) -> bool:
+        """Whether the read of the program this step launched last may
+        wait for the next step's launch: a ``decode`` or ``mixed``
+        program (a verify's accepted counts and the prefill tier's
+        picks are values the host goes on with), one weights version
+        in the step, and a resident sequence with a row still to
+        launch (with none, nothing would be queued behind it)."""
+        return (self._inflight.kind in ("decode", "mixed") and groups <= 1
+                and any(s is not None and not s.all_launched
+                        for s in self.slots))
 
     def _step(self, prefill_only: bool) -> bool:
         """``step``'s body, every part of it inside one phase of
@@ -2587,6 +2805,7 @@ class DecodeEngine:
         self._step_decode_uids = []
         self._step_state_bytes = 0
         self._step_dispatches = []
+        self._step_readbacks = []
         self._step_expert_rows = []
         with phase("expire"):
             # spill-tier housekeeping: a fresh promotion budget each
@@ -2607,15 +2826,16 @@ class DecodeEngine:
                         if s is not None and not s.prompt_done), None)
         with phase("decode.marshal"):
             riders = self._mixed_batch(pre, prefill_only)
-        did = pre is not None
         if riders:
             self._mixed_dispatch(pre, riders)
         elif pre is not None:
             self._prefill_step(pre)
+        if self.cfg.speculate:
+            # a draft is a function of ``prompt + out``: the step's
+            # chunk has to have landed before its slot is drafted for
+            self._collect()
         with phase("decode.marshal"):
-            ready = ([] if prefill_only or riders else
-                     [i for i, s in enumerate(self.slots)
-                      if s is not None and s.prompt_done])
+            ready = [] if prefill_only or riders else self._ready()
             groups = self._version_groups(ready)
         # speculation on -> every decode dispatch is a verify dispatch
         # (one program kind per bucket; a zero-draft step degenerates to
@@ -2625,7 +2845,13 @@ class DecodeEngine:
                     else self._decode_dispatch)
         for group in groups:
             dispatch(group)
-            did = True
+        # the result in flight is this step's last launch (a launch
+        # reads the one before it), or an earlier step's where this one
+        # launched nothing: read it now unless it may wait
+        if self._inflight is not None and not (
+                self._step_dispatches and self._may_defer(len(groups))):
+            self._collect()
+        did = bool(self._step_dispatches or self._step_readbacks)
         with phase("digest"):
             self._step_experts = self._fold_expert_rows()
             if self._step_restores:
@@ -2646,7 +2872,11 @@ class DecodeEngine:
             if did or self._step_events:
                 # a dispatch-free step that only expired/shed requests
                 # is still a scheduler decision the post-mortem needs
-                self.flight.append(self._flight_digest())
+                digest = self._flight_digest()
+                self.flight.append(digest)
+                if self._inflight is not None:
+                    # its rows' finite flags join this digest later
+                    self._inflight.digest = digest
                 self._step_events = []
             if self._dump_reason is not None:
                 # a quarantine happened this step: dump now that the
@@ -2658,11 +2888,17 @@ class DecodeEngine:
 
     def _step_record(self, start_ns: int, end_ns: int) -> dict:
         """The executed step as ONE ``engine_step`` span record
-        (telemetry v19): the parent span and its phases in the order
-        they closed (each a child by being in this list), and the step
+        (telemetry v20): the parent span and its phases in the order
+        they closed (each a child by being in this list), the step
         programs it launched (``dispatches``: the i-th entry belongs to
-        the i-th ``*.dispatch`` phase and the ``*.readback`` after it).
-        ``tokens_generated`` is what a reader joins a step on."""
+        the i-th ``*.dispatch`` phase) and the launches whose results
+        it read (``readbacks``: the i-th entry the ordinal of the
+        launch the i-th ``*.readback`` phase read, which may lie in an
+        earlier step's record; ``launches`` counts the engine's
+        launches up to and with this step's, so the record's own are
+        the last ``len(dispatches)`` ordinals below it). The expert
+        counters are those of the results READ. ``tokens_generated`` is
+        what a reader joins a step on."""
         return {
             "uid": None,
             "span": STEP_SPAN,
@@ -2677,11 +2913,14 @@ class DecodeEngine:
             "state_bytes": self._step_state_bytes,
             **self._step_experts,
             "dispatches": self._step_dispatches,
+            "readbacks": list(self._step_readbacks),
+            "launches": self.launches,
         }
 
     def _fold_expert_rows(self) -> dict:
         """This step's expert counters (``EXPERT_COUNTERS``), over all
-        its dispatches: ``expert_rows`` the (row, choice) pairs the held
+        the results it read (``readbacks``: they come back with the
+        picks): ``expert_rows`` the (row, choice) pairs the held
         experts received (a bucket's padded rows route too: they are
         rows the device multiplied), ``experts_touched`` the experts
         that received at least one, summed over layers and dispatches
@@ -2698,6 +2937,11 @@ class DecodeEngine:
 
     @property
     def active(self) -> int:
+        """Slots taken. Never 0 while a launched result is unread: a
+        sequence keeps its slot until its last token lands, and a read
+        waits only while some resident sequence has a row to launch
+        (``_may_defer``), so a loop on ``active or waiting`` makes the
+        step that reads it."""
         return sum(s is not None for s in self.slots)
 
     def tenant_load(self) -> dict[str, int]:
@@ -2773,6 +3017,7 @@ class DecodeEngine:
         window: low/high water describe the span since the previous
         record (the cadence envelope), then reset to the instantaneous
         value."""
+        self._collect()     # the counters are of landed tokens
         free = len(self.free_blocks)
         lo, hi = self._free_lo, self._free_hi
         self._free_lo = self._free_hi = free
@@ -2870,6 +3115,8 @@ class DecodeEngine:
             "decode_uids": list(self._step_decode_uids),
             # cumulative: steps whose chunk rode with the decode batch
             "mixed_dispatches": self.mixed_dispatches,
+            # the finite flags of the rows this step LAUNCHED, in launch
+            # order; those of a result still unread join when it lands
             "finite": self._step_finite,
             "slots": [None if s is None else
                       {"uid": s.uid, "pos": int(self.lengths[i]),
@@ -2892,8 +3139,10 @@ class DecodeEngine:
             # ring says about a slow step
             "phase_ms": self.phases.phase_ms(),
             # the step programs those ``*.dispatch`` phases launched,
-            # ``[kind, bucket]`` in order
+            # ``[kind, bucket]`` in order, and the launches (by ordinal)
+            # those ``*.readback`` phases read
             "dispatches": self._step_dispatches,
+            "readbacks": list(self._step_readbacks),
         }
 
     def dump_flight_recorder(self, reason: str) -> str | None:
@@ -2905,6 +3154,7 @@ class DecodeEngine:
         watchdog latch and chaos kill (supervisor). Returns the path,
         or None when the engine has nowhere to put it (no metrics dir,
         no explicit flight_dir)."""
+        self._collect()     # the last digest's own finite flags
         out_dir = self.flight_dir
         if out_dir is None and self.metrics is not None:
             out_dir = os.path.dirname(self.metrics.path)
@@ -2940,13 +3190,12 @@ class DecodeEngine:
             raise ValueError(f"bucket {b} not in the engine's slot "
                              f"buckets {self.slot_buckets}")
         z = np.zeros((b,), np.int32)
-        rows = {} if self.state is None else {"rows": z}
         operand = self.programs.pack(
             "decode", b, tables=np.full((b, self.cfg.max_blocks_per_seq),
                                         SCRATCH_BLOCK),
-            lengths=z, tokens=z, uids=z, poison=POISON_NONE, **rows)
+            lengths=z, tokens=z, uids=z, poison=POISON_NONE, rows=z)
         rep = StepReport.of(self.programs.body("decode", b), self.params,
-                            self._cache(), operand)
+                            self._carry(), operand)
         per_tok = self._kv_bytes_per_token()
         kv_bytes, scale_bytes = pool_bytes(self.pool)
         return {
@@ -3005,6 +3254,7 @@ class DecodeEngine:
                 metrics.decode(self.telemetry_record(round(tps, 2)))
                 last_t, last_tokens = now, self.tokens_generated
                 last_step = self.steps
+        self._collect()     # a hook may have emptied the loop's condition
         if metrics is not None:
             now = time.perf_counter()
             dt = max(now - last_t, 1e-9)

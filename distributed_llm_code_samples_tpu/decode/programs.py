@@ -19,10 +19,19 @@ mesh, ``jit``, the donated cache). The builder gets ``cfg``, the
 model's ``CacheSpec``, the vocabulary and the mesh as plain values: it
 never sees a sequence, a slot table or a metrics writer.
 
-A step program is ``run(params, cache, operand) -> (cache, result)``.
-``cache`` is the donated operand — the ``PagedKV``, or for a model with
-recurrent layers the pair ``(PagedKV, RecurrentState)`` — and comes
-back in the same form. ``operand`` is ONE ``int32`` vector holding
+A step program is ``run(params, carry, operand) -> (carry, result)``.
+``carry`` is the donated operand, ``(cache, tokens)``, and comes back in
+the same form: ``cache`` the ``PagedKV``, or for a model with recurrent
+layers the pair ``(PagedKV, RecurrentState)``; ``tokens [max_slots + 1]``
+each slot's NEXT token (``init_tokens``; the last row is the pad rows'
+scratch). A decode row's pick, and the pick of a chunk's last row, is
+written to its slot's entry, and a row whose operand token is
+``FROM_SLOT`` takes its input from there: a token goes from one step
+to the next without visiting the host, so the engine may launch a step
+before it has read the last one's result (``decode/engine.py``). A row
+whose token the host knows (a replay's recorded token, an imported
+sequence, any row of an engine that has read its last result) carries
+it in the operand as before. ``operand`` is ONE ``int32`` vector holding
 every host operand of the dispatch and ``result`` ONE ``int32`` array
 holding all the host reads, so a dispatch costs one host-to-device
 transfer and one blocking read. The wire format is written once, here:
@@ -70,6 +79,11 @@ from .sampling import make_pick
 # operand, so arming a fault never recompiles)
 POISON_NONE = -1
 POISON_ALL = -2
+
+# a batch row's ``tokens`` entry that says: take this row's input token
+# from the row's slot in the carried token store (vocabulary ids are
+# never negative)
+FROM_SLOT = -1
 
 # the pool-only programs: block ids (and row counts) are traced operands,
 # so one compiled copy each serves every block; the pool is donated
@@ -140,36 +154,37 @@ class StepPrograms:
 
     def wire(self, kind: str, bucket: int) -> Wire:
         """The operand's layout for ``(kind, bucket)``. Decode: a
-        ``bucket``-row batch's block tables, lengths, tokens and uids,
-        the poison and, where the model has recurrent layers, each
-        row's state row. Verify: decode's plus the drafts and their
-        lengths. Prefill: ONE slot's table, start position, ``bucket``
-        tokens, uid, the poison and its state row likewise. Mixed:
-        decode's for the ``bucket``-row batch plus ONE slot's table,
-        start position, full chunk (``cfg.prefill_chunk`` tokens), uid
-        and state row."""
+        ``bucket``-row batch's block tables, lengths, tokens
+        (``FROM_SLOT`` where the row's slot holds it), uids, the poison
+        and each row's slot (``rows``: the entry of the token store and,
+        where the model has recurrent layers, the state row; the
+        scratch row for a padded one). Verify: a batch's tables,
+        lengths, tokens, uids and the poison plus the drafts and their
+        lengths (the host reads every verify before it goes on, so its
+        tokens are always the host's). Prefill: ONE slot's table, start
+        position, ``bucket`` tokens, uid, the poison and its slot
+        (``row``). Mixed: decode's for the ``bucket``-row batch plus ONE
+        slot's table, start position, full chunk
+        (``cfg.prefill_chunk`` tokens), uid and slot."""
         w = self._wires.get((kind, bucket))
         if w is None:
-            t, rec = self.cfg.max_blocks_per_seq, bool(self.spec.rec_layers)
+            t = self.cfg.max_blocks_per_seq
             if kind == "prefill":
                 fields = {"table": (t,), "pos0": (), "tokens": (bucket,),
-                          "uid": (1,), "poison": ()}
-                if rec:
-                    fields["row"] = ()
+                          "uid": (1,), "poison": (), "row": ()}
             else:
                 fields = {"tables": (bucket, t), "lengths": (bucket,),
                           "tokens": (bucket,), "uids": (bucket,),
                           "poison": ()}
-                if rec:
-                    fields["rows"] = (bucket,)
                 if kind == "verify":
                     fields["drafts"] = (bucket, self.cfg.speculate)
                     fields["dlens"] = (bucket,)
-                elif kind == "mixed":
+                else:
+                    fields["rows"] = (bucket,)
+                if kind == "mixed":
                     fields.update(table=(t,), pos0=(),
-                                  chunk=(self.cfg.prefill_chunk,), uid=(1,))
-                    if rec:
-                        fields["row"] = ()
+                                  chunk=(self.cfg.prefill_chunk,), uid=(1,),
+                                  row=())
             w = self._wires[kind, bucket] = Wire(fields)
         return w
 
@@ -209,6 +224,14 @@ class StepPrograms:
                                d_inner=spec.d_inner, d_state=spec.d_state,
                                d_conv=spec.d_conv)
         return pool, state
+
+    def init_tokens(self) -> jax.Array:
+        """The token store: each slot's next token, and one scratch row
+        that a bucket's padded rows write (replicated under a mesh)."""
+        tokens = jnp.zeros((self.cfg.max_slots + 1,), jnp.int32)
+        if self.mesh is not None:
+            tokens = jax.device_put(tokens, NamedSharding(self.mesh, P()))
+        return tokens
 
     def pool_specs(self) -> PagedKV:
         """Heads are contiguous in a stored row's minor axis
@@ -373,7 +396,7 @@ class StepPrograms:
         other writes. ``f``: the unpacked operand. Returns ``(cache, x
         [b + c, d], counts)``."""
         lengths, pos0 = f["lengths"], f["pos0"]
-        rows, row = f.get("rows"), f.get("row")
+        rows, row = f["rows"], f["row"]
         positions = jnp.concatenate(
             [lengths, pos0 + jnp.arange(self.cfg.prefill_chunk)])
         x = self._embed(p, jnp.concatenate([f["tokens"], f["chunk"]]),
@@ -417,18 +440,28 @@ class StepPrograms:
 
     # -- the four bodies ---------------------------------------------------
 
+    @staticmethod
+    def _held(tokens, store, rows):
+        """A batch's input tokens: the operand's, and for a row marked
+        ``FROM_SLOT`` the one its slot holds in the token store."""
+        return jnp.where(tokens == FROM_SLOT, store[rows], tokens)
+
     def _decode_fn(self, b: int):
-        """A ``b``-slot bucket's decode step: ``result [b]``."""
+        """A ``b``-slot bucket's decode step: ``result [b]``; each
+        row's pick is also left in its slot of the token store."""
         wire = self.wire("decode", b)
 
         @jax.named_scope("decode")
-        def run(p, cache, operand):
+        def run(p, carry, operand):
+            cache, store = carry
             f = wire.unpack(operand)
             cache, x, counts = self.decode_hidden(
-                b, p, cache, f["tables"], f["lengths"], f["tokens"],
-                f.get("rows"))
-            return cache, _with_counts(_fold(*self._head_pick(
-                p, x, f["uids"], f["poison"], f["lengths"], 1)), counts)
+                b, p, cache, f["tables"], f["lengths"],
+                self._held(f["tokens"], store, f["rows"]), f["rows"])
+            picks, finite = self._head_pick(
+                p, x, f["uids"], f["poison"], f["lengths"], 1)
+            return ((cache, store.at[f["rows"]].set(picks)),
+                    _with_counts(_fold(picks, finite), counts))
 
         return run
 
@@ -446,7 +479,8 @@ class StepPrograms:
         wire = self.wire("verify", b)
 
         @jax.named_scope("decode")
-        def run(p, pool, operand):
+        def run(p, carry, operand):
+            pool, store = carry     # the store passes through as it is
             f = wire.unpack(operand)
             tables, lengths, tokens = f["tables"], f["lengths"], f["tokens"]
             uids, poison = f["uids"], f["poison"]
@@ -484,7 +518,7 @@ class StepPrograms:
                     acc = acc + alive.astype(jnp.int32)
                     cur = d
             picks = _fold(jnp.stack(picks_all, 1), jnp.stack(finite_all, 1))
-            return pool, _with_counts(
+            return (pool, store), _with_counts(
                 jnp.concatenate([picks, acc[:, None]], 1), counts)
 
         return run
@@ -492,18 +526,21 @@ class StepPrograms:
     def _prefill_fn(self, c: int):
         """One slot's prefill chunk of ``c`` tokens; the host uses the
         final row's pick only when the chunk completes the prompt:
-        ``result [1]``."""
+        ``result [1]``, left in the slot's entry of the token store too
+        (the slot's first decode row reads it there or from the host; a
+        chunk short of the prompt's end leaves a token nobody reads)."""
         wire = self.wire("prefill", c)
 
         @jax.named_scope("prefill")
-        def run(p, cache, operand):
+        def run(p, carry, operand):
+            cache, store = carry
             f = wire.unpack(operand)
             cache, x, counts = self.prefill_hidden(
-                c, p, cache, f["table"], f["pos0"], f["tokens"],
-                f.get("row"))
-            return cache, _with_counts(_fold(*self._head_pick(
-                p, x[-1:], f["uid"], f["poison"], f["pos0"][None], c)),
-                counts)
+                c, p, cache, f["table"], f["pos0"], f["tokens"], f["row"])
+            picks, finite = self._head_pick(
+                p, x[-1:], f["uid"], f["poison"], f["pos0"][None], c)
+            return ((cache, store.at[f["row"]].set(picks[0])),
+                    _with_counts(_fold(picks, finite), counts))
 
         return run
 
@@ -511,19 +548,25 @@ class StepPrograms:
         """A ``b``-slot bucket's decode step with ONE slot's full
         prefill chunk riding in it: ``result [b + 1]``, the batch's
         picks and then the chunk's last row's (which the host uses only
-        when the chunk completes the prompt)."""
+        when the chunk completes the prompt), each left in its slot of
+        the token store."""
         wire = self.wire("mixed", b)
         c = self.cfg.prefill_chunk
 
         @jax.named_scope("decode")
-        def run(p, cache, operand):
+        def run(p, carry, operand):
+            cache, store = carry
             f = wire.unpack(operand)
+            f["tokens"] = self._held(f["tokens"], store, f["rows"])
             cache, x, counts = self.mixed_hidden(b, p, cache, f)
-            return cache, _with_counts(_fold(*self._head_pick(
+            picks, finite = self._head_pick(
                 p, jnp.concatenate([x[:b], x[-1:]]),
                 jnp.concatenate([f["uids"], f["uid"]]), f["poison"],
                 jnp.concatenate([f["lengths"], f["pos0"][None] + c - 1]),
-                1)), counts)
+                1)
+            slots = jnp.concatenate([f["rows"], f["row"][None]])
+            return ((cache, store.at[slots].set(picks)),
+                    _with_counts(_fold(picks, finite), counts))
 
         return run
 
@@ -531,23 +574,25 @@ class StepPrograms:
 
     def body(self, kind: str, bucket: int):
         """The callable ``build`` jits; under a mesh shard_mapped, the
-        operand and the result replicated."""
+        operand, the token store and the result replicated."""
         run = {"decode": self._decode_fn, "prefill": self._prefill_fn,
                "verify": self._verify_fn,
                "mixed": self._mixed_fn}[kind](bucket)
         if self.mesh is None:
             return run
+        carry = (self.pool_specs(), P())
         return jax.shard_map(
             run, mesh=self.mesh,
-            in_specs=(tp_decode_specs(), self.pool_specs(), P()),
-            out_specs=(self.pool_specs(), P()), check_vma=False)
+            in_specs=(tp_decode_specs(), carry, P()),
+            out_specs=(carry, P()), check_vma=False)
 
     def build(self, kind: str, bucket: int):
-        """The compiled program, the cache donated: XLA updates the
-        blocks in place, which also needs the buffer to cross the
-        program boundary in the layout the scatters and gathers work
-        in — the pool's stored form (``decode/paged.py``;
-        ``tests/test_chip_compile.py`` pins the compiled module)."""
+        """The compiled program, the carry donated: XLA updates the
+        blocks (and the token store) in place, which also needs the
+        buffer to cross the program boundary in the layout the scatters
+        and gathers work in — the pool's stored form
+        (``decode/paged.py``; ``tests/test_chip_compile.py`` pins the
+        compiled module)."""
         if kind in _POOL_OPS:
             return jax.jit(_POOL_OPS[kind], donate_argnums=(0,))
         return jax.jit(self.body(kind, bucket), donate_argnums=(1,))

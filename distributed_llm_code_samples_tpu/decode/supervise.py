@@ -120,6 +120,7 @@ def snapshot_state(engine: DecodeEngine) -> dict:
     then the waiting queue in queue order, so a restore re-queues them
     in scheduling priority order."""
     engine._refuse_recurrent("an engine snapshot (--snapshot_dir)")
+    engine.collect()    # a snapshot holds landed tokens and positions
     requests = []
     running = sorted(
         ((seq.admit_index, slot, seq)

@@ -50,7 +50,7 @@ the same dirs render identical output even under equal timestamps.
 
 Output: step-time percentiles, throughput, MFU, HBM high-water, the
 serving summary + reliability block per engine, a **step phases**
-table (schema-v19 ``engine_step`` span records: per host phase of
+table (schema-v20 ``engine_step`` span records: per host phase of
 ``engine.step()`` the count, mean, p99 and share of step time, and per
 step program, by kind and bucket, its runs and the time from its launch
 to the end of its read), a
@@ -545,26 +545,36 @@ class _Stream:
         (a phase repeated in a step is summed first), and its share of
         all step time. ``(between phases)`` is what no phase covers.
         ``dispatches``: the step programs those steps launched, by
-        kind and bucket (v19: the record's i-th entry is its i-th
+        kind and bucket (the record's i-th entry is its i-th
         ``*.dispatch`` phase), each with how often and how long from
-        the launch to the end of the blocking read."""
+        the launch to the end of the blocking read that read it (v20:
+        the i-th ``*.readback`` phase read the launch its
+        ``readbacks`` names, which may lie in an earlier record; a
+        launch whose read is in no record is not counted)."""
         if not self.step_spans:
             return None
         per_phase: dict[str, list[float]] = {}
         per_program: dict[tuple, list[float]] = {}
+        unread: dict[int, tuple] = {}   # ordinal -> (program, t_launch)
         total_ms = 0.0
         for rec in self.step_spans:
             step_ms = (rec["end_ns"] - rec["start_ns"]) / 1e6
             total_ms += step_ms
             mine: dict[str, float] = {}
             launched = iter(rec["dispatches"])
+            ordinal = rec["launches"] - len(rec["dispatches"])
+            read = iter(rec["readbacks"])
             for name, t0, t1 in rec["phases"]:
                 mine[name] = mine.get(name, 0.0) + (t1 - t0) / 1e6
                 if name.endswith(".dispatch"):
-                    program, t_launch = tuple(next(launched)), t0
+                    unread[ordinal] = (tuple(next(launched)), t0)
+                    ordinal += 1
                 elif name.endswith(".readback"):
-                    per_program.setdefault(program, []).append(
-                        (t1 - t_launch) / 1e6)
+                    program, t_launch = unread.pop(next(read),
+                                                   (None, None))
+                    if program is not None:
+                        per_program.setdefault(program, []).append(
+                            (t1 - t_launch) / 1e6)
             mine["(between phases)"] = step_ms - sum(mine.values())
             for name, ms in mine.items():
                 per_phase.setdefault(name, []).append(ms)

@@ -234,7 +234,20 @@ import numpy as np
 # as many entries as the step has ``*.dispatch`` phases. Each key of
 # the record has a reader (``PERF.md`` section 3 names it); the bump
 # dropped none.
-SCHEMA_VERSION = 19
+# v20 (PR 38): a step's results are read one step late. The engine
+# launches a step's program and only then reads the program launched
+# before it, so a ``*.readback`` phase no longer follows its own
+# ``*.dispatch``: the ``engine_step`` record pins ``readbacks``, the
+# ordinals (among the engine's launches, from 0) of the launches its
+# ``*.readback`` phases read, the i-th entry the i-th phase's, and
+# ``launches``, the engine's launches up to and with the step's own,
+# so the record's ``dispatches`` are the ordinals ``launches -
+# len(dispatches) ..< launches`` and a readback below them lies a step
+# (or more) after its launch (``runtime/tracing.py`` has the
+# contract). The expert counters of a record are those of the results
+# it READ. Readers: ``report.py``'s programs table,
+# ``benchmark/layer_metrics/late_read_share.offline.py``.
+SCHEMA_VERSION = 20
 
 METRICS_FILENAME = "metrics.jsonl"
 
@@ -375,7 +388,8 @@ REQUEST_COMPLETED_REQUIRED = ("latency_s", "ttft_s")
 # decode-segment spans.
 # v18: ``engine_step`` spans belong to a step, not a request: null
 # ``uid``, and STEP_SPAN_REQUIRED on top (validate_record); v19 adds
-# ``dispatches`` to it, one entry a ``*.dispatch`` phase.
+# ``dispatches`` to it, one entry a ``*.dispatch`` phase; v20
+# ``readbacks``, one entry a ``*.readback`` phase, and ``launches``.
 # Same version-bump discipline as STEP_KEYS.
 SPAN_REQUIRED = ("step", "uid", "span", "start_step", "duration_s",
                  "trace_id", "tenant")
@@ -386,9 +400,11 @@ SPAN_NAMES = ("queued", "prefill", "replay", "decode", "quarantine",
               "preempt_gap", "engine_step")
 
 # the one span that belongs to a STEP, not a request (v18): null uid,
-# and the extra keys it must carry (v19: ``dispatches``)
+# and the extra keys it must carry (v19: ``dispatches``; v20:
+# ``readbacks``, ``launches``)
 STEP_SPAN = "engine_step"
-STEP_SPAN_REQUIRED = ("phases", "start_ns", "end_ns", "dispatches")
+STEP_SPAN_REQUIRED = ("phases", "start_ns", "end_ns", "dispatches",
+                      "readbacks", "launches")
 
 # The router-record contract (``decode/fleet.py``): one record per
 # fleet-router decision. ``step`` is the ROUTER's step clock (fleet
@@ -1042,12 +1058,18 @@ def validate_record(rec: Any) -> tuple[bool, str]:
             if missing:
                 return False, (f"span record (span {STEP_SPAN}) missing "
                                f"required key(s) {missing}")
-            launched = sum(p[0].endswith(".dispatch")
-                           for p in rec["phases"])
-            if len(rec["dispatches"]) != launched:
+            for key, suffix in (("dispatches", ".dispatch"),
+                                ("readbacks", ".readback")):
+                n = sum(p[0].endswith(suffix) for p in rec["phases"])
+                if len(rec[key]) != n:
+                    return False, (f"span record (span {STEP_SPAN}) has "
+                                   f"{len(rec[key])} {key!r} for {n} "
+                                   f"'*{suffix}' phase(s)")
+            if any(not 0 <= o < rec["launches"]
+                   for o in rec["readbacks"]):
                 return False, (f"span record (span {STEP_SPAN}) has "
-                               f"{len(rec['dispatches'])} 'dispatches' "
-                               f"for {launched} '*.dispatch' phase(s)")
+                               f"'readbacks' {rec['readbacks']} outside "
+                               f"its 'launches' ({rec['launches']})")
         elif rec["uid"] is None:
             return False, (f"span record (span {rec['span']}) has a "
                            f"null 'uid': only {STEP_SPAN} belongs to "

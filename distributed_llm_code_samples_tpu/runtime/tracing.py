@@ -193,7 +193,10 @@ class SpanTracer:
 # dispatch, the speculative verify path takes the ``decode.*`` names; a
 # step whose chunk rides with its decode batch runs both dispatches'
 # host phases under their own names round ONE ``mixed.*`` launch and
-# wait):
+# wait; since PR 38 a launch's ``*.readback`` and the ``prefill.book``
+# / ``decode.emit`` that fold what it read come AFTER the next launch's
+# ``*.dispatch``, in the next step's stamps where the read waited a
+# step):
 #
 #   host    expire  admit  prefill.cow  prefill.book  decode.marshal
 #           decode.cow  decode.emit  digest
@@ -206,17 +209,36 @@ class SpanTracer:
 # are told by the suffix: ``benchmark/engine_phases.py::phase_class``).
 #
 # Which program a ``*.dispatch`` launched is in the step's
-# ``dispatches`` (``engine._dispatch``; the ``engine_step`` record and
+# ``dispatches`` (``engine._launch``; the ``engine_step`` record and
 # the flight digest carry it): ``[kind, bucket]`` a launch, in launch
 # order, ``kind`` one of ``decode`` / ``prefill`` / ``mixed`` /
 # ``verify`` and ``bucket`` the key the program was built under (the
 # batch bucket; the chunk bucket for ``prefill``). The i-th entry
-# belongs to the i-th ``*.dispatch`` phase of the step's ``phases`` and
-# the ``*.readback`` that follows it. One device runs the launches in
-# that order and every one ends in a blocking read, so the k-th entry
-# of a run of steps is also the k-th step program of a device trace of
-# those steps: a reader joins the two by ORDER and needs no clock
-# (``benchmark/dispatch_join.py``). ``cow`` / ``cow_rows`` / ``implant``
+# belongs to the i-th ``*.dispatch`` phase of the step's ``phases``;
+# ``dispatches`` stays with the step that LAUNCHED the program. One
+# device runs the launches in that order, so the k-th entry of a run
+# of steps is also the k-th step program of a device trace of those
+# steps: a reader joins the two by ORDER and needs no clock.
+#
+# Which launch a ``*.readback`` read is in the step's ``readbacks``
+# (``engine._read``; telemetry v20): the launch's ORDINAL among the
+# engine's launches (from 0), the i-th entry the i-th ``*.readback``
+# phase's. A read may lie in a LATER step's record than its launch:
+# the engine launches a step's program and only then reads the one
+# launched before it (``engine.step``'s docstring says which reads
+# wait), so in steady state a record holds ONE ``*.dispatch`` and,
+# after it, the ``*.readback`` of the previous record's launch. The
+# record's ``launches`` counts the engine's launches up to and with
+# the step's own: its ``dispatches`` are the ordinals ``launches -
+# len(dispatches) ..< launches``, and a readback below them was
+# launched by an earlier step. Every launch is read exactly once, in
+# launch order; a read made between two steps (``engine.collect``, an
+# export) has an annotation and no stamp, and its ordinal is in no
+# record. A reader that pairs the i-th ``*.dispatch`` of a record with
+# the i-th ``*.readback`` of the SAME record
+# (``benchmark/dispatch_join.py``) pairs a launch with the read of the
+# one before it wherever the read waited: pair by ``readbacks``.
+# ``cow`` / ``cow_rows`` / ``implant``
 # are not step programs: no phase pair, no entry. The trainer's
 # sites are annotations only: ``train:clone`` / ``train:run``
 # (``parallel/single.py``), ``launch:build`` / ``launch:run``
@@ -275,6 +297,13 @@ class PhaseTimer:
         profiler's ``#step=n#`` stat) and its stamps start empty."""
         self.step = int(step)
         self.stamps = []
+
+    def end(self) -> None:
+        """Close the step: its stamps belong to whoever took them. A
+        phase run before the next ``begin`` (the engine reading a
+        result between two steps) keeps its annotation and no stamp."""
+        self.step = None
+        self.stamps = None
 
     def label(self, name: str) -> str:
         """``<site>:<name>``, formatted once per name."""

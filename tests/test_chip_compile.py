@@ -148,11 +148,30 @@ def _operand(eng, kind, bucket):
                         for name, (_, _, shape) in wire.fields.items()})
 
 
+def _aliased(compiled) -> int:
+    """How many arguments the compiled module aliases in to out."""
+    import re
+    return re.search(r"input_output_alias=\{(.*?)\}, entry",
+                     compiled.as_text()).group(1).count("alias)")
+
+
+def _carry_is_aliased_whole(compiled, eng):
+    """Every leaf of a step program's carry — pool, recurrent state AND
+    the slots' token store beside them — is aliased in to out, and the
+    store enters as it is stored. Returns the carry's bytes."""
+    leaves = jax.tree_util.tree_leaves(eng._carry())
+    assert _aliased(compiled) == len(leaves)
+    store = compiled.input_formats[0][1][1]
+    assert store.layout.major_to_minor == (0,), store
+    assert eng.token_store.shape == (eng.cfg.max_slots + 1,)
+    return sum(_nbytes(x) for x in leaves)
+
+
 def _step_args(eng, slots, chunk):
     """The arguments of one decode dispatch over ``slots`` rows and of
     one prefill dispatch of ``chunk`` tokens, as the engine passes
     them: the params, the cache and the one packed operand."""
-    return tuple((eng.params, eng._cache(), _operand(eng, kind, bucket))
+    return tuple((eng.params, eng._carry(), _operand(eng, kind, bucket))
                  for kind, bucket in (("decode", slots), ("prefill", chunk)))
 
 
@@ -161,7 +180,7 @@ def _cell_programs(eng, slots, chunk):
     programs at its full batch: the decode batch, a full prefill chunk,
     and the mixed program in which that chunk rides with the batch."""
     decode, prefill = _step_args(eng, slots, chunk)
-    mixed = (eng.params, eng._cache(), _operand(eng, "mixed", slots))
+    mixed = (eng.params, eng._carry(), _operand(eng, "mixed", slots))
     return {"decode": (slots, decode), "prefill": (chunk, prefill),
             "mixed": (slots, mixed)}
 
@@ -289,13 +308,16 @@ def test_step_program_keeps_the_pool_as_stored(one_chip,
         if r[1] >= slab]
     assert not moved, moved
     # the pool enters as it is stored: row-major
-    pool_formats = compiled.input_formats[0][1]
+    pool_formats, _ = compiled.input_formats[0][1]
     for side in (pool_formats.k, pool_formats.v):
         assert side.layout.major_to_minor == tuple(range(pool.k.ndim)), side
     m = compiled.memory_analysis()
     pool_bytes = pool.k.nbytes + pool.v.nbytes
-    # donation is real: the whole pool is updated in place
-    assert m.alias_size_in_bytes >= pool_bytes
+    # donation is real: the whole pool is updated in place, and the
+    # token store beside it
+    assert m.alias_size_in_bytes >= pool_bytes + eng.token_store.nbytes
+    assert _carry_is_aliased_whole(compiled, eng) == (
+        pool_bytes + eng.token_store.nbytes)
     # ...and unpadded: the arguments' bytes are their logical bytes
     # (the weights' few odd rows pad by kilobytes; the parent's pool
     # padded by 17% of itself)
@@ -368,15 +390,16 @@ def test_hybrid_step_program_keeps_the_state_as_stored(one_chip,
         compiled.as_text(), ("copy", "slice", "dynamic-slice"), "f32")
         if r[1] >= min(state.conv.size, state.ssm.size)]
     assert not moved, moved
-    cache_formats = compiled.input_formats[0][1]
+    cache_formats, _ = compiled.input_formats[0][1]
     for fmt, arr in ((cache_formats[1].conv, state.conv),
                      (cache_formats[1].ssm, state.ssm),
                      (cache_formats[0].k, pool.k)):
         assert fmt.layout.major_to_minor == tuple(range(arr.ndim)), fmt
     m = compiled.memory_analysis()
     held = (pool.k.nbytes + pool.v.nbytes + state.conv.nbytes
-            + state.ssm.nbytes)
+            + state.ssm.nbytes + eng.token_store.nbytes)
     assert m.alias_size_in_bytes >= held
+    assert _carry_is_aliased_whole(compiled, eng) == held
     # unpadded: the tail's 65 rows lie under an axis of one, in one-row
     # tiles (PR 32; flat ``[65, (K-1)*D]`` the chip padded them to 72 in
     # its 8-row tiles, the allowance this line had: an eighth of the
@@ -477,12 +500,16 @@ class _ShapesEngine:
     def __init__(self, programs, params, pool, state=None):
         self.programs, self.cfg = programs, programs.cfg
         self.params, self.pool, self.state = params, pool, state
+        self.token_store = jax.eval_shape(programs.init_tokens)
 
     def _program(self, kind, bucket):
         return self.programs.build(kind, bucket)
 
     def _cache(self):
         return self.pool if self.state is None else (self.pool, self.state)
+
+    def _carry(self):
+        return self._cache(), self.token_store
 
 
 @pytest.fixture(scope="module")
@@ -544,10 +571,12 @@ def test_latent_step_program_keeps_the_pool_as_stored(one_chip,
         compiled.as_text(), ("copy", "slice", "dynamic-slice"), "bf16")
         if r[1] >= slab and r[1] % slab == 0]
     assert not moved, moved
-    fmt = compiled.input_formats[0][1].k
+    fmt = compiled.input_formats[0][1][0].k
     assert fmt.layout.major_to_minor == tuple(range(pool.k.ndim)), fmt
     m = compiled.memory_analysis()
-    assert m.alias_size_in_bytes >= _nbytes(pool.k)
+    held = _nbytes(pool.k) + _nbytes(eng.token_store)
+    assert m.alias_size_in_bytes >= held
+    assert _carry_is_aliased_whole(compiled, eng) == held
     logical = sum(_nbytes(x) for x in jax.tree_util.tree_leaves(args[:2]))
     assert m.argument_size_in_bytes - logical < _nbytes(pool.k) // 100
     assert _total_bytes(compiled) < HBM_V5E
@@ -628,13 +657,15 @@ def test_conv_moe_step_program_keeps_pool_and_state_as_stored(
         hlo, ("copy", "slice", "dynamic-slice"), "f32")
         if r[1] >= state.conv.size]
     assert not moved, moved
-    cache_formats = compiled.input_formats[0][1]
+    cache_formats, _ = compiled.input_formats[0][1]
     for fmt, arr in ((cache_formats[0].k, pool.k), (cache_formats[0].v, pool.v),
                      (cache_formats[1].conv, state.conv)):
         assert fmt.layout.major_to_minor == tuple(range(arr.ndim)), fmt
     m = compiled.memory_analysis()
-    held = _nbytes(pool.k) + _nbytes(pool.v) + _nbytes(state.conv)
+    held = (_nbytes(pool.k) + _nbytes(pool.v) + _nbytes(state.conv)
+            + _nbytes(eng.token_store))
     assert m.alias_size_in_bytes >= held
+    assert _carry_is_aliased_whole(compiled, eng) == held
     logical = sum(_nbytes(x) for x in jax.tree_util.tree_leaves(args[:2]))
     assert m.argument_size_in_bytes - logical < _nbytes(pool.k) // 100
     assert _total_bytes(compiled) < HBM_V5E
@@ -683,38 +714,37 @@ def test_step_program_boundary_is_one_operand_and_one_result(
     beyond the params' leaves and the cache's leaves it takes exactly
     ONE argument, an ``int32`` vector, and beyond the cache it returns
     exactly ONE result, an ``int32`` array — one host-to-device
-    transfer and one blocking read a dispatch — and the cache is still
-    donated and aliased whole, leaf for leaf (a hybrid's recurrent
-    state with its pool; under a 2-way model mesh each shard's half).
+    transfer and one blocking read a dispatch — and the carry (the
+    cache and the slots' token store) is still donated and aliased
+    whole, leaf for leaf (a hybrid's recurrent state with its pool;
+    under a 2-way model mesh each shard's half of the pool).
     Compiled here, for the host's virtual devices: no topology is
     described."""
-    import re
     eng = _toy_engine(family, ways, 2 if kind == "verify" else 0,
                       toy_hybrid_config)
     bucket = 16 if kind == "prefill" else 2
     lowered = eng._program(kind, bucket).lower(
-        eng.params, eng._cache(), _operand(eng, kind, bucket))
+        eng.params, eng._carry(), _operand(eng, kind, bucket))
     (params, cache, *rest), kwargs = lowered.args_info
     leaves = jax.tree_util.tree_leaves
     assert not kwargs and len(rest) == 1
     assert len(leaves(params)) == len(leaves(eng.params))
-    assert len(leaves(cache)) == len(leaves(eng._cache()))
+    assert len(leaves(cache)) == len(leaves(eng._carry()))
     (operand,) = leaves(rest)
     assert operand.dtype == np.int32 and len(operand.shape) == 1
     cache_out, *results = lowered.out_info
     assert ([(x.shape, x.dtype) for x in leaves(cache_out)]
-            == [(x.shape, x.dtype) for x in leaves(eng._cache())])
+            == [(x.shape, x.dtype) for x in leaves(eng._carry())])
     (result,) = leaves(results)
     assert result.dtype == np.int32
     assert result.shape == {"decode": (2,), "prefill": (1,),
                             "verify": (2, 4), "mixed": (3,)}[kind]
     compiled = lowered.compile()
-    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry",
-                        compiled.as_text()).group(1)
-    assert aliased.count("alias)") == len(leaves(cache))
+    assert _aliased(compiled) == len(leaves(cache))
+    # (the token store is replicated: whole on every shard)
     held = sum(x.nbytes for x in leaves(eng._cache()))
     assert (compiled.memory_analysis().alias_size_in_bytes
-            == held // max(ways, 1))
+            == held // max(ways, 1) + eng.token_store.nbytes)
 
 
 class _BodiesOfThePredecessor:
@@ -794,7 +824,7 @@ def test_older_programs_lower_as_before_the_mixed_program(
                           toy_latent_config if family == "latent"
                           else toy_hybrid_config)
         return eng._program(kind, bucket).lower(
-            eng.params, eng._cache(), _operand(eng, kind, bucket)).as_text()
+            eng.params, eng._carry(), _operand(eng, kind, bucket)).as_text()
 
     built = lowered()
     for name in ("decode_hidden", "prefill_hidden"):

@@ -568,7 +568,7 @@ def test_step_programs_are_the_inline_reference(monkeypatch, case):
         operand = wire.pack(**{name: np.zeros(shape, np.int32) for
                                name, (_, _, shape) in wire.fields.items()})
         return eng._program(kind, bucket).lower(
-            eng.params, eng._cache(), operand).as_text()
+            eng.params, eng._carry(), operand).as_text()
 
     built = lowered()
     for name in ("_embed", "_trunk", "logits"):

@@ -413,14 +413,19 @@ def test_engine_greedy_tokens_are_the_reference_argmax(ref, weights):
         assert len(out[u]) == len(pr) + 12 + 3 * uids.index(u)
         assert_greedy_matches(ref, w, out[u], len(pr))
     # the last step decoded one row: 4 pairs a layer over 5 layers, one
-    # row of the tails
+    # row of the tails. The experts' counters are of the results a step
+    # READ (the engine drained: the step before's one-row batch and
+    # then its own), the state bytes of the rows it LAUNCHED
     pairs = EXPERT_LAYERS * TOP_K
     last = eng.flight[-1]
-    assert [last[k] for k in EXPERT_COUNTERS] == [pairs, pairs, 1]
+    assert last["readbacks"] == [eng.launches - 2, eng.launches - 1]
+    assert [last[k] for k in EXPERT_COUNTERS] == [2 * pairs, 2 * pairs, 1]
     assert last["state_bytes"] == eng.state.bytes_per_slot
     steps = [s for s in spans if s["span"] == "engine_step"]
     assert len(steps) == eng.steps
-    assert [steps[-1][k] for k in EXPERT_COUNTERS] == [pairs, pairs, 1]
+    assert [steps[-1][k] for k in EXPERT_COUNTERS] == [2 * pairs,
+                                                       2 * pairs, 1]
+    assert [steps[-2][k] for k in EXPERT_COUNTERS] == [pairs, pairs, 1]
     assert steps[-1]["state_bytes"] == eng.state.bytes_per_slot
     both = [s for s in steps if s["expert_rows"] > pairs * 8]
     assert both and all(s["expert_rows"] % pairs == 0 for s in steps)

@@ -127,7 +127,7 @@ def test_mixed_program_is_the_two_programs_on_the_same_rows(engine, family):
     seq, progs = eng.slots[slot], eng.programs
     b, *batch = eng._marshal(ready, eng.slot_buckets[-1])
     batch_f = eng._batch_fields(ready, b, *batch)
-    cache, params = eng._cache(), eng.params
+    cache, params = eng._carry(), eng.params
 
     def run(kind, bucket, cache, **fields):     # not donated: cache kept
         out, result = jax.jit(progs.body(kind, bucket))(
@@ -237,9 +237,9 @@ def test_what_cannot_ride_never_builds_the_program(engine, case):
     assert "mixed" not in kinds_built(eng) and eng.mixed_dispatches == 0
 
 
-MIXED_PHASES = ["prefill.cow", "decode.cow", "decode.marshal",
-                "mixed.upload", "mixed.dispatch", "mixed.readback",
-                "prefill.book", "decode.emit"]
+MIXED_LAUNCH = ["prefill.cow", "decode.cow", "decode.marshal",
+                "mixed.upload", "mixed.dispatch"]
+MIXED_LAND = ["mixed.readback", "prefill.book", "decode.emit"]
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -249,12 +249,15 @@ def test_a_mixed_step_is_one_dispatch_that_carried_a_chunk(engine, family):
     in the mixed program (``mixed_dispatches``), so a reader that takes
     ``dispatches - prefill dispatches`` for the decode count reads 0;
     the record's phases are both dispatches' host phases round one
-    ``mixed.*`` launch and wait; the state bytes are the batch's rows',
-    the experts' counters the whole program's; and the counter is in
-    the telemetry record and the flight digest."""
+    ``mixed.*`` launch, and the NEXT step's hold its wait and what
+    follows it (the read is a step late: ``tests/test_late_read.py``);
+    the state bytes are the batch's rows', the experts' counters the
+    whole program's, in the record of the step that read them; and the
+    counter is in the telemetry record and the flight digest."""
     sink = Collector()
     eng = engine(family, metrics=sink)
     _mid_flight(eng)
+    eng.collect()
     before = (eng.dispatch_count, eng.prefill_dispatches,
               eng.mixed_dispatches, eng.compile_count)
     assert eng.step()
@@ -263,22 +266,30 @@ def test_a_mixed_step_is_one_dispatch_that_carried_a_chunk(engine, family):
     assert eng.compile_count - before[3] == 1       # mixed(4): the one
     rec = sink.steps[-1]
     assert [p[0] for p in rec["phases"]] == (
-        ["expire", "admit", "decode.marshal"] + MIXED_PHASES
+        ["expire", "admit", "decode.marshal"] + MIXED_LAUNCH
         + ["decode.marshal", "digest"])
+    assert rec["dispatches"] == [["mixed", 4]] and rec["readbacks"] == []
     digest = eng.flight[-1]
     assert digest["mixed_dispatches"] == eng.mixed_dispatches
-    assert eng.telemetry_record()["mixed_dispatches"] == eng.mixed_dispatches
     assert digest["prefill_uid"] is not None
-    assert len(digest["decode_uids"]) == 3 and len(digest["finite"]) == 4
-    assert {"mixed.upload", "mixed.dispatch",
-            "mixed.readback"} <= set(digest["phase_ms"])
+    assert len(digest["decode_uids"]) == 3 and digest["finite"] is None
+    assert {"mixed.upload", "mixed.dispatch"} <= set(digest["phase_ms"])
     want_state = 3 * eng.state.bytes_per_slot if eng.state is not None else 0
     assert rec["state_bytes"] == digest["state_bytes"] == want_state
-    # a bucket's padded row routes too: 4 rows + the chunk's 16
-    assert rec["expert_rows"] == (4 + CHUNK) * 4 * eng.spec.expert_layers
-    # the next step is the same prompt's second full chunk: no build
+    assert rec["expert_rows"] == 0                  # nothing read yet
+    # the next step is the same prompt's second full chunk: no build;
+    # it reads the first one's result once its own program is launched
     assert eng.step() and eng.compile_count - before[3] == 1
     assert eng.mixed_dispatches - before[2] == 2
+    nxt = sink.steps[-1]
+    assert [p[0] for p in nxt["phases"]] == (
+        ["expire", "admit", "decode.marshal"] + MIXED_LAUNCH + MIXED_LAND
+        + ["decode.marshal", "digest"])
+    assert nxt["readbacks"] == [rec["launches"] - 1]
+    assert len(digest["finite"]) == 4 and all(digest["finite"])
+    # a bucket's padded row routes too: 4 rows + the chunk's 16
+    assert nxt["expert_rows"] == (4 + CHUNK) * 4 * eng.spec.expert_layers
+    assert eng.telemetry_record()["mixed_dispatches"] == eng.mixed_dispatches
 
 
 @pytest.mark.parametrize("family", FAMILIES)

@@ -373,12 +373,16 @@ def test_engine_greedy_tokens_are_the_reference_argmax(ref, weights):
     for u, pr in zip(uids, ps):
         assert len(out[u]) == len(pr) + 12 + 3 * uids.index(u)
         assert_greedy_matches(ref, w, out[u], len(pr))
-    # the last step decoded one row: 4 pairs a layer over 3 layers
+    # the counters are of the results a step READ: the last step (the
+    # engine drained) read the step before's one-row batch and then its
+    # own, 4 pairs a layer over 3 layers each
     last = eng.flight[-1]
-    assert [last[k] for k in EXPERT_COUNTERS] == [12, 12, 1]
+    assert last["readbacks"] == [eng.launches - 2, eng.launches - 1]
+    assert [last[k] for k in EXPERT_COUNTERS] == [24, 24, 1]
     steps = [s for s in spans if s["span"] == "engine_step"]
     assert len(steps) == eng.steps
-    assert [steps[-1][k] for k in EXPERT_COUNTERS] == [12, 12, 1]
+    assert [steps[-1][k] for k in EXPERT_COUNTERS] == [24, 24, 1]
+    assert [steps[-2][k] for k in EXPERT_COUNTERS] == [12, 12, 1]
     # a step with a prefill chunk of 8 and a decode batch (bucket 4 or
     # 2: padded rows route too): both dispatches' pairs
     both = [s for s in steps if s["expert_rows"] > 3 * TOP_K * 8]

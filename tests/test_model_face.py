@@ -223,7 +223,7 @@ def test_cache_spec_sizes_a_latent_pool(toy_latent_config):
                  / (pool.n_blocks * pool.block_size))
     assert per_token == eng._kv_bytes_per_token() == 4 * 128 * 2
     assert eng.programs.wire("decode", 2).fields.keys() == {
-        "tables", "lengths", "tokens", "uids", "poison"}
+        "tables", "lengths", "tokens", "uids", "poison", "rows"}
     picks, rows = eng.programs.split("decode", np.arange(2 + 3 * 16))
     assert picks.tolist() == [0, 1] and rows.shape == (3, 16)
 

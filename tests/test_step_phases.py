@@ -45,16 +45,21 @@ HOST = {"expire", "admit", "prefill.cow", "prefill.book",
 LAUNCH = {"prefill.upload", "prefill.dispatch", "decode.upload",
           "decode.dispatch", "mixed.upload", "mixed.dispatch"}
 WAIT = {"prefill.readback", "decode.readback", "mixed.readback"}
-# one dispatch's phases, in the order the engine runs them
-PREFILL = ["prefill.cow", "prefill.upload", "prefill.dispatch",
-           "prefill.readback", "prefill.book"]
+# one dispatch's phases, in the order the engine runs them: the launch
+# side where the program is launched, the landing side wherever its
+# result is read (after the NEXT launch, a step later where the read
+# waits: ``tests/test_late_read.py``)
+PREFILL = ["prefill.cow", "prefill.upload", "prefill.dispatch"]
 DECODE = ["decode.cow", "decode.marshal", "decode.upload",
-          "decode.dispatch", "decode.readback", "decode.emit"]
+          "decode.dispatch"]
 # a full chunk riding with the batch: both dispatches' host halves
 # under their own names round ONE launch and ONE wait
 MIXED = ["prefill.cow", "decode.cow", "decode.marshal", "mixed.upload",
-         "mixed.dispatch", "mixed.readback", "prefill.book",
-         "decode.emit"]
+         "mixed.dispatch"]
+LANDING = {"prefill": ["prefill.readback", "prefill.book"],
+           "decode": ["decode.readback", "decode.emit"],
+           "mixed": ["mixed.readback", "prefill.book", "decode.emit"]}
+LANDS = {n for names in LANDING.values() for n in names}
 
 
 class Collector:
@@ -112,13 +117,24 @@ def _dispatches(rec):
 
 
 def _expected(rec):
-    """The phase names a step with ``rec``'s dispatches has to show, in
-    order (the first ``decode.marshal`` is where the step looks whether
-    its chunk can ride with the batch)."""
+    """The LAUNCH-side phase names a step with ``rec``'s dispatches has
+    to show, in order (the first ``decode.marshal`` is where the step
+    looks whether its chunk can ride with the batch)."""
     n_prefill, n_decode, n_mixed = _dispatches(rec)
     return (["expire", "admit", "decode.marshal"] + MIXED * n_mixed
             + PREFILL * n_prefill + ["decode.marshal"]
             + DECODE * n_decode + ["digest"])
+
+
+def _phase_of(recs):
+    """``{launch ordinal: phase prefix}`` over consecutive records (a
+    verify takes the ``decode.*`` names)."""
+    out = {}
+    for rec in recs:
+        first = rec["launches"] - len(rec["dispatches"])
+        for i, (kind, _) in enumerate(rec["dispatches"]):
+            out[first + i] = "decode" if kind == "verify" else kind
+    return out
 
 
 def _run_scenario(name, lm_params, prompts):
@@ -141,7 +157,7 @@ def _run_scenario(name, lm_params, prompts):
             pass
         recs = sink.steps()
         assert recs and all(_dispatches(r) == (1, 0, 0) for r in recs)
-        return recs
+        return recs, _phase_of(sink.steps())
     if name == "two_versions":
         other = init_lm(jax.random.PRNGKey(5), V, D, L, max_seq_len=64)
         eng.submit(prompts[0], 24)
@@ -157,7 +173,7 @@ def _run_scenario(name, lm_params, prompts):
         eng.run()
         recs = [r for r in sink.steps() if _dispatches(r) == (0, 2, 0)]
         assert recs, "no step dispatched one decode per resident version"
-        return recs
+        return recs, _phase_of(sink.steps())
     eng.generate(prompts, 8)            # compile outside what is read
     if name == "mixed":
         # prompts of several full chunks, admitted behind a ready slot
@@ -174,7 +190,7 @@ def _run_scenario(name, lm_params, prompts):
     assert len(recs) >= 3, (name, [_dispatches(r) for r in sink.steps()])
     if name == "verify":
         assert {k for k, _ in eng._programs} == {"prefill", "verify"}
-    return recs
+    return recs, _phase_of(sink.steps())
 
 
 @pytest.mark.parametrize("scenario", [
@@ -182,14 +198,34 @@ def _run_scenario(name, lm_params, prompts):
     "verify", "mixed"])
 def test_phases_tile_the_step(scenario, lm_params, prompts):
     """Children in order, inside the parent, none overlapping, named as
-    the step's dispatches say; and the time no child covers is within
-    2% (or 50 us) of the step in the scenario's median step."""
-    recs = _run_scenario(scenario, lm_params, prompts)
+    the step's dispatches (the launch side) and its readbacks (the
+    landing side: the launches it READ, its own or the step before's)
+    say; and the time no child covers is within 2% (or 50 us) of the
+    step in the scenario's median step."""
+    recs, phase_of = _run_scenario(scenario, lm_params, prompts)
     uncovered = []
     for rec in recs:
         assert rec["uid"] is None and rec["step"] == rec["start_step"]
         phases = rec["phases"]
-        assert [p[0] for p in phases] == _expected(rec)
+        names = [p[0] for p in phases]
+        assert [n for n in names if n not in LANDS] == _expected(rec)
+        assert [n for n in names if n in LANDS] == [
+            n for o in rec["readbacks"] for n in LANDING[phase_of[o]]]
+        # nothing is read before something was launched, or between
+        # two steps: a read follows a launch of its own step
+        if rec["readbacks"]:
+            assert names.index(LANDING[phase_of[rec["readbacks"][0]]][0]) \
+                > min(i for i, n in enumerate(names)
+                      if n.endswith(".dispatch"))
+        if scenario in ("prefill_only", "verify", "two_versions"):
+            # what the host goes on with is read in the step itself
+            first = rec["launches"] - len(rec["dispatches"])
+            assert rec["readbacks"] == list(range(first, rec["launches"]))
+        elif scenario in ("decode_only", "mixed"):
+            # steady state: the step reads the step before's launch
+            assert rec["readbacks"] in ([rec["launches"] - 2],
+                                        [rec["launches"] - 2,
+                                         rec["launches"] - 1])
         assert {p[0] for p in phases} <= HOST | LAUNCH | WAIT
         t = rec["start_ns"]
         for name, start, end in phases:
@@ -261,6 +297,9 @@ def test_no_writer_no_record_digest_carries_phase_ms(lm_params, prompts,
         assert all(v >= 0 for v in ms.values())
     assert any("prefill.dispatch" in d["phase_ms"] for d in eng.flight)
     assert "decode.dispatch" in eng.flight[-1]["phase_ms"]
+    # the engine drained: the last step read its own launch too
+    assert eng.flight[-1]["readbacks"] == [eng.launches - 2,
+                                           eng.launches - 1]
     json.dumps(list(eng.flight))            # the dump stays serialisable
 
 
@@ -285,7 +324,8 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
                             "phases", "tokens_generated",
                             "state_bytes", "expert_rows",
                             "experts_touched", "expert_rows_max",
-                            "dispatches"}
+                            "dispatches", "readbacks", "launches"}
+        assert rec["launches"] == eng.launches
         assert rec["state_bytes"] == 0      # no recurrent layer here
         assert rec["expert_rows"] == rec["experts_touched"] == 0  # nor expert
         assert rec["step"] == eng.global_step == eng.flight[-1]["step"]
@@ -446,16 +486,17 @@ def _step_record(**over):
            "duration_s": 1.0, "start_ns": 1_000_000_000,
            "end_ns": 2_000_000_000,
            "phases": [["admit", 1_000_000_100, 1_000_000_900]],
-           "dispatches": []}
+           "dispatches": [], "readbacks": [], "launches": 0}
     rec.update(over)
     return rec
 
 
-def test_validate_record_takes_v19_engine_step():
-    assert SCHEMA_VERSION >= 19 and STEP_SPAN in SPAN_NAMES
+def test_validate_record_takes_v20_engine_step():
+    assert SCHEMA_VERSION >= 20 and STEP_SPAN in SPAN_NAMES
     ok, reason = validate_record(_step_record())
     assert ok, reason
-    for key in ("phases", "start_ns", "end_ns", "dispatches"):
+    for key in ("phases", "start_ns", "end_ns", "dispatches", "readbacks",
+                "launches"):
         rec = _step_record()
         del rec[key]
         ok, reason = validate_record(rec)
@@ -502,6 +543,8 @@ def test_report_reads_step_phases_and_keeps_the_waterfall(
                 if json.loads(ln).get("span") != STEP_SPAN]
         assert len(lines) - len(kept) == eng.steps
         g.writelines(kept)
+        read_in_a_step = sum(len(json.loads(ln)["readbacks"])
+                             for ln in lines if ln not in kept)
     docs = []
     for mdir in (with_dir, without_dir):
         capsys.readouterr()
@@ -519,11 +562,14 @@ def test_report_reads_step_phases_and_keeps_the_waterfall(
     assert table["phases"]["admit"]["steps"] == eng.steps
     assert sum(p["share"] for p in table["phases"].values()) == \
         pytest.approx(1.0, abs=1e-3)
-    # the step programs beside the waterfall: every dispatch, once
+    # the step programs beside the waterfall: every dispatch whose
+    # read lies in a step's record, once (``log_every``'s decode
+    # record reads what is in flight BETWEEN two steps)
     assert {d["kind"] for d in table["dispatches"]} == {
         "prefill", "decode", "mixed"}
-    assert sum(d["count"] for d in table["dispatches"]) == \
-        eng.dispatch_count
+    assert sum(d["count"] for d in table["dispatches"]) == read_in_a_step
+    assert eng.dispatch_count - eng.steps // 2 <= read_in_a_step \
+        <= eng.dispatch_count
     # --trace UID stitches one request's spans and never meets a null uid
     assert report_main([with_dir, "--trace", "0"]) == 0
     capsys.readouterr()
@@ -536,26 +582,48 @@ def test_report_reads_step_phases_and_keeps_the_waterfall(
 
 def test_report_prints_dispatches_by_kind_and_bucket(tmp_path, capsys):
     """The operator's view of ``dispatches``: per step program, by kind
-    and bucket, its runs and the time from its launch to the end of its
-    read, the tail's two programs of one step told apart."""
+    and bucket, its runs and the time from its launch to the end of the
+    read that ``readbacks`` pairs it with, in its own step's record or
+    in the next one's; the tail's two programs of one step told
+    apart."""
     from distributed_llm_code_samples_tpu.report import report_main
     ms = 1_000_000
+    launched, unread = [0], []      # the engine's count; [ordinal, kind, t]
 
-    def step(n, programs):
-        t, phases = n * 100 * ms, []
+    def step(n, programs, read_own=True):
+        """A step at ``n * 100`` ms that launches ``programs`` (``kind,
+        bucket, ms from launch to the end of its read``), each launch
+        followed by the read of whatever was unread; its last launch is
+        read in the step unless ``read_own`` is false."""
+        t, phases, reads = n * 100 * ms, [], []
+
+        def read(upto):
+            ordinal, kind, t_launch, took = unread.pop(0)
+            end = max(upto, t_launch + took * ms)
+            phases.append([kind + ".readback", upto, end])
+            reads.append(ordinal)
+            return end
+
         for kind, _, took in programs:
-            phases += [[kind + ".dispatch", t, t + ms],
-                       [kind + ".readback", t + ms, t + took * ms]]
-            t += (took + 1) * ms
+            phases.append([kind + ".dispatch", t, t + ms])
+            unread.append([launched[0], kind, t, took])
+            launched[0] += 1
+            t += ms
+            if len(unread) > 1:
+                t = read(t)
+        if read_own:
+            t = read(t)
         return _step_record(
             start_step=n, step=n, start_ns=n * 100 * ms, end_ns=t,
             duration_s=(t - n * 100 * ms) / 1e9, tokens_generated=n,
-            phases=phases, dispatches=[[k, b] for k, b, _ in programs])
+            phases=phases, dispatches=[[k, b] for k, b, _ in programs],
+            readbacks=reads, launches=launched[0])
     mdir = str(tmp_path / "m")
     with TelemetryWriter(mdir) as w:
-        w.span(step(1, [("mixed", 12, 10)]))
-        w.span(step(2, [("prefill", 4, 4), ("decode", 8, 9)]))
-        w.span(step(3, [("mixed", 12, 12)]))
+        w.span(step(1, [("mixed", 12, 10)], read_own=False))
+        w.span(step(2, [("prefill", 4, 4), ("decode", 8, 9)],
+                    read_own=False))
+        w.span(step(3, [("mixed", 12, 12)], read_own=False))
         w.span(step(4, [("decode", 8, 9)]))
     capsys.readouterr()
     assert report_main([mdir, "--json"]) == 0
@@ -563,8 +631,12 @@ def test_report_prints_dispatches_by_kind_and_bucket(tmp_path, capsys):
     assert doc["problems"] == []
     rows = {(d["kind"], d["bucket"]): d
             for d in doc["step_phases"]["dispatches"]}
+    # a read that waited a step ends one step (100 ms) and the next
+    # launch (1 ms) after its own launch: both mixed programs, the
+    # first decode; the second decode and the tail's chunk were read
+    # when their results were there (9 and 4 ms)
     assert {k: (d["count"], d["mean_ms"]) for k, d in rows.items()} == {
-        ("mixed", 12): (2, 11.0), ("decode", 8): (2, 9.0),
+        ("mixed", 12): (2, 101.0), ("decode", 8): (2, (100 + 9) / 2),
         ("prefill", 4): (1, 4.0)}
     assert report_main([mdir]) == 0
     text = capsys.readouterr().out

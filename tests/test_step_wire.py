@@ -60,22 +60,28 @@ def engine(lm_params, toy_hybrid_config):
 
 
 def _spy(eng):
-    """Record ``(kind, bucket, fields of the operand, result)`` of every
-    dispatch the engine makes, the operand unpacked by the program's
-    own layout."""
+    """Record ``[kind, bucket, fields of the operand, result]`` of every
+    dispatch the engine makes, in launch order, the operand unpacked by
+    the program's own layout; the result (the picks) is None until the
+    engine has read it (``eng.collect()``)."""
     seen = []
-    real = eng._dispatch
+    real = eng._launch
 
-    def dispatch(phase, asked, fn, params, operand):
+    def launch(phase, asked, fn, params, operand, land):
         (kind, bucket), = [k for k, v in eng._programs.items() if v is fn]
         assert asked == bucket      # what the step's ``dispatches`` notes
-        result = real(phase, asked, fn, params, operand)
         fields = {k: np.asarray(v) for k, v in
                   eng.programs.wire(kind, bucket).unpack(operand).items()}
-        seen.append((kind, bucket, fields, result))
-        return result
+        entry = [kind, bucket, fields, None]
+        seen.append(entry)
 
-    eng._dispatch = dispatch
+        def landed(picks):
+            entry[3] = picks
+            return land(picks)
+
+        real(phase, asked, fn, params, operand, landed)
+
+    eng._launch = launch
     return seen
 
 
@@ -97,7 +103,9 @@ def test_pack_then_unpack_returns_every_field(engine, family, kind):
                                    dtype=np.int64).astype(np.int32)
                 for name, (_, _, shape) in wire.fields.items()}
         names = set(want)
-        assert ("rows" in names or "row" in names) == (family == "hybrid")
+        # the row's slot: its entry of the token store (and its state
+        # row); a verify's tokens are always the host's
+        assert ("rows" in names or "row" in names) == (kind != "verify")
         assert ("drafts" in names) == (kind == "verify")
         operand = eng.programs.pack(kind, bucket, **want)
         assert operand.dtype == np.int32 and operand.ndim == 1
@@ -140,17 +148,17 @@ def test_dispatched_operands_hold_pad_rows_at_scratch(engine, prompts,
         assert f["lengths"][3] == f["tokens"][3] == f["uids"][3] == 0
         assert (f["lengths"][:3] > 0).all() and f["poison"] == -1
         assert result.shape == (4,) and (result[:3] >= 0).all()
+        assert f["rows"][3] == eng.cfg.max_slots    # the scratch row
+        assert sorted(f["rows"][:3]) == [0, 1, 2]
         if family == "hybrid":
-            assert f["rows"][3] == eng.state.scratch_row
-            assert sorted(f["rows"][:3]) == [0, 1, 2]
+            assert eng.state.scratch_row == eng.cfg.max_slots
     pre = [(b, f, r) for kind, b, f, r in seen if kind == "prefill"]
     assert pre
     for bucket, f, result in pre:
         assert f["tokens"].shape == (bucket,) and f["uid"].shape == (1,)
         assert f["uid"][0] in (1, 2, 3) and f["pos0"] % bucket == 0
         assert result.shape == (1,)
-        if family == "hybrid":
-            assert f["row"] in (0, 1, 2)
+        assert f["row"] in (0, 1, 2)
 
 
 @pytest.mark.parametrize("target", ["one-uid", "all"])
@@ -160,17 +168,19 @@ def test_nonfinite_row_reads_negative_and_is_quarantined(engine, prompts,
     """``nan_logits`` on one uid and on ``POISON_ALL``: the poisoned
     rows of the step's results read negative — every one of a verify
     row's sub-steps — the others do not, and exactly the poisoned uids
-    are quarantined at that step."""
+    are quarantined when that step's result is read."""
     eng = engine("gpt2", speculate=speculate)
     seen = _spy(eng)
     for uid, p in enumerate(prompts):
         eng.submit(p, 24, uid=uid + 1)
     while sum(s is not None and s.prompt_done for s in eng.slots) < 3:
         assert eng.step()
+    eng.collect()
     healthy = len(seen)
     assert all((r >= 0).all() for *_, r in seen)
     eng.arm_poison(POISON_ALL if target == "all" else 2)
     assert eng.step()
+    eng.collect()       # the flags land when the result is read
     (kind, _, f, result), = seen[healthy:]
     assert kind == ("verify" if speculate else "decode")
     assert f["poison"] == (POISON_ALL if target == "all" else 2)
@@ -192,9 +202,10 @@ def test_arming_the_poison_compiles_nothing(engine, prompts):
     adds no entry to any program's jit cache and builds no program."""
     eng = engine("gpt2")
     for uid, p in enumerate(prompts):
-        eng.submit(p, 8, uid=uid + 1)
-    for _ in range(6):
+        eng.submit(p, 12, uid=uid + 1)
+    while sum(s is not None and s.prompt_done for s in eng.slots) < 3:
         assert eng.step()
+    assert eng.step()       # all three decode in one batch
 
     def entries():
         return {k: fn._cache_size() for k, fn in eng._programs.items()}
@@ -202,9 +213,12 @@ def test_arming_the_poison_compiles_nothing(engine, prompts):
     before, built = entries(), eng.compile_count
     assert before and all(n == 1 for n in before.values())
     eng.arm_poison(3)
-    assert eng.step() and 3 in eng.failed
+    assert eng.step()
+    eng.collect()
+    assert 3 in eng.failed
     eng.arm_poison(POISON_ALL)
     assert eng.step()
+    eng.collect()
     assert entries() == before and eng.compile_count == built
 
 

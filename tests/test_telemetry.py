@@ -114,9 +114,15 @@ from distributed_llm_code_samples_tpu.runtime.telemetry import (
 # v19 (PR 37): every dispatch says which program it ran — the
 # ``engine_step`` record pins ``dispatches`` (``[kind, bucket]`` a step
 # program launched, one entry a ``*.dispatch`` phase, in order).
-_PINNED_VERSION = 19
+# v20 (PR 38): a step's results are read one step late — the
+# ``engine_step`` record pins ``readbacks`` (the ordinal of the launch
+# each ``*.readback`` phase read, one entry a phase, in order; it may
+# be an earlier record's launch) and ``launches`` (the engine's
+# launches up to and with the step's own).
+_PINNED_VERSION = 20
 _PINNED_STEP_SPAN_REQUIRED = frozenset({"phases", "start_ns", "end_ns",
-                                        "dispatches"})
+                                        "dispatches", "readbacks",
+                                        "launches"})
 _PINNED_STEP_KEYS = frozenset({
     "schema", "kind", "t", "step", "strategy", "loss", "grad_norm",
     "tokens_per_sec", "step_time_s", "mfu", "hbm_high_water_bytes",
@@ -319,49 +325,96 @@ def test_anomaly_and_rollback_records_round_trip(tmp_path):
 
 
 def _engine_step(**over):
-    """A v19 ``engine_step`` record as ``engine._step_record`` builds
-    it: a tail chunk's program, then the batch's."""
+    """A v20 ``engine_step`` record as ``engine._step_record`` builds
+    it: a tail chunk's program, then the batch's; the batch's launch
+    (the engine's 12th) first reads the LAST step's (its 10th), then
+    the chunk's, and its own result waits for the next step."""
     rec = {"uid": None, "span": "engine_step", "start_step": 7, "step": 7,
            "start_ns": 1_000, "end_ns": 9_000, "t": 9e-6,
            "duration_s": 8e-6, "tokens_generated": 40, "state_bytes": 0,
            "expert_rows": 0, "experts_touched": 0, "expert_rows_max": 0,
            "phases": [["admit", 1_000, 1_100],
                       ["prefill.dispatch", 1_200, 1_300],
-                      ["prefill.readback", 1_300, 4_000],
+                      ["decode.readback", 1_300, 4_000],
                       ["decode.dispatch", 4_200, 4_300],
-                      ["decode.readback", 4_300, 8_000]],
-           "dispatches": [["prefill", 4], ["decode", 8]]}
+                      ["prefill.readback", 4_300, 8_000]],
+           "dispatches": [["prefill", 4], ["decode", 8]],
+           "readbacks": [9, 10], "launches": 12}
     rec.update(over)
     return rec
 
 
-def test_engine_step_v19_round_trips(tmp_path):
+def test_engine_step_v20_round_trips(tmp_path):
     """The record goes through the writer and comes back schema-valid
-    with ``dispatches`` entry for entry beside its phases."""
+    with ``dispatches`` and ``readbacks`` entry for entry beside its
+    phases; a step that only read what was in flight is one too."""
     w = TelemetryWriter(str(tmp_path))
     w.span(_engine_step())
-    w.span(_engine_step(step=8, start_step=8, phases=[], dispatches=[]))
+    w.span(_engine_step(step=8, start_step=8, dispatches=[],
+                        phases=[["decode.readback", 9_100, 9_900]],
+                        readbacks=[11]))
+    w.span(_engine_step(step=9, start_step=9, phases=[], dispatches=[],
+                        readbacks=[]))
     w.close()
     records, problems = read_metrics(os.path.join(str(tmp_path),
                                                   METRICS_FILENAME))
     assert problems == []
-    first, idle = records
-    assert first["schema"] == SCHEMA_VERSION == 19
+    first, closing, idle = records
+    assert first["schema"] == SCHEMA_VERSION == 20
     assert first["dispatches"] == [["prefill", 4], ["decode", 8]]
     assert [p[0] for p in first["phases"] if p[0].endswith(".dispatch")] \
         == [k + ".dispatch" for k, _ in first["dispatches"]]
-    assert idle["dispatches"] == []
+    # the first read is of an EARLIER record's launch, the second of
+    # this record's first (ordinals launches - 2 and launches - 1)
+    assert first["readbacks"] == [9, 10] and first["launches"] == 12
+    assert closing["dispatches"] == [] and closing["readbacks"] == [11]
+    assert idle["dispatches"] == idle["readbacks"] == []
+
+
+@pytest.mark.parametrize("case,named", [
+    ("v19_stamp", "schema"),            # an older writer's record
+    ("no_readbacks", "readbacks"),      # v19's key set under a v20 stamp
+    ("no_launches", "launches"),
+    ("one_read_short", "readbacks"),
+    ("one_read_over", "readbacks"),
+    ("reads_the_unlaunched", "launches"),
+])
+def test_engine_step_v19_shapes_are_refused(case, named):
+    """What schema v19 wrote is refused as the contract says: by the
+    version stamp, and under a v20 stamp by the missing ``readbacks`` /
+    ``launches``; entries that do not match the ``*.readback`` phases
+    one to one, or name a launch the engine had not made, are no record
+    of what the step read."""
+    rec = dict(_engine_step(), schema=SCHEMA_VERSION, kind="span",
+               trace_id=None, tenant=None)
+    ok, reason = validate_record(rec)
+    assert ok, reason
+    if case == "v19_stamp":
+        rec["schema"] = 19
+        del rec["readbacks"], rec["launches"]
+    elif case == "no_readbacks":
+        del rec["readbacks"]
+    elif case == "no_launches":
+        del rec["launches"]
+    elif case == "one_read_short":
+        rec["readbacks"] = rec["readbacks"][:1]
+    elif case == "one_read_over":
+        rec["readbacks"] = rec["readbacks"] + [11]
+    else:
+        rec["readbacks"] = [10, 12]
+    ok, reason = validate_record(rec)
+    assert not ok and named in reason and "\n" not in reason
 
 
 @pytest.mark.parametrize("case,named", [
     ("v18_stamp", "schema"),            # an older writer's record
-    ("no_dispatches", "dispatches"),    # v18's key set under a v19 stamp
+    ("no_dispatches", "dispatches"),    # v18's key set under a later stamp
     ("one_entry_short", "dispatches"),
     ("one_entry_over", "dispatches"),
 ])
 def test_engine_step_v18_shapes_are_refused(case, named):
     """What schema v18 wrote is refused as the contract says: by the
-    version stamp, and under a v19 stamp by the missing ``dispatches``;
+    version stamp, and under a later stamp by the missing ``dispatches``;
     entries that do not match the ``*.dispatch`` phases one to one are
     no record of what the step launched."""
     rec = dict(_engine_step(), schema=SCHEMA_VERSION, kind="span",

@@ -214,6 +214,8 @@ def test_single_engine_replay_deterministic_and_host_side_only(
     def run(mdir):
         m = TelemetryWriter(mdir)
         eng = DecodeEngine(lm_params, H, _cfg(), metrics=m)
+        eng.warm()      # the whole program set: which buckets a
+        # schedule happens to reach is not what is compared below
         summary = replay_trace(eng, header, entries, vocab=V,
                                log_every=4, metrics=m)
         m.close()
@@ -246,6 +248,7 @@ def test_single_engine_replay_deterministic_and_host_side_only(
     # the overhead criterion: hand-submit the SAME materialized
     # prompts — same program set, zero compiles the trace path adds
     hand = DecodeEngine(lm_params, H, _cfg())
+    hand.warm()
     for e in entries:
         hand.submit(materialize_prompt(header, e, V),
                     int(e["max_new"]))
