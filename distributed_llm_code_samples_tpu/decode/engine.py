@@ -43,10 +43,14 @@ step's (``_launch`` / ``_collect``; ``step``'s docstring says which
 reads wait and what a caller may read when): the scheduler works from
 counts it has at launch, and a slot's next token is handed from one
 program to the next on the device (the carry's token store). A
-recurrent layer's state lives beside the pool, by slot; what cannot
-carry it yet — prefix hits, speculation, a mesh, the KV handoff,
-snapshots, the spill tier — refuses in one line for such a model
-(``_refuse_recurrent``), by what the model is and under no flag. A
+recurrent layer's state lives beside the pool, by slot, and a window
+layer's blocks in a second pool with a short table and a free list of
+their own (``wtables``, ``free_wblocks``: a slot's window blocks are a
+ring, taken at admission and handed back with the slot; ``paged.py``);
+what moves a sequence by its ONE block table alone — prefix hits,
+speculation, a mesh, the KV handoff, snapshots, the spill tier —
+refuses in one line for such a model
+(``_refuse_kept_beside``), by what the model is and under no flag. A
 latent-cache layer's rows live IN the pool (one row a token, no heads:
 ``paged.py``), so all of those carry them unchanged and only what needs
 KV heads refuses: int8 scales (``paged.init_pool``) and a mesh (here).
@@ -174,7 +178,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.face import ATTN, LATENT, ServedModel
+from ..models.face import ATTN, LATENT, WINDOW, ServedModel
 from ..parallel import launcher
 from ..runtime.policy import QosPolicy
 from ..runtime.telemetry import FLIGHT_FILENAME, STEP_SPAN
@@ -261,6 +265,15 @@ FLIGHT_RECORDER_STEPS = 256
 # the expert layers' counters a step's ``engine_step`` record and flight
 # digest carry (``DecodeEngine._fold_expert_rows``)
 EXPERT_COUNTERS = ("expert_rows", "experts_touched", "expert_rows_max")
+
+# ... and the cache reads' (``DecodeEngine._count_rows``): the cached
+# positions the step's launched rows attend over in a window layer and
+# in a full one, the window blocks that left a sequence in the step
+# (overwritten in its ring, behind its window, or handed back with its
+# slot) and those sequences hold at its end. All 0 for a model with no
+# window layer
+WINDOW_COUNTERS = ("window_rows", "full_rows", "window_blocks_released",
+                   "window_blocks_live")
 
 
 class AdmissionError(RuntimeError):
@@ -420,6 +433,9 @@ class _Seq:
     out: list[int] = field(default_factory=list)
     prefilled: int = 0
     blocks: list[int] = field(default_factory=list)
+    # the window layers' blocks (a model with none holds none): the
+    # slot's ring, block ``j`` of the sequence in entry ``j mod len``
+    wblocks: list[int] = field(default_factory=list)
     # nodes[i] is the PrefixNode backing blocks[i] when that leading
     # block is shared through the radix cache (a prefix-hit at
     # admission, or this sequence's own full prompt block transferred
@@ -551,20 +567,27 @@ class DecodeEngine:
         # cannot carry that state yet refuses, here and at the entry of
         # every later call, by what the model is: no flag turns it off
         kinds = {kind for kind, _ in params.layers}
-        self.recurrent = sorted(kinds - {ATTN, LATENT})
+        self.recurrent = sorted(kinds - {ATTN, LATENT, WINDOW})
+        self.windowed = WINDOW in kinds
+        if self.windowed and cfg.kv_dtype == "int8":
+            raise ValueError(
+                "kv_dtype int8 is not served for a model with window "
+                "layers: a block's scale is its write history's, and a "
+                "window block is overwritten as a ring")
         if mesh is not None:
-            self._refuse_recurrent("a model-axis mesh (--tp)")
+            self._refuse_kept_beside("a model-axis mesh (--tp)")
             if LATENT in kinds:
                 raise ValueError(
                     "a model-axis mesh (--tp) is not served for a model "
                     "with latent-cache layers: the pool is sharded by "
                     "KV heads, and a latent row has none")
         if cfg.speculate:
-            self._refuse_recurrent("speculate > 0 (a rejected draft "
-                                   "would have to be undone in the state)")
+            self._refuse_kept_beside("speculate > 0 (a rejected draft "
+                                     "would have to be undone there)")
         if cfg.spill_blocks or cfg.prefix_partial:
-            self._refuse_recurrent("spill_blocks / prefix_partial (they "
-                                   "extend the prefix cache, which is off)")
+            self._refuse_kept_beside("spill_blocks / prefix_partial (they "
+                                     "extend the prefix cache, which is "
+                                     "off)")
         self.params = params
         self.n_heads = n_heads
         self.cfg = cfg
@@ -614,6 +637,9 @@ class DecodeEngine:
         # (None for a model that has none): donated into the step
         # programs together and updated in place
         self.pool, self.state = self.programs.init_cache()
+        # ... and the window layers' pool (None for a model with none):
+        # a second block pool, its own scratch block, table and free list
+        self.wpool = self.programs.init_window()
         # each slot's next token, on the device beside them (and one
         # scratch row): a row's pick is handed to the slot's next row
         # there, so a step can be launched before the last one is read
@@ -633,6 +659,10 @@ class DecodeEngine:
         self.failed: dict[int, dict] = {}     # uid -> {reason, retries}
         self.prompt_lens: dict[int, int] = {}  # uid -> len(prompt)
         self.free_blocks = list(range(1, cfg.n_blocks))
+        # the window kind's: a slot's short table, used as a ring
+        wt = self.programs.window_blocks
+        self.wtables = np.full((s, wt), SCRATCH_BLOCK, np.int32)
+        self.free_wblocks = list(range(1, 1 + s * wt))
         self.slot_buckets = _buckets(cfg.max_slots)
         self.chunk_buckets = _buckets(cfg.prefill_chunk)
         self._programs: dict = {}
@@ -704,11 +734,12 @@ class DecodeEngine:
                       if cfg.prefix_cache and cfg.spill_blocks > 0
                       else None)
         # a KV block hit is worth nothing without the recurrent state at
-        # that boundary (ROADMAP M4), so a model with recurrent layers
-        # takes no hits and inserts no blocks: no cache object at all
+        # that boundary (ROADMAP M4), nor without the window layers'
+        # blocks up to it (a hit is valid by layer kind: M3), so such a
+        # model takes no hits and inserts no blocks: no cache object
         self.prefix = (PrefixCache(cfg.block_size, spill=self.spill)
                        if cfg.prefix_cache and not self.recurrent
-                       else None)
+                       and not self.windowed else None)
         # cumulative, snapshot-persisted (monotonic across crash-resume
         # like the churn trio): hit blocks mapped at admission, prompt
         # tokens those hits skipped, copy-on-write triggers (0 in
@@ -761,6 +792,9 @@ class DecodeEngine:
         # row a ready slot; written back the same size): the engine_step
         # record's and the digest's ``state_bytes``
         self._step_state_bytes = 0
+        # the cache reads of the rows this step launched and the window
+        # blocks' turnover (``WINDOW_COUNTERS``, ``_count_rows``)
+        self._step_window = dict.fromkeys(WINDOW_COUNTERS, 0)
         # the step programs this step launched, ``[kind, bucket]`` in
         # launch order (``_launch``): the engine_step record's and the
         # digest's ``dispatches``
@@ -784,21 +818,28 @@ class DecodeEngine:
 
     # -- pool ----------------------------------------------------------
 
-    def _refuse_recurrent(self, what: str) -> None:
-        """The one line every path that cannot carry a recurrent state
-        refuses with, for a model that has one."""
+    def _refuse_kept_beside(self, what: str) -> None:
+        """The one line every path that moves a sequence by its ONE
+        block table refuses with, for a model that keeps more of a
+        sequence beside it: a recurrent state, or window layers' blocks
+        in a table of their own."""
         if self.recurrent:
             raise ValueError(
                 f"{what} is not served for a model with "
                 f"{'/'.join(self.recurrent)} layers: it cannot carry "
                 "their recurrent state yet")
+        if self.windowed:
+            raise ValueError(
+                f"{what} is not served for a model with window layers: "
+                "it moves a sequence by one block table, and theirs is "
+                "a second one")
 
     def _cache(self):
         """What a model's forward reads and writes of a sequence: the
-        pool, and for a model with recurrent layers the pair (pool,
-        recurrent state)."""
-        return self.pool if self.state is None else (self.pool,
-                                                      self.state)
+        pool, and for a model that keeps more the tuple of it all
+        (``StepPrograms.whole``: the window layers' pool, the recurrent
+        state)."""
+        return self.programs.whole(self.pool, self.wpool, self.state)
 
     def _carry(self):
         """The donated operand of every step program: ``_cache()`` and
@@ -809,10 +850,7 @@ class DecodeEngine:
         """Take back what a step program returned in ``_carry()``'s
         place."""
         cache, self.token_store = carry
-        if self.state is None:
-            self.pool = cache
-        else:
-            self.pool, self.state = cache
+        self.pool, self.wpool, self.state = self.programs.parts(cache)
 
     # -- compiled programs (one per (kind, bucket); bounded) -----------
 
@@ -972,7 +1010,7 @@ class DecodeEngine:
         KV the sync path would have shipped). No handoff event is
         emitted and no span closes until the commit — the sequence has
         not left yet."""
-        self._refuse_recurrent("export_sequence (the KV handoff)")
+        self._refuse_kept_beside("export_sequence (the KV handoff)")
         if self.mesh is not None:
             raise ValueError(
                 "KV handoff is single-device (the fleet runs "
@@ -1094,7 +1132,7 @@ class DecodeEngine:
         next step — no replay, no prefill dispatch. Model fingerprint
         and the numerics-relevant config keys must match the source's
         (pool-size keys may differ; that is the point of renumbering)."""
-        self._refuse_recurrent("import_sequence (the KV handoff)")
+        self._refuse_kept_beside("import_sequence (the KV handoff)")
         if self.mesh is not None:
             raise ValueError(
                 "KV handoff is single-device (the fleet runs "
@@ -1612,6 +1650,9 @@ class DecodeEngine:
             head_i, head_vt = self._next_waiting_index()
             seq = self.waiting[head_i]
             need = self._blocks_needed(len(seq.prompt), seq.max_new)
+            # ... and of the window kind a constant: a ring as long as
+            # the window table, or the whole request where it is shorter
+            need_w = min(need, self.programs.window_blocks)
             free_slots = [i for i, s in enumerate(self.slots) if s is None]
             if not free_slots:
                 break
@@ -1642,7 +1683,7 @@ class DecodeEngine:
                 # nodes themselves (about to be locked, not evicted)
                 avail += (self.prefix.evictable_blocks()
                           - sum(1 for n in resident if n.refs == 0))
-            if need - n_res > avail:
+            if need - n_res > avail or need_w > len(self.free_wblocks):
                 pa = self.policy.preempt_after_steps
                 if pa > 0 and self._inflight is not None:
                     # a starved head is judged, and a victim replayed
@@ -1758,6 +1799,8 @@ class DecodeEngine:
                           np.int32)
             row[:need] = seq.blocks
             self.tables[slot] = row
+            seq.wblocks = [self.free_wblocks.pop(0) for _ in range(need_w)]
+            self.wtables[slot, :need_w] = seq.wblocks
             self.lengths[slot] = 0
             self.uids[slot] = seq.uid
             self.slots[slot] = seq
@@ -1997,6 +2040,15 @@ class DecodeEngine:
             self.pool = scrub_blocks(self.pool, sorted(to_scrub))
             self._corrupted.difference_update(to_scrub)
             self.block_scrubs += len(to_scrub)
+        if seq.wblocks:
+            # the window kind's go back whole (none is ever shared),
+            # scrubbed where the run's bytes are not trusted
+            if drop_shared:
+                self.wpool = scrub_blocks(self.wpool, seq.wblocks)
+            self._step_window["window_blocks_released"] += len(seq.wblocks)
+            self.free_wblocks.extend(seq.wblocks)
+            seq.wblocks = []
+            self.wtables[slot] = SCRATCH_BLOCK
         self.block_frees += len(seq.blocks)
         self.free_blocks.extend(to_free)
         seq.blocks = []
@@ -2377,9 +2429,12 @@ class DecodeEngine:
         start, the next ``c`` prompt tokens (under the name the program
         gives them), uid and the slot itself (its entry of the token
         store and its state row)."""
-        return {"table": self.tables[slot], "pos0": seq.prefilled,
-                tokens: seq.prompt[seq.prefilled:seq.prefilled + c],
-                "uid": seq.uid, "row": slot}
+        fields = {"table": self.tables[slot], "pos0": seq.prefilled,
+                  tokens: seq.prompt[seq.prefilled:seq.prefilled + c],
+                  "uid": seq.uid, "row": slot}
+        if self.windowed:
+            fields["wtable"] = self.wtables[slot]
+        return fields
 
     def _prefill_chunk(self, seq: _Seq) -> int:
         """The next chunk's size for ``seq``."""
@@ -2408,11 +2463,31 @@ class DecodeEngine:
         ``_prefill_book`` takes when the chunk's pick lands."""
         self._step_prefill_uid = seq.uid
         row = self._row(slot)
+        if self.windowed:
+            # one view for the chunk's rows: the positions up to its end
+            self._count_rows(np.asarray([seq.prefilled + c - 1]),
+                             np.asarray([seq.prefilled]))
         seq.prefilled += c
         if seq.prompt_done:
             self.lengths[slot] = len(seq.prompt)
             seq.launched = 1
         return row, c, seq.prefilled
+
+    def _count_rows(self, last: np.ndarray, first: np.ndarray) -> None:
+        """Count the cache reads of rows about to be launched
+        (``WINDOW_COUNTERS``; a model with window layers only): each
+        view ends at position ``last`` and was opened by writing from
+        ``first`` on (a decode row writes one position, a chunk its
+        own), so a full layer reads ``last + 1`` positions, a window
+        layer at most the window, and every block the write OPENS
+        beyond the ring's length overwrites one that is behind it."""
+        blk, entries = self.cfg.block_size, self.programs.window_blocks
+        w = self._step_window
+        w["full_rows"] += int((last + 1).sum())
+        w["window_rows"] += int(np.minimum(last + 1, self.spec.window).sum())
+        opened = last // blk - (first - 1) // blk   # block starts in range
+        fresh = np.minimum(opened, last // blk + 1 - entries)
+        w["window_blocks_released"] += int(np.maximum(fresh, 0).sum())
 
     def _prefill_book(self, row: tuple, c: int, end: int,
                       nxt: int) -> bool:
@@ -2509,8 +2584,14 @@ class DecodeEngine:
         if self.state is not None:
             self._step_state_bytes += (len(ready)
                                        * self.state.bytes_per_slot)
-        return dict(tables=tables, lengths=lengths, tokens=tokens,
-                    uids=uids, poison=self._poison_uid, rows=rows)
+        fields = dict(tables=tables, lengths=lengths, tokens=tokens,
+                      uids=uids, poison=self._poison_uid, rows=rows)
+        if self.windowed:
+            wtables = np.full((b, self.wtables.shape[1]), SCRATCH_BLOCK,
+                              np.int32)
+            wtables[:len(ready)] = self.wtables[ready]
+            fields["wtables"] = wtables
+        return fields
 
     def _count_batch(self, ready: list[int]) -> list[tuple]:
         """Advance the COUNTS of a decode batch that is about to be
@@ -2518,6 +2599,8 @@ class DecodeEngine:
         tokens. Returns the rows ``_emit_batch`` takes when the picks
         land."""
         rows = [self._row(slot) for slot in ready]
+        if self.windowed:
+            self._count_rows(self.lengths[ready], self.lengths[ready])
         self.lengths[ready] += 1
         for _, seq, _ in rows:
             seq.launched += 1
@@ -2804,6 +2887,7 @@ class DecodeEngine:
         self._step_prefill_uid = None
         self._step_decode_uids = []
         self._step_state_bytes = 0
+        self._step_window = dict.fromkeys(WINDOW_COUNTERS, 0)
         self._step_dispatches = []
         self._step_readbacks = []
         self._step_expert_rows = []
@@ -2854,6 +2938,8 @@ class DecodeEngine:
         did = bool(self._step_dispatches or self._step_readbacks)
         with phase("digest"):
             self._step_experts = self._fold_expert_rows()
+            self._step_window["window_blocks_live"] = (
+                self.wtables.size - len(self.free_wblocks))
             if self._step_restores:
                 # budget-deferred admission: restores ran compiled
                 # implant work this step even if no prefill/decode
@@ -2888,7 +2974,7 @@ class DecodeEngine:
 
     def _step_record(self, start_ns: int, end_ns: int) -> dict:
         """The executed step as ONE ``engine_step`` span record
-        (telemetry v20): the parent span and its phases in the order
+        (telemetry v21): the parent span and its phases in the order
         they closed (each a child by being in this list), the step
         programs it launched (``dispatches``: the i-th entry belongs to
         the i-th ``*.dispatch`` phase) and the launches whose results
@@ -2897,8 +2983,9 @@ class DecodeEngine:
         earlier step's record; ``launches`` counts the engine's
         launches up to and with this step's, so the record's own are
         the last ``len(dispatches)`` ordinals below it). The expert
-        counters are those of the results READ. ``tokens_generated`` is
-        what a reader joins a step on."""
+        counters are those of the results READ; the cache reads'
+        (``WINDOW_COUNTERS``) of the rows LAUNCHED. ``tokens_generated``
+        is what a reader joins a step on."""
         return {
             "uid": None,
             "span": STEP_SPAN,
@@ -2912,6 +2999,7 @@ class DecodeEngine:
             "tokens_generated": self.tokens_generated,
             "state_bytes": self._step_state_bytes,
             **self._step_experts,
+            **self._step_window,
             "dispatches": self._step_dispatches,
             "readbacks": list(self._step_readbacks),
             "launches": self.launches,
@@ -2977,6 +3065,12 @@ class DecodeEngine:
             free += self.prefix.evictable_blocks()
         return (usable - free) / usable
 
+    def window_pool_utilization(self) -> float:
+        """Taken fraction of the window layers' pool (0.0 for a model
+        with none); ``kv_pool_utilization`` is of the full kind's."""
+        usable = self.wtables.size
+        return (usable - len(self.free_wblocks)) / usable if usable else 0.0
+
     def live_tokens(self) -> int:
         """Cached positions currently holding real KV, summed over
         active slots. ``lengths[slot]`` only starts counting at prompt
@@ -3026,6 +3120,9 @@ class DecodeEngine:
             "tokens_per_sec": tokens_per_sec,
             "batch_occupancy": round(self.active / self.cfg.max_slots, 4),
             "kv_pool_utilization": round(self.kv_pool_utilization(), 4),
+            # extra: the window layers' pool (0.0 for a model with none)
+            "window_pool_utilization": round(
+                self.window_pool_utilization(), 4),
             "free_blocks": free,
             "free_blocks_low_water": lo,
             "free_blocks_high_water": hi,
@@ -3134,6 +3231,9 @@ class DecodeEngine:
             # the expert layers' counters of this step's dispatches
             # (0 for a model with no expert layer)
             **self._step_experts,
+            # the cache reads of the rows launched and the window
+            # blocks' turnover (0 for a model with no window layer)
+            **self._step_window,
             # where the step's host time went up to this digest
             # (runtime/tracing.py PhaseTimer): what an UNTRACED run's
             # ring says about a slow step
@@ -3193,7 +3293,10 @@ class DecodeEngine:
         operand = self.programs.pack(
             "decode", b, tables=np.full((b, self.cfg.max_blocks_per_seq),
                                         SCRATCH_BLOCK),
-            lengths=z, tokens=z, uids=z, poison=POISON_NONE, rows=z)
+            lengths=z, tokens=z, uids=z, poison=POISON_NONE, rows=z,
+            **({"wtables": np.full((b, self.wtables.shape[1]),
+                                   SCRATCH_BLOCK)}
+               if self.windowed else {}))
         rep = StepReport.of(self.programs.body("decode", b), self.params,
                             self._carry(), operand)
         per_tok = self._kv_bytes_per_token()

@@ -983,16 +983,17 @@ def generate_main(argv=None) -> int:
         params = None
         if model_config is not None:
             params = params_from_config(model_config, args.random_seed)
-            kinds = sorted({k for k, _ in params.layers}
-                           - {"attn", "latent"})
+            kinds = {k for k, _ in params.layers} - {"attn", "latent"}
             if kinds and (args.fleet or args.snapshot_dir):
-                # both move a sequence by its KV blocks alone (handoff,
-                # snapshot-resume): refused up front, by what the model
-                # is, and not mid-serve
+                # both move a sequence by its ONE block table alone
+                # (handoff, snapshot-resume): refused up front, by what
+                # the model is, and not mid-serve
+                why = ("it moves a sequence by one block table, and "
+                       "theirs is a second one" if kinds == {"window"}
+                       else "they cannot carry their recurrent state yet")
                 raise ValueError(
                     "--fleet / --snapshot_dir are not served for a "
-                    f"model with {'/'.join(kinds)} layers: they cannot "
-                    "carry their recurrent state yet")
+                    f"model with {'/'.join(sorted(kinds))} layers: {why}")
         elif not (args.fleet and args.transport in ("process", "tcp")):
             params = init_lm(jax.random.PRNGKey(args.random_seed),
                              args.vocab, args.model_size, args.layers,

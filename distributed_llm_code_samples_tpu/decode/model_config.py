@@ -3,7 +3,8 @@
 ONE function builds the engine for ``train_ffns.py generate
 --model_config FILE`` and for the benchmark's driver file
 (``benchmark/configs/jamba_engine_driver.py``,
-``glm_moe_engine_driver.py``, ``lfm2_moe_engine_driver.py``): the
+``glm_moe_engine_driver.py``, ``lfm2_moe_engine_driver.py``,
+``laguna_engine_driver.py``): the
 published keys say what the model is
 (``model_type`` picks the family's file under ``models/``, its
 ``spec_from_config`` reads the rest), the weights come from a seed or from the caller (a checkpoint restored into
@@ -17,7 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..models import hybrid_lm, lfm2_moe_lm, mla_moe_lm
+from ..models import hybrid_lm, laguna_lm, lfm2_moe_lm, mla_moe_lm
 from .engine import DecodeEngine, EngineConfig
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -29,6 +30,7 @@ FAMILIES = {
                       mla_moe_lm.init_mla_moe_lm),
     "lfm2_moe": (lfm2_moe_lm.spec_from_config,
                  lfm2_moe_lm.init_lfm2_moe_lm),
+    "laguna": (laguna_lm.spec_from_config, laguna_lm.init_laguna_lm),
 }
 
 

@@ -65,6 +65,26 @@ whole row, values over its first ``latent_rank`` lanes, so one gather
 serves both products. int8 has per-head scales and a latent row no
 heads: refused.
 
+A **window** pool (``models/face.py::WINDOW``: the third paged kind) is
+a second ``PagedKV`` beside the first, for the layers that attend over
+the last ``window`` positions only: ``k/v [L_w, n_blocks_w, block,
+H_kv*dh]`` in the same stored form, with a scratch block and a free
+list of its own. A sequence names its window blocks through a second,
+short table (``window / block + 2`` entries at most: the window, the
+block being written, one of slack) that it uses as a RING: the block of
+positions ``[j*block, (j+1)*block)`` lies in entry ``j mod entries``, so
+a block is overwritten exactly when every position in it is behind the
+window of every row still to come (``ring_positions`` says which
+position each stored row holds; ``models/attention.py::window_mask``
+hides the rest, stale rows of an overwritten block among them). The
+writes and the two reads are the full kind's, told the window
+(``write_chunk(ring=True)``, ``stored_decode_attn(window=)``,
+``gathered_chunk_attn(window=)``): a window layer's gather is its short
+table, never the sequence's whole capacity. What moves a sequence by
+ONE block table (the prefix cache, spill, handoff, snapshots,
+speculation, int8's write history, the head-sharded mesh) refuses a
+model with window layers in one line (``decode/engine.py``).
+
 The pool's layer axis counts the layers that own a KV cache index: all
 of an ``LMParams``' layers, the attention layers only of a hybrid
 (``models/hybrid_lm.py``: 2 of 28). What such a model's other layers
@@ -338,20 +358,38 @@ def write_rows(pool: PagedKV, layer: int, phys: jax.Array,
     return pool._replace(k=k, v=v, k_scale=ks, v_scale=vs)
 
 
+def ring_positions(last, entries: int, block: int) -> jax.Array:
+    """The global position each stored row of a window table holds once
+    the row at position ``last`` is written: ``last [...] -> [...,
+    entries * block]``. Entry ``e`` holds the block ``j = jc - ((jc -
+    e) mod entries)``, ``jc = last // block`` the block being written
+    (the newest block congruent to ``e``), so its row ``o`` is position
+    ``j * block + o``: negative where the entry was never written,
+    beyond ``last`` where the row is still a stale one of the block's
+    last use (``models/attention.py::window_mask`` hides both)."""
+    jc = jnp.asarray(last)[..., None] // block
+    j = jc - (jc - jnp.arange(entries)) % entries           # [..., entries]
+    pos = j[..., None] * block + jnp.arange(block)
+    return pos.reshape(*pos.shape[:-2], entries * block)
+
+
 def write_chunk(pool: PagedKV, layer: int, table: jax.Array, pos0,
                 k_new: jax.Array, v_new: jax.Array,
-                kv_dtype: str) -> PagedKV:
+                kv_dtype: str, ring: bool = False) -> PagedKV:
     """Write one sequence's prefill chunk: ``k_new/v_new [C, H_kv, dh]``
     f32 at global positions ``pos0 .. pos0+C-1`` through ``table
     [max_blocks]``. The engine's power-of-two chunk buckets never
     straddle a block boundary (chunk starts are multiples of the chunk
     size and ``block_size`` is a power of two >= or <= every bucket), so
     a chunk either part-fills exactly one block (``C < block``) or
-    covers ``C/block`` whole blocks — the two static cases below."""
+    covers ``C/block`` whole blocks — the two static cases below.
+    ``ring``: ``table`` is a window layer's short table, used as a ring
+    (block ``j`` lies in entry ``j mod len(table)``)."""
     c = k_new.shape[0]
     blk = pool.block_size
     positions = pos0 + jnp.arange(c)
-    phys = table[positions // blk]
+    entry = positions // blk
+    phys = table[entry % table.shape[0] if ring else entry]
     off = positions % blk
     if kv_dtype != "int8" or c < blk:
         # int8 c<blk touches ONE block; write_rows' per-row requant
@@ -553,11 +591,15 @@ def corrupt_block(pool: PagedKV, block: int) -> PagedKV:
 
 
 def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
-                       tables: jax.Array, lengths: jax.Array) -> jax.Array:
+                       tables: jax.Array, lengths: jax.Array,
+                       window: int = 0) -> jax.Array:
     """Single-query attention for one layer over the gathered rows AS
     STORED — what the engine's decode-side programs run. ``q [B, H,
     dh]`` f32, ``tables [B, MB]`` int32, ``lengths [B]`` attendable
-    positions; returns ``[B, H, dh]`` f32. The same mathematics as the oracle ``decode_attn(q,
+    positions; returns ``[B, H, dh]`` f32. ``window`` > 0: ``tables``
+    are window layers' short tables, used as rings, and a row attends
+    over its last ``window`` positions (``ring_positions``). The same
+    mathematics as the oracle ``decode_attn(q,
     *vmap(gather_layer), lengths)`` — same mask, scale and f32 softmax —
     as two matrix products over ``[B, T_cap, H_kv*dh]`` in the pool's
     dtype, accumulated in f32:
@@ -611,7 +653,15 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
         if pool.k_scale is not None:
             s = s * ks
         s = s / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-        mask = jnp.arange(k.shape[1]) < lengths[:, None, None]
+        if window:
+            from ..models.attention import window_mask
+            last = lengths - 1
+            mask = window_mask(
+                last[:, None, None],
+                ring_positions(last, tables.shape[1], blk)[:, None, :],
+                window)
+        else:
+            mask = jnp.arange(k.shape[1]) < lengths[:, None, None]
         p = jax.nn.softmax(jnp.where(mask, s, jnp.float32(-1e30)), axis=-1)
         if pool.k_scale is not None:
             p = p * vs
@@ -698,16 +748,27 @@ def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
 
 
 def gathered_chunk_attn(pool: PagedKV, layer: int, q: jax.Array,
-                        table: jax.Array, pos0) -> jax.Array:
+                        table: jax.Array, pos0,
+                        window: int = 0) -> jax.Array:
     """A prefill chunk's read: ``q [C, H, dh]`` at positions ``pos0 ..
     pos0+C-1`` of ONE sequence attends causally over its gathered view
     (``gather_layer`` + ``models.attention.chunk_attn``, the oracle's
     arithmetic: one slot's f32 head-split view is small). Returns
-    ``[C, H, dh]``."""
-    from ..models.attention import chunk_attn
+    ``[C, H, dh]``. ``window`` > 0: ``table`` is a window layer's short
+    table, a ring the chunk's rows were just written into, and each row
+    sees the last ``window`` positions up to its own (the rows of one
+    chunk have different window starts)."""
+    from ..models.attention import chunk_attn, window_mask
     if pool.latent_rank:
         return _latent_chunk_attn(pool, layer, q, table, pos0)
     ck, cv = gather_layer(pool, layer, table)
+    mask = None
+    if window:
+        c = q.shape[0]
+        mask = window_mask(
+            (pos0 + jnp.arange(c))[:, None],
+            ring_positions(pos0 + c - 1, table.shape[0],
+                           pool.block_size)[None, :], window)
     with jax.named_scope("attn"):
-        y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0)
+        y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0, mask)
     return y.transpose(1, 0, 2)

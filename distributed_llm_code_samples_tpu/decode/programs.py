@@ -21,8 +21,10 @@ never sees a sequence, a slot table or a metrics writer.
 
 A step program is ``run(params, carry, operand) -> (carry, result)``.
 ``carry`` is the donated operand, ``(cache, tokens)``, and comes back in
-the same form: ``cache`` the ``PagedKV``, or for a model with recurrent
-layers the pair ``(PagedKV, RecurrentState)``; ``tokens [max_slots + 1]``
+the same form: ``cache`` the ``PagedKV``, or for a model that keeps more
+the tuple of what it keeps, in the order ``(PagedKV, the window layers'
+PagedKV, RecurrentState)`` (``StepPrograms.whole`` / ``parts``: a
+hybrid's is ``(PagedKV, RecurrentState)``); ``tokens [max_slots + 1]``
 each slot's NEXT token (``init_tokens``; the last row is the pad rows'
 scratch). A decode row's pick, and the pick of a chunk's last row, is
 written to its slot's entry, and a row whose operand token is
@@ -64,7 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.face import ATTN, LATENT, CacheSpec, take
+from ..models.face import ATTN, LATENT, WINDOW, CacheSpec, take
 from ..parallel.collectives import all_gather, all_reduce
 from ..parallel.lm import tp_decode_specs, vp_embed
 from ..parallel.mesh import MODEL_AXIS
@@ -136,6 +138,15 @@ def _with_counts(picks, counts):
     return jnp.concatenate([picks.reshape(-1), counts.reshape(-1)])
 
 
+def window_entries(cfg, window: int) -> int:
+    """Entries of a slot's window table under ``cfg``: the window's
+    blocks, the blocks a prefill chunk writes before it reads, one of
+    slack; never more than a sequence can hold at all."""
+    blk = cfg.block_size
+    return min(cfg.max_blocks_per_seq,
+               -(-window // blk) + max(1, cfg.prefill_chunk // blk) + 1)
+
+
 class StepPrograms:
     """The programs of one engine configuration over one model's face:
     ``build(kind, bucket)`` is what the engine dispatches, ``body`` the
@@ -149,6 +160,10 @@ class StepPrograms:
         self.pick = make_pick(cfg.temperature, cfg.top_k, cfg.top_p, vocab,
                               cfg.seed)
         self._wires: dict = {}
+        # entries of a slot's window table (0 for a model with no window
+        # layer)
+        self.window_blocks = (window_entries(cfg, spec.window)
+                              if spec.win_layers else 0)
 
     # -- the wire format ---------------------------------------------------
 
@@ -165,13 +180,17 @@ class StepPrograms:
         position, ``bucket`` tokens, uid, the poison and its slot
         (``row``). Mixed: decode's for the ``bucket``-row batch plus ONE
         slot's table, start position, full chunk
-        (``cfg.prefill_chunk`` tokens), uid and slot."""
+        (``cfg.prefill_chunk`` tokens), uid and slot. A model with
+        window layers has, beside every ``tables`` / ``table``, the
+        rows' short window tables (``wtables`` / ``wtable``)."""
         w = self._wires.get((kind, bucket))
         if w is None:
-            t = self.cfg.max_blocks_per_seq
+            t, wt = self.cfg.max_blocks_per_seq, self.window_blocks
             if kind == "prefill":
                 fields = {"table": (t,), "pos0": (), "tokens": (bucket,),
                           "uid": (1,), "poison": (), "row": ()}
+                if wt:
+                    fields["wtable"] = (wt,)
             else:
                 fields = {"tables": (bucket, t), "lengths": (bucket,),
                           "tokens": (bucket,), "uids": (bucket,),
@@ -185,6 +204,10 @@ class StepPrograms:
                     fields.update(table=(t,), pos0=(),
                                   chunk=(self.cfg.prefill_chunk,), uid=(1,),
                                   row=())
+                if wt:
+                    fields["wtables"] = (bucket, wt)
+                    if kind == "mixed":
+                        fields["wtable"] = (wt,)
             w = self._wires[kind, bucket] = Wire(fields)
         return w
 
@@ -225,6 +248,33 @@ class StepPrograms:
                                d_conv=spec.d_conv)
         return pool, state
 
+    def init_window(self) -> PagedKV | None:
+        """The window layers' zero pool (None for a model with none): a
+        whole ring for every slot and a scratch block of its own."""
+        cfg, spec = self.cfg, self.spec
+        if not spec.win_layers:
+            return None
+        return init_pool(spec.win_layers,
+                         1 + cfg.max_slots * self.window_blocks,
+                         spec.kv_heads, cfg.block_size, spec.head_dim,
+                         cfg.kv_dtype)
+
+    def whole(self, pool, wpool=None, state=None):
+        """The cache a step program carries: the pool alone, or the
+        tuple of what the model keeps, in this order."""
+        kept = tuple(x for x in (pool, wpool, state) if x is not None)
+        return pool if len(kept) == 1 else kept
+
+    def parts(self, cache) -> tuple:
+        """``(pool, window pool, recurrent state)`` of a carried cache,
+        None where the model has not the part."""
+        spec = self.spec
+        if not (spec.win_layers or spec.rec_layers):
+            return cache, None, None
+        kept = iter(cache)
+        return (next(kept), next(kept) if spec.win_layers else None,
+                next(kept) if spec.rec_layers else None)
+
     def init_tokens(self) -> jax.Array:
         """The token store: each slot's next token, and one scratch row
         that a bucket's padded rows write (replicated under a mesh)."""
@@ -248,12 +298,16 @@ class StepPrograms:
         return p.embed(tokens, positions,
                        take if self.mesh is None else vp_embed)
 
-    def _trunk(self, p, cache, x, positions, write_attn, mix=None):
+    def _trunk(self, p, cache, x, positions, write_attn, mix=None,
+               write_window=None):
         """The walk over ``p.layers`` every program runs. Attention:
         norm, q/k/v, the caller's ``write_attn(i, pool, q, k, v) ->
         (pool, y [N, h_loc, dh])`` (where the programs differ: batched
         single-token writes and per-slot reads, or one slot's chunk),
-        output projection. Latent: the same seam — the query for the
+        output projection. Window: the same over the window layers' own
+        pool, ``write_window(i, wpool, q, k, v)``, between the model's
+        ``window_qkv`` and ``window_out``. Latent: the same seam — the
+        query for the
         stored row as ``q``, the row as the one "key" of one head and
         no value (the pool's ``v`` is zero lanes wide); the read's ``[N,
         h, latent_rank]`` goes to the model's ``latent_out``. Recurrent:
@@ -263,7 +317,7 @@ class StepPrograms:
         [expert_layers, n_experts]`` the rows each held expert received,
         None for a model with no expert layer."""
         tp = self.mesh is not None
-        pool, state = cache if self.spec.rec_layers else (cache, None)
+        pool, wpool, state = self.parts(cache)
         n = x.shape[0]
         counts = []
         for l, (kind, i) in enumerate(p.layers):
@@ -272,7 +326,11 @@ class StepPrograms:
                 q, k, v = p.attn_qkv(i, a, positions, self.spec.head_dim,
                                      self.cfg.use_rope)
                 pool, y = write_attn(i, pool, q, k, v)
-                y = p.attn_out(i, y.reshape(n, -1))
+                y = p.attn_out(i, y.reshape(n, -1), a)
+            elif kind == WINDOW:
+                q, k, v = p.window_qkv(i, a, positions)
+                wpool, y = write_window(i, wpool, q, k, v)
+                y = p.window_out(i, y.reshape(n, -1), a)
             elif kind == LATENT:
                 with jax.named_scope("mla"):
                     q, row = p.latent_qrow(i, a, positions)
@@ -290,7 +348,7 @@ class StepPrograms:
             else:
                 f = p.ffn(l, h)
             x = x + (all_reduce(f, MODEL_AXIS) if tp else f)
-        return ((pool if state is None else (pool, state)), x,
+        return (self.whole(pool, wpool, state), x,
                 jnp.stack(counts) if counts else None)
 
     def logits(self, p, x):
@@ -301,12 +359,14 @@ class StepPrograms:
             logits = all_gather(logits, MODEL_AXIS, dim=1)
         return logits
 
-    def _batch_seams(self, b: int, p, tables, lengths, rows):
-        """``(write_attn, mix)`` of ``b`` decode rows: each row's token
-        written at its own position and attended over its blocks as
-        stored; a recurrent layer advances each row's own state where
-        it lies (``rows [b]``: the slot's state row, the scratch row
-        for a padded one)."""
+    def _batch_seams(self, b: int, p, tables, lengths, rows, wtables=None):
+        """``(write_attn, mix, write_window)`` of ``b`` decode rows:
+        each row's token written at its own position and attended over
+        its blocks as stored (a window layer: into the row's ring,
+        ``wtables [b, entries]``, and over its last ``window``
+        positions); a recurrent layer advances each row's own state
+        where it lies (``rows [b]``: the slot's state row, the scratch
+        row for a padded one)."""
         cfg = self.cfg
         slot_phys = lengths // cfg.block_size
         off = lengths % cfg.block_size
@@ -323,12 +383,20 @@ class StepPrograms:
                                                 state.ssm, rows)
             return RecurrentState(conv, ssm), y
 
-        return write_attn, mix
+        def write_window(l, wpool, q, k, v):
+            phys = wtables[jnp.arange(b), slot_phys % wtables.shape[1]]
+            wpool = write_rows(wpool, l, phys, off, k, v, cfg.kv_dtype)
+            return wpool, stored_decode_attn(wpool, l, q, wtables,
+                                             lengths + 1, self.spec.window)
 
-    def _chunk_seams(self, p, table, pos0, row):
-        """``(write_attn, mix)`` of ONE slot's chunk of prompt tokens:
-        they enter the cache through its block table and attend
-        causally over the gathered view; a recurrent layer scans the
+        return write_attn, mix, write_window
+
+    def _chunk_seams(self, p, table, pos0, row, wtable=None):
+        """``(write_attn, mix, write_window)`` of ONE slot's chunk of
+        prompt tokens: they enter the cache through its block table and
+        attend causally over the gathered view (a window layer: through
+        the slot's ring ``wtable``, each row over the ``window``
+        positions up to its own); a recurrent layer scans the
         chunk through the slot's state (``row``: the convolution's tail
         and, where the model has one, the scan state), which is zero at
         position 0 whatever the row still holds (every prefill, and
@@ -346,7 +414,13 @@ class StepPrograms:
                 state = self._keep_slot_state(state, i, row, tail, s)
             return state, y
 
-        return write_attn, mix
+        def write_window(l, wpool, q, k, v):
+            wpool = write_chunk(wpool, l, wtable, pos0, k, v, cfg.kv_dtype,
+                                ring=True)
+            return wpool, gathered_chunk_attn(wpool, l, q, wtable, pos0,
+                                              self.spec.window)
+
+        return write_attn, mix, write_window
 
     def _slot_state(self, state, i, row, pos0):
         """``(tail [K-1, D], s [N, D])`` of recurrent layer ``i`` for
@@ -368,21 +442,21 @@ class StepPrograms:
             ssm=None if s is None else state.ssm.at[i, row].set(s))
 
     def decode_hidden(self, b: int, p, cache, tables, lengths, tokens,
-                      rows=None):
+                      rows=None, wtables=None):
         """The decode program up to the head (``_batch_seams``).
         Returns ``(cache, x [b, d], counts)``."""
         x = self._embed(p, tokens, lengths)             # [b, d]
-        return self._trunk(p, cache, x, lengths,
-                           *self._batch_seams(b, p, tables, lengths, rows))
+        return self._trunk(p, cache, x, lengths, *self._batch_seams(
+            b, p, tables, lengths, rows, wtables))
 
     def prefill_hidden(self, c: int, p, cache, table, pos0, tokens,
-                       row=None):
+                       row=None, wtable=None):
         """The prefill program up to the head (``_chunk_seams``).
         Returns ``(cache, x [c, d], counts)``."""
         positions = pos0 + jnp.arange(c)
         x = self._embed(p, tokens, positions)           # [c, d]
-        return self._trunk(p, cache, x, positions,
-                           *self._chunk_seams(p, table, pos0, row))
+        return self._trunk(p, cache, x, positions, *self._chunk_seams(
+            p, table, pos0, row, wtable))
 
     def mixed_hidden(self, b: int, p, cache, f: dict):
         """The mixed program up to the head: the batch's ``b`` rows and
@@ -401,13 +475,19 @@ class StepPrograms:
             [lengths, pos0 + jnp.arange(self.cfg.prefill_chunk)])
         x = self._embed(p, jnp.concatenate([f["tokens"], f["chunk"]]),
                         positions)
-        batch_attn, _ = self._batch_seams(b, p, f["tables"], lengths, rows)
-        chunk_attn, _ = self._chunk_seams(p, f["table"], pos0, row)
+        batch_attn, _, batch_window = self._batch_seams(
+            b, p, f["tables"], lengths, rows, f.get("wtables"))
+        chunk_attn, _, chunk_window = self._chunk_seams(
+            p, f["table"], pos0, row, f.get("wtable"))
 
-        def write_attn(l, pool, q, k, v):
-            pool, yb = batch_attn(l, pool, q[:b], k[:b], v[:b])
-            pool, yc = chunk_attn(l, pool, q[b:], k[b:], v[b:])
-            return pool, jnp.concatenate([yb, yc])
+        def both(batch, chunk):
+            def write(l, pool, q, k, v):
+                pool, yb = batch(l, pool, q[:b], k[:b], v[:b])
+                pool, yc = chunk(l, pool, q[b:], k[b:], v[b:])
+                return pool, jnp.concatenate([yb, yc])
+            return write
+
+        write_attn = both(batch_attn, chunk_attn)
 
         def mix(i, state, a):
             # the mixer's own weights are read once too: the model
@@ -420,7 +500,8 @@ class StepPrograms:
                     RecurrentState(conv, ssm), i, row, tail, s)
             return state, y
 
-        return self._trunk(p, cache, x, positions, write_attn, mix)
+        return self._trunk(p, cache, x, positions, write_attn, mix,
+                           both(batch_window, chunk_window))
 
     def _head_pick(self, p, x, uids, poison, pos, ahead: int):
         """head -> poison -> pick -> finite flags, for all four
@@ -457,7 +538,8 @@ class StepPrograms:
             f = wire.unpack(operand)
             cache, x, counts = self.decode_hidden(
                 b, p, cache, f["tables"], f["lengths"],
-                self._held(f["tokens"], store, f["rows"]), f["rows"])
+                self._held(f["tokens"], store, f["rows"]), f["rows"],
+                f.get("wtables"))
             picks, finite = self._head_pick(
                 p, x, f["uids"], f["poison"], f["lengths"], 1)
             return ((cache, store.at[f["rows"]].set(picks)),
@@ -536,7 +618,8 @@ class StepPrograms:
             cache, store = carry
             f = wire.unpack(operand)
             cache, x, counts = self.prefill_hidden(
-                c, p, cache, f["table"], f["pos0"], f["tokens"], f["row"])
+                c, p, cache, f["table"], f["pos0"], f["tokens"], f["row"],
+                f.get("wtable"))
             picks, finite = self._head_pick(
                 p, x[-1:], f["uid"], f["poison"], f["pos0"][None], c)
             return ((cache, store.at[f["row"]].set(picks[0])),

@@ -119,7 +119,7 @@ def snapshot_state(engine: DecodeEngine) -> dict:
     snapshot certifies, even though resume recomputes the pool) and
     then the waiting queue in queue order, so a restore re-queues them
     in scheduling priority order."""
-    engine._refuse_recurrent("an engine snapshot (--snapshot_dir)")
+    engine._refuse_kept_beside("an engine snapshot (--snapshot_dir)")
     engine.collect()    # a snapshot holds landed tokens and positions
     requests = []
     running = sorted(
@@ -247,7 +247,7 @@ def restore_engine_state(engine: DecodeEngine, snap: dict) -> None:
     have been built with the snapshot's exact config/policy — resuming
     onto a different compiled surface would silently change numerics,
     so a mismatch raises."""
-    engine._refuse_recurrent("resuming from an engine snapshot")
+    engine._refuse_kept_beside("resuming from an engine snapshot")
     cfg = dataclasses.asdict(engine.cfg)
     if cfg != snap["config"]:
         diff = {k: (snap["config"].get(k), cfg.get(k))

@@ -16,9 +16,12 @@ sequence-parallel form (ring attention over ``ppermute``) lives in
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def causal_mask(Tq: int, Tk: int, q_offset: int = 0, k_offset: int = 0):
@@ -29,6 +32,15 @@ def causal_mask(Tq: int, Tk: int, q_offset: int = 0, k_offset: int = 0):
     q_pos = q_offset + jnp.arange(Tq)[:, None]
     k_pos = k_offset + jnp.arange(Tk)[None, :]
     return q_pos >= k_pos
+
+
+def window_mask(q_pos, k_pos, window: int):
+    """The sliding-window rule over broadcastable GLOBAL positions: a
+    query at ``p`` sees the key at ``t`` where ``0 <= t <= p`` and ``p -
+    t < window`` (the current token counts: ``window`` positions in
+    all). A negative ``t`` is an entry that holds no position yet
+    (``decode/paged.py::ring_positions``)."""
+    return (k_pos >= 0) & (k_pos <= q_pos) & (q_pos - k_pos < window)
 
 
 def attn_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -95,26 +107,101 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array,
     return jax.vmap(lambda q, k, v: attention(q, k, v, causal))(q, k, v)
 
 
-def rope(x: jax.Array, positions: jax.Array,
-         base: float = 10000.0) -> jax.Array:
+def rope(x: jax.Array, positions: jax.Array, base: float = 10000.0,
+         freqs=None, scale: float = 1.0) -> jax.Array:
     """Rotary position embedding (Su et al.): rotate each head-dim pair
     ``(x_i, x_{i+dh/2})`` by ``pos * base^(-2i/dh)`` — attention scores
     then depend only on *relative* position. ``x [..., T, dh]`` (``dh``
     even), ``positions [T]`` (absolute indices; decode passes the single
     write position). Linear in ``x``, so ``jax.vjp``'s exact transpose
     (the inverse rotation) differentiates it — the framework's stance for
-    linear ops."""
+    linear ops. ``freqs [dh/2]`` takes the place of the geometric
+    ladder and ``scale`` multiplies ``cos`` and ``sin`` where a model's
+    rotary is scaled (``Rotary``)."""
     dh = x.shape[-1]
     if dh % 2:
         raise ValueError(f"rope needs an even head dim, got {dh}")
     half = dh // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions[..., :, None].astype(jnp.float32) * freqs   # [T, half]
     cos = jnp.cos(ang).astype(x.dtype)
     sin = jnp.sin(ang).astype(x.dtype)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin,
                             x1 * sin + x2 * cos], axis=-1)
+
+
+class Rotary(NamedTuple):
+    """One layer type's rotary as a published ``rope_parameters`` entry
+    states it: the base, the share of a head's lanes that is rotated
+    (the FIRST ``partial_rotary_factor * dh``, paired half-split among
+    themselves; the rest pass through) and, for ``rope_type: yarn``,
+    the scaling (Peng et al., YaRN): pair ``i`` of the rotated lanes
+    turns at ``f_i = theta^(-2i/D_rot)`` where it makes more than
+    ``beta_fast`` turns over the ``original`` positions, at ``f_i /
+    factor`` where it makes fewer than ``beta_slow``, on a linear ramp
+    in between, and ``cos`` / ``sin`` are multiplied by
+    ``attention_factor``. ``factor`` 1 is ``rope_type: default``."""
+    theta: float = 10000.0
+    partial: float = 1.0
+    factor: float = 1.0
+    original: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    @classmethod
+    def from_config(cls, entry: dict) -> "Rotary":
+        """A ``rope_parameters`` entry; a ``rope_type`` other than
+        ``default`` and ``yarn`` is refused by name."""
+        kind = entry.get("rope_type", "default")
+        base = dict(theta=float(entry.get("rope_theta", 10000.0)),
+                    partial=float(entry.get("partial_rotary_factor", 1.0)))
+        if kind == "default":
+            return cls(**base)
+        if kind != "yarn":
+            raise ValueError(f"rope_type {kind!r}: the rotary is served "
+                             "as 'default' or 'yarn' only")
+        factor = float(entry["factor"])
+        af = entry.get("attention_factor")
+        return cls(**base, factor=factor,
+                   original=int(entry["original_max_position_embeddings"]),
+                   beta_fast=float(entry.get("beta_fast", 32.0)),
+                   beta_slow=float(entry.get("beta_slow", 1.0)),
+                   attention_factor=float(
+                       0.1 * math.log(factor) + 1.0 if af is None else af))
+
+    def rot_dim(self, head_dim: int) -> int:
+        return int(head_dim * self.partial)
+
+    def freqs(self, head_dim: int) -> np.ndarray:
+        """``[D_rot / 2]`` float32: each rotated pair's turn a position."""
+        d = self.rot_dim(head_dim)
+        i = np.arange(d // 2, dtype=np.float64)
+        f = self.theta ** (-2.0 * i / d)
+        if self.factor == 1.0:
+            return f.astype(np.float32)
+
+        def pair_of(turns):     # the pair that makes ``turns`` turns
+            return (d * math.log(self.original / (turns * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+
+        lo = max(math.floor(pair_of(self.beta_fast)), 0)
+        hi = min(math.ceil(pair_of(self.beta_slow)), d - 1)
+        keep = 1.0 - np.clip((i - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+        return (f / self.factor * (1.0 - keep) + f * keep).astype(
+            np.float32)
+
+    def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
+        """``rope`` of the first ``D_rot`` lanes of ``x [..., T, dh]``."""
+        d = self.rot_dim(x.shape[-1])
+        y = rope(x[..., :d], positions, freqs=self.freqs(x.shape[-1]),
+                 scale=self.attention_factor)
+        return y if d == x.shape[-1] else jnp.concatenate(
+            [y, x[..., d:]], axis=-1)
 
 
 def rope_mha(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -181,7 +268,7 @@ def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array, layer: int,
 
 
 def chunk_attn(q: jax.Array, ck: jax.Array, cv: jax.Array,
-               q_offset) -> jax.Array:
+               q_offset, mask=None) -> jax.Array:
     """Prefill-chunk attention of ``Tq`` queries against a (gathered)
     cache that already holds the chunk's own keys: ``q [H, Tq, dh]``,
     ``ck/cv [H_kv, T_cap, dh]`` with ``H % H_kv == 0`` (GQA groups).
@@ -189,7 +276,9 @@ def chunk_attn(q: jax.Array, ck: jax.Array, cv: jax.Array,
     q_offset)`` — query ``i`` (global position ``q_offset + i``) sees
     cache positions ``<= q_offset + i``, which also hides every
     not-yet-written pool position. ``q_offset`` may be a traced scalar
-    (the chunked-prefill loop passes the running write head)."""
+    (the chunked-prefill loop passes the running write head). ``mask
+    [Tq, T_cap]`` takes the causal rule's place where the view is not
+    in position order (a window layer's ring: ``window_mask``)."""
     h, tq, dh = q.shape
     hkv, tcap, _ = ck.shape
     if h % hkv:
@@ -198,7 +287,8 @@ def chunk_attn(q: jax.Array, ck: jax.Array, cv: jax.Array,
     qg = q.reshape(hkv, h // hkv, tq, dh)
     s = jnp.einsum("kgqd,ktd->kgqt", qg, ck) / jnp.sqrt(
         jnp.asarray(dh, q.dtype))
-    mask = causal_mask(tq, tcap, q_offset=q_offset)
+    if mask is None:
+        mask = causal_mask(tq, tcap, q_offset=q_offset)
     s = jnp.where(mask, s, jnp.asarray(-1e30, s.dtype))
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("kgqt,ktd->kgqd", p, cv).reshape(h, tq, dh)

@@ -5,19 +5,30 @@ weights) with the methods of ``ServedModel``. ``decode/programs.py``
 builds every compiled step program from those answers and from the
 cache (``decode/paged.py``); neither it nor the scheduler asks which
 class the params are, reads a weight by name or calls a family's
-arithmetic. ``models/lm.py``, ``models/hybrid_lm.py`` and
-``models/mla_moe_lm.py`` are the families that exist;
+arithmetic. ``models/lm.py``, ``models/hybrid_lm.py``,
+``models/mla_moe_lm.py``, ``models/lfm2_moe_lm.py`` and
+``models/laguna_lm.py`` are the families that exist;
 ``tests/test_model_face.py`` serves one more that lives in the test
 alone. The builder keeps the cache write and read of an attention layer
-(between ``attn_qkv`` and ``attn_out``, or for a latent-cache layer
-between ``latent_qrow`` and ``latent_out``), the row of the recurrent
+(between ``attn_qkv`` and ``attn_out``; for a latent-cache layer
+between ``latent_qrow`` and ``latent_out``; for a sliding-window layer
+between ``window_qkv`` and ``window_out``), the row of the recurrent
 state a sequence owns, the residual adds, the expert layers' counters
 and, under a mesh, the collectives.
 
+Three layer kinds keep a PAGED cache, each with its own weight stack
+and its own cache index: ``ATTN`` (K/V blocks over the whole sequence),
+``LATENT`` (one latent row a token in the same pool's place) and
+``WINDOW`` (K/V blocks of the last ``CacheSpec.window`` positions only,
+in a pool and a block table of their own beside the full kind's:
+``decode/paged.py``). Every other kind is recurrent: a state row by
+slot.
+
 What more than one family is written from lives below the face: ``mm``,
 ``rmsnorm``, the gated SiLU MLP, the attention stack and its q/k/v
-(``qkv_heads``: QK-norm and the rotary base where the model has them);
-the expert layer's is ``ops/moe_serve.py``.
+(``qkv_heads``: QK-norm and the model's rotary where it has them), the
+per-head output gate (``head_gate``); the expert layer's is
+``ops/moe_serve.py``.
 """
 
 from __future__ import annotations
@@ -28,12 +39,16 @@ from typing import NamedTuple, Protocol
 import jax
 import jax.numpy as jnp
 
-from .attention import rope
+from .attention import Rotary, rope
 
 ATTN = "attn"           # the layer kind whose cache is paged KV blocks
 # ... and the kind whose paged cache row is ONE latent vector a token:
 # no head axis and no K/V pair (multi-head latent attention, absorbed)
 LATENT = "latent"
+# ... and the kind that attends over the last ``window`` positions only:
+# paged KV blocks in a pool of their own, a sequence's blocks reused as
+# a ring once every position in them is behind the window
+WINDOW = "window"
 
 
 class CacheSpec(NamedTuple):
@@ -49,7 +64,10 @@ class CacheSpec(NamedTuple):
     values. Pool and state are built from this. Beside what is kept:
     ``expert_layers`` layers route their rows over ``n_experts`` held
     experts and count them, which sizes the counters a step program
-    returns after its picks (0 for a model with no expert layer)."""
+    returns after its picks (0 for a model with no expert layer).
+    ``win_layers`` ``WINDOW`` layers own paged KV of the same
+    ``kv_heads`` x ``head_dim`` row over the last ``window`` positions
+    of a sequence (0 for a model with none)."""
     kv_layers: int
     kv_heads: int
     head_dim: int
@@ -60,6 +78,8 @@ class CacheSpec(NamedTuple):
     latent_rank: int = 0
     expert_layers: int = 0
     n_experts: int = 0
+    win_layers: int = 0
+    window: int = 0
 
 
 class ServedModel(Protocol):
@@ -68,6 +88,7 @@ class ServedModel(Protocol):
     normed residual stream ``[N, d]``. A family without recurrent
     layers is never asked for the three ``recurrent_`` methods, one
     without ``LATENT`` layers never for the two ``latent_`` ones, one
+    without ``WINDOW`` layers never for the two ``window_`` ones, one
     whose ``cache_spec`` names no expert layer never for
     ``ffn_counted``."""
     vocab: int
@@ -91,7 +112,16 @@ class ServedModel(Protocol):
     # weights' shapes, rotary inside when asked
     def attn_qkv(self, i, a, positions, head_dim, use_rope): ...
 
-    def attn_out(self, i, y): ...   # y [N, H*dh] -> [N, d]
+    # y [N, H*dh] the read's result, a the layer's normed input (what
+    # an output gate is computed from; most families ignore it) -> [N, d]
+    def attn_out(self, i, y, a): ...
+
+    # a WINDOW layer ``i`` (its own stack, its own head count and
+    # rotary): as ``attn_qkv`` / ``attn_out``; the builder writes k, v
+    # to the window pool and reads the last ``window`` positions
+    def window_qkv(self, i, a, positions): ...
+
+    def window_out(self, i, y, a): ...
 
     # a LATENT layer: -> (q [N, H, m], row [N, m]), ``m`` the stored
     # row's lanes. ``row`` is what the cache keeps of each token, ``q``
@@ -186,12 +216,15 @@ class AttnStack(NamedTuple):
 
 
 def qkv_heads(wq, wk, wv, i: int, a, positions, head_dim: int,
-              use_rope: bool, theta: float | None = None, qk_norm=None):
+              use_rope: bool, theta: float | None = None, qk_norm=None,
+              rotary: Rotary | None = None):
     """Attention layer ``i`` of stacks ``[L_a, out, d]``: ``a [N, d] ->
     q [N, h_loc, dh], k/v [N, kv_loc, dh]``, rotated by ``positions
     [N]`` when asked, at the base ``theta`` where the model states one
-    (``rope``'s own otherwise); the local head counts come off the
-    (possibly head-sharded) weights' shapes. ``qk_norm = (g_q [dh], g_k
+    (``rope``'s own otherwise) or as the model's ``rotary`` says (part
+    of a head's lanes, YaRN: ``models/attention.py::Rotary``); the
+    local head counts come off the (possibly head-sharded) weights'
+    shapes. ``qk_norm = (g_q [dh], g_k
     [dh], eps)`` norms every head of ``q`` and of ``k`` over its own
     lanes (gain-only RMSNorm, float32) between the projection and the
     rotation."""
@@ -202,8 +235,19 @@ def qkv_heads(wq, wk, wv, i: int, a, positions, head_dim: int,
         g_q, g_k, eps = qk_norm
         q, k = rmsnorm(g_q, q, eps), rmsnorm(g_k, k, eps)
     if use_rope:
-        at = rope if theta is None else functools.partial(rope, base=theta)
+        at = rotary or (rope if theta is None
+                        else functools.partial(rope, base=theta))
         rot = jax.vmap(lambda x, pos: at(x[:, None, :], pos[None])[:, 0, :])
         q = rot(q, positions)
         k = rot(k, positions)
     return q, k, v
+
+
+def head_gate(wg, i: int, a, y, head_dim: int):
+    """The per-head output gate (the head-wise form of arXiv:2505.06708):
+    head ``h`` of the read's result ``y [N, H*dh]`` is scaled by
+    ``sigmoid(W_g a)_h``, one scalar a head from the layer's normed
+    input ``a [N, d]``, ``wg [L_a, H, d]``; float32."""
+    g = jax.nn.sigmoid(mm(a, wg[i]).astype(jnp.float32))     # [N, H]
+    return (y.reshape(y.shape[0], -1, head_dim) * g[:, :, None]
+            ).reshape(y.shape)

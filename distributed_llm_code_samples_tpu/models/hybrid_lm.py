@@ -131,7 +131,7 @@ class HybridLMParams:
         return qkv_heads(self.attn.wq, self.attn.wk, self.attn.wv, i, a,
                          positions, head_dim, use_rope)
 
-    def attn_out(self, i, y):
+    def attn_out(self, i, y, a):
         return mm(y, self.attn.wo[i])
 
     def recurrent_step(self, i, a, conv, state, rows):

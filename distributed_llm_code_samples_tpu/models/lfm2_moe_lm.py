@@ -167,7 +167,7 @@ class Lfm2MoeLMParams:
                              positions, head_dim, True, self.rope_theta,
                              (self.g_q[i], self.g_k[i], self.eps))
 
-    def attn_out(self, i, y):
+    def attn_out(self, i, y, a):
         return mm(y, self.attn.wo[i])
 
     def recurrent_step(self, i, a, conv, state, rows):

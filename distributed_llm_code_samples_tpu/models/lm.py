@@ -96,7 +96,7 @@ class LMParams(NamedTuple):
         return qkv_heads(blk.wq, blk.wk, blk.wv, i, a, positions,
                          head_dim, use_rope)
 
-    def attn_out(self, i, y):
+    def attn_out(self, i, y, a):
         return mm(y, self.blocks.wo[i])
 
     def ffn(self, l, h):

@@ -8,9 +8,12 @@ range is the chip's share), so the layer here is in two parts:
 
 - ``route``: over ALL ``n_routed`` experts, in float32 at ``highest``
   (a choice flips on a rounding where two scores nearly tie, and the
-  layer's output jumps with it): ``s = sigmoid(W_r a)``; the ``top_k``
-  of ``s + b`` are chosen (``b`` the choice bias: it moves the choice
-  and never the weight); ``w_k = scale * s_k / sum_chosen s``.
+  layer's output jumps with it): ``s = sigmoid(W_r a)``, or ``softmax(W_r
+  a)`` over all of them, as the model's family says (``SCORES``: a
+  property of the model, read from its spec; no option of the engine);
+  the ``top_k`` of ``s + b`` are chosen (``b`` the choice bias where
+  the family has one: it moves the choice and never the weight);
+  ``w_k = scale * s_k / sum_chosen s``.
 - ``held_part``: the weighted part of the result that the held experts
   ``[first, first + E_held)`` give, each the gated SiLU MLP
   ``W_down (silu(W_gate a) * W_up a)``, and the rows each of them
@@ -44,26 +47,33 @@ import jax.numpy as jnp
 
 HI = jax.lax.Precision.HIGHEST
 
+# a router's score function over the logits ``[N, E]``, by name
+SCORES = {"sigmoid": jax.nn.sigmoid,
+          "softmax": lambda z: jax.nn.softmax(z, axis=-1)}
+
 
 class ExpertStack(NamedTuple):
     """The expert layers' routed part, stacked ``[L_e, ...]``: the
     router over all ``E`` experts (float32) and the ``E_held`` held."""
     w_router: jax.Array  # [L_e, E, d] float32
-    bias: jax.Array      # [L_e, E]    float32, used for the choice only
+    # [L_e, E] float32, used for the choice only; None: no choice bias
+    bias: jax.Array | None
     w_gate: jax.Array    # [L_e, E_held, F, d]
     w_up: jax.Array      # [L_e, E_held, F, d]
     w_down: jax.Array    # [L_e, E_held, d, F]
 
 
-def route(a: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
-          scale: float):
-    """``a [N, d]``, ``w_router [E, d]``, ``bias [E]`` -> ``(idx [N, k]
-    int32, w [N, k] float32)``: the chosen experts of each row and their
-    weights, which sum to ``scale``."""
-    s = jax.nn.sigmoid(jnp.matmul(a.astype(jnp.float32),
-                                  w_router.astype(jnp.float32).T,
-                                  precision=HI))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+def route(a: jax.Array, w_router: jax.Array, bias, top_k: int,
+          scale: float, score: str = "sigmoid"):
+    """``a [N, d]``, ``w_router [E, d]``, ``bias [E]`` or None -> ``(idx
+    [N, k] int32, w [N, k] float32)``: the chosen experts of each row
+    and their weights, which sum to ``scale``; ``score`` names the
+    family's score function (``SCORES``)."""
+    s = SCORES[score](jnp.matmul(a.astype(jnp.float32),
+                                 w_router.astype(jnp.float32).T,
+                                 precision=HI))
+    _, idx = jax.lax.top_k(
+        s if bias is None else s + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     return idx, scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
 
@@ -93,11 +103,12 @@ def held_part(a: jax.Array, idx: jax.Array, w: jax.Array,
 
 
 def routed(e: ExpertStack, x: int, h: jax.Array, top_k: int, scale: float,
-           first: int = 0):
+           first: int = 0, score: str = "sigmoid"):
     """Expert layer ``x`` of the stack over ``h [N, d]``: ``route`` over
     all experts, then the held experts' part -> ``(y [N, d], rows
     [E_held])``, ``first`` the global id of the first held expert."""
-    idx, w = route(h, e.w_router[x], e.bias[x], top_k, scale)
+    idx, w = route(h, e.w_router[x], None if e.bias is None else e.bias[x],
+                   top_k, scale, score)
     return held_part(h, idx, w, e.w_gate[x], e.w_up[x], e.w_down[x], first)
 
 

@@ -50,10 +50,11 @@ the same dirs render identical output even under equal timestamps.
 
 Output: step-time percentiles, throughput, MFU, HBM high-water, the
 serving summary + reliability block per engine, a **step phases**
-table (schema-v20 ``engine_step`` span records: per host phase of
-``engine.step()`` the count, mean, p99 and share of step time, and per
+table (schema-v21 ``engine_step`` span records: per host phase of
+``engine.step()`` the count, mean, p99 and share of step time, per
 step program, by kind and bucket, its runs and the time from its launch
-to the end of its read), a
+to the end of its read, and for a model with window layers what the
+steps' rows read of each kind of cache), a
 per-request **waterfall** (schema-v5 ``span`` records: queued / prefill / replay /
 decode / quarantine / preempt_gap, whose summed durations RECONCILE
 with each completed request's recorded ``latency_s``), and ONE merged
@@ -581,6 +582,7 @@ class _Stream:
         return {
             "steps": len(self.step_spans),
             "step_mean_ms": round(total_ms / len(self.step_spans), 4),
+            "cache_reads": self._cache_reads(),
             "dispatches": [
                 {"kind": kind, "bucket": bucket, "count": len(ms),
                  "mean_ms": round(float(np.mean(ms)), 4),
@@ -595,6 +597,28 @@ class _Stream:
                        if total_ms else None}
                 for name, ms in per_phase.items()},
         }
+
+    def _cache_reads(self) -> dict | None:
+        """What the steps' rows read of the two kinds of cache (v21:
+        ``window_rows`` / ``full_rows``, the cached positions a step's
+        launched rows attend over in a window layer and in a full one)
+        and the window blocks' turnover (``window_blocks_released`` in
+        all, ``window_blocks_live`` at most); None where no record
+        counted a window layer's read (a model with none)."""
+        recs = [r for r in self.step_spans if r.get("window_rows")]
+        if not recs:
+            return None
+        return {
+            "steps": len(recs),
+            "window_rows_mean": round(float(np.mean(
+                [r["window_rows"] for r in recs])), 2),
+            "full_rows_mean": round(float(np.mean(
+                [r["full_rows"] for r in recs])), 2),
+            "window_blocks_released": sum(
+                r["window_blocks_released"] for r in self.step_spans
+                if "window_blocks_released" in r),
+            "window_blocks_live_max": max(
+                r["window_blocks_live"] for r in recs)}
 
     def waterfalls(self) -> dict:
         """Per-uid span waterfall: phase breakdown + the span-sum vs
@@ -1846,6 +1870,14 @@ def _render_engine_sections(out: list, doc: dict) -> None:
             label = f"{d['kind']} ({d['bucket']})"
             out.append(f"  {label:18s} {d['count']:6d} "
                        f"{d['mean_ms']:10.4f} {d['p99_ms']:10.4f}")
+        cr = sp.get("cache_reads")
+        if cr:
+            out.append(
+                f"  cache reads: {cr['window_rows_mean']} positions a "
+                f"step in a window layer, {cr['full_rows_mean']} in a "
+                f"full one ({cr['steps']} step(s)); window blocks: "
+                f"{cr['window_blocks_live_max']} held at most, "
+                f"{cr['window_blocks_released']} released")
     rec = doc.get("recovery", {})
     if (rec.get("attempts_failed") or rec.get("nonfinite_skips")
             or rec.get("attempt_log")

@@ -247,7 +247,19 @@ import numpy as np
 # contract). The expert counters of a record are those of the results
 # it READ. Readers: ``report.py``'s programs table,
 # ``benchmark/layer_metrics/late_read_share.offline.py``.
-SCHEMA_VERSION = 20
+# v21 (PR 39): a third paged kind — the ``engine_step`` record may carry
+# the cache reads' counters (``STEP_SPAN_WINDOW``; the engine writes all
+# four, 0 for a model with no window layer): ``window_rows`` /
+# ``full_rows``, the cached positions the rows the step LAUNCHED attend
+# over in a window layer and in a full one (a chunk's one view counted
+# once), ``window_blocks_released`` (window blocks that left a sequence
+# in the step: overwritten in its ring, or handed back with its slot)
+# and ``window_blocks_live`` (held at the step's end). All four or
+# none, whole and not negative, and no more positions in a window layer
+# than in a full one (``validate_record``). Readers: ``report.py``'s
+# cache-reads line, ``benchmark/window_trace.py`` (the two attention
+# rooflines, ``window_pool_util``).
+SCHEMA_VERSION = 21
 
 METRICS_FILENAME = "metrics.jsonl"
 
@@ -405,6 +417,9 @@ SPAN_NAMES = ("queued", "prefill", "replay", "decode", "quarantine",
 STEP_SPAN = "engine_step"
 STEP_SPAN_REQUIRED = ("phases", "start_ns", "end_ns", "dispatches",
                       "readbacks", "launches")
+# ... and the four it carries together or not at all (v21)
+STEP_SPAN_WINDOW = ("window_rows", "full_rows", "window_blocks_released",
+                    "window_blocks_live")
 
 # The router-record contract (``decode/fleet.py``): one record per
 # fleet-router decision. ``step`` is the ROUTER's step clock (fleet
@@ -1070,6 +1085,15 @@ def validate_record(rec: Any) -> tuple[bool, str]:
                 return False, (f"span record (span {STEP_SPAN}) has "
                                f"'readbacks' {rec['readbacks']} outside "
                                f"its 'launches' ({rec['launches']})")
+            got = [k for k in STEP_SPAN_WINDOW if k in rec]
+            if got and (len(got) != len(STEP_SPAN_WINDOW) or any(
+                    not isinstance(rec[k], int) or rec[k] < 0
+                    for k in got) or rec["window_rows"] > rec["full_rows"]):
+                return False, (f"span record (span {STEP_SPAN}) has the "
+                               f"cache reads' counters "
+                               f"{ {k: rec[k] for k in got} }: all of "
+                               f"{list(STEP_SPAN_WINDOW)} or none, whole, "
+                               "not negative, window_rows <= full_rows")
         elif rec["uid"] is None:
             return False, (f"span record (span {rec['span']}) has a "
                            f"null 'uid': only {STEP_SPAN} belongs to "

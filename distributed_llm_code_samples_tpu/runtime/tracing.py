@@ -220,6 +220,14 @@ class SpanTracer:
 # of steps is also the k-th step program of a device trace of those
 # steps: a reader joins the two by ORDER and needs no clock.
 #
+# What the step's launched rows read of the cache is in its
+# ``window_rows`` / ``full_rows`` (``engine._count_rows``; telemetry
+# v21: the cached positions they attend over in a window layer, at most
+# the window a row, and in a full one, a chunk's one view counted once),
+# beside ``window_blocks_released`` / ``window_blocks_live``, the
+# window pool's turnover; all 0 for a model with no window layer. They
+# are counts the host has at launch: no phase of their own.
+#
 # Which launch a ``*.readback`` read is in the step's ``readbacks``
 # (``engine._read``; telemetry v20): the launch's ORDINAL among the
 # engine's launches (from 0), the i-th entry the i-th ``*.readback``

@@ -684,6 +684,109 @@ def test_conv_moe_step_program_keeps_pool_and_state_as_stored(
     assert not [l for l in hlo.splitlines() if gathered.search(l)]
 
 
+# the window-and-full-attention expert cell of the benchmark
+# (laguna-s-2.1.longreason-offline): Laguna-S-2.1's widths, 32 of 256
+# experts held, 48 / 72 gated heads over 8 KV heads of 128 lanes, an
+# eighth of the vocabulary, 64 slots x 3072 positions, bf16 weights and
+# K/V in TWO pools, cut to 4 layers (one period: the dense full-attention
+# layer and three sliding-window layers with experts) so the compile
+# stays short
+LAGUNA = dict(layers=4, slots=64, mbps=192, chunk=16)
+
+
+@pytest.fixture(scope="module")
+def laguna_engine_args():
+    """``(engine, {kind: (bucket, args)})`` at the cell's widths, over
+    shapes alone: the configuration's own file through the family's
+    ``spec_from_config``, the step programs as ``DecodeEngine`` builds
+    them, both pools in the carry."""
+    import json
+    from distributed_llm_code_samples_tpu.decode import EngineConfig
+    from distributed_llm_code_samples_tpu.decode.programs import StepPrograms
+    from distributed_llm_code_samples_tpu.models import laguna_lm
+    g = LAGUNA
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna-s-2.1-serve.json")) as f:
+        config = json.load(f)
+    n = g["layers"]
+    config = dict(config, num_hidden_layers=n, **{
+        k: config[k][:n] for k in ("layer_types", "mlp_layer_types",
+                                   "num_attention_heads_per_layer")})
+    spec = laguna_lm.spec_from_config(config)
+    params = jax.eval_shape(
+        lambda k: laguna_lm.init_laguna_lm(k, spec, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    slots, mbps, chunk = g["slots"], g["mbps"], g["chunk"]
+    programs = StepPrograms(
+        EngineConfig(n_blocks=1 + slots * mbps, max_slots=slots,
+                     max_blocks_per_seq=mbps, prefill_chunk=chunk,
+                     kv_dtype="bf16"),
+        params.cache_spec(48), params.vocab)
+    eng = _ShapesEngine(programs, params,
+                        jax.eval_shape(lambda: programs.init_cache()[0]))
+    eng.wpool = jax.eval_shape(programs.init_window)
+    eng._cache = lambda: programs.whole(eng.pool, eng.wpool)
+    return eng, _cell_programs(eng, slots, chunk)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
+def test_window_moe_step_program_keeps_both_pools_as_stored(
+        one_chip, laguna_engine_args, kind):
+    """The third paged kind in a step program (``decode/paged.py``): the
+    full layers' pool ``[1, 12289, 16, 1024]`` and the window layers'
+    ``[3, 2177, 16, 1024]`` are both taken row-major and unpadded,
+    aliased whole and updated in place, never copied and no layer's slab
+    sliced out; a window layer's gather is its ring of 34 blocks a row —
+    544 positions, where a full layer's is the table at capacity, 3,072
+    — and no gathered view of a window layer is wider. The result
+    carries the held experts' counters after the picks."""
+    import re
+    eng, programs = laguna_engine_args
+    bucket, args = programs[kind]
+    compiled = eng._program(kind, bucket).lower(
+        *_shapes_of(args, one_chip)).compile()
+    hlo = compiled.as_text()
+    pool, wpool = eng.pool, eng.wpool
+    assert eng.programs.window_blocks == 34
+    assert pool.k.shape == (1, 12289, 16, 1024) == pool.v.shape
+    assert wpool.k.shape == (3, 2177, 16, 1024) == wpool.v.shape
+    # whole slabs of either pool (a layer of the window pool is the
+    # smaller): nothing of that size is copied or sliced out
+    slab = wpool.k.size // wpool.k.shape[0]
+    moved = [r for r in _hlo_results(
+        hlo, ("copy", "slice", "dynamic-slice"), "bf16")
+        if r[1] >= slab and r[1] % slab == 0]
+    assert not moved, moved
+    (full_fmt, win_fmt), _ = compiled.input_formats[0][1]
+    for fmt, arr in ((full_fmt.k, pool.k), (full_fmt.v, pool.v),
+                     (win_fmt.k, wpool.k), (win_fmt.v, wpool.v)):
+        assert fmt.layout.major_to_minor == tuple(range(arr.ndim)), fmt
+    m = compiled.memory_analysis()
+    held = (2 * _nbytes(pool.k) + 2 * _nbytes(wpool.k)
+            + _nbytes(eng.token_store))
+    assert m.alias_size_in_bytes >= held
+    assert _carry_is_aliased_whole(compiled, eng) == held
+    logical = sum(_nbytes(x) for x in jax.tree_util.tree_leaves(args[:2]))
+    assert m.argument_size_in_bytes - logical < _nbytes(wpool.k) // 100
+    assert _total_bytes(compiled) < HBM_V5E
+    picks = {"decode": bucket, "prefill": 1, "mixed": bucket + 1}[kind]
+    out = jax.eval_shape(eng.programs.body(kind, bucket), *args)[1]
+    assert out.shape == (picks + 3 * 32,) and out.dtype == jnp.int32
+    # the gathers, by their results: a batch's ``[b, blocks, 16, 1024]``,
+    # a chunk's one slot ``[blocks, 16, 1024]``
+    rows = "" if kind == "prefill" else r"%d," % bucket
+    got = [int(n) for n in re.findall(
+        r"= bf16\[%s(\d+),16,1024\]\S* gather\(" % rows, hlo)]
+    # K and V of the one full layer at capacity, of the three window
+    # layers at the ring
+    assert sorted(got) == [34] * 6 + [192] * 2, got
+    if kind == "mixed":     # ... and the riding chunk's one slot
+        one = [int(n) for n in re.findall(
+            r"= bf16\[(\d+),16,1024\]\S* gather\(", hlo)]
+        assert sorted(one) == [34] * 6 + [192] * 2, one
+
+
 def _toy_engine(family, ways, speculate, hybrid_config):
     """A GPT-2-shaped toy, or the toy whose ``config.json`` is handed in
     (the hybrid's, the latent-attention expert model's), as small as
@@ -753,7 +856,7 @@ class _BodiesOfThePredecessor:
     ``_chunk_seams``: the closures written in place."""
 
     def decode_hidden(self, b, p, cache, tables, lengths, tokens,
-                      rows=None):
+                      rows=None, wtables=None):
         from distributed_llm_code_samples_tpu.decode import paged
         cfg = self.cfg
         x = self._embed(p, tokens, lengths)
@@ -774,7 +877,8 @@ class _BodiesOfThePredecessor:
 
         return self._trunk(p, cache, x, lengths, write_attn, mix)
 
-    def prefill_hidden(self, c, p, cache, table, pos0, tokens, row=None):
+    def prefill_hidden(self, c, p, cache, table, pos0, tokens, row=None,
+                       wtable=None):
         from distributed_llm_code_samples_tpu.decode import paged
         cfg = self.cfg
         positions = pos0 + jnp.arange(c)
@@ -902,6 +1006,8 @@ REHEARSED = {
                                       "expert_rows_max_over_mean"),
     "lfm2-24b-a2b.reasoning-offline": ("shrink_lfm2",
                                        "routed_rows_max_over_mean"),
+    "laguna-s-2.1.longreason-offline": ("shrink_laguna",
+                                        "window_pool_util"),
 }
 
 
@@ -911,8 +1017,9 @@ def test_cell_rehearsal_on_the_cpu(monkeypatch, name, trace):
     """A newer cell's whole control flow on the CPU at toy size, as
     ``benchmark/tests/test_rehearsal.py`` rehearses the older cells
     (its ``shrink.py`` knows those only; these cells' shrinks are
-    ``benchmark/tests/shrink_jamba.py``, ``shrink_glm.py`` and
-    ``shrink_lfm2.py``). Nothing here is a measurement."""
+    ``benchmark/tests/shrink_jamba.py``, ``shrink_glm.py``,
+    ``shrink_lfm2.py`` and ``shrink_laguna.py``). Nothing here is a
+    measurement."""
     import importlib
     import json
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

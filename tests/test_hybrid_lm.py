@@ -451,7 +451,8 @@ class InlineGPT2:
                 else vp_embed(p.wte, tokens))
         return rows + p.wpe[positions]
 
-    def _trunk(self, p, pool, x, positions, write_attn, mix=None):
+    def _trunk(self, p, pool, x, positions, write_attn, mix=None,
+               write_window=None):
         from distributed_llm_code_samples_tpu.ops.norm import layernorm
         from distributed_llm_code_samples_tpu.parallel.collectives import (
             all_reduce)
@@ -495,7 +496,8 @@ class InlineHybrid:
     def _embed(self, p, tokens, positions):
         return p.wte[tokens].astype(jnp.float32)
 
-    def _trunk(self, p, cache, x, positions, write_attn, mix=None):
+    def _trunk(self, p, cache, x, positions, write_attn, mix=None,
+               write_window=None):
         pool, state = cache
         n = x.shape[0]
         dh = p.head_dim

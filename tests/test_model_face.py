@@ -88,7 +88,7 @@ class NopeParams:
         return tuple((a @ w[i].T).reshape(a.shape[0], -1, head_dim)
                      for w in (self.wq, self.wk, self.wv))
 
-    def attn_out(self, i, y):
+    def attn_out(self, i, y, a):
         return y @ self.wo[i].T
 
     def ffn(self, l, h):
@@ -263,7 +263,8 @@ SCHEDULER_AND_BUILDER = [os.path.join(PKG, "decode", name)
 ARITHMETIC = re.compile(r"(^|\.)(models\.(lm|hybrid_lm|mla_moe_lm|attention"
                         r"|transformer|moe\w*|ffn_stack)"
                         r"|ops\.(norm|ssm|ffn|activations|moe\w*))$")
-FACE_NAMES = {"ATTN", "LATENT", "CacheSpec", "ServedModel", "take"}
+FACE_NAMES = {"ATTN", "LATENT", "WINDOW", "CacheSpec", "ServedModel",
+              "take"}
 
 
 def _imports(path):

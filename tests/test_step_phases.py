@@ -324,7 +324,11 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
                             "phases", "tokens_generated",
                             "state_bytes", "expert_rows",
                             "experts_touched", "expert_rows_max",
+                            "window_rows", "full_rows",
+                            "window_blocks_released",
+                            "window_blocks_live",
                             "dispatches", "readbacks", "launches"}
+        assert rec["window_rows"] == rec["full_rows"] == 0  # nor window
         assert rec["launches"] == eng.launches
         assert rec["state_bytes"] == 0      # no recurrent layer here
         assert rec["expert_rows"] == rec["experts_touched"] == 0  # nor expert

@@ -119,7 +119,13 @@ from distributed_llm_code_samples_tpu.runtime.telemetry import (
 # each ``*.readback`` phase read, one entry a phase, in order; it may
 # be an earlier record's launch) and ``launches`` (the engine's
 # launches up to and with the step's own).
-_PINNED_VERSION = 20
+# v21 (PR 39): a third paged kind — the ``engine_step`` record may
+# carry the cache reads' four counters (``STEP_SPAN_WINDOW``), all or
+# none.
+_PINNED_VERSION = 21
+_PINNED_STEP_SPAN_WINDOW = frozenset({
+    "window_rows", "full_rows", "window_blocks_released",
+    "window_blocks_live"})
 _PINNED_STEP_SPAN_REQUIRED = frozenset({"phases", "start_ns", "end_ns",
                                         "dispatches", "readbacks",
                                         "launches"})
@@ -360,7 +366,7 @@ def test_engine_step_v20_round_trips(tmp_path):
                                                   METRICS_FILENAME))
     assert problems == []
     first, closing, idle = records
-    assert first["schema"] == SCHEMA_VERSION == 20
+    assert first["schema"] == SCHEMA_VERSION == 21
     assert first["dispatches"] == [["prefill", 4], ["decode", 8]]
     assert [p[0] for p in first["phases"] if p[0].endswith(".dispatch")] \
         == [k + ".dispatch" for k, _ in first["dispatches"]]
@@ -369,6 +375,35 @@ def test_engine_step_v20_round_trips(tmp_path):
     assert first["readbacks"] == [9, 10] and first["launches"] == 12
     assert closing["dispatches"] == [] and closing["readbacks"] == [11]
     assert idle["dispatches"] == idle["readbacks"] == []
+
+
+WINDOW_READS = dict(window_rows=544, full_rows=2100,
+                    window_blocks_released=2, window_blocks_live=66)
+
+
+@pytest.mark.parametrize("over,ok", [
+    ({}, True),                              # a model with no window layer
+    (WINDOW_READS, True),
+    (dict.fromkeys(WINDOW_READS, 0), True),  # ... as the engine writes it
+    ({"window_rows": 5}, False),             # all four or none
+    ({k: v for k, v in WINDOW_READS.items() if k != "full_rows"}, False),
+    (dict(WINDOW_READS, window_rows=2101), False),   # over a full layer's
+    (dict(WINDOW_READS, window_blocks_live=-1), False),
+    (dict(WINDOW_READS, full_rows=2100.5), False),
+])
+def test_engine_step_v21_cache_read_counters(over, ok):
+    """The cache reads' counters of an ``engine_step`` record
+    (``STEP_SPAN_WINDOW``): all four or none, whole, not negative, no
+    more positions in a window layer than in a full one."""
+    from distributed_llm_code_samples_tpu.runtime.telemetry import (
+        STEP_SPAN_WINDOW)
+    assert frozenset(STEP_SPAN_WINDOW) == _PINNED_STEP_SPAN_WINDOW
+    rec = dict(_engine_step(**over), schema=SCHEMA_VERSION, kind="span",
+               trace_id=None, tenant=None)
+    got, reason = validate_record(rec)
+    assert got is ok, reason
+    if not ok:
+        assert "window_rows" in reason and "\n" not in reason
 
 
 @pytest.mark.parametrize("case,named", [
