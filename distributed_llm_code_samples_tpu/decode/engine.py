@@ -180,6 +180,7 @@ import numpy as np
 
 from ..models.face import ATTN, LATENT, WINDOW, ServedModel
 from ..parallel import launcher
+from ..parallel.mesh import MODEL_AXIS
 from ..runtime.policy import QosPolicy
 from ..runtime.telemetry import FLIGHT_FILENAME, STEP_SPAN
 from ..runtime.tracing import PhaseTimer, SpanTracer
@@ -190,7 +191,7 @@ from ..runtime import wire
 from .draft import draft_tokens
 from .paged import (SCRATCH_BLOCK, corrupt_block as _pool_corrupt_block,
                     extract_blocks, kv_bytes_per_token, pool_bytes,
-                    scrub_blocks)
+                    scrub_blocks, walks)
 from .programs import FROM_SLOT, POISON_ALL, POISON_NONE, StepPrograms
 from .prefix import PrefixCache
 from .spill import SpillTier
@@ -274,6 +275,15 @@ EXPERT_COUNTERS = ("expert_rows", "experts_touched", "expert_rows_max")
 # window layer
 WINDOW_COUNTERS = ("window_rows", "full_rows", "window_blocks_released",
                    "window_blocks_live")
+
+
+# ... and the pool's blocks the decode-side read of the step's launched
+# rows fetched (``DecodeEngine._count_blocks``): over the rows of its
+# ``decode`` / ``mixed`` / ``verify`` programs, a bucket's padded ones
+# with them, times the pool's layers — the rows' LIVE blocks where the
+# read walks each row's table (``paged.walks``), every table's whole
+# capacity where it gathers — beside that capacity
+KV_COUNTERS = ("kv_blocks_read", "kv_blocks_capacity")
 
 
 class AdmissionError(RuntimeError):
@@ -637,6 +647,11 @@ class DecodeEngine:
         # (None for a model that has none): donated into the step
         # programs together and updated in place
         self.pool, self.state = self.programs.init_cache()
+        # whether the decode-side read walks each row's live blocks or
+        # gathers every table whole: the pool's own dtype and shape say
+        # (a shard's, under a mesh); what ``_count_blocks`` counts by
+        self._walks = walks(
+            self.pool, shards=1 if mesh is None else mesh.shape[MODEL_AXIS])
         # ... and the window layers' pool (None for a model with none):
         # a second block pool, its own scratch block, table and free list
         self.wpool = self.programs.init_window()
@@ -772,6 +787,11 @@ class DecodeEngine:
         # ... of which the chunk rode with the step's decode batch in
         # ONE ``mixed`` program (``_mixed_dispatch``)
         self.mixed_dispatches = 0
+        # the pool's blocks the decode-side reads fetched, and the
+        # capacity a gather of every row's table reads (``KV_COUNTERS``,
+        # cumulative; the step's own are ``_step_kv``)
+        self.kv_blocks_read = 0
+        self.kv_blocks_capacity = 0
         # tokens emitted inside the CURRENT span per uid (decode/replay
         # segments emit many tokens per step under speculation; the
         # span record carries the count so a waterfall shows work, not
@@ -795,6 +815,7 @@ class DecodeEngine:
         # the cache reads of the rows this step launched and the window
         # blocks' turnover (``WINDOW_COUNTERS``, ``_count_rows``)
         self._step_window = dict.fromkeys(WINDOW_COUNTERS, 0)
+        self._step_kv = dict.fromkeys(KV_COUNTERS, 0)
         # the step programs this step launched, ``[kind, bucket]`` in
         # launch order (``_launch``): the engine_step record's and the
         # digest's ``dispatches``
@@ -2489,6 +2510,29 @@ class DecodeEngine:
         fresh = np.minimum(opened, last // blk + 1 - entries)
         w["window_blocks_released"] += int(np.maximum(fresh, 0).sum())
 
+    def _count_blocks(self, ready: list[int], b: int,
+                      reads: int = 1) -> None:
+        """Count the pool's blocks the decode-side read of a batch
+        about to be launched fetches (``KV_COUNTERS``): ``ready`` the
+        slots of its rows BEFORE their lengths advance, ``b`` its
+        bucket, ``reads`` the reads a row makes a layer (a verify
+        program's sub-steps, each one position further). A row that
+        attends over ``n`` positions walks ``ceil(n / block)`` blocks,
+        a padded row the scratch block; a gather reads every row's
+        whole table whatever it holds."""
+        blk, layers = self.cfg.block_size, self.pool.k.shape[0]
+        capacity = reads * b * self.cfg.max_blocks_per_seq * layers
+        if self._walks:
+            n = self.lengths[ready][:, None] + 1 + np.arange(reads)
+            read = (int((-(-n // blk)).sum())
+                    + reads * (b - len(ready))) * layers
+        else:
+            read = capacity
+        self._step_kv["kv_blocks_read"] += read
+        self._step_kv["kv_blocks_capacity"] += capacity
+        self.kv_blocks_read += read
+        self.kv_blocks_capacity += capacity
+
     def _prefill_book(self, row: tuple, c: int, end: int,
                       nxt: int) -> bool:
         """Fold a launched chunk's result (its last row's folded pick)
@@ -2593,14 +2637,15 @@ class DecodeEngine:
             fields["wtables"] = wtables
         return fields
 
-    def _count_batch(self, ready: list[int]) -> list[tuple]:
+    def _count_batch(self, ready: list[int], b: int) -> list[tuple]:
         """Advance the COUNTS of a decode batch that is about to be
-        launched: each slot's length and its sequence's launched
-        tokens. Returns the rows ``_emit_batch`` takes when the picks
-        land."""
+        launched in a bucket of ``b`` rows: each slot's length and its
+        sequence's launched tokens. Returns the rows ``_emit_batch``
+        takes when the picks land."""
         rows = [self._row(slot) for slot in ready]
         if self.windowed:
             self._count_rows(self.lengths[ready], self.lengths[ready])
+        self._count_blocks(ready, b)
         self.lengths[ready] += 1
         for _, seq, _ in rows:
             seq.launched += 1
@@ -2632,7 +2677,7 @@ class DecodeEngine:
         with phase("decode.upload"):
             operand = self.programs.pack(
                 "decode", b, **self._batch_fields(ready, b, *batch))
-            rows = self._count_batch(ready)
+            rows = self._count_batch(ready, b)
         self._launch("decode", b, fn, params, operand,
                      lambda picks: self._land(None, rows, picks))
 
@@ -2698,7 +2743,7 @@ class DecodeEngine:
                 "mixed", b, **self._batch_fields(ready, b, *batch),
                 **self._chunk_fields(slot, seq, c, "chunk"))
             chunk = self._count_chunk(slot, seq, c)
-            rows = self._count_batch(ready)
+            rows = self._count_batch(ready, b)
         self._launch(
             "mixed", b, fn, self._params_for(seq.weights_version),
             operand, lambda picks: self._land(chunk, rows, picks))
@@ -2775,6 +2820,7 @@ class DecodeEngine:
                 uids=uids, poison=self._poison_uid, drafts=drafts,
                 dlens=dlens)
         self._step_decode_uids += [self.slots[s].uid for s in ready]
+        self._count_blocks(ready, b, reads=k + 1)
 
         def land(result) -> list[bool]:
             with phase("decode.emit"):
@@ -2888,6 +2934,7 @@ class DecodeEngine:
         self._step_decode_uids = []
         self._step_state_bytes = 0
         self._step_window = dict.fromkeys(WINDOW_COUNTERS, 0)
+        self._step_kv = dict.fromkeys(KV_COUNTERS, 0)
         self._step_dispatches = []
         self._step_readbacks = []
         self._step_expert_rows = []
@@ -2974,7 +3021,7 @@ class DecodeEngine:
 
     def _step_record(self, start_ns: int, end_ns: int) -> dict:
         """The executed step as ONE ``engine_step`` span record
-        (telemetry v21): the parent span and its phases in the order
+        (telemetry v22): the parent span and its phases in the order
         they closed (each a child by being in this list), the step
         programs it launched (``dispatches``: the i-th entry belongs to
         the i-th ``*.dispatch`` phase) and the launches whose results
@@ -2984,8 +3031,8 @@ class DecodeEngine:
         launches up to and with this step's, so the record's own are
         the last ``len(dispatches)`` ordinals below it). The expert
         counters are those of the results READ; the cache reads'
-        (``WINDOW_COUNTERS``) of the rows LAUNCHED. ``tokens_generated``
-        is what a reader joins a step on."""
+        (``WINDOW_COUNTERS``, ``KV_COUNTERS``) of the rows LAUNCHED.
+        ``tokens_generated`` is what a reader joins a step on."""
         return {
             "uid": None,
             "span": STEP_SPAN,
@@ -3000,6 +3047,7 @@ class DecodeEngine:
             "state_bytes": self._step_state_bytes,
             **self._step_experts,
             **self._step_window,
+            **self._step_kv,
             "dispatches": self._step_dispatches,
             "readbacks": list(self._step_readbacks),
             "launches": self.launches,
@@ -3171,6 +3219,11 @@ class DecodeEngine:
             "prefill_dispatches": self.prefill_dispatches,
             # extra: ... of which the chunk rode with the decode batch
             "mixed_dispatches": self.mixed_dispatches,
+            # extra (v22): the pool's blocks the decode-side reads
+            # fetched, beside the capacity a gather of every launched
+            # row's table reads (``KV_COUNTERS``, cumulative)
+            "kv_blocks_read": self.kv_blocks_read,
+            "kv_blocks_capacity": self.kv_blocks_capacity,
             # v17 KV-memory-hierarchy keys (pinned): demotion volume
             # (cumulative blocks + wire bytes), promotion wins
             # (restores, the prompt tokens they kept off the prefill
@@ -3234,6 +3287,9 @@ class DecodeEngine:
             # the cache reads of the rows launched and the window
             # blocks' turnover (0 for a model with no window layer)
             **self._step_window,
+            # the pool's blocks the launched rows' reads fetched, and
+            # the capacity a gather of their tables reads
+            **self._step_kv,
             # where the step's host time went up to this digest
             # (runtime/tracing.py PhaseTimer): what an UNTRACED run's
             # ring says about a slow step

@@ -7,13 +7,27 @@ means rebuilding the batch (a recompile). This module is the
 PagedAttention-style answer in the repo's first-principles idiom: the
 cache is a static-shape **pool of fixed-size blocks**
 (``k/v [L, n_blocks, block, H_kv*dh]``) and each sequence names its
-blocks through a per-slot int32 **block table** — the KV read is a
-gather (the decode programs attend over the gathered rows as stored,
-``stored_decode_attn``; ``models.attention.gather_paged_kv`` is the
-head-split f32 view of the oracle and the prefill chunk), the write is
-a scatter, and freeing a sequence is a host-side table edit. Shapes
-never depend on sequence length, so one compiled decode step serves
-every occupancy.
+blocks through a per-slot int32 **block table** — the KV read goes
+through the table, the write is a scatter, and freeing a sequence is a
+host-side table edit. Shapes never depend on sequence length, so one
+compiled decode step serves every occupancy.
+
+Which read each kind of row takes, decided from the pool and the row
+kind alone (no flag, field or environment variable chooses):
+
+- a decode-side row (``decode``, ``mixed``, ``verify``) calls
+  ``stored_decode_attn``, which attends over the rows as stored. For
+  the FULL kind that is a WALK over the row's live blocks where they
+  lie (``ops/kv_walk.py``: one kernel a layer, nothing of a gathered
+  view's size exists) wherever ``walks`` says the pool takes it: a
+  float pool whose rows are, on the chip, two or more whole 128-lane
+  tiles. Every other pool keeps the PLAIN form,
+  ``gathered_decode_attn``: a gather of each row's whole table and two
+  products over the copy — a latent pool, a window layer's ring, an
+  int8 pool (per-block scales), a one-tile or ragged row on the chip;
+- a prefill chunk's rows call ``gathered_chunk_attn``: one slot's f32
+  head-split view (``models.attention.gather_paged_kv``), which is also
+  the tests' oracle for both decode-side forms.
 
 The stored form is the one the chip keeps as it is. A token's row holds
 all its KV heads side by side (``H_kv*dh`` lanes, head ``h`` at
@@ -36,8 +50,9 @@ step path.
 Physical block 0 is reserved as the **scratch block**: unassigned table
 slots and padded bucket rows point at it, so padded writes land
 somewhere harmless instead of needing a masked scatter, and gathers of
-short sequences read bytes the causal mask then hides. Nothing is ever
-read from it unmasked.
+short sequences read bytes the causal mask then hides (the walk does
+not fetch a row's dead blocks at all; a padded row walks the scratch
+block alone). Nothing is ever read from it unmasked.
 
 Quantization (``kv_dtype``):
 
@@ -590,11 +605,13 @@ def corrupt_block(pool: PagedKV, block: int) -> PagedKV:
                          v=pool.v.at[:, block].set(bad))
 
 
-def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
-                       tables: jax.Array, lengths: jax.Array,
-                       window: int = 0) -> jax.Array:
-    """Single-query attention for one layer over the gathered rows AS
-    STORED — what the engine's decode-side programs run. ``q [B, H,
+def gathered_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
+                         tables: jax.Array, lengths: jax.Array,
+                         window: int = 0) -> jax.Array:
+    """The PLAIN decode-side read: single-query attention for one layer
+    over a gather of every row's whole table, the rows AS STORED — what
+    ``stored_decode_attn`` runs for the pools that do not take the walk
+    (``walks``), and what the tests hold the walk to. ``q [B, H,
     dh]`` f32, ``tables [B, MB]`` int32, ``lengths [B]`` attendable
     positions; returns ``[B, H, dh]`` f32. ``window`` > 0: ``tables``
     are window layers' short tables, used as rings, and a row attends
@@ -671,6 +688,82 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     return y.reshape(b, h, dh)
 
 
+def walks(pool: PagedKV, window: int = 0, shards: int = 1) -> bool:
+    """Whether the decode-side read of ``pool`` is the walk over each
+    row's live blocks (``ops/kv_walk.py``) or the plain gather and two
+    products (``gathered_decode_attn``). Decided from the pool's own kind,
+    dtype and shape and from nothing else — no flag, field or
+    environment variable, and no model's name:
+
+    - the FULL kind only: a latent pool (``latent_rank``) and a window
+      layer's ring (``window``) keep the plain read (their own readers
+      and masks: ``PERF.md`` section 7 says what each waits for);
+    - a float pool only: an int8 pool (``k_scale``) has per-block scales
+      on the small side, which the kernel does not take;
+    - on the chip, rows of whole 128-lane tiles in blocks of whole
+      sublane tiles only (a ``--tp 4`` shard of GPT-2's row is 320
+      lanes), and of MORE than one tile: a block of one-tile rows is 4
+      KB, every block is a copy of its own, and a copy costs ~33 ns to
+      issue whatever it moves, so the walk read 122 GB/s of live rows
+      there where the plain read's one gather reads 188 (0.60 against
+      0.39 ms a program of 64 rows x 2 layers at ONE KV head of 128
+      lanes; at 512 lanes 0.71 against 2.95, at 1,024 2.07 against
+      11.5, at 1,280 1.75 against 4.32: ``PERF.md`` section 6, PR 40).
+      The interpreter, off the chip, takes any width.
+
+    ``shards``: the ways ``pool``'s rows are sharded over a mesh where
+    the caller holds the whole pool (the engine, for its counters); a
+    step program asks of the shard it is handed."""
+    if window or pool.latent_rank or pool.k_scale is not None:
+        return False
+    from ..ops import ssm
+    if ssm._interpreted():
+        return True
+    lanes = pool.k.shape[-1] // shards
+    sublanes = 32 // pool.k.dtype.itemsize
+    return (lanes > ssm._LANES and lanes % ssm._LANES == 0
+            and pool.block_size % sublanes == 0)
+
+
+def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
+                       tables: jax.Array, lengths: jax.Array,
+                       window: int = 0) -> jax.Array:
+    """The decode-side programs' cache read (``decode``, ``mixed`` and
+    ``verify``): single-query attention for one layer over the rows AS
+    STORED. ``q [B, H, dh]`` f32, ``tables [B, MB]`` int32, ``lengths
+    [B]`` attendable positions; returns ``[B, H, dh]`` f32 (a latent
+    pool: ``gathered_decode_attn`` says). One contract, met by the walk
+    over each row's live blocks where the pool takes it (``walks``) and
+    by the plain form, ``gathered_decode_attn``, where it does not.
+
+    The walk (``ops/kv_walk.py::walk_attn``) runs the same two products
+    over the rows as stored, block by block where they lie, under an
+    online float32 softmax: the query laid out for the stored row (zero
+    outside its KV head's lanes) and the probabilities in the pool's
+    dtype, sums in float32, head ``h`` keeping its KV head's ``dh``
+    lanes of the result. Against the oracle: an f32 pool to reduction
+    order, a bf16 pool to the two roundings of the small operands, as
+    the plain form. What differs: only ``ceil(lengths / block)`` blocks
+    of a row are read, so a NaN in a DEAD block of its table no longer
+    reaches it; one beyond ``lengths`` inside the last live block still
+    does (``corrupt_block``)."""
+    if not walks(pool, window):
+        return gathered_decode_attn(pool, layer, q, tables, lengths, window)
+    from ..ops.kv_walk import walk_attn
+    b, h, dh = q.shape
+    hkv = pool.kv_heads
+    g = h // hkv
+    with jax.named_scope("attn"):
+        # ``rows[b, (K,g), (k,d)] = q[b, (K,g), d]`` where ``k == K``
+        rows = jnp.where(jnp.eye(hkv, dtype=bool)[:, None, :, None],
+                         q.reshape(b, hkv, g, 1, dh), 0)
+        full = walk_attn(pool.k, pool.v, layer,
+                         rows.reshape(b, h, hkv * dh).astype(pool.k.dtype),
+                         tables, lengths, dh ** -0.5)
+        y = jnp.einsum("bkgkd->bkgd", full.reshape(b, hkv, g, hkv, dh))
+    return y.reshape(b, h, dh)
+
+
 def _latent_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                         tables: jax.Array, lengths: jax.Array) -> jax.Array:
     """``stored_decode_attn`` over latent rows: ``q [B, H, m]`` is each
@@ -724,7 +817,7 @@ def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
     ``models.attention.gather_paged_kv`` — the attention read against a
     block table; this wrapper only adds the dtype story. With
     ``decode_attn`` this is the ORACLE the tests hold
-    ``stored_decode_attn`` to; in the engine only
+    ``stored_decode_attn`` to, in both its forms; in the engine only
     the prefill chunk reads through it (one slot's view), the decode
     side attends over the rows as stored."""
     from ..models.attention import gather_paged_kv

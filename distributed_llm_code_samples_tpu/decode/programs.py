@@ -7,9 +7,11 @@ layer's q/k/v and output projection (a latent-cache layer's query and
 row, and its output), a recurrent layer's step and chunk, FFN, final
 norm and head — asked of the params, never of their class. The cache
 (``decode/paged.py``): the pool and the recurrent state, the writes and
-the ONE read of each side
-(``stored_decode_attn`` for decode and verify rows,
-``gathered_chunk_attn`` for a prefill chunk). Between them, written
+the ONE read each kind of row calls (``stored_decode_attn`` for decode
+and verify rows — which walks a full-kind float pool's live blocks
+where they lie and gathers the others' tables, as ``paged.walks`` says
+from the pool alone, never a flag here — and ``gathered_chunk_attn``
+for a prefill chunk). Between them, written
 once: the walk over the model's layers (``_trunk``, so prefill and
 decode numerics cannot drift; in the ``mixed`` body a decode batch and
 ONE slot's full chunk are rows of one walk, the weights read once, and

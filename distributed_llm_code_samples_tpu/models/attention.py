@@ -254,9 +254,13 @@ def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array, layer: int,
     of the decode engine's cache read, and what the lockstep
     ``generate`` and the engine's prefill chunk (``chunk_attn`` over
     one slot's view) compute. The engine's decode-side programs do not
-    run it: they attend over the gathered rows as stored
-    (``decode/paged.py::stored_decode_attn`` — no f32 head-split copy
-    of the view; held to this oracle in tests/test_paged_layout.py)."""
+    run it: they attend over the rows as stored
+    (``decode/paged.py::stored_decode_attn`` — for the full kind a walk
+    over each row's live blocks where they lie, ``ops/kv_walk.py``; for
+    a latent, window or int8 pool a gather of the rows' tables and two
+    products over the copy, ``gathered_decode_attn``; ``paged.walks``
+    decides from the pool. No f32 head-split copy of the view either
+    way; both held to this oracle in tests/test_paged_layout.py)."""
     layers = jnp.full_like(table, layer)
     k = pool_k[layers, table]              # [MB, block, H_kv*dh]
     v = pool_v[layers, table]

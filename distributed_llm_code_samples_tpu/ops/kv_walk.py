@@ -1,0 +1,203 @@
+"""The decode-side K/V read as a walk over each row's block table.
+
+``decode/paged.py`` keeps the cache as a pool of blocks, ``k/v [L,
+n_blocks, block, H_kv*dh]``, and a sequence names its blocks through a
+table. The plain read (``paged.gathered_decode_attn``) gathers every
+row's WHOLE table — capacity, not length — into a copy and runs two
+products over the copy. ``walk_attn`` is the same two products over the
+rows where they lie: one Pallas kernel a layer that, for each batch
+row, fetches only the row's live blocks, ``ceil(length / block)`` of
+them, from the pool in HBM into a double-buffered VMEM scratch, several
+blocks a copy step, and folds each step's scores into a running float32
+maximum, sum and accumulator (the online softmax). Nothing of a
+gathered view's size exists: no gather, no copy, no ``[b, H, T_cap]``
+scores, and the bytes that move are the live rows'.
+
+The conventions are the state kernels' (``ops/ssm.py``):
+``ssm._interpreted()`` alone decides how the kernel runs, no caller
+passes an ``interpret`` of its own, and nothing is chosen by a flag, a
+field or the environment. Which pools take the walk at all is
+``decode/paged.py::walks``'s to say, from the pool's dtype and shape.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import ssm
+
+# what the two sides' double-buffered copy steps may hold of a kernel's
+# fast memory: a quarter of ``ssm._VMEM_BUDGET`` each for K and V
+_STEP_BYTES = ssm._VMEM_BUDGET // 8
+_MASKED = -1e30
+
+
+def blocks_a_step(block: int, row_bytes: int, max_blocks: int) -> int:
+    """How many blocks one copy step fetches: as many as fit
+    ``_STEP_BYTES`` a buffer, a power of two, at least as many as make
+    the step's positions whole 128-lane tiles of the scores, and no
+    more than a table holds or than 64 (1,024 positions of scores a
+    step at blocks of 16). It follows from the row's bytes and the
+    fast memory, not from a knob: a 1,280-lane bf16 row gives 16 blocks
+    of 16 (640 KB a step and side), a 512-lane one 64."""
+    fit = max(1, _STEP_BYTES // (block * row_bytes))
+    c = 1 << (fit.bit_length() - 1)
+    c = max(c, -(-ssm._LANES // block))
+    return min(c, 1 << (max_blocks - 1).bit_length(), 64)
+
+
+def _walk_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
+                 o_ref, kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref,
+                 *, steps: int, scale: float):
+    """One batch row a grid step. ``kbuf/vbuf [2, steps * block, J]``
+    are the two buffers of each side; ``slot_ref`` says which of them
+    the row's FIRST copy step is in (the row before started it, before
+    its own last product), ``sems [side, buffer]`` count the copies."""
+    r = pl.program_id(0)
+    blk = kbuf.shape[1] // steps
+    layer = layer_ref[0]
+
+    def live(row):
+        # a padded row (length 0 or 1, its table all scratch) walks the
+        # scratch block and nothing else
+        return jnp.maximum(pl.cdiv(lengths_ref[row], blk), 1)
+
+    def fetched(row, c):
+        return jnp.minimum(live(row) - c * steps, steps)
+
+    def copies(row, c, buf, act: str):
+        """``start`` or ``wait`` for the two copies, K and V, of every
+        block the row holds of its step ``c``, into buffer ``buf``."""
+        def one(i, _):
+            phys = tables_ref[row, c * steps + i]
+            dst = pl.ds(pl.multiple_of(i * blk, blk), blk)
+            for side, (hbm, vmem) in enumerate(((k_hbm, kbuf),
+                                                (v_hbm, vbuf))):
+                getattr(pltpu.make_async_copy(
+                    hbm.at[layer, phys], vmem.at[buf, dst],
+                    sems.at[side, buf]), act)()
+            return _
+        lax.fori_loop(0, fetched(row, c), one, None)
+
+    @pl.when(r == 0)
+    def _():
+        slot_ref[0] = 0
+        copies(0, 0, 0, "start")
+
+    first = slot_ref[0]
+    n_steps = pl.cdiv(live(r), steps)
+    length = lengths_ref[r]
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q = q_ref[...]                                      # [H, J]
+
+    def step(c, _):
+        buf = lax.rem(first + c, 2)
+
+        # what comes next, into the other buffer, before this step's
+        # products: the row's next step, or the NEXT row's first
+        last = c + 1 == n_steps
+
+        @pl.when(jnp.logical_or(~last, r + 1 < pl.num_programs(0)))
+        def _():
+            copies(jnp.where(last, r + 1, r), jnp.where(last, 0, c + 1),
+                   1 - buf, "start")
+
+        copies(r, c, buf, "wait")
+
+        # the blocks of the step the row does not hold were not
+        # fetched: what the buffer has there is some earlier step's (a
+        # NaN of ANOTHER row's among it). Their scores are masked; their
+        # values must be zeros, for 0 * NaN is NaN
+        def dead(i, _):
+            vbuf[buf, pl.ds(pl.multiple_of(i * blk, blk), blk), :] = (
+                jnp.zeros((blk, vbuf.shape[2]), vbuf.dtype))
+            return _
+        lax.fori_loop(fetched(r, c), steps, dead, None)
+
+        k, v = kbuf[buf], vbuf[buf]                     # [T, J]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        pos = c * (steps * blk) + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # stale rows of the last live block meet an exact 0 (and a NaN
+        # there still poisons the row, as in the plain read)
+        s = jnp.where(pos < length, s, _MASKED)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return _
+
+    lax.fori_loop(0, n_steps, step, None)
+    slot_ref[0] = lax.rem(first + n_steps, 2)
+    o_ref[...] = acc_ref[...] / l_ref[...]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("steps", "scale", "interpret"))
+def _walk(layer, tables, lengths, q, k_pool, v_pool, *, steps: int,
+          scale: float, interpret: bool):
+    """The kernel's call, jitted on its own: ``layer [1]`` is an
+    operand, so the calls of every layer of a step program are ONE
+    traced function — lowered (the kernel to Mosaic's module) once a
+    program and called a layer, not once a layer. A program of 36
+    layers spent 1.4 s of every start-up lowering 36 copies."""
+    b, h, j = q.shape
+    blk = k_pool.shape[2]
+    row = pl.BlockSpec((None, h, j), lambda r, *_: (r, 0, 0))
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_walk_kernel, steps=steps, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[row, whole, whole], out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((2, steps * blk, j), k_pool.dtype),
+                pltpu.VMEM((2, steps * blk, j), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, j), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, j), jnp.float32),
+        interpret=interpret,
+    )(layer, tables, lengths, q, k_pool, v_pool)
+
+
+def walk_attn(k_pool: jax.Array, v_pool: jax.Array, layer: int,
+              q: jax.Array, tables: jax.Array, lengths: jax.Array,
+              scale: float) -> jax.Array:
+    """Single-query attention of ``b`` rows over their own blocks of
+    one layer of the pool, where they lie. ``k_pool/v_pool [L, n_blocks,
+    block, J]`` stay in HBM whole; ``q [b, H, J]`` in the pool's dtype
+    is each head's query laid out FOR a stored row (zero outside its KV
+    head's lanes: ``decode/paged.py`` builds it); ``tables [b, MB]``,
+    ``lengths [b]`` the attendable positions. Returns ``[b, H, J]``
+    float32: ``softmax(scale * q K^T) V`` over the first ``lengths``
+    rows, of which head ``h`` keeps its KV head's lanes.
+
+    The layer is a scalar operand, so every layer's call is the same
+    kernel to compile. Operands in the pool's dtype, sums in float32. A
+    row's dead blocks (table entries at and beyond ``ceil(length /
+    block)``) are never fetched: bytes there, a NaN among them, do not
+    reach the result."""
+    interpret = ssm._interpreted()
+    j = q.shape[-1]
+    if not interpret and j % ssm._LANES:
+        raise ValueError(ssm._UNTILED.format(d=j))
+    steps = blocks_a_step(k_pool.shape[2], j * k_pool.dtype.itemsize,
+                          tables.shape[1])
+    return _walk(jnp.asarray([layer], jnp.int32), tables, lengths, q,
+                 k_pool, v_pool, steps=steps, scale=scale,
+                 interpret=interpret)

@@ -50,7 +50,7 @@ the same dirs render identical output even under equal timestamps.
 
 Output: step-time percentiles, throughput, MFU, HBM high-water, the
 serving summary + reliability block per engine, a **step phases**
-table (schema-v21 ``engine_step`` span records: per host phase of
+table (schema-v22 ``engine_step`` span records: per host phase of
 ``engine.step()`` the count, mean, p99 and share of step time, per
 step program, by kind and bucket, its runs and the time from its launch
 to the end of its read, and for a model with window layers what the
@@ -599,16 +599,29 @@ class _Stream:
         }
 
     def _cache_reads(self) -> dict | None:
-        """What the steps' rows read of the two kinds of cache (v21:
-        ``window_rows`` / ``full_rows``, the cached positions a step's
-        launched rows attend over in a window layer and in a full one)
-        and the window blocks' turnover (``window_blocks_released`` in
-        all, ``window_blocks_live`` at most); None where no record
-        counted a window layer's read (a model with none)."""
+        """What the steps' rows read of the cache. ``blocks`` (v22):
+        the pool's blocks the decode-side reads of a step's launched
+        rows fetched (``kv_blocks_read``) beside the capacity a gather
+        of their whole tables reads, a step's mean over the steps that
+        launched such rows; None where no record counted one. Where a
+        record counted a window layer's read (v21), also ``window_rows``
+        / ``full_rows``, the cached positions a step's launched rows
+        attend over in a window layer and in a full one, and the window
+        blocks' turnover (``window_blocks_released`` in all,
+        ``window_blocks_live`` at most). None where the records hold
+        neither."""
+        kv = [r for r in self.step_spans if r.get("kv_blocks_capacity")]
+        blocks = None if not kv else {
+            "steps": len(kv),
+            "kv_blocks_read_mean": round(float(np.mean(
+                [r["kv_blocks_read"] for r in kv])), 2),
+            "kv_blocks_capacity_mean": round(float(np.mean(
+                [r["kv_blocks_capacity"] for r in kv])), 2)}
         recs = [r for r in self.step_spans if r.get("window_rows")]
         if not recs:
-            return None
+            return None if blocks is None else {"blocks": blocks}
         return {
+            "blocks": blocks,
             "steps": len(recs),
             "window_rows_mean": round(float(np.mean(
                 [r["window_rows"] for r in recs])), 2),
@@ -1871,7 +1884,15 @@ def _render_engine_sections(out: list, doc: dict) -> None:
             out.append(f"  {label:18s} {d['count']:6d} "
                        f"{d['mean_ms']:10.4f} {d['p99_ms']:10.4f}")
         cr = sp.get("cache_reads")
-        if cr:
+        if cr and cr["blocks"]:
+            kb = cr["blocks"]
+            read, held = (kb["kv_blocks_read_mean"],
+                          kb["kv_blocks_capacity_mean"])
+            out.append(
+                f"  cache reads: {read} blocks a step fetched by the "
+                f"decode-side reads, of {held} in their rows' tables "
+                f"({100 * read / held:.1f}%; {kb['steps']} step(s))")
+        if cr and "steps" in cr:
             out.append(
                 f"  cache reads: {cr['window_rows_mean']} positions a "
                 f"step in a window layer, {cr['full_rows_mean']} in a "

@@ -259,7 +259,19 @@ import numpy as np
 # than in a full one (``validate_record``). Readers: ``report.py``'s
 # cache-reads line, ``benchmark/window_trace.py`` (the two attention
 # rooflines, ``window_pool_util``).
-SCHEMA_VERSION = 21
+# v22 (PR 40): the decode-side read of the full kind walks each row's
+# live blocks — the ``engine_step`` record may carry how much it
+# fetched (``STEP_SPAN_KV``; the engine writes both): ``kv_blocks_read``,
+# the pool's blocks the reads of the rows the step LAUNCHED in its
+# ``decode`` / ``mixed`` / ``verify`` programs fetched, over the pool's
+# layers (a row's live blocks where the read walks its table, a padded
+# row the scratch block; every table's capacity where it gathers:
+# ``decode/paged.py::walks``), and ``kv_blocks_capacity``, what a gather
+# of every such row's whole table reads. Both or none, whole, not
+# negative, no more read than the capacity (``validate_record``); the
+# ``decode`` record carries the two as cumulative extras. Readers:
+# ``report.py``'s cache-reads line.
+SCHEMA_VERSION = 22
 
 METRICS_FILENAME = "metrics.jsonl"
 
@@ -420,6 +432,8 @@ STEP_SPAN_REQUIRED = ("phases", "start_ns", "end_ns", "dispatches",
 # ... and the four it carries together or not at all (v21)
 STEP_SPAN_WINDOW = ("window_rows", "full_rows", "window_blocks_released",
                     "window_blocks_live")
+# ... and the two of the decode-side reads' blocks, likewise (v22)
+STEP_SPAN_KV = ("kv_blocks_read", "kv_blocks_capacity")
 
 # The router-record contract (``decode/fleet.py``): one record per
 # fleet-router decision. ``step`` is the ROUTER's step clock (fleet
@@ -1094,6 +1108,17 @@ def validate_record(rec: Any) -> tuple[bool, str]:
                                f"{ {k: rec[k] for k in got} }: all of "
                                f"{list(STEP_SPAN_WINDOW)} or none, whole, "
                                "not negative, window_rows <= full_rows")
+            got = [k for k in STEP_SPAN_KV if k in rec]
+            if got and (len(got) != len(STEP_SPAN_KV) or any(
+                    not isinstance(rec[k], int) or rec[k] < 0
+                    for k in got)
+                    or rec["kv_blocks_read"] > rec["kv_blocks_capacity"]):
+                return False, (f"span record (span {STEP_SPAN}) has the "
+                               f"decode-side reads' blocks "
+                               f"{ {k: rec[k] for k in got} }: both of "
+                               f"{list(STEP_SPAN_KV)} or none, whole, not "
+                               "negative, kv_blocks_read <= "
+                               "kv_blocks_capacity")
         elif rec["uid"] is None:
             return False, (f"span record (span {rec['span']}) has a "
                            f"null 'uid': only {STEP_SPAN} belongs to "

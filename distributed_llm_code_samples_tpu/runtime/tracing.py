@@ -226,7 +226,10 @@ class SpanTracer:
 # the window a row, and in a full one, a chunk's one view counted once),
 # beside ``window_blocks_released`` / ``window_blocks_live``, the
 # window pool's turnover; all 0 for a model with no window layer. They
-# are counts the host has at launch: no phase of their own.
+# are counts the host has at launch: no phase of their own. So are
+# ``kv_blocks_read`` / ``kv_blocks_capacity`` (``engine._count_blocks``;
+# telemetry v22): the pool's blocks the decode-side reads of the
+# launched rows fetched, and what a gather of their whole tables reads.
 #
 # Which launch a ``*.readback`` read is in the step's ``readbacks``
 # (``engine._read``; telemetry v20): the launch's ORDINAL among the

@@ -1,0 +1,120 @@
+"""The decode-side K/V read alone, on the chip: the walk over live
+blocks (``ops/kv_walk.py``) beside the plain gather and two products
+(``decode/paged.py::gathered_decode_attn``) at the serving cells' shapes.
+
+    python3 scripts/kv_walk_on_chip.py [--steps 8,16,32] [--cells gpt2,lfm2]
+
+One jitted program a form: every full-kind layer's read of one batch,
+as a decode-side program holds them, over a pool filled to the cell's
+``kv_pool_util``. Prints and writes (``chiprun_out/kv_walk.json``) ms a
+program and the live bytes a second each form moved. A microbench: the
+cell decides (PERF.md section 6, PR 32: the two did not agree there).
+Raises off a TPU: a CPU number is no device time.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from distributed_llm_code_samples_tpu.decode import paged
+from distributed_llm_code_samples_tpu.ops import kv_walk
+
+# (rows, heads, KV heads, head dim, blocks a sequence, full-kind
+# layers, the share of capacity that is live): the four cells with a
+# full-kind layer
+CELLS = {"gpt2": (12, 20, 20, 64, 64, 36, 0.365),
+         "lfm2": (64, 32, 8, 64, 128, 2, 0.553),
+         "laguna": (64, 48, 8, 128, 192, 3, 0.592),
+         "jamba": (64, 20, 1, 128, 128, 2, 0.547)}
+BLOCK = 16
+
+
+def _case(name, seed=0):
+    b, h, hkv, dh, mb, layers, util = CELLS[name]
+    rng = np.random.default_rng(seed)
+    pool = paged.init_pool(layers, 1 + b * mb, hkv, BLOCK, dh, "bf16")
+    key = jax.random.PRNGKey(seed)
+    pool = pool._replace(
+        k=jax.random.normal(key, pool.k.shape, jnp.bfloat16),
+        v=jax.random.normal(jax.random.fold_in(key, 1), pool.v.shape,
+                            jnp.bfloat16))
+    cap = mb * BLOCK
+    lengths = np.clip(rng.uniform(0.1, 2 * util - 0.1, size=b) * cap, 1,
+                      cap).astype(np.int32)
+    lengths[0] = cap                        # one row at the cap
+    tables = 1 + rng.permutation(b * mb).reshape(b, mb).astype(np.int32)
+    q = jax.random.normal(jax.random.fold_in(key, 2), (layers, b, h, dh))
+    live = int(lengths.sum()) * 2 * hkv * dh * 2 * layers
+    return pool, q, jnp.asarray(tables), jnp.asarray(lengths), live
+
+
+def _program(read, layers):
+    @jax.jit
+    def run(pool, q, tables, lengths):
+        return sum(read(pool, l, q[l], tables, lengths)
+                   for l in range(layers))
+    return run
+
+
+def _ms(run, args, reps=20):
+    out = run(*args)
+    out.block_until_ready()
+    t = time.perf_counter()
+    for _ in range(reps):
+        out = run(*args)
+    out.block_until_ready()
+    return (time.perf_counter() - t) / reps * 1e3, out
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--steps", default="")
+    ap.add_argument("--cells", default=",".join(CELLS))
+    a = ap.parse_args()
+    if jax.default_backend() != "tpu":
+        raise SystemExit("no TPU: a CPU time is no device time")
+    rules = [None] + [int(s) for s in a.steps.split(",") if s]
+    # the walk at EVERY cell's shape, also where ``paged.walks`` keeps
+    # the plain read for what this script measured there
+    paged.walks = lambda *_a, **_k: True
+    rule = kv_walk.blocks_a_step
+    out = {"device": jax.devices()[0].device_kind, "cells": {}}
+    for name in a.cells.split(","):
+        pool, q, tables, lengths, live = _case(name)
+        layers = pool.k.shape[0]
+        args = (pool, q, tables, lengths)
+        plain_ms, want = _ms(
+            _program(paged.gathered_decode_attn, layers), args)
+        rows = {"live_bytes": live, "kv_lanes": pool.k.shape[-1],
+                "plain_ms": plain_ms, "plain_gbs": live / plain_ms / 1e6}
+        for steps in rules:
+            if steps is not None:
+                kv_walk.blocks_a_step = lambda *_, s=steps: s
+            got_steps = kv_walk.blocks_a_step(
+                BLOCK, pool.k.shape[-1] * 2, tables.shape[1])
+            ms, got = _ms(_program(paged.stored_decode_attn, layers), args)
+            kv_walk.blocks_a_step = rule
+            err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+            rows[f"walk_ms@{got_steps}" + ("" if steps else "(rule)")] = ms
+            rows[f"walk_gbs@{got_steps}" + ("" if steps else "(rule)")] = (
+                live / ms / 1e6)
+            rows[f"rel_err@{got_steps}"] = err
+        out["cells"][name] = rows
+        print(name, json.dumps(rows), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/kv_walk.json", "w") as f:
+        json.dump(out, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()

@@ -211,19 +211,23 @@ def gpt2_engine_args():
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "f32", "int8"])
-def test_engine_decode_step_compiles(one_chip, gpt2_engine_args, kv_dtype):
+def test_engine_decode_step_compiles(one_chip, gpt2_engine_args,
+                                     kernels_for_the_chip, kv_dtype):
     """The engine's own decode program at chip_smoke's serving shape,
-    every pool dtype: compiles for one v5e chip and fits it, is XLA's
-    alone (no Mosaic call: the one cache read is
-    ``paged.stored_decode_attn``), and beyond the gather holds no array
-    of the gathered view's size that is wider than the operand the
+    every pool dtype: compiles for one v5e chip and fits it; a float
+    pool's cache read is ONE Mosaic call a layer (the walk over each
+    row's live blocks, ``ops/kv_walk.py``), an int8 pool's is XLA's
+    alone (``paged.gathered_decode_attn``: ``paged.walks`` says which
+    from the pool), and beyond a gather the program holds no array of
+    the gathered view's size that is wider than the operand the
     products take (the pool's own dtype; bf16 for int8 codes, which are
     exact in it)."""
     eng, decode, _, slots, _ = gpt2_engine_args(kv_dtype)
     compiled = eng._program("decode", slots).lower(
         *_shapes_of(decode, one_chip)).compile()
     hlo = compiled.as_text()
-    assert MOSAIC not in hlo
+    assert sum(MOSAIC in l for l in hlo.splitlines()) == (
+        0 if kv_dtype == "int8" else GPT2["layers"])
     assert _total_bytes(compiled) < HBM_V5E
     pool = eng.pool
     view = (slots * eng.cfg.max_blocks_per_seq * pool.block_size
@@ -285,7 +289,8 @@ def _hlo_results(hlo: str, ops: tuple[str, ...], dtype: str):
 
 @pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
 def test_step_program_keeps_the_pool_as_stored(one_chip,
-                                               gpt2_large_engine_args, kind):
+                                               gpt2_large_engine_args,
+                                               kernels_for_the_chip, kind):
     """The stored form is the form the chip keeps (``decode/paged.py``):
     a step program of the serving cell takes the donated pool row-major
     and unpadded, updates it in place, and never copies the pool or
@@ -301,8 +306,8 @@ def test_step_program_keeps_the_pool_as_stored(one_chip,
     pool = eng.pool
     slab = pool.k.size // pool.k.shape[0]
     # nothing of one layer's slab or more is copied or sliced out, in
-    # the pool's dtype (the batch's gathered blocks are one block short
-    # of a slab, and are what the attention reads)
+    # the pool's dtype (the kernel that reads the batch's rows takes
+    # the pool whole, where it lies)
     moved = [r for r in _hlo_results(
         compiled.as_text(), ("copy", "slice", "dynamic-slice"), "bf16")
         if r[1] >= slab]
@@ -357,12 +362,13 @@ def jamba_engine_args():
 
 @pytest.fixture
 def kernels_for_the_chip(monkeypatch):
-    """``ops/ssm.py`` runs its kernel in the interpreter wherever the
-    process's default backend is no TPU — here, though what is lowered
-    is for the described chip. A test that compiles a hybrid DECODE
-    program takes this fixture, so that the program holds the kernel
-    the chip would run (steered in the test: the program has no option
-    for it)."""
+    """``ops/ssm.py`` runs its kernels, and ``ops/kv_walk.py`` its
+    own, in the interpreter wherever the process's default backend is
+    no TPU — here, though what is lowered is for the described chip. A
+    test that compiles a decode-side program takes this fixture, so
+    that the program holds the kernels the chip would run and
+    ``paged.walks`` answers for the chip (steered in the test: the
+    program has no option for it)."""
     from distributed_llm_code_samples_tpu.ops import ssm
     monkeypatch.setattr(ssm, "_interpreted", lambda: False)
 
@@ -637,8 +643,9 @@ def test_conv_moe_step_program_keeps_pool_and_state_as_stored(
     copied and no layer's slab sliced out; the state is ONE leaf (no
     zero-sized scan store is handed in); the decode program holds ONE
     kernel call a convolution layer (``ops/ssm.py::conv_step_in_place``
-    at ``K = 3``, ``D = 2048``, no bias operand) and no array of a
-    batch's gathered tails."""
+    at ``K = 3``, ``D = 2048``, no bias operand) and one an attention
+    layer (``ops/kv_walk.py``), and no array of a batch's gathered
+    tails."""
     import re
     eng, programs = lfm2_engine_args
     bucket, args = programs[kind]
@@ -672,14 +679,19 @@ def test_conv_moe_step_program_keeps_pool_and_state_as_stored(
     picks = {"decode": bucket, "prefill": 1, "mixed": bucket + 1}[kind]
     out = jax.eval_shape(eng.programs.body(kind, bucket), *args)[1]
     assert out.shape == (picks + 3 * 64,) and out.dtype == jnp.int32
-    calls = [l for l in hlo.splitlines() if MOSAIC in l]
+    calls = [l.split(" custom-call(")[0] for l in hlo.splitlines()
+             if MOSAIC in l]
     if kind == "prefill":
         assert not calls            # the chunk's convolution is plain ops
         return
-    # ... in the mixed program too: the kernel is the batch's rows'
+    # ... in the mixed program too: the kernels are the batch's rows'.
+    # One a convolution layer, and one an attention layer: the walk
+    # over the rows' live K/V blocks (32 heads' sums over 512-lane rows)
+    walk = [l for l in calls if "f32[%d,32,512]" % bucket in l]
+    assert len(walk) == pool.k.shape[0] == 1
+    calls = [l for l in calls if l not in walk]
     assert len(calls) == eng.programs.spec.rec_layers == 3
-    assert all("f32[3,65,1,4096]" in l.split(" custom-call(")[0]
-               for l in calls)
+    assert all("f32[3,65,1,4096]" in l for l in calls)
     gathered = re.compile(r"= \(?[^=]*\bf32\[%d,(2,2048|4096)\]" % bucket)
     assert not [l for l in hlo.splitlines() if gathered.search(l)]
 
@@ -732,15 +744,17 @@ def laguna_engine_args():
 
 @pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
 def test_window_moe_step_program_keeps_both_pools_as_stored(
-        one_chip, laguna_engine_args, kind):
+        one_chip, laguna_engine_args, kernels_for_the_chip, kind):
     """The third paged kind in a step program (``decode/paged.py``): the
     full layers' pool ``[1, 12289, 16, 1024]`` and the window layers'
     ``[3, 2177, 16, 1024]`` are both taken row-major and unpadded,
     aliased whole and updated in place, never copied and no layer's slab
     sliced out; a window layer's gather is its ring of 34 blocks a row —
-    544 positions, where a full layer's is the table at capacity, 3,072
-    — and no gathered view of a window layer is wider. The result
-    carries the held experts' counters after the picks."""
+    544 positions — and no gathered view of a window layer is wider; a
+    full layer's batch rows are not gathered at all (one kernel call
+    walks their live blocks: ``ops/kv_walk.py``), only a prefill
+    chunk's one slot still is, at the table's capacity, 3,072. The
+    result carries the held experts' counters after the picks."""
     import re
     eng, programs = laguna_engine_args
     bucket, args = programs[kind]
@@ -778,13 +792,19 @@ def test_window_moe_step_program_keeps_both_pools_as_stored(
     rows = "" if kind == "prefill" else r"%d," % bucket
     got = [int(n) for n in re.findall(
         r"= bf16\[%s(\d+),16,1024\]\S* gather\(" % rows, hlo)]
-    # K and V of the one full layer at capacity, of the three window
-    # layers at the ring
-    assert sorted(got) == [34] * 6 + [192] * 2, got
+    # K and V of the three window layers at the ring; of the one full
+    # layer at capacity for a chunk's one slot alone: a batch's rows of
+    # a full layer are walked where they lie, by one kernel call
+    chunk = [34] * 6 + [192] * 2
+    assert sorted(got) == (chunk if kind == "prefill" else [34] * 6), got
+    walk = [l for l in hlo.splitlines() if MOSAIC in l]
+    assert len(walk) == (0 if kind == "prefill" else pool.k.shape[0])
+    assert all("f32[%d,48,1024]" % bucket in l.split(" custom-call(")[0]
+               for l in walk)
     if kind == "mixed":     # ... and the riding chunk's one slot
         one = [int(n) for n in re.findall(
             r"= bf16\[(\d+),16,1024\]\S* gather\(", hlo)]
-        assert sorted(one) == [34] * 6 + [192] * 2, one
+        assert sorted(one) == chunk, one
 
 
 def _toy_engine(family, ways, speculate, hybrid_config):
@@ -951,51 +971,114 @@ def _entry_results(hlo: str):
             for bits, dims in shape.findall(m.group(1))]
 
 
+# fixture -> whether the cell's full-kind pool takes the walk on the
+# chip (``paged.walks``: GPT-2 large's 1,280-lane rows, LFM2's 512,
+# Laguna's 1,024) or keeps the plain gather (the hybrid's ONE KV head
+# of 128 lanes, by measurement; the latent rows, a kind of their own)
+WALKS = {"gpt2_large_engine_args": True, "jamba_engine_args": False,
+         "glm_engine_args": False, "lfm2_engine_args": True,
+         "laguna_engine_args": True}
+
+
 @pytest.mark.parametrize("kind", ["decode", "mixed"])
-@pytest.mark.parametrize("fixture", ["gpt2_large_engine_args",
-                                     "jamba_engine_args",
-                                     "glm_engine_args",
-                                     "lfm2_engine_args"])
+@pytest.mark.parametrize("fixture", sorted(WALKS))
 def test_decode_program_reads_the_gathered_rows_as_stored(
         one_chip, request, kernels_for_the_chip, fixture, kind):
-    """The decode program attends over each slot's gathered blocks in
-    the form and dtype the pool stores them (``decode/paged.py::
-    stored_decode_attn``): beyond the gather itself, no instruction of
-    either serving cell's decode program produces an array of a
-    gathered view's elements (``slots * T_cap * H_kv*dh``) or more that
-    is wider than the stored dtype, and none transposes or copies one.
-    As for the pool's stored form, this stands in a counter's place:
-    the arithmetic does not engage sometimes. (The parent of PR 28
-    fails this for GPT-2 large with two ``reshape
-    f32[12,1024,20,64]`` a layer — each slot's rows cast to f32 and
-    split into heads of 64 lanes, written padded to 128 — and passes
-    for the hybrid, whose one KV head of 128 lanes it already read as
-    stored. ``temp_size_in_bytes`` of this 2-layer GPT-2 program: 152
-    MB on the parent, 0.8 MB now; one layer's attention alone 151 MB
-    against 0.) The latent cell's rows are read the same way: one
-    gather of 640-lane rows a layer, both products over them as stored,
-    no slice of the view for the values' 512 lanes. And the gated
-    convolution cell's: 8 KV heads x 64 lanes with four query heads a
-    group, a 512-lane row of whole tiles.)"""
+    """What stands in a counter's place: the read has no fallback
+    inside a cell, so the arithmetic does not engage sometimes.
+
+    **A pool that takes the walk** (``decode/paged.py::walks``; GPT-2
+    large, the gated convolution cell's 8 KV heads x 64 lanes, Laguna's
+    full layers): the decode-side program produces NO array of a
+    gathered view's elements (``slots * T_cap * H_kv*dh``) at all, in
+    any dtype — no gather, no copy, no ``[b, H, T_cap]`` scores — and
+    holds one kernel call a full-kind layer whose result is the heads'
+    sums over the stored row, ``f32[b, H, H_kv*dh]``
+    (``ops/kv_walk.py``), the K/V pool aliased whole beside it.
+    Laguna's window layers keep the plain read: their gather is the
+    ring, 34 blocks a row, well under a full view. (The parent of
+    PR 40 fails this with two gathers of the view's size a layer.)
+
+    **A pool that keeps the plain read** (the hybrid's one KV head of
+    128 lanes, the latent cell's rows): the program attends over each
+    slot's gathered blocks in the form and dtype the pool stores them
+    (``paged.gathered_decode_attn``): beyond the gather itself, no
+    instruction produces an array of a gathered view's elements or more
+    that is wider than the stored dtype, and none transposes or copies
+    one. (The parent of PR 28 failed this for GPT-2 large with two
+    ``reshape f32[12,1024,20,64]`` a layer; ``temp_size_in_bytes`` of
+    the 2-layer GPT-2 program: 152 MB then, 0.8 MB with the gather,
+    less with the walk.) The latent cell's rows: one gather of 640-lane
+    rows a layer, both products over them as stored, no slice of the
+    view for the values' 512 lanes."""
     eng, programs = request.getfixturevalue(fixture)
     bucket, args = programs[kind]
     compiled = eng._program(kind, bucket).lower(
         *_shapes_of(args, one_chip)).compile()
+    hlo = compiled.as_text()
     pool = eng.pool
     view = (bucket * eng.cfg.max_blocks_per_seq * pool.block_size
             * pool.k.shape[-1])
     passed = ("parameter", "get-tuple-element", "tuple", "bitcast")
     held = {(x.dtype.itemsize, x.size)              # updated in place
             for x in jax.tree_util.tree_leaves(args[:2])}
-    big = [r for r in _entry_results(compiled.as_text())
+    big = [r for r in _entry_results(hlo)
            if r[2] >= view and r[0] not in passed and r[1:] not in held]
-    assert big, "the gather's own results are of the view's size"
-    wide = [r for r in big if r[1] > pool.k.dtype.itemsize]
-    assert not wide, wide
-    moved = [r for r in big if r[0] in ("transpose", "copy")]
-    assert not moved, moved
+    walk = [l.split(" custom-call(")[0] for l in hlo.splitlines()
+            if MOSAIC in l and "f32[%d," % bucket in l
+            and ",%d]" % pool.k.shape[-1] in l.split(" custom-call(")[0]]
+    if WALKS[fixture]:
+        assert not big, big
+        assert len(walk) == pool.k.shape[0], walk
+        _carry_is_aliased_whole(compiled, eng)
+    else:
+        assert big, "the gather's own results are of the view's size"
+        assert not walk, walk
+        wide = [r for r in big if r[1] > pool.k.dtype.itemsize]
+        assert not wide, wide
+        moved = [r for r in big if r[0] in ("transpose", "copy")]
+        assert not moved, moved
     if fixture == "gpt2_large_engine_args":
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 22
+
+
+@pytest.mark.parametrize("cell", ["gpt2-large", "gpt2-large-f32",
+                                  "jamba2-3b", "lfm2-24b-a2b",
+                                  "laguna-s-2.1"])
+def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
+                                              kernels_for_the_chip, cell):
+    """The walk alone, for the described v5e, at each serving cell's
+    ``(b, H, H_kv, dh, MB)`` over its layers' pool: Mosaic takes it (the
+    hybrid's one-tile rows too, which ``paged.walks`` leaves to the
+    plain read by measurement, and a float32 pool), the pool is neither
+    copied nor held twice, and what the kernel keeps of the chip's fast
+    memory follows from the row's bytes: so many blocks a copy step
+    that the four buffers fit ``ssm._VMEM_BUDGET``."""
+    from distributed_llm_code_samples_tpu.ops import kv_walk, ssm
+    b, h, hkv, dh, mb, layers = {
+        "gpt2-large": (12, 20, 20, 64, 64, 36),
+        "gpt2-large-f32": (12, 20, 20, 64, 64, 36),
+        "jamba2-3b": (64, 20, 1, 128, 128, 2),
+        "lfm2-24b-a2b": (64, 32, 8, 64, 128, 2),
+        "laguna-s-2.1": (64, 48, 8, 128, 192, 3)}[cell]
+    dt = jnp.float32 if cell.endswith("f32") else jnp.bfloat16
+    j, blk = hkv * dh, 16
+    steps = kv_walk.blocks_a_step(blk, j * dt.dtype.itemsize, mb)
+    assert steps & (steps - 1) == 0 and (steps * blk) % 128 == 0
+    assert 4 * steps * blk * j * dt.dtype.itemsize <= ssm._VMEM_BUDGET
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    side = shape((layers, 1 + b * mb, blk, j), dt)
+    compiled = jax.jit(functools.partial(
+        kv_walk.walk_attn, layer=layers - 1, scale=dh ** -0.5)).lower(
+            side, side, q=shape((b, h, j), dt),
+            tables=shape((b, mb), jnp.int32),
+            lengths=shape((b,), jnp.int32)).compile()
+    assert sum(MOSAIC in l for l in compiled.as_text().splitlines()) == 1
+    m = compiled.memory_analysis()
+    # the two sides and the queries, once each
+    nbytes = (2 * int(np.prod(side.shape)) + b * h * j) * dt.dtype.itemsize
+    assert m.argument_size_in_bytes - nbytes < 2 ** 20
+    assert m.temp_size_in_bytes < 2 ** 20
 
 
 # cell -> (its shrink under benchmark/tests, the per-layer metric that

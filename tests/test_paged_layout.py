@@ -8,9 +8,11 @@ rows come back from the gather at the right (position, head), a block
 document is still head-major ``[L, n, H_kv, block, dh]`` and implants
 bit-identically, and the block-level edits touch the named block in
 every layer and nothing else. The decode programs' attention over the
-gathered rows as stored (``stored_decode_attn``) is held to the oracle
-``decode_attn(q, *vmap(gather_layer))`` and to a NumPy model of its own
-arithmetic.
+rows as stored (``stored_decode_attn``) is held to the oracle
+``decode_attn(q, *vmap(gather_layer))`` in both its forms: the plain
+gather and two products (``gathered_decode_attn``; also to a NumPy
+model of its own arithmetic) and the walk over each row's live blocks
+(``ops/kv_walk.py``).
 """
 
 import jax
@@ -19,9 +21,10 @@ import numpy as np
 import pytest
 
 from distributed_llm_code_samples_tpu.decode.paged import (
-    KV_DTYPES, _quantize, _rows_major, copy_block, copy_block_rows,
-    corrupt_block, extract_blocks, gather_layer, implant_block, init_pool,
-    scrub_blocks, stored_decode_attn, write_chunk, write_rows)
+    KV_DTYPES, SCRATCH_BLOCK, _quantize, _rows_major, copy_block,
+    copy_block_rows, corrupt_block, extract_blocks, gather_layer,
+    gathered_decode_attn, implant_block, init_pool, scrub_blocks,
+    stored_decode_attn, walks, write_chunk, write_rows)
 from distributed_llm_code_samples_tpu.models.lm import decode_attn
 
 L, NB, HKV, BLK, DH = 2, 7, 3, 4, 8
@@ -273,15 +276,36 @@ def _attn_case(shape, kv_dtype, stale, seed=0):
     return pool, q, jnp.asarray(tables, jnp.int32), model
 
 
+def _two_roundings_bound(qg, kc, vc, live, ks=None, vs=None):
+    """What rounding the two small operands to bf16 (``u = 2**-9``) may
+    move a head's output by, ``[b, H_kv, g]``: a score moves by at most
+    ``ds = u * max_t sum_j |q_j k_tj| / sqrt(dh)``, so a probability by
+    a factor within ``exp(+-2 ds)``, and its own rounding adds ``u``:
+    ``(exp(2 ds) - 1 + u) * max_t |v_t|``. ``qg [b, H_kv, g, dh]``,
+    ``kc/vc [b, H_kv, T, dh]`` the stored values as floats, ``live [b,
+    T]``, ``ks/vs [b, H_kv, T]`` their scales (int8)."""
+    u = 2.0 ** -9
+    one = np.ones(kc.shape[:3])
+    ks, vs = one if ks is None else ks, one if vs is None else vs
+    ds = u * np.where(live[:, None, None, :], np.einsum(
+        "bkgd,bktd->bkgt", np.abs(qg), np.abs(kc)) * ks[:, :, None],
+        0).max(-1) / np.sqrt(qg.shape[-1])                  # [b, k, g]
+    vmax = np.where(live[:, None, :, None], np.abs(vc) * vs[..., None],
+                    0).max((2, 3))                          # [b, k]
+    return (np.expm1(2 * ds) + u) * vmax[:, :, None]
+
+
 def _stored(pool, q, tables):
-    return np.asarray(jax.jit(lambda q: stored_decode_attn(
+    return np.asarray(jax.jit(lambda q: gathered_decode_attn(
         pool, 0, q, tables, jnp.asarray(A_LENGTHS)))(q))
 
 
 @pytest.mark.parametrize("kv_dtype", KV_DTYPES)
 @pytest.mark.parametrize("shape", sorted(ATTN_SHAPES))
-def test_stored_decode_attn_matches_the_oracle(shape, kv_dtype):
-    """``stored_decode_attn`` against ``decode_attn(q,
+def test_gathered_decode_attn_matches_the_oracle(shape, kv_dtype):
+    """``gathered_decode_attn`` (the plain decode-side read: every
+    int8 pool's, and what the walk is held to below) against
+    ``decode_attn(q,
     *vmap(gather_layer))`` on the same pool bytes, ragged lengths from 1
     to the capacity, stale rows beyond them at the largest finite
     values. An f32 pool: the same f32 products in another order, 1e-5
@@ -328,13 +352,7 @@ def test_stored_decode_attn_matches_the_oracle(shape, kv_dtype):
     assert np.abs(got - model).max() <= 1e-4 * scale
     # the model with nothing rounded is the oracle
     assert np.abs(attend(qg, lambda p: p) - want).max() <= 1e-5 * scale
-    u = 2.0 ** -9
-    ds = u * np.where(mask, np.einsum(
-        "bkgd,bktd->bkgt", np.abs(qg), np.abs(kc)) * ks[:, :, None],
-        0).max(-1) / np.sqrt(dh)                            # [b, k, g]
-    vmax = np.where(live[:, None, :, None], np.abs(vc) * vs[..., None],
-                    0).max((2, 3))                          # [b, k]
-    bound = (np.expm1(2 * ds) + u) * vmax[:, :, None]
+    bound = _two_roundings_bound(qg, kc, vc, live, ks, vs)
     err = np.abs(got - want).reshape(b, hkv, h // hkv, dh).max(-1)
     assert (err <= bound + 1e-5 * scale).all()
     # the roundings are really there (bf16 operands, not an f32 upcast
@@ -351,3 +369,106 @@ def test_stale_bytes_beyond_the_length_carry_no_mass(kv_dtype):
     zero = _stored(*_attn_case("mha20x64", kv_dtype, "zero")[:3])
     assert np.isfinite(large).all()
     np.testing.assert_array_equal(large, zero)
+
+
+# ---------------------------------------------------------------------
+# the walk over each row's live blocks (``ops/kv_walk.py``: what
+# ``stored_decode_attn`` runs where ``paged.walks`` says so), against the
+# oracle it replaces: the four serving cells' head layouts — GPT-2's
+# MHA of 64, LFM2's 8 KV heads of 64 with 4 query heads a group, the
+# hybrid's ONE KV head of 128 under 20, Laguna's 8 of 128 with 6 a group
+WALK_SHAPES = {"mha4x64": (4, 4, 64), "gqa32over8x64": (32, 8, 64),
+               "mqa20over1x128": (20, 1, 128),
+               "gqa48over8x128": (48, 8, 128)}
+W_MB, W_BLK = 5, 8
+# ragged in one batch: one position, a block less one, a whole block, a
+# block and one, the capacity; and a bucket's padded row (its table all
+# scratch, one position)
+W_LENGTHS = np.asarray([1, W_BLK - 1, W_BLK, W_BLK + 1, W_MB * W_BLK, 1],
+                       np.int32)
+W_POISONED = 3          # the row of ``W_BLK + 1`` positions: 2 live blocks
+
+
+def _walk_case(shape, kv_dtype, poison):
+    """A two-layer pool (the read is of layer 1), every byte random and
+    finite but the stale ones beyond each row's length, which are the
+    largest a freed sequence could leave; permuted tables; then
+    ``poison``: a NaN in a DEAD block of row ``W_POISONED``'s table
+    (``"dead"``) or beyond its length inside its last live block
+    (``"last"``)."""
+    hq, hkv, dh = WALK_SHAPES[shape]
+    rng = np.random.default_rng(1)
+    b = len(W_LENGTHS)
+    nb = 1 + (b - 1) * W_MB
+    tables = 1 + rng.permutation((b - 1) * W_MB).reshape(b - 1, W_MB)
+    tables = np.concatenate(
+        [tables, np.full((1, W_MB), SCRATCH_BLOCK)]).astype(np.int32)
+    pool = init_pool(2, nb, hkv, W_BLK, dh, kv_dtype)
+    live = np.zeros((nb, W_BLK), bool)
+    for r in range(b):
+        pos = np.arange(W_LENGTHS[r])
+        live[tables[r][pos // W_BLK], pos % W_BLK] = True
+    sides = []
+    for _ in "kv":
+        src = rng.normal(size=(2, nb, W_BLK, hkv * dh)).astype(np.float32)
+        src = np.where(live[None, :, :, None], src, 3e4)
+        if poison == "dead":
+            src[:, tables[W_POISONED, 2:]] = np.nan
+        elif poison == "last":
+            src[:, tables[W_POISONED, 1], 1:] = np.nan
+        sides.append(jnp.asarray(src, pool.k.dtype))
+    pool = pool._replace(k=sides[0], v=sides[1])
+    q = jnp.asarray(rng.normal(size=(b, hq, dh)), jnp.float32)
+    return pool, q, jnp.asarray(tables)
+
+
+def _walked(pool, q, tables):
+    assert walks(pool)
+    return np.asarray(jax.jit(lambda q: stored_decode_attn(
+        pool, 1, q, tables, jnp.asarray(W_LENGTHS)))(q))
+
+
+@pytest.mark.parametrize("poison", ["none", "dead", "last"])
+@pytest.mark.parametrize("kv_dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("shape", sorted(WALK_SHAPES))
+def test_walked_stored_decode_attn_matches_the_oracle(shape, kv_dtype, poison):
+    """``stored_decode_attn`` over a pool that takes the walk, against
+    ``decode_attn(q, *vmap(gather_layer))`` on the same bytes, under the
+    plain form's bounds: an f32 pool to reduction order (1e-5 of the
+    output's scale), a bf16 pool to the two roundings of the small
+    operands (the online softmax rounds ``exp(s - m)`` for the running
+    maximum ``m`` where the plain form rounds the normalised
+    probability: one relative ``u`` either way). And what the walk
+    changes, stated: a NaN in a DEAD block of a row's table does not
+    reach the row, bit for bit; one beyond the length inside its last
+    live block poisons that row and no other, as in the plain form."""
+    pool, q, tables = _walk_case(shape, kv_dtype, "none")
+    got = _walked(pool, q, tables)
+    lengths = jnp.asarray(W_LENGTHS)
+    if poison != "none":
+        bad = _walked(*_walk_case(shape, kv_dtype, poison))
+        rows = np.arange(len(W_LENGTHS)) != W_POISONED
+        np.testing.assert_array_equal(bad[rows], got[rows])
+        if poison == "dead":
+            np.testing.assert_array_equal(bad, got)
+        else:
+            assert np.isnan(bad[W_POISONED]).all()
+        return
+    kc, vc = jax.vmap(lambda t: gather_layer(pool, 1, t))(tables)
+    want = np.asarray(jax.jit(decode_attn)(q, kc, vc, lengths))
+    assert got.shape == want.shape and got.dtype == np.float32
+    assert np.isfinite(got).all()
+    scale = np.abs(want).max()
+    if kv_dtype == "f32":
+        assert np.abs(got - want).max() <= 1e-5 * scale
+        return
+    b, h, dh = q.shape
+    hkv = kc.shape[1]
+    qg = np.asarray(q, np.float64).reshape(b, hkv, h // hkv, dh)
+    live = np.arange(kc.shape[2]) < W_LENGTHS[:, None]
+    bound = _two_roundings_bound(qg, np.asarray(kc, np.float64),
+                                 np.asarray(vc, np.float64), live)
+    err = np.abs(got - want).reshape(b, hkv, h // hkv, dh).max(-1)
+    assert (err <= bound + 1e-5 * scale).all()
+    # bf16 operands, not an f32 upcast of the cache
+    assert err.max() > 1e-4 * scale

@@ -303,6 +303,57 @@ def test_no_writer_no_record_digest_carries_phase_ms(lm_params, prompts,
     json.dumps(list(eng.flight))            # the dump stays serialisable
 
 
+@pytest.mark.parametrize("kv_dtype,speculate", [
+    ("bf16", 0), ("f32", 2), ("int8", 0)])
+def test_kv_blocks_read_is_what_the_decode_side_reads_fetch(
+        lm_params, prompts, kv_dtype, speculate):
+    """``kv_blocks_read`` of a step's record and digest (telemetry v22):
+    over the rows of the decode-side programs the step LAUNCHED, a
+    bucket's padded ones (one scratch block each) with them, the blocks
+    that hold the positions each row attends over, times the pool's
+    layers, where the read walks the rows' tables (a float pool; a
+    verify program's rows read once a sub-step, each one position
+    further); every table's capacity where it gathers (an int8 pool).
+    ``kv_blocks_capacity`` beside it is the gather's in both; the
+    ``decode`` record carries the two summed."""
+    from distributed_llm_code_samples_tpu.decode.paged import walks
+    sink = Collector()
+    eng = DecodeEngine(lm_params, H, EngineConfig(
+        **BASE, kv_dtype=kv_dtype, speculate=speculate), metrics=sink)
+    assert walks(eng.pool) is (kv_dtype != "int8")
+    for p in prompts:
+        eng.submit(p, 6)
+    blk, mb = BASE["block_size"], BASE["max_blocks_per_seq"]
+    launched, launch = [], eng._launch
+
+    def spy(phase, bucket, fn, params, operand, land):
+        # the rows' lengths as the program is handed them (a padded
+        # row's is 0: it attends over the one position it writes)
+        if phase != "prefill":
+            launched.append(eng.programs.wire(phase, bucket).unpack(
+                operand)["lengths"])
+        return launch(phase, bucket, fn, params, operand, land)
+
+    eng._launch = spy
+    read = capacity = 0
+    while eng.active or eng.waiting:
+        del launched[:]
+        eng.step()
+        rec = sink.steps()[-1]
+        held = sum(len(n) for n in launched) * (speculate + 1) * mb
+        want = held if kv_dtype == "int8" else sum(
+            int((-(-(n + 1 + sub) // blk)).sum())
+            for n in launched for sub in range(speculate + 1))
+        assert rec["kv_blocks_read"] == L * want
+        assert rec["kv_blocks_capacity"] == L * held
+        assert eng.flight[-1]["kv_blocks_read"] == L * want
+        read, capacity = read + L * want, capacity + L * held
+    assert 0 < read <= capacity and (read < capacity) is (kv_dtype != "int8")
+    doc = eng.telemetry_record()
+    assert (doc["kv_blocks_read"], doc["kv_blocks_capacity"]) == (
+        read, capacity)
+
+
 def test_record_counts_are_the_engines_counters(lm_params, prompts):
     """One record an executed step, holding what is read and no more:
     the step number and ``tokens_generated`` after the step (what a
@@ -327,6 +378,7 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
                             "window_rows", "full_rows",
                             "window_blocks_released",
                             "window_blocks_live",
+                            "kv_blocks_read", "kv_blocks_capacity",
                             "dispatches", "readbacks", "launches"}
         assert rec["window_rows"] == rec["full_rows"] == 0  # nor window
         assert rec["launches"] == eng.launches
@@ -581,6 +633,14 @@ def test_report_reads_step_phases_and_keeps_the_waterfall(
     text = capsys.readouterr().out
     assert "step phases:" in text and "decode.readback" in text
     assert "program (bucket)" in text and "mixed (3)" in text
+    # the cache-reads line (v22): the blocks the decode-side reads
+    # fetched a step beside their rows' tables, as the engine counted
+    reads = table["cache_reads"]["blocks"]
+    assert reads["kv_blocks_read_mean"] * reads["steps"] == pytest.approx(
+        eng.kv_blocks_read, abs=0.01 * reads["steps"])
+    assert 0 < reads["kv_blocks_read_mean"] < reads[
+        "kv_blocks_capacity_mean"]
+    assert "blocks a step fetched by the decode-side reads" in text
     assert "per-request waterfalls" in text
 
 

@@ -122,7 +122,12 @@ from distributed_llm_code_samples_tpu.runtime.telemetry import (
 # v21 (PR 39): a third paged kind — the ``engine_step`` record may
 # carry the cache reads' four counters (``STEP_SPAN_WINDOW``), all or
 # none.
-_PINNED_VERSION = 21
+# v22 (PR 40): the decode-side read walks each row's live blocks — the
+# ``engine_step`` record may carry the blocks the launched rows' reads
+# fetched beside their tables' capacity (``STEP_SPAN_KV``), both or
+# none.
+_PINNED_VERSION = 22
+_PINNED_STEP_SPAN_KV = frozenset({"kv_blocks_read", "kv_blocks_capacity"})
 _PINNED_STEP_SPAN_WINDOW = frozenset({
     "window_rows", "full_rows", "window_blocks_released",
     "window_blocks_live"})
@@ -366,7 +371,7 @@ def test_engine_step_v20_round_trips(tmp_path):
                                                   METRICS_FILENAME))
     assert problems == []
     first, closing, idle = records
-    assert first["schema"] == SCHEMA_VERSION == 21
+    assert first["schema"] == SCHEMA_VERSION == 22
     assert first["dispatches"] == [["prefill", 4], ["decode", 8]]
     assert [p[0] for p in first["phases"] if p[0].endswith(".dispatch")] \
         == [k + ".dispatch" for k, _ in first["dispatches"]]
@@ -404,6 +409,35 @@ def test_engine_step_v21_cache_read_counters(over, ok):
     assert got is ok, reason
     if not ok:
         assert "window_rows" in reason and "\n" not in reason
+
+
+KV_READS = dict(kv_blocks_read=2 * 70, kv_blocks_capacity=2 * 8 * 24)
+
+
+@pytest.mark.parametrize("over,ok", [
+    (KV_READS, True),
+    (dict.fromkeys(KV_READS, 0), True),      # a step with no decode row
+    (dict(KV_READS, kv_blocks_read=2 * 8 * 24), True),   # a gather's
+    (dict(KV_READS, **WINDOW_READS), True),
+    ({"kv_blocks_read": 5}, False),          # both or none
+    ({"kv_blocks_capacity": 5}, False),
+    (dict(KV_READS, kv_blocks_read=2 * 8 * 24 + 1), False),  # over it
+    (dict(KV_READS, kv_blocks_capacity=-1), False),
+    (dict(KV_READS, kv_blocks_read=140.0), False),
+])
+def test_engine_step_v22_kv_block_counters(over, ok):
+    """The decode-side reads' blocks of an ``engine_step`` record
+    (``STEP_SPAN_KV``): both or none, whole, not negative, no more read
+    than the launched rows' tables hold."""
+    from distributed_llm_code_samples_tpu.runtime.telemetry import (
+        STEP_SPAN_KV)
+    assert frozenset(STEP_SPAN_KV) == _PINNED_STEP_SPAN_KV
+    rec = dict(_engine_step(**over), schema=SCHEMA_VERSION, kind="span",
+               trace_id=None, tenant=None)
+    got, reason = validate_record(rec)
+    assert got is ok, reason
+    if not ok:
+        assert "kv_blocks_read" in reason and "\n" not in reason
 
 
 @pytest.mark.parametrize("case,named", [
