@@ -46,8 +46,13 @@ program to the next on the device (the carry's token store). A
 recurrent layer's state lives beside the pool, by slot, and a window
 layer's blocks in a second pool with a short table and a free list of
 their own (``wtables``, ``free_wblocks``: a slot's window blocks are a
-ring, taken at admission and handed back with the slot; ``paged.py``);
-what moves a sequence by its ONE block table alone — prefix hits,
+ring, taken at admission and handed back with the slot; ``paged.py``).
+A chunked layer (chunk-summarised attention) has an index in BOTH
+pools: its ring is the window kind's, and a ROW of the full kind's pool
+stands for one finished chunk of ``block_size`` positions, so a table
+of ``max_blocks_per_seq`` blocks holds ``block_size`` times the
+positions (``capacity``, ``_blocks_needed``); what moves a sequence by
+its ONE block table alone — prefix hits,
 speculation, a mesh, the KV handoff, snapshots, the spill tier —
 refuses in one line for such a model
 (``_refuse_kept_beside``), by what the model is and under no flag. A
@@ -178,7 +183,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.face import ATTN, LATENT, WINDOW, ServedModel
+from ..models.face import ATTN, CHUNKED, LATENT, WINDOW, ServedModel
 from ..parallel import launcher
 from ..parallel.mesh import MODEL_AXIS
 from ..runtime.policy import QosPolicy
@@ -275,6 +280,14 @@ EXPERT_COUNTERS = ("expert_rows", "experts_touched", "expert_rows_max")
 # window layer
 WINDOW_COUNTERS = ("window_rows", "full_rows", "window_blocks_released",
                    "window_blocks_live")
+
+# ... and a chunked layer's (``_count_rows`` too): the chunk summaries
+# the step's launched rows attend over (those of every window before a
+# row's own; a chunk's view counted once) and the rows among them that
+# finished a chunk and so wrote its summary. For such a model
+# ``window_rows`` counts the positions of a row's own ALIGNED window.
+# Both 0 for a model with no chunked layer
+CHUNK_COUNTERS = ("summary_rows", "summaries_written")
 
 
 # ... and the pool's blocks the decode-side read of the step's launched
@@ -577,13 +590,21 @@ class DecodeEngine:
         # cannot carry that state yet refuses, here and at the entry of
         # every later call, by what the model is: no flag turns it off
         kinds = {kind for kind, _ in params.layers}
-        self.recurrent = sorted(kinds - {ATTN, LATENT, WINDOW})
-        self.windowed = WINDOW in kinds
+        self.recurrent = sorted(kinds - {ATTN, LATENT, WINDOW, CHUNKED})
+        # chunked layers keep their exact keys in the window kind's
+        # ring: whatever a window layer's second table refuses, they do
+        self.chunked = CHUNKED in kinds
+        self.windowed = WINDOW in kinds or self.chunked
+        # ... as a refusal names them
+        self._ringed = "/".join(sorted(kinds & {WINDOW, CHUNKED}))
         if self.windowed and cfg.kv_dtype == "int8":
             raise ValueError(
-                "kv_dtype int8 is not served for a model with window "
-                "layers: a block's scale is its write history's, and a "
-                "window block is overwritten as a ring")
+                "kv_dtype int8 is not served for a model with "
+                f"{self._ringed} layers: a block's scale is its "
+                "write history's, and a window block is overwritten as "
+                "a ring")
+        if self.chunked:
+            self._check_chunked(params.cache_spec(n_heads), cfg, kinds)
         if mesh is not None:
             self._refuse_kept_beside("a model-axis mesh (--tp)")
             if LATENT in kinds:
@@ -664,6 +685,10 @@ class DecodeEngine:
         self._inflight: _Launch | None = None
         self.launches = 0
         s, mb = cfg.max_slots, cfg.max_blocks_per_seq
+        # cached positions one sequence may hold: a table's rows, each
+        # of which a chunked layer's summaries make a whole chunk
+        self.capacity = cfg.capacity * (cfg.block_size if self.chunked
+                                        else 1)
         self.tables = np.full((s, mb), SCRATCH_BLOCK, np.int32)
         # cached positions by slot, counted as rows are LAUNCHED
         self.lengths = np.zeros((s,), np.int32)
@@ -814,7 +839,8 @@ class DecodeEngine:
         self._step_state_bytes = 0
         # the cache reads of the rows this step launched and the window
         # blocks' turnover (``WINDOW_COUNTERS``, ``_count_rows``)
-        self._step_window = dict.fromkeys(WINDOW_COUNTERS, 0)
+        self._step_window = dict.fromkeys(
+            WINDOW_COUNTERS + CHUNK_COUNTERS, 0)
         self._step_kv = dict.fromkeys(KV_COUNTERS, 0)
         # the step programs this step launched, ``[kind, bucket]`` in
         # launch order (``_launch``): the engine_step record's and the
@@ -839,11 +865,37 @@ class DecodeEngine:
 
     # -- pool ----------------------------------------------------------
 
+    @staticmethod
+    def _check_chunked(spec, cfg, kinds) -> None:
+        """What a chunked layer's two stores hold the engine to, each
+        refused by name: a pool block IS a chunk, a window is whole
+        blocks, a prefill chunk lies inside one block (it finishes at
+        most one chunk), and every layer is of the kind (a layer owns
+        the same index in both pools)."""
+        blk = cfg.block_size
+        if kinds != {CHUNKED}:
+            raise ValueError(
+                "a model with chunked layers is served with every layer "
+                f"chunked only, got {sorted(kinds)}: a chunked layer "
+                "owns the same index in both pools")
+        if spec.chunk != blk or spec.window % blk:
+            raise ValueError(
+                f"chunked layers summarise chunks of {spec.chunk} "
+                f"positions in windows of {spec.window}: served with "
+                f"block_size == the chunk only, got {blk} (a pool block "
+                "is a chunk, its summary one row)")
+        if blk % cfg.prefill_chunk:
+            raise ValueError(
+                f"prefill_chunk {cfg.prefill_chunk} does not divide the "
+                f"chunked layers' chunk of {blk} positions: a prefill "
+                "chunk would straddle a chunk's boundary and finish "
+                "more than one summary")
+
     def _refuse_kept_beside(self, what: str) -> None:
         """The one line every path that moves a sequence by its ONE
         block table refuses with, for a model that keeps more of a
-        sequence beside it: a recurrent state, or window layers' blocks
-        in a table of their own."""
+        sequence beside it: a recurrent state, or window (or chunked)
+        layers' blocks in a table of their own."""
         if self.recurrent:
             raise ValueError(
                 f"{what} is not served for a model with "
@@ -851,7 +903,8 @@ class DecodeEngine:
                 "their recurrent state yet")
         if self.windowed:
             raise ValueError(
-                f"{what} is not served for a model with window layers: "
+                f"{what} is not served for a model with {self._ringed} "
+                "layers: "
                 "it moves a sequence by one block table, and theirs is "
                 "a second one")
 
@@ -1336,11 +1389,11 @@ class DecodeEngine:
         # (_blocks_needed counts the same way), so a request may exactly
         # fill its block reservation
         cached = len(prompt) + max_new - 1
-        if cached > self.cfg.capacity:
+        if cached > self.capacity:
             raise ValueError(
                 f"prompt {len(prompt)} + max_new {max_new} needs "
                 f"{cached} cached positions, exceeding the per-sequence "
-                f"cache capacity {self.cfg.capacity} "
+                f"cache capacity {self.capacity} "
                 "(max_blocks_per_seq * block_size)")
         if cached > self.params.max_seq_len:
             raise ValueError(
@@ -1483,7 +1536,17 @@ class DecodeEngine:
         return seq.uid
 
     def _blocks_needed(self, t0: int, max_new: int) -> int:
-        return blocks_needed(t0, max_new, self.cfg.block_size)
+        """The request's blocks of the full kind's pool: its positions'
+        or, for chunked layers, its chunks' summary rows' (one row a
+        block of positions)."""
+        need = blocks_needed(t0, max_new, self.cfg.block_size)
+        return -(-need // self.cfg.block_size) if self.chunked else need
+
+    def _wblocks_needed(self, t0: int, max_new: int) -> int:
+        """... and of the window kind's a constant: a ring as long as
+        the window table, or the whole request where it is shorter."""
+        return min(blocks_needed(t0, max_new, self.cfg.block_size),
+                   self.programs.window_blocks)
 
     # -- request lifecycle (telemetry schema v4 `request` records) -----
 
@@ -1671,9 +1734,7 @@ class DecodeEngine:
             head_i, head_vt = self._next_waiting_index()
             seq = self.waiting[head_i]
             need = self._blocks_needed(len(seq.prompt), seq.max_new)
-            # ... and of the window kind a constant: a ring as long as
-            # the window table, or the whole request where it is shorter
-            need_w = min(need, self.programs.window_blocks)
+            need_w = self._wblocks_needed(len(seq.prompt), seq.max_new)
             free_slots = [i for i, s in enumerate(self.slots) if s is None]
             if not free_slots:
                 break
@@ -2501,11 +2562,23 @@ class DecodeEngine:
         ``first`` on (a decode row writes one position, a chunk its
         own), so a full layer reads ``last + 1`` positions, a window
         layer at most the window, and every block the write OPENS
-        beyond the ring's length overwrites one that is behind it."""
+        beyond the ring's length overwrites one that is behind it. A
+        chunked layer's counts (``CHUNK_COUNTERS``) are taken here
+        too."""
         blk, entries = self.cfg.block_size, self.programs.window_blocks
         w = self._step_window
+        window = self.spec.window
         w["full_rows"] += int((last + 1).sum())
-        w["window_rows"] += int(np.minimum(last + 1, self.spec.window).sum())
+        if self.chunked:
+            # the row's own aligned window up to itself, and a summary
+            # for every chunk of the windows before it; a write that
+            # ends on a chunk's last position finishes its summary
+            w["window_rows"] += int((last % window + 1).sum())
+            w["summary_rows"] += int((last // window).sum()) * (window
+                                                                 // blk)
+            w["summaries_written"] += int((last % blk == blk - 1).sum())
+        else:
+            w["window_rows"] += int(np.minimum(last + 1, window).sum())
         opened = last // blk - (first - 1) // blk   # block starts in range
         fresh = np.minimum(opened, last // blk + 1 - entries)
         w["window_blocks_released"] += int(np.maximum(fresh, 0).sum())
@@ -2524,6 +2597,13 @@ class DecodeEngine:
         capacity = reads * b * self.cfg.max_blocks_per_seq * layers
         if self._walks:
             n = self.lengths[ready][:, None] + 1 + np.arange(reads)
+            if self.chunked:
+                # the pool's rows are summaries: those of the windows
+                # before the row's own (a row in its first window
+                # walks one block, masked whole)
+                n = np.maximum(
+                    (n - 1) // self.spec.window * (self.spec.window // blk),
+                    1)
             read = (int((-(-n // blk)).sum())
                     + reads * (b - len(ready))) * layers
         else:
@@ -2933,7 +3013,8 @@ class DecodeEngine:
         self._step_prefill_uid = None
         self._step_decode_uids = []
         self._step_state_bytes = 0
-        self._step_window = dict.fromkeys(WINDOW_COUNTERS, 0)
+        self._step_window = dict.fromkeys(
+            WINDOW_COUNTERS + CHUNK_COUNTERS, 0)
         self._step_kv = dict.fromkeys(KV_COUNTERS, 0)
         self._step_dispatches = []
         self._step_readbacks = []
@@ -3138,8 +3219,8 @@ class DecodeEngine:
                           if s is not None)
         if not live_blocks:
             return 0.0
-        return 1.0 - self.live_tokens() / (live_blocks
-                                           * self.cfg.block_size)
+        return 1.0 - self.live_tokens() / (
+            live_blocks * self.capacity // self.cfg.max_blocks_per_seq)
 
     def kv_bytes_stored(self) -> int:
         """Live-token KV bytes at the engine's storage dtype — the

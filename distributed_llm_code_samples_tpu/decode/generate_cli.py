@@ -989,7 +989,8 @@ def generate_main(argv=None) -> int:
                 # (handoff, snapshot-resume): refused up front, by what
                 # the model is, and not mid-serve
                 why = ("it moves a sequence by one block table, and "
-                       "theirs is a second one" if kinds == {"window"}
+                       "theirs is a second one"
+                       if kinds <= {"window", "chunked"}
                        else "they cannot carry their recurrent state yet")
                 raise ValueError(
                     "--fleet / --snapshot_dir are not served for a "

@@ -4,7 +4,7 @@ ONE function builds the engine for ``train_ffns.py generate
 --model_config FILE`` and for the benchmark's driver file
 (``benchmark/configs/jamba_engine_driver.py``,
 ``glm_moe_engine_driver.py``, ``lfm2_moe_engine_driver.py``,
-``laguna_engine_driver.py``): the
+``laguna_engine_driver.py``, ``evabyte_engine_driver.py``): the
 published keys say what the model is
 (``model_type`` picks the family's file under ``models/``, its
 ``spec_from_config`` reads the rest), the weights come from a seed or from the caller (a checkpoint restored into
@@ -18,7 +18,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..models import hybrid_lm, laguna_lm, lfm2_moe_lm, mla_moe_lm
+from ..models import (evabyte_lm, hybrid_lm, laguna_lm, lfm2_moe_lm,
+                      mla_moe_lm)
 from .engine import DecodeEngine, EngineConfig
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -31,6 +32,7 @@ FAMILIES = {
     "lfm2_moe": (lfm2_moe_lm.spec_from_config,
                  lfm2_moe_lm.init_lfm2_moe_lm),
     "laguna": (laguna_lm.spec_from_config, laguna_lm.init_laguna_lm),
+    "evabyte": (evabyte_lm.spec_from_config, evabyte_lm.init_evabyte_lm),
 }
 
 
@@ -58,7 +60,8 @@ def params_from_config(config: dict, seed: int = 0):
                              seed >> 31)
     return init(
         key, spec, dtype=weights_dtype(config),
-        scale=float(config.get("initializer_range", 2e-2)))
+        scale=float(config.get("initializer_range",
+                               config.get("init_std", 2e-2))))
 
 
 def engine_from_config(config: dict, params=None, *, seed: int = 0,

@@ -100,6 +100,23 @@ ONE block table (the prefix cache, spill, handoff, snapshots,
 speculation, int8's write history, the head-sharded mesh) refuses a
 model with window layers in one line (``decode/engine.py``).
 
+A **chunked** layer (``models/face.py::CHUNKED``: the fourth paged
+kind, chunk-summarised attention) owns TWO stores under its one index
+and adds no pool of its own: the window kind's ring, read under the
+ALIGNED rule (``models/attention.py::aligned_mask``: a query sees the
+keys of its own multiple-of-``window`` window up to itself; the windows
+do not slide), and one ROW of the full kind's pool for every finished
+chunk of ``block`` positions (a pool block IS a chunk): row ``j`` of
+the sequence's table holds ``(ktilde_j, vtilde_j)``, the model's
+``chunk_summary`` of ring block ``j`` AS STORED, written by whichever
+program writes the chunk's last position (``write_summaries``; the
+scratch block on every other step). A row attends over its ring and
+over the summaries of every earlier window, ``(window / block) *
+(position // window)`` rows, under ONE softmax: each read hands back
+its softmax statistics beside its result (``stats=True``) and
+``join_reads`` puts them together. A full-kind table of ``MB`` blocks
+so stands for ``MB * block * block`` positions.
+
 The pool's layer axis counts the layers that own a KV cache index: all
 of an ``LMParams``' layers, the attention layers only of a hybrid
 (``models/hybrid_lm.py``: 2 of 28). What such a model's other layers
@@ -607,7 +624,8 @@ def corrupt_block(pool: PagedKV, block: int) -> PagedKV:
 
 def gathered_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                          tables: jax.Array, lengths: jax.Array,
-                         window: int = 0) -> jax.Array:
+                         window: int = 0, aligned: bool = False,
+                         stats: bool = False):
     """The PLAIN decode-side read: single-query attention for one layer
     over a gather of every row's whole table, the rows AS STORED — what
     ``stored_decode_attn`` runs for the pools that do not take the walk
@@ -615,7 +633,11 @@ def gathered_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     dh]`` f32, ``tables [B, MB]`` int32, ``lengths [B]`` attendable
     positions; returns ``[B, H, dh]`` f32. ``window`` > 0: ``tables``
     are window layers' short tables, used as rings, and a row attends
-    over its last ``window`` positions (``ring_positions``). The same
+    over its last ``window`` positions (``ring_positions``), or, where
+    ``aligned``, over the positions of its own multiple-of-``window``
+    window up to itself. ``stats``: ``(y, m [B, H], l [B, H])``, the
+    result beside each head's score maximum and its sum of ``exp(s -
+    m)`` (``join_reads``). The same
     mathematics as the oracle ``decode_attn(q,
     *vmap(gather_layer), lengths)`` — same mask, scale and f32 softmax —
     as two matrix products over ``[B, T_cap, H_kv*dh]`` in the pool's
@@ -670,22 +692,28 @@ def gathered_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
         if pool.k_scale is not None:
             s = s * ks
         s = s / jnp.sqrt(jnp.asarray(dh, jnp.float32))
+        from ..models.attention import (aligned_mask, softmax_stats,
+                                        window_mask)
         if window:
-            from ..models.attention import window_mask
             last = lengths - 1
-            mask = window_mask(
+            mask = (aligned_mask if aligned else window_mask)(
                 last[:, None, None],
                 ring_positions(last, tables.shape[1], blk)[:, None, :],
                 window)
         else:
             mask = jnp.arange(k.shape[1]) < lengths[:, None, None]
-        p = jax.nn.softmax(jnp.where(mask, s, jnp.float32(-1e30)), axis=-1)
+        s = jnp.where(mask, s, jnp.float32(-1e30))
+        if stats:
+            p, m, l = softmax_stats(s)
+        else:
+            p = jax.nn.softmax(s, axis=-1)
         if pool.k_scale is not None:
             p = p * vs
         full = jnp.einsum("bht,btj->bhj", p.astype(dt), v.astype(dt),
                           preferred_element_type=jnp.float32)
         y = jnp.einsum("bkgkd->bkgd", full.reshape(b, hkv, g, hkv, dh))
-    return y.reshape(b, h, dh)
+    y = y.reshape(b, h, dh)
+    return (y, m, l) if stats else y
 
 
 def walks(pool: PagedKV, window: int = 0, shards: int = 1) -> bool:
@@ -727,14 +755,18 @@ def walks(pool: PagedKV, window: int = 0, shards: int = 1) -> bool:
 
 def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                        tables: jax.Array, lengths: jax.Array,
-                       window: int = 0) -> jax.Array:
+                       window: int = 0, aligned: bool = False,
+                       stats: bool = False):
     """The decode-side programs' cache read (``decode``, ``mixed`` and
     ``verify``): single-query attention for one layer over the rows AS
     STORED. ``q [B, H, dh]`` f32, ``tables [B, MB]`` int32, ``lengths
     [B]`` attendable positions; returns ``[B, H, dh]`` f32 (a latent
     pool: ``gathered_decode_attn`` says). One contract, met by the walk
     over each row's live blocks where the pool takes it (``walks``) and
-    by the plain form, ``gathered_decode_attn``, where it does not.
+    by the plain form, ``gathered_decode_attn``, where it does not
+    (``window`` / ``aligned``: a ring under its rule, as there).
+    ``stats``: ``(y, m [B, H], l [B, H])`` from either form, for
+    ``join_reads``.
 
     The walk (``ops/kv_walk.py::walk_attn``) runs the same two products
     over the rows as stored, block by block where they lie, under an
@@ -748,7 +780,8 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     reaches it; one beyond ``lengths`` inside the last live block still
     does (``corrupt_block``)."""
     if not walks(pool, window):
-        return gathered_decode_attn(pool, layer, q, tables, lengths, window)
+        return gathered_decode_attn(pool, layer, q, tables, lengths, window,
+                                    aligned, stats)
     from ..ops.kv_walk import walk_attn
     b, h, dh = q.shape
     hkv = pool.kv_heads
@@ -759,9 +792,12 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                          q.reshape(b, hkv, g, 1, dh), 0)
         full = walk_attn(pool.k, pool.v, layer,
                          rows.reshape(b, h, hkv * dh).astype(pool.k.dtype),
-                         tables, lengths, dh ** -0.5)
+                         tables, lengths, dh ** -0.5, stats)
+        if stats:
+            full, m, l = full
         y = jnp.einsum("bkgkd->bkgd", full.reshape(b, hkv, g, hkv, dh))
-    return y.reshape(b, h, dh)
+    y = y.reshape(b, h, dh)
+    return (y, m, l) if stats else y
 
 
 def _latent_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
@@ -841,8 +877,9 @@ def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
 
 
 def gathered_chunk_attn(pool: PagedKV, layer: int, q: jax.Array,
-                        table: jax.Array, pos0,
-                        window: int = 0) -> jax.Array:
+                        table: jax.Array, pos0, window: int = 0,
+                        aligned: bool = False, rows=None,
+                        stats: bool = False):
     """A prefill chunk's read: ``q [C, H, dh]`` at positions ``pos0 ..
     pos0+C-1`` of ONE sequence attends causally over its gathered view
     (``gather_layer`` + ``models.attention.chunk_attn``, the oracle's
@@ -850,18 +887,69 @@ def gathered_chunk_attn(pool: PagedKV, layer: int, q: jax.Array,
     ``[C, H, dh]``. ``window`` > 0: ``table`` is a window layer's short
     table, a ring the chunk's rows were just written into, and each row
     sees the last ``window`` positions up to its own (the rows of one
-    chunk have different window starts)."""
-    from ..models.attention import chunk_attn, window_mask
+    chunk have different window starts), or, where ``aligned``, the
+    positions of its own multiple-of-``window`` window up to its own.
+    ``rows`` (a scalar): the view is no sequence of positions but the
+    first ``rows`` stored rows, every query seeing them all (a chunked
+    layer's summaries). ``stats``: ``(y, m [C, H], l [C, H])``
+    (``join_reads``)."""
+    from ..models.attention import aligned_mask, chunk_attn, window_mask
     if pool.latent_rank:
         return _latent_chunk_attn(pool, layer, q, table, pos0)
     ck, cv = gather_layer(pool, layer, table)
     mask = None
     if window:
         c = q.shape[0]
-        mask = window_mask(
+        mask = (aligned_mask if aligned else window_mask)(
             (pos0 + jnp.arange(c))[:, None],
             ring_positions(pos0 + c - 1, table.shape[0],
                            pool.block_size)[None, :], window)
+    elif rows is not None:
+        mask = jnp.broadcast_to(jnp.arange(ck.shape[1]) < rows,
+                                (q.shape[0], ck.shape[1]))
     with jax.named_scope("attn"):
-        y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0, mask)
+        y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0, mask, stats)
+    if stats:
+        y, m, l = y
+        return y.transpose(1, 0, 2), m.T, l.T
     return y.transpose(1, 0, 2)
+
+
+def join_reads(*reads):
+    """Two (or more) reads of the same queries over disjoint sets of
+    keys as ONE softmax over their union: each read is ``(y [..., H,
+    dh], m [..., H], l [..., H])``, its own normalised result beside its
+    score maximum and its sum of ``exp(s - m)`` (``stats=True`` of the
+    reads above). With ``M = max_i m_i`` and ``w_i = l_i exp(m_i - M)``
+    the joint result is ``sum_i w_i y_i / sum_i w_i``. A read over NO
+    key (its mask hid every row: ``m`` is the mask's value, ``-1e30``)
+    gets the weight ``exp(-1e30 - M) = 0`` exactly, and whatever it
+    returned in ``y``'s place, a NaN among it, is dropped."""
+    with jax.named_scope("attn.join"):
+        top = functools.reduce(jnp.maximum, [m for _, m, _ in reads])
+        ws = [l * jnp.exp(m - top) for _, m, l in reads]
+        total = functools.reduce(jnp.add, ws)
+        out = sum(jnp.where(w[..., None] > 0, w[..., None] * y, 0.0)
+                  for (y, _, _), w in zip(reads, ws))
+        return out / total[..., None]
+
+
+def write_summaries(pool: PagedKV, wpool: PagedKV, layer: int, summarise,
+                    ring_phys, tables, last, kv_dtype: str) -> PagedKV:
+    """A chunked layer's summary rows (the module docstring), for ``N``
+    writes that end at positions ``last [N]``: the ring block each ends
+    in, ``ring_phys [N]``, is read AS STORED and summarised
+    (``summarise(k_blk, v_blk) -> (ktilde, vtilde) [N, H_kv, dh]``, the
+    model's), and row ``j = last // block`` of the sequence
+    (``tables [N, MB]``: entry ``j // block``, offset ``j % block``)
+    takes the pair where ``last`` is the LAST position of its chunk;
+    every other write's lands in the scratch block."""
+    blk = wpool.block_size
+    with jax.named_scope("attn.summary"):
+        kt, vt = summarise(wpool.k[layer, ring_phys],
+                           wpool.v[layer, ring_phys])
+        j = last // blk
+        done = last % blk == blk - 1
+        phys = jnp.where(done, tables[jnp.arange(j.shape[0]), j // blk],
+                         SCRATCH_BLOCK)
+        return write_rows(pool, layer, phys, j % blk, kt, vt, kv_dtype)

@@ -4,14 +4,17 @@
 program is made of lives here, put together from two sides. The model
 (``models/face.py::ServedModel``): embedding, norm, an attention
 layer's q/k/v and output projection (a latent-cache layer's query and
-row, and its output), a recurrent layer's step and chunk, FFN, final
+row, and its output; a chunked layer's q/k/v, the summary of a finished
+chunk, and its output), a recurrent layer's step and chunk, FFN, final
 norm and head — asked of the params, never of their class. The cache
 (``decode/paged.py``): the pool and the recurrent state, the writes and
 the ONE read each kind of row calls (``stored_decode_attn`` for decode
 and verify rows — which walks a full-kind float pool's live blocks
 where they lie and gathers the others' tables, as ``paged.walks`` says
 from the pool alone, never a flag here — and ``gathered_chunk_attn``
-for a prefill chunk). Between them, written
+for a prefill chunk; a chunked layer calls each over BOTH its stores
+with ``stats=True`` and joins the two: ``paged.join_reads``). Between
+them, written
 once: the walk over the model's layers (``_trunk``, so prefill and
 decode numerics cannot drift; in the ``mixed`` body a decode batch and
 ONE slot's full chunk are rows of one walk, the weights read once, and
@@ -61,6 +64,7 @@ position, never the shard) draws the same everywhere.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -68,15 +72,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.face import ATTN, LATENT, WINDOW, CacheSpec, take
+from ..models.face import ATTN, CHUNKED, LATENT, WINDOW, CacheSpec, take
 from ..parallel.collectives import all_gather, all_reduce
 from ..parallel.lm import tp_decode_specs, vp_embed
 from ..parallel.mesh import MODEL_AXIS
 from ..runtime.guardrails import rows_finite
 from .paged import (PagedKV, RecurrentState, SCRATCH_BLOCK, copy_block,
                     copy_block_rows, gathered_chunk_attn, implant_block,
-                    init_pool, init_state, stored_decode_attn, write_chunk,
-                    write_rows)
+                    init_pool, init_state, join_reads, stored_decode_attn,
+                    write_chunk, write_rows, write_summaries)
 from .sampling import make_pick
 
 # poison operand values (chaos nan_logits injection rides a runtime
@@ -140,12 +144,14 @@ def _with_counts(picks, counts):
     return jnp.concatenate([picks.reshape(-1), counts.reshape(-1)])
 
 
-def window_entries(cfg, window: int) -> int:
+def window_entries(cfg, window: int, chunked: bool = False) -> int:
     """Entries of a slot's window table under ``cfg``: the window's
     blocks, the blocks a prefill chunk writes before it reads, one of
-    slack; never more than a sequence can hold at all."""
+    slack; never more than a sequence can hold at all (``chunked``: a
+    row of its full-kind table stands for a whole block of positions,
+    ``models/face.py::CHUNKED``)."""
     blk = cfg.block_size
-    return min(cfg.max_blocks_per_seq,
+    return min(cfg.max_blocks_per_seq * (blk if chunked else 1),
                -(-window // blk) + max(1, cfg.prefill_chunk // blk) + 1)
 
 
@@ -164,8 +170,9 @@ class StepPrograms:
         self._wires: dict = {}
         # entries of a slot's window table (0 for a model with no window
         # layer)
-        self.window_blocks = (window_entries(cfg, spec.window)
-                              if spec.win_layers else 0)
+        self.window_blocks = (
+            window_entries(cfg, spec.window, bool(spec.chunk))
+            if spec.win_layers else 0)
 
     # -- the wire format ---------------------------------------------------
 
@@ -183,8 +190,11 @@ class StepPrograms:
         (``row``). Mixed: decode's for the ``bucket``-row batch plus ONE
         slot's table, start position, full chunk
         (``cfg.prefill_chunk`` tokens), uid and slot. A model with
-        window layers has, beside every ``tables`` / ``table``, the
-        rows' short window tables (``wtables`` / ``wtable``)."""
+        window layers (or chunked ones, whose exact keys lie in the same
+        ring) has, beside every ``tables`` / ``table``, the rows' short
+        window tables (``wtables`` / ``wtable``); how many summaries a
+        chunked layer's row attends over is no field: it follows from
+        its length."""
         w = self._wires.get((kind, bucket))
         if w is None:
             t, wt = self.cfg.max_blocks_per_seq, self.window_blocks
@@ -301,14 +311,19 @@ class StepPrograms:
                        take if self.mesh is None else vp_embed)
 
     def _trunk(self, p, cache, x, positions, write_attn, mix=None,
-               write_window=None):
+               write_window=None, write_chunked=None):
         """The walk over ``p.layers`` every program runs. Attention:
         norm, q/k/v, the caller's ``write_attn(i, pool, q, k, v) ->
         (pool, y [N, h_loc, dh])`` (where the programs differ: batched
         single-token writes and per-slot reads, or one slot's chunk),
         output projection. Window: the same over the window layers' own
         pool, ``write_window(i, wpool, q, k, v)``, between the model's
-        ``window_qkv`` and ``window_out``. Latent: the same seam — the
+        ``window_qkv`` and ``window_out``. Chunked: a layer with an index
+        in BOTH pools, ``write_chunked(i, pool, wpool, q, k, v) ->
+        (pool, wpool, y)`` between ``chunked_qkv`` and ``chunked_out``:
+        the exact keys of the row's aligned window in the ring, a
+        summary row a finished chunk in the full kind's pool, the two
+        reads joined. Latent: the same seam — the
         query for the
         stored row as ``q``, the row as the one "key" of one head and
         no value (the pool's ``v`` is zero lanes wide); the read's ``[N,
@@ -333,6 +348,10 @@ class StepPrograms:
                 q, k, v = p.window_qkv(i, a, positions)
                 wpool, y = write_window(i, wpool, q, k, v)
                 y = p.window_out(i, y.reshape(n, -1), a)
+            elif kind == CHUNKED:
+                q, k, v = p.chunked_qkv(i, a, positions)
+                pool, wpool, y = write_chunked(i, pool, wpool, q, k, v)
+                y = p.chunked_out(i, y.reshape(n, -1), a)
             elif kind == LATENT:
                 with jax.named_scope("mla"):
                     q, row = p.latent_qrow(i, a, positions)
@@ -362,11 +381,15 @@ class StepPrograms:
         return logits
 
     def _batch_seams(self, b: int, p, tables, lengths, rows, wtables=None):
-        """``(write_attn, mix, write_window)`` of ``b`` decode rows:
-        each row's token written at its own position and attended over
-        its blocks as stored (a window layer: into the row's ring,
-        ``wtables [b, entries]``, and over its last ``window``
-        positions); a recurrent layer advances each row's own state
+        """``(write_attn, mix, write_window, write_chunked)`` of ``b``
+        decode rows: each row's token written at its own position and
+        attended over its blocks as stored (a window layer: into the
+        row's ring, ``wtables [b, entries]``, and over its last
+        ``window`` positions; a chunked layer: into the ring, its
+        chunk's summary into the full kind's pool where the position
+        ends the chunk, and over the ring's aligned window joined with
+        the summaries of every earlier one, whose count follows from
+        ``lengths``); a recurrent layer advances each row's own state
         where it lies (``rows [b]``: the slot's state row, the scratch
         row for a padded one)."""
         cfg = self.cfg
@@ -391,14 +414,35 @@ class StepPrograms:
             return wpool, stored_decode_attn(wpool, l, q, wtables,
                                              lengths + 1, self.spec.window)
 
-        return write_attn, mix, write_window
+        def write_chunked(l, pool, wpool, q, k, v):
+            window = self.spec.window
+            phys = wtables[jnp.arange(b), slot_phys % wtables.shape[1]]
+            wpool = write_rows(wpool, l, phys, off, k, v, cfg.kv_dtype)
+            pool = write_summaries(
+                pool, wpool, l, functools.partial(p.chunk_summary, l),
+                phys, tables, lengths, cfg.kv_dtype)
+            with jax.named_scope("attn.ring"):
+                ring = stored_decode_attn(wpool, l, q, wtables, lengths + 1,
+                                          window, aligned=True, stats=True)
+            with jax.named_scope("attn.summary"):
+                summaries = stored_decode_attn(
+                    pool, l, q, tables,
+                    window // cfg.block_size * (lengths // window),
+                    stats=True)
+            return pool, wpool, join_reads(ring, summaries)
+
+        return write_attn, mix, write_window, write_chunked
 
     def _chunk_seams(self, p, table, pos0, row, wtable=None):
-        """``(write_attn, mix, write_window)`` of ONE slot's chunk of
-        prompt tokens: they enter the cache through its block table and
-        attend causally over the gathered view (a window layer: through
-        the slot's ring ``wtable``, each row over the ``window``
-        positions up to its own); a recurrent layer scans the
+        """``(write_attn, mix, write_window, write_chunked)`` of ONE
+        slot's chunk of prompt tokens: they enter the cache through its
+        block table and attend causally over the gathered view (a
+        window layer: through the slot's ring ``wtable``, each row over
+        the ``window`` positions up to its own; a chunked layer: the
+        ring under the aligned rule joined with the summaries of every
+        earlier window, and the chunk's own summary written where it
+        ends on a chunk's last position: a chunk lies in ONE block,
+        which the engine holds to); a recurrent layer scans the
         chunk through the slot's state (``row``: the convolution's tail
         and, where the model has one, the scan state), which is zero at
         position 0 whatever the row still holds (every prefill, and
@@ -422,7 +466,25 @@ class StepPrograms:
             return wpool, gathered_chunk_attn(wpool, l, q, wtable, pos0,
                                               self.spec.window)
 
-        return write_attn, mix, write_window
+        def write_chunked(l, pool, wpool, q, k, v):
+            window, blk = self.spec.window, cfg.block_size
+            wpool = write_chunk(wpool, l, wtable, pos0, k, v, cfg.kv_dtype,
+                                ring=True)
+            last = pos0 + q.shape[0] - 1
+            pool = write_summaries(
+                pool, wpool, l, functools.partial(p.chunk_summary, l),
+                wtable[last // blk % wtable.shape[0]][None], table[None],
+                last[None], cfg.kv_dtype)
+            with jax.named_scope("attn.ring"):
+                ring = gathered_chunk_attn(wpool, l, q, wtable, pos0, window,
+                                           aligned=True, stats=True)
+            with jax.named_scope("attn.summary"):
+                summaries = gathered_chunk_attn(
+                    pool, l, q, table, pos0,
+                    rows=window // blk * (pos0 // window), stats=True)
+            return pool, wpool, join_reads(ring, summaries)
+
+        return write_attn, mix, write_window, write_chunked
 
     def _slot_state(self, state, i, row, pos0):
         """``(tail [K-1, D], s [N, D])`` of recurrent layer ``i`` for
@@ -477,16 +539,18 @@ class StepPrograms:
             [lengths, pos0 + jnp.arange(self.cfg.prefill_chunk)])
         x = self._embed(p, jnp.concatenate([f["tokens"], f["chunk"]]),
                         positions)
-        batch_attn, _, batch_window = self._batch_seams(
+        batch_attn, _, batch_window, batch_chunked = self._batch_seams(
             b, p, f["tables"], lengths, rows, f.get("wtables"))
-        chunk_attn, _, chunk_window = self._chunk_seams(
+        chunk_attn, _, chunk_window, chunk_chunked = self._chunk_seams(
             p, f["table"], pos0, row, f.get("wtable"))
 
         def both(batch, chunk):
-            def write(l, pool, q, k, v):
-                pool, yb = batch(l, pool, q[:b], k[:b], v[:b])
-                pool, yc = chunk(l, pool, q[b:], k[b:], v[b:])
-                return pool, jnp.concatenate([yb, yc])
+            # ``kept``: the pool the seam writes (a chunked layer's: two)
+            def write(l, *kept_qkv):
+                *kept, q, k, v = kept_qkv
+                *kept, yb = batch(l, *kept, q[:b], k[:b], v[:b])
+                *kept, yc = chunk(l, *kept, q[b:], k[b:], v[b:])
+                return *kept, jnp.concatenate([yb, yc])
             return write
 
         write_attn = both(batch_attn, chunk_attn)
@@ -503,7 +567,8 @@ class StepPrograms:
             return state, y
 
         return self._trunk(p, cache, x, positions, write_attn, mix,
-                           both(batch_window, chunk_window))
+                           both(batch_window, chunk_window),
+                           both(batch_chunked, chunk_chunked))
 
     def _head_pick(self, p, x, uids, poison, pos, ahead: int):
         """head -> poison -> pick -> finite flags, for all four

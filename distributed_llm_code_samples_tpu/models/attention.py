@@ -43,6 +43,27 @@ def window_mask(q_pos, k_pos, window: int):
     return (k_pos >= 0) & (k_pos <= q_pos) & (q_pos - k_pos < window)
 
 
+def aligned_mask(q_pos, k_pos, window: int):
+    """The ALIGNED-window rule over broadcastable GLOBAL positions
+    (``models/face.py::CHUNKED``): windows are the multiples of
+    ``window`` and do not slide; a query at ``p`` sees the key at ``t``
+    where ``t <= p`` lies in ``p``'s own window, ``t // window == p //
+    window``. A negative ``t`` is an entry that holds no position yet."""
+    return ((k_pos >= 0) & (k_pos <= q_pos)
+            & (k_pos // window == q_pos // window))
+
+
+def softmax_stats(s: jax.Array):
+    """Softmax over the last axis with its statistics: ``(p, m, l)``,
+    the probabilities beside each row's maximum and its sum of ``exp(s
+    - m)`` — what a join with another read of the same queries needs
+    (``decode/paged.py::join_reads``)."""
+    m = jnp.max(s, axis=-1)
+    e = jnp.exp(s - m[..., None])
+    l = jnp.sum(e, axis=-1)
+    return e / l[..., None], m, l
+
+
 def attn_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
              causal: bool = True):
     """Softmax attention forward; returns ``(y, (p,))`` with the probability
@@ -272,7 +293,7 @@ def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array, layer: int,
 
 
 def chunk_attn(q: jax.Array, ck: jax.Array, cv: jax.Array,
-               q_offset, mask=None) -> jax.Array:
+               q_offset, mask=None, stats: bool = False):
     """Prefill-chunk attention of ``Tq`` queries against a (gathered)
     cache that already holds the chunk's own keys: ``q [H, Tq, dh]``,
     ``ck/cv [H_kv, T_cap, dh]`` with ``H % H_kv == 0`` (GQA groups).
@@ -282,7 +303,9 @@ def chunk_attn(q: jax.Array, ck: jax.Array, cv: jax.Array,
     not-yet-written pool position. ``q_offset`` may be a traced scalar
     (the chunked-prefill loop passes the running write head). ``mask
     [Tq, T_cap]`` takes the causal rule's place where the view is not
-    in position order (a window layer's ring: ``window_mask``)."""
+    in position order (a window layer's ring: ``window_mask``).
+    ``stats``: ``(y, m [H, Tq], l [H, Tq])``, the result beside each
+    row's score maximum and its sum of ``exp(s - m)``."""
     h, tq, dh = q.shape
     hkv, tcap, _ = ck.shape
     if h % hkv:
@@ -294,6 +317,10 @@ def chunk_attn(q: jax.Array, ck: jax.Array, cv: jax.Array,
     if mask is None:
         mask = causal_mask(tq, tcap, q_offset=q_offset)
     s = jnp.where(mask, s, jnp.asarray(-1e30, s.dtype))
+    if stats:
+        p, m, l = softmax_stats(s)
+        y = jnp.einsum("kgqt,ktd->kgqd", p, cv)
+        return (y.reshape(h, tq, dh), m.reshape(h, tq), l.reshape(h, tq))
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("kgqt,ktd->kgqd", p, cv).reshape(h, tq, dh)
 

@@ -6,23 +6,30 @@ builds every compiled step program from those answers and from the
 cache (``decode/paged.py``); neither it nor the scheduler asks which
 class the params are, reads a weight by name or calls a family's
 arithmetic. ``models/lm.py``, ``models/hybrid_lm.py``,
-``models/mla_moe_lm.py``, ``models/lfm2_moe_lm.py`` and
-``models/laguna_lm.py`` are the families that exist;
+``models/mla_moe_lm.py``, ``models/lfm2_moe_lm.py``,
+``models/laguna_lm.py`` and ``models/evabyte_lm.py`` are the families
+that exist;
 ``tests/test_model_face.py`` serves one more that lives in the test
 alone. The builder keeps the cache write and read of an attention layer
 (between ``attn_qkv`` and ``attn_out``; for a latent-cache layer
 between ``latent_qrow`` and ``latent_out``; for a sliding-window layer
-between ``window_qkv`` and ``window_out``), the row of the recurrent
+between ``window_qkv`` and ``window_out``; for a chunk-summarised layer
+between ``chunked_qkv`` and ``chunked_out``, with ``chunk_summary`` for
+the row it keeps of every finished chunk), the row of the recurrent
 state a sequence owns, the residual adds, the expert layers' counters
 and, under a mesh, the collectives.
 
-Three layer kinds keep a PAGED cache, each with its own weight stack
+Four layer kinds keep a PAGED cache, each with its own weight stack
 and its own cache index: ``ATTN`` (K/V blocks over the whole sequence),
-``LATENT`` (one latent row a token in the same pool's place) and
+``LATENT`` (one latent row a token in the same pool's place),
 ``WINDOW`` (K/V blocks of the last ``CacheSpec.window`` positions only,
 in a pool and a block table of their own beside the full kind's:
-``decode/paged.py``). Every other kind is recurrent: a state row by
-slot.
+``decode/paged.py``) and ``CHUNKED``, whose ONE layer owns TWO stores
+under the same index: the window kind's ring for the exact K/V of the
+current ALIGNED window, and a row of the full kind's pool for every
+finished chunk of ``CacheSpec.chunk`` positions (its summary), the two
+reads joined under one softmax. Every other kind is recurrent: a state
+row by slot.
 
 What more than one family is written from lives below the face: ``mm``,
 ``rmsnorm``, the gated SiLU MLP, the attention stack and its q/k/v
@@ -49,6 +56,11 @@ LATENT = "latent"
 # paged KV blocks in a pool of their own, a sequence's blocks reused as
 # a ring once every position in them is behind the window
 WINDOW = "window"
+# ... and the kind that sees the keys of its own ALIGNED window of
+# ``window`` positions exactly (the window kind's ring) and every
+# earlier window as one summary row a finished chunk of ``chunk``
+# positions (rows of the full kind's pool), under ONE softmax
+CHUNKED = "chunked"
 
 
 class CacheSpec(NamedTuple):
@@ -67,7 +79,11 @@ class CacheSpec(NamedTuple):
     returns after its picks (0 for a model with no expert layer).
     ``win_layers`` ``WINDOW`` layers own paged KV of the same
     ``kv_heads`` x ``head_dim`` row over the last ``window`` positions
-    of a sequence (0 for a model with none)."""
+    of a sequence (0 for a model with none). ``chunk`` > 0 says the
+    layers are ``CHUNKED`` ones: each has an index in BOTH stores
+    (``kv_layers == win_layers``), a row of the full kind's pool stands
+    for one finished chunk of ``chunk`` positions, and the window is
+    aligned to multiples of ``window`` (it does not slide)."""
     kv_layers: int
     kv_heads: int
     head_dim: int
@@ -80,6 +96,7 @@ class CacheSpec(NamedTuple):
     n_experts: int = 0
     win_layers: int = 0
     window: int = 0
+    chunk: int = 0
 
 
 class ServedModel(Protocol):
@@ -89,6 +106,7 @@ class ServedModel(Protocol):
     layers is never asked for the three ``recurrent_`` methods, one
     without ``LATENT`` layers never for the two ``latent_`` ones, one
     without ``WINDOW`` layers never for the two ``window_`` ones, one
+    without ``CHUNKED`` layers never for the three ``chunk`` ones, one
     whose ``cache_spec`` names no expert layer never for
     ``ffn_counted``."""
     vocab: int
@@ -122,6 +140,20 @@ class ServedModel(Protocol):
     def window_qkv(self, i, a, positions): ...
 
     def window_out(self, i, y, a): ...
+
+    # a CHUNKED layer ``i``: as ``attn_qkv`` / ``attn_out``; the builder
+    # writes k, v to the ring, reads the row's aligned window there and
+    # the summaries of every earlier window from the full kind's pool,
+    # and joins the two reads under one softmax
+    def chunked_qkv(self, i, a, positions): ...
+
+    # one finished chunk AS STORED, k_blk / v_blk [n, chunk, H_kv*dh]
+    # -> (ktilde, vtilde) [n, H_kv, dh] float32: the ONE key and value
+    # a head keeps of the chunk (the builder writes them where the
+    # chunk's last position is written)
+    def chunk_summary(self, i, k_blk, v_blk): ...
+
+    def chunked_out(self, i, y, a): ...
 
     # a LATENT layer: -> (q [N, H, m], row [N, m]), ``m`` the stored
     # row's lanes. ``row`` is what the cache keeps of each token, ``q``

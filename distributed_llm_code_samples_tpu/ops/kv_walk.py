@@ -53,12 +53,15 @@ def blocks_a_step(block: int, row_bytes: int, max_blocks: int) -> int:
 
 
 def _walk_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
-                 o_ref, kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref,
-                 *, steps: int, scale: float):
+                 o_ref, *rest, steps: int, scale: float):
     """One batch row a grid step. ``kbuf/vbuf [2, steps * block, J]``
     are the two buffers of each side; ``slot_ref`` says which of them
     the row's FIRST copy step is in (the row before started it, before
-    its own last product), ``sems [side, buffer]`` count the copies."""
+    its own last product), ``sems [side, buffer]`` count the copies.
+    Where the call asks for the softmax statistics, two more outputs
+    stand before the scratch: each head's score maximum and its sum of
+    ``exp(s - m)``, a whole tile of lanes wide."""
+    *stats, kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref = rest
     r = pl.program_id(0)
     blk = kbuf.shape[1] // steps
     layer = layer_ref[0]
@@ -142,12 +145,14 @@ def _walk_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
     lax.fori_loop(0, n_steps, step, None)
     slot_ref[0] = lax.rem(first + n_steps, 2)
     o_ref[...] = acc_ref[...] / l_ref[...]
+    for out, ref in zip(stats, (m_ref, l_ref)):
+        out[...] = jnp.broadcast_to(ref[...], out.shape)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("steps", "scale", "interpret"))
+                   static_argnames=("steps", "scale", "interpret", "stats"))
 def _walk(layer, tables, lengths, q, k_pool, v_pool, *, steps: int,
-          scale: float, interpret: bool):
+          scale: float, interpret: bool, stats: bool = False):
     """The kernel's call, jitted on its own: ``layer [1]`` is an
     operand, so the calls of every layer of a step program are ONE
     traced function — lowered (the kernel to Mosaic's module) once a
@@ -157,11 +162,16 @@ def _walk(layer, tables, lengths, q, k_pool, v_pool, *, steps: int,
     blk = k_pool.shape[2]
     row = pl.BlockSpec((None, h, j), lambda r, *_: (r, 0, 0))
     whole = pl.BlockSpec(memory_space=pl.ANY)
+    out_specs, out_shape = row, jax.ShapeDtypeStruct((b, h, j), jnp.float32)
+    if stats:
+        stat = pl.BlockSpec((None, h, ssm._LANES), lambda r, *_: (r, 0, 0))
+        wide = jax.ShapeDtypeStruct((b, h, ssm._LANES), jnp.float32)
+        out_specs, out_shape = [row, stat, stat], [out_shape, wide, wide]
     return pl.pallas_call(
         functools.partial(_walk_kernel, steps=steps, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(b,),
-            in_specs=[row, whole, whole], out_specs=row,
+            in_specs=[row, whole, whole], out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((2, steps * blk, j), k_pool.dtype),
                 pltpu.VMEM((2, steps * blk, j), v_pool.dtype),
@@ -170,14 +180,14 @@ def _walk(layer, tables, lengths, q, k_pool, v_pool, *, steps: int,
                 pltpu.VMEM((h, 1), jnp.float32),
                 pltpu.VMEM((h, 1), jnp.float32),
                 pltpu.VMEM((h, j), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, h, j), jnp.float32),
+        out_shape=out_shape,
         interpret=interpret,
     )(layer, tables, lengths, q, k_pool, v_pool)
 
 
 def walk_attn(k_pool: jax.Array, v_pool: jax.Array, layer: int,
               q: jax.Array, tables: jax.Array, lengths: jax.Array,
-              scale: float) -> jax.Array:
+              scale: float, stats: bool = False):
     """Single-query attention of ``b`` rows over their own blocks of
     one layer of the pool, where they lie. ``k_pool/v_pool [L, n_blocks,
     block, J]`` stay in HBM whole; ``q [b, H, J]`` in the pool's dtype
@@ -191,13 +201,24 @@ def walk_attn(k_pool: jax.Array, v_pool: jax.Array, layer: int,
     kernel to compile. Operands in the pool's dtype, sums in float32. A
     row's dead blocks (table entries at and beyond ``ceil(length /
     block)``) are never fetched: bytes there, a NaN among them, do not
-    reach the result."""
+    reach the result.
+
+    ``stats``: ``(o, m [b, H], l [b, H])``, the result beside each
+    head's score maximum and its sum of ``exp(s - m)``, which the
+    kernel holds in its scratch anyway: what a join with another read
+    of the same queries needs (``decode/paged.py::join_reads``). A row
+    of length 0 reads its first block masked whole: ``m`` is the mask's
+    value and the join gives the read no weight."""
     interpret = ssm._interpreted()
     j = q.shape[-1]
     if not interpret and j % ssm._LANES:
         raise ValueError(ssm._UNTILED.format(d=j))
     steps = blocks_a_step(k_pool.shape[2], j * k_pool.dtype.itemsize,
                           tables.shape[1])
-    return _walk(jnp.asarray([layer], jnp.int32), tables, lengths, q,
-                 k_pool, v_pool, steps=steps, scale=scale,
-                 interpret=interpret)
+    got = _walk(jnp.asarray([layer], jnp.int32), tables, lengths, q,
+                k_pool, v_pool, steps=steps, scale=scale,
+                interpret=interpret, stats=stats)
+    if not stats:
+        return got
+    o, m, l = got
+    return o, m[..., 0], l[..., 0]

@@ -608,8 +608,9 @@ class _Stream:
         / ``full_rows``, the cached positions a step's launched rows
         attend over in a window layer and in a full one, and the window
         blocks' turnover (``window_blocks_released`` in all,
-        ``window_blocks_live`` at most). None where the records hold
-        neither."""
+        ``window_blocks_live`` at most) and, of a chunked layer (v23),
+        the chunk summaries a step's rows attend over and those
+        written. None where the records hold neither."""
         kv = [r for r in self.step_spans if r.get("kv_blocks_capacity")]
         blocks = None if not kv else {
             "steps": len(kv),
@@ -631,7 +632,12 @@ class _Stream:
                 r["window_blocks_released"] for r in self.step_spans
                 if "window_blocks_released" in r),
             "window_blocks_live_max": max(
-                r["window_blocks_live"] for r in recs)}
+                r["window_blocks_live"] for r in recs),
+            # v23: a chunked layer's summaries (0 with none)
+            "summary_rows_mean": round(float(np.mean(
+                [r.get("summary_rows", 0) for r in recs])), 2),
+            "summaries_written": sum(
+                r.get("summaries_written", 0) for r in recs)}
 
     def waterfalls(self) -> dict:
         """Per-uid span waterfall: phase breakdown + the span-sum vs
@@ -1899,6 +1905,11 @@ def _render_engine_sections(out: list, doc: dict) -> None:
                 f"full one ({cr['steps']} step(s)); window blocks: "
                 f"{cr['window_blocks_live_max']} held at most, "
                 f"{cr['window_blocks_released']} released")
+            if cr["summary_rows_mean"] or cr["summaries_written"]:
+                out.append(
+                    f"  cache reads: {cr['summary_rows_mean']} chunk "
+                    "summaries a step beside the window's positions, "
+                    f"{cr['summaries_written']} written")
     rec = doc.get("recovery", {})
     if (rec.get("attempts_failed") or rec.get("nonfinite_skips")
             or rec.get("attempt_log")

@@ -271,7 +271,18 @@ import numpy as np
 # negative, no more read than the capacity (``validate_record``); the
 # ``decode`` record carries the two as cumulative extras. Readers:
 # ``report.py``'s cache-reads line.
-SCHEMA_VERSION = 22
+# v23 (PR 41): a fourth paged kind, chunk-summarised attention, whose
+# layer keeps two stores — the ``engine_step`` record may carry its
+# counters (``STEP_SPAN_CHUNKS``; the engine writes both, 0 for a model
+# with no chunked layer): ``summary_rows``, the chunk summaries the rows
+# the step LAUNCHED attend over (one a chunk of every window before a
+# row's own; a chunk's view counted once), and ``summaries_written``,
+# the launched writes that finished a chunk. For such a model
+# ``window_rows`` counts the positions of a row's own ALIGNED window.
+# Both or none, whole and not negative (``validate_record``). Readers:
+# ``report.py``'s cache-reads line, ``benchmark/chunk_trace.py`` (the
+# two stores' rooflines, ``summary_rows_share``).
+SCHEMA_VERSION = 23
 
 METRICS_FILENAME = "metrics.jsonl"
 
@@ -434,6 +445,8 @@ STEP_SPAN_WINDOW = ("window_rows", "full_rows", "window_blocks_released",
                     "window_blocks_live")
 # ... and the two of the decode-side reads' blocks, likewise (v22)
 STEP_SPAN_KV = ("kv_blocks_read", "kv_blocks_capacity")
+# ... and a chunked layer's two, likewise (v23)
+STEP_SPAN_CHUNKS = ("summary_rows", "summaries_written")
 
 # The router-record contract (``decode/fleet.py``): one record per
 # fleet-router decision. ``step`` is the ROUTER's step clock (fleet
@@ -1119,6 +1132,15 @@ def validate_record(rec: Any) -> tuple[bool, str]:
                                f"{list(STEP_SPAN_KV)} or none, whole, not "
                                "negative, kv_blocks_read <= "
                                "kv_blocks_capacity")
+            got = [k for k in STEP_SPAN_CHUNKS if k in rec]
+            if got and (len(got) != len(STEP_SPAN_CHUNKS) or any(
+                    not isinstance(rec[k], int) or rec[k] < 0
+                    for k in got)):
+                return False, (f"span record (span {STEP_SPAN}) has the "
+                               f"chunk summaries' counters "
+                               f"{ {k: rec[k] for k in got} }: both of "
+                               f"{list(STEP_SPAN_CHUNKS)} or none, whole, "
+                               "not negative")
         elif rec["uid"] is None:
             return False, (f"span record (span {rec['span']}) has a "
                            f"null 'uid': only {STEP_SPAN} belongs to "

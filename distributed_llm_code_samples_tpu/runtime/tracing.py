@@ -230,6 +230,10 @@ class SpanTracer:
 # ``kv_blocks_read`` / ``kv_blocks_capacity`` (``engine._count_blocks``;
 # telemetry v22): the pool's blocks the decode-side reads of the
 # launched rows fetched, and what a gather of their whole tables reads.
+# And ``summary_rows`` / ``summaries_written`` (telemetry v23): for a
+# model whose layers summarise finished chunks, the summaries the
+# launched rows attend over and the launched writes that finished one;
+# ``window_rows`` then counts a row's own ALIGNED window.
 #
 # Which launch a ``*.readback`` read is in the step's ``readbacks``
 # (``engine._read``; telemetry v20): the launch's ORDINAL among the

@@ -807,6 +807,119 @@ def test_window_moe_step_program_keeps_both_pools_as_stored(
         assert sorted(one) == chunk, one
 
 
+# the chunk-summarised attention cell of the benchmark
+# (evabyte.bytereason-offline): EvaByte's widths, 32 heads of 128 lanes
+# (no grouping), window 2,048 in chunks of 16, all 320 byte ids and the
+# 8-head output, 24 slots x 9,216 positions, bf16 weights and K/V in TWO
+# stores a layer, cut to 2 layers (the period is one) so the compile
+# stays short
+EVABYTE = dict(layers=2, slots=24, mbps=36, chunk=16)
+
+
+@pytest.fixture(scope="module")
+def evabyte_engine_args():
+    """``(engine, {kind: (bucket, args)})`` at the cell's widths, over
+    shapes alone: the configuration's own file through the family's
+    ``spec_from_config`` and its driver's ``engine_config``, the step
+    programs as ``DecodeEngine`` builds them, both stores in the carry."""
+    import importlib.util
+    import json
+    from distributed_llm_code_samples_tpu.decode.programs import StepPrograms
+    from distributed_llm_code_samples_tpu.models import evabyte_lm
+    g = EVABYTE
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    configs = os.path.join(root, "benchmark", "configs")
+    with open(os.path.join(configs, "evabyte-6.5b-serve.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=g["layers"])
+    at = importlib.util.spec_from_file_location(
+        "evabyte_engine_driver",
+        os.path.join(configs, "evabyte_engine_driver.py"))
+    driver = importlib.util.module_from_spec(at)
+    at.loader.exec_module(driver)
+    cfg = driver.engine_config(config)
+    assert (cfg.max_slots, cfg.max_blocks_per_seq, cfg.prefill_chunk) == (
+        g["slots"], g["mbps"], g["chunk"])
+    spec = evabyte_lm.spec_from_config(config)
+    params = jax.eval_shape(
+        lambda k: evabyte_lm.init_evabyte_lm(k, spec, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    programs = StepPrograms(cfg, params.cache_spec(32), params.vocab)
+    eng = _ShapesEngine(programs, params,
+                        jax.eval_shape(lambda: programs.init_cache()[0]))
+    eng.wpool = jax.eval_shape(programs.init_window)
+    eng._cache = lambda: programs.whole(eng.pool, eng.wpool)
+    return eng, _cell_programs(eng, g["slots"], g["chunk"])
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
+def test_chunked_step_program_keeps_both_stores_as_stored(
+        one_chip, evabyte_engine_args, kernels_for_the_chip, kind):
+    """The fourth paged kind in a step program (``decode/paged.py``):
+    ONE layer owns an index in both pools. The summaries' pool ``[2,
+    865, 16, 4096]`` (the full kind's: a row a finished chunk) and the
+    ring's ``[2, 3121, 16, 4096]`` (the window kind's: 130 blocks a
+    slot) are both taken row-major and unpadded, aliased whole and
+    updated in place, never copied and no layer's slab sliced out. A
+    batch's rows gather their rings, 130 blocks a row (the plain read)
+    and WALK their summaries where they lie: one kernel call a layer,
+    which hands back its softmax statistics beside its sums (three
+    results), and no gather of a summary table; a prefill chunk's one
+    slot gathers both its ring and its 36 blocks of summaries. The
+    result is the picks over head 0's 320 rows."""
+    import re
+    eng, programs = evabyte_engine_args
+    bucket, args = programs[kind]
+    compiled = eng._program(kind, bucket).lower(
+        *_shapes_of(args, one_chip)).compile()
+    hlo = compiled.as_text()
+    pool, wpool = eng.pool, eng.wpool
+    assert eng.programs.window_blocks == 130
+    assert pool.k.shape == (2, 865, 16, 4096) == pool.v.shape
+    assert wpool.k.shape == (2, 3121, 16, 4096) == wpool.v.shape
+    from distributed_llm_code_samples_tpu.decode import paged
+    assert paged.walks(pool) and not paged.walks(wpool, 2048)
+    slab = pool.k.size // pool.k.shape[0]
+    moved = [r for r in _hlo_results(
+        hlo, ("copy", "slice", "dynamic-slice"), "bf16")
+        if r[1] >= slab and r[1] % slab == 0]
+    assert not moved, moved
+    (full_fmt, win_fmt), _ = compiled.input_formats[0][1]
+    for fmt, arr in ((full_fmt.k, pool.k), (full_fmt.v, pool.v),
+                     (win_fmt.k, wpool.k), (win_fmt.v, wpool.v)):
+        assert fmt.layout.major_to_minor == tuple(range(arr.ndim)), fmt
+    m = compiled.memory_analysis()
+    held = (2 * _nbytes(pool.k) + 2 * _nbytes(wpool.k)
+            + _nbytes(eng.token_store))
+    assert m.alias_size_in_bytes >= held
+    assert _carry_is_aliased_whole(compiled, eng) == held
+    logical = sum(_nbytes(x) for x in jax.tree_util.tree_leaves(args[:2]))
+    assert m.argument_size_in_bytes - logical < _nbytes(pool.k) // 100
+    # the cell's 8 layers: 6 more of weights and of both stores, and the
+    # program's temporaries once
+    per_layer = (202_391_552 * 2 + _nbytes(pool.k) + _nbytes(wpool.k))
+    assert _total_bytes(compiled) + 6 * per_layer < HBM_V5E
+    picks = {"decode": bucket, "prefill": 1, "mixed": bucket + 1}[kind]
+    out = jax.eval_shape(eng.programs.body(kind, bucket), *args)[1]
+    assert out.shape == (picks,) and out.dtype == jnp.int32
+    rows = "" if kind == "prefill" else r"%d," % bucket
+    got = [int(n) for n in re.findall(
+        r"= bf16\[%s(\d+),16,4096\]\S* gather\(" % rows, hlo)]
+    # K and V of both layers: a batch's rows at the ring; a chunk's one
+    # slot at the ring and at its table of summaries
+    chunk = [36] * 4 + [130] * 4
+    assert sorted(got) == (chunk if kind == "prefill" else [130] * 4), got
+    walk = [l.split(" custom-call(")[0] for l in hlo.splitlines()
+            if MOSAIC in l]
+    assert len(walk) == (0 if kind == "prefill" else pool.k.shape[0])
+    assert all("f32[%d,32,4096]" % bucket in l
+               and l.count("f32[%d,32,128]" % bucket) == 2 for l in walk)
+    if kind == "mixed":     # ... and the riding chunk's one slot,
+        # beside the blocks the batch's rows may finish (K and V a layer)
+        one = [int(n) for n in re.findall(
+            r"= bf16\[(\d+),16,4096\]\S* gather\(", hlo)]
+        assert sorted(one) == [bucket] * 4 + chunk, one
+
+
 def _toy_engine(family, ways, speculate, hybrid_config):
     """A GPT-2-shaped toy, or the toy whose ``config.json`` is handed in
     (the hybrid's, the latent-attention expert model's), as small as
@@ -1044,7 +1157,7 @@ def test_decode_program_reads_the_gathered_rows_as_stored(
 
 @pytest.mark.parametrize("cell", ["gpt2-large", "gpt2-large-f32",
                                   "jamba2-3b", "lfm2-24b-a2b",
-                                  "laguna-s-2.1"])
+                                  "laguna-s-2.1", "evabyte"])
 def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
                                               kernels_for_the_chip, cell):
     """The walk alone, for the described v5e, at each serving cell's
@@ -1060,7 +1173,10 @@ def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
         "gpt2-large-f32": (12, 20, 20, 64, 64, 36),
         "jamba2-3b": (64, 20, 1, 128, 128, 2),
         "lfm2-24b-a2b": (64, 32, 8, 64, 128, 2),
-        "laguna-s-2.1": (64, 48, 8, 128, 192, 3)}[cell]
+        "laguna-s-2.1": (64, 48, 8, 128, 192, 3),
+        # the chunk summaries' pool: 8 KB rows, with the statistics out
+        "evabyte": (24, 32, 32, 128, 36, 8)}[cell]
+    stats = cell == "evabyte"
     dt = jnp.float32 if cell.endswith("f32") else jnp.bfloat16
     j, blk = hkv * dh, 16
     steps = kv_walk.blocks_a_step(blk, j * dt.dtype.itemsize, mb)
@@ -1069,7 +1185,8 @@ def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
     shape = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     side = shape((layers, 1 + b * mb, blk, j), dt)
     compiled = jax.jit(functools.partial(
-        kv_walk.walk_attn, layer=layers - 1, scale=dh ** -0.5)).lower(
+        kv_walk.walk_attn, layer=layers - 1, scale=dh ** -0.5,
+        stats=stats)).lower(
             side, side, q=shape((b, h, j), dt),
             tables=shape((b, mb), jnp.int32),
             lengths=shape((b,), jnp.int32)).compile()
@@ -1091,6 +1208,7 @@ REHEARSED = {
                                        "routed_rows_max_over_mean"),
     "laguna-s-2.1.longreason-offline": ("shrink_laguna",
                                         "window_pool_util"),
+    "evabyte.bytereason-offline": ("shrink_evabyte", "summary_rows_share"),
 }
 
 
@@ -1101,8 +1219,9 @@ def test_cell_rehearsal_on_the_cpu(monkeypatch, name, trace):
     ``benchmark/tests/test_rehearsal.py`` rehearses the older cells
     (its ``shrink.py`` knows those only; these cells' shrinks are
     ``benchmark/tests/shrink_jamba.py``, ``shrink_glm.py``,
-    ``shrink_lfm2.py`` and ``shrink_laguna.py``). Nothing here is a
-    measurement."""
+    ``shrink_lfm2.py``, ``shrink_laguna.py`` and ``shrink_evabyte.py``,
+    the last under a clock that moves by the engine's steps). Nothing
+    here is a measurement."""
     import importlib
     import json
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -1112,6 +1231,9 @@ def test_cell_rehearsal_on_the_cpu(monkeypatch, name, trace):
     shrink = importlib.import_module("benchmark.tests." + shrink)
     real = flops.peaks
     monkeypatch.setattr(flops, "peaks", lambda kind: real("TPU v5 lite"))
+    if hasattr(shrink, "step_clock"):
+        # the window a count of steps, on any machine under any load
+        shrink.step_clock(monkeypatch)
     line = run.run_cell(name, 2**31 + 4242, 1.5, bool(trace),
                         check_device=False, shrink=shrink.serve)
     assert line["correct"] is True and line["failed"] == 0
@@ -1129,8 +1251,32 @@ def test_cell_rehearsal_on_the_cpu(monkeypatch, name, trace):
         # device trace: none on the CPU)
         assert line["metrics"][counter]["value"] > 0
         # ... and the traced steps that carried a chunk say how many of
-        # them rode with the batch (the mixed path is on: no switch)
-        assert 0 <= line["metrics"]["chunk_ride_share.offline"]["value"] <= 100
+        # them rode with the batch (the mixed path is on: no switch),
+        # where the cell lists that metric (the byte cell's traced steps
+        # on the chip carry no chunk: PERF.md section 4)
+        ride = "chunk_ride_share.offline"
+        if name in listed[ride]["workloads"]:
+            assert 0 <= line["metrics"][ride]["value"] <= 100
+
+
+def test_deep_window_script_rehearsal_on_the_cpu(monkeypatch, capsys):
+    """``benchmark/tests/deep_window_on_chip.py`` at the byte cell's toy
+    size: every slot's request runs past three window boundaries, the
+    longest past four, and ``serve.check`` holds them to the reference
+    (the cell's own window ends before any request that long does:
+    PERF.md section 7). Nothing here is a measurement."""
+    import json
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmark.tests import deep_window_on_chip, shrink_evabyte
+    rc = deep_window_on_chip.main(["--seed", str(2**31 + 77)],
+                                  shrink=shrink_evabyte.serve)
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0 and out["correct"]["ok"] and out["failed"] == 0
+    assert out["rows"] == 4 and len(out["lengths"]) == 4
+    assert min(out["windows_crossed"]) == 3
+    assert max(out["windows_crossed"]) == 4 and max(out["lengths"]) == 256
+    assert out["correct"]["requests"] == 3
 
 
 def test_train_single_step_compiles_at_paper_width(one_chip):

@@ -452,7 +452,7 @@ class InlineGPT2:
         return rows + p.wpe[positions]
 
     def _trunk(self, p, pool, x, positions, write_attn, mix=None,
-               write_window=None):
+               write_window=None, write_chunked=None):
         from distributed_llm_code_samples_tpu.ops.norm import layernorm
         from distributed_llm_code_samples_tpu.parallel.collectives import (
             all_reduce)
@@ -497,7 +497,7 @@ class InlineHybrid:
         return p.wte[tokens].astype(jnp.float32)
 
     def _trunk(self, p, cache, x, positions, write_attn, mix=None,
-               write_window=None):
+               write_window=None, write_chunked=None):
         pool, state = cache
         n = x.shape[0]
         dh = p.head_dim

@@ -263,8 +263,8 @@ SCHEDULER_AND_BUILDER = [os.path.join(PKG, "decode", name)
 ARITHMETIC = re.compile(r"(^|\.)(models\.(lm|hybrid_lm|mla_moe_lm|attention"
                         r"|transformer|moe\w*|ffn_stack)"
                         r"|ops\.(norm|ssm|ffn|activations|moe\w*))$")
-FACE_NAMES = {"ATTN", "LATENT", "WINDOW", "CacheSpec", "ServedModel",
-              "take"}
+FACE_NAMES = {"ATTN", "LATENT", "WINDOW", "CHUNKED", "CacheSpec",
+              "ServedModel", "take"}
 
 
 def _imports(path):

@@ -378,9 +378,11 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
                             "window_rows", "full_rows",
                             "window_blocks_released",
                             "window_blocks_live",
+                            "summary_rows", "summaries_written",
                             "kv_blocks_read", "kv_blocks_capacity",
                             "dispatches", "readbacks", "launches"}
         assert rec["window_rows"] == rec["full_rows"] == 0  # nor window
+        assert rec["summary_rows"] == rec["summaries_written"] == 0
         assert rec["launches"] == eng.launches
         assert rec["state_bytes"] == 0      # no recurrent layer here
         assert rec["expert_rows"] == rec["experts_touched"] == 0  # nor expert

@@ -126,7 +126,11 @@ from distributed_llm_code_samples_tpu.runtime.telemetry import (
 # ``engine_step`` record may carry the blocks the launched rows' reads
 # fetched beside their tables' capacity (``STEP_SPAN_KV``), both or
 # none.
-_PINNED_VERSION = 22
+# v23 (PR 41): a fourth paged kind, chunk-summarised attention — the
+# ``engine_step`` record may carry the summaries its launched rows
+# attend over and those they wrote (``STEP_SPAN_CHUNKS``), both or none.
+_PINNED_VERSION = 23
+_PINNED_STEP_SPAN_CHUNKS = frozenset({"summary_rows", "summaries_written"})
 _PINNED_STEP_SPAN_KV = frozenset({"kv_blocks_read", "kv_blocks_capacity"})
 _PINNED_STEP_SPAN_WINDOW = frozenset({
     "window_rows", "full_rows", "window_blocks_released",
@@ -371,7 +375,7 @@ def test_engine_step_v20_round_trips(tmp_path):
                                                   METRICS_FILENAME))
     assert problems == []
     first, closing, idle = records
-    assert first["schema"] == SCHEMA_VERSION == 22
+    assert first["schema"] == SCHEMA_VERSION == 23
     assert first["dispatches"] == [["prefill", 4], ["decode", 8]]
     assert [p[0] for p in first["phases"] if p[0].endswith(".dispatch")] \
         == [k + ".dispatch" for k, _ in first["dispatches"]]
@@ -438,6 +442,31 @@ def test_engine_step_v22_kv_block_counters(over, ok):
     assert got is ok, reason
     if not ok:
         assert "kv_blocks_read" in reason and "\n" not in reason
+
+
+CHUNK_READS = dict(summary_rows=3072, summaries_written=2)
+
+
+@pytest.mark.parametrize("over,ok", [
+    ({}, True),                                     # none of the two
+    (CHUNK_READS, True),
+    (dict(summary_rows=0, summaries_written=0), True),
+    (dict(summary_rows=128), False),                # one without the other
+    (dict(CHUNK_READS, summaries_written=-1), False),
+    (dict(CHUNK_READS, summary_rows=12.5), False),
+])
+def test_engine_step_v23_chunk_summary_counters(over, ok):
+    """A chunked layer's counters of an ``engine_step`` record
+    (``STEP_SPAN_CHUNKS``): both or none, whole, not negative."""
+    from distributed_llm_code_samples_tpu.runtime.telemetry import (
+        STEP_SPAN_CHUNKS)
+    assert frozenset(STEP_SPAN_CHUNKS) == _PINNED_STEP_SPAN_CHUNKS
+    rec = dict(_engine_step(**over), schema=SCHEMA_VERSION, kind="span",
+               trace_id=None, tenant=None)
+    got, reason = validate_record(rec)
+    assert got is ok, reason
+    if not ok:
+        assert "summary_rows" in reason and "\n" not in reason
 
 
 @pytest.mark.parametrize("case,named", [
