@@ -196,7 +196,7 @@ from ..runtime import wire
 from .draft import draft_tokens
 from .paged import (SCRATCH_BLOCK, corrupt_block as _pool_corrupt_block,
                     extract_blocks, kv_bytes_per_token, pool_bytes,
-                    scrub_blocks, walks)
+                    ring_start, scrub_blocks, walks)
 from .programs import FROM_SLOT, POISON_ALL, POISON_NONE, StepPrograms
 from .prefix import PrefixCache
 from .spill import SpillTier
@@ -290,13 +290,17 @@ WINDOW_COUNTERS = ("window_rows", "full_rows", "window_blocks_released",
 CHUNK_COUNTERS = ("summary_rows", "summaries_written")
 
 
-# ... and the pool's blocks the decode-side read of the step's launched
-# rows fetched (``DecodeEngine._count_blocks``): over the rows of its
+# ... and the blocks the decode-side reads of the step's launched rows
+# fetched (``DecodeEngine._count_blocks``): over the rows of its
 # ``decode`` / ``mixed`` / ``verify`` programs, a bucket's padded ones
 # with them, times the pool's layers — the rows' LIVE blocks where the
 # read walks each row's table (``paged.walks``), every table's whole
-# capacity where it gathers — beside that capacity
-KV_COUNTERS = ("kv_blocks_read", "kv_blocks_capacity")
+# capacity where it gathers — beside that capacity; the full kind's pool
+# (``kv_*``) and the window layers' ring (``ring_*``: the blocks from
+# the one that holds a row's ``paged.ring_start`` to the one it writes;
+# both 0 for a model with no window layer)
+KV_COUNTERS = ("kv_blocks_read", "kv_blocks_capacity",
+               "ring_blocks_read", "ring_blocks_capacity")
 
 
 class AdmissionError(RuntimeError):
@@ -674,8 +678,10 @@ class DecodeEngine:
         self._walks = walks(
             self.pool, shards=1 if mesh is None else mesh.shape[MODEL_AXIS])
         # ... and the window layers' pool (None for a model with none):
-        # a second block pool, its own scratch block, table and free list
+        # a second block pool, its own scratch block, table and free
+        # list; its read walks or gathers by the same rule
         self.wpool = self.programs.init_window()
+        self._ring_walks = self.wpool is not None and walks(self.wpool)
         # each slot's next token, on the device beside them (and one
         # scratch row): a row's pick is handed to the slot's next row
         # there, so a step can be launched before the last one is read
@@ -812,11 +818,12 @@ class DecodeEngine:
         # ... of which the chunk rode with the step's decode batch in
         # ONE ``mixed`` program (``_mixed_dispatch``)
         self.mixed_dispatches = 0
-        # the pool's blocks the decode-side reads fetched, and the
-        # capacity a gather of every row's table reads (``KV_COUNTERS``,
-        # cumulative; the step's own are ``_step_kv``)
-        self.kv_blocks_read = 0
-        self.kv_blocks_capacity = 0
+        # the blocks the decode-side reads fetched of the pool and of
+        # the rings, and the capacity a gather of every row's table
+        # reads (``KV_COUNTERS``, cumulative; the step's own are
+        # ``_step_kv``)
+        self.kv_blocks_read = self.kv_blocks_capacity = 0
+        self.ring_blocks_read = self.ring_blocks_capacity = 0
         # tokens emitted inside the CURRENT span per uid (decode/replay
         # segments emit many tokens per step under speculation; the
         # span record carries the count so a waterfall shows work, not
@@ -2585,33 +2592,39 @@ class DecodeEngine:
 
     def _count_blocks(self, ready: list[int], b: int,
                       reads: int = 1) -> None:
-        """Count the pool's blocks the decode-side read of a batch
-        about to be launched fetches (``KV_COUNTERS``): ``ready`` the
-        slots of its rows BEFORE their lengths advance, ``b`` its
-        bucket, ``reads`` the reads a row makes a layer (a verify
-        program's sub-steps, each one position further). A row that
-        attends over ``n`` positions walks ``ceil(n / block)`` blocks,
-        a padded row the scratch block; a gather reads every row's
-        whole table whatever it holds."""
-        blk, layers = self.cfg.block_size, self.pool.k.shape[0]
-        capacity = reads * b * self.cfg.max_blocks_per_seq * layers
-        if self._walks:
-            n = self.lengths[ready][:, None] + 1 + np.arange(reads)
+        """Count the blocks the decode-side reads of a batch about to
+        be launched fetch (``KV_COUNTERS``): ``ready`` the slots of its
+        rows BEFORE their lengths advance, ``b`` its bucket, ``reads``
+        the reads a row makes a layer (a verify program's sub-steps,
+        each one position further). Of the pool, a row that attends
+        over ``n`` positions walks ``ceil(n / block)`` blocks; of a
+        window layer's ring, the blocks from its window's first
+        position to its last (``paged.ring_start``: the rule the kernel
+        is handed); a padded row the scratch block of either; a gather
+        reads every row's whole table whatever it holds."""
+        blk = self.cfg.block_size
+        n = self.lengths[ready][:, None] + 1 + np.arange(reads)
+        padded = reads * (b - len(ready))
+        ring = ring_held = 0
+        if self.wpool is not None:
+            window = self.spec.window
+            layers = self.wpool.k.shape[0]
+            ring_held = reads * b * self.programs.window_blocks * layers
+            first = ring_start(n - 1, window, self.chunked) // blk
+            ring = (((int(((n - 1) // blk - first + 1).sum()) + padded)
+                     * layers) if self._ring_walks else ring_held)
             if self.chunked:
                 # the pool's rows are summaries: those of the windows
                 # before the row's own (a row in its first window
                 # walks one block, masked whole)
-                n = np.maximum(
-                    (n - 1) // self.spec.window * (self.spec.window // blk),
-                    1)
-            read = (int((-(-n // blk)).sum())
-                    + reads * (b - len(ready))) * layers
-        else:
-            read = capacity
-        self._step_kv["kv_blocks_read"] += read
-        self._step_kv["kv_blocks_capacity"] += capacity
-        self.kv_blocks_read += read
-        self.kv_blocks_capacity += capacity
+                n = np.maximum((n - 1) // window * (window // blk), 1)
+        layers = self.pool.k.shape[0]
+        held = reads * b * self.cfg.max_blocks_per_seq * layers
+        read = ((int((-(-n // blk)).sum()) + padded) * layers
+                if self._walks else held)
+        for key, blocks in zip(KV_COUNTERS, (read, held, ring, ring_held)):
+            self._step_kv[key] += blocks
+            setattr(self, key, getattr(self, key) + blocks)
 
     def _prefill_book(self, row: tuple, c: int, end: int,
                       nxt: int) -> bool:
@@ -3102,7 +3115,7 @@ class DecodeEngine:
 
     def _step_record(self, start_ns: int, end_ns: int) -> dict:
         """The executed step as ONE ``engine_step`` span record
-        (telemetry v22): the parent span and its phases in the order
+        (telemetry v24): the parent span and its phases in the order
         they closed (each a child by being in this list), the step
         programs it launched (``dispatches``: the i-th entry belongs to
         the i-th ``*.dispatch`` phase) and the launches whose results
@@ -3300,11 +3313,13 @@ class DecodeEngine:
             "prefill_dispatches": self.prefill_dispatches,
             # extra: ... of which the chunk rode with the decode batch
             "mixed_dispatches": self.mixed_dispatches,
-            # extra (v22): the pool's blocks the decode-side reads
-            # fetched, beside the capacity a gather of every launched
-            # row's table reads (``KV_COUNTERS``, cumulative)
+            # extra (v22; v24 the rings'): the blocks the decode-side
+            # reads fetched, beside the capacity a gather of every
+            # launched row's table reads (``KV_COUNTERS``, cumulative)
             "kv_blocks_read": self.kv_blocks_read,
             "kv_blocks_capacity": self.kv_blocks_capacity,
+            "ring_blocks_read": self.ring_blocks_read,
+            "ring_blocks_capacity": self.ring_blocks_capacity,
             # v17 KV-memory-hierarchy keys (pinned): demotion volume
             # (cumulative blocks + wire bytes), promotion wins
             # (restores, the prompt tokens they kept off the prefill

@@ -17,14 +17,14 @@ kind alone (no flag, field or environment variable chooses):
 
 - a decode-side row (``decode``, ``mixed``, ``verify``) calls
   ``stored_decode_attn``, which attends over the rows as stored. For
-  the FULL kind that is a WALK over the row's live blocks where they
-  lie (``ops/kv_walk.py``: one kernel a layer, nothing of a gathered
-  view's size exists) wherever ``walks`` says the pool takes it: a
-  float pool whose rows are, on the chip, two or more whole 128-lane
-  tiles. Every other pool keeps the PLAIN form,
-  ``gathered_decode_attn``: a gather of each row's whole table and two
-  products over the copy — a latent pool, a window layer's ring, an
-  int8 pool (per-block scales), a one-tile or ragged row on the chip;
+  the FULL kind and for a window layer's RING that is a WALK over the
+  row's live blocks where they lie (``ops/kv_walk.py``: one kernel a
+  layer, nothing of a gathered view's size exists) wherever ``walks``
+  says the pool takes it: a float pool whose rows are, on the chip,
+  two or more whole 128-lane tiles. Every other pool keeps the PLAIN
+  form, ``gathered_decode_attn``: a gather of each row's whole table
+  and two products over the copy — a latent pool, an int8 pool
+  (per-block scales), a one-tile or ragged row on the chip;
 - a prefill chunk's rows call ``gathered_chunk_attn``: one slot's f32
   head-split view (``models.attention.gather_paged_kv``), which is also
   the tests' oracle for both decode-side forms.
@@ -91,7 +91,9 @@ positions ``[j*block, (j+1)*block)`` lies in entry ``j mod entries``, so
 a block is overwritten exactly when every position in it is behind the
 window of every row still to come (``ring_positions`` says which
 position each stored row holds; ``models/attention.py::window_mask``
-hides the rest, stale rows of an overwritten block among them). The
+hides the rest, stale rows of an overwritten block among them; the walk
+is told where a row's window starts, ``ring_start``, and fetches the
+blocks from there to the row's last). The
 writes and the two reads are the full kind's, told the window
 (``write_chunk(ring=True)``, ``stored_decode_attn(window=)``,
 ``gathered_chunk_attn(window=)``): a window layer's gather is its short
@@ -405,6 +407,18 @@ def ring_positions(last, entries: int, block: int) -> jax.Array:
     return pos.reshape(*pos.shape[:-2], entries * block)
 
 
+def ring_start(last, window: int, aligned: bool):
+    """The first position a row at position ``last`` attends over in a
+    window layer: ``last - window + 1`` under the sliding rule
+    (``models/attention.py::window_mask``), the start of ``last``'s own
+    multiple-of-``window`` window under the aligned one
+    (``aligned_mask``), never below 0. Both rules give ONE range
+    ``[start, last]``, which is what the walk and the engine's count of
+    the blocks it fetches take; numpy or jax arrays alike."""
+    return ((last - last % window) if aligned
+            else (last - window + 1)).clip(0)
+
+
 def write_chunk(pool: PagedKV, layer: int, table: jax.Array, pos0,
                 k_new: jax.Array, v_new: jax.Array,
                 kv_dtype: str, ring: bool = False) -> PagedKV:
@@ -716,16 +730,18 @@ def gathered_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     return (y, m, l) if stats else y
 
 
-def walks(pool: PagedKV, window: int = 0, shards: int = 1) -> bool:
+def walks(pool: PagedKV, shards: int = 1) -> bool:
     """Whether the decode-side read of ``pool`` is the walk over each
     row's live blocks (``ops/kv_walk.py``) or the plain gather and two
     products (``gathered_decode_attn``). Decided from the pool's own kind,
     dtype and shape and from nothing else — no flag, field or
-    environment variable, and no model's name:
+    environment variable, and no model's name; a window layer's ring is
+    admitted under the same rule as the full kind's pool (the walk reads
+    any table as a ring):
 
-    - the FULL kind only: a latent pool (``latent_rank``) and a window
-      layer's ring (``window``) keep the plain read (their own readers
-      and masks: ``PERF.md`` section 7 says what each waits for);
+    - a pool of K/V rows only: a latent pool (``latent_rank``) keeps
+      the plain read (its reader tells a decode event by that gather:
+      ``PERF.md`` section 7);
     - a float pool only: an int8 pool (``k_scale``) has per-block scales
       on the small side, which the kernel does not take;
     - on the chip, rows of whole 128-lane tiles in blocks of whole
@@ -742,7 +758,7 @@ def walks(pool: PagedKV, window: int = 0, shards: int = 1) -> bool:
     ``shards``: the ways ``pool``'s rows are sharded over a mesh where
     the caller holds the whole pool (the engine, for its counters); a
     step program asks of the shard it is handed."""
-    if window or pool.latent_rank or pool.k_scale is not None:
+    if pool.latent_rank or pool.k_scale is not None:
         return False
     from ..ops import ssm
     if ssm._interpreted():
@@ -763,36 +779,44 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     [B]`` attendable positions; returns ``[B, H, dh]`` f32 (a latent
     pool: ``gathered_decode_attn`` says). One contract, met by the walk
     over each row's live blocks where the pool takes it (``walks``) and
-    by the plain form, ``gathered_decode_attn``, where it does not
-    (``window`` / ``aligned``: a ring under its rule, as there).
-    ``stats``: ``(y, m [B, H], l [B, H])`` from either form, for
-    ``join_reads``.
+    by the plain form, ``gathered_decode_attn``, where it does not.
+    ``window`` > 0: ``tables`` are window layers' short tables, used as
+    rings, and a row attends over its last ``window`` positions or,
+    where ``aligned``, over those of its own multiple-of-``window``
+    window up to itself. ``stats``: ``(y, m [B, H], l [B, H])`` from
+    either form, for ``join_reads``.
 
     The walk (``ops/kv_walk.py::walk_attn``) runs the same two products
     over the rows as stored, block by block where they lie, under an
     online float32 softmax: the query laid out for the stored row (zero
     outside its KV head's lanes) and the probabilities in the pool's
     dtype, sums in float32, head ``h`` keeping its KV head's ``dh``
-    lanes of the result. Against the oracle: an f32 pool to reduction
-    order, a bf16 pool to the two roundings of the small operands, as
-    the plain form. What differs: only ``ceil(lengths / block)`` blocks
-    of a row are read, so a NaN in a DEAD block of its table no longer
-    reaches it; one beyond ``lengths`` inside the last live block still
-    does (``corrupt_block``)."""
-    if not walks(pool, window):
+    lanes of the result. It is handed each row's range of positions,
+    ``[ring_start, lengths)`` for a ring and ``[0, lengths)`` otherwise
+    (this is the one place on the path that knows the two window rules),
+    and reads any table as a ring. Against the oracle: an f32 pool to
+    reduction order, a bf16 pool to the two roundings of the small
+    operands, as the plain form. What differs: only the blocks that
+    hold a position of the range are read, so a NaN in a DEAD block of
+    a row's table no longer reaches it; one inside a live block, before
+    the range's start or beyond ``lengths`` (a ring's stale rows of that
+    entry's last use), still does (``corrupt_block``)."""
+    if not walks(pool):
         return gathered_decode_attn(pool, layer, q, tables, lengths, window,
                                     aligned, stats)
     from ..ops.kv_walk import walk_attn
     b, h, dh = q.shape
     hkv = pool.kv_heads
     g = h // hkv
+    starts = (ring_start(lengths - 1, window, aligned) if window
+              else jnp.zeros_like(lengths))
     with jax.named_scope("attn"):
         # ``rows[b, (K,g), (k,d)] = q[b, (K,g), d]`` where ``k == K``
         rows = jnp.where(jnp.eye(hkv, dtype=bool)[:, None, :, None],
                          q.reshape(b, hkv, g, 1, dh), 0)
         full = walk_attn(pool.k, pool.v, layer,
                          rows.reshape(b, h, hkv * dh).astype(pool.k.dtype),
-                         tables, lengths, dh ** -0.5, stats)
+                         tables, starts, lengths, dh ** -0.5, stats)
         if stats:
             full, m, l = full
         y = jnp.einsum("bkgkd->bkgd", full.reshape(b, hkv, g, hkv, dh))

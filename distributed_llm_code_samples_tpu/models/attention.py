@@ -276,10 +276,11 @@ def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array, layer: int,
     ``generate`` and the engine's prefill chunk (``chunk_attn`` over
     one slot's view) compute. The engine's decode-side programs do not
     run it: they attend over the rows as stored
-    (``decode/paged.py::stored_decode_attn`` — for the full kind a walk
-    over each row's live blocks where they lie, ``ops/kv_walk.py``; for
-    a latent, window or int8 pool a gather of the rows' tables and two
-    products over the copy, ``gathered_decode_attn``; ``paged.walks``
+    (``decode/paged.py::stored_decode_attn`` — for the full kind and a
+    window layer's ring a walk over each row's live blocks where they
+    lie, ``ops/kv_walk.py``; for a latent or int8 pool a gather of the
+    rows' tables and two products over the copy,
+    ``gathered_decode_attn``; ``paged.walks``
     decides from the pool. No f32 head-split copy of the view either
     way; both held to this oracle in tests/test_paged_layout.py)."""
     layers = jnp.full_like(table, layer)

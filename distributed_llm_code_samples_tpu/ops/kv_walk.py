@@ -6,18 +6,27 @@ table. The plain read (``paged.gathered_decode_attn``) gathers every
 row's WHOLE table — capacity, not length — into a copy and runs two
 products over the copy. ``walk_attn`` is the same two products over the
 rows where they lie: one Pallas kernel a layer that, for each batch
-row, fetches only the row's live blocks, ``ceil(length / block)`` of
-them, from the pool in HBM into a double-buffered VMEM scratch, several
-blocks a copy step, and folds each step's scores into a running float32
-maximum, sum and accumulator (the online softmax). Nothing of a
-gathered view's size exists: no gather, no copy, no ``[b, H, T_cap]``
-scores, and the bytes that move are the live rows'.
+row, fetches only the row's live blocks, those that hold its attendable
+positions ``[start, length)``, from the pool in HBM into a
+double-buffered VMEM scratch, several blocks a copy step, and folds each
+step's scores into a running float32 maximum, sum and accumulator (the
+online softmax). Nothing of a gathered view's size exists: no gather,
+no copy, no ``[b, H, T_cap]`` scores, and the bytes that move are the
+live rows'.
+
+A table is read as a RING: block ``j`` of the sequence lies in entry ``j
+mod MB``. For the full kind ``start`` is 0 and ``j < MB``, so the table
+is read in order from its first entry; a window layer's short table
+(``decode/paged.py``: the third paged kind) wraps, and its row starts
+where its window does, under the sliding or the aligned rule alike: the
+kernel is told the range and knows neither rule.
 
 The conventions are the state kernels' (``ops/ssm.py``):
 ``ssm._interpreted()`` alone decides how the kernel runs, no caller
 passes an ``interpret`` of its own, and nothing is chosen by a flag, a
 field or the environment. Which pools take the walk at all is
-``decode/paged.py::walks``'s to say, from the pool's dtype and shape.
+``decode/paged.py::walks``'s to say, from the pool's kind, dtype and
+shape.
 """
 
 from __future__ import annotations
@@ -52,9 +61,12 @@ def blocks_a_step(block: int, row_bytes: int, max_blocks: int) -> int:
     return min(c, 1 << (max_blocks - 1).bit_length(), 64)
 
 
-def _walk_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
-                 o_ref, *rest, steps: int, scale: float):
-    """One batch row a grid step. ``kbuf/vbuf [2, steps * block, J]``
+def _walk_kernel(layer_ref, tables_ref, starts_ref, lengths_ref, q_ref,
+                 k_hbm, v_hbm, o_ref, *rest, steps: int, scale: float):
+    """One batch row a grid step: the blocks ``starts // block ..
+    (lengths - 1) // block`` of its sequence, ``steps`` of them a copy
+    step, each from the table's entry of its number modulo the table's
+    width. ``kbuf/vbuf [2, steps * block, J]``
     are the two buffers of each side; ``slot_ref`` says which of them
     the row's FIRST copy step is in (the row before started it, before
     its own last product), ``sems [side, buffer]`` count the copies.
@@ -64,12 +76,16 @@ def _walk_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
     *stats, kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref = rest
     r = pl.program_id(0)
     blk = kbuf.shape[1] // steps
+    entries = tables_ref.shape[1]
     layer = layer_ref[0]
+
+    def head(row):
+        return lax.div(starts_ref[row], blk)
 
     def live(row):
         # a padded row (length 0 or 1, its table all scratch) walks the
         # scratch block and nothing else
-        return jnp.maximum(pl.cdiv(lengths_ref[row], blk), 1)
+        return jnp.maximum(pl.cdiv(lengths_ref[row], blk) - head(row), 1)
 
     def fetched(row, c):
         return jnp.minimum(live(row) - c * steps, steps)
@@ -77,8 +93,14 @@ def _walk_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
     def copies(row, c, buf, act: str):
         """``start`` or ``wait`` for the two copies, K and V, of every
         block the row holds of its step ``c``, into buffer ``buf``."""
+        # a row holds no more blocks than its table has entries, so the
+        # step's entries wrap at most once past its first
+        entry0 = lax.rem(head(row) + c * steps, entries)
+
         def one(i, _):
-            phys = tables_ref[row, c * steps + i]
+            entry = entry0 + i
+            phys = tables_ref[row, jnp.where(entry < entries, entry,
+                                             entry - entries)]
             dst = pl.ds(pl.multiple_of(i * blk, blk), blk)
             for side, (hbm, vmem) in enumerate(((k_hbm, kbuf),
                                                 (v_hbm, vbuf))):
@@ -95,7 +117,7 @@ def _walk_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
 
     first = slot_ref[0]
     n_steps = pl.cdiv(live(r), steps)
-    length = lengths_ref[r]
+    start, length = starts_ref[r], lengths_ref[r]
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -128,10 +150,12 @@ def _walk_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
         k, v = kbuf[buf], vbuf[buf]                     # [T, J]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        pos = c * (steps * blk) + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # stale rows of the last live block meet an exact 0 (and a NaN
-        # there still poisons the row, as in the plain read)
-        s = jnp.where(pos < length, s, _MASKED)
+        pos = ((head(r) + c * steps) * blk
+               + lax.broadcasted_iota(jnp.int32, s.shape, 1))
+        # rows of the first live block before the start and stale rows
+        # of the last one meet an exact 0 (and a NaN there still poisons
+        # the row, as in the plain read)
+        s = jnp.where((pos >= start) & (pos < length), s, _MASKED)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -151,7 +175,7 @@ def _walk_kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm,
 
 @functools.partial(jax.jit,
                    static_argnames=("steps", "scale", "interpret", "stats"))
-def _walk(layer, tables, lengths, q, k_pool, v_pool, *, steps: int,
+def _walk(layer, tables, starts, lengths, q, k_pool, v_pool, *, steps: int,
           scale: float, interpret: bool, stats: bool = False):
     """The kernel's call, jitted on its own: ``layer [1]`` is an
     operand, so the calls of every layer of a step program are ONE
@@ -170,7 +194,7 @@ def _walk(layer, tables, lengths, q, k_pool, v_pool, *, steps: int,
     return pl.pallas_call(
         functools.partial(_walk_kernel, steps=steps, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(b,),
+            num_scalar_prefetch=4, grid=(b,),
             in_specs=[row, whole, whole], out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((2, steps * blk, j), k_pool.dtype),
@@ -182,26 +206,29 @@ def _walk(layer, tables, lengths, q, k_pool, v_pool, *, steps: int,
                 pltpu.VMEM((h, j), jnp.float32)]),
         out_shape=out_shape,
         interpret=interpret,
-    )(layer, tables, lengths, q, k_pool, v_pool)
+    )(layer, tables, starts, lengths, q, k_pool, v_pool)
 
 
 def walk_attn(k_pool: jax.Array, v_pool: jax.Array, layer: int,
-              q: jax.Array, tables: jax.Array, lengths: jax.Array,
-              scale: float, stats: bool = False):
+              q: jax.Array, tables: jax.Array, starts: jax.Array,
+              lengths: jax.Array, scale: float, stats: bool = False):
     """Single-query attention of ``b`` rows over their own blocks of
     one layer of the pool, where they lie. ``k_pool/v_pool [L, n_blocks,
     block, J]`` stay in HBM whole; ``q [b, H, J]`` in the pool's dtype
     is each head's query laid out FOR a stored row (zero outside its KV
     head's lanes: ``decode/paged.py`` builds it); ``tables [b, MB]``,
-    ``lengths [b]`` the attendable positions. Returns ``[b, H, J]``
-    float32: ``softmax(scale * q K^T) V`` over the first ``lengths``
-    rows, of which head ``h`` keeps its KV head's lanes.
+    block ``j`` of a sequence in entry ``j mod MB``; ``starts [b]`` /
+    ``lengths [b]`` the first attendable position and the one after the
+    last (the full kind: ``starts`` 0; a ring: no more blocks between
+    them than ``MB``). Returns ``[b, H, J]`` float32: ``softmax(scale *
+    q K^T) V`` over the positions ``starts <= t < lengths``, of which
+    head ``h`` keeps its KV head's lanes.
 
     The layer is a scalar operand, so every layer's call is the same
     kernel to compile. Operands in the pool's dtype, sums in float32. A
-    row's dead blocks (table entries at and beyond ``ceil(length /
-    block)``) are never fetched: bytes there, a NaN among them, do not
-    reach the result.
+    row's dead blocks (those that hold no position of its range) are
+    never fetched: bytes there, a NaN among them, do not reach the
+    result.
 
     ``stats``: ``(o, m [b, H], l [b, H])``, the result beside each
     head's score maximum and its sum of ``exp(s - m)``, which the
@@ -215,8 +242,8 @@ def walk_attn(k_pool: jax.Array, v_pool: jax.Array, layer: int,
         raise ValueError(ssm._UNTILED.format(d=j))
     steps = blocks_a_step(k_pool.shape[2], j * k_pool.dtype.itemsize,
                           tables.shape[1])
-    got = _walk(jnp.asarray([layer], jnp.int32), tables, lengths, q,
-                k_pool, v_pool, steps=steps, scale=scale,
+    got = _walk(jnp.asarray([layer], jnp.int32), tables, starts, lengths,
+                q, k_pool, v_pool, steps=steps, scale=scale,
                 interpret=interpret, stats=stats)
     if not stats:
         return got

@@ -50,7 +50,7 @@ the same dirs render identical output even under equal timestamps.
 
 Output: step-time percentiles, throughput, MFU, HBM high-water, the
 serving summary + reliability block per engine, a **step phases**
-table (schema-v22 ``engine_step`` span records: per host phase of
+table (schema-v24 ``engine_step`` span records: per host phase of
 ``engine.step()`` the count, mean, p99 and share of step time, per
 step program, by kind and bucket, its runs and the time from its launch
 to the end of its read, and for a model with window layers what the
@@ -78,7 +78,8 @@ import numpy as np
 from .runtime.telemetry import (FLIGHT_FILENAME, METRICS_FILENAME,
                                 RECORD_KINDS,
                                 ROUTER_POSTMORTEM_PREFIX,
-                                STATUS_FILENAME, STEP_SPAN, read_metrics)
+                                STATUS_FILENAME, STEP_SPAN, STEP_SPAN_KV,
+                                STEP_SPAN_RING, read_metrics)
 
 # a completed request's span durations telescope to its latency by
 # construction (runtime/tracing.py); the tolerance only absorbs the
@@ -602,8 +603,11 @@ class _Stream:
         """What the steps' rows read of the cache. ``blocks`` (v22):
         the pool's blocks the decode-side reads of a step's launched
         rows fetched (``kv_blocks_read``) beside the capacity a gather
-        of their whole tables reads, a step's mean over the steps that
-        launched such rows; None where no record counted one. Where a
+        of their whole tables reads, and the same two of a window
+        layer's rings (v24: ``ring_blocks_read`` /
+        ``ring_blocks_capacity``, 0 with none), a step's mean over the
+        steps that launched such rows; None where no record counted
+        one. Where a
         record counted a window layer's read (v21), also ``window_rows``
         / ``full_rows``, the cached positions a step's launched rows
         attend over in a window layer and in a full one, and the window
@@ -614,10 +618,9 @@ class _Stream:
         kv = [r for r in self.step_spans if r.get("kv_blocks_capacity")]
         blocks = None if not kv else {
             "steps": len(kv),
-            "kv_blocks_read_mean": round(float(np.mean(
-                [r["kv_blocks_read"] for r in kv])), 2),
-            "kv_blocks_capacity_mean": round(float(np.mean(
-                [r["kv_blocks_capacity"] for r in kv])), 2)}
+            **{f"{key}_mean": round(float(np.mean(
+                [r.get(key, 0) for r in kv])), 2)
+               for key in STEP_SPAN_KV + STEP_SPAN_RING}}
         recs = [r for r in self.step_spans if r.get("window_rows")]
         if not recs:
             return None if blocks is None else {"blocks": blocks}
@@ -1898,6 +1901,11 @@ def _render_engine_sections(out: list, doc: dict) -> None:
                 f"  cache reads: {read} blocks a step fetched by the "
                 f"decode-side reads, of {held} in their rows' tables "
                 f"({100 * read / held:.1f}%; {kb['steps']} step(s))")
+            if kb["ring_blocks_capacity_mean"]:
+                out.append(
+                    f"  cache reads: {kb['ring_blocks_read_mean']} blocks "
+                    "a step fetched of the window layers' rings, of "
+                    f"{kb['ring_blocks_capacity_mean']} entries")
         if cr and "steps" in cr:
             out.append(
                 f"  cache reads: {cr['window_rows_mean']} positions a "

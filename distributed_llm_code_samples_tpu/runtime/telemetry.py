@@ -282,7 +282,19 @@ import numpy as np
 # Both or none, whole and not negative (``validate_record``). Readers:
 # ``report.py``'s cache-reads line, ``benchmark/chunk_trace.py`` (the
 # two stores' rooflines, ``summary_rows_share``).
-SCHEMA_VERSION = 23
+# v24 (PR 44): a window layer's ring is walked too — the ``engine_step``
+# record may carry, beside the full kind's pair, the RING's
+# (``STEP_SPAN_RING``; the engine writes both, 0 for a model with no
+# window layer): ``ring_blocks_read``, the window pool's blocks the
+# reads of the rows the step LAUNCHED fetched over its layers (where the
+# read walks: from the block that holds a row's first attendable
+# position, ``decode/paged.py::ring_start``, to the one it writes, a
+# padded row the scratch block; every ring's entries where it gathers),
+# and ``ring_blocks_capacity``, the entries of those rows' rings. Both
+# or none, whole, not negative, no more read than the capacity
+# (``validate_record``); the ``decode`` record carries the two as
+# cumulative extras. Readers: ``report.py``'s cache-reads line.
+SCHEMA_VERSION = 24
 
 METRICS_FILENAME = "metrics.jsonl"
 
@@ -445,6 +457,8 @@ STEP_SPAN_WINDOW = ("window_rows", "full_rows", "window_blocks_released",
                     "window_blocks_live")
 # ... and the two of the decode-side reads' blocks, likewise (v22)
 STEP_SPAN_KV = ("kv_blocks_read", "kv_blocks_capacity")
+# ... and the same two of a window layer's ring (v24)
+STEP_SPAN_RING = ("ring_blocks_read", "ring_blocks_capacity")
 # ... and a chunked layer's two, likewise (v23)
 STEP_SPAN_CHUNKS = ("summary_rows", "summaries_written")
 
@@ -1121,17 +1135,18 @@ def validate_record(rec: Any) -> tuple[bool, str]:
                                f"{ {k: rec[k] for k in got} }: all of "
                                f"{list(STEP_SPAN_WINDOW)} or none, whole, "
                                "not negative, window_rows <= full_rows")
-            got = [k for k in STEP_SPAN_KV if k in rec]
-            if got and (len(got) != len(STEP_SPAN_KV) or any(
-                    not isinstance(rec[k], int) or rec[k] < 0
-                    for k in got)
-                    or rec["kv_blocks_read"] > rec["kv_blocks_capacity"]):
-                return False, (f"span record (span {STEP_SPAN}) has the "
-                               f"decode-side reads' blocks "
-                               f"{ {k: rec[k] for k in got} }: both of "
-                               f"{list(STEP_SPAN_KV)} or none, whole, not "
-                               "negative, kv_blocks_read <= "
-                               "kv_blocks_capacity")
+            for (read, held), what in (
+                    (STEP_SPAN_KV, "decode-side reads' blocks"),
+                    (STEP_SPAN_RING, "rings' blocks read")):
+                got = [k for k in (read, held) if k in rec]
+                if got and (len(got) != 2 or any(
+                        not isinstance(rec[k], int) or rec[k] < 0
+                        for k in got) or rec[read] > rec[held]):
+                    return False, (
+                        f"span record (span {STEP_SPAN}) has the {what} "
+                        f"{ {k: rec[k] for k in got} }: both of "
+                        f"{[read, held]} or none, whole, not negative, "
+                        f"{read} <= {held}")
             got = [k for k in STEP_SPAN_CHUNKS if k in rec]
             if got and (len(got) != len(STEP_SPAN_CHUNKS) or any(
                     not isinstance(rec[k], int) or rec[k] < 0

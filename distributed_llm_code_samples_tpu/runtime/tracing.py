@@ -229,7 +229,9 @@ class SpanTracer:
 # are counts the host has at launch: no phase of their own. So are
 # ``kv_blocks_read`` / ``kv_blocks_capacity`` (``engine._count_blocks``;
 # telemetry v22): the pool's blocks the decode-side reads of the
-# launched rows fetched, and what a gather of their whole tables reads.
+# launched rows fetched, and what a gather of their whole tables reads
+# (``ring_blocks_read`` / ``ring_blocks_capacity``, telemetry v24: the
+# same two of a window layer's rings).
 # And ``summary_rows`` / ``summaries_written`` (telemetry v23): for a
 # model whose layers summarise finished chunks, the summaries the
 # launched rows attend over and the launched writes that finished one;

@@ -749,12 +749,14 @@ def test_window_moe_step_program_keeps_both_pools_as_stored(
     full layers' pool ``[1, 12289, 16, 1024]`` and the window layers'
     ``[3, 2177, 16, 1024]`` are both taken row-major and unpadded,
     aliased whole and updated in place, never copied and no layer's slab
-    sliced out; a window layer's gather is its ring of 34 blocks a row —
-    544 positions — and no gathered view of a window layer is wider; a
-    full layer's batch rows are not gathered at all (one kernel call
-    walks their live blocks: ``ops/kv_walk.py``), only a prefill
-    chunk's one slot still is, at the table's capacity, 3,072. The
-    result carries the held experts' counters after the picks."""
+    sliced out; a batch's rows are not gathered at all, in a full layer
+    or in a window layer (one kernel call a layer walks their live
+    blocks where they lie, ``ops/kv_walk.py``: the full layers' over
+    the table, ``f32[b,48,1024]``, the window layers' over the ring of
+    34 entries, ``f32[b,72,1024]``); only a prefill chunk's one slot
+    still is, a window layer's at its ring — 544 positions — and the
+    full layer's at the table's capacity, 3,072. The result carries the
+    held experts' counters after the picks."""
     import re
     eng, programs = laguna_engine_args
     bucket, args = programs[kind]
@@ -765,6 +767,8 @@ def test_window_moe_step_program_keeps_both_pools_as_stored(
     assert eng.programs.window_blocks == 34
     assert pool.k.shape == (1, 12289, 16, 1024) == pool.v.shape
     assert wpool.k.shape == (3, 2177, 16, 1024) == wpool.v.shape
+    from distributed_llm_code_samples_tpu.decode import paged
+    assert paged.walks(pool) and paged.walks(wpool)
     # whole slabs of either pool (a layer of the window pool is the
     # smaller): nothing of that size is copied or sliced out
     slab = wpool.k.size // wpool.k.shape[0]
@@ -792,15 +796,21 @@ def test_window_moe_step_program_keeps_both_pools_as_stored(
     rows = "" if kind == "prefill" else r"%d," % bucket
     got = [int(n) for n in re.findall(
         r"= bf16\[%s(\d+),16,1024\]\S* gather\(" % rows, hlo)]
-    # K and V of the three window layers at the ring; of the one full
-    # layer at capacity for a chunk's one slot alone: a batch's rows of
-    # a full layer are walked where they lie, by one kernel call
+    # a chunk's one slot alone: K and V of the three window layers at
+    # the ring, of the one full layer at capacity. A batch's rows are
+    # walked where they lie, by one kernel call a layer of either kind,
+    # a window layer's handed its pool whole and the rings as tables
     chunk = [34] * 6 + [192] * 2
-    assert sorted(got) == (chunk if kind == "prefill" else [34] * 6), got
+    assert sorted(got) == (chunk if kind == "prefill" else []), got
     walk = [l for l in hlo.splitlines() if MOSAIC in l]
-    assert len(walk) == (0 if kind == "prefill" else pool.k.shape[0])
-    assert all("f32[%d,48,1024]" % bucket in l.split(" custom-call(")[0]
-               for l in walk)
+    heads = sorted(l.split(" custom-call(")[0].split("f32[%d," % bucket)[1]
+                   .split(",")[0] for l in walk)
+    assert heads == ([] if kind == "prefill" else
+                     ["48"] * pool.k.shape[0] + ["72"] * wpool.k.shape[0])
+    for l in walk:
+        ring = "f32[%d,72,1024]" % bucket in l.split(" custom-call(")[0]
+        assert ("bf16[3,2177,16,1024]" in l
+                and "s32[%d,34]" % bucket in l) is ring, l
     if kind == "mixed":     # ... and the riding chunk's one slot
         one = [int(n) for n in re.findall(
             r"= bf16\[(\d+),16,1024\]\S* gather\(", hlo)]
@@ -860,12 +870,14 @@ def test_chunked_step_program_keeps_both_stores_as_stored(
     ring's ``[2, 3121, 16, 4096]`` (the window kind's: 130 blocks a
     slot) are both taken row-major and unpadded, aliased whole and
     updated in place, never copied and no layer's slab sliced out. A
-    batch's rows gather their rings, 130 blocks a row (the plain read)
-    and WALK their summaries where they lie: one kernel call a layer,
-    which hands back its softmax statistics beside its sums (three
-    results), and no gather of a summary table; a prefill chunk's one
-    slot gathers both its ring and its 36 blocks of summaries. The
-    result is the picks over head 0's 320 rows."""
+    batch's rows WALK both stores where they lie: TWO kernel calls a
+    layer, one handed the ring's pool whole and the rings as tables
+    ``[b, 130]`` (what ``benchmark/chunk_trace.py`` knows a ring's
+    kernel by), one the summaries', each of which hands back its
+    softmax statistics beside its sums (three results), and no gather
+    of a ring or of a summary table; a prefill chunk's one slot gathers
+    both its ring and its 36 blocks of summaries. The result is the
+    picks over head 0's 320 rows."""
     import re
     eng, programs = evabyte_engine_args
     bucket, args = programs[kind]
@@ -877,7 +889,7 @@ def test_chunked_step_program_keeps_both_stores_as_stored(
     assert pool.k.shape == (2, 865, 16, 4096) == pool.v.shape
     assert wpool.k.shape == (2, 3121, 16, 4096) == wpool.v.shape
     from distributed_llm_code_samples_tpu.decode import paged
-    assert paged.walks(pool) and not paged.walks(wpool, 2048)
+    assert paged.walks(pool) and paged.walks(wpool)
     slab = pool.k.size // pool.k.shape[0]
     moved = [r for r in _hlo_results(
         hlo, ("copy", "slice", "dynamic-slice"), "bf16")
@@ -904,15 +916,21 @@ def test_chunked_step_program_keeps_both_stores_as_stored(
     rows = "" if kind == "prefill" else r"%d," % bucket
     got = [int(n) for n in re.findall(
         r"= bf16\[%s(\d+),16,4096\]\S* gather\(" % rows, hlo)]
-    # K and V of both layers: a batch's rows at the ring; a chunk's one
-    # slot at the ring and at its table of summaries
+    # K and V of both layers, of a chunk's one slot alone: at the ring
+    # and at its table of summaries
     chunk = [36] * 4 + [130] * 4
-    assert sorted(got) == (chunk if kind == "prefill" else [130] * 4), got
-    walk = [l.split(" custom-call(")[0] for l in hlo.splitlines()
-            if MOSAIC in l]
-    assert len(walk) == (0 if kind == "prefill" else pool.k.shape[0])
-    assert all("f32[%d,32,4096]" % bucket in l
-               and l.count("f32[%d,32,128]" % bucket) == 2 for l in walk)
+    assert sorted(got) == (chunk if kind == "prefill" else []), got
+    walk = [l for l in hlo.splitlines() if MOSAIC in l]
+    assert len(walk) == (0 if kind == "prefill" else 2 * pool.k.shape[0])
+    for l in walk:
+        result = l.split(" custom-call(")[0]
+        assert ("f32[%d,32,4096]" % bucket in result
+                and result.count("f32[%d,32,128]" % bucket) == 2), l
+    for store, entries in ((wpool, 130), (pool, 36)):
+        handed = "bf16[%s]" % ",".join(map(str, store.k.shape))
+        tables = "s32[%d,%d]" % (bucket, entries)
+        assert sum(handed in l and tables in l for l in walk) == (
+            len(walk) // 2), (handed, tables)
     if kind == "mixed":     # ... and the riding chunk's one slot,
         # beside the blocks the batch's rows may finish (K and V a layer)
         one = [int(n) for n in re.findall(
@@ -1108,9 +1126,9 @@ def test_decode_program_reads_the_gathered_rows_as_stored(
     holds one kernel call a full-kind layer whose result is the heads'
     sums over the stored row, ``f32[b, H, H_kv*dh]``
     (``ops/kv_walk.py``), the K/V pool aliased whole beside it.
-    Laguna's window layers keep the plain read: their gather is the
-    ring, 34 blocks a row, well under a full view. (The parent of
-    PR 40 fails this with two gathers of the view's size a layer.)
+    Laguna's window layers walk their rings by the same kernel: a call
+    a window layer beside a call a full layer. (The parent of PR 40
+    fails this with two gathers of the view's size a layer.)
 
     **A pool that keeps the plain read** (the hybrid's one KV head of
     128 lanes, the latent cell's rows): the program attends over each
@@ -1142,7 +1160,9 @@ def test_decode_program_reads_the_gathered_rows_as_stored(
             and ",%d]" % pool.k.shape[-1] in l.split(" custom-call(")[0]]
     if WALKS[fixture]:
         assert not big, big
-        assert len(walk) == pool.k.shape[0], walk
+        wpool = getattr(eng, "wpool", None)
+        assert len(walk) == pool.k.shape[0] + (
+            0 if wpool is None else wpool.k.shape[0]), walk
         _carry_is_aliased_whole(compiled, eng)
     else:
         assert big, "the gather's own results are of the view's size"
@@ -1157,16 +1177,19 @@ def test_decode_program_reads_the_gathered_rows_as_stored(
 
 @pytest.mark.parametrize("cell", ["gpt2-large", "gpt2-large-f32",
                                   "jamba2-3b", "lfm2-24b-a2b",
-                                  "laguna-s-2.1", "evabyte"])
+                                  "laguna-s-2.1", "evabyte",
+                                  "laguna-ring", "evabyte-ring"])
 def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
                                               kernels_for_the_chip, cell):
     """The walk alone, for the described v5e, at each serving cell's
     ``(b, H, H_kv, dh, MB)`` over its layers' pool: Mosaic takes it (the
     hybrid's one-tile rows too, which ``paged.walks`` leaves to the
-    plain read by measurement, and a float32 pool), the pool is neither
-    copied nor held twice, and what the kernel keeps of the chip's fast
-    memory follows from the row's bytes: so many blocks a copy step
-    that the four buffers fit ``ssm._VMEM_BUDGET``."""
+    plain read by measurement, a float32 pool, and the two cells' RINGS:
+    a table of 34 or 130 entries read modulo its width, each row with
+    the start of its range), the pool is neither copied nor held twice,
+    and what the kernel keeps of the chip's fast memory follows from the
+    row's bytes: so many blocks a copy step that the four buffers fit
+    ``ssm._VMEM_BUDGET``."""
     from distributed_llm_code_samples_tpu.ops import kv_walk, ssm
     b, h, hkv, dh, mb, layers = {
         "gpt2-large": (12, 20, 20, 64, 64, 36),
@@ -1175,8 +1198,12 @@ def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
         "lfm2-24b-a2b": (64, 32, 8, 64, 128, 2),
         "laguna-s-2.1": (64, 48, 8, 128, 192, 3),
         # the chunk summaries' pool: 8 KB rows, with the statistics out
-        "evabyte": (24, 32, 32, 128, 36, 8)}[cell]
-    stats = cell == "evabyte"
+        "evabyte": (24, 32, 32, 128, 36, 8),
+        # the window layers' rings: ``[9, 2177, 16, 1024]`` under 72
+        # heads, ``[8, 3121, 16, 4096]`` with the statistics out
+        "laguna-ring": (64, 72, 8, 128, 34, 9),
+        "evabyte-ring": (24, 32, 32, 128, 130, 8)}[cell]
+    stats = cell.startswith("evabyte")
     dt = jnp.float32 if cell.endswith("f32") else jnp.bfloat16
     j, blk = hkv * dh, 16
     steps = kv_walk.blocks_a_step(blk, j * dt.dtype.itemsize, mb)
@@ -1189,6 +1216,7 @@ def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
         stats=stats)).lower(
             side, side, q=shape((b, h, j), dt),
             tables=shape((b, mb), jnp.int32),
+            starts=shape((b,), jnp.int32),
             lengths=shape((b,), jnp.int32)).compile()
     assert sum(MOSAIC in l for l in compiled.as_text().splitlines()) == 1
     m = compiled.memory_analysis()

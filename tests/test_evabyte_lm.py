@@ -631,7 +631,7 @@ def test_a_quarantined_sequence_leaves_no_poison_in_either_store(
 def test_the_records_carry_the_counters_and_report_prints_them(
         tmp_path, capsys, weights):
     """With a writer attached every step's ``engine_step`` record
-    (telemetry v23) carries ``summary_rows`` / ``summaries_written``
+    (telemetry v24) carries ``summary_rows`` / ``summaries_written``
     beside the window's counters and is schema-valid; ``report`` says
     on its cache-reads line how many summaries a step's rows attended
     over and how many were written."""
@@ -658,6 +658,11 @@ def test_the_records_carry_the_counters_and_report_prints_them(
     text = capsys.readouterr().out
     assert "chunk summaries a step beside the window's positions" in text
     assert "9 written" in text
+    # v24: the ring's blocks the decode-side reads fetched, on a line of
+    # their own, fewer than the rings' entries
+    assert 0 < sum(r["ring_blocks_read"] for r in steps) == (
+        eng.ring_blocks_read) < eng.ring_blocks_capacity
+    assert "blocks a step fetched of the window layers' rings" in text
 
 
 # -- (d) what the two stores cannot carry yet refuses, in one line ----------

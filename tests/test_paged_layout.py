@@ -12,7 +12,7 @@ rows as stored (``stored_decode_attn``) is held to the oracle
 ``decode_attn(q, *vmap(gather_layer))`` in both its forms: the plain
 gather and two products (``gathered_decode_attn``; also to a NumPy
 model of its own arithmetic) and the walk over each row's live blocks
-(``ops/kv_walk.py``).
+(``ops/kv_walk.py``), a full pool's table and a window layer's ring.
 """
 
 import jax
@@ -21,10 +21,11 @@ import numpy as np
 import pytest
 
 from distributed_llm_code_samples_tpu.decode.paged import (
-    KV_DTYPES, SCRATCH_BLOCK, _quantize, _rows_major, copy_block,
-    copy_block_rows, corrupt_block, extract_blocks, gather_layer,
-    gathered_decode_attn, implant_block, init_pool, scrub_blocks,
-    stored_decode_attn, walks, write_chunk, write_rows)
+    KV_DTYPES, SCRATCH_BLOCK, _heads_major, _quantize, _rows_major,
+    copy_block, copy_block_rows, corrupt_block, extract_blocks,
+    gather_layer, gathered_decode_attn, implant_block, init_pool,
+    ring_positions, ring_start, scrub_blocks, stored_decode_attn, walks,
+    write_chunk, write_rows)
 from distributed_llm_code_samples_tpu.models.lm import decode_attn
 
 L, NB, HKV, BLK, DH = 2, 7, 3, 4, 8
@@ -472,3 +473,142 @@ def test_walked_stored_decode_attn_matches_the_oracle(shape, kv_dtype, poison):
     assert (err <= bound + 1e-5 * scale).all()
     # bf16 operands, not an f32 upcast of the cache
     assert err.max() > 1e-4 * scale
+
+
+# ---------------------------------------------------------------------
+# the walk over a RING: a window layer's short table, block ``j`` of the
+# sequence in entry ``j mod entries``, read from the block that holds
+# the row's first attendable position to the one that holds its last,
+# under the sliding rule (Laguna) and the aligned one (EvaByte)
+R_HQ, R_HKV, R_DH = 6, 2, 8
+R_BLK, R_WINDOW = 4, 16
+R_ENTRIES = R_WINDOW // R_BLK + 2
+# in one batch: before the ring's first wrap; exactly at a window
+# boundary (``last % window == 0``: ONE live position under the aligned
+# rule; a start that is no block multiple under the sliding one); one
+# short of it (a whole window under both); wrapped twice; a start inside
+# a block before the first wrap; a bucket's padded row
+R_LENGTHS = np.asarray([7, 33, 32, 55, 22, 1], np.int32)
+R_POISONED = 3          # ``last`` 54: block 13, in entry 1, offset 2
+
+
+def _ring_case(kv_dtype, poison):
+    """A two-layer window pool (the read is of layer 1), every byte
+    random and finite: a ring holds stale rows of its entries' last use
+    wherever the mask hides them, the largest a freed sequence could
+    leave beyond each row's last position. ``poison``: a NaN in a DEAD
+    entry of row ``R_POISONED``'s ring (the one after the block being
+    written: behind the window under both rules) or in the stale rows
+    of its last live block."""
+    rng = np.random.default_rng(2)
+    b = len(R_LENGTHS)
+    nb = 1 + (b - 1) * R_ENTRIES
+    tables = 1 + rng.permutation((b - 1) * R_ENTRIES).reshape(b - 1, -1)
+    tables = np.concatenate(
+        [tables, np.full((1, R_ENTRIES), SCRATCH_BLOCK)]).astype(np.int32)
+    pool = init_pool(2, nb, R_HKV, R_BLK, R_DH, kv_dtype)
+    pos = np.asarray(ring_positions(R_LENGTHS - 1, R_ENTRIES, R_BLK))
+    beyond = np.zeros((nb, R_BLK), bool)
+    for r in range(b):
+        late = (pos[r] >= R_LENGTHS[r]).reshape(R_ENTRIES, R_BLK)
+        beyond[tables[r]] |= late
+    last = R_LENGTHS[R_POISONED] - 1
+    sides = []
+    for _ in "kv":
+        src = rng.normal(size=(2, nb, R_BLK, R_HKV * R_DH)).astype(
+            np.float32)
+        src = np.where(beyond[None, :, :, None], 3e4, src)
+        if poison == "dead":
+            src[:, tables[R_POISONED, (last // R_BLK + 1) % R_ENTRIES]] = (
+                np.nan)
+        elif poison == "last":
+            src[:, tables[R_POISONED, last // R_BLK % R_ENTRIES],
+                last % R_BLK + 1:] = np.nan
+        sides.append(jnp.asarray(src, pool.k.dtype))
+    pool = pool._replace(k=sides[0], v=sides[1])
+    q = jnp.asarray(rng.normal(size=(b, R_HQ, R_DH)), jnp.float32)
+    return pool, q, jnp.asarray(tables), pos
+
+
+@pytest.mark.parametrize("poison", ["none", "dead", "last"])
+@pytest.mark.parametrize("stats", [False, True])
+@pytest.mark.parametrize("kv_dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("rule", ["sliding", "aligned"])
+def test_walked_ring_matches_the_plain_read_and_the_oracle(rule, kv_dtype,
+                                                           stats, poison):
+    """``stored_decode_attn`` over a window layer's ring, which takes
+    the walk under the same rule as a full pool, against
+    ``gathered_decode_attn`` (the form the tests hold the walk to:
+    ``ring_positions`` and the mask functions) and against a NumPy model
+    of the rule itself, ``softmax`` over the positions ``start <= t <=
+    last`` of each row's ring, under the full kind's bounds: an f32 pool
+    to reduction order, a bf16 pool to the two roundings of the small
+    operands. With ``stats`` the score maximum and the sum beside it
+    agree too. A NaN in a DEAD entry of a row's ring does not reach the
+    row, bit for bit; one in the stale rows of its last live block
+    poisons that row and no other, as in the plain read."""
+    aligned = rule == "aligned"
+    lengths = jnp.asarray(R_LENGTHS)
+
+    def walked(pool, q, tables):
+        assert walks(pool)
+        return [np.asarray(x) for x in jax.tree.leaves(jax.jit(
+            lambda q: stored_decode_attn(pool, 1, q, tables, lengths,
+                                         R_WINDOW, aligned, stats))(q))]
+
+    pool, q, tables, pos = _ring_case(kv_dtype, "none")
+    got = walked(pool, q, tables)
+    assert len(got) == (3 if stats else 1)
+    if poison != "none":
+        bad = walked(*_ring_case(kv_dtype, poison)[:3])
+        rows = np.arange(len(R_LENGTHS)) != R_POISONED
+        for x, y in zip(bad, got):
+            np.testing.assert_array_equal(x[rows], y[rows])
+        if poison == "dead":
+            for x, y in zip(bad, got):
+                np.testing.assert_array_equal(x, y)
+        else:
+            assert np.isnan(bad[0][R_POISONED]).all()
+        return
+    plain = [np.asarray(x) for x in jax.tree.leaves(jax.jit(
+        lambda q: gathered_decode_attn(pool, 1, q, tables, lengths,
+                                       R_WINDOW, aligned, stats))(q))]
+    # the rule, written out: one range of positions a row
+    last = R_LENGTHS - 1
+    start = np.maximum(last // R_WINDOW * R_WINDOW if aligned
+                       else last - R_WINDOW + 1, 0)
+    assert (np.asarray(ring_start(last, R_WINDOW, aligned)) == start).all()
+    live = (pos >= start[:, None]) & (pos <= last[:, None])     # [b, T]
+    assert live.sum(1).tolist() == (
+        [7, 1, 16, 7, 6, 1] if aligned else [7, 16, 16, 16, 16, 1])
+    b, h, dh = q.shape
+    kc, vc = (np.asarray(_heads_major(side[1][np.asarray(tables)], dh),
+                         np.float64).transpose(0, 2, 1, 3, 4).reshape(
+                             b, R_HKV, -1, dh) for side in (pool.k, pool.v))
+    qg = np.asarray(q, np.float64).reshape(b, R_HKV, h // R_HKV, dh)
+    s = np.einsum("bkgd,bktd->bkgt", qg, kc) / np.sqrt(dh)
+    s = np.where(live[:, None, None, :], s, -np.inf)
+    m = s.max(-1)
+    e = np.exp(s - m[..., None])
+    want = np.einsum("bkgt,bktd->bkgd", e / e.sum(-1, keepdims=True),
+                     np.where(live[:, None, :, None], vc, 0))
+    scale = np.abs(want).max()
+    assert got[0].shape == (b, h, dh) and got[0].dtype == np.float32
+    assert np.isfinite(got[0]).all()
+    err = np.abs(got[0].reshape(want.shape) - want).max(-1)     # [b, k, g]
+    if kv_dtype == "f32":
+        assert err.max() <= 1e-5 * scale
+        assert np.abs(got[0] - plain[0]).max() <= 1e-5 * scale
+    else:
+        bound = _two_roundings_bound(qg, kc, vc, live)
+        assert (err <= bound + 1e-5 * scale).all()
+        assert err.max() > 1e-4 * scale     # bf16 operands, no upcast
+        assert (np.abs(got[0] - plain[0]).reshape(want.shape).max(-1)
+                <= bound + 1e-5 * scale).all()
+    if stats:
+        # the same statistics from either form: what ``join_reads`` takes
+        tol = 1e-5 if kv_dtype == "f32" else 2.0 ** -7
+        np.testing.assert_allclose(got[1], m.reshape(b, h), rtol=tol,
+                                   atol=tol)
+        np.testing.assert_allclose(got[1], plain[1], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(got[2], plain[2], rtol=1e-4)

@@ -354,6 +354,68 @@ def test_kv_blocks_read_is_what_the_decode_side_reads_fetch(
         read, capacity)
 
 
+@pytest.mark.parametrize("walked", [True, False])
+@pytest.mark.parametrize("family", ["laguna", "evabyte"])
+def test_ring_blocks_read_is_what_the_window_layers_reads_fetch(
+        monkeypatch, family, walked):
+    """``ring_blocks_read`` of a step's digest and the engine's total
+    (telemetry v24), counted by hand from the lengths each decode-side
+    program was handed: a row that writes position ``t`` walks the
+    blocks from the one that holds its first attendable position —
+    ``t - window + 1`` under the sliding rule (Laguna's toy), the start
+    of ``t``'s own window under the aligned one (EvaByte's) — to block
+    ``t // block``, a bucket's padded row one scratch block, times the
+    window layers; ``ring_blocks_capacity`` is every row's whole ring.
+    Where the ring keeps the plain read (the chip's rule refuses the
+    toys' one-tile rows) the two are equal."""
+    import test_evabyte_lm
+    import test_laguna_lm
+    from distributed_llm_code_samples_tpu.ops import ssm
+    toy = {"laguna": test_laguna_lm, "evabyte": test_evabyte_lm}[family]
+    driver = toy._load(family + "_engine_driver")
+    params = driver._params(toy.TOY, driver.make_weights(toy.TOY, 11))
+    if not walked:
+        monkeypatch.setattr(ssm, "_interpreted", lambda: False)
+    eng = toy.engine(params, slots=4, mbps=8 if family == "laguna" else 2)
+    blk, window = eng.cfg.block_size, eng.spec.window
+    entries, layers = eng.programs.window_blocks, eng.wpool.k.shape[0]
+    assert eng._ring_walks is walked and entries == window // blk + 2
+    for p in toy.prompts_of([40, 5, 21], seed=3):
+        eng.submit(p, 70)
+    launched, launch = [], eng._launch
+
+    def spy(phase, bucket, fn, params, operand, land):
+        if phase != "prefill":
+            launched.append(eng.programs.wire(phase, bucket).unpack(
+                operand)["lengths"])
+        return launch(phase, bucket, fn, params, operand, land)
+
+    eng._launch = spy
+    read = held = padded = 0
+    while eng.active or eng.waiting:
+        del launched[:]
+        eng.step()
+        want = cap = 0
+        for t in launched:          # the position each row writes
+            first = (t // window * window if family == "evabyte"
+                     else np.maximum(t - window + 1, 0))
+            want += int((t // blk - first // blk + 1).sum()) * layers
+            cap += len(t) * entries * layers
+            padded += int((t == 0).sum())
+        digest = eng.flight[-1]
+        assert digest["ring_blocks_capacity"] == cap
+        assert digest["ring_blocks_read"] == (want if walked else cap)
+        read, held = read + want, held + cap
+    # rows past a window boundary, past the ring's first wrap, and a
+    # padded row among them
+    assert eng.lengths.max() == 0 and padded and 0 < read < held
+    assert max(len(p) for p in eng.finished.values()) > max(
+        window, entries * blk)
+    doc = eng.telemetry_record()
+    assert (doc["ring_blocks_read"], doc["ring_blocks_capacity"]) == (
+        read if walked else held, held)
+
+
 def test_record_counts_are_the_engines_counters(lm_params, prompts):
     """One record an executed step, holding what is read and no more:
     the step number and ``tokens_generated`` after the step (what a
@@ -380,9 +442,11 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
                             "window_blocks_live",
                             "summary_rows", "summaries_written",
                             "kv_blocks_read", "kv_blocks_capacity",
+                            "ring_blocks_read", "ring_blocks_capacity",
                             "dispatches", "readbacks", "launches"}
         assert rec["window_rows"] == rec["full_rows"] == 0  # nor window
         assert rec["summary_rows"] == rec["summaries_written"] == 0
+        assert rec["ring_blocks_read"] == rec["ring_blocks_capacity"] == 0
         assert rec["launches"] == eng.launches
         assert rec["state_bytes"] == 0      # no recurrent layer here
         assert rec["expert_rows"] == rec["experts_touched"] == 0  # nor expert

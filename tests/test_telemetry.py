@@ -129,7 +129,12 @@ from distributed_llm_code_samples_tpu.runtime.telemetry import (
 # v23 (PR 41): a fourth paged kind, chunk-summarised attention — the
 # ``engine_step`` record may carry the summaries its launched rows
 # attend over and those they wrote (``STEP_SPAN_CHUNKS``), both or none.
-_PINNED_VERSION = 23
+# v24 (PR 44): a window layer's ring is walked too — the ``engine_step``
+# record may carry the ring's blocks fetched beside the rings' entries
+# (``STEP_SPAN_RING``), both or none.
+_PINNED_VERSION = 24
+_PINNED_STEP_SPAN_RING = frozenset({"ring_blocks_read",
+                                    "ring_blocks_capacity"})
 _PINNED_STEP_SPAN_CHUNKS = frozenset({"summary_rows", "summaries_written"})
 _PINNED_STEP_SPAN_KV = frozenset({"kv_blocks_read", "kv_blocks_capacity"})
 _PINNED_STEP_SPAN_WINDOW = frozenset({
@@ -375,7 +380,7 @@ def test_engine_step_v20_round_trips(tmp_path):
                                                   METRICS_FILENAME))
     assert problems == []
     first, closing, idle = records
-    assert first["schema"] == SCHEMA_VERSION == 23
+    assert first["schema"] == SCHEMA_VERSION == 24
     assert first["dispatches"] == [["prefill", 4], ["decode", 8]]
     assert [p[0] for p in first["phases"] if p[0].endswith(".dispatch")] \
         == [k + ".dispatch" for k, _ in first["dispatches"]]
@@ -442,6 +447,37 @@ def test_engine_step_v22_kv_block_counters(over, ok):
     assert got is ok, reason
     if not ok:
         assert "kv_blocks_read" in reason and "\n" not in reason
+
+
+# 24 rows of 8 layers: 587 positions a row in blocks of 16, of 130 entries
+RING_READS = dict(ring_blocks_read=8 * 24 * 38,
+                  ring_blocks_capacity=8 * 24 * 130)
+
+
+@pytest.mark.parametrize("over,ok", [
+    (RING_READS, True),
+    (dict.fromkeys(RING_READS, 0), True),    # no window layer, or no row
+    (dict(RING_READS, ring_blocks_read=8 * 24 * 130), True),  # a gather's
+    (dict(RING_READS, **KV_READS, **WINDOW_READS), True),
+    ({"ring_blocks_read": 5}, False),        # both or none
+    ({"ring_blocks_capacity": 5}, False),
+    (dict(RING_READS, ring_blocks_read=8 * 24 * 130 + 1), False),
+    (dict(RING_READS, ring_blocks_capacity=-1), False),
+    (dict(RING_READS, ring_blocks_read=7296.0), False),
+])
+def test_engine_step_v24_ring_block_counters(over, ok):
+    """The rings' blocks of an ``engine_step`` record
+    (``STEP_SPAN_RING``): both or none, whole, not negative, no more
+    read than the launched rows' rings hold."""
+    from distributed_llm_code_samples_tpu.runtime.telemetry import (
+        STEP_SPAN_RING)
+    assert frozenset(STEP_SPAN_RING) == _PINNED_STEP_SPAN_RING
+    rec = dict(_engine_step(**over), schema=SCHEMA_VERSION, kind="span",
+               trace_id=None, tenant=None)
+    got, reason = validate_record(rec)
+    assert got is ok, reason
+    if not ok:
+        assert "ring_blocks_read" in reason and "\n" not in reason
 
 
 CHUNK_READS = dict(summary_rows=3072, summaries_written=2)
