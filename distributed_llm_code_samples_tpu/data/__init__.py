@@ -70,7 +70,14 @@ def batch_from_seed(seed: jax.Array, batch_size: int, model_size: int,
                          dloss_dx)
     dloss_dx = jnp.where(inf_p, jnp.asarray(jnp.inf, dloss_dx.dtype),
                          dloss_dx)
-    return x, dloss_dx
+    # The pair is STORED once before anything reads it. Without the
+    # barrier the compiler fuses the draw (threefry + the inverse error
+    # function) into every matrix product that reads x or dloss_dx, and a
+    # producer fused into a product is evaluated again for every pass the
+    # product makes over that operand: at d=8192 a draw that costs ~2 ms
+    # alone cost 28-68 ms inside each of the step's four products (PERF.md
+    # section 6, PR 47). Same values, same key, same compiled step.
+    return jax.lax.optimization_barrier((x, dloss_dx))
 
 
 def mock_data(seeds, batch_size: int, model_size: int, dtype=jnp.float32):

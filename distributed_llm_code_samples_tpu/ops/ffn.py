@@ -56,11 +56,9 @@ def ffn_bwd_saved(dy: jax.Array, w1: jax.Array, w2: jax.Array, x: jax.Array,
     Identical math to ``ffn_bwd`` — ``a = relu(h)`` so the ReLU mask
     ``h > 0`` equals ``a > 0`` — but skips the pre-activation recompute
     (``train_ffns.py:63``), trading one ``[tokens, ffn]`` residual in HBM
-    for one fewer matmul per block backward. Measured throughput-equal to
-    the recompute policy on the v5e-class bench chip (the extra residual
-    traffic costs what the extra matmul costs), so ``ffn_block`` (remat)
-    stays the default for its memory profile; this variant exists for
-    HBM-rich parts where the trade tips the other way.
+    for one fewer matmul per block backward. ``ffn_block`` (remat) stays
+    the default for its memory profile; which is faster has not been
+    read on the step as it is since PR 47 (``parallel/single.py``).
 
     Returns ``(dx, (dw1, dw2))``.
     """

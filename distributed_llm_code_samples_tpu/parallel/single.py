@@ -36,8 +36,7 @@ def make_step(batch_size: int, model_size: int, lr: float = LR,
 
     By default the chain is composed functionally (``ops.stack.stack_grads``):
     each block still runs the hand-written VJP rule via ``custom_vjp``, but
-    residual plumbing is left to XLA — ~10% faster on v5e than restacking
-    activations by hand. ``manual_loop=True`` selects the literal
+    residual plumbing is left to XLA. ``manual_loop=True`` selects the literal
     reference-shaped loops (``stack_fwd``/``stack_bwd``); both paths run the
     same per-block math and agree to float tolerance (allclose-verified in
     tests/test_ops.py — XLA may schedule the two programs differently, so
@@ -50,10 +49,12 @@ def make_step(batch_size: int, model_size: int, lr: float = LR,
     ``remat=False`` saves the post-ReLU activation instead of recomputing
     the ffn1 pre-activation in the backward (``ops.ffn.ffn_block_saved``)
     — one fewer matmul per block backward, same hand-written math, same
-    gradients. Measured on the v5e-class bench chip at the BASELINE
-    config-5 shape the two are throughput-equal (the step is
-    matmul-issue-bound either way), so the default keeps the reference's
-    memory-lean recompute policy (``train_ffns.py:63``).
+    gradients. The default keeps the reference's memory-lean recompute
+    policy (``train_ffns.py:63``). (Every speed comparison of these
+    policies made before PR 47 — "throughput-equal", "~10% faster" — was
+    read on a step that spent over half its time drawing its batch again
+    inside its matrix products, ``data.batch_from_seed``; none has been
+    read again on the step as it is now: ROADMAP A7 / C3.)
 
     ``mixed`` selects the TPU-first precision policy: bf16 matmul
     inputs on the MXU, fp32 params/gradients/accumulation, bf16

@@ -1321,6 +1321,94 @@ def test_train_single_step_compiles_at_paper_width(one_chip):
     assert _total_bytes(compiled) < HBM_V5E
 
 
+def _computations(hlo: str) -> dict:
+    """``name -> (result, body lines)`` of an optimised HLO text."""
+    import re
+    head = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\) -> (.+) \{$")
+    comps, lines = {}, None
+    for line in hlo.splitlines():
+        m = head.match(line)
+        if m:
+            lines = []
+            comps[m.group(1)] = (m.group(2), lines)
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    return comps
+
+
+def _reach(comps: dict, name: str) -> set:
+    """``name`` and every computation it calls, nested calls followed."""
+    import re
+    seen, todo = set(), [name]
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += re.findall(r"(?:calls|to_apply|body|condition)="
+                               r"%([\w.\-]+)", "\n".join(comps[name][1]))
+    return seen
+
+
+def _holds(comps: dict, name: str, *ops: str) -> bool:
+    return any(f" {op}(" in line for line in comps[name][1] for op in ops)
+
+
+@pytest.mark.parametrize("guarded", [False, True], ids=["plain", "guarded"])
+@pytest.mark.parametrize("layers", [1, 2])
+def test_train_single_step_draws_its_batch_once(one_chip, layers, guarded):
+    """``ffn-d8192.train-single``'s step at the cell's own arguments
+    (d=8192, 8x1024 tokens, one step a call), and the same at two layers
+    and under the guarded scan: NO fusion that holds a matrix product
+    reaches the batch's draw (threefry's ``xor``; a producer fused into
+    a product is evaluated again for every pass the product makes over
+    that operand: four of four did until PR 47, 28-68 ms each a step),
+    exactly ONE fusion outside them holds it, and the program leaves no
+    work out: its products are the ``6 x layers - 2`` of 2.199e12
+    multiply-adds that ``benchmark/flops.py`` counts."""
+    import re
+    from benchmark import harness
+    from benchmark.tests.trainer_products_on_chip import products
+    from distributed_llm_code_samples_tpu import LR
+    from distributed_llm_code_samples_tpu.parallel import single
+    from distributed_llm_code_samples_tpu.runtime import guardrails
+    config = dict(harness.load_cell("ffn-d8192.train-single")["config"],
+                  layers=layers)
+    d, ffn = config["model_size"], config["ffn_size"]
+    tokens = config["batch_size"] * config["seq_len"]
+    params = _shapes_of(jax.eval_shape(
+        lambda: init_ffn_stack(jax.random.PRNGKey(7), d, layers)), one_chip)
+    seeds = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    static = (tokens, d, LR, True, False, False, False, None, False, 1)
+    if guarded:
+        guard = guardrails.GuardrailConfig()
+        gstate = _shapes_of(jax.eval_shape(
+            lambda: guardrails.init_state(guard)), one_chip)
+        compiled = single._run_guarded.lower(params, gstate, seeds, *static,
+                                             guard).compile()
+    else:
+        compiled = single._run.lower(params, seeds, *static).compile()
+    hlo = compiled.as_text()
+    comps = _computations(hlo)
+    fusions = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", hlo))
+    with_product = {f for f in fusions
+                    if _holds(comps, f, "convolution", "dot")}
+    redrawn = {f for f in with_product
+               if any(_holds(comps, c, "xor") for c in _reach(comps, f))}
+    assert not redrawn, sorted(redrawn)
+    inside = set().union(*(_reach(comps, f) for f in with_product))
+    draws = [comps[f][0] for f in fusions - inside
+             if _holds(comps, f, "xor") and f"[{tokens},{d}]" in comps[f][0]]
+    assert len(draws) == 1 and draws[0].count(f"[{tokens},{d}]") == 2, draws
+    found = products(hlo)
+    assert len(found) == len(with_product) == 6 * layers - 2
+    assert all(p["macs"] == tokens * d * ffn for p in found)
+    counted = harness.driver_module(config).flops_per_token(config) * tokens
+    assert counted == 2.0 * sum(p["macs"] for p in found)
+    assert _total_bytes(compiled) < HBM_V5E
+
+
 # ---------------------------------------------------------------------
 # a described v5e-8: the cross-chip schedules
 

@@ -8,7 +8,8 @@ import pytest
 
 from distributed_llm_code_samples_tpu import DLOSS_DX_COEF
 from distributed_llm_code_samples_tpu.data import (
-    batch_from_seed, mock_data, make_seed_schedule, shard_seeds_strided)
+    POISON_INF_BIT, POISON_NAN_BIT, batch_from_seed, mock_data,
+    make_seed_schedule, shard_seeds_strided)
 
 
 def test_batch_deterministic():
@@ -136,3 +137,107 @@ def test_real_text_training_loss_falls():
         batch_fn=lambda s: text_batch_from_seed(s, B, T))
     loss1 = float(lm_loss(params, etok, etgt, H_))
     assert loss1 < loss0 - 0.5, (loss0, loss1)
+
+
+# ---------------------------------------------------------------------
+# PR 47: the pair is returned from behind ``lax.optimization_barrier``
+# (stored once, where a fused draw is repeated inside every matrix
+# product that reads it). The barrier changes no value anywhere the
+# draw is used.
+
+def _plain_draw(seed, batch_size, model_size):
+    """``batch_from_seed`` restated with no barrier: the same fold-in,
+    split and two normal draws, the poison bits by hand."""
+    seed = jnp.asarray(seed)
+    key = jax.random.fold_in(jax.random.PRNGKey(0),
+                             jnp.bitwise_and(seed, jnp.int32(~(3 << 28))))
+    kx, kd = jax.random.split(key)
+    x = jax.random.normal(kx, (batch_size, model_size))
+    dy = DLOSS_DX_COEF * jax.random.normal(kd, (batch_size, model_size))
+    dy = jnp.where(jnp.bitwise_and(seed, jnp.int32(1 << 29)) != 0,
+                   jnp.float32(jnp.nan), dy)
+    dy = jnp.where(jnp.bitwise_and(seed, jnp.int32(1 << 28)) != 0,
+                   jnp.float32(jnp.inf), dy)
+    return x, dy
+
+
+def _one_device_shard_map(draw):
+    from jax.sharding import Mesh, PartitionSpec as P
+    mesh = Mesh(np.array(jax.devices()[:1]), ("one",))
+    per_seed = jax.shard_map(draw, mesh=mesh, in_specs=P(), out_specs=P())
+    return lambda seeds: jax.lax.map(per_seed, seeds)
+
+
+def _seed_by_seed(draw):
+    return lambda seeds: jax.tree.map(lambda *rows: jnp.stack(rows),
+                                      *[draw(s) for s in seeds])
+
+
+_SEEDS = jnp.asarray([0, 1, 77_777, 99_999], dtype=jnp.int32)
+_WAYS = {
+    "eager": _seed_by_seed,
+    "jit": lambda draw: _seed_by_seed(jax.jit(draw)),
+    "scan": lambda draw: jax.jit(lambda seeds: jax.lax.scan(
+        lambda c, s: (c, draw(s)), 0, seeds)[1]),
+    "vmap": lambda draw: jax.jit(jax.vmap(draw)),
+    "shard_map": lambda draw: jax.jit(_one_device_shard_map(draw)),
+}
+
+
+@pytest.mark.parametrize("way", sorted(_WAYS))
+def test_batch_is_the_plain_draw_bit_for_bit(way):
+    """Each way of running the draw gives what the same way gives for
+    its plain restatement (a compiled ``0.1 * normal`` rounds its last
+    bit otherwise than the eager one, with or without the barrier), and
+    every way gives the eager ``x``."""
+    got = _WAYS[way](lambda s: batch_from_seed(s, 8, 16))(_SEEDS)
+    want = _WAYS[way](lambda s: _plain_draw(s, 8, 16))(_SEEDS)
+    assert got[0].shape == got[1].shape == (len(_SEEDS), 8, 16)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    for i, s in enumerate(_SEEDS):
+        np.testing.assert_array_equal(np.asarray(got[0][i]),
+                                      np.asarray(_plain_draw(s, 8, 16)[0]))
+
+
+@pytest.mark.parametrize("way", ["eager", "scan", "vmap"])
+@pytest.mark.parametrize("bit, bad", [(POISON_NAN_BIT, np.isnan),
+                                      (POISON_INF_BIT, np.isposinf)],
+                         ids=["nan", "inf"])
+def test_poisoned_seed_keeps_x_and_poisons_dloss_dx(bit, bad, way):
+    seeds = jnp.asarray([5, 5 | bit], dtype=jnp.int32)
+    x, dy = _WAYS[way](lambda s: batch_from_seed(s, 8, 16))(seeds)
+    np.testing.assert_array_equal(np.asarray(x[0]), np.asarray(x[1]))
+    assert np.isfinite(np.asarray(dy[0])).all()
+    assert bad(np.asarray(dy[1])).all()
+    want = _WAYS[way](lambda s: _plain_draw(s, 8, 16))(seeds)
+    for g, w in zip((x, dy), want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("policy", [
+    {}, {"remat": False}, {"mixed": True}, {"manual_loop": True},
+    {"accum": 2}, {"unroll": False}],
+    ids=["recompute", "saved", "mixed", "manual_loop", "accum2", "scan"])
+def test_single_step_is_the_step_over_a_stored_batch(monkeypatch, policy):
+    """One ``single.make_step`` step gives bitwise the parameters the
+    same step gives when its batch is handed to it as two stored arrays
+    (the plain draw's, made outside the program): what the step was
+    before the barrier, with nothing left to fuse."""
+    from distributed_llm_code_samples_tpu.models import init_ffn_stack
+    from distributed_llm_code_samples_tpu.parallel import single
+    tokens, d, seed = 32, 16, jnp.int32(4711)
+    params = init_ffn_stack(jax.random.PRNGKey(3), d, 2)
+    step = single.make_step(tokens, d, lr=0.1, **policy)
+    got = jax.jit(step)(params, seed)
+
+    def over_stored(params, x, dy):
+        monkeypatch.setattr(single, "batch_from_seed",
+                            lambda *_: (x, dy))
+        return step(params, seed)
+
+    x, dy = jax.jit(lambda s: _plain_draw(s, tokens, d))(seed)
+    want = jax.jit(over_stored)(params, x, dy)
+    for g, w, p in zip(got, want, params):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        assert not np.array_equal(np.asarray(g), np.asarray(p))
