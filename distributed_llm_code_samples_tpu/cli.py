@@ -463,7 +463,7 @@ def _train(args, argv) -> int:
         return 2
     if args.head != "oracle" and args.method not in (9, 11, 12, 13):
         # same pattern as the --comm guard: inapplicable flags exit 2
-        # instead of silently running the oracle head (ADVICE r4)
+        # instead of silently running the oracle head
         print("error: --head fused applies to --method 11 (LM TP), "
               "12 (MoE LM EP), 13 (sequence-parallel LM), or the "
               "--method 9 sweep (which verifies them)", file=sys.stderr)

@@ -2601,9 +2601,12 @@ class DecodeEngine:
         window layer's ring, the blocks from its window's first
         position to its last (``paged.ring_start``: the rule the kernel
         is handed); a padded row the scratch block of either; a gather
-        reads every row's whole table whatever it holds."""
+        reads every row's whole table whatever it holds. A verify
+        program's dead sub-steps past a row's last position count the
+        table's capacity, no more (only ``reads > 1`` can pass it)."""
         blk = self.cfg.block_size
-        n = self.lengths[ready][:, None] + 1 + np.arange(reads)
+        n = np.minimum(self.lengths[ready][:, None] + 1 + np.arange(reads),
+                       self.capacity)
         padded = reads * (b - len(ready))
         ring = ring_held = 0
         if self.wpool is not None:

@@ -2488,8 +2488,7 @@ class FleetRouter:
             "prefix_routed_hit_blocks": self.prefix_routed_hit_blocks,
             # the migration-stall surface (live moves only): blocks +
             # SERIALIZED wire bytes shipped and the per-move wall-clock
-            # list's summary (bench_decode.py's fleet_handoff_* rows
-            # read the raw accumulators off the router instead)
+            # list's summary
             "handoff_blocks": self.handoff_blocks,
             "handoff_bytes": self.handoff_bytes,
             "wire_rejects": self.wire_rejects,

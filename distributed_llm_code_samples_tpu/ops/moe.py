@@ -274,7 +274,9 @@ def moe_layer_gather(wg: jax.Array, w1: jax.Array, w2: jax.Array,
     int32 scatters for the slot bookkeeping. The third dispatch
     formulation next to ``moe_layer`` (one-hot einsums, O(k*T^2*cf*d)
     MXU work) and ``moe_layer_scatter`` (scatter-add rows, serialized
-    on TPU); bench_moe.py records which one the chip defends."""
+    on TPU). No cell of ``BENCHMARK.json`` trains through any of the
+    three, so which one the chip defends is unmeasured (ROADMAP W2 /
+    C3)."""
     n_experts = w1.shape[0]
     t = x.shape[0]
     cap = (expert_capacity(t, n_experts, capacity_factor)

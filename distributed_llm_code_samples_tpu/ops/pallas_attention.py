@@ -31,10 +31,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # the shared tile/precision helpers live in pallas_ffn (the canonical
-# module; imports flow attention -> ffn only, so there is no cycle) —
-# _env_block reads tile envs at TRACE time so on-chip sweeps can vary
-# them between jax.clear_caches() points without re-execing
-from .pallas_ffn import _env_block, _mxu, _pick_block
+# module; imports flow attention -> ffn only, so there is no cycle)
+from .pallas_ffn import _mxu, _pick_block
 from .pallas_ffn import _resolve_mxu_bf16 as _resolve_mxu_bf16_base
 
 _NEG = -1e30
@@ -42,28 +40,15 @@ _LANES = 128
 _Q_QUANTUM = 8
 
 
-# Default tile sizes, env-overridable for on-chip sweeps. r04 swept on
-# the v5e chip (T=8192, H8, dh64): 128x128 tiles ran the whole step at
-# ~7 TFLOP/s — the online-softmax VPU work (exp, rescale, stats) per
-# tile was unamortized against dh=64 matmuls. 1024x1024 forward tiles
-# reach 49.6 TF/s; the backward peaks near 512x512 (53.6 TF/s) and
-# larger tiles only add VMEM pressure (2048x1024 fails to compile).
-# `_pick_block` caps every block at the actual T, so small/test shapes
-# are unaffected.
-def _DEF_BQ():
-    return _env_block("FLASH_BLOCK_Q", 1024)
-
-
-def _DEF_BK():
-    return _env_block("FLASH_BLOCK_K", 1024)
-
-
-def _DEF_BWD_BQ():
-    return _env_block("FLASH_BWD_BLOCK_Q", 512)
-
-
-def _DEF_BWD_BK():
-    return _env_block("FLASH_BWD_BLOCK_K", 512)
+# Default tile sizes, as swept on the v5e chip (T=8192, H8, dh64): 128x128
+# tiles ran the whole step at ~7 TFLOP/s — the online-softmax VPU work
+# (exp, rescale, stats) per tile was unamortized against dh=64 matmuls.
+# 1024x1024 forward tiles reach 49.6 TF/s; the backward peaks near
+# 512x512 (53.6 TF/s) and larger tiles only add VMEM pressure (2048x1024
+# fails to compile). `_pick_block` caps every block at the actual T, so
+# small/test shapes are unaffected.
+_BLOCK_Q = _BLOCK_K = 1024
+_BWD_BLOCK_Q = _BWD_BLOCK_K = 512
 
 
 def _resolve_mxu_bf16(mxu_bf16, interpret: bool) -> bool:
@@ -156,8 +141,8 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     T, dh = q.shape
     scale = 1.0 / (dh ** 0.5)
     _mxu_bf16 = _resolve_mxu_bf16(mxu_bf16, interpret)
-    bq = _pick_block(T, block_q or _DEF_BQ(), _Q_QUANTUM)
-    bk = _pick_block(k.shape[0], block_k or _DEF_BK(), _Q_QUANTUM)
+    bq = _pick_block(T, block_q or _BLOCK_Q, _Q_QUANTUM)
+    bk = _pick_block(k.shape[0], block_k or _BLOCK_K, _Q_QUANTUM)
     grid = (T // bq, k.shape[0] // bk)
     y, lse = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
@@ -262,8 +247,8 @@ def flash_attention_bwd(dy: jax.Array, q, k, v, y, lse, *,
     Tk = k.shape[0]
     scale = 1.0 / (dh ** 0.5)
     _mxu_bf16 = _resolve_mxu_bf16(mxu_bf16, interpret)
-    bq = _pick_block(T, block_q or _DEF_BWD_BQ(), _Q_QUANTUM)
-    bk = _pick_block(Tk, block_k or _DEF_BWD_BK(), _Q_QUANTUM)
+    bq = _pick_block(T, block_q or _BWD_BLOCK_Q, _Q_QUANTUM)
+    bk = _pick_block(Tk, block_k or _BWD_BLOCK_K, _Q_QUANTUM)
     # D_i = rowsum(dy * y): the only softmax statistic the tiles can't
     # rebuild locally; elementwise, computed once outside the kernels
     d = jnp.sum(dy.astype(jnp.float32) * y.astype(jnp.float32),

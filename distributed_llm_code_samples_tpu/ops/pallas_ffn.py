@@ -36,13 +36,14 @@ hide.
 flash kernels 7→41 TF/s on chip in r4): every MXU operand is cast to
 bf16 by default on the compiled path (``mxu_bf16`` — f32 operands make
 Mosaic emit multi-pass dots, ~3x the single bf16 pass XLA's default f32
-precision lowers to), and the block sizes are sweepable
-(``bench.py``'s ``BENCH_PALLAS_SWEEP=1`` tries the tile grid on chip
-and reports the best). The 18-vs-14 FLOP structure is inherent to the
-3-kernel split, so the arithmetic ceiling is 14/18 ≈ 0.78 of an
-equally-efficient XLA — ``pallas_vs_xla`` ≥ 0.9 is only reachable if
-the kernels beat XLA's per-FLOP efficiency; ``bench.py`` records the
-live ratio and this docstring carries the measured verdict either way.
+precision lowers to), and the default block sizes are the values an
+on-chip sweep of the tile grid settled on (``_BLOCK_T``, ``_BLOCK_F``,
+``_DW_BLOCK_F``; a caller that wants another passes ``block_t`` /
+``block_f``). The 18-vs-14 FLOP structure is inherent to the 3-kernel
+split, so the arithmetic ceiling is 14/18 ≈ 0.78 of an
+equally-efficient XLA. No cell of ``BENCHMARK.json`` runs ``--pallas``:
+the ratio above is the last measured one, and ROADMAP C4 is the item
+that deletes this module.
 """
 
 from __future__ import annotations
@@ -98,13 +99,11 @@ _TOKEN_QUANTUM = 8
 _FFN_QUANTUM = 128
 
 
-def _env_block(name: str, default: int) -> int:
-    """Tile-size default, env-overridable so bench.py's on-chip sweep
-    can tune without replumbing the trainers (the sweep calls
-    ``jax.clear_caches()`` between points — the envs are read at trace
-    time)."""
-    v = os.environ.get(name)
-    return int(v) if v else default
+# default tiles (token x ffn): the swept values on the v5e; the weight-grad
+# kernel's ffn tile is smaller for the reason its docstring gives
+_BLOCK_T = 256
+_BLOCK_F = 512
+_DW_BLOCK_F = 256
 
 
 def _fwd_kernel(x_ref, w1_ref, w2_ref, y_ref, acc_ref, *, mxu_bf16):
@@ -137,9 +136,9 @@ def ffn_fwd_pallas(w1: jax.Array, w2: jax.Array, x: jax.Array, *,
     accumulation throughout)."""
     T, d = x.shape
     ffn = w1.shape[0]
-    bt = _pick_block(T, block_t or _env_block("PALLAS_FFN_BT", 256),
+    bt = _pick_block(T, block_t or _BLOCK_T,
                      _TOKEN_QUANTUM)
-    bf = _pick_block(ffn, block_f or _env_block("PALLAS_FFN_BF", 512),
+    bf = _pick_block(ffn, block_f or _BLOCK_F,
                      _FFN_QUANTUM)
     grid = (T // bt, ffn // bf)
     return pl.pallas_call(
@@ -195,9 +194,9 @@ def ffn_bwd_dx_pallas(dy: jax.Array, w1: jax.Array, w2: jax.Array,
     """Input gradient ``dx = (relu'(x w1^T) * (dy w2)) w1`` fused."""
     T, d = x.shape
     ffn = w1.shape[0]
-    bt = _pick_block(T, block_t or _env_block("PALLAS_FFN_BT", 256),
+    bt = _pick_block(T, block_t or _BLOCK_T,
                      _TOKEN_QUANTUM)
-    bf = _pick_block(ffn, block_f or _env_block("PALLAS_FFN_BF", 512),
+    bf = _pick_block(ffn, block_f or _BLOCK_F,
                      _FFN_QUANTUM)
     grid = (T // bt, ffn // bf)
     return pl.pallas_call(
@@ -262,9 +261,9 @@ def ffn_bwd_dw_pallas(dy: jax.Array, w1: jax.Array, w2: jax.Array,
     256 compiles and runs)."""
     T, d = x.shape
     ffn = w1.shape[0]
-    bt = _pick_block(T, block_t or _env_block("PALLAS_FFN_BT", 256),
+    bt = _pick_block(T, block_t or _BLOCK_T,
                      _TOKEN_QUANTUM)
-    bf = _pick_block(ffn, block_f or _env_block("PALLAS_FFN_DW_BF", 256),
+    bf = _pick_block(ffn, block_f or _DW_BLOCK_F,
                      _FFN_QUANTUM)
     grid = (ffn // bf, T // bt)  # token axis is the reduction
     return pl.pallas_call(

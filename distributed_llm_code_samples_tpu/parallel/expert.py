@@ -298,8 +298,8 @@ def train_moe_dense(params: MoEStackParams, seeds, batch_size: int,
     ``dispatch``: ``"dense"`` one-hot einsum movement, ``"scatter"``
     (``ops.moe.moe_layer_scatter`` — same math, O(T*d) scatter-add
     movement), or ``"gather"`` (``ops.moe.moe_layer_gather`` —
-    gather-only movement in both directions; see bench_moe.py for the
-    measured verdict).
+    gather-only movement in both directions). No cell runs an expert
+    trainer: the choice among the three is unmeasured (ROADMAP W2).
     """
     if batch_size % n_groups:
         raise ValueError(f"batch_size={batch_size} not divisible by "

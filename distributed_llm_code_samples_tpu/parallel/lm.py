@@ -612,7 +612,7 @@ def train_lm_tp(params: LMParams, seeds, batch_size: int, model_size: int,
     # vma-off reduction contract on EVERY backend); interpret is a
     # separate, backend-only decision — the fused head must still run
     # the COMPILED kernels on TPU. interpret=None lets _make_tp_step's
-    # backend fallback decide (ADVICE r4: tying it to `not check` ran
+    # backend fallback decide (tying it to `not check` ran
     # the Pallas head in interpret mode on real TPU).
     step = _make_tp_step(batch_size, model_size, seq_len, h_local,
                          params.vocab, lr, resolve_attn(attn_impl),
@@ -706,7 +706,7 @@ def tp_shard_params(params: LMParams, mesh,
     """Lay the LM params out in the Megatron decode layout (vocab/head
     sharded) ONCE. ``tp_generate``/``tp_sample`` and the decode engine
     detect the layout and skip their per-call reshard copy, so repeat
-    decodes (serving loops, ``bench_decode``) pay neither a retrace
+    decodes (serving loops) pay neither a retrace
     (the program is cached) nor a per-call host-side param copy. With
     ``n_heads`` (the serving engine's ``--tp`` set-up) the layout's
     divisibility is checked first."""
@@ -752,8 +752,8 @@ def _tp_decode_program(mesh, n_new: int, n_heads: int, v_local: int,
                        temperature: float = 0.0):
     """Build (once per static decode config) the jitted shard_map decode
     program ``(sharded_params, prompt) -> tokens``. jax.jit's own cache
-    then handles shape-polymorphic re-traces; callers timing repeat
-    decodes (bench_decode) hit the compiled program directly.
+    then handles shape-polymorphic re-traces; repeat decodes hit the
+    compiled program directly.
     ``temperature > 0`` switches the pick from greedy to an EXACT
     categorical sample via the Gumbel-max trick: each shard perturbs its
     local ``logits/T`` with iid Gumbel noise (key folded on

@@ -64,8 +64,9 @@ def make_step(batch_size: int, model_size: int, lr: float = LR,
     (``ops.ffn.ffn_block_mixed_remat``); ``remat=False`` saves the bf16
     post-ReLU (``ops.ffn.ffn_block_mixed``). The MXU time is identical to
     f32 either way (default-precision f32 matmuls are single bf16
-    passes); the halved stash bytes are the single-chip lever, and
-    bench.py measures which residual policy wins.
+    passes); the halved stash bytes are the single-chip lever. Which
+    residual policy wins is unmeasured: the one trainer cell,
+    ``ffn-d8192.train-single``, runs the default (ROADMAP A7 / C3).
 
     ``accum`` splits the step's tokens into that many gradient-
     accumulation chunks (``lax.scan``, summed grads, one update): peak

@@ -4,7 +4,7 @@ shares.
 The reference's observability surface is a rank-0 chrome trace plus
 ad-hoc wall-clock prints (``train_ffns.py:129-141, :378-382``). This
 repo had grown real instrumentation — collective counting
-(``utils/hlo.py``), trace span analysis (``bench_trace.py``),
+(``utils/hlo.py``), trace span analysis (``utils/trace_analysis.py``),
 supervise's per-attempt JSONL (``runtime/failure.py``) — but each piece
 was an island with its own format. This module is the common spine
 (MegaScale's in-depth per-step observability stance): one
@@ -654,9 +654,10 @@ ALERT_DETECTOR_REQUIRED = {
 }
 
 # Non-step record kinds the stream also carries: run headers ("meta"),
-# recovery/chaos/checkpoint events ("event"), bench measurement rows
-# ("bench" — bench.py's per-measurement plumbing rides the same
-# writer), the self-healing kinds ("anomaly", "rollback"), and the
+# recovery/chaos/checkpoint events ("event"), measurement rows
+# ("bench": no writer is left in the tree, ROADMAP C2; the benchmark's
+# numbers go to the driver's ledger), the self-healing kinds
+# ("anomaly", "rollback"), and the
 # serving engine's "decode" cadence + "request" lifecycle + "span"
 # per-request phase records.
 RECORD_KINDS = ("step", "meta", "event", "bench", "anomaly", "rollback",
@@ -683,8 +684,8 @@ REQUIRED_KEYS = {
 
 # bf16 peak matmul FLOP/s by chip generation (public spec sheets; the
 # default f32 jnp matmul on TPU lowers to single-pass bf16 MXU ops, so
-# bf16 peak is the honest MFU denominator — bench.py's convention, now
-# shared). Unknown kinds (CPU, new chips) return None: an honest null
+# bf16 peak is the honest MFU denominator of the step records' `mfu`;
+# the benchmark keeps its own table, benchmark/peaks.json). Unknown kinds (CPU, new chips) return None: an honest null
 # MFU beats a guessed one in a persistent artifact.
 PEAK_BF16_FLOPS = {
     "v2": 45e12, "v3": 123e12, "v4": 275e12,
@@ -707,19 +708,18 @@ def ffn_model_flops(tokens: int, model_size: int, n_layers: int,
                     ffn_dim: int | None = None) -> int:
     """Hand-counted model matmul FLOPs of ONE training step of the
     reference FFN stack: fwd 2 matmuls = 4Tdf, bwd 4 matmuls = 8Tdf per
-    layer (bench.py's 12Tdf convention — the recompute policy's extra
-    executed matmul is HFU, never MFU)."""
+    layer (the 12Tdf convention — the recompute policy's extra
+    executed matmul is HFU, never MFU; ``benchmark/flops.py`` counts
+    the products a step has to run, two fewer a stack)."""
     f = 4 * model_size if ffn_dim is None else ffn_dim
     return 12 * tokens * model_size * f * n_layers
 
 
 def transformer_model_flops(tokens: int, model_size: int, n_layers: int,
                             seq_len: int) -> int:
-    """Per-step model FLOPs of the pre-LN transformer family (bench.py's
-    families convention): attention projections 8Td^2, scores+AV 2T^2d
-    (causal halving is applied by bench_attention's convention only for
-    its causal benchmark — the trainer accounting here matches
-    bench.py's families section), FFN 16Td^2; fwd 1x + bwd 2x."""
+    """Per-step model FLOPs of the pre-LN transformer family:
+    attention projections 8Td^2, scores+AV 2T^2d (no causal halving),
+    FFN 16Td^2; fwd 1x + bwd 2x."""
     b = tokens // seq_len
     per_layer = (8 * seq_len * model_size ** 2
                  + 2 * seq_len ** 2 * model_size
@@ -856,8 +856,9 @@ class TelemetryWriter:
         self._put(rec)
 
     def bench(self, record: dict) -> None:
-        """Enqueue one bench measurement row (bench.py's per-measurement
-        plumbing — metric name, value, unit, shape)."""
+        """Enqueue one measurement row (metric name, value, unit,
+        shape). Nothing in the tree calls it since the bench scripts
+        went (ROADMAP C2)."""
         rec = dict(record)
         rec.setdefault("t", time.time())
         rec["kind"] = "bench"

@@ -1,11 +1,11 @@
-"""Shared on-chip timing discipline for the bench scripts.
+"""On-chip timing discipline: fence, then read the clock.
 
 JAX returns before the device finishes, so a timing without a fence
-measures the enqueue. Every bench (a) runs its whole schedule as ONE
+measures the enqueue. A caller (a) runs its whole schedule as ONE
 compiled program (``lax.scan`` over steps) and (b) fences the timed
-window with ``jax.block_until_ready`` on the program's outputs. This
-module is the single home of that methodology so bench.py /
-bench_moe.py / bench_decode.py cannot drift apart.
+window with ``jax.block_until_ready`` on the program's outputs. One
+user is left, ``train_real_text.py``; the benchmark (``benchmark/``)
+times its windows itself and imports nothing from here (ROADMAP C2).
 """
 
 from __future__ import annotations

@@ -1,8 +1,7 @@
 """Chrome-trace span analysis, keyed on the framework's named scopes.
 
-``bench_trace.py`` grew the first span parser (comm-vs-compute interval
-intersection over a captured Perfetto/chrome trace); this module lifts
-it into an importable library and extends it with the **named-scope
+A span parser (comm-vs-compute interval intersection over a captured
+Perfetto/chrome trace) as an importable library, with the **named-scope
 region map**: every parallel strategy annotates its step with
 ``jax.named_scope`` regions (see ``SCOPES`` below), those names flow
 into XLA op metadata and — on hardware traces — into the span names the
@@ -73,8 +72,7 @@ SCOPES = {
 # per-strategy four-role contract below applies to the training keys)
 SERVING_SCOPES = ("decode", "prefill")
 
-# span-name keywords (lowercased substring match) — the bench_trace.py
-# classifiers, shared
+# span-name keywords (lowercased substring match)
 COMM_KEYWORDS = ("all-gather", "all_gather", "reduce-scatter",
                  "reduce_scatter", "all-reduce", "all_reduce",
                  "copy-start", "collective-permute", "dma")

@@ -337,3 +337,46 @@ def test_token_budget_defers_and_never_deadlocks(lm_params, tmp_path):
               and r["event"] == "admitted"]
     order = [r["uid"] for r in admits]
     assert order.index(2) < order.index(1), order
+
+
+# ---------------------------------------------------------------------------
+# CLI surface
+
+
+def test_generate_cli_autoscale_qos_policy(tmp_path, capsys):
+    """`generate --autoscale --qos --policy` over a bursty two-tenant
+    trace through a two-engine fleet: the controller scales up and sheds
+    nothing, the router stream holds its schema-valid autoscale records,
+    the payload names the policy, and `report --slo` prints attainment
+    by tenant and by policy."""
+    import json
+
+    from distributed_llm_code_samples_tpu.decode.generate_cli import (
+        generate_main)
+    from distributed_llm_code_samples_tpu.report import report_main
+    mdir = str(tmp_path / "m")
+    assert generate_main([
+        "-d", "32", "-l", "2", "--heads", "4", "--vocab", "64",
+        "--max_seq_len", "64", "--block_size", "8", "--prefill_chunk",
+        "4", "--log_every", "2", "--fleet", "2", "--max_slots", "2",
+        "--autoscale", "min=2,max=3,up=3,down=1,hysteresis=2,cooldown=6",
+        "--qos", "discipline=wfq,weights=a:2;b:1", "--policy", "wfq",
+        "--trace_gen", "n=10,arrival=bursty:40:0.2:0.3,"
+        "plen=zipf:1.7:3:12,max_new=4,tenants=a:3;b:1,seed=5",
+        "--metrics_dir", mdir]) == 0
+    run = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert not run["failed"] and run["shed"] == 0
+    assert run["policy"] == "wfq"
+    assert run["autoscale"]["scale_ups"] >= 1, run["autoscale"]
+    recs, problems = read_metrics(
+        os.path.join(mdir, "router", METRICS_FILENAME))
+    assert not problems, problems
+    auto = [r for r in recs if r["kind"] == "autoscale"]
+    assert any(r["event"] == "scale_up" for r in auto), auto
+    assert all(validate_record(r)[0] for r in auto)
+    dirs = [os.path.join(mdir, d) for d in sorted(os.listdir(mdir))
+            if os.path.isdir(os.path.join(mdir, d)) and d != "spool"]
+    assert report_main(dirs + ["--slo", "100:0.5"]) == 0
+    text = capsys.readouterr().out
+    assert "tenant a" in text and "tenant b" in text
+    assert "policy wfq" in text and "goodput" in text

@@ -1,6 +1,11 @@
 """Data-layer tests: deterministic seeds-as-dataset semantics
 (reference ``train_ffns.py:144-151, :182, :350-360``)."""
 
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -241,3 +246,39 @@ def test_single_step_is_the_step_over_a_stored_batch(monkeypatch, policy):
     for g, w, p in zip(got, want, params):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
         assert not np.array_equal(np.asarray(g), np.asarray(p))
+
+
+def test_train_real_text_contract(tmp_path):
+    """``train_real_text.py`` as a child process at toy sizes: falling
+    train AND held-out loss curves over a held-out tail no training
+    window samples, the best held-out loss as the headline, a sampled
+    continuation, and the artifact file."""
+    from conftest import load_scaled_timeout
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    art = str(tmp_path / "textlm.json")
+    env = dict(os.environ, BENCH_PLATFORM="cpu", TEXTLM_STEPS="20",
+               TEXTLM_SEGMENTS="2", TEXTLM_D="32", TEXTLM_LAYERS="1",
+               TEXTLM_HEADS="2", TEXTLM_SEQ="32", TEXTLM_BATCH="4",
+               TEXTLM_ARTIFACT=art)
+    env.pop("JAX_PLATFORMS", None)
+    r = subprocess.run([sys.executable, "train_real_text.py"],
+                       capture_output=True, text=True, env=env, cwd=repo,
+                       timeout=load_scaled_timeout(900))
+    assert r.returncode == 0, r.stdout + r.stderr
+    payload = json.loads([ln for ln in r.stdout.splitlines()
+                          if ln.startswith("{")][-1])
+    assert payload["metric"] == "real_text_lm_best_holdout_loss"
+    curve = payload["loss_curve"]
+    assert curve[0]["step"] == 0 and curve[-1]["step"] == 20
+    best = min(curve[1:], key=lambda p: p["holdout_loss"])
+    assert payload["value"] == best["holdout_loss"]
+    assert payload["value"] < payload["initial_holdout_loss"], curve
+    assert payload["best_step"] == best["step"]
+    assert curve[-1]["train_loss"] < curve[0]["train_loss"], curve
+    assert "generalization_gap" in payload
+    assert "final_holdout_loss" in payload
+    assert "warmup_cosine" in payload["schedule"]
+    assert payload["train_bytes"] + payload["holdout_bytes"] \
+        == payload["corpus_bytes"]
+    assert isinstance(payload["sample"], str) and len(payload["sample"])
+    assert os.path.exists(art)

@@ -386,8 +386,9 @@ def test_decode_records_schema_valid(lm_params, prompts, tmp_path):
 
 def test_generate_cli_end_to_end(tmp_path):
     """The `generate` subcommand end to end in-process: two staggered
-    prompts, metrics stream, schema-valid decode records, rc 0 — the
-    tier1.sh decode smoke's in-suite twin."""
+    prompts, a metrics stream of schema-valid decode and span records
+    that counts every token, rc 0, and `report --audit` holding over
+    the stream."""
     import distributed_llm_code_samples_tpu.cli as cli
     from distributed_llm_code_samples_tpu.runtime.telemetry import (
         METRICS_FILENAME, read_metrics)
@@ -401,9 +402,12 @@ def test_generate_cli_end_to_end(tmp_path):
     records, problems = read_metrics(os.path.join(mdir,
                                                   METRICS_FILENAME))
     assert problems == []
-    assert [r for r in records if r["kind"] == "decode"]
+    decs = [r for r in records if r["kind"] == "decode"]
+    assert decs and decs[-1]["tokens_generated"] == 2 * 5
+    assert [r for r in records if r["kind"] == "span"]
     assert any(r["kind"] == "meta" and r.get("subcommand") == "generate"
                for r in records)
+    assert cli.main(["report", mdir, "--audit"]) == 0
 
 
 def test_generate_cli_rejects_bad_flags(capsys):

@@ -657,3 +657,53 @@ GEN_BASE = ["--prompt_lens", "3", "--max_new", "2", "-d", "32", "-l",
 ])
 def test_cli_deploy_flag_rejections(extra):
     assert _gen(GEN_BASE + extra) == 2
+
+
+def test_generate_cli_rolls_a_trainer_checkpoint(tmp_path, capsys):
+    """The trainer is the publisher: `-m 11 --checkpoint_dir` leaves the
+    ladder, `generate --weights_from` serves its newest step, and
+    `generate --fleet 3 --deploy_dir --deploy_round 4` rolls it engine
+    by engine mid-serve with nothing shed: every request equals one of
+    the two pinned-version single-engine runs, both versions serve some,
+    and the router stream holds the deploy's records in order."""
+    import distributed_llm_code_samples_tpu.cli as cli
+    ck = str(tmp_path / "ck")
+    assert cli.main(["-m", "11", "-s", "4", "-bs", "2", "-n", "64", "-d",
+                     "32", "-l", "2", "--heads", "4", "--vocab", "64",
+                     "--checkpoint_dir", ck, "--checkpoint_every",
+                     "2"]) == 0
+    ladder = os.path.join(ck, "train_lm_tp")
+    serve = ["generate", "--prompt_lens", "3,7,5,6,4,9", "--max_new", "8",
+             "-d", "32", "-l", "2", "--heads", "4", "--vocab", "64",
+             "--max_seq_len", "64", "--block_size", "8",
+             "--prefill_chunk", "4", "--max_slots", "1", "--log_every",
+             "2"]
+
+    def run(extra):
+        capsys.readouterr()
+        assert cli.main(serve + extra) == 0
+        return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+    def tokens(payload):
+        return {s["uid"]: s["tokens"] for s in payload["sequences"]}
+
+    old, new = tokens(run([])), tokens(run(["--weights_from", ladder]))
+    mdir = str(tmp_path / "m")
+    rolled = run(["--fleet", "3", "--deploy_dir", ladder,
+                  "--deploy_round", "4", "--metrics_dir", mdir])
+    assert not rolled["failed"] and rolled["shed"] == 0
+    st = rolled["fleet"]
+    assert st["deploys"] == 1 and st["deploy_rollbacks"] == 0
+    assert {e["serving_version"] for e in st["engines"].values()} == {4}
+    got = tokens(rolled)
+    on_old = {u for u in got if got[u] == old[u]}
+    on_new = {u for u in got if got[u] == new[u]}
+    assert on_old and on_new and on_old | on_new == set(got) == set(old)
+    recs, problems = read_metrics(
+        os.path.join(mdir, "router", METRICS_FILENAME))
+    assert not problems, problems
+    deps = [r for r in recs if r["kind"] == "deploy"]
+    assert [d["event"] for d in deps] == (
+        ["started"] + ["engine_swapped"] * 3 + ["completed"])
+    assert all(validate_record(d)[0] and d["from_version"] == 0
+               and d["to_version"] == 4 for d in deps)

@@ -741,7 +741,7 @@ def test_tp_generate_matches_single_device(mesh_model4):
 def test_tp_generate_presharded_skips_copy_and_matches(mesh_model4):
     """tp_shard_params once + tp_generate = the same tokens as handing
     tp_generate unsharded params, and the presharded layout is detected
-    (no per-call reshard copy — the ADVICE r3 bench_decode fix)."""
+    (no per-call reshard copy)."""
     from distributed_llm_code_samples_tpu.parallel import (tp_generate,
                                                            tp_shard_params)
     from distributed_llm_code_samples_tpu.parallel.lm import (

@@ -97,7 +97,7 @@ def test_capacity_overflow_drops_to_zero():
 
 
 def test_dropped_token_passes_through_stack_residual():
-    """Switch drop semantics (ADVICE r1): a capacity-dropped token keeps
+    """Switch drop semantics: a capacity-dropped token keeps
     its input activation through the stack's residual instead of zeroing
     for every remaining layer."""
     p = MoEStackParams(wg=jnp.zeros((1, E, D)).at[0, 0].set(1.0),
@@ -258,7 +258,7 @@ def test_ep_overflow_pressure_matches_oracle(params, mesh_ep4):
     """Under real capacity pressure (factor 0.25: ~8 candidates per 2
     slots per expert per shard) EP's grouped drops equal the per-shard
     oracle's — the capacity semantics are shared, not just the no-drop
-    regime (VERDICT r1 item 10 / ADVICE r1)."""
+    regime."""
     n = 4
     # sanity: this factor actually drops at this shape
     wg, x = params.wg[0], batch_from_seed(jnp.int32(3), T, D,

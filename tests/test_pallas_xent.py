@@ -151,7 +151,7 @@ def test_resolve_head_rejects_unknown():
 
 
 def test_train_lm_tp_fused_head_leaves_interpret_to_backend(monkeypatch):
-    """Regression (ADVICE r4): ``train_lm_tp`` tied ``interpret`` to the
+    """Regression: ``train_lm_tp`` tied ``interpret`` to the
     vma decision (``not _vma_check(...)``), so ``head_impl='fused'`` —
     which runs vma-off on EVERY backend — forced the Pallas head into
     interpret mode on real TPU too, defeating the compiled kernels the

@@ -305,3 +305,37 @@ def test_spec_decode_records_schema_v6(lm_params, prompts, tmp_path):
     eng0 = DecodeEngine(lm_params, H, EngineConfig(**BASE))
     rec = eng0.telemetry_record()
     assert rec["drafted_tokens"] == 0 and rec["accept_rate"] is None
+
+
+# ---------------------------------------------------------------------------
+# CLI surface
+
+
+def test_generate_cli_speculate_matches_plain(tmp_path, capsys):
+    """`generate --speculate 4` against the flag-free run of the same
+    prompts: byte-identical tokens in fewer engine steps, and a metrics
+    stream whose decode records count more tokens than steps."""
+    import json
+
+    import distributed_llm_code_samples_tpu.cli as cli
+    from distributed_llm_code_samples_tpu.runtime.telemetry import (
+        METRICS_FILENAME, read_metrics)
+    args = ["generate", "--prompt_lens", "3,7", "--max_new", "24", "-d",
+            "32", "-l", "2", "--heads", "4", "--vocab", "64",
+            "--max_seq_len", "64", "--block_size", "8",
+            "--prefill_chunk", "4", "--log_every", "4"]
+    mdir = str(tmp_path / "metrics")
+    assert cli.main(args) == 0
+    plain = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert cli.main(args + ["--speculate", "4",
+                            "--metrics_dir", mdir]) == 0
+    spec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert ({s["uid"]: s["tokens"] for s in spec["sequences"]}
+            == {s["uid"]: s["tokens"] for s in plain["sequences"]})
+    assert spec["engine_steps"] < plain["engine_steps"]
+    records, problems = read_metrics(os.path.join(mdir,
+                                                  METRICS_FILENAME))
+    assert problems == []
+    decs = [r for r in records if r["kind"] == "decode"]
+    assert decs[-1]["accepted_tokens"] > 0
+    assert decs[-1]["tokens_generated"] == 2 * 24 > decs[-1]["step"]

@@ -424,3 +424,44 @@ def test_partial_off_by_default(lm_params, short_shared):
     eng = _staggered(lm_params, dict(BIG, kv_dtype="f32"),
                      short_shared)
     assert eng.partial_hits == 0
+
+
+# ---------------------------------------------------------------------------
+# CLI surface
+
+
+def test_generate_cli_spill_restores_match_big_pool(tmp_path, capsys):
+    """The session-churn drill through `generate`: four 9-token
+    sessions returning three times through an 11-block pool with
+    `--spill_blocks 32` emit the tokens of a 64-block pool that never
+    evicts, report restores, and leave a stream that `report --audit`
+    holds."""
+    import json
+
+    import distributed_llm_code_samples_tpu.cli as cli
+    from distributed_llm_code_samples_tpu.runtime.telemetry import (
+        METRICS_FILENAME, read_metrics)
+    ret = ("1,2,3,4,5,6,7,8,9;9,8,7,6,5,4,3,2,1;"
+           "11,12,13,14,15,16,17,18,19;21,22,23,24,25,26,27,28,29")
+    args = ["generate", "--prompts", ";".join([ret] * 3), "--max_new",
+            "6", "-d", "32", "-l", "2", "--heads", "4", "--vocab", "64",
+            "--max_seq_len", "64", "--block_size", "4",
+            "--prefill_chunk", "4", "--max_slots", "2",
+            "--max_blocks_per_seq", "8", "--log_every", "2"]
+    mdir = str(tmp_path / "metrics")
+    assert cli.main(args + ["--n_blocks", "64"]) == 0
+    oracle = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert cli.main(args + ["--n_blocks", "11", "--spill_blocks", "32",
+                            "--metrics_dir", mdir]) == 0
+    spill = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert ({s["uid"]: s["tokens"] for s in spill["sequences"]}
+            == {s["uid"]: s["tokens"] for s in oracle["sequences"]})
+    assert spill["spilled_blocks"] >= spill["restores"] > 0
+    assert spill["restore_tokens_saved"] > 0
+    records, problems = read_metrics(os.path.join(mdir,
+                                                  METRICS_FILENAME))
+    assert problems == []
+    assert any(r["kind"] == "decode" and r["restores"] > 0
+               for r in records)
+    assert cli.main(["report", mdir, "--audit"]) == 0
+    capsys.readouterr()

@@ -1093,7 +1093,7 @@ def test_writer_readbacks_happen_off_thread(tmp_path):
 
 
 def test_flops_and_peak_helpers():
-    # 12*T*d*f*L — bench.py's hand count, shared
+    # 12*T*d*f*L, the hand count
     assert ffn_model_flops(64, 8, 2) == 12 * 64 * 8 * 32 * 2
     assert hand_flops_per_step("ffn", tokens=64, model_size=8,
                                n_layers=2) == ffn_model_flops(64, 8, 2)

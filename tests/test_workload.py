@@ -705,8 +705,52 @@ def test_process_transport_replay_matches_inprocess_with_kill(
 
 
 # ---------------------------------------------------------------------------
-# CLI surface (rc-2 rejection discipline; the end-to-end runs live in
-# tier1.sh's workload smoke)
+# CLI surface: one end-to-end run, then the rc-2 rejection discipline
+
+
+def test_generate_cli_trace_gen_then_file_replay(tmp_path, capsys):
+    """`--trace_gen SPEC --trace_out FILE` through a two-engine fleet,
+    then `--trace FILE`: the file replays the generated trace to the
+    same tokens, the same workload summary and the same workload
+    records, and `report` over the replay's streams prints every
+    tenant and audits clean."""
+    from distributed_llm_code_samples_tpu.decode.generate_cli import (
+        generate_main)
+    from distributed_llm_code_samples_tpu.report import report_main
+    trace = str(tmp_path / "trace.jsonl")
+    shape = ["-d", "32", "-l", "2", "--heads", "4", "--vocab", "64",
+             "--max_seq_len", "64", "--block_size", "8",
+             "--prefill_chunk", "4", "--log_every", "2", "--fleet", "2"]
+    spec = ("n=10,arrival=bursty:40:0.2:0.3,plen=zipf:1.7:3:12,"
+            "max_new=4,tenants=a:3;b:1,seed=5")
+
+    def run(source, tag):
+        mdir = str(tmp_path / tag)
+        assert generate_main(source + shape
+                             + ["--metrics_dir", mdir]) == 0
+        payload = json.loads(
+            capsys.readouterr().out.strip().splitlines()[-1])
+        recs, problems = read_metrics(
+            os.path.join(mdir, "router", METRICS_FILENAME))
+        assert not problems, problems
+        return payload, [_strip_t(r) for r in recs
+                         if r["kind"] == "workload"]
+
+    r1, wl1 = run(["--trace_gen", spec, "--trace_out", trace], "m1")
+    r2, wl2 = run(["--trace", trace], "m2")
+    assert not r1["failed"] and not r2["failed"]
+    assert ({s["uid"]: s["tokens"] for s in r1["sequences"]}
+            == {s["uid"]: s["tokens"] for s in r2["sequences"]})
+    assert r1["workload"] == r2["workload"]
+    assert set(r1["workload"]["tenants"]) == {"a", "b"}
+    assert wl1 and wl1 == wl2
+    dirs = [str(tmp_path / "m2" / e) for e in ("router", "e0", "e1")]
+    assert report_main(dirs) == 0
+    text = capsys.readouterr().out
+    assert "workload [trace" in text and "TTFT" in text
+    assert "tenant a" in text and "tenant b" in text
+    assert report_main(dirs + ["--audit"]) == 0
+    capsys.readouterr()
 
 
 def test_generate_cli_trace_rejections(tmp_path):
