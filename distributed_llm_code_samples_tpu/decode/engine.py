@@ -302,6 +302,14 @@ CHUNK_COUNTERS = ("summary_rows", "summaries_written")
 KV_COUNTERS = ("kv_blocks_read", "kv_blocks_capacity",
                "ring_blocks_read", "ring_blocks_capacity")
 
+# ... and what turns those counts into bytes: the bytes ONE cached
+# position takes in ONE layer of each store, its K row and its V row as
+# the arrays hold them (``window_row_bytes`` 0 for a model with no
+# window layer). Constants of the engine, carried by every
+# ``engine_step`` record and by the ``decode`` record so that a reader
+# takes a store's width from the program and not from a family's keys
+ROW_BYTES = ("kv_row_bytes", "window_row_bytes")
+
 
 class AdmissionError(RuntimeError):
     """A request was shed at submit time (bounded queue full, or a
@@ -682,6 +690,10 @@ class DecodeEngine:
         # list; its read walks or gathers by the same rule
         self.wpool = self.programs.init_window()
         self._ring_walks = self.wpool is not None and walks(self.wpool)
+        self.row_bytes = dict(zip(ROW_BYTES, (
+            0 if store is None else
+            (store.k.shape[3] + store.v.shape[3]) * store.k.dtype.itemsize
+            for store in (self.pool, self.wpool))))
         # each slot's next token, on the device beside them (and one
         # scratch row): a row's pick is handed to the slot's next row
         # there, so a step can be launched before the last one is read
@@ -3128,7 +3140,8 @@ class DecodeEngine:
         launches up to and with this step's, so the record's own are
         the last ``len(dispatches)`` ordinals below it). The expert
         counters are those of the results READ; the cache reads'
-        (``WINDOW_COUNTERS``, ``KV_COUNTERS``) of the rows LAUNCHED.
+        (``WINDOW_COUNTERS``, ``KV_COUNTERS``) of the rows LAUNCHED,
+        with each store's bytes a position beside them (``ROW_BYTES``).
         ``tokens_generated`` is what a reader joins a step on."""
         return {
             "uid": None,
@@ -3145,6 +3158,7 @@ class DecodeEngine:
             **self._step_experts,
             **self._step_window,
             **self._step_kv,
+            **self.row_bytes,
             "dispatches": self._step_dispatches,
             "readbacks": list(self._step_readbacks),
             "launches": self.launches,
@@ -3244,10 +3258,11 @@ class DecodeEngine:
         return int(self.live_tokens() * self._kv_bytes_per_token())
 
     def _kv_bytes_per_token(self) -> float:
-        spec = self.spec
+        spec, row = self.spec, self.spec.row
         return kv_bytes_per_token(self.cfg.kv_dtype, spec.kv_layers,
-                                  spec.kv_heads, spec.head_dim,
-                                  latent=bool(spec.latent_rank))
+                                  row.heads, row.k_dim,
+                                  latent=bool(spec.latent_rank),
+                                  v_head_dim=row.v_dim)
 
     def telemetry_record(self, tokens_per_sec=None) -> dict:
         """One schema-v5 ``decode`` record (``runtime/telemetry.py``
@@ -3323,6 +3338,9 @@ class DecodeEngine:
             "kv_blocks_capacity": self.kv_blocks_capacity,
             "ring_blocks_read": self.ring_blocks_read,
             "ring_blocks_capacity": self.ring_blocks_capacity,
+            # extra (v24, additive): a cached position's bytes in one
+            # layer of each store (``ROW_BYTES``)
+            **self.row_bytes,
             # v17 KV-memory-hierarchy keys (pinned): demotion volume
             # (cumulative blocks + wire bytes), promotion wins
             # (restores, the prompt tokens they kept off the prefill

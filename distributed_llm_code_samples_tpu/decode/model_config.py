@@ -4,7 +4,8 @@ ONE function builds the engine for ``train_ffns.py generate
 --model_config FILE`` and for the benchmark's driver file
 (``benchmark/configs/jamba_engine_driver.py``,
 ``glm_moe_engine_driver.py``, ``lfm2_moe_engine_driver.py``,
-``laguna_engine_driver.py``, ``evabyte_engine_driver.py``): the
+``laguna_engine_driver.py``, ``evabyte_engine_driver.py``,
+``mimo_v2_flash_engine_driver.py``): the
 published keys say what the model is
 (``model_type`` picks the family's file under ``models/``, its
 ``spec_from_config`` reads the rest), the weights come from a seed or from the caller (a checkpoint restored into
@@ -19,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import (evabyte_lm, hybrid_lm, laguna_lm, lfm2_moe_lm,
-                      mla_moe_lm)
+                      mimo_v2_flash_lm, mla_moe_lm)
 from .engine import DecodeEngine, EngineConfig
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -33,6 +34,8 @@ FAMILIES = {
                  lfm2_moe_lm.init_lfm2_moe_lm),
     "laguna": (laguna_lm.spec_from_config, laguna_lm.init_laguna_lm),
     "evabyte": (evabyte_lm.spec_from_config, evabyte_lm.init_evabyte_lm),
+    "mimo_v2_flash": (mimo_v2_flash_lm.spec_from_config,
+                      mimo_v2_flash_lm.init_mimo_v2_flash_lm),
 }
 
 

@@ -6,7 +6,10 @@ sequences pays for its longest member and freeing a finished sequence
 means rebuilding the batch (a recompile). This module is the
 PagedAttention-style answer in the repo's first-principles idiom: the
 cache is a static-shape **pool of fixed-size blocks**
-(``k/v [L, n_blocks, block, H_kv*dh]``) and each sequence names its
+(``k/v [L, n_blocks, block, H_kv*dh]``; where a value head is not as
+wide as a key head, ``v [.., H_kv*dv]``: the two sides are arrays of
+their own and ``models/face.py::KVRow`` says both widths) and each
+sequence names its
 blocks through a per-slot int32 **block table** — the KV read goes
 through the table, the write is a scatter, and freeing a sequence is a
 host-side table edit. Shapes never depend on sequence length, so one
@@ -97,7 +100,11 @@ blocks from there to the row's last). The
 writes and the two reads are the full kind's, told the window
 (``write_chunk(ring=True)``, ``stored_decode_attn(window=)``,
 ``gathered_chunk_attn(window=)``): a window layer's gather is its short
-table, never the sequence's whole capacity. What moves a sequence by
+table, never the sequence's whole capacity. Its row is its own
+(``CacheSpec.window_row``: the window layers may have other KV head
+counts and widths than the full ones), and every read takes an optional
+per-head SINK, one more term of the softmax's denominator that has no
+value row (``models/attention.py::softmax_stats``). What moves a sequence by
 ONE block table (the prefix cache, spill, handoff, snapshots,
 speculation, int8's write history, the head-sharded mesh) refuses a
 model with window layers in one line (``decode/engine.py``).
@@ -139,6 +146,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..models.face import KVRow
+
 KV_DTYPES = ("f32", "bf16", "int8")
 
 # physical block 0 is the scratch block (see module docstring)
@@ -147,7 +156,7 @@ SCRATCH_BLOCK = 0
 
 @functools.partial(jax.tree_util.register_dataclass,
                    data_fields=["k", "v", "k_scale", "v_scale"],
-                   meta_fields=["head_dim", "latent_rank"])
+                   meta_fields=["head_dim", "latent_rank", "v_head_dim"])
 @dataclasses.dataclass(frozen=True)
 class PagedKV:
     """The block pool. ``k/v [L, n_blocks, block, H_kv*dh]`` in the
@@ -155,7 +164,10 @@ class PagedKV:
     module docstring says why); ``k_scale/v_scale [L, n_blocks, H_kv]``
     f32 per-block dequantization scales (``None`` unless
     ``kv_dtype="int8"``). ``head_dim`` is static (pytree metadata, not
-    a leaf): it is what splits a row back into heads. ``latent_rank``
+    a leaf): it is what splits a row back into heads; ``v_head_dim`` is
+    the value side's (``v [.., H_kv*dv]``; given as 0 it is
+    ``head_dim``, and kept resolved), and ``row`` the whole
+    description. ``latent_rank``
     > 0 marks a pool of latent rows (the module docstring): ``k`` holds
     them whole (``head_dim`` their lanes), ``v`` is zero lanes wide,
     and a row's first ``latent_rank`` lanes are its values."""
@@ -165,6 +177,11 @@ class PagedKV:
     v_scale: jax.Array | None
     head_dim: int
     latent_rank: int = 0
+    v_head_dim: int = 0
+
+    def __post_init__(self):
+        if not self.v_head_dim:
+            object.__setattr__(self, "v_head_dim", self.head_dim)
 
     def _replace(self, **fields) -> "PagedKV":
         return dataclasses.replace(self, **fields)
@@ -181,6 +198,12 @@ class PagedKV:
     def kv_heads(self) -> int:
         """KV heads in a row — the LOCAL count inside a TP shard."""
         return self.k.shape[3] // self.head_dim
+
+    @property
+    def row(self) -> KVRow:
+        """The store's row: its (local) KV heads, a key head's lanes
+        and a value head's."""
+        return KVRow(self.kv_heads, self.head_dim, self.v_head_dim)
 
 
 @functools.partial(jax.tree_util.register_dataclass,
@@ -271,15 +294,17 @@ def storage_dtype(kv_dtype: str):
 
 
 def kv_bytes_per_token(kv_dtype: str, n_layers: int, kv_heads: int,
-                       head_dim: int, latent: bool = False) -> float:
+                       head_dim: int, latent: bool = False,
+                       v_head_dim: int = 0) -> float:
     """Stored KV bytes per cached token position — the roofline's
     ``kv_bytes`` knob. int8 adds the amortized per-block scale pair
     (negligible; counted as 0 here, the bench reports block overheads
     separately). A ``latent`` row is one vector of ``head_dim`` lanes,
-    not a K/V pair."""
+    not a K/V pair; ``v_head_dim`` > 0 is a value head's lanes where
+    they are not a key head's."""
     per_elt = {"f32": 4, "bf16": 2, "int8": 1}[kv_dtype]
-    return ((1 if latent else 2) * n_layers * kv_heads * head_dim
-            * per_elt)
+    lanes = head_dim if latent else head_dim + (v_head_dim or head_dim)
+    return n_layers * kv_heads * lanes * per_elt
 
 
 def pool_bytes(pool: PagedKV) -> tuple[int, int]:
@@ -296,9 +321,11 @@ def pool_bytes(pool: PagedKV) -> tuple[int, int]:
 
 def init_pool(n_layers: int, n_blocks: int, kv_heads: int,
               block_size: int, head_dim: int, kv_dtype: str = "f32",
-              latent_rank: int = 0) -> PagedKV:
+              latent_rank: int = 0, v_head_dim: int = 0) -> PagedKV:
     """Zero-filled pool. ``n_blocks`` includes the reserved scratch
-    block, so at least 2 are required for any real sequence."""
+    block, so at least 2 are required for any real sequence.
+    ``v_head_dim`` > 0: the V side's row is ``kv_heads * v_head_dim``
+    lanes (``KVRow``)."""
     if n_blocks < 2:
         raise ValueError(f"n_blocks must be >= 2 (block {SCRATCH_BLOCK} "
                          f"is the reserved scratch block), got {n_blocks}")
@@ -321,8 +348,10 @@ def init_pool(n_layers: int, n_blocks: int, kv_heads: int,
         return (jnp.zeros((n_layers, n_blocks, kv_heads), jnp.float32)
                 if kv_dtype == "int8" else None)
 
-    return PagedKV(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt),
-                   k_scale=scale(), v_scale=scale(), head_dim=head_dim)
+    v_shape = shape[:3] + (kv_heads * (v_head_dim or head_dim),)
+    return PagedKV(k=jnp.zeros(shape, dt), v=jnp.zeros(v_shape, dt),
+                   k_scale=scale(), v_scale=scale(), head_dim=head_dim,
+                   v_head_dim=v_head_dim)
 
 
 def _quantize(x: jax.Array, valid: jax.Array):
@@ -349,9 +378,10 @@ def write_rows(pool: PagedKV, layer: int, phys: jax.Array,
                off: jax.Array, k_new: jax.Array, v_new: jax.Array,
                kv_dtype: str) -> PagedKV:
     """Scatter ``N`` new KV rows into the pool: row ``i`` lands at
-    ``(layer, phys[i], off[i], :)``. ``k_new/v_new [N, H_kv, dh]``
-    f32. For f32/bf16 this is one masked-free scatter; for int8 each
-    touched block is read back, dequantized, re-quantized over its valid
+    ``(layer, phys[i], off[i], :)``. ``k_new [N, H_kv, dh]``, ``v_new
+    [N, H_kv, dv]`` f32 (each side's lanes a head are its operand's).
+    For f32/bf16 this is one masked-free scatter; for int8 each touched
+    block is read back, dequantized, re-quantized over its valid
     rows ``0..off[i]`` (blocks fill in order, so everything at or below
     the newest offset is live) and written whole. Duplicate ``phys``
     entries are only ever the scratch block (padded bucket rows) — last
@@ -378,7 +408,7 @@ def write_rows(pool: PagedKV, layer: int, phys: jax.Array,
 
     def requant(pool_side, scale_side, new):
         old = _dequantize(                              # [N, Hkv, blk, dh]
-            _heads_major(pool_side[layer, phys], pool.head_dim),
+            _heads_major(pool_side[layer, phys], new.shape[-1]),
             scale_side[layer, phys])
         ins = rows[None, None, :, None] == off[:, None, None, None]
         cur = jnp.where(ins, new[:, :, None, :], old)
@@ -450,12 +480,12 @@ def write_chunk(pool: PagedKV, layer: int, table: jax.Array, pos0,
         raise ValueError(f"chunk {c} > block {blk} must be a whole "
                          "multiple (power-of-two buckets guarantee it)")
     nb = c // blk
-    hkv, dh = pool.kv_heads, pool.head_dim
+    hkv = pool.kv_heads
     blocks = table[pos0 // blk + jnp.arange(nb)]        # [nb]
     valid = jnp.ones((nb, hkv, blk), bool)
 
     def quant_whole(pool_side, scale_side, new):
-        shaped = new.reshape(nb, blk, hkv, dh).transpose(0, 2, 1, 3)
+        shaped = new.reshape(nb, blk, hkv, -1).transpose(0, 2, 1, 3)
         q, scale = _quantize(shaped, valid)
         return (pool_side.at[layer, blocks].set(_rows_major(q)),
                 scale_side.at[layer, blocks].set(scale))
@@ -472,13 +502,14 @@ def _int8_partial_chunk(pool: PagedKV, layer: int, phys, off: jax.Array,
     block, dequantize, insert the ``C`` rows at ``off``, re-quantize
     over rows ``0..max(off)``."""
     blk = pool.block_size
-    hkv, dh = pool.kv_heads, pool.head_dim
+    hkv = pool.kv_heads
     rows = jnp.arange(blk)
     valid_hi = off[-1]                                  # fills in order
     valid = jnp.broadcast_to((rows <= valid_hi)[None, :], (hkv, blk))
     hit = jnp.zeros((blk,), bool).at[off].set(True)
 
     def requant(pool_side, scale_side, new):
+        dh = new.shape[-1]
         old = _dequantize(                              # [Hkv, blk, dh]
             _heads_major(pool_side[layer, phys], dh),
             scale_side[layer, phys])
@@ -571,7 +602,8 @@ def copy_block_rows(pool: PagedKV, src, dst, n_rows) -> PagedKV:
 def extract_blocks(pool: PagedKV, blocks) -> dict:
     """Host-side copy of the named physical blocks' bytes — the export
     half of the single-sequence KV handoff (``decode/fleet.py``):
-    ``k``/``v`` come back ``[L, n, H_kv, block, dh]`` numpy arrays AT
+    ``k``/``v`` come back ``[L, n, H_kv, block, dh]`` (``v``: the
+    row's ``v_dim`` lanes a head) numpy arrays AT
     THE STORAGE DTYPE (int8 codes stay int8 — the import must not
     round-trip through f32, or the bit-exactness contract dies at the
     requantization boundary), ``k_scale``/``v_scale`` ``[L, n, H_kv]``
@@ -583,11 +615,12 @@ def extract_blocks(pool: PagedKV, blocks) -> dict:
     import numpy as np
     idx = np.asarray(blocks, np.int32)
 
-    def doc(side):
+    def doc(side, lanes):
         return np.ascontiguousarray(
-            _heads_major(np.asarray(side[:, idx]), pool.head_dim))
+            _heads_major(np.asarray(side[:, idx]), lanes))
 
-    out = {"k": doc(pool.k), "v": doc(pool.v),
+    row = pool.row
+    out = {"k": doc(pool.k, row.k_dim), "v": doc(pool.v, row.v_dim),
            "k_scale": None, "v_scale": None}
     if pool.k_scale is not None:
         out["k_scale"] = np.asarray(pool.k_scale[:, idx])
@@ -639,13 +672,17 @@ def corrupt_block(pool: PagedKV, block: int) -> PagedKV:
 def gathered_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                          tables: jax.Array, lengths: jax.Array,
                          window: int = 0, aligned: bool = False,
-                         stats: bool = False):
+                         stats: bool = False, sink=None):
     """The PLAIN decode-side read: single-query attention for one layer
     over a gather of every row's whole table, the rows AS STORED — what
     ``stored_decode_attn`` runs for the pools that do not take the walk
     (``walks``), and what the tests hold the walk to. ``q [B, H,
     dh]`` f32, ``tables [B, MB]`` int32, ``lengths [B]`` attendable
-    positions; returns ``[B, H, dh]`` f32. ``window`` > 0: ``tables``
+    positions; returns ``[B, H, dv]`` f32 (``dv`` the row's ``v_dim``:
+    ``dh`` unless the pool says otherwise). ``sink [H]`` f32: each
+    head's sink, one more term of its softmax's denominator with no
+    value row (``models/attention.py::softmax_stats``; the statistics
+    count it). ``window`` > 0: ``tables``
     are window layers' short tables, used as rings, and a row attends
     over its last ``window`` positions (``ring_positions``), or, where
     ``aligned``, over the positions of its own multiple-of-``window``
@@ -662,7 +699,7 @@ def gathered_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
       ``scores[b,h,t] = sum_j K[b,t,j] * qbd[b,j,h]`` runs over the
       whole stored row (one KV head: ``qbd`` is just ``q^T``);
     - ``full[b,h,j] = sum_t p[b,h,t] * V[b,t,j]``, of which head ``h``
-      keeps its own KV head's ``dh`` lanes.
+      keeps its own KV head's ``dv`` lanes.
 
     The small operands (``qbd``, the probabilities) are brought to the
     rows' dtype — bf16 operands under f32 accumulation for a bf16 pool,
@@ -683,12 +720,12 @@ def gathered_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     if pool.latent_rank:
         return _latent_decode_attn(pool, layer, q, tables, lengths)
     b, h, dh = q.shape
-    hkv, blk = pool.kv_heads, pool.block_size
+    hkv, blk, dv = pool.kv_heads, pool.block_size, pool.row.v_dim
     g = h // hkv
     with jax.named_scope("gather"):
         layers = jnp.full_like(tables, layer)   # the layer rides in the indices
         k = pool.k[layers, tables].reshape(b, -1, hkv * dh)
-        v = pool.v[layers, tables].reshape(b, -1, hkv * dh)
+        v = pool.v[layers, tables].reshape(b, -1, hkv * dv)
         if pool.k_scale is not None:
             # per-block scales -> per (query head, position): [B, H, T]
             def per_head(scale):
@@ -717,16 +754,16 @@ def gathered_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
         else:
             mask = jnp.arange(k.shape[1]) < lengths[:, None, None]
         s = jnp.where(mask, s, jnp.float32(-1e30))
-        if stats:
-            p, m, l = softmax_stats(s)
+        if stats or sink is not None:
+            p, m, l = softmax_stats(s, sink)    # [H] against [B, H]
         else:
             p = jax.nn.softmax(s, axis=-1)
         if pool.k_scale is not None:
             p = p * vs
         full = jnp.einsum("bht,btj->bhj", p.astype(dt), v.astype(dt),
                           preferred_element_type=jnp.float32)
-        y = jnp.einsum("bkgkd->bkgd", full.reshape(b, hkv, g, hkv, dh))
-    y = y.reshape(b, h, dh)
+        y = jnp.einsum("bkgkd->bkgd", full.reshape(b, hkv, g, hkv, dv))
+    y = y.reshape(b, h, dv)
     return (y, m, l) if stats else y
 
 
@@ -753,7 +790,8 @@ def walks(pool: PagedKV, shards: int = 1) -> bool:
       0.39 ms a program of 64 rows x 2 layers at ONE KV head of 128
       lanes; at 512 lanes 0.71 against 2.95, at 1,024 2.07 against
       11.5, at 1,280 1.75 against 4.32: ``PERF.md`` section 6, PR 40).
-      The interpreter, off the chip, takes any width.
+      Where the two sides' rows differ (``KVRow``) each side's has to
+      be such a row. The interpreter, off the chip, takes any width.
 
     ``shards``: the ways ``pool``'s rows are sharded over a mesh where
     the caller holds the whole pool (the engine, for its counters); a
@@ -763,21 +801,24 @@ def walks(pool: PagedKV, shards: int = 1) -> bool:
     from ..ops import ssm
     if ssm._interpreted():
         return True
-    lanes = pool.k.shape[-1] // shards
     sublanes = 32 // pool.k.dtype.itemsize
-    return (lanes > ssm._LANES and lanes % ssm._LANES == 0
+    return (all(lanes > ssm._LANES and lanes % ssm._LANES == 0
+                for lanes in (pool.k.shape[-1] // shards,
+                              pool.v.shape[-1] // shards))
             and pool.block_size % sublanes == 0)
 
 
 def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                        tables: jax.Array, lengths: jax.Array,
                        window: int = 0, aligned: bool = False,
-                       stats: bool = False):
+                       stats: bool = False, sink=None):
     """The decode-side programs' cache read (``decode``, ``mixed`` and
     ``verify``): single-query attention for one layer over the rows AS
     STORED. ``q [B, H, dh]`` f32, ``tables [B, MB]`` int32, ``lengths
-    [B]`` attendable positions; returns ``[B, H, dh]`` f32 (a latent
-    pool: ``gathered_decode_attn`` says). One contract, met by the walk
+    [B]`` attendable positions; returns ``[B, H, dv]`` f32, ``dv`` the
+    row's ``v_dim`` (a latent pool: ``gathered_decode_attn`` says).
+    ``sink [H]`` f32: each head's sink, a term of the softmax's
+    denominator with no value row. One contract, met by the walk
     over each row's live blocks where the pool takes it (``walks``) and
     by the plain form, ``gathered_decode_attn``, where it does not.
     ``window`` > 0: ``tables`` are window layers' short tables, used as
@@ -790,8 +831,10 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     over the rows as stored, block by block where they lie, under an
     online float32 softmax: the query laid out for the stored row (zero
     outside its KV head's lanes) and the probabilities in the pool's
-    dtype, sums in float32, head ``h`` keeping its KV head's ``dh``
-    lanes of the result. It is handed each row's range of positions,
+    dtype, sums in float32, head ``h`` keeping its KV head's ``dv``
+    lanes of the result (the V side's row; a sink is where the running
+    maximum and sum START, no column and no copy). It is handed each
+    row's range of positions,
     ``[ring_start, lengths)`` for a ring and ``[0, lengths)`` otherwise
     (this is the one place on the path that knows the two window rules),
     and reads any table as a ring. Against the oracle: an f32 pool to
@@ -803,10 +846,10 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     entry's last use), still does (``corrupt_block``)."""
     if not walks(pool):
         return gathered_decode_attn(pool, layer, q, tables, lengths, window,
-                                    aligned, stats)
+                                    aligned, stats, sink)
     from ..ops.kv_walk import walk_attn
     b, h, dh = q.shape
-    hkv = pool.kv_heads
+    hkv, dv = pool.kv_heads, pool.row.v_dim
     g = h // hkv
     starts = (ring_start(lengths - 1, window, aligned) if window
               else jnp.zeros_like(lengths))
@@ -816,11 +859,11 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                          q.reshape(b, hkv, g, 1, dh), 0)
         full = walk_attn(pool.k, pool.v, layer,
                          rows.reshape(b, h, hkv * dh).astype(pool.k.dtype),
-                         tables, starts, lengths, dh ** -0.5, stats)
+                         tables, starts, lengths, dh ** -0.5, stats, sink)
         if stats:
             full, m, l = full
-        y = jnp.einsum("bkgkd->bkgd", full.reshape(b, hkv, g, hkv, dh))
-    y = y.reshape(b, h, dh)
+        y = jnp.einsum("bkgkd->bkgd", full.reshape(b, hkv, g, hkv, dv))
+    y = y.reshape(b, h, dv)
     return (y, m, l) if stats else y
 
 
@@ -872,8 +915,8 @@ def _latent_chunk_attn(pool: PagedKV, layer: int, q: jax.Array,
 
 def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
     """One sequence's dequantized contiguous KV view for one layer:
-    ``table [max_blocks]`` -> ``(k, v)`` each ``[H_kv, T_cap, dh]`` f32
-    (``T_cap = max_blocks * block``). The gather itself is
+    ``table [max_blocks]`` -> ``(k [H_kv, T_cap, dh], v [H_kv, T_cap,
+    dv])`` f32 (``T_cap = max_blocks * block``). The gather itself is
     ``models.attention.gather_paged_kv`` — the attention read against a
     block table; this wrapper only adds the dtype story. With
     ``decode_attn`` this is the ORACLE the tests hold
@@ -886,7 +929,7 @@ def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
     # the paged-KV traffic term the DECODE roofline prices
     with jax.named_scope("gather"):
         k, v = gather_paged_kv(pool.k, pool.v, layer, table,
-                               pool.head_dim)
+                               pool.head_dim, pool.v_head_dim)
         if pool.k_scale is None:
             if k.dtype != jnp.float32:
                 k = k.astype(jnp.float32)
@@ -903,13 +946,14 @@ def gather_layer(pool: PagedKV, layer: int, table: jax.Array):
 def gathered_chunk_attn(pool: PagedKV, layer: int, q: jax.Array,
                         table: jax.Array, pos0, window: int = 0,
                         aligned: bool = False, rows=None,
-                        stats: bool = False):
+                        stats: bool = False, sink=None):
     """A prefill chunk's read: ``q [C, H, dh]`` at positions ``pos0 ..
     pos0+C-1`` of ONE sequence attends causally over its gathered view
     (``gather_layer`` + ``models.attention.chunk_attn``, the oracle's
     arithmetic: one slot's f32 head-split view is small). Returns
-    ``[C, H, dh]``. ``window`` > 0: ``table`` is a window layer's short
-    table, a ring the chunk's rows were just written into, and each row
+    ``[C, H, dv]`` (``dv`` the row's ``v_dim``). ``sink [H]``: each
+    head's sink, as in the decode-side read. ``window`` > 0: ``table``
+    is a window layer's short table, a ring the chunk's rows were just written into, and each row
     sees the last ``window`` positions up to its own (the rows of one
     chunk have different window starts), or, where ``aligned``, the
     positions of its own multiple-of-``window`` window up to its own.
@@ -932,7 +976,8 @@ def gathered_chunk_attn(pool: PagedKV, layer: int, q: jax.Array,
         mask = jnp.broadcast_to(jnp.arange(ck.shape[1]) < rows,
                                 (q.shape[0], ck.shape[1]))
     with jax.named_scope("attn"):
-        y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0, mask, stats)
+        y = chunk_attn(q.transpose(1, 0, 2), ck, cv, pos0, mask, stats,
+                       sink)
     if stats:
         y, m, l = y
         return y.transpose(1, 0, 2), m.T, l.T

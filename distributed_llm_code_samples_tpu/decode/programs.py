@@ -247,9 +247,10 @@ class StepPrograms:
         """The zero pool (head-sharded under a mesh) and the recurrent
         layers' state by slot beside it (None for a model with none)."""
         cfg, spec = self.cfg, self.spec
-        pool = init_pool(spec.kv_layers, cfg.n_blocks, spec.kv_heads,
-                         cfg.block_size, spec.head_dim, cfg.kv_dtype,
-                         latent_rank=spec.latent_rank)
+        row = spec.row
+        pool = init_pool(spec.kv_layers, cfg.n_blocks, row.heads,
+                         cfg.block_size, row.k_dim, cfg.kv_dtype,
+                         latent_rank=spec.latent_rank, v_head_dim=row.v_dim)
         if self.mesh is not None:
             pool = jax.tree.map(
                 lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
@@ -263,14 +264,16 @@ class StepPrograms:
 
     def init_window(self) -> PagedKV | None:
         """The window layers' zero pool (None for a model with none): a
-        whole ring for every slot and a scratch block of its own."""
+        whole ring for every slot and a scratch block of its own, in
+        the window kind's own row (``CacheSpec.window_row``)."""
         cfg, spec = self.cfg, self.spec
         if not spec.win_layers:
             return None
+        row = spec.window_row
         return init_pool(spec.win_layers,
                          1 + cfg.max_slots * self.window_blocks,
-                         spec.kv_heads, cfg.block_size, spec.head_dim,
-                         cfg.kv_dtype)
+                         row.heads, cfg.block_size, row.k_dim, cfg.kv_dtype,
+                         v_head_dim=row.v_dim)
 
     def whole(self, pool, wpool=None, state=None):
         """The cache a step program carries: the pool alone, or the
@@ -303,7 +306,8 @@ class StepPrograms:
         arr = P(None, None, None, MODEL_AXIS)
         sc = (P(None, None, MODEL_AXIS) if self.cfg.kv_dtype == "int8"
               else None)
-        return PagedKV(arr, arr, sc, sc, self.spec.head_dim)
+        row = self.spec.row
+        return PagedKV(arr, arr, sc, sc, row.k_dim, v_head_dim=row.v_dim)
 
     # -- the model's forward over the cache -------------------------------
 
@@ -319,7 +323,8 @@ class StepPrograms:
         single-token writes and per-slot reads, or one slot's chunk),
         output projection. Window: the same over the window layers' own
         pool, ``write_window(i, wpool, q, k, v)``, between the model's
-        ``window_qkv`` and ``window_out``. Chunked: a layer with an index
+        ``window_qkv`` and ``window_out`` (the seam asks the model for
+        the layer's ``window_sink``). Chunked: a layer with an index
         in BOTH pools, ``write_chunked(i, pool, wpool, q, k, v) ->
         (pool, wpool, y)`` between ``chunked_qkv`` and ``chunked_out``:
         the exact keys of the row's aligned window in the ring, a
@@ -386,7 +391,8 @@ class StepPrograms:
         decode rows: each row's token written at its own position and
         attended over its blocks as stored (a window layer: into the
         row's ring, ``wtables [b, entries]``, and over its last
-        ``window`` positions; a chunked layer: into the ring, its
+        ``window`` positions, the model's ``window_sink`` in the
+        softmax where it has one; a chunked layer: into the ring, its
         chunk's summary into the full kind's pool where the position
         ends the chunk, and over the ring's aligned window joined with
         the summaries of every earlier one, whose count follows from
@@ -413,7 +419,8 @@ class StepPrograms:
             phys = wtables[jnp.arange(b), slot_phys % wtables.shape[1]]
             wpool = write_rows(wpool, l, phys, off, k, v, cfg.kv_dtype)
             return wpool, stored_decode_attn(wpool, l, q, wtables,
-                                             lengths + 1, self.spec.window)
+                                             lengths + 1, self.spec.window,
+                                             sink=p.window_sink(l))
 
         def write_chunked(l, pool, wpool, q, k, v):
             window = self.spec.window
@@ -465,7 +472,8 @@ class StepPrograms:
             wpool = write_chunk(wpool, l, wtable, pos0, k, v, cfg.kv_dtype,
                                 ring=True)
             return wpool, gathered_chunk_attn(wpool, l, q, wtable, pos0,
-                                              self.spec.window)
+                                              self.spec.window,
+                                              sink=p.window_sink(l))
 
         def write_chunked(l, pool, wpool, q, k, v):
             window, blk = self.spec.window, cfg.block_size

@@ -53,14 +53,22 @@ def aligned_mask(q_pos, k_pos, window: int):
             & (k_pos // window == q_pos // window))
 
 
-def softmax_stats(s: jax.Array):
+def softmax_stats(s: jax.Array, sink=None):
     """Softmax over the last axis with its statistics: ``(p, m, l)``,
     the probabilities beside each row's maximum and its sum of ``exp(s
     - m)`` — what a join with another read of the same queries needs
-    (``decode/paged.py::join_reads``)."""
+    (``decode/paged.py::join_reads``). ``sink`` (broadcastable to
+    ``s.shape[:-1]``): one more term of the denominator and of nothing
+    else, ``m = max(sink, max_j s_j)`` and ``l = exp(sink - m) + sum_j
+    exp(s_j - m)``; it has no probability of its own, so ``p`` sums to
+    less than 1 (an attention sink: a head may put mass nowhere)."""
     m = jnp.max(s, axis=-1)
+    if sink is not None:
+        m = jnp.maximum(m, sink)
     e = jnp.exp(s - m[..., None])
     l = jnp.sum(e, axis=-1)
+    if sink is not None:
+        l = l + jnp.exp(sink - m)
     return e / l[..., None], m, l
 
 
@@ -254,7 +262,8 @@ rope_mha.supports_gqa = True  # handles fewer k heads (see attn_sublayer)
 
 
 def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array, layer: int,
-                    table: jax.Array, head_dim: int):
+                    table: jax.Array, head_dim: int,
+                    v_head_dim: int | None = None):
     """Materialize one sequence's contiguous KV view from the block pool.
 
     ``pool_k/pool_v [L, n_blocks, block, H_kv*dh]`` (the WHOLE pool, as
@@ -263,7 +272,9 @@ def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array, layer: int,
     block ids, in sequence order. The layer rides inside the gather's
     indices, so no one-layer ``[n_blocks, ...]`` slab is sliced out of
     the pool first.
-    Returns ``(k, v)`` each ``[H_kv, max_blocks * block, dh]`` — exactly
+    Returns ``(k, v)`` each ``[H_kv, max_blocks * block, dh]`` (``v``'s
+    lanes a head are ``v_head_dim`` where the two sides' rows differ:
+    ``pool_v [L, n_blocks, block, H_kv*dv]``) — exactly
     the contiguous cache layout ``_decode_attn`` reads, so downstream
     attention is bit-identical to a contiguous cache holding the same
     values (the gather only moves bytes). Positions beyond the sequence
@@ -289,15 +300,16 @@ def gather_paged_kv(pool_k: jax.Array, pool_v: jax.Array, layer: int,
     mb, blk, m = k.shape
     hkv = m // head_dim
     k = k.reshape(mb * blk, hkv, head_dim).transpose(1, 0, 2)
-    v = v.reshape(mb * blk, hkv, head_dim).transpose(1, 0, 2)
+    v = v.reshape(mb * blk, hkv, v_head_dim or head_dim).transpose(1, 0, 2)
     return k, v
 
 
 def chunk_attn(q: jax.Array, ck: jax.Array, cv: jax.Array,
-               q_offset, mask=None, stats: bool = False):
+               q_offset, mask=None, stats: bool = False, sink=None):
     """Prefill-chunk attention of ``Tq`` queries against a (gathered)
     cache that already holds the chunk's own keys: ``q [H, Tq, dh]``,
-    ``ck/cv [H_kv, T_cap, dh]`` with ``H % H_kv == 0`` (GQA groups).
+    ``ck [H_kv, T_cap, dh]``, ``cv [H_kv, T_cap, dv]`` with ``H % H_kv
+    == 0`` (GQA groups); the result is ``[H, Tq, dv]``.
     The mask is the global causal rule via ``causal_mask(Tq, T_cap,
     q_offset)`` — query ``i`` (global position ``q_offset + i``) sees
     cache positions ``<= q_offset + i``, which also hides every
@@ -306,9 +318,12 @@ def chunk_attn(q: jax.Array, ck: jax.Array, cv: jax.Array,
     [Tq, T_cap]`` takes the causal rule's place where the view is not
     in position order (a window layer's ring: ``window_mask``).
     ``stats``: ``(y, m [H, Tq], l [H, Tq])``, the result beside each
-    row's score maximum and its sum of ``exp(s - m)``."""
+    row's score maximum and its sum of ``exp(s - m)``. ``sink [H]``:
+    each head's sink, one more term of its softmax's denominator
+    (``softmax_stats``)."""
     h, tq, dh = q.shape
     hkv, tcap, _ = ck.shape
+    dv = cv.shape[-1]
     if h % hkv:
         raise ValueError(f"query heads {h} not divisible by kv heads "
                          f"{hkv}")
@@ -318,12 +333,13 @@ def chunk_attn(q: jax.Array, ck: jax.Array, cv: jax.Array,
     if mask is None:
         mask = causal_mask(tq, tcap, q_offset=q_offset)
     s = jnp.where(mask, s, jnp.asarray(-1e30, s.dtype))
-    if stats:
-        p, m, l = softmax_stats(s)
-        y = jnp.einsum("kgqt,ktd->kgqd", p, cv)
-        return (y.reshape(h, tq, dh), m.reshape(h, tq), l.reshape(h, tq))
+    if stats or sink is not None:
+        p, m, l = softmax_stats(
+            s, None if sink is None else sink.reshape(hkv, h // hkv, 1))
+        y = jnp.einsum("kgqt,ktd->kgqd", p, cv).reshape(h, tq, dv)
+        return (y, m.reshape(h, tq), l.reshape(h, tq)) if stats else y
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("kgqt,ktd->kgqd", p, cv).reshape(h, tq, dh)
+    return jnp.einsum("kgqt,ktd->kgqd", p, cv).reshape(h, tq, dv)
 
 
 def gqa(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -7,13 +7,14 @@ cache (``decode/paged.py``); neither it nor the scheduler asks which
 class the params are, reads a weight by name or calls a family's
 arithmetic. ``models/lm.py``, ``models/hybrid_lm.py``,
 ``models/mla_moe_lm.py``, ``models/lfm2_moe_lm.py``,
-``models/laguna_lm.py`` and ``models/evabyte_lm.py`` are the families
-that exist;
+``models/laguna_lm.py``, ``models/evabyte_lm.py`` and
+``models/mimo_v2_flash_lm.py`` are the families that exist;
 ``tests/test_model_face.py`` serves one more that lives in the test
 alone. The builder keeps the cache write and read of an attention layer
 (between ``attn_qkv`` and ``attn_out``; for a latent-cache layer
 between ``latent_qrow`` and ``latent_out``; for a sliding-window layer
-between ``window_qkv`` and ``window_out``; for a chunk-summarised layer
+between ``window_qkv`` and ``window_out``, with ``window_sink`` for the
+term a head may add to its softmax's denominator; for a chunk-summarised layer
 between ``chunked_qkv`` and ``chunked_out``, with ``chunk_summary`` for
 the row it keeps of every finished chunk), the row of the recurrent
 state a sequence owns, the residual adds, the expert layers' counters
@@ -63,9 +64,23 @@ WINDOW = "window"
 CHUNKED = "chunked"
 
 
+class KVRow(NamedTuple):
+    """One paged store's row, the ONE description its pool, the writes,
+    the reads, the byte counts and the host's block documents take
+    their widths from: ``heads`` KV heads side by side, each a key of
+    ``k_dim`` lanes in the K side's row and a value of ``v_dim`` lanes
+    in the V side's (the two sides are arrays of their own, so their
+    rows need not be equally wide)."""
+    heads: int
+    k_dim: int
+    v_dim: int
+
+
 class CacheSpec(NamedTuple):
     """What a model keeps per served sequence, as sizes: ``kv_layers``
-    layers own paged KV of ``kv_heads`` heads of ``head_dim`` lanes;
+    layers own paged KV of ``kv_heads`` heads of ``head_dim`` lanes
+    (``v_head_dim`` > 0: a value head has that many lanes, a key head
+    ``head_dim``; ``row`` is the store's ``KVRow``);
     ``rec_layers`` layers own a recurrent state of inner width
     ``d_inner``, state size ``d_state`` and ``d_conv`` convolution taps
     (0 for a model with none; ``d_state`` 0 with ``rec_layers`` > 0 is
@@ -77,9 +92,11 @@ class CacheSpec(NamedTuple):
     ``expert_layers`` layers route their rows over ``n_experts`` held
     experts and count them, which sizes the counters a step program
     returns after its picks (0 for a model with no expert layer).
-    ``win_layers`` ``WINDOW`` layers own paged KV of the same
-    ``kv_heads`` x ``head_dim`` row over the last ``window`` positions
-    of a sequence (0 for a model with none). ``chunk`` > 0 says the
+    ``win_layers`` ``WINDOW`` layers own paged KV over the last
+    ``window`` positions of a sequence (0 for a model with none), in a
+    row of their own where the model states one (``win_row``: its own
+    KV heads and widths; None: the full kind's row, ``window_row``
+    either way). ``chunk`` > 0 says the
     layers are ``CHUNKED`` ones: each has an index in BOTH stores
     (``kv_layers == win_layers``), a row of the full kind's pool stands
     for one finished chunk of ``chunk`` positions, and the window is
@@ -97,6 +114,19 @@ class CacheSpec(NamedTuple):
     win_layers: int = 0
     window: int = 0
     chunk: int = 0
+    v_head_dim: int = 0
+    win_row: KVRow | None = None
+
+    @property
+    def row(self) -> KVRow:
+        """The full kind's store."""
+        return KVRow(self.kv_heads, self.head_dim,
+                     self.v_head_dim or self.head_dim)
+
+    @property
+    def window_row(self) -> KVRow:
+        """The window kind's store."""
+        return self.win_row or self.row
 
 
 class ServedModel(Protocol):
@@ -126,8 +156,9 @@ class ServedModel(Protocol):
 
     def norm(self, g, x): ...       # the family's norm with gain g
 
-    # -> q [N, H, dh], k, v [N, H_kv, dh]: local head counts off the
-    # weights' shapes, rotary inside when asked
+    # -> q [N, H, dh], k [N, H_kv, dh], v [N, H_kv, dv] (``dv`` the
+    # store's ``v_dim``: ``dh`` for most families): local head counts
+    # off the weights' shapes, rotary inside when asked
     def attn_qkv(self, i, a, positions, head_dim, use_rope): ...
 
     # y [N, H*dh] the read's result, a the layer's normed input (what
@@ -138,6 +169,12 @@ class ServedModel(Protocol):
     # rotary): as ``attn_qkv`` / ``attn_out``; the builder writes k, v
     # to the window pool and reads the last ``window`` positions
     def window_qkv(self, i, a, positions): ...
+
+    # [H] float32 or None: head ``h``'s SINK, a learned scalar that
+    # joins the denominator of the layer's softmax beside the scores
+    # and has no value row (``p_j = exp(s_j - m) / (exp(sink_h - m) +
+    # sum_j' exp(s_j' - m))``); None for a family without one
+    def window_sink(self, i): ...
 
     def window_out(self, i, y, a): ...
 
@@ -249,9 +286,11 @@ class AttnStack(NamedTuple):
 
 def qkv_heads(wq, wk, wv, i: int, a, positions, head_dim: int,
               use_rope: bool, theta: float | None = None, qk_norm=None,
-              rotary: Rotary | None = None):
+              rotary: Rotary | None = None, v_head_dim: int | None = None):
     """Attention layer ``i`` of stacks ``[L_a, out, d]``: ``a [N, d] ->
-    q [N, h_loc, dh], k/v [N, kv_loc, dh]``, rotated by ``positions
+    q [N, h_loc, dh], k [N, kv_loc, dh], v [N, kv_loc, dv]`` (``dv`` is
+    ``v_head_dim`` where a value head is not as wide as a key head,
+    else ``dh``), rotated by ``positions
     [N]`` when asked, at the base ``theta`` where the model states one
     (``rope``'s own otherwise) or as the model's ``rotary`` says (part
     of a head's lanes, YaRN: ``models/attention.py::Rotary``); the
@@ -262,7 +301,8 @@ def qkv_heads(wq, wk, wv, i: int, a, positions, head_dim: int,
     rotation."""
     q = mm(a, wq[i]).reshape(-1, wq.shape[1] // head_dim, head_dim)
     k = mm(a, wk[i]).reshape(-1, wk.shape[1] // head_dim, head_dim)
-    v = mm(a, wv[i]).reshape(-1, wv.shape[1] // head_dim, head_dim)
+    dv = v_head_dim or head_dim
+    v = mm(a, wv[i]).reshape(-1, wv.shape[1] // dv, dv)
     if qk_norm is not None:
         g_q, g_k, eps = qk_norm
         q, k = rmsnorm(g_q, q, eps), rmsnorm(g_k, k, eps)

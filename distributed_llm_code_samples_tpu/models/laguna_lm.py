@@ -164,6 +164,9 @@ class LagunaLMParams:
             return _qkv(self.window, i, a, positions, self.head_dim,
                         self.rot_window)
 
+    def window_sink(self, i):
+        return None             # the family's softmax has no sink
+
     def window_out(self, i, y, a):
         with jax.named_scope("attn.window"):
             return mm(head_gate(self.wg_window, i, a, y, self.head_dim),

@@ -1,18 +1,21 @@
 """The decode-side K/V read as a walk over each row's block table.
 
-``decode/paged.py`` keeps the cache as a pool of blocks, ``k/v [L,
-n_blocks, block, H_kv*dh]``, and a sequence names its blocks through a
-table. The plain read (``paged.gathered_decode_attn``) gathers every
-row's WHOLE table — capacity, not length — into a copy and runs two
-products over the copy. ``walk_attn`` is the same two products over the
-rows where they lie: one Pallas kernel a layer that, for each batch
-row, fetches only the row's live blocks, those that hold its attendable
-positions ``[start, length)``, from the pool in HBM into a
-double-buffered VMEM scratch, several blocks a copy step, and folds each
-step's scores into a running float32 maximum, sum and accumulator (the
-online softmax). Nothing of a gathered view's size exists: no gather,
-no copy, no ``[b, H, T_cap]`` scores, and the bytes that move are the
-live rows'.
+``decode/paged.py`` keeps the cache as a pool of blocks, ``k [L,
+n_blocks, block, H_kv*dh]`` and ``v [L, n_blocks, block, H_kv*dv]``
+(two arrays, whose rows need not be equally wide), and a sequence names
+its blocks through a table. The plain read
+(``paged.gathered_decode_attn``) gathers every row's WHOLE table —
+capacity, not length — into a copy and runs two products over the copy.
+``walk_attn`` is the same two products over the rows where they lie: one
+Pallas kernel a layer that, for each batch row, fetches only the row's
+live blocks, those that hold its attendable positions ``[start,
+length)``, from the pool in HBM into a double-buffered VMEM scratch,
+several blocks a copy step, and folds each step's scores into a running
+float32 maximum, sum and accumulator (the online softmax; where a head
+has a SINK, a term of the denominator with no value row, the maximum
+starts at the sink and the sum at 1, and the sink costs no column and
+no copy). Nothing of a gathered view's size exists: no gather, no copy,
+no ``[b, H, T_cap]`` scores, and the bytes that move are the live rows'.
 
 A table is read as a RING: block ``j`` of the sequence lies in entry ``j
 mod MB``. For the full kind ``start`` is 0 and ``j < MB``, so the table
@@ -62,18 +65,24 @@ def blocks_a_step(block: int, row_bytes: int, max_blocks: int) -> int:
 
 
 def _walk_kernel(layer_ref, tables_ref, starts_ref, lengths_ref, q_ref,
-                 k_hbm, v_hbm, o_ref, *rest, steps: int, scale: float):
+                 k_hbm, v_hbm, *rest, steps: int, scale: float,
+                 sunk: bool):
     """One batch row a grid step: the blocks ``starts // block ..
     (lengths - 1) // block`` of its sequence, ``steps`` of them a copy
     step, each from the table's entry of its number modulo the table's
-    width. ``kbuf/vbuf [2, steps * block, J]``
-    are the two buffers of each side; ``slot_ref`` says which of them
+    width. ``kbuf [2, steps * block, J]`` and ``vbuf [2, steps * block,
+    Jv]`` are the two buffers of each side, each as wide as its side's
+    row; ``slot_ref`` says which of them
     the row's FIRST copy step is in (the row before started it, before
     its own last product), ``sems [side, buffer]`` count the copies.
     Where the call asks for the softmax statistics, two more outputs
     stand before the scratch: each head's score maximum and its sum of
-    ``exp(s - m)``, a whole tile of lanes wide."""
-    *stats, kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref = rest
+    ``exp(s - m)``, a whole tile of lanes wide. ``sunk``: one more
+    input stands before the outputs, ``sink_ref [H, 1]``, each head's
+    sink: ``exp(sink - m)`` is in the sum from the start."""
+    if sunk:
+        sink_ref, *rest = rest
+    o_ref, *stats, kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref = rest
     r = pl.program_id(0)
     blk = kbuf.shape[1] // steps
     entries = tables_ref.shape[1]
@@ -118,8 +127,12 @@ def _walk_kernel(layer_ref, tables_ref, starts_ref, lengths_ref, q_ref,
     first = slot_ref[0]
     n_steps = pl.cdiv(live(r), steps)
     start, length = starts_ref[r], lengths_ref[r]
-    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    if sunk:
+        m_ref[...] = sink_ref[...]
+        l_ref[...] = jnp.ones_like(l_ref)
+    else:
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
     q = q_ref[...]                                      # [H, J]
 
@@ -175,54 +188,67 @@ def _walk_kernel(layer_ref, tables_ref, starts_ref, lengths_ref, q_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("steps", "scale", "interpret", "stats"))
-def _walk(layer, tables, starts, lengths, q, k_pool, v_pool, *, steps: int,
-          scale: float, interpret: bool, stats: bool = False):
+def _walk(layer, tables, starts, lengths, q, k_pool, v_pool, sink=None, *,
+          steps: int, scale: float, interpret: bool, stats: bool = False):
     """The kernel's call, jitted on its own: ``layer [1]`` is an
     operand, so the calls of every layer of a step program are ONE
     traced function — lowered (the kernel to Mosaic's module) once a
     program and called a layer, not once a layer. A program of 36
     layers spent 1.4 s of every start-up lowering 36 copies."""
     b, h, j = q.shape
-    blk = k_pool.shape[2]
+    blk, jv = k_pool.shape[2], v_pool.shape[3]
     row = pl.BlockSpec((None, h, j), lambda r, *_: (r, 0, 0))
     whole = pl.BlockSpec(memory_space=pl.ANY)
-    out_specs, out_shape = row, jax.ShapeDtypeStruct((b, h, j), jnp.float32)
+    out_specs = pl.BlockSpec((None, h, jv), lambda r, *_: (r, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((b, h, jv), jnp.float32)
+    in_specs, operands = [row, whole, whole], [q, k_pool, v_pool]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((h, 1), lambda r, *_: (0, 0)))
+        operands.append(sink.astype(jnp.float32).reshape(h, 1))
     if stats:
         stat = pl.BlockSpec((None, h, ssm._LANES), lambda r, *_: (r, 0, 0))
         wide = jax.ShapeDtypeStruct((b, h, ssm._LANES), jnp.float32)
-        out_specs, out_shape = [row, stat, stat], [out_shape, wide, wide]
+        out_specs, out_shape = ([out_specs, stat, stat],
+                                [out_shape, wide, wide])
     return pl.pallas_call(
-        functools.partial(_walk_kernel, steps=steps, scale=scale),
+        functools.partial(_walk_kernel, steps=steps, scale=scale,
+                          sunk=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(b,),
-            in_specs=[row, whole, whole], out_specs=out_specs,
+            in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((2, steps * blk, j), k_pool.dtype),
-                pltpu.VMEM((2, steps * blk, j), v_pool.dtype),
+                pltpu.VMEM((2, steps * blk, jv), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((h, 1), jnp.float32),
                 pltpu.VMEM((h, 1), jnp.float32),
-                pltpu.VMEM((h, j), jnp.float32)]),
+                pltpu.VMEM((h, jv), jnp.float32)]),
         out_shape=out_shape,
         interpret=interpret,
-    )(layer, tables, starts, lengths, q, k_pool, v_pool)
+    )(layer, tables, starts, lengths, *operands)
 
 
 def walk_attn(k_pool: jax.Array, v_pool: jax.Array, layer: int,
               q: jax.Array, tables: jax.Array, starts: jax.Array,
-              lengths: jax.Array, scale: float, stats: bool = False):
+              lengths: jax.Array, scale: float, stats: bool = False,
+              sink=None):
     """Single-query attention of ``b`` rows over their own blocks of
-    one layer of the pool, where they lie. ``k_pool/v_pool [L, n_blocks,
-    block, J]`` stay in HBM whole; ``q [b, H, J]`` in the pool's dtype
+    one layer of the pool, where they lie. ``k_pool [L, n_blocks,
+    block, J]`` and ``v_pool [L, n_blocks, block, Jv]`` stay in HBM
+    whole; ``q [b, H, J]`` in the pool's dtype
     is each head's query laid out FOR a stored row (zero outside its KV
     head's lanes: ``decode/paged.py`` builds it); ``tables [b, MB]``,
     block ``j`` of a sequence in entry ``j mod MB``; ``starts [b]`` /
     ``lengths [b]`` the first attendable position and the one after the
     last (the full kind: ``starts`` 0; a ring: no more blocks between
-    them than ``MB``). Returns ``[b, H, J]`` float32: ``softmax(scale *
+    them than ``MB``). Returns ``[b, H, Jv]`` float32: ``softmax(scale *
     q K^T) V`` over the positions ``starts <= t < lengths``, of which
-    head ``h`` keeps its KV head's lanes.
+    head ``h`` keeps its KV head's lanes. ``sink [H]`` float32: head
+    ``h``'s softmax has ``exp(sink_h - m)`` more in its denominator
+    (``models/attention.py::softmax_stats``) — the kernel's running
+    maximum starts at the sink and its sum at 1; without one the call
+    is the kernel it was.
 
     The layer is a scalar operand, so every layer's call is the same
     kernel to compile. Operands in the pool's dtype, sums in float32. A
@@ -232,18 +258,22 @@ def walk_attn(k_pool: jax.Array, v_pool: jax.Array, layer: int,
 
     ``stats``: ``(o, m [b, H], l [b, H])``, the result beside each
     head's score maximum and its sum of ``exp(s - m)``, which the
-    kernel holds in its scratch anyway: what a join with another read
-    of the same queries needs (``decode/paged.py::join_reads``). A row
-    of length 0 reads its first block masked whole: ``m`` is the mask's
-    value and the join gives the read no weight."""
+    kernel holds in its scratch anyway (a sink counted in both): what a
+    join with another read of the same queries needs
+    (``decode/paged.py::join_reads``). A row of length 0 reads its
+    first block masked whole: without a sink ``m`` is the mask's value
+    and the join gives the read no weight."""
     interpret = ssm._interpreted()
-    j = q.shape[-1]
-    if not interpret and j % ssm._LANES:
-        raise ValueError(ssm._UNTILED.format(d=j))
-    steps = blocks_a_step(k_pool.shape[2], j * k_pool.dtype.itemsize,
+    for j in (q.shape[-1], v_pool.shape[-1]):
+        if not interpret and j % ssm._LANES:
+            raise ValueError(ssm._UNTILED.format(d=j))
+    # a copy step holds the same blocks of both sides: the wider row's
+    # bytes say how many
+    wide = max(k_pool.shape[-1], v_pool.shape[-1])
+    steps = blocks_a_step(k_pool.shape[2], wide * k_pool.dtype.itemsize,
                           tables.shape[1])
     got = _walk(jnp.asarray([layer], jnp.int32), tables, starts, lengths,
-                q, k_pool, v_pool, steps=steps, scale=scale,
+                q, k_pool, v_pool, sink, steps=steps, scale=scale,
                 interpret=interpret, stats=stats)
     if not stats:
         return got

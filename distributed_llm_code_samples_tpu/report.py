@@ -79,7 +79,8 @@ from .runtime.telemetry import (FLIGHT_FILENAME, METRICS_FILENAME,
                                 RECORD_KINDS,
                                 ROUTER_POSTMORTEM_PREFIX,
                                 STATUS_FILENAME, STEP_SPAN, STEP_SPAN_KV,
-                                STEP_SPAN_RING, read_metrics)
+                                STEP_SPAN_RING, STEP_SPAN_ROW_BYTES,
+                                read_metrics)
 
 # a completed request's span durations telescope to its latency by
 # construction (runtime/tracing.py); the tolerance only absorbs the
@@ -116,6 +117,12 @@ def _fmt_bytes(n: int | None) -> str:
             return f"{n:.1f} {unit}"
         n /= 1024
     return f"{n:.1f} PiB"
+
+
+def _row_bytes(n: int | None) -> str:
+    """The tail of a cache-reads line: what a position of the store
+    takes in one layer, where the program's records said."""
+    return f"; a position is {n} bytes a layer" if n else ""
 
 
 def _fmt_t(t: float, t0: float) -> str:
@@ -614,13 +621,17 @@ class _Stream:
         blocks' turnover (``window_blocks_released`` in all,
         ``window_blocks_live`` at most) and, of a chunked layer (v23),
         the chunk summaries a step's rows attend over and those
-        written. None where the records hold neither."""
+        written. ``blocks`` also holds each store's bytes a position a
+        layer where the program wrote them (``STEP_SPAN_ROW_BYTES``,
+        None from an older stream). None where the records hold
+        neither."""
         kv = [r for r in self.step_spans if r.get("kv_blocks_capacity")]
         blocks = None if not kv else {
             "steps": len(kv),
             **{f"{key}_mean": round(float(np.mean(
                 [r.get(key, 0) for r in kv])), 2)
-               for key in STEP_SPAN_KV + STEP_SPAN_RING}}
+               for key in STEP_SPAN_KV + STEP_SPAN_RING},
+            **{key: kv[0].get(key) for key in STEP_SPAN_ROW_BYTES}}
         recs = [r for r in self.step_spans if r.get("window_rows")]
         if not recs:
             return None if blocks is None else {"blocks": blocks}
@@ -1900,12 +1911,14 @@ def _render_engine_sections(out: list, doc: dict) -> None:
             out.append(
                 f"  cache reads: {read} blocks a step fetched by the "
                 f"decode-side reads, of {held} in their rows' tables "
-                f"({100 * read / held:.1f}%; {kb['steps']} step(s))")
+                f"({100 * read / held:.1f}%; {kb['steps']} step(s))"
+                + _row_bytes(kb["kv_row_bytes"]))
             if kb["ring_blocks_capacity_mean"]:
                 out.append(
                     f"  cache reads: {kb['ring_blocks_read_mean']} blocks "
                     "a step fetched of the window layers' rings, of "
-                    f"{kb['ring_blocks_capacity_mean']} entries")
+                    f"{kb['ring_blocks_capacity_mean']} entries"
+                    + _row_bytes(kb["window_row_bytes"]))
         if cr and "steps" in cr:
             out.append(
                 f"  cache reads: {cr['window_rows_mean']} positions a "

@@ -294,6 +294,17 @@ import numpy as np
 # or none, whole, not negative, no more read than the capacity
 # (``validate_record``); the ``decode`` record carries the two as
 # cumulative extras. Readers: ``report.py``'s cache-reads line.
+# v24, additive (PR 49; no bump: a record without the group reads as
+# before): each store's BYTES a position — the ``engine_step`` record
+# and the ``decode`` record may carry ``kv_row_bytes`` and
+# ``window_row_bytes`` (``STEP_SPAN_ROW_BYTES``; the engine writes
+# both), what ONE cached position takes in ONE layer of the full kind's
+# pool and of the window layers', its K row and its V row as the arrays
+# hold them (the two sides' rows may differ in width, and the two
+# stores' in KV heads: ``models/face.py::KVRow``); 0 for a store the
+# model has not. Both or none, whole, not negative
+# (``validate_record``). Readers: ``report.py``'s cache-reads line
+# (bytes beside blocks), ``benchmark/sink_window_trace.py``.
 SCHEMA_VERSION = 24
 
 METRICS_FILENAME = "metrics.jsonl"
@@ -461,6 +472,8 @@ STEP_SPAN_KV = ("kv_blocks_read", "kv_blocks_capacity")
 STEP_SPAN_RING = ("ring_blocks_read", "ring_blocks_capacity")
 # ... and a chunked layer's two, likewise (v23)
 STEP_SPAN_CHUNKS = ("summary_rows", "summaries_written")
+# ... and each store's bytes a position a layer (v24, additive)
+STEP_SPAN_ROW_BYTES = ("kv_row_bytes", "window_row_bytes")
 
 # The router-record contract (``decode/fleet.py``): one record per
 # fleet-router decision. ``step`` is the ROUTER's step clock (fleet
@@ -1148,15 +1161,17 @@ def validate_record(rec: Any) -> tuple[bool, str]:
                         f"{ {k: rec[k] for k in got} }: both of "
                         f"{[read, held]} or none, whole, not negative, "
                         f"{read} <= {held}")
-            got = [k for k in STEP_SPAN_CHUNKS if k in rec]
-            if got and (len(got) != len(STEP_SPAN_CHUNKS) or any(
-                    not isinstance(rec[k], int) or rec[k] < 0
-                    for k in got)):
-                return False, (f"span record (span {STEP_SPAN}) has the "
-                               f"chunk summaries' counters "
-                               f"{ {k: rec[k] for k in got} }: both of "
-                               f"{list(STEP_SPAN_CHUNKS)} or none, whole, "
-                               "not negative")
+            for group, what in (
+                    (STEP_SPAN_CHUNKS, "chunk summaries' counters"),
+                    (STEP_SPAN_ROW_BYTES, "stores' bytes a position")):
+                got = [k for k in group if k in rec]
+                if got and (len(got) != len(group) or any(
+                        not isinstance(rec[k], int) or rec[k] < 0
+                        for k in got)):
+                    return False, (
+                        f"span record (span {STEP_SPAN}) has the {what} "
+                        f"{ {k: rec[k] for k in got} }: both of "
+                        f"{list(group)} or none, whole, not negative")
         elif rec["uid"] is None:
             return False, (f"span record (span {rec['span']}) has a "
                            f"null 'uid': only {STEP_SPAN} belongs to "

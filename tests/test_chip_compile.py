@@ -816,6 +816,126 @@ def test_window_moe_step_program_keeps_both_pools_as_stored(
         assert sorted(one) == chunk, one
 
 
+# the sink-softmax, split-width expert cell of the benchmark
+# (mimo-v2-flash.longreason-offline): MiMo-V2-Flash's widths, 16 of 256
+# experts held, 64 query heads over 4 KV heads on a full layer and 8 on
+# a window layer, a key head of 192 lanes beside a value head of 128, an
+# eighth of the vocabulary, 64 slots x 3072 positions, bf16 weights and
+# K/V in TWO pools with rows of their own, cut to 3 layers (published
+# layers 0 to 2: the dense full-attention layer and two sliding-window
+# layers with experts) so the compile stays short
+MIMO = dict(layers=3, slots=64, mbps=192, chunk=16)
+
+
+@pytest.fixture(scope="module")
+def mimo_engine_args():
+    """``(engine, {kind: (bucket, args)})`` at the cell's widths, over
+    shapes alone: the configuration's own file through the family's
+    ``spec_from_config``, the step programs as ``DecodeEngine`` builds
+    them, both pools in the carry."""
+    import json
+    from distributed_llm_code_samples_tpu.decode import EngineConfig
+    from distributed_llm_code_samples_tpu.decode.programs import StepPrograms
+    from distributed_llm_code_samples_tpu.models import mimo_v2_flash_lm
+    g = MIMO
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2-flash-serve.json")) as f:
+        config = json.load(f)
+    n = g["layers"]
+    config = dict(config, num_hidden_layers=n, **{
+        k: config[k][:n] for k in ("hybrid_layer_pattern",
+                                   "moe_layer_freq")})
+    spec = mimo_v2_flash_lm.spec_from_config(config)
+    params = jax.eval_shape(
+        lambda k: mimo_v2_flash_lm.init_mimo_v2_flash_lm(
+            k, spec, dtype=jnp.bfloat16), jax.random.PRNGKey(0))
+    slots, mbps, chunk = g["slots"], g["mbps"], g["chunk"]
+    programs = StepPrograms(
+        EngineConfig(n_blocks=1 + slots * mbps, max_slots=slots,
+                     max_blocks_per_seq=mbps, prefill_chunk=chunk,
+                     kv_dtype="bf16"),
+        params.cache_spec(64), params.vocab)
+    eng = _ShapesEngine(programs, params,
+                        jax.eval_shape(lambda: programs.init_cache()[0]))
+    eng.wpool = jax.eval_shape(programs.init_window)
+    eng._cache = lambda: programs.whole(eng.pool, eng.wpool)
+    return eng, _cell_programs(eng, slots, chunk)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
+def test_sink_moe_step_program_keeps_both_pools_as_stored(
+        one_chip, mimo_engine_args, kernels_for_the_chip, kind):
+    """Two pools with rows of their own in a step program
+    (``models/face.py::KVRow``): the full layers' ``k [1, 12289, 16,
+    768]`` beside ``v [.., 512]`` and the window layers' ``k [2, 641,
+    16, 1536]`` beside ``v [.., 1024]`` are all four taken row-major and
+    unpadded, aliased whole and updated in place, never copied and no
+    layer's slab sliced out; both pools take the walk (whole 128-lane
+    tiles on either side), so a batch's rows are not gathered at all:
+    one kernel call a layer, the full layers' over the table with the
+    result ``f32[b,64,512]`` (V's lanes), the window layers' over the
+    ring of 10 entries with ``f32[b,64,1024]`` and the layer's 64 sinks
+    as one more operand, ``f32[64,1]``; only a prefill chunk's one slot
+    is gathered, a window layer's at its ring — 160 positions — and the
+    full layer's at the table's capacity. The result carries the held
+    experts' counters after the picks."""
+    import re
+    eng, programs = mimo_engine_args
+    bucket, args = programs[kind]
+    compiled = eng._program(kind, bucket).lower(
+        *_shapes_of(args, one_chip)).compile()
+    hlo = compiled.as_text()
+    pool, wpool = eng.pool, eng.wpool
+    assert eng.programs.window_blocks == 10
+    assert pool.k.shape == (1, 12289, 16, 768)
+    assert pool.v.shape == (1, 12289, 16, 512)
+    assert wpool.k.shape == (2, 641, 16, 1536)
+    assert wpool.v.shape == (2, 641, 16, 1024)
+    from distributed_llm_code_samples_tpu.decode import paged
+    assert paged.walks(pool) and paged.walks(wpool)
+    slab = wpool.v.size // wpool.v.shape[0]
+    moved = [r for r in _hlo_results(
+        hlo, ("copy", "slice", "dynamic-slice"), "bf16")
+        if r[1] >= slab and r[1] % slab == 0]
+    assert not moved, moved
+    (full_fmt, win_fmt), _ = compiled.input_formats[0][1]
+    sides = (pool.k, pool.v, wpool.k, wpool.v)
+    for fmt, arr in zip((full_fmt.k, full_fmt.v, win_fmt.k, win_fmt.v),
+                        sides):
+        assert fmt.layout.major_to_minor == tuple(range(arr.ndim)), fmt
+    m = compiled.memory_analysis()
+    held = sum(_nbytes(x) for x in sides) + _nbytes(eng.token_store)
+    assert m.alias_size_in_bytes >= held
+    assert _carry_is_aliased_whole(compiled, eng) == held
+    logical = sum(_nbytes(x) for x in jax.tree_util.tree_leaves(args[:2]))
+    assert m.argument_size_in_bytes - logical < _nbytes(wpool.v) // 100
+    assert _total_bytes(compiled) < HBM_V5E
+    picks = {"decode": bucket, "prefill": 1, "mixed": bucket + 1}[kind]
+    out = jax.eval_shape(eng.programs.body(kind, bucket), *args)[1]
+    assert out.shape == (picks + 2 * 16,) and out.dtype == jnp.int32
+    # no gathered view in a decode-side program's batch: the gathers,
+    # by their results, are a chunk's one slot alone (K and V of the
+    # two window layers at the ring, of the full layer at capacity)
+    batch = re.findall(r"= bf16\[%d,\d+,16,\d+\]\S* gather\(" % bucket, hlo)
+    assert not batch, batch
+    got = sorted((int(n), int(j)) for n, j in re.findall(
+        r"= bf16\[(\d+),16,(\d+)\]\S* gather\(", hlo))
+    assert got == ([] if kind == "decode" else sorted(
+        [(10, 1536), (10, 1024)] * 2 + [(192, 768), (192, 512)])), got
+    walk = [l for l in hlo.splitlines() if MOSAIC in l]
+    lanes = sorted(l.split(" custom-call(")[0].split("f32[%d,64," % bucket)[1]
+                   .split("]")[0] for l in walk)
+    assert lanes == ([] if kind == "prefill" else ["1024", "1024", "512"])
+    for l in walk:
+        ring = "f32[%d,64,1024]" % bucket in l.split(" custom-call(")[0]
+        assert ("bf16[2,641,16,1536]" in l and "bf16[2,641,16,1024]" in l
+                and "s32[%d,10]" % bucket in l
+                and "f32[64,1]" in l) is ring, l
+        assert ("bf16[1,12289,16,768]" in l and "bf16[1,12289,16,512]" in l
+                and "s32[%d,192]" % bucket in l) is not ring, l
+
+
 # the chunk-summarised attention cell of the benchmark
 # (evabyte.bytereason-offline): EvaByte's widths, 32 heads of 128 lanes
 # (no grouping), window 2,048 in chunks of 16, all 320 byte ids and the
@@ -1177,7 +1297,8 @@ def test_decode_program_reads_the_gathered_rows_as_stored(
 @pytest.mark.parametrize("cell", ["gpt2-large", "gpt2-large-f32",
                                   "jamba2-3b", "lfm2-24b-a2b",
                                   "laguna-s-2.1", "evabyte",
-                                  "laguna-ring", "evabyte-ring"])
+                                  "laguna-ring", "evabyte-ring",
+                                  "mimo-full", "mimo-ring"])
 def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
                                               kernels_for_the_chip, cell):
     """The walk alone, for the described v5e, at each serving cell's
@@ -1188,9 +1309,18 @@ def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
     the start of its range), the pool is neither copied nor held twice,
     and what the kernel keeps of the chip's fast memory follows from the
     row's bytes: so many blocks a copy step that the four buffers fit
-    ``ssm._VMEM_BUDGET``."""
+    ``ssm._VMEM_BUDGET``. The sink-softmax cell's two stores have a K
+    side of ``H_kv x 192`` lanes beside a V side of ``H_kv x 128`` (768
+    / 512 under a table of 192 entries, 1,536 / 1,024 under a ring of
+    10 with the layer's 64 sinks as an operand): the result is as wide
+    as V's row and a copy step's blocks follow from the WIDER row's
+    bytes (32 and 16: one step holds a ring's 9 live blocks)."""
     from distributed_llm_code_samples_tpu.ops import kv_walk, ssm
+    dv, sunk = (128, cell == "mimo-ring") if cell.startswith("mimo") else (
+        None, False)
     b, h, hkv, dh, mb, layers = {
+        "mimo-full": (64, 64, 4, 192, 192, 2),
+        "mimo-ring": (64, 64, 8, 192, 10, 9),
         "gpt2-large": (12, 20, 20, 64, 64, 36),
         "gpt2-large-f32": (12, 20, 20, 64, 64, 36),
         "jamba2-3b": (64, 20, 1, 128, 128, 2),
@@ -1204,23 +1334,31 @@ def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
         "evabyte-ring": (24, 32, 32, 128, 130, 8)}[cell]
     stats = cell.startswith("evabyte")
     dt = jnp.float32 if cell.endswith("f32") else jnp.bfloat16
-    j, blk = hkv * dh, 16
+    j, jv, blk = hkv * dh, hkv * (dv or dh), 16
     steps = kv_walk.blocks_a_step(blk, j * dt.dtype.itemsize, mb)
     assert steps & (steps - 1) == 0 and (steps * blk) % 128 == 0
-    assert 4 * steps * blk * j * dt.dtype.itemsize <= ssm._VMEM_BUDGET
+    assert 2 * steps * blk * (j + jv) * dt.dtype.itemsize <= (
+        ssm._VMEM_BUDGET)
+    if dv:
+        assert steps == {"mimo-full": 32, "mimo-ring": 16}[cell]
     shape = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    side = shape((layers, 1 + b * mb, blk, j), dt)
+    k_side = shape((layers, 1 + b * mb, blk, j), dt)
+    v_side = shape((layers, 1 + b * mb, blk, jv), dt)
+    sink = {"sink": shape((h,), jnp.float32)} if sunk else {}
     compiled = jax.jit(functools.partial(
         kv_walk.walk_attn, layer=layers - 1, scale=dh ** -0.5,
         stats=stats)).lower(
-            side, side, q=shape((b, h, j), dt),
+            k_side, v_side, q=shape((b, h, j), dt),
             tables=shape((b, mb), jnp.int32),
             starts=shape((b,), jnp.int32),
-            lengths=shape((b,), jnp.int32)).compile()
-    assert sum(MOSAIC in l for l in compiled.as_text().splitlines()) == 1
+            lengths=shape((b,), jnp.int32), **sink).compile()
+    hlo = compiled.as_text()
+    assert sum(MOSAIC in l for l in hlo.splitlines()) == 1
+    assert "f32[%d,%d,%d]" % (b, h, jv) in hlo      # as wide as V's row
     m = compiled.memory_analysis()
     # the two sides and the queries, once each
-    nbytes = (2 * int(np.prod(side.shape)) + b * h * j) * dt.dtype.itemsize
+    nbytes = (int(np.prod(k_side.shape)) + int(np.prod(v_side.shape))
+              + b * h * j) * dt.dtype.itemsize
     assert m.argument_size_in_bytes - nbytes < 2 ** 20
     assert m.temp_size_in_bytes < 2 ** 20
 
@@ -1236,6 +1374,8 @@ REHEARSED = {
     "laguna-s-2.1.longreason-offline": ("shrink_laguna",
                                         "window_pool_util"),
     "evabyte.bytereason-offline": ("shrink_evabyte", "summary_rows_share"),
+    "mimo-v2-flash.longreason-offline": ("shrink_mimo",
+                                         "share_rows_max_over_mean"),
 }
 
 
@@ -1246,9 +1386,9 @@ def test_cell_rehearsal_on_the_cpu(monkeypatch, name, trace):
     ``benchmark/tests/test_rehearsal.py`` rehearses the older cells
     (its ``shrink.py`` knows those only; these cells' shrinks are
     ``benchmark/tests/shrink_jamba.py``, ``shrink_glm.py``,
-    ``shrink_lfm2.py``, ``shrink_laguna.py`` and ``shrink_evabyte.py``,
-    the last under a clock that moves by the engine's steps). Nothing
-    here is a measurement."""
+    ``shrink_lfm2.py``, ``shrink_laguna.py``, ``shrink_evabyte.py`` and
+    ``shrink_mimo.py``, the last two under a clock that moves by the
+    engine's steps). Nothing here is a measurement."""
     import importlib
     import json
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -330,3 +330,57 @@ def test_the_cache_read_has_no_option(capsys):
                        "--max_new", "2"])
     assert err.value.code == 2
     assert f"unrecognized arguments: --{gone}" in capsys.readouterr().err
+
+
+def test_cache_spec_gives_each_store_a_row_of_its_own():
+    """``CacheSpec.row`` / ``.window_row`` (``models/face.py::KVRow``)
+    are the ONE description each pool is built from: a spec that states
+    neither a value width nor a window row (every family before the
+    seventh) gives both stores the full kind's ``kv_heads x head_dim``
+    row on either side, as it always did; one that states them gives the
+    full kind's pool ``H_kv x dk`` lanes of K beside ``H_kv x dv`` of V
+    and the window kind's pool its own KV heads, and the pool's specs
+    under a mesh are the same tree as the pool."""
+    from distributed_llm_code_samples_tpu.decode.programs import StepPrograms
+    from distributed_llm_code_samples_tpu.models.face import KVRow
+    cfg = EngineConfig(block_size=8, n_blocks=11, max_slots=2,
+                       max_blocks_per_seq=5, kv_dtype="bf16")
+    same = CacheSpec(2, 4, 8, win_layers=3, window=16)
+    assert same.row == same.window_row == KVRow(4, 8, 8)
+    progs = StepPrograms(cfg, same, 96)
+    pool, wpool = progs.init_cache()[0], progs.init_window()
+    assert pool.k.shape == pool.v.shape == (2, 11, 8, 32)
+    assert wpool.k.shape == wpool.v.shape == (3, 1 + 2 * 5, 8, 32)
+    assert pool.row == wpool.row == KVRow(4, 8, 8)
+    split = CacheSpec(2, 2, 24, win_layers=5, window=16, v_head_dim=16,
+                      win_row=KVRow(4, 24, 16))
+    assert split.row == KVRow(2, 24, 16)
+    assert split.window_row == KVRow(4, 24, 16)
+    progs = StepPrograms(cfg, split, 96)
+    pool, wpool = progs.init_cache()[0], progs.init_window()
+    assert pool.k.shape == (2, 11, 8, 48) and pool.v.shape == (2, 11, 8, 32)
+    assert wpool.k.shape == (5, 11, 8, 96)
+    assert wpool.v.shape == (5, 11, 8, 64)
+    assert (pool.row, wpool.row) == (split.row, split.window_row)
+    assert pool.kv_heads == 2 and wpool.kv_heads == 4
+    assert (jax.tree_util.tree_structure(progs.pool_specs())
+            == jax.tree_util.tree_structure(pool))
+
+
+def test_qkv_heads_splits_values_by_their_own_width():
+    """``qkv_heads(v_head_dim=)``: ``W_v [H_kv * dv, d]`` gives ``v [N,
+    H_kv, dv]`` beside ``k [N, H_kv, dh]``; without it a value head is
+    as wide as a key head."""
+    from distributed_llm_code_samples_tpu.models.face import qkv_heads
+    rng = np.random.default_rng(0)
+    a = jnp.asarray(rng.normal(size=(5, 32)), jnp.float32)
+    wq, wk, wv = (jnp.asarray(rng.normal(size=(1, n, 32)), jnp.float32)
+                  for n in (8 * 24, 2 * 24, 2 * 16))
+    q, k, v = qkv_heads(wq, wk, wv, 0, a, jnp.arange(5), 24, False,
+                        v_head_dim=16)
+    assert (q.shape, k.shape, v.shape) == ((5, 8, 24), (5, 2, 24),
+                                           (5, 2, 16))
+    np.testing.assert_allclose(np.asarray(v).reshape(5, 32),
+                               np.asarray(a @ wv[0].T), rtol=1e-6)
+    _, _, v = qkv_heads(wq, wk, wk, 0, a, jnp.arange(5), 24, False)
+    assert v.shape == (5, 2, 24)

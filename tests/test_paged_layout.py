@@ -612,3 +612,162 @@ def test_walked_ring_matches_the_plain_read_and_the_oracle(rule, kv_dtype,
                                    atol=tol)
         np.testing.assert_allclose(got[1], plain[1], rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(got[2], plain[2], rtol=1e-4)
+
+
+# -- K and V rows of different widths, and a sink in the softmax -------------
+# (``models/face.py::KVRow``: a key head of 12 lanes beside a value head
+# of 8, as MiMo-V2-Flash's 192 / 128 in small)
+
+S_HQ, S_HKV, S_DK, S_DV = 6, 2, 12, 8
+
+
+def _split_case(kv_dtype, ring: bool):
+    """``_ring_case``'s batch (or the same lengths as a full pool's
+    tables, ``ring`` False) over a pool whose K row is ``2 x 12`` lanes
+    and whose V row is ``2 x 8``, every byte random and finite, the
+    stale rows beyond each row's last position large."""
+    rng = np.random.default_rng(5)
+    b = len(R_LENGTHS)
+    entries = R_ENTRIES if ring else -(-int(R_LENGTHS.max()) // R_BLK)
+    nb = 1 + (b - 1) * entries
+    tables = 1 + rng.permutation((b - 1) * entries).reshape(b - 1, -1)
+    tables = np.concatenate(
+        [tables, np.full((1, entries), SCRATCH_BLOCK)]).astype(np.int32)
+    pool = init_pool(2, nb, S_HKV, R_BLK, S_DK, kv_dtype, v_head_dim=S_DV)
+    assert pool.k.shape[-1] == S_HKV * S_DK
+    assert pool.v.shape[-1] == S_HKV * S_DV
+    assert pool.row == (S_HKV, S_DK, S_DV) and pool.kv_heads == S_HKV
+    pos = (np.asarray(ring_positions(R_LENGTHS - 1, entries, R_BLK)) if ring
+           else np.broadcast_to(np.arange(entries * R_BLK),
+                                (b, entries * R_BLK)))
+    beyond = np.zeros((nb, R_BLK), bool)
+    for r in range(b):
+        beyond[tables[r]] |= (pos[r] >= R_LENGTHS[r]).reshape(entries, R_BLK)
+    sides = []
+    for side in (pool.k, pool.v):
+        src = rng.normal(size=side.shape).astype(np.float32)
+        sides.append(jnp.asarray(
+            np.where(beyond[None, :, :, None], 3e4, src), side.dtype))
+    pool = pool._replace(k=sides[0], v=sides[1])
+    q = jnp.asarray(rng.normal(size=(b, S_HQ, S_DK)), jnp.float32)
+    sink = jnp.asarray(rng.normal(size=(S_HQ,)) + 1.0, jnp.float32)
+    return pool, q, jnp.asarray(tables), pos, sink
+
+
+@pytest.mark.parametrize("case", ["ring-f32-sink", "ring-bf16-sink",
+                                  "ring-f32-sink-stats", "full-f32-plain",
+                                  "full-bf16-sink"])
+def test_walk_over_split_widths_and_a_sink_matches_the_plain_read(case):
+    """The walk with a K row and a V row of different widths (the
+    result's lanes are V's) and a per-head sink (where the running
+    maximum and sum start: ``ops/kv_walk.py``), against
+    ``gathered_decode_attn`` handed the same and against a NumPy model
+    that writes the sink as what it is: one more term of the
+    denominator, ``exp(sink - m)``, with no value row. Only the cases
+    that differ in a code path: the ring under the sliding rule at both
+    dtypes, the statistics (which count the sink), the full kind with
+    and without a sink."""
+    kind, kv_dtype, *opts = case.split("-")
+    ring, sunk, stats = kind == "ring", "sink" in opts, "stats" in opts
+    pool, q, tables, pos, sink = _split_case(kv_dtype, ring)
+    sink = sink if sunk else None
+    lengths = jnp.asarray(R_LENGTHS)
+    window = R_WINDOW if ring else 0
+
+    def read(fn):
+        return [np.asarray(x) for x in jax.tree.leaves(jax.jit(
+            lambda q: fn(pool, 1, q, tables, lengths, window, False, stats,
+                         sink))(q))]
+
+    assert walks(pool)
+    got, plain = read(stored_decode_attn), read(gathered_decode_attn)
+    last = R_LENGTHS - 1
+    start = np.maximum(last - R_WINDOW + 1, 0) if ring else 0 * last
+    live = (pos >= start[:, None]) & (pos <= last[:, None])
+    b, h, _ = q.shape
+    kc = np.asarray(_heads_major(pool.k[1][np.asarray(tables)], S_DK),
+                    np.float64).transpose(0, 2, 1, 3, 4).reshape(
+                        b, S_HKV, -1, S_DK)
+    vc = np.asarray(_heads_major(pool.v[1][np.asarray(tables)], S_DV),
+                    np.float64).transpose(0, 2, 1, 3, 4).reshape(
+                        b, S_HKV, -1, S_DV)
+    qg = np.asarray(q, np.float64).reshape(b, S_HKV, h // S_HKV, S_DK)
+    s = np.einsum("bkgd,bktd->bkgt", qg, kc) / np.sqrt(S_DK)
+    s = np.where(live[:, None, None, :], s, -np.inf)
+    m = s.max(-1)
+    if sunk:
+        sk = np.asarray(sink, np.float64).reshape(S_HKV, -1)
+        m = np.maximum(m, sk)
+    e = np.exp(s - m[..., None])
+    total = e.sum(-1) + (np.exp(sk - m) if sunk else 0.0)
+    want = np.einsum("bkgt,bktd->bkgd", e / total[..., None],
+                     np.where(live[:, None, :, None], vc, 0))
+    scale = np.abs(want).max()
+    assert got[0].shape == (b, h, S_DV) and got[0].dtype == np.float32
+    err = np.abs(got[0].reshape(want.shape) - want).max(-1)
+    if kv_dtype == "f32":
+        assert err.max() <= 1e-5 * scale
+        assert np.abs(got[0] - plain[0]).max() <= 1e-5 * scale
+    else:
+        # ``_two_roundings_bound`` normalises by the scores' sum alone;
+        # a sink only makes every probability smaller
+        bound = _two_roundings_bound(qg, kc, vc, live)
+        assert (err <= bound + 1e-5 * scale).all()
+        assert (np.abs(got[0] - plain[0]).reshape(want.shape).max(-1)
+                <= bound + 1e-5 * scale).all()
+    if sunk:
+        # the sink took mass: the same read without it lies far off
+        bare = np.asarray(jax.jit(lambda q: stored_decode_attn(
+            pool, 1, q, tables, lengths, window))(q))
+        assert np.abs(bare - got[0]).max() > 0.05 * scale
+    if stats:
+        np.testing.assert_allclose(got[1], m.reshape(b, h), rtol=1e-5,
+                                   atol=1e-5)
+        np.testing.assert_allclose(got[2], total.reshape(b, h), rtol=1e-4)
+        np.testing.assert_allclose(got[1], plain[1], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(got[2], plain[2], rtol=1e-4)
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "bf16"])
+def test_split_width_rows_come_back_at_position_head_and_lane(kv_dtype):
+    """The writes, the chunk's view and the block documents take each
+    side's width from the pool's row: rows written as a chunk and as
+    decode rows come back from ``gather_layer`` as ``k [H_kv, T, 12]``
+    and ``v [H_kv, T, 8]``; a block document is ``[L, n, H_kv, block,
+    12]`` beside ``[L, n, H_kv, block, 8]`` and implants bit for bit;
+    and a position's bytes are ``H_kv x (12 + 8)`` elements."""
+    from distributed_llm_code_samples_tpu.decode.paged import (
+        kv_bytes_per_token, pool_bytes)
+    rng = np.random.default_rng(7)
+    pool = init_pool(L, NB, HKV, BLK, S_DK, kv_dtype, v_head_dim=S_DV)
+    table = jnp.asarray([3, 1, 5, 0], jnp.int32)
+    k = rng.normal(size=(10, HKV, S_DK)).astype(np.float32)
+    v = rng.normal(size=(10, HKV, S_DV)).astype(np.float32)
+    pool = write_chunk(pool, 1, table, 0, jnp.asarray(k[:8]),
+                       jnp.asarray(v[:8]), kv_dtype)
+    for t in (8, 9):
+        pool = write_rows(pool, 1, table[t // BLK][None],
+                          jnp.asarray([t % BLK]), jnp.asarray(k[t:t + 1]),
+                          jnp.asarray(v[t:t + 1]), kv_dtype)
+    gk, gv = gather_layer(pool, 1, table)
+    assert gk.shape == (HKV, 4 * BLK, S_DK) and gv.shape == (HKV, 4 * BLK,
+                                                             S_DV)
+    tol = _tolerance(kv_dtype, k)
+    assert np.abs(np.asarray(gk)[:, :10].transpose(1, 0, 2) - k).max() <= tol
+    assert np.abs(np.asarray(gv)[:, :10].transpose(1, 0, 2) - v).max() <= tol
+    doc = extract_blocks(pool, [3, 1])
+    assert doc["k"].shape == (L, 2, HKV, BLK, S_DK)
+    assert doc["v"].shape == (L, 2, HKV, BLK, S_DV)
+    fresh = init_pool(L, NB, HKV, BLK, S_DK, kv_dtype, v_head_dim=S_DV)
+    fresh = implant_block(fresh, 6, jnp.asarray(doc["k"][:, 0]),
+                          jnp.asarray(doc["v"][:, 0]))
+    assert np.array_equal(np.asarray(fresh.k[:, 6], np.float32),
+                          np.asarray(pool.k[:, 3], np.float32))
+    assert np.array_equal(np.asarray(fresh.v[:, 6], np.float32),
+                          np.asarray(pool.v[:, 3], np.float32))
+    per_tok = kv_bytes_per_token(kv_dtype, L, HKV, S_DK, v_head_dim=S_DV)
+    assert per_tok == L * HKV * (S_DK + S_DV) * pool.k.dtype.itemsize
+    assert pool_bytes(pool)[0] == per_tok * NB * BLK
+    # equal widths: the byte count it always was
+    assert kv_bytes_per_token(kv_dtype, L, HKV, DH) == (
+        2 * L * HKV * DH * pool.k.dtype.itemsize)

@@ -416,6 +416,109 @@ def test_ring_blocks_read_is_what_the_window_layers_reads_fetch(
         read if walked else held, held)
 
 
+def test_the_sink_familys_record_counts_positions_and_states_bytes(
+        tmp_path, capsys):
+    """The ``engine_step`` record of the family whose two stores have
+    rows of their own (``models/mimo_v2_flash_lm.py``'s toy), counted
+    by hand from what each program was handed: ``full_rows`` the
+    positions up to each row's own, ``window_rows`` at most the window
+    of them, the rings' and the pool's blocks the walks fetch over 5
+    window and 2 full layers, the experts' three over 6 expert layers of
+    8 held experts — POSITIONS and blocks, not bytes — and, carried
+    once a record beside them, each store's BYTES a position a layer
+    from the arrays (``kv_row_bytes`` 2 heads x (24 + 16) lanes x 4,
+    ``window_row_bytes`` twice that: the window layers have 4 KV
+    heads). ``validate_record`` takes the pair together or not at all,
+    and ``report`` prints the bytes on its cache-reads lines."""
+    import test_mimo_v2_flash_lm as toy
+    from distributed_llm_code_samples_tpu.report import report_main
+    from distributed_llm_code_samples_tpu.runtime.telemetry import (
+        STEP_SPAN_ROW_BYTES)
+    driver = toy._load("mimo_v2_flash_engine_driver")
+    params = driver._params(toy.TOY, driver.make_weights(toy.TOY, 11))
+    mdir = str(tmp_path / "m")
+    with TelemetryWriter(mdir) as w:
+        eng = DecodeEngine(params, toy.HEADS, EngineConfig(
+            max_slots=4, n_blocks=1 + 4 * 8, max_blocks_per_seq=8),
+            metrics=w)
+        blk, window = eng.cfg.block_size, eng.spec.window
+        entries = eng.programs.window_blocks
+        assert (window, entries, eng.wpool.k.shape[0],
+                eng.pool.k.shape[0]) == (16, 3, 5, 2)
+        for p in toy.prompts_of([40, 5, 21], seed=3):
+            eng.submit(p, 50)
+        launched, launch = [], eng._launch
+
+        def spy(phase, bucket, fn, params, operand, land):
+            launched.append((phase, eng.programs.wire(
+                phase, bucket).unpack(operand)))
+            return launch(phase, bucket, fn, params, operand, land)
+
+        eng._launch = spy
+        n = 0
+        while eng.active or eng.waiting:
+            del launched[:]
+            eng.step()
+            full = win = ring = ring_cap = kv = kv_cap = 0
+            for phase, f in launched:
+                if "wtable" in f:       # a chunk: ONE view, up to its end
+                    c = len(f.get("chunk", f["tokens"]))
+                    last = int(f["pos0"]) + c - 1
+                    full, win = full + last + 1, win + min(last + 1, window)
+                if phase == "prefill":
+                    continue
+                t = f["lengths"]        # the position each row writes
+                live = (f["uids"] != 0) | (t != 0)
+                full += int((t[live] + 1).sum())
+                win += int(np.minimum(t[live] + 1, window).sum())
+                first = np.maximum(t - window + 1, 0)
+                ring += int((t // blk - first // blk + 1).sum()) * 5
+                ring_cap += len(t) * entries * 5
+                kv += int((t // blk + 1).sum()) * 2
+                kv_cap += len(t) * 8 * 2
+            d = eng.flight[-1]
+            assert (d["full_rows"], d["window_rows"]) == (full, win)
+            assert (d["ring_blocks_read"], d["ring_blocks_capacity"]) == (
+                ring, ring_cap)
+            assert (d["kv_blocks_read"], d["kv_blocks_capacity"]) == (
+                kv, kv_cap)
+            n += bool(launched)
+        assert n > 50 and max(len(p) for p in eng.finished.values()) > 4 * (
+            window)
+    records, problems = read_metrics(os.path.join(mdir, METRICS_FILENAME))
+    assert problems == []
+    steps = [r for r in records if r.get("span") == STEP_SPAN]
+    assert len(steps) == eng.steps
+    for r in steps:
+        assert (r["kv_row_bytes"], r["window_row_bytes"]) == (320, 640)
+        assert r["window_rows"] <= r["full_rows"]
+        assert 0 <= r["experts_touched"] <= 6 * 8 * len(r["readbacks"])
+        assert r["expert_rows_max"] <= r["expert_rows"]
+    # every row routes 4 of 16 experts in each of 6 layers, half of
+    # which are held here: the held pairs are about half of all pairs
+    rows = sum(b for r in steps for k, b in r["dispatches"]
+               if k != "prefill") + 16 * sum(
+        k in ("prefill", "mixed") for r in steps for k, _ in r["dispatches"])
+    pairs = sum(r["expert_rows"] for r in steps)
+    assert 0.3 < pairs / (rows * 4 * 6) < 0.7
+    # the pair of byte counts: both or none, whole, not negative
+    ok, reason = validate_record(steps[-1])
+    assert ok, reason
+    for over in ({"kv_row_bytes": None}, {"window_row_bytes": -1},
+                 {"kv_row_bytes": 2.5}):
+        rec = {k: v for k, v in dict(steps[-1], **over).items()
+               if k not in over or v is not None}
+        ok, reason = validate_record(rec)
+        assert not ok and "bytes a position" in reason, (over, reason)
+    bare = {k: v for k, v in steps[-1].items()
+            if k not in STEP_SPAN_ROW_BYTES}
+    assert validate_record(bare)[0]
+    assert report_main([mdir]) == 0
+    out = capsys.readouterr().out
+    assert "a position is 320 bytes a layer" in out
+    assert "a position is 640 bytes a layer" in out
+
+
 def test_record_counts_are_the_engines_counters(lm_params, prompts):
     """One record an executed step, holding what is read and no more:
     the step number and ``tokens_generated`` after the step (what a
@@ -443,7 +546,12 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
                             "summary_rows", "summaries_written",
                             "kv_blocks_read", "kv_blocks_capacity",
                             "ring_blocks_read", "ring_blocks_capacity",
+                            "kv_row_bytes", "window_row_bytes",
                             "dispatches", "readbacks", "launches"}
+        # a position's bytes in one layer of the pool, from its arrays;
+        # no window pool here
+        assert rec["kv_row_bytes"] == 2 * H * (D // H) * 4
+        assert rec["window_row_bytes"] == 0
         assert rec["window_rows"] == rec["full_rows"] == 0  # nor window
         assert rec["summary_rows"] == rec["summaries_written"] == 0
         assert rec["ring_blocks_read"] == rec["ring_blocks_capacity"] == 0
