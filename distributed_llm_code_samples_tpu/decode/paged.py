@@ -20,14 +20,15 @@ kind alone (no flag, field or environment variable chooses):
 
 - a decode-side row (``decode``, ``mixed``, ``verify``) calls
   ``stored_decode_attn``, which attends over the rows as stored. For
-  the FULL kind and for a window layer's RING that is a WALK over the
-  row's live blocks where they lie (``ops/kv_walk.py``: one kernel a
-  layer, nothing of a gathered view's size exists) wherever ``walks``
-  says the pool takes it: a float pool whose rows are, on the chip,
-  two or more whole 128-lane tiles. Every other pool keeps the PLAIN
-  form, ``gathered_decode_attn``: a gather of each row's whole table
-  and two products over the copy — a latent pool, an int8 pool
-  (per-block scales), a one-tile or ragged row on the chip;
+  the FULL kind, for a LATENT pool and for a window layer's RING that
+  is a WALK over the row's live blocks where they lie
+  (``ops/kv_walk.py``: one kernel a layer, nothing of a gathered
+  view's size exists) wherever ``walks`` says the pool takes it: a
+  float pool whose rows are, on the chip, two or more whole 128-lane
+  tiles. Every other pool keeps the PLAIN form,
+  ``gathered_decode_attn``: a gather of each row's whole table and two
+  products over the copy — an int8 pool (per-block scales), a
+  one-tile or ragged row on the chip;
 - a prefill chunk's rows call ``gathered_chunk_attn``: one slot's f32
   head-split view (``models.attention.gather_paged_kv``), which is also
   the tests' oracle for both decode-side forms.
@@ -79,9 +80,12 @@ shape with a minor axis of 0 — no bytes, so everything that moves a
 block by its id (the scatters, copy-on-write, the prefix cache's
 sharing, scrub, the chaos block) runs on it unchanged. The two reads
 take every head's query against the row as ONE KV head: scores over the
-whole row, values over its first ``latent_rank`` lanes, so one gather
-serves both products. int8 has per-head scales and a latent row no
-heads: refused.
+whole row, values over its first ``latent_rank`` lanes, so ONE fetch of
+a row serves both products: the walk copies each live block once into
+one buffer (a V side of no lanes is no operand of the kernel), the
+plain form gathers once. Both products run over the whole row and the
+result's lanes beyond ``latent_rank`` are dropped after them. int8 has
+per-head scales and a latent row no heads: refused.
 
 A **window** pool (``models/face.py::WINDOW``: the third paged kind) is
 a second ``PagedKV`` beside the first, for the layers that attend over
@@ -679,7 +683,9 @@ def gathered_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     (``walks``), and what the tests hold the walk to. ``q [B, H,
     dh]`` f32, ``tables [B, MB]`` int32, ``lengths [B]`` attendable
     positions; returns ``[B, H, dv]`` f32 (``dv`` the row's ``v_dim``:
-    ``dh`` unless the pool says otherwise). ``sink [H]`` f32: each
+    ``dh`` unless the pool says otherwise; a latent pool:
+    ``_latent_decode_attn``, the same gather with the one row on both
+    sides of it). ``sink [H]`` f32: each
     head's sink, one more term of its softmax's denominator with no
     value row (``models/attention.py::softmax_stats``; the statistics
     count it). ``window`` > 0: ``tables``
@@ -776,9 +782,6 @@ def walks(pool: PagedKV, shards: int = 1) -> bool:
     admitted under the same rule as the full kind's pool (the walk reads
     any table as a ring):
 
-    - a pool of K/V rows only: a latent pool (``latent_rank``) keeps
-      the plain read (its reader tells a decode event by that gather:
-      ``PERF.md`` section 7);
     - a float pool only: an int8 pool (``k_scale``) has per-block scales
       on the small side, which the kernel does not take;
     - on the chip, rows of whole 128-lane tiles in blocks of whole
@@ -791,12 +794,14 @@ def walks(pool: PagedKV, shards: int = 1) -> bool:
       lanes; at 512 lanes 0.71 against 2.95, at 1,024 2.07 against
       11.5, at 1,280 1.75 against 4.32: ``PERF.md`` section 6, PR 40).
       Where the two sides' rows differ (``KVRow``) each side's has to
-      be such a row. The interpreter, off the chip, takes any width.
+      be such a row; a side of NO lanes (a latent pool's ``v``) is no
+      operand of the kernel and has no say. The interpreter, off the
+      chip, takes any width.
 
     ``shards``: the ways ``pool``'s rows are sharded over a mesh where
     the caller holds the whole pool (the engine, for its counters); a
     step program asks of the shard it is handed."""
-    if pool.latent_rank or pool.k_scale is not None:
+    if pool.k_scale is not None:
         return False
     from ..ops import ssm
     if ssm._interpreted():
@@ -804,7 +809,7 @@ def walks(pool: PagedKV, shards: int = 1) -> bool:
     sublanes = 32 // pool.k.dtype.itemsize
     return (all(lanes > ssm._LANES and lanes % ssm._LANES == 0
                 for lanes in (pool.k.shape[-1] // shards,
-                              pool.v.shape[-1] // shards))
+                              pool.v.shape[-1] // shards) if lanes)
             and pool.block_size % sublanes == 0)
 
 
@@ -816,7 +821,10 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     ``verify``): single-query attention for one layer over the rows AS
     STORED. ``q [B, H, dh]`` f32, ``tables [B, MB]`` int32, ``lengths
     [B]`` attendable positions; returns ``[B, H, dv]`` f32, ``dv`` the
-    row's ``v_dim`` (a latent pool: ``gathered_decode_attn`` says).
+    row's ``v_dim``. A latent pool: ``q [B, H, m]`` is each head's query
+    FOR the stored row, scaled by the model
+    (``models/face.py::latent_qrow``), and the result is ``[B, H,
+    latent_rank]``.
     ``sink [H]`` f32: each head's sink, a term of the softmax's
     denominator with no value row. One contract, met by the walk
     over each row's live blocks where the pool takes it (``walks``) and
@@ -843,11 +851,24 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
     hold a position of the range are read, so a NaN in a DEAD block of
     a row's table no longer reaches it; one inside a live block, before
     the range's start or beyond ``lengths`` (a ring's stale rows of that
-    entry's last use), still does (``corrupt_block``)."""
+    entry's last use), still does (``corrupt_block``).
+
+    A latent pool's walk is ONE-SIDED: the row is key and value both
+    (``v`` has no lanes and is no operand of the kernel), so each live
+    block is fetched once and both products run over it. The query is
+    already laid out for the row, one KV head, and scaled; the kernel
+    hands back the heads' sums over the WHOLE stored row, ``f32[B, H,
+    m]``, as for every other kind, and the lanes beyond ``latent_rank``
+    (the rotary lanes' sums, the filling's zeros) are dropped here."""
     if not walks(pool):
         return gathered_decode_attn(pool, layer, q, tables, lengths, window,
                                     aligned, stats, sink)
     from ..ops.kv_walk import walk_attn
+    if pool.latent_rank:
+        with jax.named_scope("attn"):
+            full = walk_attn(pool.k, pool.v, layer, q.astype(pool.k.dtype),
+                             tables, jnp.zeros_like(lengths), lengths, 1.0)
+        return full[..., :pool.latent_rank]
     b, h, dh = q.shape
     hkv, dv = pool.kv_heads, pool.row.v_dim
     g = h // hkv
@@ -869,10 +890,13 @@ def stored_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
 
 def _latent_decode_attn(pool: PagedKV, layer: int, q: jax.Array,
                         tables: jax.Array, lengths: jax.Array) -> jax.Array:
-    """``stored_decode_attn`` over latent rows: ``q [B, H, m]`` is each
-    head's query FOR the stored row, scaled by the model
+    """The PLAIN form of ``stored_decode_attn`` over latent rows (what
+    a pool that does not take the walk runs, a one-tile row on the chip,
+    and what the tests hold the one-sided walk to): ``q [B, H, m]`` is
+    each head's query FOR the stored row, scaled by the model
     (``models/face.py::latent_qrow``); returns ``[B, H, latent_rank]``.
-    ONE gather, and both products run over the whole row as stored —
+    ONE gather of every row's whole table, and both products run over
+    the whole row as stored —
     ``s[b,h,t] = rows[b,t,:] . q[b,h,:]`` and ``full[b,h,:] = sum_t
     p[b,h,t] rows[b,t,:]``, of which the first ``latent_rank`` lanes are
     the result (the rotary lanes ride along: an eighth more MXU work,

@@ -9,10 +9,10 @@ chunk, and its output), a recurrent layer's step and chunk, FFN, final
 norm and head — asked of the params, never of their class. The cache
 (``decode/paged.py``): the pool and the recurrent state, the writes and
 the ONE read each kind of row calls (``stored_decode_attn`` for decode
-and verify rows — which walks a float K/V pool's live blocks where
-they lie, a full layer's table and a window layer's ring alike, and
-gathers the others' tables, as ``paged.walks`` says from the pool
-alone, never a flag here — and ``gathered_chunk_attn``
+and verify rows — which walks a float pool's live blocks where they
+lie, a full layer's table, a latent pool's one-sided rows and a window
+layer's ring alike, and gathers the others' tables, as ``paged.walks``
+says from the pool alone, never a flag here — and ``gathered_chunk_attn``
 for a prefill chunk; a chunked layer calls each over BOTH its stores
 with ``stats=True`` and joins the two: ``paged.join_reads``). Between
 them, written

@@ -2,8 +2,9 @@
 
 ``decode/paged.py`` keeps the cache as a pool of blocks, ``k [L,
 n_blocks, block, H_kv*dh]`` and ``v [L, n_blocks, block, H_kv*dv]``
-(two arrays, whose rows need not be equally wide), and a sequence names
-its blocks through a table. The plain read
+(two arrays, whose rows need not be equally wide; a LATENT pool's ``v``
+has no lanes at all: its one row a position is key and value both), and
+a sequence names its blocks through a table. The plain read
 (``paged.gathered_decode_attn``) gathers every row's WHOLE table —
 capacity, not length — into a copy and runs two products over the copy.
 ``walk_attn`` is the same two products over the rows where they lie: one
@@ -16,6 +17,8 @@ has a SINK, a term of the denominator with no value row, the maximum
 starts at the sink and the sum at 1, and the sink costs no column and
 no copy). Nothing of a gathered view's size exists: no gather, no copy,
 no ``[b, H, T_cap]`` scores, and the bytes that move are the live rows'.
+A pool with ONE side is walked one-sided: each block is fetched once,
+into one pair of buffers, and both products run over that buffer.
 
 A table is read as a RING: block ``j`` of the sequence lies in entry ``j
 mod MB``. For the full kind ``start`` is 0 and ``j < MB``, so the table
@@ -45,34 +48,40 @@ from jax.experimental.pallas import tpu as pltpu
 from . import ssm
 
 # what the two sides' double-buffered copy steps may hold of a kernel's
-# fast memory: a quarter of ``ssm._VMEM_BUDGET`` each for K and V
+# fast memory: a quarter of ``ssm._VMEM_BUDGET`` each for K and V (a
+# pool of one side: the half of it for that side)
 _STEP_BYTES = ssm._VMEM_BUDGET // 8
 _MASKED = -1e30
 
 
-def blocks_a_step(block: int, row_bytes: int, max_blocks: int) -> int:
+def blocks_a_step(block: int, row_bytes: int, max_blocks: int,
+                  sides: int = 2) -> int:
     """How many blocks one copy step fetches: as many as fit
-    ``_STEP_BYTES`` a buffer, a power of two, at least as many as make
-    the step's positions whole 128-lane tiles of the scores, and no
-    more than a table holds or than 64 (1,024 positions of scores a
-    step at blocks of 16). It follows from the row's bytes and the
-    fast memory, not from a knob: a 1,280-lane bf16 row gives 16 blocks
-    of 16 (640 KB a step and side), a 512-lane one 64."""
-    fit = max(1, _STEP_BYTES // (block * row_bytes))
+    ``_STEP_BYTES`` a buffer (twice that where the pool has one side
+    and the other's buffers do not exist), a power of two, at least as
+    many as make the step's positions whole 128-lane tiles of the
+    scores, and no more than a table holds or than 64 (1,024 positions
+    of scores a step at blocks of 16). It follows from the row's bytes
+    and the fast memory, not from a knob: a 1,280-lane bf16 row gives
+    16 blocks of 16 (640 KB a step and side), a 512-lane one 64, a
+    latent pool's one 640-lane row 64."""
+    fit = max(1, _STEP_BYTES * 2 // sides // (block * row_bytes))
     c = 1 << (fit.bit_length() - 1)
     c = max(c, -(-ssm._LANES // block))
     return min(c, 1 << (max_blocks - 1).bit_length(), 64)
 
 
 def _walk_kernel(layer_ref, tables_ref, starts_ref, lengths_ref, q_ref,
-                 k_hbm, v_hbm, *rest, steps: int, scale: float,
-                 sunk: bool):
+                 *rest, sides: int, steps: int, scale: float, sunk: bool):
     """One batch row a grid step: the blocks ``starts // block ..
     (lengths - 1) // block`` of its sequence, ``steps`` of them a copy
     step, each from the table's entry of its number modulo the table's
-    width. ``kbuf [2, steps * block, J]`` and ``vbuf [2, steps * block,
+    width. ``sides`` pools stand after the query, ``k_hbm`` and
+    ``v_hbm``, or the ONE whose rows are key and value both; ``kbuf [2,
+    steps * block, J]`` and ``vbuf [2, steps * block,
     Jv]`` are the two buffers of each side, each as wide as its side's
-    row; ``slot_ref`` says which of them
+    row (one side: one pair, which both products read); ``slot_ref``
+    says which of them
     the row's FIRST copy step is in (the row before started it, before
     its own last product), ``sems [side, buffer]`` count the copies.
     Where the call asks for the softmax statistics, two more outputs
@@ -80,9 +89,12 @@ def _walk_kernel(layer_ref, tables_ref, starts_ref, lengths_ref, q_ref,
     ``exp(s - m)``, a whole tile of lanes wide. ``sunk``: one more
     input stands before the outputs, ``sink_ref [H, 1]``, each head's
     sink: ``exp(sink - m)`` is in the sum from the start."""
+    hbms, rest = rest[:sides], rest[sides:]
     if sunk:
         sink_ref, *rest = rest
-    o_ref, *stats, kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref = rest
+    *outs, sems, slot_ref, m_ref, l_ref, acc_ref = rest
+    (o_ref, *stats), bufs = outs[:-sides], outs[-sides:]
+    kbuf, vbuf = bufs[0], bufs[-1]
     r = pl.program_id(0)
     blk = kbuf.shape[1] // steps
     entries = tables_ref.shape[1]
@@ -100,7 +112,7 @@ def _walk_kernel(layer_ref, tables_ref, starts_ref, lengths_ref, q_ref,
         return jnp.minimum(live(row) - c * steps, steps)
 
     def copies(row, c, buf, act: str):
-        """``start`` or ``wait`` for the two copies, K and V, of every
+        """``start`` or ``wait`` for the copies, one a side, of every
         block the row holds of its step ``c``, into buffer ``buf``."""
         # a row holds no more blocks than its table has entries, so the
         # step's entries wrap at most once past its first
@@ -111,8 +123,7 @@ def _walk_kernel(layer_ref, tables_ref, starts_ref, lengths_ref, q_ref,
             phys = tables_ref[row, jnp.where(entry < entries, entry,
                                              entry - entries)]
             dst = pl.ds(pl.multiple_of(i * blk, blk), blk)
-            for side, (hbm, vmem) in enumerate(((k_hbm, kbuf),
-                                                (v_hbm, vbuf))):
+            for side, (hbm, vmem) in enumerate(zip(hbms, bufs)):
                 getattr(pltpu.make_async_copy(
                     hbm.at[layer, phys], vmem.at[buf, dst],
                     sems.at[side, buf]), act)()
@@ -153,14 +164,16 @@ def _walk_kernel(layer_ref, tables_ref, starts_ref, lengths_ref, q_ref,
         # the blocks of the step the row does not hold were not
         # fetched: what the buffer has there is some earlier step's (a
         # NaN of ANOTHER row's among it). Their scores are masked; their
-        # values must be zeros, for 0 * NaN is NaN
+        # values must be zeros, for 0 * NaN is NaN (one side: the rows
+        # are the keys too, and a zero key's score is masked all the same)
         def dead(i, _):
             vbuf[buf, pl.ds(pl.multiple_of(i * blk, blk), blk), :] = (
                 jnp.zeros((blk, vbuf.shape[2]), vbuf.dtype))
             return _
         lax.fori_loop(fetched(r, c), steps, dead, None)
 
-        k, v = kbuf[buf], vbuf[buf]                     # [T, J]
+        k = kbuf[buf]                                   # [T, J]
+        v = k if sides == 1 else vbuf[buf]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         pos = ((head(r) + c * steps) * blk
@@ -196,12 +209,15 @@ def _walk(layer, tables, starts, lengths, q, k_pool, v_pool, sink=None, *,
     program and called a layer, not once a layer. A program of 36
     layers spent 1.4 s of every start-up lowering 36 copies."""
     b, h, j = q.shape
-    blk, jv = k_pool.shape[2], v_pool.shape[3]
+    # a V side of no lanes is no operand: the K side's rows are the
+    # values too, and the result is as wide as they are
+    pools = [k_pool, v_pool] if v_pool.shape[3] else [k_pool]
+    blk, jv = k_pool.shape[2], pools[-1].shape[3]
     row = pl.BlockSpec((None, h, j), lambda r, *_: (r, 0, 0))
     whole = pl.BlockSpec(memory_space=pl.ANY)
     out_specs = pl.BlockSpec((None, h, jv), lambda r, *_: (r, 0, 0))
     out_shape = jax.ShapeDtypeStruct((b, h, jv), jnp.float32)
-    in_specs, operands = [row, whole, whole], [q, k_pool, v_pool]
+    in_specs, operands = [row] + [whole] * len(pools), [q, *pools]
     if sink is not None:
         in_specs.append(pl.BlockSpec((h, 1), lambda r, *_: (0, 0)))
         operands.append(sink.astype(jnp.float32).reshape(h, 1))
@@ -211,15 +227,15 @@ def _walk(layer, tables, starts, lengths, q, k_pool, v_pool, sink=None, *,
         out_specs, out_shape = ([out_specs, stat, stat],
                                 [out_shape, wide, wide])
     return pl.pallas_call(
-        functools.partial(_walk_kernel, steps=steps, scale=scale,
-                          sunk=sink is not None),
+        functools.partial(_walk_kernel, sides=len(pools), steps=steps,
+                          scale=scale, sunk=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(b,),
             in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((2, steps * blk, j), k_pool.dtype),
-                pltpu.VMEM((2, steps * blk, jv), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                *(pltpu.VMEM((2, steps * blk, side.shape[3]), side.dtype)
+                  for side in pools),
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((h, 1), jnp.float32),
                 pltpu.VMEM((h, 1), jnp.float32),
@@ -236,7 +252,9 @@ def walk_attn(k_pool: jax.Array, v_pool: jax.Array, layer: int,
     """Single-query attention of ``b`` rows over their own blocks of
     one layer of the pool, where they lie. ``k_pool [L, n_blocks,
     block, J]`` and ``v_pool [L, n_blocks, block, Jv]`` stay in HBM
-    whole; ``q [b, H, J]`` in the pool's dtype
+    whole (``Jv`` 0, a latent pool: the K side's rows are the values
+    too, each block is fetched ONCE for both products, and the result
+    is ``[b, H, J]``); ``q [b, H, J]`` in the pool's dtype
     is each head's query laid out FOR a stored row (zero outside its KV
     head's lanes: ``decode/paged.py`` builds it); ``tables [b, MB]``,
     block ``j`` of a sequence in entry ``j mod MB``; ``starts [b]`` /
@@ -271,7 +289,7 @@ def walk_attn(k_pool: jax.Array, v_pool: jax.Array, layer: int,
     # bytes say how many
     wide = max(k_pool.shape[-1], v_pool.shape[-1])
     steps = blocks_a_step(k_pool.shape[2], wide * k_pool.dtype.itemsize,
-                          tables.shape[1])
+                          tables.shape[1], sides=2 if v_pool.shape[-1] else 1)
     got = _walk(jnp.asarray([layer], jnp.int32), tables, starts, lengths,
                 q, k_pool, v_pool, sink, steps=steps, scale=scale,
                 interpret=interpret, stats=stats)

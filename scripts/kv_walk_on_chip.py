@@ -9,7 +9,9 @@ as a decode-side program holds them, over a pool filled to the cell's
 ``kv_pool_util``; and the two cells' RINGS (``evabyte-ring``,
 ``laguna-ring``: every window layer's read of one batch over its short
 tables, the rows at the cell's traced mix of window depths, and
-``...-ring:full`` with every row a whole window deep). Prints and writes
+``...-ring:full`` with every row a whole window deep); and the latent
+cell's ONE-sided pool (``glm``: rows of 640 lanes, key and value both).
+Prints and writes
 (``chiprun_out/kv_walk.json``) ms a program and the live bytes a second
 each form moved. A microbench: the cell decides (PERF.md section 6,
 PR 32: the two did not agree there). Raises off a TPU: a CPU number is
@@ -45,6 +47,9 @@ CELLS = {"gpt2": (12, 20, 20, 64, 64, 36, 0.365),
 # the longest sequence): the two cells with a ring
 RINGS = {"evabyte-ring": (24, 32, 32, 128, 130, 8, 2048, True, 587, 9216),
          "laguna-ring": (64, 72, 8, 128, 34, 9, 512, False, 460, 3072)}
+# (rows, heads, the row's lanes, of which the values, blocks a sequence,
+# layers, the share of capacity that is live): the cell with a latent pool
+LATENT = {"glm": (64, 20, 640, 512, 128, 7, 0.34)}
 BLOCK = 16
 
 
@@ -53,6 +58,7 @@ def _case(name, seed=0):
     one cell's batch."""
     rng = np.random.default_rng(seed)
     ring, _, fill = name.partition(":")
+    rank = 0
     if ring in RINGS:
         (b, h, hkv, dh, mb, layers, window, aligned, mix,
          longest) = RINGS[ring]
@@ -71,24 +77,32 @@ def _case(name, seed=0):
                             rng.integers(window, longest, b)))
         attended = depth
     else:
-        b, h, hkv, dh, mb, layers, util = CELLS[name]
+        if name in LATENT:
+            b, h, dh, rank, mb, layers, util = LATENT[name]
+            hkv = 1
+        else:
+            b, h, hkv, dh, mb, layers, util = CELLS[name]
         window = aligned = 0
         cap = mb * BLOCK
         lengths = np.clip(rng.uniform(0.1, 2 * util - 0.1, size=b) * cap,
                           1, cap).astype(np.int32)
         lengths[0] = cap                    # one row at the cap
         attended = lengths
-    pool = paged.init_pool(layers, 1 + b * mb, hkv, BLOCK, dh, "bf16")
+    pool = paged.init_pool(layers, 1 + b * mb, hkv, BLOCK, dh, "bf16",
+                           latent_rank=rank)
     key = jax.random.PRNGKey(seed)
 
     def side(key):      # one layer's draw in every layer: no 6 GB of bits
         return jnp.tile(jax.random.normal(key, (1, *pool.k.shape[1:]),
                                           jnp.bfloat16), (layers, 1, 1, 1))
 
-    pool = pool._replace(k=side(key), v=side(jax.random.fold_in(key, 1)))
+    pool = pool._replace(k=side(key))
+    if pool.v.shape[-1]:
+        pool = pool._replace(v=side(jax.random.fold_in(key, 1)))
     tables = 1 + rng.permutation(b * mb).reshape(b, mb).astype(np.int32)
     q = jax.random.normal(jax.random.fold_in(key, 2), (layers, b, h, dh))
-    live = int(attended.sum()) * 2 * hkv * dh * 2 * layers
+    sides = 2 if pool.v.shape[-1] else 1
+    live = int(attended.sum()) * sides * hkv * dh * 2 * layers
     return (pool, q, jnp.asarray(tables),
             jnp.asarray(lengths, jnp.int32), live, (window, bool(aligned)))
 
@@ -115,7 +129,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", default="")
     ap.add_argument("--cells", default=",".join(
-        [*CELLS, *RINGS, *(r + ":full" for r in RINGS)]))
+        [*CELLS, *LATENT, *RINGS, *(r + ":full" for r in RINGS)]))
     a = ap.parse_args()
     if jax.default_backend() != "tpu":
         raise SystemExit("no TPU: a CPU time is no device time")
@@ -135,9 +149,10 @@ def main():
                 "plain_ms": plain_ms, "plain_gbs": live / plain_ms / 1e6}
         for steps in rules:
             if steps is not None:
-                kv_walk.blocks_a_step = lambda *_, s=steps: s
+                kv_walk.blocks_a_step = lambda *_, s=steps, **__: s
             got_steps = kv_walk.blocks_a_step(
-                BLOCK, pool.k.shape[-1] * 2, tables.shape[1])
+                BLOCK, pool.k.shape[-1] * 2, tables.shape[1],
+                sides=2 if pool.v.shape[-1] else 1)
             ms, got = _ms(
                 _program(paged.stored_decode_attn, layers, window), args)
             kv_walk.blocks_a_step = rule

@@ -1223,10 +1223,10 @@ def _entry_results(hlo: str):
 
 # fixture -> whether the cell's full-kind pool takes the walk on the
 # chip (``paged.walks``: GPT-2 large's 1,280-lane rows, LFM2's 512,
-# Laguna's 1,024) or keeps the plain gather (the hybrid's ONE KV head
-# of 128 lanes, by measurement; the latent rows, a kind of their own)
+# Laguna's 1,024, the latent cell's ONE side of 640) or keeps the plain
+# gather (the hybrid's ONE KV head of 128 lanes, by measurement)
 WALKS = {"gpt2_large_engine_args": True, "jamba_engine_args": False,
-         "glm_engine_args": False, "lfm2_engine_args": True,
+         "glm_engine_args": True, "lfm2_engine_args": True,
          "laguna_engine_args": True}
 
 
@@ -1239,28 +1239,32 @@ def test_decode_program_reads_the_gathered_rows_as_stored(
 
     **A pool that takes the walk** (``decode/paged.py::walks``; GPT-2
     large, the gated convolution cell's 8 KV heads x 64 lanes, Laguna's
-    full layers): the decode-side program produces NO array of a
-    gathered view's elements (``slots * T_cap * H_kv*dh``) at all, in
-    any dtype — no gather, no copy, no ``[b, H, T_cap]`` scores — and
-    holds one kernel call a full-kind layer whose result is the heads'
-    sums over the stored row, ``f32[b, H, H_kv*dh]``
-    (``ops/kv_walk.py``), the K/V pool aliased whole beside it.
-    Laguna's window layers walk their rings by the same kernel: a call
-    a window layer beside a call a full layer. (The parent of PR 40
-    fails this with two gathers of the view's size a layer.)
+    full layers, the latent cell's rows): the decode-side program
+    produces NO array of a gathered view's elements (``slots * T_cap *
+    H_kv*dh``) at all, in any dtype — no gather, no copy, no ``[b, H,
+    T_cap]`` scores — and holds one kernel call a full-kind layer whose
+    result is the heads' sums over the stored row, ``f32[b, H,
+    H_kv*dh]`` (``ops/kv_walk.py``), the K/V pool aliased whole beside
+    it. Laguna's window layers walk their rings by the same kernel: a
+    call a window layer beside a call a full layer. The latent cell's
+    pool has ONE side: a call a layer with the result ``f32[b, 20,
+    640]`` (the whole row's sums: the values' 512 lanes are sliced from
+    that small result, never from the cache) and, among the call's
+    operands, the 640-lane pool ONCE and nothing of no lanes. (The
+    parent of PR 40 fails this with two gathers of the view's size a
+    layer, the parent of PR 50 for the latent cell with one,
+    ``bf16[b x 128, 16, 640]``.)
 
     **A pool that keeps the plain read** (the hybrid's one KV head of
-    128 lanes, the latent cell's rows): the program attends over each
-    slot's gathered blocks in the form and dtype the pool stores them
+    128 lanes): the program attends over each slot's gathered blocks in
+    the form and dtype the pool stores them
     (``paged.gathered_decode_attn``): beyond the gather itself, no
     instruction produces an array of a gathered view's elements or more
     that is wider than the stored dtype, and none transposes or copies
     one. (The parent of PR 28 failed this for GPT-2 large with two
     ``reshape f32[12,1024,20,64]`` a layer; ``temp_size_in_bytes`` of
     the 2-layer GPT-2 program: 152 MB then, 0.8 MB with the gather,
-    less with the walk.) The latent cell's rows: one gather of 640-lane
-    rows a layer, both products over them as stored, no slice of the
-    view for the values' 512 lanes."""
+    less with the walk.)"""
     eng, programs = request.getfixturevalue(fixture)
     bucket, args = programs[kind]
     compiled = eng._program(kind, bucket).lower(
@@ -1283,6 +1287,21 @@ def test_decode_program_reads_the_gathered_rows_as_stored(
         assert len(walk) == pool.k.shape[0] + (
             0 if wpool is None else wpool.k.shape[0]), walk
         _carry_is_aliased_whole(compiled, eng)
+        if pool.latent_rank:
+            import re
+            # the kernel's operands: the four prefetched scalars, the
+            # query for the stored row, and the pool's ONE side, once
+            for line in hlo.splitlines():
+                if MOSAIC in line and "f32[%d," % bucket in line:
+                    handed = line.split("operand_layout_constraints={")[1]
+                    handed = re.findall(r"\b[a-z]+\d+\[[\d,]*\]",
+                                        handed.split("}}")[0])
+                    assert handed == [
+                        "s32[1]", "s32[%d,%d]" % (
+                            bucket, eng.cfg.max_blocks_per_seq),
+                        "s32[%d]" % bucket, "s32[%d]" % bucket,
+                        "bf16[%d,20,640]" % bucket,
+                        "bf16[%s]" % ",".join(map(str, pool.k.shape))], handed
     else:
         assert big, "the gather's own results are of the view's size"
         assert not walk, walk
@@ -1298,7 +1317,7 @@ def test_decode_program_reads_the_gathered_rows_as_stored(
                                   "jamba2-3b", "lfm2-24b-a2b",
                                   "laguna-s-2.1", "evabyte",
                                   "laguna-ring", "evabyte-ring",
-                                  "mimo-full", "mimo-ring"])
+                                  "mimo-full", "mimo-ring", "glm-latent"])
 def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
                                               kernels_for_the_chip, cell):
     """The walk alone, for the described v5e, at each serving cell's
@@ -1314,11 +1333,19 @@ def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
     / 512 under a table of 192 entries, 1,536 / 1,024 under a ring of
     10 with the layer's 64 sinks as an operand): the result is as wide
     as V's row and a copy step's blocks follow from the WIDER row's
-    bytes (32 and 16: one step holds a ring's 9 live blocks)."""
+    bytes (32 and 16: one step holds a ring's 9 live blocks). The latent
+    cell's pool has ONE side, 640 lanes under 20 heads' queries for the
+    row: a V side of no lanes is handed in and is no operand of the
+    kernel, the one pair of buffers takes the absent side's budget too
+    (64 blocks a copy step where two sides of 640 lanes would get 32),
+    and the result is as wide as the row."""
     from distributed_llm_code_samples_tpu.ops import kv_walk, ssm
     dv, sunk = (128, cell == "mimo-ring") if cell.startswith("mimo") else (
         None, False)
+    if cell == "glm-latent":
+        dv = 0
     b, h, hkv, dh, mb, layers = {
+        "glm-latent": (64, 20, 1, 640, 128, 7),
         "mimo-full": (64, 64, 4, 192, 192, 2),
         "mimo-ring": (64, 64, 8, 192, 10, 9),
         "gpt2-large": (12, 20, 20, 64, 64, 36),
@@ -1334,13 +1361,17 @@ def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
         "evabyte-ring": (24, 32, 32, 128, 130, 8)}[cell]
     stats = cell.startswith("evabyte")
     dt = jnp.float32 if cell.endswith("f32") else jnp.bfloat16
-    j, jv, blk = hkv * dh, hkv * (dv or dh), 16
-    steps = kv_walk.blocks_a_step(blk, j * dt.dtype.itemsize, mb)
+    j, jv, blk = hkv * dh, hkv * (dh if dv is None else dv), 16
+    steps = kv_walk.blocks_a_step(blk, j * dt.dtype.itemsize, mb,
+                                  sides=2 if jv else 1)
     assert steps & (steps - 1) == 0 and (steps * blk) % 128 == 0
     assert 2 * steps * blk * (j + jv) * dt.dtype.itemsize <= (
         ssm._VMEM_BUDGET)
-    if dv:
-        assert steps == {"mimo-full": 32, "mimo-ring": 16}[cell]
+    if dv is not None:
+        assert steps == {"mimo-full": 32, "mimo-ring": 16,
+                         "glm-latent": 64}[cell]
+        assert kv_walk.blocks_a_step(blk, j * dt.dtype.itemsize, mb) == (
+            {"glm-latent": 32}.get(cell, steps))
     shape = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     k_side = shape((layers, 1 + b * mb, blk, j), dt)
     v_side = shape((layers, 1 + b * mb, blk, jv), dt)
@@ -1354,13 +1385,63 @@ def test_kv_walk_compiles_at_the_cells_shapes(one_chip,
             lengths=shape((b,), jnp.int32), **sink).compile()
     hlo = compiled.as_text()
     assert sum(MOSAIC in l for l in hlo.splitlines()) == 1
-    assert "f32[%d,%d,%d]" % (b, h, jv) in hlo      # as wide as V's row
+    # as wide as V's row (one side: as that side's)
+    assert "f32[%d,%d,%d]" % (b, h, jv or j) in hlo
     m = compiled.memory_analysis()
     # the two sides and the queries, once each
     nbytes = (int(np.prod(k_side.shape)) + int(np.prod(v_side.shape))
               + b * h * j) * dt.dtype.itemsize
     assert m.argument_size_in_bytes - nbytes < 2 ** 20
     assert m.temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("cell", ["gpt2-large", "lfm2-24b-a2b", "mimo-ring",
+                                  "evabyte", "glm-latent"])
+def test_walk_kernel_copies_and_products_by_the_pools_sides(cell):
+    """The one-sided form is chosen by a static property of the operand
+    (a V side of no lanes) and must not leak into a two-sided call: the
+    kernel TRACED for a K/V pool is what it was before the latent kind
+    walked (its jaxpr's text was compared with the parent's at PR 50,
+    equal to the byte at these four cells' shapes; what is pinned here
+    is what a refactor cannot move without meaning to): both pools are
+    operands, a pair of buffers a side, semaphores ``[2, 2]``, four
+    copy starts (two sites: the next step's, and the first row's) and
+    two waits, two products. The latent pool's: ONE pool among the
+    operands, one pair of buffers, semaphores ``[1, 2]``, two starts,
+    one wait and the same two products: each block is fetched once for
+    both. Shapes alone: nothing is compiled or run."""
+    import collections
+    from conftest import jaxpr_eqns
+    from distributed_llm_code_samples_tpu.ops import kv_walk
+    b, h, j, jv, mb, layers, kw = {
+        "gpt2-large": (12, 20, 1280, 1280, 64, 36, {}),
+        "lfm2-24b-a2b": (64, 32, 512, 512, 128, 2, {}),
+        "mimo-ring": (64, 64, 1536, 1024, 10, 9,
+                      {"sink": jax.ShapeDtypeStruct((64,), jnp.float32)}),
+        "evabyte": (24, 32, 4096, 4096, 36, 8, {"stats": True}),
+        "glm-latent": (64, 20, 640, 0, 128, 7, {})}[cell]
+    stats = kw.pop("stats", False)
+    shape, dt = jax.ShapeDtypeStruct, jnp.bfloat16
+    pools = [shape((layers, 1 + b * mb, 16, lanes), dt) for lanes in (j, jv)]
+    jaxpr = jax.make_jaxpr(lambda k, v, q, tables, starts, lengths, **kw: (
+        kv_walk.walk_attn(k, v, layers - 1, q, tables, starts, lengths,
+                          0.125, stats=stats, **kw)))(
+            *pools, shape((b, h, j), dt), shape((b, mb), jnp.int32),
+            shape((b,), jnp.int32), shape((b,), jnp.int32), **kw).jaxpr
+    walk, = [e for e in jaxpr_eqns(jaxpr)
+             if e.primitive.name == "pallas_call"]
+    sides = 2 if jv else 1
+    handed = [v.aval.shape for v in walk.invars]
+    assert [x for x in handed if len(x) == 4] == [p.shape for p in
+                                                  pools[:sides]]
+    scratch = [x.shape for x in walk.params["grid_mapping"].scratch_avals]
+    assert [x[2] for x in scratch if len(x) == 3] == [j, jv][:sides]
+    assert (sides, 2) in scratch
+    census = collections.Counter(
+        e.primitive.name for e in jaxpr_eqns(walk.params["jaxpr"]))
+    assert (census["dma_start"], census["dma_wait"],
+            census["dot_general"]) == (2 * sides, sides, 2)
+    assert walk.outvars[0].aval.shape == (b, h, jv or j)
 
 
 # cell -> (its shrink under benchmark/tests, the per-layer metric that

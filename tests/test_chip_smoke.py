@@ -22,8 +22,11 @@ from conftest import load_scaled_timeout
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the child's prologue: toy sizes, the platform assertion stood in for,
-# a peak for the CPU so the MFU check has something to divide by, and
-# allocator statistics the CPU does not keep
+# a peak for the CPU so the MFU check has something to divide by (small
+# enough that a step record's ``round(mfu, 4)`` cannot read 0.0: at 1e12
+# the second shape's 9.4 MFLOP step read 0.0 whenever its first chunk,
+# compile included, took over 0.19 s), and allocator statistics the CPU
+# does not keep
 TOY = r"""
 import sys
 sys.path.insert(0, {repo!r})
@@ -45,7 +48,7 @@ cs.TRAIN_LM[:] = ["-d", "32", "-l", "2", "--heads", "4", "--vocab", "64",
 cs.require_tpu = cs.describe_devices
 cs.peak_bytes = lambda device: 1      # the CPU keeps no allocator statistics
 peak = telemetry.peak_flops
-telemetry.peak_flops = lambda kind: peak(kind) or 1e12
+telemetry.peak_flops = lambda kind: peak(kind) or 1e9
 """
 
 

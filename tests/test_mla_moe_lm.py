@@ -246,6 +246,30 @@ def test_absorbed_attention_is_the_naive_form(ref, weights):
     assert np.abs(np.asarray(got - want)).max() < 2e-5
 
 
+def test_the_walked_read_hands_latent_out_the_rank_lanes_alone(
+        weights, monkeypatch):
+    """The decode-side read of the latent pool is the one-sided walk
+    (``paged.walks``; PR 50), whose kernel returns the heads' sums over
+    the WHOLE stored row, 128 lanes here: what reaches ``latent_out``
+    from every program, the decode batch's and a riding chunk's alike,
+    is the first ``latent_rank`` lanes and no more."""
+    from distributed_llm_code_samples_tpu.decode import paged
+    _, p = weights
+    seen, real = [], type(p).latent_out
+
+    def spy(self, i, o):
+        seen.append(o.shape)
+        return real(self, i, o)
+    monkeypatch.setattr(type(p), "latent_out", spy)
+    eng = engine(p)
+    assert paged.walks(eng.pool) and eng.pool.k.shape[-1] == 128
+    eng.generate(prompts_of([5, 21, 9]), 6)
+    kinds = {k for d in eng.flight for k, _ in d["dispatches"]}
+    assert {"decode", "mixed"} <= kinds
+    rank = eng.pool.latent_rank
+    assert seen and {shape[1:] for shape in seen} == {(HEADS, rank)}
+
+
 # -- (c) the router, by hand -----------------------------------------------------
 
 

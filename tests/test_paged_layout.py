@@ -12,13 +12,15 @@ rows as stored (``stored_decode_attn``) is held to the oracle
 ``decode_attn(q, *vmap(gather_layer))`` in both its forms: the plain
 gather and two products (``gathered_decode_attn``; also to a NumPy
 model of its own arithmetic) and the walk over each row's live blocks
-(``ops/kv_walk.py``), a full pool's table and a window layer's ring.
+(``ops/kv_walk.py``), a full pool's table, a window layer's ring and a
+latent pool's one-sided rows.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import jaxpr_eqns
 
 from distributed_llm_code_samples_tpu.decode.paged import (
     KV_DTYPES, SCRATCH_BLOCK, _heads_major, _quantize, _rows_major,
@@ -381,6 +383,11 @@ def test_stale_bytes_beyond_the_length_carry_no_mass(kv_dtype):
 WALK_SHAPES = {"mha4x64": (4, 4, 64), "gqa32over8x64": (32, 8, 64),
                "mqa20over1x128": (20, 1, 128),
                "gqa48over8x128": (48, 8, 128)}
+# ... and the LATENT kind (heads, the row's lanes, of which the values:
+# GLM's 20 heads over ONE row of 640 lanes whose first 512 are the
+# values, in small): the pool has no V side, the kernel walks it
+# one-sided, and the query comes scaled and laid out for the row
+LATENT_SHAPES = {"latent20x40of32": (20, 40, 32)}
 W_MB, W_BLK = 5, 8
 # ragged in one batch: one position, a block less one, a whole block, a
 # block and one, the capacity; and a bucket's padded row (its table all
@@ -390,28 +397,36 @@ W_LENGTHS = np.asarray([1, W_BLK - 1, W_BLK, W_BLK + 1, W_MB * W_BLK, 1],
 W_POISONED = 3          # the row of ``W_BLK + 1`` positions: 2 live blocks
 
 
-def _walk_case(shape, kv_dtype, poison):
+def _walk_case(shape, kv_dtype, poison, lengths=W_LENGTHS):
     """A two-layer pool (the read is of layer 1), every byte random and
     finite but the stale ones beyond each row's length, which are the
     largest a freed sequence could leave; permuted tables; then
     ``poison``: a NaN in a DEAD block of row ``W_POISONED``'s table
     (``"dead"``) or beyond its length inside its last live block
-    (``"last"``)."""
-    hq, hkv, dh = WALK_SHAPES[shape]
+    (``"last"``). A shape of ``LATENT_SHAPES``: a latent pool, the K
+    side its rows and no V side, ``q`` each head's query for the row.
+    ``lengths``: another batch of as many rows, the last still the
+    padded one; a row of length 0 is padded too (its table all
+    scratch)."""
+    if shape in LATENT_SHAPES:
+        (hq, dh, rank), hkv = LATENT_SHAPES[shape], 1
+    else:
+        (hq, hkv, dh), rank = WALK_SHAPES[shape], 0
     rng = np.random.default_rng(1)
     b = len(W_LENGTHS)
     nb = 1 + (b - 1) * W_MB
     tables = 1 + rng.permutation((b - 1) * W_MB).reshape(b - 1, W_MB)
     tables = np.concatenate(
         [tables, np.full((1, W_MB), SCRATCH_BLOCK)]).astype(np.int32)
-    pool = init_pool(2, nb, hkv, W_BLK, dh, kv_dtype)
+    tables[lengths == 0] = SCRATCH_BLOCK
+    pool = init_pool(2, nb, hkv, W_BLK, dh, kv_dtype, latent_rank=rank)
     live = np.zeros((nb, W_BLK), bool)
     for r in range(b):
-        pos = np.arange(W_LENGTHS[r])
+        pos = np.arange(lengths[r])
         live[tables[r][pos // W_BLK], pos % W_BLK] = True
     sides = []
-    for _ in "kv":
-        src = rng.normal(size=(2, nb, W_BLK, hkv * dh)).astype(np.float32)
+    for side in (pool.k, pool.v):       # a latent pool's ``v``: no lanes
+        src = rng.normal(size=side.shape).astype(np.float32)
         src = np.where(live[None, :, :, None], src, 3e4)
         if poison == "dead":
             src[:, tables[W_POISONED, 2:]] = np.nan
@@ -423,15 +438,36 @@ def _walk_case(shape, kv_dtype, poison):
     return pool, q, jnp.asarray(tables)
 
 
-def _walked(pool, q, tables):
+def _walked(pool, q, tables, lengths=W_LENGTHS):
     assert walks(pool)
     return np.asarray(jax.jit(lambda q: stored_decode_attn(
-        pool, 1, q, tables, jnp.asarray(W_LENGTHS)))(q))
+        pool, 1, q, tables, jnp.asarray(lengths)))(q))
+
+
+def _latent_oracle(pool, q, tables):
+    """The latent read written down plainly in float64 over layer 1's
+    rows: scores over the WHOLE row (the query is scaled already),
+    values the row's first ``latent_rank`` lanes. Returns the result
+    ``[b, H, rank]`` beside ``(qg, kc, vc)`` in the K/V oracle's form,
+    one KV head, the query times ``sqrt(m)`` so that
+    ``_two_roundings_bound``'s ``1 / sqrt(m)`` is this read's 1."""
+    b, h, m = q.shape
+    rows = np.asarray(pool.k[1].astype(jnp.float32), np.float64)[
+        np.asarray(tables)].reshape(b, 1, -1, m)
+    kc, vc = rows, rows[..., :pool.latent_rank]
+    qg = np.asarray(q, np.float64)[:, None]                 # [b, 1, H, m]
+    s = np.einsum("bkgd,bktd->bkgt", qg, kc)
+    live = np.arange(kc.shape[2]) < W_LENGTHS[:, None]
+    s = np.where(live[:, None, None, :], s, -np.inf)
+    e = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bkgt,bktd->bkgd", e / e.sum(-1, keepdims=True),
+                     np.where(live[:, None, :, None], vc, 0))
+    return want.reshape(b, h, -1), (qg * np.sqrt(m), kc, vc)
 
 
 @pytest.mark.parametrize("poison", ["none", "dead", "last"])
 @pytest.mark.parametrize("kv_dtype", ["f32", "bf16"])
-@pytest.mark.parametrize("shape", sorted(WALK_SHAPES))
+@pytest.mark.parametrize("shape", sorted({**WALK_SHAPES, **LATENT_SHAPES}))
 def test_walked_stored_decode_attn_matches_the_oracle(shape, kv_dtype, poison):
     """``stored_decode_attn`` over a pool that takes the walk, against
     ``decode_attn(q, *vmap(gather_layer))`` on the same bytes, under the
@@ -442,7 +478,9 @@ def test_walked_stored_decode_attn_matches_the_oracle(shape, kv_dtype, poison):
     probability: one relative ``u`` either way). And what the walk
     changes, stated: a NaN in a DEAD block of a row's table does not
     reach the row, bit for bit; one beyond the length inside its last
-    live block poisons that row and no other, as in the plain form."""
+    live block poisons that row and no other, as in the plain form.
+    The latent kind's oracle is ``_latent_oracle``: the same bounds over
+    ONE fetch of each row for both products."""
     pool, q, tables = _walk_case(shape, kv_dtype, "none")
     got = _walked(pool, q, tables)
     lengths = jnp.asarray(W_LENGTHS)
@@ -455,24 +493,121 @@ def test_walked_stored_decode_attn_matches_the_oracle(shape, kv_dtype, poison):
         else:
             assert np.isnan(bad[W_POISONED]).all()
         return
-    kc, vc = jax.vmap(lambda t: gather_layer(pool, 1, t))(tables)
-    want = np.asarray(jax.jit(decode_attn)(q, kc, vc, lengths))
+    b, h, dh = q.shape
+    if pool.latent_rank:
+        want, (qg, kc, vc) = _latent_oracle(pool, q, tables)
+        # the rank's lanes and no more are what the read hands on
+        assert got.shape == (b, h, pool.latent_rank)
+    else:
+        kc, vc = jax.vmap(lambda t: gather_layer(pool, 1, t))(tables)
+        want = np.asarray(jax.jit(decode_attn)(q, kc, vc, lengths))
+        qg = np.asarray(q, np.float64).reshape(b, kc.shape[1], -1, dh)
     assert got.shape == want.shape and got.dtype == np.float32
     assert np.isfinite(got).all()
     scale = np.abs(want).max()
     if kv_dtype == "f32":
         assert np.abs(got - want).max() <= 1e-5 * scale
         return
-    b, h, dh = q.shape
     hkv = kc.shape[1]
-    qg = np.asarray(q, np.float64).reshape(b, hkv, h // hkv, dh)
     live = np.arange(kc.shape[2]) < W_LENGTHS[:, None]
     bound = _two_roundings_bound(qg, np.asarray(kc, np.float64),
                                  np.asarray(vc, np.float64), live)
-    err = np.abs(got - want).reshape(b, hkv, h // hkv, dh).max(-1)
+    err = np.abs(got - want).reshape(b, hkv, h // hkv, -1).max(-1)
     assert (err <= bound + 1e-5 * scale).all()
     # bf16 operands, not an f32 upcast of the cache
     assert err.max() > 1e-4 * scale
+
+
+# a latent batch of its own: a padded row of length 0 and one of length 1
+# (their tables all scratch), a row of one block, one at capacity, one
+# that ends mid-block three blocks deep, one position
+L_LENGTHS = np.asarray([0, W_BLK, W_MB * W_BLK, 3 * W_BLK - 2, 1, 1],
+                       np.int32)
+
+
+@pytest.mark.parametrize("steps", ["rule", "2-a-step"])
+@pytest.mark.parametrize("kv_dtype", ["f32", "bf16"])
+def test_walked_latent_read_matches_the_plain_form(monkeypatch, kv_dtype,
+                                                   steps):
+    """The one-sided walk against ``gathered_decode_attn`` (the plain
+    form: one gather of capacity, both products over the copy) on the
+    same latent pool: every row that has a position agrees, an f32 pool
+    to reduction order, a bf16 pool within twice the two roundings' own
+    bound (both sides round). Ragged lengths in one batch, a padded row
+    of length 1 and one of length 0 (which attends over nothing: any
+    finite numbers, and no other row's). ``2-a-step``: two blocks a copy
+    step in place of the rule's whole table, so a row takes up to three
+    steps, the ONE pair of buffers alternates within a row and across
+    rows, and a last step's unfetched block is zeroed in the buffer both
+    products read."""
+    from distributed_llm_code_samples_tpu.ops import kv_walk
+    if steps != "rule":
+        monkeypatch.setattr(kv_walk, "blocks_a_step", lambda *a, **k: 2)
+    pool, q, tables = _walk_case("latent20x40of32", kv_dtype, "none",
+                                 L_LENGTHS)
+    lengths = jnp.asarray(L_LENGTHS)
+    got = _walked(pool, q, tables, L_LENGTHS)
+    plain = np.asarray(jax.jit(lambda q: gathered_decode_attn(
+        pool, 1, q, tables, lengths))(q))
+    assert got.shape == plain.shape == (*q.shape[:2], pool.latent_rank)
+    assert np.isfinite(got).all()
+    some = L_LENGTHS > 0
+    scale = np.abs(plain[some]).max()
+    if kv_dtype == "f32":
+        assert np.abs(got - plain)[some].max() <= 1e-5 * scale
+        return
+    b, h, m = q.shape
+    rows = np.asarray(pool.k[1].astype(jnp.float32), np.float64)[
+        np.asarray(tables)].reshape(b, 1, -1, m)
+    live = np.arange(rows.shape[2]) < L_LENGTHS[:, None]
+    bound = _two_roundings_bound(
+        np.asarray(q, np.float64)[:, None] * np.sqrt(m), rows,
+        rows[..., :pool.latent_rank], live)[:, 0]           # [b, H]
+    err = np.abs(got - plain).max(-1)
+    assert (err <= 2 * bound + 1e-5 * scale)[some].all()
+
+
+def _pallas_calls(jaxpr):
+    return [e for e in jaxpr_eqns(jaxpr) if e.primitive.name == "pallas_call"]
+
+
+def test_latent_walk_fetches_one_side_and_hands_on_the_rank_lanes():
+    """What the kernel is handed and what leaves the read: the V side of
+    no lanes is no operand of the ``pallas_call`` (ONE pool, one pair of
+    buffers, one semaphore a buffer: one copy a block for both
+    products), the kernel's result is the heads' sums over the WHOLE
+    stored row, ``f32[b, H, m]`` (what a K/V pool's call returns of ITS
+    row, and what the benchmark's reader knows the call by), and
+    ``stored_decode_attn`` hands on its first ``latent_rank`` lanes
+    alone: the rotary lanes' sums, which are not zero, stop there."""
+    from distributed_llm_code_samples_tpu.ops import kv_walk
+    pool, q, tables = _walk_case("latent20x40of32", "f32", "none")
+    b, h, m = q.shape
+    lengths = jnp.asarray(W_LENGTHS)
+    call, = _pallas_calls(jax.make_jaxpr(lambda q: stored_decode_attn(
+        pool, 1, q, tables, lengths))(q).jaxpr)
+    operands = [v.aval.shape for v in call.invars]
+    assert operands.count(pool.k.shape) == 1 and pool.v.shape not in operands
+    assert [(v.aval.shape, v.aval.dtype) for v in call.outvars] == [
+        ((b, h, m), jnp.float32)]
+    scratch = [x.shape for x in call.params["grid_mapping"].scratch_avals]
+    assert [x[::2] for x in scratch if len(x) == 3] == [(2, m)]
+    assert (1, 2) in scratch and (2, 2) not in scratch      # semaphores
+    # a K/V pool of the same rows: both sides, a pair of buffers each
+    both, q2, _ = _walk_case("mqa20over1x128", "f32", "none")
+    call, = _pallas_calls(jax.make_jaxpr(lambda q: stored_decode_attn(
+        both, 1, q, tables, lengths))(q2).jaxpr)
+    operands = [v.aval.shape for v in call.invars]
+    assert operands.count(both.k.shape) == 2
+    scratch = [x.shape for x in call.params["grid_mapping"].scratch_avals]
+    assert [x[::2] for x in scratch if len(x) == 3] == 2 * [(2, 128)]
+    assert (2, 2) in scratch
+    got = np.asarray(stored_decode_attn(pool, 1, q, tables, lengths))
+    full = np.asarray(kv_walk.walk_attn(
+        pool.k, pool.v, 1, q, tables, jnp.zeros_like(lengths), lengths, 1.0))
+    assert full.shape == (b, h, m) and got.shape == (b, h, pool.latent_rank)
+    np.testing.assert_array_equal(got, full[..., :pool.latent_rank])
+    assert np.abs(full[..., pool.latent_rank:]).min() > 0
 
 
 # ---------------------------------------------------------------------

@@ -303,6 +303,23 @@ def test_no_writer_no_record_digest_carries_phase_ms(lm_params, prompts,
     json.dumps(list(eng.flight))            # the dump stays serialisable
 
 
+def _launched_lengths(eng) -> list:
+    """Spy on ``eng._launch``: the list it returns holds, for every
+    decode-side program launched since it was last cleared, the rows'
+    lengths as the program is handed them (a padded row's is 0: it
+    attends over the one position it writes)."""
+    launched, launch = [], eng._launch
+
+    def spy(phase, bucket, fn, params, operand, land):
+        if phase != "prefill":
+            launched.append(eng.programs.wire(phase, bucket).unpack(
+                operand)["lengths"])
+        return launch(phase, bucket, fn, params, operand, land)
+
+    eng._launch = spy
+    return launched
+
+
 @pytest.mark.parametrize("kv_dtype,speculate", [
     ("bf16", 0), ("f32", 2), ("int8", 0)])
 def test_kv_blocks_read_is_what_the_decode_side_reads_fetch(
@@ -324,17 +341,7 @@ def test_kv_blocks_read_is_what_the_decode_side_reads_fetch(
     for p in prompts:
         eng.submit(p, 6)
     blk, mb = BASE["block_size"], BASE["max_blocks_per_seq"]
-    launched, launch = [], eng._launch
-
-    def spy(phase, bucket, fn, params, operand, land):
-        # the rows' lengths as the program is handed them (a padded
-        # row's is 0: it attends over the one position it writes)
-        if phase != "prefill":
-            launched.append(eng.programs.wire(phase, bucket).unpack(
-                operand)["lengths"])
-        return launch(phase, bucket, fn, params, operand, land)
-
-    eng._launch = spy
+    launched = _launched_lengths(eng)
     read = capacity = 0
     while eng.active or eng.waiting:
         del launched[:]
@@ -352,6 +359,51 @@ def test_kv_blocks_read_is_what_the_decode_side_reads_fetch(
     doc = eng.telemetry_record()
     assert (doc["kv_blocks_read"], doc["kv_blocks_capacity"]) == (
         read, capacity)
+
+
+@pytest.mark.parametrize("walked", [True, False])
+def test_latent_kv_blocks_read_is_the_rows_live_blocks(monkeypatch, walked):
+    """A toy latent engine's ``kv_blocks_read`` (``engine._count_blocks``
+    books by ``paged.walks`` of the pool: no counter of its own): since
+    PR 50 the latent pool walks, so a step's digest counts the blocks
+    that hold the positions each launched decode-side row attends over,
+    a padded row's one scratch block with them, times the pool's layers
+    — by hand from the lengths each program was handed — and the total
+    stays below ``kv_blocks_capacity``, the tables' whole capacity,
+    which is what the plain read fetched and still does where the pool
+    does not take the walk (the chip's rule refuses the toy's ONE-tile
+    row of 128 lanes: the two are equal there)."""
+    import test_mla_moe_lm as toy
+    from distributed_llm_code_samples_tpu.decode.paged import walks
+    from distributed_llm_code_samples_tpu.ops import ssm
+    driver = toy._load("glm_moe_engine_driver")
+    params = driver._params(toy.TOY, driver.make_weights(toy.TOY, 11))
+    if not walked:
+        monkeypatch.setattr(ssm, "_interpreted", lambda: False)
+    eng = toy.engine(params, slots=4, mbps=8)
+    assert eng.pool.latent_rank and eng.pool.v.shape[-1] == 0
+    assert walks(eng.pool) is walked and eng._walks is walked
+    blk, mb = eng.cfg.block_size, eng.cfg.max_blocks_per_seq
+    layers = eng.pool.k.shape[0]
+    for p in toy.prompts_of([40, 5, 21], seed=3):
+        eng.submit(p, 30)
+    launched = _launched_lengths(eng)
+    read = held = padded = 0
+    while eng.active or eng.waiting:
+        del launched[:]
+        eng.step()
+        # a row handed length ``n`` attends over ``n + 1`` positions
+        want = sum(int((n // blk + 1).sum()) for n in launched) * layers
+        cap = sum(len(n) for n in launched) * mb * layers
+        padded += sum(int((n == 0).sum()) for n in launched)
+        digest = eng.flight[-1]
+        assert digest["kv_blocks_capacity"] == cap
+        assert digest["kv_blocks_read"] == (want if walked else cap)
+        read, held = read + want, held + cap
+    assert padded and 0 < read < held
+    doc = eng.telemetry_record()
+    assert (doc["kv_blocks_read"], doc["kv_blocks_capacity"]) == (
+        read if walked else held, held)
 
 
 @pytest.mark.parametrize("walked", [True, False])
@@ -382,15 +434,7 @@ def test_ring_blocks_read_is_what_the_window_layers_reads_fetch(
     assert eng._ring_walks is walked and entries == window // blk + 2
     for p in toy.prompts_of([40, 5, 21], seed=3):
         eng.submit(p, 70)
-    launched, launch = [], eng._launch
-
-    def spy(phase, bucket, fn, params, operand, land):
-        if phase != "prefill":
-            launched.append(eng.programs.wire(phase, bucket).unpack(
-                operand)["lengths"])
-        return launch(phase, bucket, fn, params, operand, land)
-
-    eng._launch = spy
+    launched = _launched_lengths(eng)
     read = held = padded = 0
     while eng.active or eng.waiting:
         del launched[:]
