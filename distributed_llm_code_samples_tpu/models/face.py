@@ -284,6 +284,22 @@ class AttnStack(NamedTuple):
     wo: jax.Array        # [L_a, d, H*dh]
 
 
+def mm_held(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``mm(x, w)`` with the product held as it is written: its result
+    ``[N, out]`` passes an optimization barrier before anything reshapes
+    it. Where ``w`` is layer ``i`` of a stack and the result's heads are
+    split next (or split and merged again by the cache write), this
+    compiler otherwise moves that reshape onto the weight, the operand
+    becomes ``bitcast(slice(stack))``, the slice no longer fuses into
+    the product, and every layer of the stack is written out to HBM and
+    read back (MiMo-V2-Flash's ``W_q``: 2.9 GB of traffic a decode
+    program for 1.1 GB of weights, PR 51). Held, the product is
+    ``dot(x, slice(stack))`` as the output and MLP products are, and a
+    layer's weights cross HBM once. The barrier stands on the small
+    result, so it costs no copy."""
+    return jax.lax.optimization_barrier(mm(x, w))
+
+
 def qkv_heads(wq, wk, wv, i: int, a, positions, head_dim: int,
               use_rope: bool, theta: float | None = None, qk_norm=None,
               rotary: Rotary | None = None, v_head_dim: int | None = None):
@@ -299,10 +315,10 @@ def qkv_heads(wq, wk, wv, i: int, a, positions, head_dim: int,
     [dh], eps)`` norms every head of ``q`` and of ``k`` over its own
     lanes (gain-only RMSNorm, float32) between the projection and the
     rotation."""
-    q = mm(a, wq[i]).reshape(-1, wq.shape[1] // head_dim, head_dim)
-    k = mm(a, wk[i]).reshape(-1, wk.shape[1] // head_dim, head_dim)
+    q = mm_held(a, wq[i]).reshape(-1, wq.shape[1] // head_dim, head_dim)
+    k = mm_held(a, wk[i]).reshape(-1, wk.shape[1] // head_dim, head_dim)
     dv = v_head_dim or head_dim
-    v = mm(a, wv[i]).reshape(-1, wv.shape[1] // dv, dv)
+    v = mm_held(a, wv[i]).reshape(-1, wv.shape[1] // dv, dv)
     if qk_norm is not None:
         g_q, g_k, eps = qk_norm
         q, k = rmsnorm(g_q, q, eps), rmsnorm(g_k, k, eps)

@@ -705,12 +705,11 @@ def test_conv_moe_step_program_keeps_pool_and_state_as_stored(
 LAGUNA = dict(layers=4, slots=64, mbps=192, chunk=16)
 
 
-@pytest.fixture(scope="module")
-def laguna_engine_args():
-    """``(engine, {kind: (bucket, args)})`` at the cell's widths, over
-    shapes alone: the configuration's own file through the family's
-    ``spec_from_config``, the step programs as ``DecodeEngine`` builds
-    them, both pools in the carry."""
+def _laguna_engine_args(layers):
+    """``(engine, {kind: (bucket, args)})`` at the cell's widths and
+    ``layers`` of its depth, over shapes alone: the configuration's own
+    file through the family's ``spec_from_config``, the step programs as
+    ``DecodeEngine`` builds them, both pools in the carry."""
     import json
     from distributed_llm_code_samples_tpu.decode import EngineConfig
     from distributed_llm_code_samples_tpu.decode.programs import StepPrograms
@@ -720,7 +719,7 @@ def laguna_engine_args():
     with open(os.path.join(root, "benchmark", "configs",
                            "laguna-s-2.1-serve.json")) as f:
         config = json.load(f)
-    n = g["layers"]
+    n = layers
     config = dict(config, num_hidden_layers=n, **{
         k: config[k][:n] for k in ("layer_types", "mlp_layer_types",
                                    "num_attention_heads_per_layer")})
@@ -739,6 +738,11 @@ def laguna_engine_args():
     eng.wpool = jax.eval_shape(programs.init_window)
     eng._cache = lambda: programs.whole(eng.pool, eng.wpool)
     return eng, _cell_programs(eng, slots, chunk)
+
+
+@pytest.fixture(scope="module")
+def laguna_engine_args():
+    return _laguna_engine_args(LAGUNA["layers"])
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
@@ -827,12 +831,11 @@ def test_window_moe_step_program_keeps_both_pools_as_stored(
 MIMO = dict(layers=3, slots=64, mbps=192, chunk=16)
 
 
-@pytest.fixture(scope="module")
-def mimo_engine_args():
-    """``(engine, {kind: (bucket, args)})`` at the cell's widths, over
-    shapes alone: the configuration's own file through the family's
-    ``spec_from_config``, the step programs as ``DecodeEngine`` builds
-    them, both pools in the carry."""
+def _mimo_engine_args(layers):
+    """``(engine, {kind: (bucket, args)})`` at the cell's widths and
+    ``layers`` of its depth, over shapes alone: the configuration's own
+    file through the family's ``spec_from_config``, the step programs as
+    ``DecodeEngine`` builds them, both pools in the carry."""
     import json
     from distributed_llm_code_samples_tpu.decode import EngineConfig
     from distributed_llm_code_samples_tpu.decode.programs import StepPrograms
@@ -842,7 +845,7 @@ def mimo_engine_args():
     with open(os.path.join(root, "benchmark", "configs",
                            "mimo-v2-flash-serve.json")) as f:
         config = json.load(f)
-    n = g["layers"]
+    n = layers
     config = dict(config, num_hidden_layers=n, **{
         k: config[k][:n] for k in ("hybrid_layer_pattern",
                                    "moe_layer_freq")})
@@ -861,6 +864,11 @@ def mimo_engine_args():
     eng.wpool = jax.eval_shape(programs.init_window)
     eng._cache = lambda: programs.whole(eng.pool, eng.wpool)
     return eng, _cell_programs(eng, slots, chunk)
+
+
+@pytest.fixture(scope="module")
+def mimo_engine_args():
+    return _mimo_engine_args(MIMO["layers"])
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
@@ -945,12 +953,12 @@ def test_sink_moe_step_program_keeps_both_pools_as_stored(
 EVABYTE = dict(layers=2, slots=24, mbps=36, chunk=16)
 
 
-@pytest.fixture(scope="module")
-def evabyte_engine_args():
-    """``(engine, {kind: (bucket, args)})`` at the cell's widths, over
-    shapes alone: the configuration's own file through the family's
-    ``spec_from_config`` and its driver's ``engine_config``, the step
-    programs as ``DecodeEngine`` builds them, both stores in the carry."""
+def _evabyte_engine_args(layers):
+    """``(engine, {kind: (bucket, args)})`` at the cell's widths and
+    ``layers`` of its depth, over shapes alone: the configuration's own
+    file through the family's ``spec_from_config`` and its driver's
+    ``engine_config``, the step programs as ``DecodeEngine`` builds
+    them, both stores in the carry."""
     import importlib.util
     import json
     from distributed_llm_code_samples_tpu.decode.programs import StepPrograms
@@ -959,7 +967,7 @@ def evabyte_engine_args():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     configs = os.path.join(root, "benchmark", "configs")
     with open(os.path.join(configs, "evabyte-6.5b-serve.json")) as f:
-        config = dict(json.load(f), num_hidden_layers=g["layers"])
+        config = dict(json.load(f), num_hidden_layers=layers)
     at = importlib.util.spec_from_file_location(
         "evabyte_engine_driver",
         os.path.join(configs, "evabyte_engine_driver.py"))
@@ -978,6 +986,11 @@ def evabyte_engine_args():
     eng.wpool = jax.eval_shape(programs.init_window)
     eng._cache = lambda: programs.whole(eng.pool, eng.wpool)
     return eng, _cell_programs(eng, g["slots"], g["chunk"])
+
+
+@pytest.fixture(scope="module")
+def evabyte_engine_args():
+    return _evabyte_engine_args(EVABYTE["layers"])
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
@@ -1219,6 +1232,122 @@ def _entry_results(hlo: str):
              int(np.prod([int(x) for x in dims.split(",")])))
             for m in line.finditer(entry)
             for bits, dims in shape.findall(m.group(1))]
+
+
+def _entry_ops(hlo: str):
+    """``(name, op, arrays, operands)`` of every instruction of the entry
+    computation: ``arrays`` the ``(bytes an element, dims)`` of each
+    array of its result in the order written, ``operands`` the names it
+    is handed."""
+    import re
+    entry = hlo[hlo.index("\nENTRY "):]
+    line = re.compile(r"^\s*(?:ROOT )?(%\S+) = (.*?) ([\w\-]+)\((.*?)\)(?:,|$)",
+                      re.M)
+    shape = re.compile(r"\b[a-z]+(\d+)\[([\d,]*)\]")
+    return [(m.group(1), m.group(3),
+             [(int(bits) // 8, tuple(int(x) for x in dims.split(",") if x))
+              for bits, dims in shape.findall(m.group(2))],
+             re.findall(r"%[\w.\-]+", m.group(4)))
+            for m in line.finditer(entry)]
+
+
+# the three cells whose attention stacks' layers were written out of
+# their stacks (PR 51), at the depth ``BENCHMARK.json``'s configuration
+# runs them: what the compiler does with a stack depends on its size
+# beside the chip's 128 MiB of VMEM, so the cut fixtures above do not
+# stand in for these
+FULL_DEPTH = {"mimo": (_mimo_engine_args, 11),
+              "laguna": (_laguna_engine_args, 12),
+              "evabyte": (_evabyte_engine_args, 8)}
+
+
+@pytest.fixture(scope="module")
+def full_depth_engine_args():
+    """``cell -> (engine, {kind: (bucket, args)})`` at the cell's whole
+    depth, over shapes alone, built once a cell."""
+    built = {}
+
+    def get(cell):
+        if cell not in built:
+            build, layers = FULL_DEPTH[cell]
+            built[cell] = build(layers)
+        return built[cell]
+
+    return get
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed", "prefill"])
+@pytest.mark.parametrize("cell", sorted(FULL_DEPTH))
+def test_step_program_reads_a_layers_qkv_weights_where_their_stack_lies(
+        one_chip, full_depth_engine_args, kernels_for_the_chip, cell, kind):
+    """``models/face.py::qkv_heads`` holds its three products as written
+    (``mm_held``), so each is ``dot(a, slice(stack))`` with the slice
+    INSIDE the product's fusion and a layer's weights cross HBM once a
+    program. Two things the compiled program must not hold, both read
+    off the params' own shapes:
+
+    - a layer of one of the three stacks, however its rows are viewed,
+      as a RESULT of the entry computation — written out by a fusion or
+      a copy and read again by the product — other than an
+      asynchronous fetch from the parameter itself (``slice-start`` /
+      ``copy-start`` and what views their results). The parent of PR 51
+      fails this in every case: MiMo's decode program wrote all eleven
+      ``bf16[1,12288,4096]`` out of two multi-result fusions and moved
+      each into VMEM by a ``copy-start``, 2.9 GB of traffic for 1.1 GB
+      of weights; EvaByte's all sixteen ``bf16[1,4096,4096]`` of ``W_q``
+      and ``W_k``;
+    - asynchronous copies out of those parameters that add up to more
+      than a tenth of them: a product that takes its layer from the
+      stack viewed with its heads apart (``'nd,hkd->nhk'``, the form
+      tried first) keeps the slice inside too, but the memory-space
+      assignment then fetches the WHOLE stack into VMEM for each
+      layer's product wherever the stack fits (MiMo's 113 MB ``W_k``
+      stack eight times over, three ``slice-start``s of
+      ``bf16[3,1536,4096]`` each: 1.46 GB of async copies out of 1.32 GB
+      of stacks in its decode program, 2 ms a program slower on the
+      chip). What the held form still fetches that way is MiMo's
+      two-layer full stacks, 21 MB."""
+    eng, programs = full_depth_engine_args(cell)
+    bucket, args = programs[kind]
+    hlo = eng._program(kind, bucket).lower(
+        *_shapes_of(args, one_chip)).compile().as_text()
+    stacks = {tuple(x.shape) for path, x in
+              jax.tree_util.tree_leaves_with_path(eng.params)
+              if jax.tree_util.keystr(path).rsplit(".", 1)[-1]
+              in ("wq", "wk", "wv") and len(x.shape) == 3}
+    assert stacks
+    # one layer of a stack, however its rows are viewed: ``[.., d]``
+    # with ``out`` rows in all
+    layer = {s[1:] for s in stacks}
+    ops = _entry_ops(hlo)
+    params = {name for name, op, arrays, _ in ops if op == "parameter"
+              and arrays[:1] in [[(2, s)] for s in stacks]}
+    assert params, "no stack among the entry's parameters"
+    by_name = {name: (op, operands) for name, op, _, operands in ops}
+
+    def from_the_stack(name):
+        op, operands = by_name[name]
+        if op in ("slice-done", "copy-done"):
+            return from_the_stack(operands[0])
+        if op == "custom-call":     # fetched pieces viewed as one array
+            return bool(operands) and all(map(from_the_stack, operands))
+        return op in ("slice-start", "copy-start") and operands[0] in params
+
+    passed = ("parameter", "get-tuple-element", "tuple", "bitcast")
+    written = [(name, op, dims) for name, op, arrays, _ in ops
+               if op not in passed and not from_the_stack(name)
+               for size, dims in arrays
+               if size == 2 and len(dims) > 1
+               and (int(np.prod(dims[:-1])), dims[-1]) in layer]
+    assert not written, written
+    fetched = 0
+    for name, op, arrays, operands in ops:
+        if op in ("copy-start", "slice-start") and operands[0] in params:
+            size, dims = arrays[0] if op == "copy-start" else arrays[-2]
+            fetched += size * int(np.prod(dims))
+    whole = sum(arrays[0][0] * int(np.prod(arrays[0][1]))
+                for name, op, arrays, _ in ops if name in params)
+    assert fetched <= whole // 10, (fetched, whole)
 
 
 # fixture -> whether the cell's full-kind pool takes the walk on the

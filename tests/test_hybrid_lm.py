@@ -442,6 +442,9 @@ def _rot(q, positions):
                                         pos[None])[:, 0, :])(q, positions)
 
 
+_held = jax.lax.optimization_barrier
+
+
 class InlineGPT2:
     """``LMParams`` served as the engine wrote it before any seam."""
 
@@ -464,11 +467,12 @@ class InlineGPT2:
         for l in range(p.n_layers):
             a = layernorm(blk.ln1[l], x)
             # the projections one at a time: each weight is sliced where
-            # it is used
+            # it is used, each product held as written before its heads
+            # are split (``face.mm_held``, PR 51)
             dh = self.spec.head_dim
-            q = (a @ blk.wq[l].T).reshape(-1, blk.wq.shape[1] // dh, dh)
-            k = (a @ blk.wk[l].T).reshape(-1, blk.wk.shape[1] // dh, dh)
-            v = (a @ blk.wv[l].T).reshape(-1, blk.wv.shape[1] // dh, dh)
+            q = _held(a @ blk.wq[l].T).reshape(-1, blk.wq.shape[1] // dh, dh)
+            k = _held(a @ blk.wk[l].T).reshape(-1, blk.wk.shape[1] // dh, dh)
+            v = _held(a @ blk.wv[l].T).reshape(-1, blk.wv.shape[1] // dh, dh)
             if self.cfg.use_rope:
                 q, k = _rot(q, positions), _rot(k, positions)
             pool, y = write_attn(l, pool, q, k, v)
@@ -505,9 +509,9 @@ class InlineHybrid:
             a = hybrid_lm.rmsnorm(p.norm_in[l], x, p.eps)
             if kind == "attn":
                 t = p.attn
-                q = (a @ t.wq[i].T).reshape(-1, t.wq.shape[1] // dh, dh)
-                k = (a @ t.wk[i].T).reshape(-1, t.wk.shape[1] // dh, dh)
-                v = (a @ t.wv[i].T).reshape(-1, t.wv.shape[1] // dh, dh)
+                q = _held(a @ t.wq[i].T).reshape(-1, t.wq.shape[1] // dh, dh)
+                k = _held(a @ t.wk[i].T).reshape(-1, t.wk.shape[1] // dh, dh)
+                v = _held(a @ t.wv[i].T).reshape(-1, t.wv.shape[1] // dh, dh)
                 pool, y = write_attn(i, pool, q, k, v)
                 y = y.reshape(n, -1) @ t.wo[i].T
             else:

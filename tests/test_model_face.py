@@ -384,3 +384,87 @@ def test_qkv_heads_splits_values_by_their_own_width():
                                np.asarray(a @ wv[0].T), rtol=1e-6)
     _, _, v = qkv_heads(wq, wk, wk, 0, a, jnp.arange(5), 24, False)
     assert v.shape == (5, 2, 24)
+
+
+def _qkv_case(name):
+    """``(stacks, layer, activations, positions, head_dim, use_rope,
+    keywords)`` of one caller's shape family, at a toy width."""
+    from distributed_llm_code_samples_tpu.models.attention import Rotary
+    rng = np.random.default_rng(sum(map(ord, name)))
+    d, n, layers = 32, 5, 3
+    h, hkv, dh, dv = 8, 2, 8, 8
+    wdt = adt = jnp.float32
+    use_rope, kw = True, {}
+    if name == "gpt2_f32":          # lm.py: no grouping, no rotary
+        hkv, use_rope = h, False
+    elif name == "gqa_bf16":        # hybrid_lm.py: bf16 weights, f32 rows
+        wdt, use_rope = jnp.bfloat16, False
+    elif name == "bf16_both":       # operands of one type, not float32
+        wdt = adt = jnp.bfloat16
+    elif name == "narrow_v":        # mimo_v2_flash_lm.py: dv < dh
+        dh, dv, wdt = 24, 16, jnp.bfloat16
+        kw = dict(v_head_dim=dv, rotary=Rotary(theta=5e6, partial=0.334))
+    elif name == "qk_norm":         # lfm2_moe_lm.py: norm, then theta
+        g = [jnp.asarray(1 + 0.1 * rng.normal(size=dh), jnp.float32)
+             for _ in range(2)]
+        kw = dict(qk_norm=(g[0], g[1], 1e-5), theta=1e6)
+        wdt = jnp.bfloat16
+    elif name == "yarn_half":       # laguna_lm.py: YaRN on half a head
+        wdt = jnp.bfloat16
+        kw = dict(rotary=Rotary(theta=5e5, partial=0.5, factor=8.0,
+                                original=16, attention_factor=1.2))
+    elif name == "head_shard":      # lm.py under ``generate --tp 2``:
+        hkv, use_rope = h, False    # the test takes the LOCAL half
+    else:
+        assert name == "rope_default", name
+    stacks = tuple(jnp.asarray(0.3 * rng.normal(size=(layers, rows, d)), wdt)
+                   for rows in (h * dh, hkv * dh, hkv * dv))
+    a = jnp.asarray(rng.normal(size=(n, d)), adt)
+    return stacks, 1, a, jnp.asarray(rng.integers(0, 40, n)), dh, use_rope, kw
+
+
+@pytest.mark.parametrize("name", ["gpt2_f32", "gqa_bf16", "bf16_both",
+                                  "narrow_v", "qk_norm", "yarn_half",
+                                  "head_shard", "rope_default"])
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+def test_qkv_heads_holds_its_products_and_changes_no_result(
+        monkeypatch, name, jitted):
+    """PR 51 changed how ``qkv_heads``' three products reach the
+    compiler (each held as written behind a barrier on its result,
+    ``face.mm_held``, so that the layer's slice stays inside the
+    product), not what they compute: for every caller's shape family
+    the three results have the shapes and dtypes of the predecessor's
+    (the same function with the plain ``mm`` in the held one's place)
+    and agree at the resolution of the type the product sums in."""
+    from distributed_llm_code_samples_tpu.models import face
+    (wq, wk, wv), i, a, pos, dh, use_rope, kw = _qkv_case(name)
+
+    def run():
+        wrapped = (lambda wq, wk, wv, a, pos: face.qkv_heads(
+            wq, wk, wv, i, a, pos, dh, use_rope, **kw))
+        return (jax.jit(wrapped) if jitted else wrapped)(wq, wk, wv, a, pos)
+
+    got = run()
+    with monkeypatch.context() as patch:
+        patch.setattr(face, "mm_held", face.mm)
+        want = run()
+    same_type = a.dtype == wq.dtype
+    tol = (2e-2 if same_type and a.dtype == jnp.bfloat16 else 2e-6)
+    for x, y in zip(got, want):
+        assert x.shape == y.shape and x.dtype == y.dtype
+        np.testing.assert_allclose(np.asarray(x, np.float32),
+                                   np.asarray(y, np.float32),
+                                   rtol=tol, atol=tol)
+    heads = wq.shape[1] // dh
+    assert got[0].shape == (a.shape[0], heads, dh)
+    if name == "head_shard":
+        # a shard's rows of each stack give that shard's heads of the
+        # whole: the local count is read off the stack handed in
+        half = heads // 2 * dh
+        local = face.qkv_heads(wq[:, :half], wk[:, :half], wv[:, :half],
+                               i, a, pos, dh, use_rope, **kw)
+        for x, y in zip(local, got):
+            assert x.shape == (a.shape[0], heads // 2, dh)
+            np.testing.assert_allclose(np.asarray(x),
+                                       np.asarray(y[:, :heads // 2]),
+                                       rtol=tol, atol=tol)
