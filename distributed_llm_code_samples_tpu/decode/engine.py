@@ -309,6 +309,12 @@ KV_COUNTERS = ("kv_blocks_read", "kv_blocks_capacity",
 # ``engine_step`` record and by the ``decode`` record so that a reader
 # takes a store's width from the program and not from a family's keys
 ROW_BYTES = ("kv_row_bytes", "window_row_bytes")
+# ... and what ONE sequence holds in ONE recurrent layer, the state and
+# the convolution's tail (``models/face.py::StateRow``; 0 and 0 for a
+# model with no recurrent layer): ``state_bytes`` counts a launched
+# row's two, over the recurrent layers, ONCE (read; it is written back
+# the same size)
+STATE_ROW_BYTES = ("state_row_bytes", "tail_row_bytes")
 
 
 class AdmissionError(RuntimeError):
@@ -694,6 +700,9 @@ class DecodeEngine:
             0 if store is None else
             (store.k.shape[3] + store.v.shape[3]) * store.k.dtype.itemsize
             for store in (self.pool, self.wpool))))
+        row = self.spec.state_row
+        self.state_row_bytes = dict(zip(STATE_ROW_BYTES, (
+            (0, 0) if row is None else (row.state_bytes, row.tail_bytes))))
         # each slot's next token, on the device beside them (and one
         # scratch row): a row's pick is handed to the slot's next row
         # there, so a step can be launched before the last one is read
@@ -3141,7 +3150,8 @@ class DecodeEngine:
         the last ``len(dispatches)`` ordinals below it). The expert
         counters are those of the results READ; the cache reads'
         (``WINDOW_COUNTERS``, ``KV_COUNTERS``) of the rows LAUNCHED,
-        with each store's bytes a position beside them (``ROW_BYTES``).
+        with each store's bytes a position beside them (``ROW_BYTES``)
+        and a recurrent layer's bytes a sequence (``STATE_ROW_BYTES``).
         ``tokens_generated`` is what a reader joins a step on."""
         return {
             "uid": None,
@@ -3159,6 +3169,7 @@ class DecodeEngine:
             **self._step_window,
             **self._step_kv,
             **self.row_bytes,
+            **self.state_row_bytes,
             "dispatches": self._step_dispatches,
             "readbacks": list(self._step_readbacks),
             "launches": self.launches,
@@ -3338,9 +3349,11 @@ class DecodeEngine:
             "kv_blocks_capacity": self.kv_blocks_capacity,
             "ring_blocks_read": self.ring_blocks_read,
             "ring_blocks_capacity": self.ring_blocks_capacity,
-            # extra (v24, additive): a cached position's bytes in one
-            # layer of each store (``ROW_BYTES``)
+            # extra (v24, v25, additive): a cached position's bytes in
+            # one layer of each store (``ROW_BYTES``) and a sequence's
+            # in one recurrent layer (``STATE_ROW_BYTES``)
             **self.row_bytes,
+            **self.state_row_bytes,
             # v17 KV-memory-hierarchy keys (pinned): demotion volume
             # (cumulative blocks + wire bytes), promotion wins
             # (restores, the prompt tokens they kept off the prefill

@@ -5,7 +5,7 @@ ONE function builds the engine for ``train_ffns.py generate
 (``benchmark/configs/jamba_engine_driver.py``,
 ``glm_moe_engine_driver.py``, ``lfm2_moe_engine_driver.py``,
 ``laguna_engine_driver.py``, ``evabyte_engine_driver.py``,
-``mimo_v2_flash_engine_driver.py``): the
+``mimo_v2_flash_engine_driver.py``, ``qwen3_next_engine_driver.py``): the
 published keys say what the model is
 (``model_type`` picks the family's file under ``models/``, its
 ``spec_from_config`` reads the rest), the weights come from a seed or from the caller (a checkpoint restored into
@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import (evabyte_lm, hybrid_lm, laguna_lm, lfm2_moe_lm,
-                      mimo_v2_flash_lm, mla_moe_lm)
+                      mimo_v2_flash_lm, mla_moe_lm, qwen3_next_lm)
 from .engine import DecodeEngine, EngineConfig
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -36,6 +36,8 @@ FAMILIES = {
     "evabyte": (evabyte_lm.spec_from_config, evabyte_lm.init_evabyte_lm),
     "mimo_v2_flash": (mimo_v2_flash_lm.spec_from_config,
                       mimo_v2_flash_lm.init_mimo_v2_flash_lm),
+    "qwen3_next": (qwen3_next_lm.spec_from_config,
+                   qwen3_next_lm.init_qwen3_next_lm),
 }
 
 

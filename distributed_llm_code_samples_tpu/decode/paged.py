@@ -150,7 +150,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..models.face import KVRow
+from ..models.face import KVRow, StateRow
 
 KV_DTYPES = ("f32", "bf16", "int8")
 
@@ -217,15 +217,22 @@ class RecurrentState:
     """The second kind of per-sequence state: what a recurrent
     (state-space) layer carries, which does not grow with the sequence.
     Indexed by SLOT, not through a block table: ``conv [L_r, slots+1,
-    1, (K-1)*D]`` the convolution's last ``K-1`` inputs, oldest first,
-    and ``ssm [L_r, slots+1, N, D]`` the scan state (``ops/ssm.py``),
-    both float32, ``L_r`` the model's recurrent layers. A layer kind
-    that carries the convolution's tail and NO scan state (a gated
-    short convolution, ``models/lfm2_moe_lm.py``: ``d_state`` 0) has
+    1, (K-1)*C]`` the convolution's last ``K-1`` inputs, oldest first,
+    and ``ssm [L_r, slots+1, N, D]`` the state (``ops/ssm.py``: Mamba's
+    scan state, or a gated delta rule's matrix a value head, ``N`` its
+    key lanes and ``D`` the heads' value lanes side by side), both
+    float32, ``L_r`` the model's recurrent layers; ``C, K, N, D`` are
+    the model's ``StateRow`` (``init_state`` builds both from it: the
+    convolution's lanes ``C`` and the state's ``D`` are equal for a
+    Mamba mixer and differ for a delta-rule one). A layer kind
+    that carries the convolution's tail and NO state (a gated
+    short convolution, ``models/lfm2_moe_lm.py``: ``rows`` 0) has
     ``ssm`` None: no leaf, no operand, no bytes — never an array of no
-    elements handed to a program or a kernel. The inner width
-    is the minor axis of both, so the chip keeps them unpadded (a ``[D,
-    N]`` state would pad ``N = 16`` up to 128 lanes). Row ``slots`` is
+    elements handed to a program or a kernel. The wide axis
+    is the minor one of both, so the chip keeps them unpadded (a ``[D,
+    N]`` state would pad ``N = 16`` up to 128 lanes; a delta rule's
+    ``[128, H_v * 128]`` is a head's ``[128, 128]`` block in whole
+    128-lane tiles). Row ``slots`` is
     the scratch row — the pool's idiom: padded bucket rows read and
     write it, nothing else does. A slot's row is never cleared: the
     prefill program takes zeros in its place at position 0. Donated
@@ -262,17 +269,17 @@ class RecurrentState:
         return int(held // self.conv.shape[1])
 
 
-def init_state(n_layers: int, slots: int, d_inner: int, d_state: int,
-               d_conv: int) -> RecurrentState:
+def init_state(n_layers: int, slots: int, row: StateRow) -> RecurrentState:
     """Zero-filled recurrent state for ``slots`` sequences (+ the
-    scratch row) over ``n_layers`` recurrent layers; ``d_state`` 0 is a
-    layer kind with no scan state (``ssm`` None)."""
-    rows = slots + 1
+    scratch row) over ``n_layers`` recurrent layers, each sequence's
+    row of a layer as ``row`` says (``models/face.py::StateRow``); a
+    row of no state rows is a layer kind that carries its tail alone
+    (``ssm`` None)."""
     return RecurrentState(
-        conv=jnp.zeros((n_layers, rows, 1, (d_conv - 1) * d_inner),
+        conv=jnp.zeros((n_layers, slots + 1, 1, row.tail_lanes),
                        jnp.float32),
-        ssm=(jnp.zeros((n_layers, rows, d_state, d_inner), jnp.float32)
-             if d_state else None))
+        ssm=(jnp.zeros((n_layers, slots + 1, row.rows, row.lanes),
+                       jnp.float32) if row.rows else None))
 
 
 def _heads_major(x, head_dim: int):

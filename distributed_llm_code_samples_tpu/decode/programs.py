@@ -258,8 +258,7 @@ class StepPrograms:
         state = None
         if spec.rec_layers:
             state = init_state(spec.rec_layers, cfg.max_slots,
-                               d_inner=spec.d_inner, d_state=spec.d_state,
-                               d_conv=spec.d_conv)
+                               spec.state_row)
         return pool, state
 
     def init_window(self) -> PagedKV | None:
@@ -496,13 +495,13 @@ class StepPrograms:
         return write_attn, mix, write_window, write_chunked
 
     def _slot_state(self, state, i, row, pos0):
-        """``(tail [K-1, D], s [N, D])`` of recurrent layer ``i`` for
+        """``(tail [K-1, C], s [N, D])`` of recurrent layer ``i`` for
         the sequence in state row ``row`` whose chunk starts at
         ``pos0``: zeros at position 0, whatever the row still holds. A
         layer kind with no scan state carries None for it."""
         fresh = pos0 == 0
         tail = jnp.where(fresh, 0.0, state.conv[i, row]).reshape(
-            -1, self.spec.d_inner)
+            -1, self.spec.state_row.conv_lanes)
         s = (None if state.ssm is None
              else jnp.where(fresh, 0.0, state.ssm[i, row]))
         return tail, s

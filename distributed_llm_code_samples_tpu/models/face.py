@@ -7,8 +7,9 @@ cache (``decode/paged.py``); neither it nor the scheduler asks which
 class the params are, reads a weight by name or calls a family's
 arithmetic. ``models/lm.py``, ``models/hybrid_lm.py``,
 ``models/mla_moe_lm.py``, ``models/lfm2_moe_lm.py``,
-``models/laguna_lm.py``, ``models/evabyte_lm.py`` and
-``models/mimo_v2_flash_lm.py`` are the families that exist;
+``models/laguna_lm.py``, ``models/evabyte_lm.py``,
+``models/mimo_v2_flash_lm.py`` and ``models/qwen3_next_lm.py`` are the
+families that exist;
 ``tests/test_model_face.py`` serves one more that lives in the test
 alone. The builder keeps the cache write and read of an attention layer
 (between ``attn_qkv`` and ``attn_out``; for a latent-cache layer
@@ -30,7 +31,10 @@ under the same index: the window kind's ring for the exact K/V of the
 current ALIGNED window, and a row of the full kind's pool for every
 finished chunk of ``CacheSpec.chunk`` positions (its summary), the two
 reads joined under one softmax. Every other kind is recurrent: a state
-row by slot.
+row by slot, whose sizes the model states once (``StateRow``: a Mamba-1
+scan state ``[N, D]`` behind a convolution over the same ``D`` lanes, a
+gated short convolution's tail alone, a gated delta rule's matrix a
+head behind a convolution over ``q``, ``k`` and ``v``).
 
 What more than one family is written from lives below the face: ``mm``,
 ``rmsnorm``, the gated SiLU MLP, the attention stack and its q/k/v
@@ -76,16 +80,52 @@ class KVRow(NamedTuple):
     v_dim: int
 
 
+class StateRow(NamedTuple):
+    """What ONE recurrent layer keeps of ONE sequence, the ONE
+    description the store (``decode/paged.py::init_state``), the prefill
+    program's slices, the byte counts and the counters take their sizes
+    from: the last ``taps - 1`` inputs of a depthwise causal convolution
+    over ``conv_lanes`` lanes (the tail), and a state of ``rows`` rows
+    of ``lanes`` lanes (``rows`` 0: the layer carries its tail and NO
+    state). The two widths are the layer's own and need not agree: a
+    Mamba-1 mixer convolves the ``D`` lanes its ``[N, D]`` scan state
+    has; a gated delta-rule mixer convolves ``q``, ``k`` and ``v`` side
+    by side (``2 H_k d_k + H_v d_v`` lanes) and keeps a matrix a value
+    head, ``[d_k, H_v * d_v]``, a head's ``[d_k, d_v]`` block whole
+    lane tiles of it. Both float32 (``ops/ssm.py``)."""
+    conv_lanes: int
+    taps: int
+    rows: int
+    lanes: int
+
+    @property
+    def tail_lanes(self) -> int:
+        """Lanes of the stored tail: its taps end to end."""
+        return (self.taps - 1) * self.conv_lanes
+
+    @property
+    def tail_bytes(self) -> int:
+        return 4 * self.tail_lanes
+
+    @property
+    def state_bytes(self) -> int:
+        return 4 * self.rows * self.lanes
+
+    @property
+    def bytes(self) -> int:
+        """Bytes a sequence holds in one recurrent layer."""
+        return self.tail_bytes + self.state_bytes
+
+
 class CacheSpec(NamedTuple):
     """What a model keeps per served sequence, as sizes: ``kv_layers``
     layers own paged KV of ``kv_heads`` heads of ``head_dim`` lanes
     (``v_head_dim`` > 0: a value head has that many lanes, a key head
     ``head_dim``; ``row`` is the store's ``KVRow``);
-    ``rec_layers`` layers own a recurrent state of inner width
-    ``d_inner``, state size ``d_state`` and ``d_conv`` convolution taps
-    (0 for a model with none; ``d_state`` 0 with ``rec_layers`` > 0 is
-    a recurrent layer that carries its convolution's tail and NO scan
-    state). ``latent_rank`` > 0 says the ``kv_layers``
+    ``rec_layers`` layers own a recurrent state, each sequence's row of
+    it ``state_row`` (``StateRow``: the convolution's lanes and taps,
+    the state's rows and lanes; None for a model with no recurrent
+    layer). ``latent_rank`` > 0 says the ``kv_layers``
     are ``LATENT`` ones: a token's row is one vector of ``head_dim``
     lanes (``kv_heads`` 1) whose first ``latent_rank`` are also its
     values. Pool and state are built from this. Beside what is kept:
@@ -105,9 +145,7 @@ class CacheSpec(NamedTuple):
     kv_heads: int
     head_dim: int
     rec_layers: int = 0
-    d_inner: int = 0
-    d_state: int = 0
-    d_conv: int = 0
+    state_row: StateRow | None = None
     latent_rank: int = 0
     expert_layers: int = 0
     n_experts: int = 0
@@ -203,13 +241,14 @@ class ServedModel(Protocol):
 
     # one token of b rows, a [b, d]: the state is advanced where it is
     # stored (``decode/paged.py::RecurrentState``: conv [L_r, S, 1,
-    # (K-1)*D], ssm [L_r, S, N, D], both WHOLE), rows [b] naming each
-    # row's slot -> (y, conv, ssm). Where the model's ``d_state`` is 0
-    # there is no scan state: ``ssm`` is None in and out
+    # (K-1)*C], ssm [L_r, S, N, D], both WHOLE; ``C, K, N, D`` the
+    # model's ``StateRow``), rows [b] naming each row's slot -> (y,
+    # conv, ssm). Where the row has no state (``rows`` 0) ``ssm`` is
+    # None in and out
     def recurrent_step(self, i, a, conv, ssm, rows): ...
 
-    # a chunk of one row: a [c, d], tail [K-1, D], state [N, D] (None
-    # where ``d_state`` is 0) -> (y, tail, state)
+    # a chunk of one row: a [c, d], tail [K-1, C], state [N, D] (None
+    # where the row has none) -> (y, tail, state)
     def recurrent_chunk(self, i, a, tail, state): ...
 
     # a decode batch's b rows and then ONE sequence's chunk, a [b + c,

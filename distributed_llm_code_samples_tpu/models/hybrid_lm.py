@@ -38,8 +38,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import ssm
-from .face import (ATTN, AttnStack, CacheSpec, MLPStack, gated_mlp,
-                   layers_of, mm, qkv_heads, rmsnorm)
+from .face import (ATTN, AttnStack, CacheSpec, MLPStack, StateRow,
+                   gated_mlp, layers_of, mm, qkv_heads, rmsnorm)
 
 MAMBA = "mamba"
 
@@ -117,8 +117,10 @@ class HybridLMParams:
             kv_layers=self.attn.wq.shape[0],
             kv_heads=self.attn.wk.shape[1] // self.head_dim,
             head_dim=self.head_dim, rec_layers=m.w_in.shape[0],
-            d_inner=m.conv_w.shape[2], d_state=m.a_log.shape[1],
-            d_conv=m.conv_w.shape[1])
+            state_row=StateRow(conv_lanes=m.conv_w.shape[2],
+                               taps=m.conv_w.shape[1],
+                               rows=m.a_log.shape[1],
+                               lanes=m.conv_w.shape[2]))
 
     def embed(self, tokens, positions, lookup):
         # no position of any kind: the recurrent layers carry order

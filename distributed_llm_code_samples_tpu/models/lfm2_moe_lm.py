@@ -17,7 +17,7 @@ anywhere:
   no activation); ``y = W_out (C * v)``. What a sequence carries is the
   last ``K-1`` values of ``u`` — the convolution's tail in
   ``decode/paged.py::RecurrentState``, by slot — and NO scan state
-  (``cache_spec().d_state`` 0). The convolution itself is
+  (``cache_spec().state_row.rows`` 0). The convolution itself is
   ``ops/ssm.py``'s: ``conv_chunk`` for a prefill chunk,
   ``conv_step_in_place`` on the stored rows for a decode batch; the two
   gate products round it are plain.
@@ -62,8 +62,8 @@ import jax.numpy as jnp
 
 from ..ops import moe_serve, ssm
 from ..ops.moe_serve import ExpertStack, holder  # noqa: F401  (the family's names)
-from .face import (ATTN, AttnStack, CacheSpec, MLPStack, gated_mlp,
-                   layers_of, mm, qkv_heads, rmsnorm)
+from .face import (ATTN, AttnStack, CacheSpec, MLPStack, StateRow,
+                   gated_mlp, layers_of, mm, qkv_heads, rmsnorm)
 
 CONV = "conv"
 # config.json's ``layer_types`` -> the engine's layer kinds
@@ -150,8 +150,8 @@ class Lfm2MoeLMParams:
             kv_layers=self.attn.wq.shape[0],
             kv_heads=self.attn.wk.shape[1] // self.head_dim,
             head_dim=self.head_dim, rec_layers=c.w_in.shape[0],
-            d_inner=c.conv_w.shape[2], d_state=0,
-            d_conv=c.conv_w.shape[1],
+            state_row=StateRow(conv_lanes=c.conv_w.shape[2],
+                               taps=c.conv_w.shape[1], rows=0, lanes=0),
             expert_layers=self.experts.w_gate.shape[0],
             n_experts=self.experts.w_gate.shape[1])
 

@@ -2,7 +2,11 @@
 its depthwise causal convolution alone is also the core of a gated
 short-convolution layer (``models/lfm2_moe_lm.py``: ``K = 3``, no bias,
 no scan state), which runs the same ``conv_chunk`` and
-``conv_step_in_place``.
+``conv_step_in_place``; so does a gated delta-rule mixer
+(``models/qwen3_next_lm.py``: ``K = 4`` over the lanes of ``q``, ``k``
+and ``v`` side by side), whose recurrence, a matrix a head that is
+contracted twice a token, has its one home in ``ops/delta_rule.py``
+(the same four forms, this file's tiling and interpreter rule).
 
 A recurrent layer carries, per sequence, a state that does not grow with
 the sequence: the scan state ``s [N, D]`` and the last ``K-1`` inputs of

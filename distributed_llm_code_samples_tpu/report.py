@@ -80,7 +80,7 @@ from .runtime.telemetry import (FLIGHT_FILENAME, METRICS_FILENAME,
                                 ROUTER_POSTMORTEM_PREFIX,
                                 STATUS_FILENAME, STEP_SPAN, STEP_SPAN_KV,
                                 STEP_SPAN_RING, STEP_SPAN_ROW_BYTES,
-                                read_metrics)
+                                STEP_SPAN_STATE_ROW, read_metrics)
 
 # a completed request's span durations telescope to its latency by
 # construction (runtime/tracing.py); the tolerance only absorbs the
@@ -591,6 +591,7 @@ class _Stream:
             "steps": len(self.step_spans),
             "step_mean_ms": round(total_ms / len(self.step_spans), 4),
             "cache_reads": self._cache_reads(),
+            "state_row": self._state_row(),
             "dispatches": [
                 {"kind": kind, "bucket": bucket, "count": len(ms),
                  "mean_ms": round(float(np.mean(ms)), 4),
@@ -605,6 +606,15 @@ class _Stream:
                        if total_ms else None}
                 for name, ms in per_phase.items()},
         }
+
+    def _state_row(self) -> dict | None:
+        """What a sequence holds in ONE recurrent layer (v25:
+        ``STEP_SPAN_STATE_ROW``, constants of the engine carried by
+        every record), or None where no record says or the model has no
+        recurrent layer."""
+        rec = next((r for r in self.step_spans
+                    if any(r.get(k) for k in STEP_SPAN_STATE_ROW)), None)
+        return rec and {k: rec[k] for k in STEP_SPAN_STATE_ROW}
 
     def _cache_reads(self) -> dict | None:
         """What the steps' rows read of the cache. ``blocks`` (v22):
@@ -1931,6 +1941,12 @@ def _render_engine_sections(out: list, doc: dict) -> None:
                     f"  cache reads: {cr['summary_rows_mean']} chunk "
                     "summaries a step beside the window's positions, "
                     f"{cr['summaries_written']} written")
+        row = sp.get("state_row")
+        if row:
+            state, tail = (row[k] for k in STEP_SPAN_STATE_ROW)
+            out.append(
+                f"  state row: a slot keeps {state + tail} bytes a "
+                f"recurrent layer: {state} of state, {tail} of tail")
     rec = doc.get("recovery", {})
     if (rec.get("attempts_failed") or rec.get("nonfinite_skips")
             or rec.get("attempt_log")

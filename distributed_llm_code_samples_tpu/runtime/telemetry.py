@@ -305,7 +305,17 @@ import numpy as np
 # model has not. Both or none, whole, not negative
 # (``validate_record``). Readers: ``report.py``'s cache-reads line
 # (bytes beside blocks), ``benchmark/sink_window_trace.py``.
-SCHEMA_VERSION = 24
+# v25, additive (PR 52): a recurrent layer's BYTES a sequence — the
+# ``engine_step`` record and the ``decode`` record may carry
+# ``state_row_bytes`` and ``tail_row_bytes`` (``STEP_SPAN_STATE_ROW``;
+# the engine writes both), what ONE sequence holds in ONE recurrent
+# layer: its state (a Mamba scan state, a delta rule's matrix a head)
+# and its convolution's last inputs, the two widths of
+# ``models/face.py::StateRow``; 0 and 0 for a model with no recurrent
+# layer. ``state_bytes`` is a step's launched rows times their sum over
+# the recurrent layers, counted once. Both or none, whole, not negative
+# (``validate_record``). Readers: ``report.py``'s state-row line.
+SCHEMA_VERSION = 25
 
 METRICS_FILENAME = "metrics.jsonl"
 
@@ -474,6 +484,8 @@ STEP_SPAN_RING = ("ring_blocks_read", "ring_blocks_capacity")
 STEP_SPAN_CHUNKS = ("summary_rows", "summaries_written")
 # ... and each store's bytes a position a layer (v24, additive)
 STEP_SPAN_ROW_BYTES = ("kv_row_bytes", "window_row_bytes")
+# ... and a recurrent layer's bytes a sequence (v25, additive)
+STEP_SPAN_STATE_ROW = ("state_row_bytes", "tail_row_bytes")
 
 # The router-record contract (``decode/fleet.py``): one record per
 # fleet-router decision. ``step`` is the ROUTER's step clock (fleet
@@ -1163,7 +1175,9 @@ def validate_record(rec: Any) -> tuple[bool, str]:
                         f"{read} <= {held}")
             for group, what in (
                     (STEP_SPAN_CHUNKS, "chunk summaries' counters"),
-                    (STEP_SPAN_ROW_BYTES, "stores' bytes a position")):
+                    (STEP_SPAN_ROW_BYTES, "stores' bytes a position"),
+                    (STEP_SPAN_STATE_ROW,
+                     "recurrent layer's bytes a sequence")):
                 got = [k for k in group if k in rec]
                 if got and (len(got) != len(group) or any(
                         not isinstance(rec[k], int) or rec[k] < 0

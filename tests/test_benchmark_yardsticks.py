@@ -8,7 +8,7 @@ reducer on its recorded fixtures (``test_xplane``), the join of programs
 to launches by ordinal and its late-read form (``test_dispatch_join_late``,
 ``test_recorded_windows``), the readers that book a program's ops
 (``test_expert_trace_by_ordinal``, ``test_chunk_trace``,
-``test_sink_window_trace``), the shares read
+``test_sink_window_trace``, ``test_delta_trace``), the shares read
 from step records (``test_chunk_ride_share``, ``test_late_read_share``),
 the trainer's FLOP count against the compiled step
 (``test_trainer_flops``), and the refusal to measure off a TPU
@@ -37,7 +37,7 @@ FILES = {"test_xplane": (), "test_dispatch_join_late": (),
          "test_trainer_flops": (), "test_expert_trace_by_ordinal": (),
          "test_chunk_ride_share": (), "test_late_read_share": (),
          "test_chunk_trace": (), "test_recorded_windows": (),
-         "test_sink_window_trace": (),
+         "test_sink_window_trace": (), "test_delta_trace": (),
          "test_rehearsal": ("test_refuses_to_run_off_a_tpu",)}
 
 

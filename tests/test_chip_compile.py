@@ -439,7 +439,8 @@ def test_hybrid_decode_program_updates_the_state_where_it_lies(
     for store in (state.conv, state.ssm):   # a result of a call a layer
         shape = "f32[%s]" % ",".join(map(str, store.shape))
         assert sum(shape in l for l in calls) == spec.rec_layers, shape
-    n, d, k1 = spec.d_state, spec.d_inner, spec.d_conv - 1
+    row = spec.state_row
+    n, d, k1 = row.rows, row.lanes, row.taps - 1
     gathered = re.compile(r"= \(?[^=]*\bf32\[%d,(%d,%d|%d,%d|%d)\]"
                           % (bucket, n, d, k1, d, k1 * d))
     assert not [l for l in hlo.splitlines() if gathered.search(l)]
@@ -462,9 +463,11 @@ def test_state_kernels_compile_at_the_hybrid_cells_widths(
     whole and enters row-major and unpadded as it is stored, and nothing
     is held beside it."""
     from distributed_llm_code_samples_tpu.decode.paged import init_state
+    from distributed_llm_code_samples_tpu.models.face import StateRow
     from distributed_llm_code_samples_tpu.ops import ssm
     n, d, k, layers = 16, 5120, 4, 26
-    state = jax.eval_shape(lambda: init_state(layers, 64, d, n, k))
+    state = jax.eval_shape(lambda: init_state(layers, 64,
+                                              StateRow(d, k, n, d)))
     f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
                             sharding=one_chip)
     rows = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
@@ -1176,7 +1179,7 @@ class _BodiesOfThePredecessor:
             with jax.named_scope("ssm"):
                 fresh = pos0 == 0
                 tail = jnp.where(fresh, 0.0, state.conv[i, row]).reshape(
-                    -1, self.spec.d_inner)
+                    -1, self.spec.state_row.conv_lanes)
                 s = (None if state.ssm is None
                      else jnp.where(fresh, 0.0, state.ssm[i, row]))
                 y, tail, s = p.recurrent_chunk(i, a, tail, s)
@@ -1573,6 +1576,119 @@ def test_walk_kernel_copies_and_products_by_the_pools_sides(cell):
     assert walk.outvars[0].aval.shape == (b, h, jv or j)
 
 
+# the gated delta-rule, fine-grained expert cell of the benchmark
+# (qwen3-next.longreason-offline): Qwen3-Next-80B-A3B's widths, 16 key
+# and 32 value heads of 128 lanes in the delta layers (a state row of
+# [128, 4096] behind a convolution over 8,192 lanes), 16 query heads of
+# 256 lanes over 2 KV heads in the full layers, 64 of 512 experts held
+# beside a gated shared one, an eighth of the vocabulary, 128 slots x
+# 3072 positions, bf16 weights and K/V, cut to 4 layers (one period:
+# delta x 3, full) so the compile stays short
+QWEN3_NEXT = dict(layers=4, slots=128, mbps=192, chunk=16)
+
+
+def _qwen3_next_engine_args(layers):
+    """``(engine, {kind: (bucket, args)})`` at the cell's widths and
+    ``layers`` of its depth, over shapes alone: the configuration's own
+    file through the family's ``spec_from_config``, the step programs as
+    ``DecodeEngine`` builds them, the pool and the state in the carry."""
+    import json
+    from distributed_llm_code_samples_tpu.decode import EngineConfig
+    from distributed_llm_code_samples_tpu.decode.programs import StepPrograms
+    from distributed_llm_code_samples_tpu.models import qwen3_next_lm
+    g = QWEN3_NEXT
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "qwen3-next-80b-a3b-serve.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=layers)
+    spec = qwen3_next_lm.spec_from_config(config)
+    params = jax.eval_shape(
+        lambda k: qwen3_next_lm.init_qwen3_next_lm(
+            k, spec, dtype=jnp.bfloat16), jax.random.PRNGKey(0))
+    slots, mbps, chunk = g["slots"], g["mbps"], g["chunk"]
+    programs = StepPrograms(
+        EngineConfig(n_blocks=1 + slots * mbps, max_slots=slots,
+                     max_blocks_per_seq=mbps, prefill_chunk=chunk,
+                     kv_dtype="bf16"),
+        params.cache_spec(16), params.vocab)
+    eng = _ShapesEngine(programs, params,
+                        *jax.eval_shape(programs.init_cache))
+    return eng, _cell_programs(eng, slots, chunk)
+
+
+@pytest.fixture(scope="module")
+def qwen3_next_engine_args():
+    return _qwen3_next_engine_args(QWEN3_NEXT["layers"])
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "mixed"])
+def test_delta_moe_step_program_keeps_pool_and_state_as_stored(
+        one_chip, qwen3_next_engine_args, kernels_for_the_chip, kind):
+    """A state row whose convolution and state differ in width
+    (``models/face.py::StateRow``) in a step program: the tails ``[3,
+    129, 1, 24576]`` (three taps of 8,192 lanes) and the matrices ``[3,
+    129, 128, 4096]`` (a value head's ``[128, 128]`` block whole lane
+    tiles) are taken row-major and unpadded beside the pool, aliased
+    whole and updated in place; a decode-side program holds ONE delta
+    kernel call and ONE convolution call a gated-delta layer, each with
+    its store as a result, no ``[b, 128, 4096]`` gathered copy and no
+    state-sized copy of the program's own; the full layer's read is one
+    ``walk_attn`` call at heads of 256 lanes (two 128-lane tiles a
+    head, rows of 512 lanes a side). The result carries the held
+    experts' counters after the picks."""
+    import re
+    eng, programs = qwen3_next_engine_args
+    bucket, args = programs[kind]
+    compiled = eng._program(kind, bucket).lower(
+        *_shapes_of(args, one_chip)).compile()
+    hlo = compiled.as_text()
+    pool, state, spec = eng.pool, eng.state, eng.programs.spec
+    row = spec.state_row
+    assert (row.conv_lanes, row.taps, row.rows, row.lanes) == (
+        8192, 4, 128, 4096)
+    assert row.bytes == 2_195_456
+    assert state.conv.shape == (3, 129, 1, 24576)
+    assert state.ssm.shape == (3, 129, 128, 4096)
+    assert pool.k.shape == pool.v.shape == (1, 24577, 16, 512)
+    from distributed_llm_code_samples_tpu.decode import paged
+    assert paged.walks(pool)
+    (pool_fmt, state_fmt), _ = compiled.input_formats[0][1]
+    stores = (pool.k, pool.v, state.conv, state.ssm)
+    for fmt, arr in zip((pool_fmt.k, pool_fmt.v, state_fmt.conv,
+                         state_fmt.ssm), stores):
+        assert fmt.layout.major_to_minor == tuple(range(arr.ndim)), fmt
+    m = compiled.memory_analysis()
+    held = sum(_nbytes(x) for x in stores) + _nbytes(eng.token_store)
+    assert m.alias_size_in_bytes >= held
+    assert _carry_is_aliased_whole(compiled, eng) == held
+    logical = sum(_nbytes(x) for x in jax.tree_util.tree_leaves(args[:2]))
+    assert m.argument_size_in_bytes - logical < _nbytes(state.conv) // 10
+    assert _total_bytes(compiled) < HBM_V5E
+    picks = {"decode": bucket, "prefill": 1, "mixed": bucket + 1}[kind]
+    out = jax.eval_shape(eng.programs.body(kind, bucket), *args)[1]
+    assert out.shape == (picks + 4 * 64,) and out.dtype == jnp.int32
+    calls = [l.split(" custom-call(")[0] for l in hlo.splitlines()
+             if MOSAIC in l]
+    shapes = {"conv": "f32[3,129,1,24576]", "delta": "f32[3,129,128,4096]",
+              "walk": "f32[%d,16,512]" % bucket}
+    got = {k: sum(v in l for l in calls) for k, v in shapes.items()}
+    decode_side = kind != "prefill"
+    assert got == {"conv": 3 * decode_side, "delta": 3 * decode_side,
+                   "walk": 1 * decode_side}, got
+    assert len(calls) == sum(got.values())
+    # no copy of a batch's state rows, in any layout, and no array of
+    # the whole store's size (or a layer's) made by the program itself
+    gathered = re.compile(r"= \(?[^=]*\bf32\[%d,(128,4096|3,8192|24576)\]"
+                          % bucket)
+    assert not [l for l in hlo.splitlines() if gathered.search(l)]
+    layer = _nbytes(state.ssm) // state.ssm.shape[0] // 4
+    relaid = [r for r in _entry_results(hlo)
+              if r[0] in ("reshape", "copy", "pad", "transpose", "slice",
+                          "dynamic-slice")
+              and r[1] == 4 and r[2] >= layer]
+    assert not relaid, relaid
+
+
 # cell -> (its shrink under benchmark/tests, the per-layer metric that
 # reads one of the program's own counters: readable with no device trace)
 REHEARSED = {
@@ -1586,6 +1702,8 @@ REHEARSED = {
     "evabyte.bytereason-offline": ("shrink_evabyte", "summary_rows_share"),
     "mimo-v2-flash.longreason-offline": ("shrink_mimo",
                                          "share_rows_max_over_mean"),
+    "qwen3-next.longreason-offline": ("shrink_qwen3_next",
+                                      "state_bytes_live"),
 }
 
 
@@ -1596,9 +1714,10 @@ def test_cell_rehearsal_on_the_cpu(monkeypatch, name, trace):
     ``benchmark/tests/test_rehearsal.py`` rehearses the older cells
     (its ``shrink.py`` knows those only; these cells' shrinks are
     ``benchmark/tests/shrink_jamba.py``, ``shrink_glm.py``,
-    ``shrink_lfm2.py``, ``shrink_laguna.py``, ``shrink_evabyte.py`` and
-    ``shrink_mimo.py``, the last two under a clock that moves by the
-    engine's steps). Nothing here is a measurement."""
+    ``shrink_lfm2.py``, ``shrink_laguna.py``, ``shrink_evabyte.py``,
+    ``shrink_mimo.py`` and ``shrink_qwen3_next.py``, the last three
+    under a clock that moves by the engine's steps). Nothing here is a
+    measurement."""
     import importlib
     import json
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -251,8 +251,8 @@ def test_parameter_count_at_published_widths():
     assert [i for i, k in enumerate(p.kinds) if k == "attn"] == [1, 5]
     cs = p.cache_spec(32)
     assert (cs.kv_layers, cs.kv_heads, cs.head_dim) == (2, 8, 64)
-    assert (cs.rec_layers, cs.d_inner, cs.d_state, cs.d_conv) == (
-        7, 2048, 0, 3)
+    assert (cs.rec_layers, cs.state_row) == (7, (2048, 3, 0, 0))
+    assert (cs.state_row.tail_bytes, cs.state_row.bytes) == (16_384, 16_384)
     assert (cs.expert_layers, cs.n_experts, cs.latent_rank) == (8, 64, 0)
     assert "5,177,950,976" in config["serving"]["note"]
     assert "10,358,000,128" in config["serving"]["note"]

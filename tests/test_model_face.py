@@ -33,7 +33,8 @@ from distributed_llm_code_samples_tpu.decode.model_config import (
     params_from_config)
 from distributed_llm_code_samples_tpu.models import init_lm
 from distributed_llm_code_samples_tpu.models.face import (ATTN, LATENT,
-                                                          CacheSpec)
+                                                          CacheSpec,
+                                                          StateRow)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG = os.path.join(ROOT, "distributed_llm_code_samples_tpu")
@@ -194,8 +195,9 @@ SPECS = {
                                  n_heads=4, n_kv_heads=2),
                  CacheSpec(3, 2, 8)),
     "hybrid": (lambda: params_from_config(HYBRID_TOY, 1),
-               CacheSpec(2, 1, 16, rec_layers=4, d_inner=128, d_state=4,
-                         d_conv=4)),
+               CacheSpec(2, 1, 16, rec_layers=4,
+                         state_row=StateRow(conv_lanes=128, taps=4, rows=4,
+                                            lanes=128))),
 }
 
 
@@ -248,11 +250,16 @@ def test_cache_spec_sizes_pool_and_state(family):
     assert pool.head_dim == want.head_dim
     if not want.rec_layers:
         assert eng.state is None and eng.recurrent == []
+        assert set(eng.state_row_bytes.values()) == {0}
         return
     assert eng.recurrent == ["mamba"]
-    assert eng.state.conv.shape == (4, 3, 1,
-                                    (want.d_conv - 1) * want.d_inner)
-    assert eng.state.ssm.shape == (4, 3, want.d_state, want.d_inner)
+    row = want.state_row
+    assert eng.state.conv.shape == (4, 3, 1, row.tail_lanes)
+    assert eng.state.ssm.shape == (4, 3, row.rows, row.lanes)
+    assert eng.state.bytes_per_slot == 4 * row.bytes
+    # ... at the row's two widths, which every record carries
+    assert eng.state_row_bytes == {"state_row_bytes": 4 * 4 * 128,
+                                   "tail_row_bytes": 4 * 3 * 128}
 
 
 # -- the structure -------------------------------------------------------------

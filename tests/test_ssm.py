@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from distributed_llm_code_samples_tpu.decode.paged import init_state
+from distributed_llm_code_samples_tpu.models.face import StateRow
 from distributed_llm_code_samples_tpu.ops import ssm
 
 TOL = 2e-4
@@ -49,7 +50,7 @@ def _operands(rows, k, seed=0, bias=True):
         return jax.random.normal(next(ks), shape, jnp.float32)
 
     b = len(rows)
-    zero = init_state(LAYERS, SLOTS, D, N, k)
+    zero = init_state(LAYERS, SLOTS, StateRow(D, k, N, D))
     state = zero._replace(conv=normal(*zero.conv.shape),
                           ssm=normal(*zero.ssm.shape))
     return dict(

@@ -591,11 +591,14 @@ def test_record_counts_are_the_engines_counters(lm_params, prompts):
                             "kv_blocks_read", "kv_blocks_capacity",
                             "ring_blocks_read", "ring_blocks_capacity",
                             "kv_row_bytes", "window_row_bytes",
+                            "state_row_bytes", "tail_row_bytes",
                             "dispatches", "readbacks", "launches"}
         # a position's bytes in one layer of the pool, from its arrays;
         # no window pool here
         assert rec["kv_row_bytes"] == 2 * H * (D // H) * 4
         assert rec["window_row_bytes"] == 0
+        # ... and no recurrent layer: no state row
+        assert rec["state_row_bytes"] == rec["tail_row_bytes"] == 0
         assert rec["window_rows"] == rec["full_rows"] == 0  # nor window
         assert rec["summary_rows"] == rec["summaries_written"] == 0
         assert rec["ring_blocks_read"] == rec["ring_blocks_capacity"] == 0

@@ -132,7 +132,11 @@ from distributed_llm_code_samples_tpu.runtime.telemetry import (
 # v24 (PR 44): a window layer's ring is walked too — the ``engine_step``
 # record may carry the ring's blocks fetched beside the rings' entries
 # (``STEP_SPAN_RING``), both or none.
-_PINNED_VERSION = 24
+# v25 (PR 52): a recurrent layer's row has two widths — the
+# ``engine_step`` record may carry what ONE sequence holds in ONE
+# recurrent layer, its state and its convolution's tail
+# (``STEP_SPAN_STATE_ROW``), both or none.
+_PINNED_VERSION = 25
 _PINNED_STEP_SPAN_RING = frozenset({"ring_blocks_read",
                                     "ring_blocks_capacity"})
 _PINNED_STEP_SPAN_CHUNKS = frozenset({"summary_rows", "summaries_written"})
@@ -380,7 +384,7 @@ def test_engine_step_v20_round_trips(tmp_path):
                                                   METRICS_FILENAME))
     assert problems == []
     first, closing, idle = records
-    assert first["schema"] == SCHEMA_VERSION == 24
+    assert first["schema"] == SCHEMA_VERSION == 25
     assert first["dispatches"] == [["prefill", 4], ["decode", 8]]
     assert [p[0] for p in first["phases"] if p[0].endswith(".dispatch")] \
         == [k + ".dispatch" for k, _ in first["dispatches"]]
